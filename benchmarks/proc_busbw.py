@@ -420,7 +420,7 @@ def _pairs_main(args, comm):
 
     Each timed batch runs the flat plane (``set_hier("off")``) and the
     hierarchical plane (``set_hier("on")``) back to back, alternating
-    across batches, so co-tenant phase noise hits both sides equally —
+    across batches, so slow drift of the machine hits both sides equally —
     the measurement convention of the PR-2 tree/ring comparison.  Rank
     0 prints one record per side plus a ratio record."""
     import jax.numpy as jnp
@@ -1430,4 +1430,7 @@ def _copy_rate_gbps():
 
 
 if __name__ == "__main__":
+    from mpi4jax_tpu.utils.runtime import enable_compile_cache
+
+    enable_compile_cache()
     main()

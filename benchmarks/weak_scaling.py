@@ -70,7 +70,7 @@ def main(argv=None):
 
     import mpi4jax_tpu as m
     from mpi4jax_tpu.models import shallow_water as sw
-    from mpi4jax_tpu.utils.runtime import best_mesh_shape, drain
+    from mpi4jax_tpu.utils.runtime import best_mesh_shape
 
     all_devices = jax.devices()
     counts = []
@@ -101,11 +101,9 @@ def main(argv=None):
         first = sw.make_first_step(cfg, comm)
         multi = sw.make_multistep(cfg, comm, args.steps)
         s = first(init())
-        s = multi(s)
-        drain(s.h)
+        s = jax.block_until_ready(multi(s))
         t0 = time.perf_counter()
-        s = multi(s)
-        drain(s.h)
+        s = jax.block_until_ready(multi(s))
         dt = time.perf_counter() - t0
         rate = ny * nx * args.steps / dt
         per_dev = rate / n
@@ -213,4 +211,7 @@ def _proc_main(args):
 
 
 if __name__ == "__main__":
+    from mpi4jax_tpu.utils.runtime import enable_compile_cache
+
+    enable_compile_cache()
     main()

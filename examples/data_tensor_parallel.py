@@ -103,4 +103,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from mpi4jax_tpu.utils.runtime import enable_compile_cache
+
+    enable_compile_cache()
     main()

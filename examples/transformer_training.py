@@ -72,7 +72,7 @@ def main(argv=None):
         "--kv-bucket", type=int, default=None,
         help="decode with bucketed KV growth: each step reads only the "
         "cache written so far, rounded up to this bucket — the "
-        "large-batch decode lever (docs/performance.md)",
+        "large-batch decode lever",
     )
     p.add_argument(
         "--force-cpu", action="store_true",
@@ -82,8 +82,7 @@ def main(argv=None):
         "--remat", choices=("off", "full", "dots", "names"), default="off",
         help="dense-mode activation checkpointing: full = per-layer "
         "jax.checkpoint, dots = save every matmul output, names = the "
-        "q/k/attn-out/mlp-out policy the MFU bench uses "
-        "(docs/performance.md)",
+        "q/k/attn-out/mlp-out policy the MFU bench uses",
     )
     args = p.parse_args(argv)
 
@@ -266,6 +265,9 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from mpi4jax_tpu.utils.runtime import enable_compile_cache
+
+    enable_compile_cache()
     main()
 
 

@@ -77,6 +77,13 @@ def _swallow(fn):
         pass
 
 
+def _acquire_devices():
+    """Initialise the worker's jax backend (a seam the tests fake)."""
+    import jax
+
+    return jax.devices()
+
+
 def child_main(argv):
     """Entry for worker processes (internal)."""
     prog, *prog_args = argv
@@ -85,6 +92,26 @@ def child_main(argv):
         import jax
 
         jax.config.update("jax_platforms", platform)
+    size = int(os.environ.get("T4J_SIZE", "1"))
+    if platform != "cpu" and size > 1:
+        # An accelerator chip belongs to one process at a time and this
+        # launcher pins no chip to a worker, so in a world larger than
+        # one all but the first worker can fail to get the device.  Find
+        # out now, before the bootstrap waits for peers that will die.
+        try:
+            _acquire_devices()
+        except RuntimeError as exc:
+            raise SystemExit(
+                f"mpi4jax_tpu.launch: rank {os.environ.get('T4J_RANK', '?')}"
+                f" of {size} could not get a device of platform "
+                f"{platform!r}: {exc}\n"
+                "An accelerator chip belongs to one process at a time, and "
+                "this launcher does not pin chips to workers (no "
+                "TPU_VISIBLE_* or process-bounds handling): every worker "
+                "of --platform default/tpu sees the whole host's chips. "
+                "Run accelerator workers with -np 1, or keep the workers "
+                "on --platform cpu."
+            ) from exc
     from mpi4jax_tpu.native import runtime
 
     runtime.ensure_initialized()
@@ -168,8 +195,11 @@ def main(argv=None):
         "--platform",
         default="cpu",
         help="jax platform to pin workers to (default: cpu). Pass "
-        "'default' to leave the site/environment platform untouched — "
-        "e.g. to run workers against a real accelerator.",
+        "'default' to leave the environment's platform untouched — "
+        "e.g. to run a worker against a real accelerator. A chip "
+        "belongs to one process at a time and no chip is pinned to a "
+        "worker, so an accelerator platform needs -np 1; with more, a "
+        "worker that cannot get the device exits at once and says so.",
     )
     parser.add_argument(
         "--shims",

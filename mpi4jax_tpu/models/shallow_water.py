@@ -779,12 +779,12 @@ def make_solver(
 
     init = make_init(cfg, comm)
     first = make_first_step(cfg, comm)
-    multi = make_multistep(cfg, comm, num_multisteps)
-
-    from mpi4jax_tpu.utils.runtime import drain
-
-    def sync(state):
-        return drain(state.h)
+    # the loop below is `state = multi(state)`: update in place, unless a
+    # callback or an asynchronous save may still hold the old state
+    multi = make_multistep(
+        cfg, comm, num_multisteps,
+        donate=on_chunk is None and checkpoint_dir is None,
+    )
 
     def solve(t1):
         mgr = None
@@ -829,7 +829,7 @@ def make_solver(
                 # reference)
                 state = multi(state)
                 t += cfg.dt * num_multisteps
-            sync(state)
+            jax.block_until_ready(state)
             if on_chunk is not None:
                 on_chunk(state, t)
             steps = 0
@@ -852,7 +852,7 @@ def make_solver(
                         {"state": state, "t": np.float64(t)},
                         every=checkpoint_every,
                     )
-            sync(state)
+            jax.block_until_ready(state)
             wall = time.perf_counter() - start
             return state, wall, steps
         finally:

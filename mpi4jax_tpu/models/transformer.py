@@ -271,8 +271,8 @@ def _forward_sharded(
         # the attention internals, whose [T, T] score tensors are the
         # memory hog — recovering most of full-remat's memory saving at
         # a fraction of its ~1/3 FLOP overhead.
-        # remat="names" is the measured sweet spot on bandwidth-starved
-        # chips (docs/performance.md step timeline): keep FOUR
+        # remat="names" is the builders' choice for the `large` preset
+        # (not measured on the current chip): keep FOUR
         # [tokens, d]-sized tensors per layer (q, k, attn-out, mlp-out
         # — v is tagged "v_proj", deliberately outside the save list)
         # and recompute only the cheap glue (rmsnorms, residual adds,
@@ -332,9 +332,9 @@ def _ce(logits, targets):
     log-probability IS ``logits[target] - logsumexp``), but never
     materialises a float32 ``[B, S, V]`` tensor: the f32 conversion
     fuses into the logsumexp reductions, so XLA reads the bf16 logits
-    and writes only ``[B, S]`` statistics.  The log_softmax form cost
-    ~12 GB/step of f32 HBM round-trips on the MFU config's
-    ``[16, 2048, 32768]`` logits (step timeline, docs/performance.md)."""
+    and writes only ``[B, S]`` statistics.  The log_softmax form moves
+    ~12 GB/step of f32 through HBM on the `large` preset's
+    ``[16, 2048, 32768]`` logits (a count from shapes)."""
     lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
     picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)
     return (lse - picked[..., 0].astype(jnp.float32)).mean()
@@ -704,8 +704,8 @@ def make_global_decode(
     saves) or ``"flash"`` (the Pallas blockwise kernel, ops/flash.py)
     for LONG prompts, where the dense [P, P] score tensor dominates the
     prefill — the long-context inference analog of the training-side
-    crossover (docs/performance.md "Flash vs dense").  Token-identical
-    either way (same math; the equivalence is pinned on-chip).
+    crossover.  Token-identical either way at full matmul precision
+    (chip_smoke.py's transformer.decode phase checks it on the chip).
 
     ``kv_bucket=N`` runs the generate loop in KV-length buckets: the
     scan carry is a cache VIEW whose static length grows by N per
@@ -713,8 +713,8 @@ def make_global_decode(
     reads/attends only ``ceil((pos+1)/N)·N`` cache positions instead of
     the full ``max_len`` budget.  Decode is KV-bandwidth-bound at large
     batch, and with the un-bucketed loop every step pays the PADDED
-    budget read — at the bench's batch-32 point that padding tax is the
-    measured ~2× gap to the bandwidth bound (docs/performance.md).
+    budget read (how much that costs: not measured on the current
+    chip).
     Token-exact vs the un-bucketed loop (garbage positions beyond
     ``pos`` are causally masked either way).
     """

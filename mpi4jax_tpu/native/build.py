@@ -3,7 +3,8 @@
 The reference compiles its Cython bridge with mpicc at pip-install time
 (setup.py:75-86 custom_build_ext); here the C++ bridge is compiled with
 g++ against the XLA FFI headers shipped inside jaxlib
-(``jax.ffi.include_dir()``), cached by source mtime, on first use.
+(``jax.ffi.include_dir()``) on first use, and cached under a key made
+of the sources' content, the build mode and the CPU's feature flags.
 
 Also usable standalone:  python -m mpi4jax_tpu.native.build
 """
@@ -84,38 +85,43 @@ def _machine_key():
     return f"{key}|{san}" if san else key
 
 
+def _build_key():
+    """What a cached .so must have been built from to be loaded: the
+    machine/build-mode key plus the CONTENT of every source and header.
+    A copy of the tree keeps neither mtimes nor the machine, so a
+    binary that travelled with it, or one older than an edit that kept
+    the mtime, never matches."""
+    import hashlib
+
+    h = hashlib.sha256(_machine_key().encode())
+    for name in _SOURCES + _HEADERS:
+        h.update(name.encode())
+        h.update((_SRC_DIR / name).read_bytes())
+    return h.hexdigest()
+
+
 def _needs_build():
     if not _OUT.exists():
         return True
-    key_file = _OUT.with_suffix(".buildinfo")
     try:
-        if key_file.read_text().strip() != _machine_key():
-            return True
+        recorded = _OUT.with_suffix(".buildinfo").read_text().strip()
     except OSError:
         return True
-    out_mtime = _OUT.stat().st_mtime
-    for s in _SOURCES + _HEADERS:
-        if (_SRC_DIR / s).stat().st_mtime > out_mtime:
-            return True
-    return False
+    return recorded != _build_key()
 
 
 def _ffi_include_dir():
-    """The XLA FFI headers inside the installed jaxlib.  jax>=0.7
-    exposes them as jax.ffi; older lines (which cannot import the
-    package but can still build/lint the bridge standalone) as
-    jax.extend.ffi."""
-    try:
-        import jax.ffi as ffi
-    except ImportError:
-        from jax.extend import ffi
-    return ffi.include_dir()
+    """The XLA FFI headers inside the installed jaxlib."""
+    import jax.ffi
+
+    return jax.ffi.include_dir()
 
 
 def build(verbose=False):
     import os
 
     include = _ffi_include_dir()
+    key = _build_key()  # of the sources as read now, before compiling
     tmp = _OUT.with_suffix(f".tmp{os.getpid()}.so")
     # compiler override mirrors the reference's MPI4JAX_BUILD_MPICC
     # (setup.py:78); CXX is the conventional spelling here
@@ -167,7 +173,7 @@ def build(verbose=False):
             f"native bridge build failed:\n{proc.stderr[-4000:]}"
         )
     os.replace(tmp, _OUT)  # atomic: concurrent loaders never see a torn .so
-    _OUT.with_suffix(".buildinfo").write_text(_machine_key() + "\n")
+    _OUT.with_suffix(".buildinfo").write_text(key + "\n")
     return _OUT
 
 

@@ -1551,15 +1551,9 @@ def is_initialized():
 
 
 def _ffi_module():
-    """jax.ffi (jax>=0.7), or jax.extend.ffi on older lines — the
-    latter keeps the ctypes control plane and the staged data plane
-    usable from standalone harnesses on old-jax containers (same
-    fallback as native/build.py)."""
-    try:
-        import jax.ffi as ffi
-    except ImportError:
-        from jax.extend import ffi
-    return ffi
+    import jax.ffi
+
+    return jax.ffi
 
 
 def _register_ffi_targets(lib):

@@ -52,9 +52,8 @@ def _tri_gate(causal, q_offset, k_offset, tq, tk, pad_q, pad_k, block_q,
     """True when the squashed-triangle causal grid applies: square
     unsharded causal attention with no padding and equal blocks.  The
     triangle grid visits only the ~half of the blocks the causal mask
-    keeps (and masks only the diagonal ones), measured ~1.4x over the
-    rectangular grid at seq 8192 (docs/performance.md); sharded
-    (offset) and padded cases keep the general rectangular path.
+    keeps (and masks only the diagonal ones); sharded (offset) and
+    padded cases keep the general rectangular path.
 
     The flat triangle index is inverted with a float32 sqrt
     (:func:`_tri_iq_ik`) whose ±1 boundary guards absorb at most one
@@ -256,11 +255,9 @@ def flash_attention(
 ):
     """Blockwise attention, same contract as ``local_attention``.
 
-    Block sizes default to 1024 — the r5 sweep at seq 2048/b16/h16/d128
-    measured fwd+bwd 10.45 ms at 1024x1024 vs 13.30 ms at the old
-    512x512 default and worse at every other feasible pair (1024x2048
-    and 2048x* exceed VMEM; absolute times swing ±30% with co-tenancy —
-    docs/performance.md) — and are clamped down for short sequences.
+    Block sizes default to 1024 (1024x2048 and 2048x* exceed VMEM; the
+    choice among the feasible pairs is not measured on the current
+    chip) and are clamped down for short sequences.
 
     ``q``: [B, Tq, H, D]; ``k``/``v``: [B, Tk, H, D].  Sequence lengths
     are padded internally to the block sizes (padded K rows are masked
@@ -270,6 +267,11 @@ def flash_attention(
 
     ``scale`` and the offsets are trace-time constants (they are baked
     into the kernel); pass Python numbers, not traced values.
+
+    float32 operands are exact in interpret mode only: on the chip the
+    kernel's float32 dots run at the MXU's default precision, one bf16
+    pass (forward 9.4e-3 from a full-precision dense reference at
+    [1, 2048, 4, 128]; chip_smoke.py reports it on every run).
 
     Grouped-query attention (``k``/``v`` with fewer heads, ``Hq % Hkv
     == 0``) is supported by repeating kv heads before the kernel — the

@@ -372,19 +372,31 @@ def _rendezvous_recv(x, source, tag, comm, token, status):
                 f"has shape/dtype {payload.shape}/{payload.dtype}, but "
                 f"the recv template expects {shape}/{np.dtype(dtype)}"
             )
-        return payload, np.int32(src), np.int32(tg), np.asarray(stamp)
+        return (
+            payload,
+            np.full(env, src, np.int32),
+            np.full(env, tg, np.int32),
+            np.reshape(stamp, env),
+        )
 
+    # The envelope scalars travel at the payload's rank.  On TPU a host
+    # callback inside shard_map becomes one host receive per result, and
+    # jax (0.9.0, callback.receive_from_host) annotates each of them with
+    # the FIRST result's sharding: results of another rank are refused by
+    # the verifier ("sharding doesn't match tensor rank").
+    env = (1,) * len(shape)
     y, src, tg, stamp = io_callback(
         take_cb,
         (
             jax.ShapeDtypeStruct(shape, dtype),
-            jax.ShapeDtypeStruct((), np.int32),
-            jax.ShapeDtypeStruct((), np.int32),
-            jax.ShapeDtypeStruct((), np.float32),
+            jax.ShapeDtypeStruct(env, np.int32),
+            jax.ShapeDtypeStruct(env, np.int32),
+            jax.ShapeDtypeStruct(env, np.float32),
         ),
         comm.rank(), want, jnp.int32(tag), token.stamp,
         ordered=False,
     )
+    src, tg, stamp = (v.reshape(()) for v in (src, tg, stamp))
     y = promote_vma(y, comm.axes)
     token = token.with_stamp(promote_vma(stamp, comm.axes))
     if status is not None:

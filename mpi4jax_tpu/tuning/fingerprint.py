@@ -5,7 +5,7 @@ the same process count, the same host layout (hosts x locals-per-host
 — the inputs the hierarchical-plane selection is built from), and the
 same knob schema (a knob added or re-interpreted invalidates every
 older cache).  The fingerprint hashes exactly those inputs; anything
-else (link health, co-tenant load) is deliberately NOT covered — see
+else (link health, other jobs' load) is deliberately NOT covered — see
 docs/sharp-bits.md "stale tuning caches" for why a cache can go stale
 without the fingerprint changing.
 

@@ -12,7 +12,8 @@
    VPU-shuffle floor, so the kernel is shuffle-bound long before its
    HBM-traffic savings (the design goal below) can matter — and that
    bound is structural to the stencil shape, not a block-size tuning
-   issue (docs/shallow-water.md "Hardware calibration notes").  The
+   issue (the builders' verdict before PR 1; not measured on the
+   current chip).  The
    module stays in the tree as (a) the equivalence-tested record of
    why the XLA path is the default, and (b) a ready scaffold for
    hardware/toolchains where the shuffle-vs-bandwidth tradeoff flips.

@@ -1,0 +1,221 @@
+"""chip_smoke.py's check functions at a tiny size on the CPU devices.
+
+The script has no rehearsal option: what it runs on the chip at the real
+size is steered here, so a wrong path, argument or comparison is found
+without chip time.  The chip run itself is `python chip_smoke.py`
+(through the chip tool); nothing here is a device result.
+"""
+
+import json
+import pathlib
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+try:
+    import chip_smoke
+finally:
+    sys.path.pop(0)
+
+from mpi4jax_tpu.models import shallow_water as sw  # noqa: E402
+from mpi4jax_tpu.models import transformer as tfm  # noqa: E402
+
+# tests/test_bench_smoke.py's TINY, as build() keywords
+TINY = dict(batch=1, seq=64, layers=2, d_model=64, heads=4, kv_heads=4,
+            d_ff=128)
+SW_TINY = sw.SWConfig(ny=24, nx=48)
+
+
+def test_solver_check_tiny():
+    cpu = jax.devices("cpu")
+    out = chip_smoke.solver_check(SW_TINY, cpu[:1], cpu[1], steps_per_call=5)
+    assert out["schedules_max_diff"] <= chip_smoke.TOL_SAME_ARITHMETIC
+    # the same backend on another device: the reference path itself
+    assert out["reference_max_diff"] == 0.0
+
+
+def test_solver_four_device_checks_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    weak = chip_smoke.solver_weak_check(cfg, cpu, steps_per_call=5)
+    assert "48x96 on 2x2" in weak["compared"]
+    inv = chip_smoke.solver_invariance_check(cfg, cpu, steps_per_call=5)
+    assert inv["max_diff"] <= chip_smoke.TOL_SAME_ARITHMETIC
+
+
+@pytest.mark.parametrize("n", [1, 4, 8])
+def test_ops_check(n):
+    assert chip_smoke.ops_check(jax.devices()[:n])["max_diff"] == 0.0
+
+
+def test_ops_check_catches_a_wrong_value(monkeypatch):
+    import mpi4jax_tpu as m
+
+    real = m.bcast
+    monkeypatch.setattr(
+        m, "bcast", lambda x, root, **kw: real(x + 1, root, **kw)
+    )
+    with pytest.raises(AssertionError, match="bcast"):
+        chip_smoke.ops_check(jax.devices()[:4])
+
+
+def test_grad_and_selfcomm_checks():
+    assert chip_smoke.grad_check(jax.devices())["max_diff"] == 0.0
+    assert chip_smoke.selfcomm_check()["max_diff"] == 0.0
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_rendezvous_check(n):
+    assert chip_smoke.rendezvous_check(jax.devices()[:n])["max_diff"] == 0.0
+
+
+def test_train_check_tiny_falls_and_halves_a_refused_batch(monkeypatch):
+    out = chip_smoke.train_falls_check(
+        TINY, jax.devices()[:1], vocab=256, attn_impl="xla",
+        expect_kernel=False,
+    )
+    assert out["batch"] == 1 and out["mesh"] == [1, 1, 1]
+    assert out["losses"][-1] < out["losses"][0]
+
+    # a compiler that refuses batch 4 sends the check to batch 2
+    from benchmarks import transformer as tb
+
+    real_build = tb.build
+
+    def build(**kw):
+        built = real_build(**kw)
+        if kw["batch"] == 4:
+            def refuse(*a, **k):
+                raise jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: Used 22.07G of 15.75G hbm"
+                )
+            built.step = type("S", (), {"lower": staticmethod(refuse)})
+        return built
+
+    monkeypatch.setattr(tb, "build", build)
+    out = chip_smoke.train_check(
+        dict(TINY, batch=4), jax.devices()[:1], vocab=256,
+        attn_impl="xla", expect_kernel=False, steps=1,
+    )
+    assert out["batch"] == 2
+    assert out["refused"][0]["batch"] == 4
+
+
+def test_train_check_reports_a_quiet_dense_path():
+    with pytest.raises(AssertionError, match="tpu_custom_call"):
+        chip_smoke.train_check(
+            TINY, jax.devices()[:1], vocab=256, attn_impl="xla", steps=1
+        )
+
+
+def test_train_sharded_check_tiny():
+    out = chip_smoke.train_sharded_check(
+        TINY, jax.devices()[:4], vocab=256, attn_impl="xla",
+        expect_kernel=False,
+    )
+    assert out["four_chips"]["mesh"] == [1, 2, 2]
+    assert out["one_chip"]["global_tokens"] == [1, 128]
+    assert out["max_diff"] <= chip_smoke.TOL_SHARDED_LOSS
+
+
+def test_flash_check_interpret():
+    out = chip_smoke.flash_check((1, 128, 2, 64), interpret=True, blocks=64)
+    assert out["max_diff"] <= 3e-2 and out["max_rel_diff_grad"] <= 3e-2
+    # interpret mode keeps float32 dots exact; the chip does not
+    assert out["max_diff_f32_inputs"] <= 2e-5
+
+
+def test_decode_check_tiny():
+    cfg = tfm.TransformerConfig(
+        vocab=32, d_model=16, layers=2, heads=4, kv_heads=2, head_dim=8,
+        d_ff=32,
+    )
+    out = chip_smoke.decode_check(
+        cfg, jax.devices(), batch=4, prompt=5, max_len=14,
+        prefill_impl="xla",
+    )
+    assert out["max_diff"] == 0
+
+
+def test_staged_check_on_the_cpu_staging_path(monkeypatch):
+    # the launcher worker's io_callback tier, forced on the CPU; the
+    # child pins its own platform whatever this process uses
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    out = chip_smoke.staged_check(
+        "cpu", env={"MPI4JAX_TPU_FORCE_STAGED": "1"}
+    )
+    assert out["max_diff"] == 0.0
+    with pytest.raises(AssertionError, match="staged ok platform=cpu"):
+        chip_smoke.staged_check("tpu")
+
+
+def test_run_phase_line(capsys):
+    assert chip_smoke.run_phase("demo", lambda: {"max_diff": 0.0}) is True
+    assert chip_smoke.run_phase("bad", lambda: 1 / 0) is False
+    good, bad = map(json.loads, capsys.readouterr().out.splitlines())
+    assert good["phase"] == "demo" and good["ok"] is True
+    assert {"wall_s", "compile_s", "cache"} <= set(good)
+    assert bad["ok"] is False and "ZeroDivisionError" in bad["error"]
+
+
+def test_main_without_a_tpu_exits_nonzero_and_runs_no_phase(
+    monkeypatch, capsys
+):
+    ran = []
+    real = chip_smoke._run_child
+
+    def run_child(group, deadline):
+        ran.append(group)
+        return real(group, deadline)
+
+    monkeypatch.setattr(chip_smoke, "_run_child", run_child)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert chip_smoke.main([]) == 2
+    assert ran == ["probe"]
+    captured = capsys.readouterr()
+    assert captured.out == ""
+    assert "no TPU" in captured.err and "'cpu'" in captured.err
+
+
+def test_main_reports_a_missing_phase_record(monkeypatch, capsys):
+    def run_child(group, deadline):
+        if group == "probe":
+            return 0, json.dumps({"phase": "probe", "device": {
+                "platform": "tpu", "kind": "TPU v5 lite", "count": 1}})
+        if group == "rendezvous":
+            return None, ""  # killed at its deadline
+        return 0, "\n".join(
+            json.dumps({"phase": name, "ok": True})
+            for name in chip_smoke.GROUPS[1][group][1]
+        )
+
+    monkeypatch.setattr(chip_smoke, "_run_child", run_child)
+    assert chip_smoke.main([]) == 1
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert lines[-1] == {"ok": False, "failed": ["rendezvous"]}
+    assert any(
+        x.get("phase") == "rendezvous" and "deadline" in x["error"]
+        for x in lines
+    )
+
+
+def test_main_ok_line_is_last_and_exact(monkeypatch, capsys):
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}
+
+    def run_child(group, deadline):
+        if group == "probe":
+            return 0, json.dumps({"phase": "probe", "device": device})
+        return 0, "\n".join(
+            json.dumps({"phase": name, "ok": True})
+            for name in chip_smoke.GROUPS[4][group][1]
+        )
+
+    monkeypatch.setattr(chip_smoke, "_run_child", run_child)
+    assert chip_smoke.main(["--chips", "4"]) == 0
+    last = capsys.readouterr().out.splitlines()[-1]
+    assert json.loads(last) == {"ok": True, "device": device}
+    assert np.all([k in last for k in ('"ok": true', '"count": 4')])

@@ -92,7 +92,7 @@ def test_two_process_distributed_world(tmp_path):
             stderr=subprocess.STDOUT,
             text=True,
             env=env,
-            cwd=str(tmp_path),  # NOT the repo: keep sitecustomize out
+            cwd=str(tmp_path),  # the package comes from PYTHONPATH alone
             start_new_session=True,
         )
         for pid in (0, 1)

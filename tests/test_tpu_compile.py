@@ -1,0 +1,124 @@
+"""The main path's kernels and the solver step compile for a described
+TPU v5e — no chip attached, nothing executed.
+
+The TPU compiler is installed wherever libtpu is, and refuses here what
+it would refuse on the chip: a slice off the tiling, a kernel over its
+fast-memory budget, a program that does not fit device memory.  These
+guard the shapes ``chip_smoke.py`` drives at no chip time.  A compile
+that passes is not a chip run and says nothing about results or speed.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
+
+import mpi4jax_tpu as m  # noqa: E402
+from mpi4jax_tpu.models import shallow_water as sw  # noqa: E402
+from mpi4jax_tpu.ops.flash import flash_attention  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    # an entry compiled for a described chip is written to the cache but
+    # cannot be read back without the chip: the next run would warn and
+    # compile again
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# [B, T, H, D] of benchmarks/transformer.py SIZES["large"] and ["long"]
+HEAD_SHAPES = {"large": (16, 2048, 16, 128), "long": (2, 8192, 16, 128)}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("size", sorted(HEAD_SHAPES))
+def test_flash_compiles_for_v5e(v5e, size, direction):
+    x = jax.ShapeDtypeStruct(
+        HEAD_SHAPES[size], jnp.bfloat16,
+        sharding=SingleDeviceSharding(v5e.devices[0]),
+    )
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return (fwd(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    fn = fwd if direction == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("ghost", [2, 4])
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
+    """1800x3600 per chip, the donated 25-step call the bench times."""
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px],
+    )
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=1800 * py, nx=3600 * px, ghost=ghost)
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(sw.make_init(cfg, comm)),
+    )
+    compiled = sw.make_multistep(cfg, comm, 25, donate=True).lower(
+        state).compile()
+    # one chip: XLA elides every halo exchange; four: they are real
+    assert ("collective-permute" in compiled.as_text()) == (py * px > 1)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 2**30  # six fields of ~26 MB
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_op_surface_compiles_for_v5e(v5e, n):
+    """chip_smoke.py's 13-op program and its rendezvous ring (host
+    callbacks from device code) lower for the chip."""
+    import chip_smoke
+
+    devices = v5e.devices[:n]
+    sharding = NamedSharding(
+        jax.make_mesh(
+            (n,), ("p",), axis_types=(jax.sharding.AxisType.Auto,),
+            devices=devices,
+        ),
+        jax.P("p"),
+    )
+    fn, x = chip_smoke.ops_program(devices)
+    text = fn.lower(
+        jax.ShapeDtypeStruct((x.size,), x.dtype, sharding=sharding)
+    ).compile().as_text()
+    # across chips the ops are ICI collectives; on one chip XLA elides them
+    assert ("all-reduce" in text) == (n > 1)
+    ring = chip_smoke.rendezvous_program(devices).lower(
+        jax.ShapeDtypeStruct((n, 1), jnp.float32, sharding=sharding)
+    ).compile()
+    # on TPU a host callback is a pair of host transfers, not a custom call
+    assert "is_host_transfer=true" in ring.as_text()

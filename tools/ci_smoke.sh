@@ -73,9 +73,10 @@
 #                (docs/observability.md "diagnosing a slow step").
 #                ctypes only — runs on old-jax containers.
 #  11. bench   — bench.py --quick --out BENCH_quick.json: the cheap
-#                trajectory point every PR records.  The record must
-#                appear and be valid JSON even when the flagship or
-#                the native legs cannot run (explicit "skipped" keys).
+#                trajectory point.  Needs a TPU: without one bench.py
+#                exits non-zero and writes no record, and the lane
+#                fails.  Native legs that cannot run leave explicit
+#                "skipped" keys.
 #  12. elastic — tools/elastic_smoke.py twice: plain and under
 #                AddressSanitizer.  Elastic world membership
 #                (docs/failure-semantics.md "elastic membership"):
