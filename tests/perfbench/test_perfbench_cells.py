@@ -1,0 +1,280 @@
+"""A run of the benchmark end to end on the CPU's virtual devices: small
+cells added as new files, both references against the library, the
+controls and broken timed paths that have to come out not correct."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import run
+from perfbench.harness import files
+from perfbench.harness.compilemeter import CompileMeter
+
+from perfbench_fixtures import ROOT, cell_args, make_copy
+
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+TOY_CELLS = ["sw-toy-1x1", "sw-toy-2x2", "coll-toy"]
+
+
+@pytest.fixture(scope="module")
+def copy(tmp_path_factory):
+    return make_copy(tmp_path_factory.mktemp("perfbench"))
+
+
+def _run(copy, cell, **kw):
+    root, bench = copy
+    return run.run_cell(cell_args(cell, **kw), jax.devices(), root=root, bench_dir=bench)
+
+
+def _session(copy, cell, seed=11):
+    root, bench = copy
+    workload = files.load_json("workloads", cell, bench)
+    config = files.load_json("configs", workload["config"], bench)
+    driver = files.load_module("drivers", config["driver"], bench)
+    session = driver.setup(
+        run.Context(config, workload, seed, jax.devices(), bench))
+    for row in workload["rows"]:
+        session.batch(row["name"])
+    return session
+
+
+@pytest.mark.parametrize("cell", TOY_CELLS)
+def test_a_cell_added_as_new_files_runs_and_is_correct(copy, cell, capsys):
+    result = _run(copy, cell)
+    assert set(result) == LINE_KEYS
+    assert set(result["device"]) == DEVICE_KEYS
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 0
+    benchmark = files.load_benchmark(copy[0])
+    wanted = {m["name"] for m in files.metrics_of(benchmark, "end_to_end", cell)}
+    assert set(result["metrics"]) == wanted and "setup_s" in wanted
+    assert all(set(v) == {"value", "unit"} and v["value"] > 0
+               for v in result["metrics"].values())
+    # every number compared is printed beside its limit
+    out = capsys.readouterr().out
+    assert "against the limit" in out and "NOT CORRECT" not in out
+    json.dumps(result)
+
+
+@pytest.fixture
+def recorded_trace(monkeypatch):
+    """A traced run needs a chip's trace and the chip's peaks: hand the
+    reduction the recorded one."""
+    from perfbench.harness import peaks, trace
+
+    recorded = files.BENCH_DIR / "testdata" / "solver-1chip.xplane.pb"
+    monkeypatch.setattr(trace, "find_xplane", lambda log_dir: str(recorded))
+    monkeypatch.setattr(peaks, "peaks_for", lambda kind: {"hbm_gbps": 819.0})
+
+
+@pytest.mark.parametrize("cell,has,lacks", [
+    ("sw-toy-1x1", {"toy_batches", "compile_s", "sw_device_ops_per_step",
+                    "sw_hbm_roofline_share", "device_idle_share.sw"}, "coll_row_busbw"),
+    ("coll-toy", {"compile_s", "allreduce_tax_large", "allreduce_tax_small",
+                  "coll_row_busbw", "device_idle_share.coll"}, "toy_batches"),
+])
+def test_a_traced_run_reports_the_cells_per_layer_metrics(
+        copy, recorded_trace, cell, has, lacks):
+    result = _run(copy, cell, trace=1)
+    assert set(result) == LINE_KEYS | {"breakdown"}
+    assert set(result["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
+    assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
+    assert set(result["metrics"]) == has and lacks not in result["metrics"]
+    assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert all(len(v) <= 10 for v in result["breakdown"].values())
+    assert result["correct"] is True
+
+
+@pytest.mark.parametrize("cell", TOY_CELLS)
+def test_the_control_in_the_programs_place_is_not_correct(copy, cell):
+    session = _session(copy, cell)
+    sound = session.check()
+    assert all(c["value"] <= c["limit"] for c in sound), sound
+    control = session.control()
+    assert any(c["value"] > c["limit"] for c in control), control
+
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(copy, monkeypatch):
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    monkeypatch.setattr(
+        sw, "make_multistep", lambda cfg, comm, n, donate=False: lambda state: state)
+    result = _run(copy, "sw-toy-1x1")
+    assert result["correct"] is False and result["failed"] == 0
+
+
+def test_an_allreduce_that_leaves_out_the_exchange_is_not_correct(copy, monkeypatch):
+    import mpi4jax_tpu as m
+
+    monkeypatch.setattr(m, "allreduce", lambda x, op, comm=None: (x, None))
+    result = _run(copy, "coll-toy")
+    assert result["correct"] is False
+
+
+def test_a_compilation_inside_the_window_is_counted():
+    class Compiles:
+        def batch(self, row):
+            jax.block_until_ready(jax.jit(lambda x: x + len(seen))(jnp.zeros(3)))
+            seen.append(row)
+
+    seen = []
+    meter = CompileMeter().start()
+    _, samples, traced, failed, compiled = run.run_window(
+        Compiles(), ["a"], 0.05, meter)
+    assert failed == 0 and not traced
+    assert compiled >= len(samples) > 0
+
+
+def test_a_failed_batch_is_counted_and_ends_the_window():
+    class Fails:
+        def batch(self, row):
+            raise RuntimeError("no")
+
+    _, samples, _, failed, _ = run.run_window(
+        Fails(), ["a"], 5.0, CompileMeter().start())
+    assert failed == 1 and not samples
+
+
+def test_without_a_tpu_the_command_fails_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, "-m", "perfbench.run", "--workload", "sw-bench-1chip",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
+    assert done.returncode != 0
+    assert "no TPU" in done.stderr
+    assert "metrics" not in done.stdout and "correct" not in done.stdout
+
+
+def test_the_runtime_environment_keeps_what_the_caller_set(monkeypatch):
+    monkeypatch.delenv("TPU_PREMAPPED_BUFFER_SIZE", raising=False)
+    monkeypatch.delenv("TPU_LOG_DIR", raising=False)
+    run.runtime_environment()
+    assert os.environ["TPU_PREMAPPED_BUFFER_SIZE"] == str(256 << 20)
+    assert os.environ["TPU_LOG_DIR"] == "disabled"
+    monkeypatch.setenv("TPU_PREMAPPED_BUFFER_SIZE", "1024")
+    run.runtime_environment()
+    assert os.environ["TPU_PREMAPPED_BUFFER_SIZE"] == "1024"
+
+
+def test_the_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path):
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        run.enable_compile_cache(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        jax.config.update("jax_compilation_cache_dir", "/kept")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/kept")
+        run.enable_compile_cache(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "/kept"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def test_a_cell_that_benchmark_json_does_not_name_is_refused(copy):
+    with pytest.raises(files.BenchmarkFileError):
+        _run(copy, "no-such-cell")
+
+
+# -- the references by themselves -------------------------------------
+
+
+@pytest.fixture(scope="module")
+def sw_reference():
+    return files.load_module("references", "shallow-water")
+
+
+@pytest.fixture(scope="module")
+def sw_start():
+    driver = files.load_module("drivers", "shallow_water")
+    config = files.load_json("configs", "shallow-water")
+    modes = driver.mode_table(5, config["assumed"]["perturbation"])
+    fields = driver.make_fields(config["model"], 96, 32, 5e3, 5e3)(modes)
+    return config, fields
+
+
+def test_the_reference_in_bands_of_rows_is_the_reference(sw_reference, sw_start):
+    config, fields = sw_start
+    params = sw_reference.parameters(config["model"], 5e3, 5e3)
+    steps = 3
+    whole = sw_reference.run(*fields, params, steps)
+    bands = sw_reference.row_blocks(96, 3, steps)
+    assert [b[2:] for b in bands] == [(0, 32), (32, 64), (64, 96)]
+    assert bands[1][:2] == (32 - 18, 64 + 18)  # a real band, not the domain
+    for lo, hi, keep_lo, keep_hi in bands:
+        part = sw_reference.run(*(a[lo:hi] for a in fields), params, steps, "float32", lo)
+        for w, p in zip(whole, part):
+            np.testing.assert_allclose(
+                p[keep_lo - lo:keep_hi - lo], w[keep_lo:keep_hi], rtol=0, atol=1e-6)
+
+
+def test_the_reference_moves_and_bfloat16_moves_it_far(sw_reference, sw_start):
+    config, fields = sw_start
+    params = sw_reference.parameters(config["model"], 5e3, 5e3)
+    h32, _, _ = sw_reference.run(*fields, params, 11)
+    h16, _, _ = sw_reference.run(*fields, params, 11, "bfloat16")
+    assert np.isfinite(np.asarray(h32)).all()
+    assert float(jnp.max(jnp.abs(h32 - fields[0]))) > 1e-3  # eleven steps move h
+    assert float(jnp.max(jnp.abs(h16 - h32))) > 0.1  # half a metre an ulp at 100 m
+
+
+def test_seeded_modes_are_periodic_and_bounded():
+    driver = files.load_module("drivers", "shallow_water")
+    assumed = files.load_json("configs", "shallow-water")["assumed"]["perturbation"]
+    a, b = driver.mode_table(2**31 + 9, assumed), driver.mode_table(2**31 + 9, assumed)
+    np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(a, driver.mode_table(3, assumed))
+    assert a.shape == (3, 5)
+    assert (a[:, 0] % 2 == 0).all() and (a[:, :2] >= 4).all() and (a[:, :2] <= 12).all()
+    assert a[:, 4].sum() == pytest.approx(0.2)
+
+
+@pytest.fixture(scope="module")
+def coll_reference():
+    return files.load_module("references", "collectives")
+
+
+def test_collectives_reference_semantics(coll_reference):
+    x = np.arange(4 * 8, dtype=np.float32).reshape(4, 8)
+    ref = coll_reference.expected
+    np.testing.assert_array_equal(
+        ref({"op": "allreduce"}, x, (2, 2)), np.tile(x.sum(0), (4, 1)))
+    np.testing.assert_array_equal(ref({"op": "bcast", "root": 2}, x, (2, 2)), np.tile(x[2], (4, 1)))
+    np.testing.assert_array_equal(ref({"op": "sendrecv", "shift": 1}, x, (2, 2))[1], x[0])
+    assert ref({"op": "allgather"}, x, (2, 2)).shape == (4, 4, 8)
+    blocks = x.reshape(4, 4, 2)
+    out = ref({"op": "alltoall"}, blocks, (2, 2))
+    np.testing.assert_array_equal(out[3][1], blocks[1][3])
+    with pytest.raises(ValueError):
+        ref({"op": "gossip"}, x, (2, 2))
+
+
+def test_collectives_reference_halo(coll_reference):
+    # four 6x6 blocks with a ring of 1; block r is filled with r + 1
+    x = np.stack([np.full((6, 6), r + 1.0, np.float32) for r in range(4)])
+    row = {"op": "halo", "width": 1, "periodic": [False, True]}
+    out = coll_reference.expected(row, x, (2, 2))
+    top_left = out[0]
+    assert top_left[2, 0] == 2 and top_left[2, -1] == 2  # x is periodic: both from rank 1
+    assert top_left[-1, 2] == 3  # north neighbour
+    assert top_left[0, 2] == 1  # a wall keeps its ghost row
+    assert top_left[-1, -1] == 4  # the corner arrives through the second exchange
+    assert coll_reference.mismatches(out, out) == 0
+    assert coll_reference.mismatches(out, out + 1) == out.size
+
+
+def test_payloads_sum_exactly_in_float32():
+    lo, hi = files.load_json("configs", "collectives")["model"]["payload_values"]
+    assert 4 * max(abs(lo), abs(hi)) <= 2**23
