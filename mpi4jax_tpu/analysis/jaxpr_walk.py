@@ -30,8 +30,6 @@ from mpi4jax_tpu.analysis.contracts import Finding
 
 __all__ = ["walk_comm_jaxpr", "OpOccurrence"]
 
-_SCOPE_PREFIX = "mpi4jax_tpu."
-
 
 class OpOccurrence:
     """One communication op as seen in the lowered jaxpr.
@@ -196,13 +194,17 @@ def _is_tainted(var, tainted):
 def _comm_scope(eqn):
     """The innermost ``mpi4jax_tpu.<op>`` segment of the eqn's name
     stack, or None."""
+    # here, not at module scope: this package imports without jax, and
+    # an eqn to look at means jax is there
+    from mpi4jax_tpu.ops._core import SCOPE_PREFIX
+
     try:
         stack = str(eqn.source_info.name_stack)
     except Exception:
         return None
     hit = None
     for seg in stack.split("/"):
-        if seg.startswith(_SCOPE_PREFIX):
+        if seg.startswith(SCOPE_PREFIX):
             hit = seg
     return hit
 

@@ -41,6 +41,10 @@ __all__ = [
 ANY_SOURCE = -1
 ANY_TAG = -1
 
+# Every public op runs inside jax.named_scope(SCOPE_PREFIX + <op>): see
+# publishes_token.
+SCOPE_PREFIX = "mpi4jax_tpu."
+
 
 @dataclass(frozen=True)
 class PendingSendMeta:
@@ -463,15 +467,22 @@ def publishes_token(fn):
     (analysis/record.py).
 
     The ``jax.named_scope`` below is load-bearing for the analyzer too:
-    it stamps every lowered eqn's name stack with ``mpi4jax_tpu.<op>``,
-    which is how the jaxpr walker (analysis/jaxpr_walk.py) identifies
-    communication eqns inside control-flow sub-jaxprs regardless of
-    backend.
+    it stamps every lowered eqn's name stack with ``mpi4jax_tpu.<op>``
+    (``SCOPE_PREFIX`` + the op's name), which is how the jaxpr walker
+    (analysis/jaxpr_walk.py) identifies communication eqns inside
+    control-flow sub-jaxprs regardless of backend.  The same scope
+    reaches the compiled program (an instruction's ``op_name``) and the
+    device profile (an event's ``tf_op``), and is what the benchmark's
+    per-layer metrics read to give device time to the op that emitted
+    it (perfbench/harness/scopes.py).  A composite op names its phases
+    with nested scopes that do not start with the prefix
+    (parallel/halo.py: ``pack``, ``wire``, ``unpack``).
     """
     import contextlib
     import functools
 
     name = fn.__name__
+    scope = SCOPE_PREFIX + name
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
@@ -501,11 +512,11 @@ def publishes_token(fn):
         with tel_scope:
             if _arecord.active():
                 with _arecord.op_frame():
-                    with jax.named_scope(f"mpi4jax_tpu.{name}"):
+                    with jax.named_scope(scope):
                         out = fn(*args, **kwargs)
                     _arecord.record_op(name, fn, args, kwargs, out)
             else:
-                with jax.named_scope(f"mpi4jax_tpu.{name}"):
+                with jax.named_scope(scope):
                     out = fn(*args, **kwargs)
         token = None
         if isinstance(out, Token):

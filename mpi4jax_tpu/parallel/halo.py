@@ -25,6 +25,14 @@ from mpi4jax_tpu.ops.p2p import sendrecv, sendrecv_multi
 
 __all__ = ["halo_exchange_2d", "halo_exchange_2d_batch"]
 
+# The exchange's phases, as jax.named_scope segments inside the op's own
+# ``mpi4jax_tpu.<op>`` scope (ops/_core.py publishes_token): a lowered
+# instruction's op_name reads ``.../mpi4jax_tpu.halo_exchange_2d/pack/...``,
+# which is how a device profile splits the op's time into slicing slabs,
+# the permute and writing ghosts.  Metadata only; none may start with
+# SCOPE_PREFIX (the analyzer takes the innermost such segment as the op).
+PACK, WIRE, UNPACK = "pack", "wire", "unpack"
+
 
 def _axis_shift(arr_slice, template, comm, axis, disp, periodic, token):
     """One directional exchange along ``axis`` (disp = ±1).
@@ -116,55 +124,55 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
             pairs = sub.shift_perm(axis, disp, periodic=per)
             if not pairs:
                 return [None] * len(slabs)
-            outs, token = sendrecv_multi(
-                slabs, templates, source=pairs, dest=pairs, comm=sub,
-                token=token,
-            )
+            with jax.named_scope(WIRE):
+                outs, token = sendrecv_multi(
+                    slabs, templates, source=pairs, dest=pairs, comm=sub,
+                    token=token,
+                )
             return list(outs)
         if stack:
-            halo, token = _axis_shift(
-                jnp.stack(slabs), jnp.stack(templates), comm, axis, disp,
-                per, token,
-            )
-            return [None] * len(slabs) if halo is None else list(halo)
+            with jax.named_scope(PACK):
+                slab, template = jnp.stack(slabs), jnp.stack(templates)
+            with jax.named_scope(WIRE):
+                halo, token = _axis_shift(
+                    slab, template, comm, axis, disp, per, token
+                )
+            if halo is None:
+                return [None] * len(slabs)
+            with jax.named_scope(UNPACK):
+                return list(halo)
         out = []
         for slab, template in zip(slabs, templates):
-            halo, token = _axis_shift(
-                slab, template, comm, axis, disp, per, token
-            )
+            with jax.named_scope(WIRE):
+                halo, token = _axis_shift(
+                    slab, template, comm, axis, disp, per, token
+                )
             out.append(halo)
         return out
+
+    def pack(sent, received):
+        with jax.named_scope(PACK):
+            return [a[sent] for a in arrs], [a[received] for a in arrs]
 
     def write(arrs, halo, region):
         # halo[i] is None on a global no-op shift: ghosts already hold
         # the right values, skip the (identical) write
-        return [
-            a if halo[i] is None else a.at[region].set(halo[i])
-            for i, a in enumerate(arrs)
-        ]
+        with jax.named_scope(UNPACK):
+            return [
+                a if halo[i] is None else a.at[region].set(halo[i])
+                for i, a in enumerate(arrs)
+            ]
 
     # --- x direction: full-height column slabs (corners ride along) ---
-    halo = shift(
-        [a[:, -2 * w : -w] for a in arrs], [a[:, :w] for a in arrs],
-        "x", +1, per_x,
-    )
+    halo = shift(*pack(np.s_[:, -2 * w : -w], np.s_[:, :w]), "x", +1, per_x)
     arrs = write(arrs, halo, np.s_[:, :w])
-    halo = shift(
-        [a[:, w : 2 * w] for a in arrs], [a[:, -w:] for a in arrs],
-        "x", -1, per_x,
-    )
+    halo = shift(*pack(np.s_[:, w : 2 * w], np.s_[:, -w:]), "x", -1, per_x)
     arrs = write(arrs, halo, np.s_[:, -w:])
 
     # --- y direction: full-width row slabs (x halos already current) ---
-    halo = shift(
-        [a[-2 * w : -w, :] for a in arrs], [a[:w, :] for a in arrs],
-        "y", +1, per_y,
-    )
+    halo = shift(*pack(np.s_[-2 * w : -w, :], np.s_[:w, :]), "y", +1, per_y)
     arrs = write(arrs, halo, np.s_[:w, :])
-    halo = shift(
-        [a[w : 2 * w, :] for a in arrs], [a[-w:, :] for a in arrs],
-        "y", -1, per_y,
-    )
+    halo = shift(*pack(np.s_[w : 2 * w, :], np.s_[-w:, :]), "y", -1, per_y)
     arrs = write(arrs, halo, np.s_[-w:, :])
 
     return arrs, token

@@ -1,0 +1,136 @@
+"""The halo exchange names its phases: ``pack``, ``wire`` and ``unpack``
+as nested ``jax.named_scope``s inside the op's own ``mpi4jax_tpu.<op>``
+scope.  Metadata only: the traced program stays eqn for eqn what it was,
+and the contract analyzer still sees one op a call."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mpi4jax_tpu.analysis import verify_comm
+from mpi4jax_tpu.analysis.jaxpr_walk import walk_comm_jaxpr
+from mpi4jax_tpu.models import shallow_water as sw
+from mpi4jax_tpu.ops._core import SCOPE_PREFIX
+from mpi4jax_tpu.parallel import halo
+from mpi4jax_tpu.parallel.halo import halo_exchange_2d, halo_exchange_2d_batch
+
+
+def _eqns(jaxpr):
+    """Every eqn of a jaxpr and of its sub-jaxprs, in program order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _stacks(fn, *args):
+    """``{primitive name: {name stacks}}`` over the traced program."""
+    out = {}
+    for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        out.setdefault(eqn.primitive.name, set()).add(
+            str(eqn.source_info.name_stack))
+    return out
+
+
+def _program(comm, exchange):
+    def local(_):
+        arr = jnp.arange(64.0).reshape(8, 8)
+        return exchange(arr, comm)[None]
+
+    return jax.shard_map(
+        local, mesh=comm.mesh, in_specs=jax.P(("y", "x")),
+        out_specs=jax.P(("y", "x"), None, None))
+
+
+def _single(arr, comm):
+    return halo_exchange_2d(arr, comm, periodic=(False, True), width=2)[0]
+
+
+def _batch(arr, comm):
+    return halo_exchange_2d_batch(
+        [arr, 2 * arr], comm, periodic=(False, True), width=2)[0][1]
+
+
+def test_the_phase_names_do_not_look_like_ops():
+    # the analyzer takes the innermost segment with the prefix as the op
+    assert (halo.PACK, halo.WIRE, halo.UNPACK) == ("pack", "wire", "unpack")
+    assert SCOPE_PREFIX == "mpi4jax_tpu."
+    assert not any(p.startswith(SCOPE_PREFIX)
+                   for p in (halo.PACK, halo.WIRE, halo.UNPACK))
+
+
+@pytest.mark.parametrize("exchange,op,packed_by", [
+    (_single, "halo_exchange_2d", "slice"),
+    (_batch, "halo_exchange_2d_batch", "concatenate"),
+])
+def test_an_exchange_lowers_with_its_three_phases(comm2d, exchange, op, packed_by):
+    stacks = _stacks(_program(comm2d, exchange), jnp.zeros(8))
+    outer = f"{SCOPE_PREFIX}{op}"
+    # slab slices (and the batched form's stack) under pack, the permute
+    # under wire inside the sendrecv's own scope, the ghost writes under unpack
+    assert any(s.endswith(f"{outer}/pack") for s in stacks["slice"])
+    assert any(s.endswith(f"{outer}/pack") for s in stacks[packed_by])
+    assert all(s.endswith(f"{outer}/wire/{SCOPE_PREFIX}sendrecv")
+               for s in stacks["ppermute"])
+    assert stacks["ppermute"]
+    assert all(s.endswith(f"{outer}/unpack") for s in stacks["scatter"])
+    # nothing of the op lies outside its three phases
+    mine = {s for group in stacks.values() for s in group if outer in s}
+    assert all(s.split(outer + "/")[-1].split("/")[0] in ("pack", "wire", "unpack")
+               for s in mine)
+
+
+def test_verify_comm_still_counts_one_op_a_call(comm2d):
+    def local(_):
+        arr = jnp.arange(64.0).reshape(8, 8)
+        out, token = halo_exchange_2d(arr, comm2d, periodic=(False, True), width=2)
+        outs, _ = halo_exchange_2d_batch(
+            [out, 2 * out], comm2d, periodic=(False, True), width=2, token=token)
+        return outs[0][None]
+
+    prog = jax.shard_map(
+        local, mesh=comm2d.mesh, in_specs=jax.P(("y", "x")),
+        out_specs=jax.P(("y", "x"), None, None))
+    report = verify_comm(lambda: prog(jnp.zeros(8)))()
+    assert report.ok, report
+    assert [e.kind for e in report.events] == [
+        "halo_exchange_2d", "halo_exchange_2d_batch"]
+    # the walker's occurrences: the op's own eqns and its four sendrecvs,
+    # by the innermost scope with the prefix, as before the phases
+    occurrences, findings = walk_comm_jaxpr(jax.make_jaxpr(prog)(jnp.zeros(8)))
+    assert not findings
+    ops = [o.op for o in occurrences]
+    assert set(ops) == {"halo_exchange_2d", "halo_exchange_2d_batch", "sendrecv"}
+    assert ops.count("sendrecv") == 8
+    assert not any(p in ops for p in ("pack", "wire", "unpack"))
+
+
+# (mesh, ghost) -> eqns of the traced 10-step program and the sha1 of their
+# primitive names in order, taken at the commit before the phases were named
+PINNED = {
+    ((1, 1), 2): (497, "22a4156f60c2"),
+    ((2, 4), 2): (748, "adfad795e9e5"),
+    ((2, 4), 4): (633, "0b5c22b5abbd"),
+}
+
+
+@pytest.mark.parametrize("mesh_shape,ghost", sorted(PINNED))
+def test_the_solvers_program_is_eqn_for_eqn_what_it_was(mesh_shape, ghost):
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=jax.devices()[:py * px])
+    import mpi4jax_tpu as m
+
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=24 * py, nx=48 * px, ghost=ghost)
+    state = jax.eval_shape(sw.make_init(cfg, comm))
+    jaxpr = jax.make_jaxpr(sw.make_multistep(cfg, comm, 10))(state).jaxpr
+    names = [eqn.primitive.name for eqn in _eqns(jaxpr)]
+    digest = hashlib.sha1(" ".join(names).encode()).hexdigest()[:12]
+    assert (len(names), digest) == PINNED[mesh_shape, ghost]

@@ -72,10 +72,9 @@ def test_flash_compiles_for_v5e(v5e, size, direction):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("ghost", [2, 4])
-@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
-def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
-    """1800x3600 per chip, the donated 25-step call the bench times."""
+def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
+    """The donated ``steps``-step call at ``ny`` x ``nx`` cells a chip,
+    compiled for the described chips."""
     py, px = mesh_shape
     mesh = jax.make_mesh(
         mesh_shape, ("y", "x"),
@@ -83,14 +82,22 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
         devices=v5e.devices[:py * px],
     )
     comm = m.MeshComm.from_mesh(mesh)
-    cfg = sw.SWConfig(ny=1800 * py, nx=3600 * px, ghost=ghost)
+    cfg = sw.SWConfig(ny=ny * py, nx=nx * px, ghost=ghost)
     sharding = NamedSharding(mesh, jax.P("y", "x"))
     state = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         jax.eval_shape(sw.make_init(cfg, comm)),
     )
-    compiled = sw.make_multistep(cfg, comm, 25, donate=True).lower(
+    return sw.make_multistep(cfg, comm, steps, donate=True).lower(
         state).compile()
+
+
+@pytest.mark.parametrize("ghost", [2, 4])
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
+    """1800x3600 per chip, the donated 25-step call the bench times."""
+    py, px = mesh_shape
+    compiled = _compiled_multistep(v5e, mesh_shape, ghost, 1800, 3600, 25)
     # one chip: XLA elides every halo exchange; four: they are real
     assert ("collective-permute" in compiled.as_text()) == (py * px > 1)
     mem = compiled.memory_analysis()
@@ -122,3 +129,39 @@ def test_op_surface_compiles_for_v5e(v5e, n):
     ).compile()
     # on TPU a host callback is a pair of host transfers, not a custom call
     assert "is_host_transfer=true" in ring.as_text()
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_the_v5e_programs_text_says_where_an_instruction_came_from(
+        v5e, mesh_shape):
+    """The benchmark gives device time to the layer that emitted it by
+    reading the compiled program's text (perfbench/harness/scopes.py):
+    the TPU executable's has to carry the ops' scopes, the halo's three
+    phases and the source lines, in the form that reader parses."""
+    import re
+
+    from perfbench.harness import scopes
+
+    py, px = mesh_shape
+    text = _compiled_multistep(v5e, mesh_shape, 2, 180, 360, 10).as_text()
+    table = scopes.origins(text)
+    lines = dict(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", text, re.M))
+    halo = "mpi4jax_tpu.halo_exchange_2d"
+    phases = {o.scopes[1] for o in table.values() if len(o.scopes) > 1}
+    assert {"pack", "unpack"} <= phases <= {"pack", "wire", "unpack"}
+    writes = [o for o in table.values() if o.scopes == (halo, "unpack")]
+    assert {o.source.split(":")[0] for o in writes} == {
+        "mpi4jax_tpu/parallel/halo.py"}
+    # the model's own in-place updates are not the halo's ghost writes
+    updates = [o for o in table.values()
+               if o.op_name and o.op_name.endswith("/scatter-add")]
+    assert updates and all(
+        scopes.layer_of(o) == scopes.PROGRAMS
+        and o.source.startswith("mpi4jax_tpu/models/shallow_water.py:")
+        for o in updates)
+    permutes = [table[name] for name, rest in lines.items()
+                if scopes.is_collective(f"%{name} = {rest}")]
+    # one chip: every permute is elided; four: each lies under the wire
+    assert bool(permutes) == (py * px > 1)
+    assert all(o.scopes == (halo, "wire", "mpi4jax_tpu.sendrecv")
+               for o in permutes)
