@@ -24,6 +24,7 @@ def main(argv=None):
     args = p.parse_args(argv)
     benchmark = files.load_benchmark()
     cell = files.find_cell(benchmark, args.workload)
+    run.enable_bytecode_cache(files.ROOT)
     run.runtime_environment()
     run.enable_compile_cache(files.ROOT)
     devices = run.require_chips(cell["chips"])

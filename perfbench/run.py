@@ -2,11 +2,12 @@
 
     python3 -m perfbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-One process, which holds the chips: reach the TPU or fail, turn on the
-persistent compile cache, let the cell's driver build its inputs from
-the seed and warm up its programs (set-up), run the cell's batches for
-``--seconds`` (nothing may compile there), then check what the timed
-programs produce against the plain reference, and print one JSON line.
+One process, which holds the chips: turn on the bytecode cache, reach
+the TPU or fail, turn on the persistent compile cache, let the cell's
+driver build its inputs from the seed and warm up its programs (set-up),
+run the cell's batches for ``--seconds`` (nothing may compile there),
+then check what the timed programs produce against the plain reference,
+and print one JSON line.
 
 This file knows no cell, configuration or metric by name.  A cell named
 in ``BENCHMARK.json`` has ``workloads/<cell>.json``; that names its
@@ -33,6 +34,7 @@ if __package__ in (None, ""):  # run as a file: make the checkout importable
 from perfbench.harness import files, stats  # noqa: E402
 
 CACHE_DIR = ".jax_cache"  # fixed: the path is part of the cache's key
+PYCACHE_DIR = ".pycache"  # CPython's bytecode, beside the executables
 TRACE_DIR = ".perfbench_trace"
 # The TPU runtime pins a 4 GiB host buffer for transfers while it starts:
 # 7 s of jax.devices() on a v5e's host, and up to 11 s once the machine has
@@ -76,6 +78,7 @@ class View:
     probe: dict
     compile: dict
     peaks: dict
+    setup: dict
 
 
 def parse_args(argv=None):
@@ -93,6 +96,23 @@ def runtime_environment():
     two checkouts would share: they are turned off."""
     os.environ.setdefault("TPU_PREMAPPED_BUFFER_SIZE", str(PREMAPPED_BYTES))
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+def enable_bytecode_cache(root):
+    """Before jax is imported.  Set-up is judged warm: as the compiled
+    programs come from ``CACHE_DIR`` from a tree's second run on, what
+    the run imports comes from bytecode under ``PYCACHE_DIR``, a fixed
+    path in the checkout, or under the caller's ``PYTHONPYCACHEPREFIX``,
+    which stays.  The image sets ``PYTHONDONTWRITEBYTECODE=1`` and, on
+    the chip's machine, has no bytecode outside the standard library,
+    so every run would compile from source what it imports (jax: 2.8 s;
+    ``jax.experimental.pallas``: 1.2 s more; PERF.md Finding 11).  That
+    is a default of the image, not a choice of the caller, and with it
+    on the cache cannot exist: it is overridden, for this process only.
+    A tree's first run compiles and writes; the others read."""
+    sys.dont_write_bytecode = False
+    if not os.environ.get("PYTHONPYCACHEPREFIX"):
+        sys.pycache_prefix = str(pathlib.Path(root) / PYCACHE_DIR)
 
 
 def enable_compile_cache(root):
@@ -206,8 +226,8 @@ def run_cell(args, devices, root=files.ROOT, bench_dir=files.BENCH_DIR):
     meter = CompileMeter().start()
     ctx = Context(config, workload, args.seed, list(devices),
                   pathlib.Path(bench_dir))
+    t_driver = time.perf_counter()  # the driver's module loads the program
     driver = files.load_module("drivers", config["driver"], bench_dir)
-    t_driver = time.perf_counter()
     session = driver.setup(ctx)
     print(f"perfbench: set-up: {t_driver - _T0:.3f} s to reach the chips, "
           f"{time.perf_counter() - t_driver:.3f} s in the driver", flush=True)
@@ -225,13 +245,14 @@ def run_cell(args, devices, root=files.ROOT, bench_dir=files.BENCH_DIR):
                       for _ in range(rows[r].get("trace_batches", 1))]
     t_start, samples, traced, failed, compiled = run_window(
         session, cycle, args.seconds, meter, trace_dir, trace_rows)
-    setup_s = t_start - _T0
+    setup = {"setup_s": t_start - _T0, "after_chips_s": t_start - t_driver}
     device = describe_devices(devices, cell["chips"])
     print(f"perfbench: {len(samples)} batches in the window, {failed} failed, "
           f"{compiled} compilations inside it (limit 0)", flush=True)
 
     metrics, extra = {}, {}
-    end_to_end = dict(session.end_to_end(samples) if samples else {}, setup_s=setup_s)
+    end_to_end = dict(session.end_to_end(samples) if samples else {},
+                      setup_s=setup["setup_s"])
     if args.trace:
         t_read = time.perf_counter()
         reduced = tracing.read_xplane(tracing.find_xplane(trace_dir), HOST_SPANS)
@@ -243,7 +264,7 @@ def run_cell(args, devices, root=files.ROOT, bench_dir=files.BENCH_DIR):
         extra["breakdown"] = tracing.breakdown(reduced, HOST_SPANS)
         probe = session.layer_probe() if hasattr(session, "layer_probe") else {}
         view = View(session, session.facts(), samples, traced, reduced, probe,
-                    setup_compile, peaks_for(device["kind"]))
+                    setup_compile, peaks_for(device["kind"]), setup)
         for entry in files.metrics_of(benchmark, "per_layer", cell["name"]):
             reader = files.load_module("layer_metrics", entry["name"], bench_dir)
             value = reader.read(view)
@@ -275,6 +296,7 @@ def main(argv=None):
     args = parse_args(argv)
     benchmark = files.load_benchmark()
     cell = files.find_cell(benchmark, args.workload)
+    enable_bytecode_cache(files.ROOT)
     runtime_environment()
     import jax
 

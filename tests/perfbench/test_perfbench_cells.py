@@ -4,6 +4,7 @@ controls and broken timed paths that have to come out not correct."""
 
 import json
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -75,10 +76,12 @@ def recorded_trace(monkeypatch):
 
 
 @pytest.mark.parametrize("cell,has,lacks", [
-    ("sw-toy-1x1", {"toy_batches", "compile_s", "sw_device_ops_per_step",
-                    "sw_hbm_roofline_share", "device_idle_share.sw"}, "coll_row_busbw"),
-    ("coll-toy", {"compile_s", "allreduce_tax_large", "allreduce_tax_small",
-                  "coll_row_busbw", "device_idle_share.coll"}, "toy_batches"),
+    ("sw-toy-1x1", {"toy_batches", "compile_s", "setup_after_chips_s",
+                    "sw_device_ops_per_step", "sw_hbm_roofline_share",
+                    "device_idle_share.sw"}, "coll_row_busbw"),
+    ("coll-toy", {"compile_s", "setup_after_chips_s", "allreduce_tax_large",
+                  "allreduce_tax_small", "coll_row_busbw",
+                  "device_idle_share.coll"}, "toy_batches"),
 ])
 def test_a_traced_run_reports_the_cells_per_layer_metrics(
         copy, recorded_trace, cell, has, lacks):
@@ -142,8 +145,9 @@ def test_a_failed_batch_is_counted_and_ends_the_window():
     assert failed == 1 and not samples
 
 
-def test_without_a_tpu_the_command_fails_and_prints_no_result():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def test_without_a_tpu_the_command_fails_and_prints_no_result(tmp_path):
+    # jax is imported before the look for a chip: its bytecode goes to tmp_path
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPYCACHEPREFIX=str(tmp_path))
     done = subprocess.run(
         [sys.executable, "-m", "perfbench.run", "--workload", "sw-bench-1chip",
          "--seed", "1", "--seconds", "1", "--trace", "0"],
@@ -162,6 +166,92 @@ def test_the_runtime_environment_keeps_what_the_caller_set(monkeypatch):
     monkeypatch.setenv("TPU_PREMAPPED_BUFFER_SIZE", "1024")
     run.runtime_environment()
     assert os.environ["TPU_PREMAPPED_BUFFER_SIZE"] == "1024"
+
+
+IMPORTS_TWICE = """
+import importlib, importlib.machinery, json, sys
+from perfbench import run
+
+root, modules = sys.argv[1:]
+run.enable_bytecode_cache(root)
+compiled = []
+to_code = importlib.machinery.SourceFileLoader.source_to_code
+def counting(self, data, path, **kw):
+    if "throwaway" in path:
+        compiled.append(path)
+    return to_code(self, data, path, **kw)
+importlib.machinery.SourceFileLoader.source_to_code = counting
+sys.path.insert(0, modules)
+import throwaway
+first = len(compiled)
+del sys.modules["throwaway"]
+importlib.invalidate_caches()
+import throwaway
+print(json.dumps({"first": first, "second": len(compiled) - first,
+                  "cached": throwaway.__cached__, "answer": throwaway.ANSWER,
+                  "prefix": sys.pycache_prefix}))
+"""
+
+
+@pytest.mark.parametrize("callers", [False, True])
+def test_the_bytecode_cache_is_written_once_and_read_after(tmp_path, callers):
+    """The image's PYTHONDONTWRITEBYTECODE=1 is overridden; the cache is
+    at a fixed path in the checkout unless the caller named a prefix."""
+    modules = tmp_path / "modules"
+    modules.mkdir()
+    (modules / "throwaway.py").write_text("ANSWER = 6 * 7\n")
+    root, theirs = tmp_path / "checkout", tmp_path / "theirs"
+    env = dict(os.environ, PYTHONDONTWRITEBYTECODE="1", PYTHONPATH=str(ROOT))
+    env.pop("PYTHONPYCACHEPREFIX", None)
+    if callers:
+        env["PYTHONPYCACHEPREFIX"] = str(theirs)
+    done = subprocess.run(
+        [sys.executable, "-c", IMPORTS_TWICE, str(root), str(modules)],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    said = json.loads(done.stdout.splitlines()[-1])
+    assert said["answer"] == 42
+    assert (said["first"], said["second"]) == (1, 0)  # compiled once, then read
+    prefix = theirs if callers else root / run.PYCACHE_DIR
+    assert said["prefix"] == str(prefix)
+    cached = pathlib.Path(said["cached"])
+    assert cached.is_file() and prefix in cached.parents
+    assert not (modules / "__pycache__").exists()
+    assert (root / run.PYCACHE_DIR).exists() is not callers
+
+
+def test_both_commands_turn_the_bytecode_cache_on_before_jax_starts(monkeypatch):
+    from perfbench import control
+
+    calls = []
+
+    def stop(chips):
+        raise SystemExit("no chips in this test")
+
+    monkeypatch.setattr(run, "enable_bytecode_cache", lambda root: calls.append(root))
+    monkeypatch.setattr(run, "runtime_environment", lambda: calls.append("runtime"))
+    monkeypatch.setattr(run, "enable_compile_cache", lambda root: None)
+    monkeypatch.setattr(run, "require_chips", stop)
+    for main, argv in ((run.main, ["--seed", "1", "--seconds", "1"]),
+                       (control.main, ["--seeds", "1"])):
+        with pytest.raises(SystemExit):
+            main(["--workload", "sw-bench-1chip", *argv])
+    assert calls == [files.ROOT, "runtime"] * 2
+
+
+def test_set_up_after_the_chips_is_a_per_layer_metric_of_every_cell(
+        copy, recorded_trace, capsys):
+    benchmark = files.load_benchmark()
+    entry = {m["name"]: m for m in benchmark["per_layer"]}["setup_after_chips_s"]
+    assert entry == {"name": "setup_after_chips_s", "unit": "s", "better": "lower",
+                     "source": "host_clock", "layer": "entry", "moves": "setup_s"}
+    result = _run(copy, "sw-toy-2x2", trace=1)
+    after = result["metrics"]["setup_after_chips_s"]["value"]
+    out = capsys.readouterr().out
+    whole = float(out.split("perfbench: setup_s = ")[1].split()[0])
+    in_driver = float(out.split(" s in the driver")[0].rsplit(" ", 1)[1])
+    # the printed half is rounded; the schedule and the trace directory lie between
+    assert 0 < in_driver - 0.001 <= after < whole
+    assert after - in_driver < 0.5
 
 
 def test_the_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path):
