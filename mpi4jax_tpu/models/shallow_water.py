@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mpi4jax_tpu.models import sw_kernels
 from mpi4jax_tpu.ops import reductions
 from mpi4jax_tpu.ops._core import as_token
 from mpi4jax_tpu.ops.allreduce import allreduce
@@ -408,6 +409,58 @@ def _zero_wall_rows(a_r1, is_south, is_north, *, extra_north_interior=False):
     return jnp.where(kill, jnp.zeros((), a_r1.dtype), a_r1)
 
 
+def _wall_v_wide(v, is_north):
+    """v = 0 on the northern wall row (the last interior row, ghost 2)."""
+    return jnp.where(is_north, v.at[-3, :].set(0.0), v)
+
+
+def _viscosity_round(u, v, cfg, is_south, is_north):
+    """Round 2 of :func:`_step_wide` as array code: lateral friction of
+    ``u`` and ``v`` (ghosts fresh) on the interior, then ``v = 0`` on the
+    northern wall row.  The definition: what every backend but the TPU
+    runs, and what :func:`sw_kernels.viscosity_round` is tested against.
+    """
+    G = 2
+    V = _ring_view
+    nu, dx, dy = cfg.lateral_viscosity, cfg.dx, cfg.dy
+    dt = jnp.asarray(cfg.dt, u.dtype)
+
+    def friction(a):
+        gx = nu * (V(a, 1, 0, 1) - V(a, 1)) / dx
+        gy = nu * (V(a, 1, 1, 0) - V(a, 1)) / dy
+        gx = _zero_wall_rows(gx, is_south, is_north)
+        gy = _zero_wall_rows(gy, is_south, is_north)
+        return a.at[G:-G, G:-G].add(
+            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
+        )
+
+    return friction(u), _wall_v_wide(friction(v), is_north)
+
+
+def _viscosity_runs_as_kernel(comm, u):
+    """Whether round 2 of :func:`_step_wide` is the Pallas kernel: on
+    TPU devices (a Mosaic kernel runs nowhere else), in float32 (the
+    tiling's 8-row strips are float32's), on a block with at least one
+    such strip whose tiles fit VMEM.  Decided from what the step is
+    built on, where it is traced; anything else runs the array code."""
+    rows, width = u.shape
+    return (
+        {d.platform for d in comm.mesh.devices.flat} == {"tpu"}
+        and u.dtype == jnp.float32
+        and sw_kernels.tile_rows(rows, width, u.dtype, fields=2) > 0
+    )
+
+
+def _kernels_ahead(cfg, comm):
+    """Import Pallas before a step is traced, if the step will run a
+    kernel (:func:`sw_kernels.pallas` says what that saves)."""
+    if cfg.ghost == 2 and cfg.lateral_viscosity > 0:
+        ny_l, nx_l = cfg.local_interior(comm)
+        block = jax.ShapeDtypeStruct((ny_l + 4, nx_l + 4), jnp.dtype(cfg.dtype))
+        if _viscosity_runs_as_kernel(comm, block):
+            sw_kernels.pallas()
+
+
 def _step_wide(state, cfg, comm, *, first_step=False, token=None):
     """Wide-halo (ghost=2) step: communicate prognostic fields only.
 
@@ -447,10 +500,6 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
     h, u, v, dh, du, dv = state
     dt = jnp.asarray(cfg.dt, h.dtype)
     V = _ring_view
-
-    def wall_v_full(a):
-        """v = 0 on the northern wall row (last interior row)."""
-        return jnp.where(is_north, a.at[-(G + 1), :].set(0.0), a)
 
     # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
     h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
@@ -508,28 +557,18 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
         h = h.at[G:-G, G:-G].add(dt * (a * dh_new + b * dh))
         u = u.at[G:-G, G:-G].add(dt * (a * du_new + b * du))
         v = v.at[G:-G, G:-G].add(dt * (a * dv_new + b * dv))
-    v = wall_v_full(v)
+    v = _wall_v_wide(v, is_north)
 
     # --- round 2: refresh u/v ghosts for the viscosity stencils ---
     nu = cfg.lateral_viscosity
     if nu > 0:
         u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
-        gx = nu * (V(u, 1, 0, 1) - V(u, 1)) / dx
-        gy = nu * (V(u, 1, 1, 0) - V(u, 1)) / dy
-        gx = _zero_wall_rows(gx, is_south, is_north)
-        gy = _zero_wall_rows(gy, is_south, is_north)
-        u = u.at[G:-G, G:-G].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
-        gx = nu * (V(v, 1, 0, 1) - V(v, 1)) / dx
-        gy = nu * (V(v, 1, 1, 0) - V(v, 1)) / dy
-        gx = _zero_wall_rows(gx, is_south, is_north)
-        gy = _zero_wall_rows(gy, is_south, is_north)
-        v = v.at[G:-G, G:-G].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
-        v = wall_v_full(v)
+        if _viscosity_runs_as_kernel(comm, u):
+            u, v = sw_kernels.viscosity_round(
+                u, v, is_south, is_north, nu=nu, dx=dx, dy=dy, dt=cfg.dt)
+        else:
+            u, v = _viscosity_round(u, v, cfg, is_south, is_north)
 
     return SWState(h, u, v, dh_new, du_new, dv_new), token
 
@@ -715,6 +754,7 @@ def make_multistep(cfg, comm, num_steps, *, donate=False):
 
         return lax.fori_loop(0, num_steps, body, state)
 
+    _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)
     return jax.jit(
         jax.shard_map(
@@ -742,6 +782,7 @@ def make_first_step(cfg, comm):
         state, _tok = shallow_water_step(state, cfg, comm, first_step=True)
         return state
 
+    _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)
     return jax.jit(
         jax.shard_map(local_fn, mesh=comm.mesh, in_specs=(specs,), out_specs=specs)

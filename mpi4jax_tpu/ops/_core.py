@@ -572,6 +572,19 @@ def vma_of(x):
         return None
 
 
+def union_vma_struct(shape, dtype, *arrays):
+    """``ShapeDtypeStruct`` carrying the union of ``arrays``' varying
+    manual axes (required by shard_map's vma checking for pallas_call
+    outputs); plain struct on JAX builds without vma typing."""
+    import jax
+
+    vmas = [vma_of(a) for a in arrays]
+    if all(v is None for v in vmas):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    axes = frozenset().union(*(v or () for v in vmas))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=axes)
+
+
 def promote_vma(x, axes):
     """Promote ``x`` to be device-varying over all of ``axes``.
 

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpi4jax_tpu.ops._core import union_vma_struct
+
 __all__ = ["flash_attention"]
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -76,21 +78,6 @@ def _tri_gate(causal, q_offset, k_offset, tq, tk, pad_q, pad_k, block_q,
         return False
     nq = tq // block_q
     return nq * (nq + 1) // 2 <= 1 << 22
-
-
-def _union_vma_sds(shape, dtype, *arrays):
-    """ShapeDtypeStruct carrying the union of the operands' varying
-    manual axes (required by shard_map's vma checking for pallas_call
-    outputs); plain struct on JAX builds without vma typing."""
-    from mpi4jax_tpu.ops._core import vma_of
-
-    vmas = [vma_of(a) for a in arrays]
-    if all(v is None for v in vmas):
-        return jax.ShapeDtypeStruct(shape, dtype)
-    axes = set()
-    for v in vmas:
-        axes.update(v or ())
-    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(axes))
 
 
 def _kernel(
@@ -615,8 +602,8 @@ def _flash_bwd(
             pl.BlockSpec((1, block_k, d), dkv_kmap),
         ],
         out_shape=(
-            _union_vma_sds((b * h, nk * block_k, d), k.dtype, qf, kf, vf, gf),
-            _union_vma_sds((b * h, nk * block_k, d), v.dtype, qf, kf, vf, gf),
+            union_vma_struct((b * h, nk * block_k, d), k.dtype, qf, kf, vf, gf),
+            union_vma_struct((b * h, nk * block_k, d), v.dtype, qf, kf, vf, gf),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -649,7 +636,7 @@ def _flash_bwd(
         grid=dq_grid,
         in_specs=specs_for(dq_qmap, dq_kmap),
         out_specs=pl.BlockSpec((1, block_q, d), dq_qmap),
-        out_shape=_union_vma_sds(
+        out_shape=union_vma_struct(
             (b * h, nq * block_q, d), q.dtype, qf, kf, vf, gf
         ),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -771,13 +758,13 @@ def _flash_fwd_impl(
     # inside shard_map the output varies over the union of the
     # operands' varying axes; check_vma requires it spelled out
     out_shape = [
-        _union_vma_sds((b * h, nq * block_q, d), q.dtype, qf, kf, vf),
+        union_vma_struct((b * h, nq * block_q, d), q.dtype, qf, kf, vf),
     ]
     if with_lse:
         for _ in range(2):  # m and l residuals
             out_specs.append(pl.BlockSpec((1, block_q, 1), qmap))
             out_shape.append(
-                _union_vma_sds(
+                union_vma_struct(
                     (b * h, nq * block_q, 1), jnp.float32, qf, kf, vf
                 )
             )

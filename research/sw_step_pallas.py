@@ -1,24 +1,26 @@
 """Fused Pallas TPU kernels for the shallow-water wide-halo step.
 
-.. admonition:: RETIRED — research appendix, not a production path
+.. admonition:: Research appendix, not a production path
    (round 4; moved out of the package into ``research/`` in round 5 —
    its equivalence suite is the opt-in ``pytest research/``)
 
-   Nothing in the package selects these kernels; the XLA step is the
-   default everywhere and the only benched path.  On the target
-   runtime the kernel is **measurably slower** (5.8 ms vs 3.3 ms per
-   step): the stencil's shifted reads lower to Mosaic lane-roll /
-   sublane-shift shuffles that run at the measured 0.03–0.05 Tops/s
-   VPU-shuffle floor, so the kernel is shuffle-bound long before its
-   HBM-traffic savings (the design goal below) can matter — and that
-   bound is structural to the stencil shape, not a block-size tuning
-   issue (the builders' verdict before PR 1; not measured on the
-   current chip).  The
-   module stays in the tree as (a) the equivalence-tested record of
-   why the XLA path is the default, and (b) a ready scaffold for
-   hardware/toolchains where the shuffle-vs-bandwidth tradeoff flips.
-   The flash-attention kernel (ops/flash.py) is the package's
-   rent-paying Pallas path.
+   Nothing in the package selects these kernels.  The verdict that
+   retired them, "shuffle-bound, measurably slower (5.8 ms vs 3.3 ms per
+   step)", is an older chip allotment's (the builders' before PR 1, on a
+   chip that granted 80-180 GB/s) and does not hold on the v5e: there a
+   row-tiled stencil kernel with the same lane rotations and sublane
+   shifts runs at what a plain copy reaches, 614 GB/s in PR 25 (ledger
+   and ``PERF.md``) and 632 GB/s for the viscosity round that
+   ``mpi4jax_tpu/models/sw_kernels.py`` now puts on the main path
+   (``PERF.md``, PR 27).  That module is the production kernel and
+   differs from this file where the chip's compiler forced it: the
+   field is updated in place through a VMEM window one tile behind its
+   input (the 8-row neighbour blocks below make XLA copy a field that a
+   custom call both overwrites and reads through a second operand), and
+   a tile is walked in strips of 8 rows.  This file stays as the
+   equivalence-tested scaffold of round 1 (``_main_kernel``), which is
+   the next kernel on that tiling (``ROADMAP.md`` S8, D9), and its
+   viscosity kernel as the record of the scheme it started from.
 
 The XLA form of :func:`mpi4jax_tpu.models.shallow_water._step_wide`
 materialises ~10 intermediate full-size fields per step (hc, fluxes,

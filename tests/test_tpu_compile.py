@@ -92,14 +92,25 @@ def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
         state).compile()
 
 
-@pytest.mark.parametrize("ghost", [2, 4])
+@pytest.mark.parametrize("ghost", [1, 2, 4])
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
 def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     """1800x3600 per chip, the donated 25-step call the bench times."""
     py, px = mesh_shape
     compiled = _compiled_multistep(v5e, mesh_shape, ghost, 1800, 3600, 25)
+    text = compiled.as_text()
     # one chip: XLA elides every halo exchange; four: they are real
-    assert ("collective-permute" in compiled.as_text()) == (py * px > 1)
+    assert ("collective-permute" in text) == (py * px > 1)
+    # the wide-halo step's viscosity round is the Pallas kernel on TPU
+    # devices, updating u and v in place (XLA copies a field that a
+    # custom call both overwrites and reads through a second operand);
+    # the other two schedules are array code
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == (1 if ghost == 2 else 0)
+    if kernels:
+        assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (2, {})}" in kernels[0]
+        fields = kernels[0].split("custom-call(")[1].split(")")[0].split(", ")[1:]
+        assert len(fields) == 2 and "copy" not in " ".join(fields), fields
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 2**30  # six fields of ~26 MB
 
