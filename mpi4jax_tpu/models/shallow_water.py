@@ -219,10 +219,12 @@ def initial_state(cfg, comm, *, token=None):
         v0.astype(cfg.dtype), comm, periodic=per, token=token, width=G
     )
 
-    if G == 2:
+    if G == 2 and not _runs_as_kernels(cfg, comm):
         zeros = jnp.zeros((ny_l, nx_l), h0.dtype)  # wide: interior-only
     else:
-        zeros = jnp.zeros_like(h0)  # narrow + single-exchange: full-shape
+        # narrow, single-exchange, and wide where its rounds are kernels
+        # (sw_kernels.tendency_round says why): full-shape
+        zeros = jnp.zeros_like(h0)
     return SWState(h0, u0, v0, zeros, zeros, zeros), token
 
 
@@ -414,97 +416,22 @@ def _wall_v_wide(v, is_north):
     return jnp.where(is_north, v.at[-3, :].set(0.0), v)
 
 
-def _viscosity_round(u, v, cfg, is_south, is_north):
-    """Round 2 of :func:`_step_wide` as array code: lateral friction of
-    ``u`` and ``v`` (ghosts fresh) on the interior, then ``v = 0`` on the
-    northern wall row.  The definition: what every backend but the TPU
-    runs, and what :func:`sw_kernels.viscosity_round` is tested against.
+def _tendency_round(h, u, v, dh, du, dv, cfg, comm, is_south, is_north,
+                    first_step):
+    """Round 1 of :func:`_step_wide` as array code: the tendencies of
+    ``h``, ``u`` and ``v`` (ghosts fresh) from fluxes, potential
+    vorticity and kinetic energy recomputed one ring into the ghost
+    region, the Adams-Bashforth update of the interior (forward Euler on
+    the ``first_step``, which reads no old tendency), then ``v = 0`` on
+    the northern wall row.  Tendencies are interior-shaped here.  The
+    definition: what every backend but the TPU runs, and what
+    :func:`sw_kernels.tendency_round` is tested against.
     """
     G = 2
     V = _ring_view
-    nu, dx, dy = cfg.lateral_viscosity, cfg.dx, cfg.dy
-    dt = jnp.asarray(cfg.dt, u.dtype)
-
-    def friction(a):
-        gx = nu * (V(a, 1, 0, 1) - V(a, 1)) / dx
-        gy = nu * (V(a, 1, 1, 0) - V(a, 1)) / dy
-        gx = _zero_wall_rows(gx, is_south, is_north)
-        gy = _zero_wall_rows(gy, is_south, is_north)
-        return a.at[G:-G, G:-G].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
-
-    return friction(u), _wall_v_wide(friction(v), is_north)
-
-
-def _viscosity_runs_as_kernel(comm, u):
-    """Whether round 2 of :func:`_step_wide` is the Pallas kernel: on
-    TPU devices (a Mosaic kernel runs nowhere else), in float32 (the
-    tiling's 8-row strips are float32's), on a block with at least one
-    such strip whose tiles fit VMEM.  Decided from what the step is
-    built on, where it is traced; anything else runs the array code."""
-    rows, width = u.shape
-    return (
-        {d.platform for d in comm.mesh.devices.flat} == {"tpu"}
-        and u.dtype == jnp.float32
-        and sw_kernels.tile_rows(rows, width, u.dtype, fields=2) > 0
-    )
-
-
-def _kernels_ahead(cfg, comm):
-    """Import Pallas before a step is traced, if the step will run a
-    kernel (:func:`sw_kernels.pallas` says what that saves)."""
-    if cfg.ghost == 2 and cfg.lateral_viscosity > 0:
-        ny_l, nx_l = cfg.local_interior(comm)
-        block = jax.ShapeDtypeStruct((ny_l + 4, nx_l + 4), jnp.dtype(cfg.dtype))
-        if _viscosity_runs_as_kernel(comm, block):
-            sw_kernels.pallas()
-
-
-def _step_wide(state, cfg, comm, *, first_step=False, token=None):
-    """Wide-halo (ghost=2) step: communicate prognostic fields only.
-
-    The narrow schedule exchanges every intermediate field because a
-    1-cell ghost ring can't support compound stencils (~12 exchanges per
-    step — the reference's structure, shallow_water.py:277-412). With a
-    2-cell ring, the fluxes, potential vorticity, kinetic energy, and
-    viscosity gradients are all *recomputed locally* one ring into the
-    ghost region from the exchanged ``h``/``u``/``v``, so a step is:
-
-        round 1: exchange h, u, v   → all tendencies, AB2 update
-        round 2: exchange u, v      → viscosity, wall condition
-
-    5 thin exchanges instead of 12 (and 2 ordering rounds instead of
-    12, which is what matters at scale: SURVEY §3.4 — per-exchange
-    dispatch/launch latency dominates the reference's scaling).
-    Numerically identical to the narrow path up to FMA/fusion roundoff
-    (asserted at ~ulp tolerance by
-    tests/test_shallow_water.py::test_wide_equals_narrow): the ~1%
-    redundant ghost-ring flops ride along with already-loaded data.
-
-    Tendencies are stored interior-shaped (the ghost region of a
-    tendency is never read).
-    """
-    G = 2
-    if not cfg.periodic_x:
-        raise NotImplementedError(
-            "wide-halo schedule currently requires periodic_x=True "
-            "(x-boundary clamps are not implemented); use ghost=1"
-        )
-    token = as_token(token)
-    per = (False, True)
-    ny_l, nx_l = cfg.local_interior(comm)
-    is_north, is_south = _wall_masks(comm)
+    ny_l = h.shape[0] - 2 * G
     dx, dy, g = cfg.dx, cfg.dy, cfg.gravity
-
-    h, u, v, dh, du, dv = state
     dt = jnp.asarray(cfg.dt, h.dtype)
-    V = _ring_view
-
-    # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
-    h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
-    u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
-    v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
 
     # cell-centred height: narrow builds it by edge-padding the interior
     # and exchanging; here it is h with wall ghost rows clamped to the
@@ -558,19 +485,141 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
         u = u.at[G:-G, G:-G].add(dt * (a * du_new + b * du))
         v = v.at[G:-G, G:-G].add(dt * (a * dv_new + b * dv))
     v = _wall_v_wide(v, is_north)
+    return h, u, v, dh_new, du_new, dv_new
+
+
+def _viscosity_round(u, v, cfg, is_south, is_north):
+    """Round 2 of :func:`_step_wide` as array code: lateral friction of
+    ``u`` and ``v`` (ghosts fresh) on the interior, then ``v = 0`` on the
+    northern wall row.  The definition: what every backend but the TPU
+    runs, and what :func:`sw_kernels.viscosity_round` is tested against.
+    """
+    G = 2
+    V = _ring_view
+    nu, dx, dy = cfg.lateral_viscosity, cfg.dx, cfg.dy
+    dt = jnp.asarray(cfg.dt, u.dtype)
+
+    def friction(a):
+        gx = nu * (V(a, 1, 0, 1) - V(a, 1)) / dx
+        gy = nu * (V(a, 1, 1, 0) - V(a, 1)) / dy
+        gx = _zero_wall_rows(gx, is_south, is_north)
+        gy = _zero_wall_rows(gy, is_south, is_north)
+        return a.at[G:-G, G:-G].add(
+            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
+        )
+
+    return friction(u), _wall_v_wide(friction(v), is_north)
+
+
+def _runs_as_kernels(cfg, comm):
+    """Whether the two rounds of :func:`_step_wide` are the Pallas
+    kernels of :mod:`sw_kernels`: on TPU devices (a Mosaic kernel runs
+    nowhere else), in float32 (the tiling's 8-row strips are float32's),
+    on a block (one device's padded field) with at least one such strip
+    whose tiles fit VMEM beside those of round 1's other five fields.
+    Decided from what a step is built on, by who builds it, traces it or
+    makes the state it will carry; anything else runs the array code,
+    both rounds of it."""
+    if cfg.ghost != 2:
+        return False
+    ny_l, nx_l = cfg.local_interior(comm)
+    dtype = jnp.dtype(cfg.dtype)
+    return (
+        {d.platform for d in comm.mesh.devices.flat} == {"tpu"}
+        and dtype == jnp.float32
+        and sw_kernels.tile_rows(ny_l + 4, nx_l + 4, dtype, fields=6) > 0
+    )
+
+
+def _kernels_ahead(cfg, comm):
+    """Import Pallas before a step is traced, if the step will run
+    kernels (:func:`sw_kernels.pallas` says what that saves)."""
+    if _runs_as_kernels(cfg, comm):
+        sw_kernels.pallas()
+
+
+def _step_wide(state, cfg, comm, *, first_step=False, token=None):
+    """Wide-halo (ghost=2) step: communicate prognostic fields only.
+
+    The narrow schedule exchanges every intermediate field because a
+    1-cell ghost ring can't support compound stencils (~12 exchanges per
+    step — the reference's structure, shallow_water.py:277-412). With a
+    2-cell ring, the fluxes, potential vorticity, kinetic energy, and
+    viscosity gradients are all *recomputed locally* one ring into the
+    ghost region from the exchanged ``h``/``u``/``v``, so a step is:
+
+        round 1: exchange h, u, v   → all tendencies, AB2 update
+        round 2: exchange u, v      → viscosity, wall condition
+
+    5 thin exchanges instead of 12 (and 2 ordering rounds instead of
+    12, which is what matters at scale: SURVEY §3.4 — per-exchange
+    dispatch/launch latency dominates the reference's scaling).
+    Numerically identical to the narrow path up to FMA/fusion roundoff
+    (asserted at ~ulp tolerance by
+    tests/test_shallow_water.py::test_wide_equals_narrow): the ~1%
+    redundant ghost-ring flops ride along with already-loaded data.
+
+    Tendencies are stored interior-shaped (the ghost region of a
+    tendency is never read), except where the two rounds run as the
+    kernels of :mod:`sw_kernels` (:func:`_runs_as_kernels`): there
+    the state carries them at the fields' padded shape, zero on the
+    ghost ring.  A first step reads none, and takes either.
+    """
+    G = 2
+    if not cfg.periodic_x:
+        raise NotImplementedError(
+            "wide-halo schedule currently requires periodic_x=True "
+            "(x-boundary clamps are not implemented); use ghost=1"
+        )
+    token = as_token(token)
+    per = (False, True)
+    ny_l, _nx_l = cfg.local_interior(comm)
+    is_north, is_south = _wall_masks(comm)
+    dx, dy = cfg.dx, cfg.dy
+
+    h, u, v, dh, du, dv = state
+    kernels = _runs_as_kernels(cfg, comm)
+
+    # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
+    h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
+    u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
+    v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
+
+    if kernels:
+        if first_step:
+            # forward Euler is AB2 with (1, 0) on zero tendencies, and the
+            # caller's, which this step does not read, may be of either shape
+            a, b = 1.0, 0.0
+            dh = du = dv = jnp.zeros_like(h)
+        else:
+            a, b = cfg.ab_a, cfg.ab_b
+            if dh.shape != h.shape:
+                raise ValueError(
+                    f"tendencies of shape {dh.shape} beside fields of shape "
+                    f"{h.shape}: where the step runs as kernels its state "
+                    "carries them padded, as make_init and make_first_step "
+                    "return them")
+        iy, _ix = _device_coords(comm)
+        h, u, v, dh, du, dv = sw_kernels.tendency_round(
+            h, u, v, dh, du, dv, is_south, is_north, iy * ny_l, a, b,
+            dx=dx, dy=dy, dt=cfg.dt, gravity=cfg.gravity,
+            coriolis_f=cfg.coriolis_f, coriolis_beta=cfg.coriolis_beta)
+    else:
+        h, u, v, dh, du, dv = _tendency_round(
+            h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)
 
     # --- round 2: refresh u/v ghosts for the viscosity stencils ---
     nu = cfg.lateral_viscosity
     if nu > 0:
         u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
-        if _viscosity_runs_as_kernel(comm, u):
+        if kernels:
             u, v = sw_kernels.viscosity_round(
                 u, v, is_south, is_north, nu=nu, dx=dx, dy=dy, dt=cfg.dt)
         else:
             u, v = _viscosity_round(u, v, cfg, is_south, is_north)
 
-    return SWState(h, u, v, dh_new, du_new, dv_new), token
+    return SWState(h, u, v, dh, du, dv), token
 
 
 def _step_wide4(state, cfg, comm, *, first_step=False, token=None):

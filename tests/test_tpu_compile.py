@@ -9,6 +9,7 @@ that passes is not a chip run and says nothing about results or speed.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -74,7 +75,7 @@ def test_flash_compiles_for_v5e(v5e, size, direction):
 
 def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
     """The donated ``steps``-step call at ``ny`` x ``nx`` cells a chip,
-    compiled for the described chips."""
+    compiled for the described chips; ``steps`` 0: the first step."""
     py, px = mesh_shape
     mesh = jax.make_mesh(
         mesh_shape, ("y", "x"),
@@ -88,8 +89,37 @@ def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         jax.eval_shape(sw.make_init(cfg, comm)),
     )
+    if steps == 0:
+        return sw.make_first_step(cfg, comm).lower(state).compile()
     return sw.make_multistep(cfg, comm, steps, donate=True).lower(
         state).compile()
+
+
+def _kernels(text):
+    """The Pallas calls of a compiled program's text: name -> (line,
+    the operands that are fields, the kernel's own text)."""
+    found = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            name = line.split("=")[0].strip().lstrip("%").split(".")[0]
+            operands = line.split("custom-call(")[1].split(")")[0]
+            operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
+            layouts = line.split("operand_layout_constraints={")[1].split("}}")[0]
+            fields = [op for op, layout in zip(operands, layouts.split("}, "))
+                      if "," in layout.split("[")[1].split("]")[0]]
+            body = line.split('"custom_call_config":{"body":"')[1].split('"')[0]
+            found[name] = line, fields, body
+    return found
+
+
+def _copied(text, fields):
+    """Those of a call's field operands that are copies, but for a
+    field XLA kept in its faster memory (``S(1)``: a field of a few
+    tens of MB) and moves to where the call takes it."""
+    moved = {f"%{m[1]}" for m in re.finditer(
+        r"%(copy-done[\w.]*) = .*copy-done\(%(copy-start[\w.]*)\)", text)
+        if re.search(rf"%{re.escape(m[2])} = .*S\(1\).*copy-start\(", text)}
+    return [op for op in fields if "copy" in op and op not in moved]
 
 
 @pytest.mark.parametrize("ghost", [1, 2, 4])
@@ -101,18 +131,37 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     text = compiled.as_text()
     # one chip: XLA elides every halo exchange; four: they are real
     assert ("collective-permute" in text) == (py * px > 1)
-    # the wide-halo step's viscosity round is the Pallas kernel on TPU
-    # devices, updating u and v in place (XLA copies a field that a
+    # the wide-halo step's two rounds are the Pallas kernels on TPU
+    # devices, updating their fields in place (XLA copies a field that a
     # custom call both overwrites and reads through a second operand);
     # the other two schedules are array code
-    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(kernels) == (1 if ghost == 2 else 0)
-    if kernels:
-        assert "output_to_operand_aliasing={{0}: (1, {}), {1}: (2, {})}" in kernels[0]
-        fields = kernels[0].split("custom-call(")[1].split(")")[0].split(", ")[1:]
-        assert len(fields) == 2 and "copy" not in " ".join(fields), fields
+    kernels = _kernels(text)
+    assert sorted(kernels) == (
+        ["tendency_round", "viscosity_round"] if ghost == 2 else [])
+    for name, n_scalars, n_fields in (
+            ("tendency_round", 2, 6), ("viscosity_round", 1, 2))[:len(kernels)]:
+        line, fields, _ = kernels[name]
+        aliasing = ", ".join(
+            f"{{{k}}}: ({n_scalars + k}, {{}})" for k in range(n_fields))
+        assert f"output_to_operand_aliasing={{{aliasing}}}" in line
+        assert len(fields) == n_fields and not _copied(text, fields), fields
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 2**30  # six fields of ~26 MB
+    # not one field's bytes beside the state: every intermediate of the
+    # two rounds stays in the kernels
+    assert (mem.temp_size_in_bytes < 1800 * 3600 * 4) == (ghost == 2)
+
+
+def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e):
+    """Forward Euler is the AB2 kernel with other scalars, so a process
+    traces and lowers round 1 once for its two programs."""
+    texts = [_compiled_multistep(v5e, (1, 1), 2, 1800, 3600, steps).as_text()
+             for steps in (0, 10)]
+    first, rest = (_kernels(text) for text in texts)
+    assert sorted(first) == sorted(rest) == ["tendency_round", "viscosity_round"]
+    for name in first:
+        assert first[name][2] == rest[name][2], name
+        assert not _copied(texts[0], first[name][1]), first[name][1]
 
 
 @pytest.mark.parametrize("n", [1, 4])
@@ -149,8 +198,6 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
     reading the compiled program's text (perfbench/harness/scopes.py):
     the TPU executable's has to carry the ops' scopes, the halo's three
     phases and the source lines, in the form that reader parses."""
-    import re
-
     from perfbench.harness import scopes
 
     py, px = mesh_shape
@@ -163,13 +210,14 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
     writes = [o for o in table.values() if o.scopes == (halo, "unpack")]
     assert {o.source.split(":")[0] for o in writes} == {
         "mpi4jax_tpu/parallel/halo.py"}
-    # the model's own in-place updates are not the halo's ghost writes
-    updates = [o for o in table.values()
-               if o.op_name and o.op_name.endswith("/scatter-add")]
-    assert updates and all(
-        scopes.layer_of(o) == scopes.PROGRAMS
-        and o.source.startswith("mpi4jax_tpu/models/shallow_water.py:")
-        for o in updates)
+    # what the model's two rounds leave beside their kernels (the
+    # scalars they are handed, their results taken apart; the calls' own
+    # line the reader does not find yet, PERF.md section 7) is the
+    # programs', not the halo's
+    model = [o for o in table.values()
+             if o.source and o.source.startswith("mpi4jax_tpu/models/")]
+    assert model and all(
+        scopes.layer_of(o) == scopes.PROGRAMS and not o.scopes for o in model)
     permutes = [table[name] for name, rest in lines.items()
                 if scopes.is_collective(f"%{name} = {rest}")]
     # one chip: every permute is elided; four: each lies under the wire
