@@ -222,8 +222,8 @@ def initial_state(cfg, comm, *, token=None):
     if G == 2 and not _runs_as_kernels(cfg, comm):
         zeros = jnp.zeros((ny_l, nx_l), h0.dtype)  # wide: interior-only
     else:
-        # narrow, single-exchange, and wide where its rounds are kernels
-        # (sw_kernels.tendency_round says why): full-shape
+        # narrow, single-exchange, and wide where the step is a kernel
+        # (sw_kernels.wide_step says why): full-shape
         zeros = jnp.zeros_like(h0)
     return SWState(h0, u0, v0, zeros, zeros, zeros), token
 
@@ -425,7 +425,7 @@ def _tendency_round(h, u, v, dh, du, dv, cfg, comm, is_south, is_north,
     the ``first_step``, which reads no old tendency), then ``v = 0`` on
     the northern wall row.  Tendencies are interior-shaped here.  The
     definition: what every backend but the TPU runs, and what
-    :func:`sw_kernels.tendency_round` is tested against.
+    :func:`sw_kernels.wide_step` is tested against.
     """
     G = 2
     V = _ring_view
@@ -492,7 +492,7 @@ def _viscosity_round(u, v, cfg, is_south, is_north):
     """Round 2 of :func:`_step_wide` as array code: lateral friction of
     ``u`` and ``v`` (ghosts fresh) on the interior, then ``v = 0`` on the
     northern wall row.  The definition: what every backend but the TPU
-    runs, and what :func:`sw_kernels.viscosity_round` is tested against.
+    runs, and what :func:`sw_kernels.wide_step` is tested against.
     """
     G = 2
     V = _ring_view
@@ -512,14 +512,16 @@ def _viscosity_round(u, v, cfg, is_south, is_north):
 
 
 def _runs_as_kernels(cfg, comm):
-    """Whether the two rounds of :func:`_step_wide` are the Pallas
-    kernels of :mod:`sw_kernels`: on TPU devices (a Mosaic kernel runs
+    """Whether :func:`_step_wide` after its first exchange is the Pallas
+    kernel of :mod:`sw_kernels`: on TPU devices (a Mosaic kernel runs
     nowhere else), in float32 (the tiling's 8-row strips are float32's),
     on a block (one device's padded field) with at least one such strip
-    whose tiles fit VMEM beside those of round 1's other five fields.
+    whose tiles fit VMEM beside those of the step's other five arrays.
+    (Such a block has four interior rows or more: the kernel counts on
+    two, so that a neighbour's edge row is not its wall row as well.)
     Decided from what a step is built on, by who builds it, traces it or
     makes the state it will carry; anything else runs the array code,
-    both rounds of it."""
+    five exchanges and both rounds of it."""
     if cfg.ghost != 2:
         return False
     ny_l, nx_l = cfg.local_interior(comm)
@@ -532,8 +534,8 @@ def _runs_as_kernels(cfg, comm):
 
 
 def _kernels_ahead(cfg, comm):
-    """Import Pallas before a step is traced, if the step will run
-    kernels (:func:`sw_kernels.pallas` says what that saves)."""
+    """Import Pallas before a step is traced, if the step will run the
+    kernel (:func:`sw_kernels.pallas` says what that saves)."""
     if _runs_as_kernels(cfg, comm):
         sw_kernels.pallas()
 
@@ -558,12 +560,23 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
     (asserted at ~ulp tolerance by
     tests/test_shallow_water.py::test_wide_equals_narrow): the ~1%
     redundant ghost-ring flops ride along with already-loaded data.
+    Tendencies are stored interior-shaped here (the ghost region of a
+    tendency is never read).
 
-    Tendencies are stored interior-shaped (the ghost region of a
-    tendency is never read), except where the two rounds run as the
-    kernels of :mod:`sw_kernels` (:func:`_runs_as_kernels`): there
-    the state carries them at the fields' padded shape, zero on the
-    ghost ring.  A first step reads none, and takes either.
+    Where the step runs as the kernel of :mod:`sw_kernels`
+    (:func:`_runs_as_kernels`) it is shorter still, on every mesh:
+
+        exchange h, u, v   → one kernel: round 1 on the interior and on
+                             ring 1 of u and v, then round 2
+
+    3 exchanges and 12 passes over a field: round 1 reads one ring round
+    a cell and the exchange brings two, so the kernel computes on ring 1
+    the very ``u``, ``v`` a second exchange would bring, and the two
+    rounds are one walk over the rows.  There the state carries its
+    tendencies at the fields' padded shape, with ring 1 of ``du`` and
+    ``dv`` holding the neighbours' (round 1 on ring 1 steps from them):
+    hand a later step the tendencies a step returned.  A first step
+    reads none, and takes either shape.
     """
     G = 2
     if not cfg.periodic_x:
@@ -575,17 +588,16 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
     per = (False, True)
     ny_l, _nx_l = cfg.local_interior(comm)
     is_north, is_south = _wall_masks(comm)
-    dx, dy = cfg.dx, cfg.dy
+    nu = cfg.lateral_viscosity
 
     h, u, v, dh, du, dv = state
-    kernels = _runs_as_kernels(cfg, comm)
 
     # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
     h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
     u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
     v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
 
-    if kernels:
+    if _runs_as_kernels(cfg, comm):
         if first_step:
             # forward Euler is AB2 with (1, 0) on zero tendencies, and the
             # caller's, which this step does not read, may be of either shape
@@ -596,28 +608,25 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
             if dh.shape != h.shape:
                 raise ValueError(
                     f"tendencies of shape {dh.shape} beside fields of shape "
-                    f"{h.shape}: where the step runs as kernels its state "
+                    f"{h.shape}: where the step runs as a kernel its state "
                     "carries them padded, as make_init and make_first_step "
                     "return them")
         iy, _ix = _device_coords(comm)
-        h, u, v, dh, du, dv = sw_kernels.tendency_round(
+        state = sw_kernels.wide_step(
             h, u, v, dh, du, dv, is_south, is_north, iy * ny_l, a, b,
-            dx=dx, dy=dy, dt=cfg.dt, gravity=cfg.gravity,
-            coriolis_f=cfg.coriolis_f, coriolis_beta=cfg.coriolis_beta)
-    else:
-        h, u, v, dh, du, dv = _tendency_round(
-            h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)
+            nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
+            gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
+            coriolis_beta=cfg.coriolis_beta)
+        return SWState(*state), token
+
+    h, u, v, dh, du, dv = _tendency_round(
+        h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)
 
     # --- round 2: refresh u/v ghosts for the viscosity stencils ---
-    nu = cfg.lateral_viscosity
     if nu > 0:
         u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
-        if kernels:
-            u, v = sw_kernels.viscosity_round(
-                u, v, is_south, is_north, nu=nu, dx=dx, dy=dy, dt=cfg.dt)
-        else:
-            u, v = _viscosity_round(u, v, cfg, is_south, is_north)
+        u, v = _viscosity_round(u, v, cfg, is_south, is_north)
 
     return SWState(h, u, v, dh, du, dv), token
 

@@ -1,10 +1,11 @@
-"""The solver's Pallas kernels against the array code they replace.
+"""The solver's Pallas kernel against the array code it replaces.
 
-Everything here runs on the CPU backend, a kernel in Pallas's
-interpret mode: it shows that each kernel computes what the array code
-of the same round computes, at block shapes that exercise the tiling's
-edges, and that the step picks the kernels only where they can run.  That
-the kernel compiles for the chip is ``tests/test_tpu_compile.py``'s to
+Everything here runs on the CPU backend, the kernel in Pallas's
+interpret mode: it shows that the kernel computes what the array code's
+two rounds compute with an exchange between them, at block shapes that
+exercise the tiling's edges and on meshes where ring 1 is a neighbour's,
+and that the step picks the kernel only where it can run.  That the
+kernel compiles for the chip is ``tests/test_tpu_compile.py``'s to
 show; how fast it is, only a chip run's (``PERF.md``).
 """
 
@@ -13,7 +14,7 @@ import os
 import subprocess
 import sys
 import types
-from dataclasses import replace
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
@@ -21,20 +22,20 @@ import numpy as np
 import pytest
 
 import mpi4jax_tpu as m
+from mpi4jax_tpu.analysis import verify_comm
 from mpi4jax_tpu.models import shallow_water as sw
 from mpi4jax_tpu.models import sw_kernels
 
 G = 2
-# friction strong enough to see: dt * nu / dx**2 is 0.02, not the 1e-6
-# of the published coefficients, so an error in a stencil or a mask is
-# five orders of magnitude over float32's roundoff
-STRONG = dict(dx=5e3, dy=4e3, coriolis_f=1.0, ghost=G)
 
 # rows x width of one device's padded block, and the VMEM budget the
 # tiling is given (None: its own): 52 rows leave a last tile of 4 under
 # tiles of 48; 184 x 364 is the demo grid's block, one tile; 21 rows are
-# no multiple of 8; the small budgets cut 100 rows into tiles of 8, 24
+# no multiple of 8; the small budgets cut 100 rows into tiles of 8, 24;
+# 256 columns fill their vector registers, so that a rotation's wrap
+# lands in the ghost columns and not past them
 SHAPES = {
+    "aligned-36x256": (36, 256, None),
     "ragged-52x100": (52, 100, None),
     "demo-184x364": (184, 364, None),
     "odd-21x40": (21, 40, None),
@@ -50,125 +51,170 @@ UNIT = dict(dx=1.0, dy=0.8, gravity=1.0, depth=1.0, coriolis_f=1.0,
             coriolis_beta=4e-3, ghost=G)
 
 
-def _budget(monkeypatch, shape, fields):
-    """``SHAPES[shape]`` with its VMEM budget in place, scaled to a call
-    of ``fields`` fields so that the tiles are the name's."""
+def _budget(monkeypatch, shape):
+    """``SHAPES[shape]`` with its VMEM budget in place, scaled to the
+    call's six arrays so that the tiles are the name's."""
     rows, width, budget = SHAPES[shape]
     if budget is not None:
-        monkeypatch.setattr(
-            sw_kernels, "_VMEM_BLOCK_BUDGET", budget * fields // 2)
-        tile = sw_kernels.tile_rows(rows, width, jnp.float32, fields)
+        monkeypatch.setattr(sw_kernels, "_VMEM_BLOCK_BUDGET", budget * 3)
+        tile = sw_kernels.tile_rows(rows, width, jnp.float32, fields=6)
         assert tile == int(shape.split("-")[2]) and rows > 3 * tile
     return rows, width
 
 
-def _ring(shape):
-    ring = np.ones(shape, bool)
-    ring[G:-G, G:-G] = False
-    return ring
+def _ring(shape, ring):
+    """The cells of a padded block's ghost ring ``ring`` (2: outermost)."""
+    inside = np.zeros(shape, bool)
+    inside[G - ring:shape[0] - G + ring, G - ring:shape[1] - G + ring] = True
+    inside[G - ring + 1:shape[0] - G + ring - 1,
+           G - ring + 1:shape[1] - G + ring - 1] = False
+    return inside
 
 
-def _fields(rows, width, seed=0):
-    ku, kv = jax.random.split(jax.random.PRNGKey(seed))
-    return (jax.random.normal(ku, (rows, width), jnp.float32),
-            jax.random.normal(kv, (rows, width), jnp.float32))
+@dataclass(frozen=True)
+class _Viscous(sw.SWConfig):
+    """A configuration whose friction is set apart from its rotation:
+    round 1 in ``UNIT`` with a friction strong enough to see (``dt * nu
+    / dx**2`` is 0.02, not the 1e-4 of the unit rotation's own), so that
+    an error in a stencil or a mask of either round is four orders of
+    magnitude over float32's roundoff."""
 
+    nu: float = 0.0
 
-@pytest.mark.parametrize("walls", sorted(WALLS))
-@pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_viscosity_kernel_matches_the_array_code(shape, walls, monkeypatch):
-    rows, width = _budget(monkeypatch, shape, fields=2)
-    cfg = sw.SWConfig(ny=rows - 2 * G, nx=width - 2 * G, **STRONG)
-    u, v = _fields(rows, width)
-    south, north = (jnp.bool_(w) for w in WALLS[walls])
-    want = sw._viscosity_round(u, v, cfg, south, north)
-    got = sw_kernels.viscosity_round(
-        u, v, south, north, nu=cfg.lateral_viscosity, dx=cfg.dx, dy=cfg.dy,
-        dt=cfg.dt, interpret=True)
-    for name, before, a, b in zip("uv", (u, v), got, want):
-        a, b, before = (np.asarray(x) for x in (a, b, before))
-        # the round did something, and the kernel did the same
-        assert np.abs(b - before)[G:-G, G:-G].max() > 0.1, name
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6, err_msg=name)
-        # the ghost ring goes through untouched, bit for bit (the wall
-        # condition zeroes its row from end to end, as the array code's)
-        ring = _ring(a.shape)
-        np.testing.assert_array_equal(a[ring], b[ring], err_msg=name)
-        ring[-(G + 1)] = False
-        np.testing.assert_array_equal(a[ring], before[ring], err_msg=name)
-    # v = 0 on the northern wall row, and only under a northern wall
-    wall_row = np.asarray(got[1])[-(G + 1)]
-    assert (wall_row == 0).all() == WALLS[walls][1]
+    @property
+    def lateral_viscosity(self):
+        return self.nu
 
 
 @functools.lru_cache
-def _tendency_definition(cfg, first_step):
-    """``sw._tendency_round`` on one device's block, which it asks its
-    mesh the place of: jitted, the walls traced, so that the wall cases
-    of a shape share one build."""
+def _definition(cfg, first_step, south, north):
+    """The array code of ``sw._step_wide`` after its first exchange on
+    one device's block of ``cfg.ny + 4`` x ``cfg.nx + 4``, which it asks
+    its mesh the place of.  Round 1 runs on a block **one ring larger**
+    wherever no wall stands (a row more on a side without a wall, a
+    column more on either side), whose interior is the block's interior
+    and ring 1: there ring 1 is fresh, as the second exchange would
+    make it.  Round 2 runs on that result cut back to the block.
+    Returns the block's ``h``, ``u``, ``v`` after both rounds and the
+    tendencies at the larger interior's shape."""
     mesh = jax.make_mesh(
         (1, 1), ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     comm = m.MeshComm.from_mesh(mesh)
-    block, flag = jax.P("y", "x"), jax.P()
+    below, above = int(not south), int(not north)
+    larger = replace(cfg, ny=cfg.ny + below + above, nx=cfg.nx + 2)
+    walls = jnp.bool_(south), jnp.bool_(north)
+
+    def rounds(h, u, v, dh, du, dv):
+        h, u, v, dh, du, dv = sw._tendency_round(
+            h, u, v, dh, du, dv, larger, comm, *walls, first_step)
+        h, u, v = (x[below:x.shape[0] - above, 1:-1] for x in (h, u, v))
+        if cfg.nu > 0:
+            u, v = sw._viscosity_round(u, v, cfg, *walls)
+        return h, u, v, dh, du, dv
+
+    block = jax.P("y", "x")
     return jax.jit(jax.shard_map(
-        lambda *args: sw._tendency_round(
-            *args[:6], cfg, comm, *args[6:], first_step),
-        mesh=mesh, in_specs=(block,) * 6 + (flag,) * 2, out_specs=(block,) * 6))
+        rounds, mesh=mesh, in_specs=(block,) * 6, out_specs=(block,) * 6))
 
 
+@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
 @pytest.mark.parametrize("first_step", [False, True], ids=["ab2", "euler"])
 @pytest.mark.parametrize("walls", sorted(WALLS))
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_tendency_kernel_matches_the_array_code(
-        shape, walls, first_step, monkeypatch):
-    rows, width = _budget(monkeypatch, shape, fields=6)
-    cfg = sw.SWConfig(ny=rows - 2 * G, nx=width - 2 * G, **UNIT)
+def test_the_kernel_matches_the_array_code(
+        shape, walls, first_step, nu, monkeypatch):
+    rows, width = _budget(monkeypatch, shape)
+    south, north = WALLS[walls]
+    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
+    below, above = int(not south), int(not north)
+    # the larger block, and where the kernel's lies in it
+    big = (rows + below + above, width + 2)
+    cut = (slice(below, below + rows), slice(1, 1 + width))
     keys = jax.random.split(jax.random.PRNGKey(1), 6)
-    h, u, v = (
-        mean + spread * jax.random.normal(key, (rows, width), jnp.float32)
-        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5)))
-    old = [0.5 * jax.random.normal(key, (cfg.ny, cfg.nx), jnp.float32)
-           for key in keys[3:]]
-    south, north = (jnp.bool_(w) for w in WALLS[walls])
-    want = _tendency_definition(cfg, first_step)(h, u, v, *old, south, north)
+    fields = [
+        mean + spread * jax.random.normal(key, big, jnp.float32)
+        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
+    old = [0.5 * jax.random.normal(
+        key, (big[0] - 2 * G, big[1] - 2 * G), jnp.float32)
+        for key in keys[3:]]
+    want = [np.asarray(x) for x in _definition(
+        cfg, first_step, south, north)(*fields, *old)]
+
+    ring1, ring2 = _ring((rows, width), 1), _ring((rows, width), 2)
+    inner = ~(ring1 | ring2)
+    # ring 1 beyond a wall is no neighbour's: nothing there is touched
+    beyond = np.zeros((rows, width), bool)
+    beyond[G - 1], beyond[rows - G] = south, north
+    beyond &= ring1
+    fresh = ring1 & ~beyond
+
+    def padded(x, name):
+        """A tendency of the larger block's interior at the kernel's
+        block's shape: zero on ring 2, beyond a wall and, dh's, on
+        ring 1."""
+        x = np.pad(np.asarray(x), G)[cut]
+        return np.where(inner | (fresh if name != "dh" else False), x, 0)
+
+    names = ("dh", "du", "dv")
     if first_step:
-        a, b, old = 1.0, 0.0, [jnp.zeros_like(x) for x in old]
+        a, b, mine = 1.0, 0.0, [np.zeros((rows, width), np.float32)] * 3
     else:
         a, b = cfg.ab_a, cfg.ab_b
-    got = sw_kernels.tendency_round(
-        h, u, v, *(jnp.pad(x, G) for x in old), south, north, 0, a, b, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
+        mine = [padded(x, name) for x, name in zip(old, names)]
+    got = sw_kernels.wide_step(
+        *(x[cut] for x in fields), *mine, jnp.bool_(south), jnp.bool_(north),
+        below, a, b, nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
         gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
         coriolis_beta=cfg.coriolis_beta, interpret=True)
-    got, want = ([np.asarray(x) for x in xs] for xs in (got, want))
-    ring = _ring((rows, width))
-    for name, before, a, b in zip("huv", (h, u, v), got, want):
-        before = np.asarray(before)
-        # the round did something, and the kernel did the same
-        assert np.abs(b - before)[G:-G, G:-G].max() > 0.1, name
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6, err_msg=name)
-        np.testing.assert_array_equal(a[ring], b[ring], err_msg=name)
-        if name == "v":
-            ring = ring.copy()
-            ring[-(G + 1)] = False
-        np.testing.assert_array_equal(a[ring], before[ring], err_msg=name)
-    # the new tendencies at the fields' shape, zero on the ghost ring
-    for name, a, b in zip(("dh", "du", "dv"), got[3:], want[3:]):
-        assert np.abs(b).max() > 0.5, name
+    got = [np.asarray(x) for x in got]
+
+    before = [np.asarray(x[cut]) for x in fields]
+    round1 = want[:3] if not nu else [np.asarray(x) for x in _definition(
+        replace(cfg, nu=0.0), first_step, south, north)(*fields, *old)[:3]]
+    for name, x0, x1, x2, x in zip("huv", before, round1, want, got):
+        # what the kernel steps: the interior, and ring 1 of u and v
+        # where it is a neighbour's
+        stepped = inner | (fresh if name != "h" else False)
+        # the rounds did something there, and the kernel did the same
+        assert np.abs(x1 - x0)[inner].max() > 0.1, name
+        if name != "h":
+            assert np.abs(x1 - x0)[fresh].max() > 0.05, name
+            assert (np.abs(x2 - x1)[inner].max() > 0.01) == (nu > 0), name
         np.testing.assert_allclose(
-            a[G:-G, G:-G], b, rtol=0, atol=1e-6, err_msg=name)
-        assert not a[_ring(a.shape)].any(), name
+            x[stepped], x2[stepped], rtol=0, atol=2e-6, err_msg=name)
+        # the rest goes through, bit for bit (the wall condition zeroes
+        # its row from end to end, as the array code's)
+        still = ~stepped
+        if name == "v":
+            still[-(G + 1)] = False
+        np.testing.assert_array_equal(x[still], x0[still], err_msg=name)
+    # the new tendencies at the fields' shape: du's and dv's ring 1 the
+    # neighbour's, the rest of the ghost ring zero
+    for name, x, x1 in zip(names, got[3:], want[3:]):
+        x1 = padded(x1, name)
+        kept = inner | (fresh if name != "dh" else False)
+        assert min(np.abs(x1[zone]).max()
+                   for zone in (inner, kept & ring1) if zone.any()) > 0.5, name
+        np.testing.assert_allclose(x, x1, rtol=0, atol=2e-6, err_msg=name)
+        assert not x[~kept].any(), name
     wall_row = got[2][-(G + 1)]
-    assert (wall_row == 0).all() == WALLS[walls][1]
+    assert (wall_row == 0).all() == north
 
 
-@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def _exchanges_a_step(multistep, state):
+    """The halo exchanges in the traced one-step program."""
+    report = verify_comm(lambda: multistep(state))()
+    return [e.kind for e in report.events].count("halo_exchange_2d")
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 1), (1, 2), (2, 2)])
 def test_multistep_through_the_kernels_matches_the_array_path(
         mesh_shape, monkeypatch):
     """``make_init``, ``make_first_step`` and ``make_multistep`` with
-    both rounds forced through the kernels (interpreted) against the
-    array path, after 1 + 10 steps: walls and the Coriolis parameter's
-    rows on the right devices, halos between the rounds, the first step
-    and the rest through one kernel."""
+    the step forced through the kernel (interpreted) against the array
+    path, after 1 + 10 steps: walls and the Coriolis parameter's rows on
+    the right devices, ring 1 recomputed where the array path exchanges
+    a second time, the first step and the rest through one kernel."""
     mesh = jax.make_mesh(
         mesh_shape, ("y", "x"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -180,7 +226,8 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     # wall, so that a device that took another's rows would show
     cfg = sw.SWConfig(ny=40, nx=48, ghost=G, coriolis_f=2e-2, depth=1e3,
                       coriolis_beta=1e-7)
-    block = (40 // py + 2 * G, 48 // px + 2 * G)
+    ny_l, nx_l = 40 // py, 48 // px
+    block = (ny_l + 2 * G, nx_l + 2 * G)
 
     def run():
         state = sw.make_init(cfg, comm)()
@@ -188,30 +235,30 @@ def test_multistep_through_the_kernels_matches_the_array_path(
         return jax.tree.map(
             np.asarray, sw.make_multistep(cfg, comm, 10)(state))
 
+    def blocks(x):
+        """A global array of padded blocks, a block at a time."""
+        return x.reshape(py, block[0], px, block[1]).transpose(0, 2, 1, 3)
+
     def interiors(x):
         """A global array of padded blocks without their ghost rings."""
-        blocks = x.reshape(py, block[0], px, block[1])
-        return blocks[:, G:-G, :, G:-G].reshape(40, 48)
+        return blocks(x)[..., G:-G, G:-G].transpose(0, 2, 1, 3).reshape(40, 48)
 
     want = run()
     assert want.dh.shape == (40, 48)
+    state = sw.make_init(cfg, comm)()
+    assert _exchanges_a_step(sw.make_multistep(cfg, comm, 1), state) == 5
     calls = []
+    wide_step = sw_kernels.wide_step
 
-    def interpreted(kernel):
-        def call(*args, **kwargs):
-            calls.append((kernel.__name__, args[0].shape))
-            return kernel(*args, interpret=True, **kwargs)
+    def interpreted(*args, **kwargs):
+        calls.append(args[0].shape)
+        return wide_step(*args, **dict(kwargs, interpret=True))
 
-        return call
-
-    rounds = ("tendency_round", "viscosity_round")
-    for name in rounds:
-        monkeypatch.setattr(
-            sw_kernels, name, interpreted(getattr(sw_kernels, name)))
+    monkeypatch.setattr(sw_kernels, "wide_step", interpreted)
     monkeypatch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: True)
     # Pallas's interpreter slices blocks at indices that vary over no
-    # mesh axis, which shard_map's checker refuses; the compiled kernels
-    # are checked (tests/test_tpu_compile.py)
+    # mesh axis, which shard_map's checker refuses; the compiled kernel
+    # is checked (tests/test_tpu_compile.py)
     monkeypatch.setattr(
         jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
     imports = []
@@ -219,13 +266,16 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     monkeypatch.setattr(
         sw_kernels, "pallas", lambda: imports.append(1) or pallas())
     got = run()
-    # each round is built once in each of the two programs, on one
+    # the step is built once in each of the two programs, on one
     # device's block; Pallas is asked for where each program is built,
-    # and once more where each kernel is traced: the second program
-    # reuses the first's traces
-    assert calls == [(name, block) for name in rounds] * 2
-    assert len(imports) == 4
-    # where the rounds are kernels the state carries padded tendencies,
+    # and once more where the kernel is traced: the second program
+    # reuses the first's trace
+    assert calls == [block] * 2
+    assert len(imports) == 3
+    # three exchanges a step where the array path has five
+    state = sw.make_init(cfg, comm)()
+    assert _exchanges_a_step(sw.make_multistep(cfg, comm, 1), state) == 3
+    # where the step is a kernel the state carries padded tendencies,
     # from make_init on; a first step takes them interior-shaped too, as
     # who builds a state of their own hands them in (the benchmark)
     assert got.dh.shape == got.h.shape
@@ -236,25 +286,43 @@ def test_multistep_through_the_kernels_matches_the_array_path(
         sw.make_first_step(cfg, comm)(sw.make_init(cfg, comm)()).dv)
     with pytest.raises(ValueError, match="carries them padded"):
         sw.make_multistep(cfg, comm, 1)(bare)
-    # what a broken round would leave: a kernel that took every block
+    # what a broken step would leave: a kernel that took every block
     # for the mesh's first, a friction that did nothing
-    tendency_round = sw_kernels.tendency_round
     monkeypatch.setattr(
-        sw_kernels, "tendency_round",
-        lambda *args, **kwargs: tendency_round(
-            *args[:8], 0, *args[9:], **kwargs))
+        sw_kernels, "wide_step",
+        lambda *args, **kwargs: interpreted(*args[:8], 0, *args[9:], **kwargs))
     misplaced = run()
-    monkeypatch.setattr(sw_kernels, "tendency_round", tendency_round)
     monkeypatch.setattr(
-        sw_kernels, "viscosity_round", lambda u, v, *args, **kwargs: (u, v))
+        sw_kernels, "wide_step",
+        lambda *args, **kwargs: interpreted(*args, **dict(kwargs, nu=0.0)))
     smooth = run()
     for name, a, b, c, d in zip(
             sw.SWState._fields, got, want, misplaced, smooth):
         assert np.isfinite(b).all()
         tolerance = 2e-5 * max(1.0, np.abs(b).max())
         if name.startswith("d"):
-            assert not (a != 0)[np.tile(_ring(block), mesh_shape)].any(), name
+            # the tendencies' ghost ring: ring 2 zero; ring 1 of du and
+            # dv what the neighbour holds for those cells (periodic in
+            # x), zero beyond a wall; all of dh's zero
+            held = np.pad(interiors(a), ((1, 1), (0, 0)))
+            held = np.pad(held, ((0, 0), (1, 1)), mode="wrap")
+            for (iy, ix), mine in np.ndenumerate(np.empty((py, px))):
+                mine = blocks(a)[iy, ix]
+                theirs = np.pad(held[iy * ny_l:(iy + 1) * ny_l + 2,
+                                     ix * nx_l:(ix + 1) * nx_l + 2], 1)
+                if name == "dh":
+                    theirs[_ring(block, 1)] = 0
+                else:
+                    assert np.abs(theirs[_ring(block, 1)]).max() > 0, name
+                np.testing.assert_allclose(
+                    mine, theirs, rtol=0, atol=1e-6 * np.abs(b).max(),
+                    err_msg=name)
+                assert not mine[_ring(block, 2)].any(), name
             a, c, d = interiors(a), interiors(c), interiors(d)
+        elif name in "uv":
+            # the array path's second exchange refreshes ring 2 as well,
+            # which nothing reads before the next step's exchange
+            a, b, c, d = (interiors(x) for x in (a, b, c, d))
         np.testing.assert_allclose(a, b, rtol=0, atol=tolerance, err_msg=name)
         if name in "uv":
             assert np.abs(d - b).max() > 20 * tolerance, name
@@ -275,9 +343,12 @@ def _comm_on(platform):
     ("tpu", "float64", 7204, 14404, False),   # the strips are float32's
     ("tpu", "bfloat16", 7204, 14404, False),
     ("tpu", "float32", 7, 364, False),        # not one strip of 8 rows
+    # one interior row, whose ring 1 would be a neighbour's wall row: a
+    # block with a strip has four or more
+    ("tpu", "float32", 5, 364, False),
     ("tpu", "float32", 7204, 300_000, False),  # a strip over the budget
-    # round 1's six fields decide for both rounds: a strip of two
-    # fields this wide would fit, one of six does not
+    # the state's six arrays go through one call: a strip of two this
+    # wide would fit, one of six does not
     ("tpu", "float32", 7204, 40_000, True),
     ("tpu", "float32", 7204, 100_000, False),
 ], ids=lambda x: str(x))
@@ -386,7 +457,7 @@ print("later", "jax.experimental.mosaic.gpu" in sys.modules)
 
 @pytest.mark.parametrize("platform,ghost,nu,expected", [
     ("tpu", 2, 1, 1), ("cpu", 2, 1, 0), ("tpu", 1, 1, 0), ("tpu", 4, 1, 0),
-    ("tpu", 2, 0, 1),  # without friction round 1 is a kernel still
+    ("tpu", 2, 0, 1),  # without friction the step is a kernel still
 ])
 def test_a_step_built_for_tpu_devices_imports_pallas_before_it_is_traced(
         platform, ghost, nu, expected, monkeypatch):

@@ -101,7 +101,7 @@ def _kernels(text):
     found = {}
     for line in text.splitlines():
         if "tpu_custom_call" in line:
-            name = line.split("=")[0].strip().lstrip("%").split(".")[0]
+            name = line.split("=")[0].split("%")[1].split(".")[0]
             operands = line.split("custom-call(")[1].split(")")[0]
             operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
             layouts = line.split("operand_layout_constraints={")[1].split("}}")[0]
@@ -131,37 +131,35 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     text = compiled.as_text()
     # one chip: XLA elides every halo exchange; four: they are real
     assert ("collective-permute" in text) == (py * px > 1)
-    # the wide-halo step's two rounds are the Pallas kernels on TPU
-    # devices, updating their fields in place (XLA copies a field that a
-    # custom call both overwrites and reads through a second operand);
-    # the other two schedules are array code
+    # the wide-halo step after its first exchange is one Pallas kernel
+    # on TPU devices, updating the six arrays of the state in place (XLA
+    # copies a field that a custom call both overwrites and reads
+    # through a second operand); the other two schedules are array code
     kernels = _kernels(text)
-    assert sorted(kernels) == (
-        ["tendency_round", "viscosity_round"] if ghost == 2 else [])
-    for name, n_scalars, n_fields in (
-            ("tendency_round", 2, 6), ("viscosity_round", 1, 2))[:len(kernels)]:
-        line, fields, _ = kernels[name]
-        aliasing = ", ".join(
-            f"{{{k}}}: ({n_scalars + k}, {{}})" for k in range(n_fields))
+    assert sorted(kernels) == (["wide_step"] if ghost == 2 else [])
+    assert text.count("tpu_custom_call") == (ghost == 2)  # one a step
+    if kernels:
+        line, fields, _ = kernels["wide_step"]
+        aliasing = ", ".join(f"{{{k}}}: ({2 + k}, {{}})" for k in range(6))
         assert f"output_to_operand_aliasing={{{aliasing}}}" in line
-        assert len(fields) == n_fields and not _copied(text, fields), fields
+        assert len(fields) == 6 and not _copied(text, fields), fields
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 2**30  # six fields of ~26 MB
     # not one field's bytes beside the state: every intermediate of the
-    # two rounds stays in the kernels
+    # step stays in the kernel
     assert (mem.temp_size_in_bytes < 1800 * 3600 * 4) == (ghost == 2)
 
 
-def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e):
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     """Forward Euler is the AB2 kernel with other scalars, so a process
-    traces and lowers round 1 once for its two programs."""
-    texts = [_compiled_multistep(v5e, (1, 1), 2, 1800, 3600, steps).as_text()
+    traces and lowers the step's kernel once for its two programs."""
+    texts = [_compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, steps).as_text()
              for steps in (0, 10)]
     first, rest = (_kernels(text) for text in texts)
-    assert sorted(first) == sorted(rest) == ["tendency_round", "viscosity_round"]
-    for name in first:
-        assert first[name][2] == rest[name][2], name
-        assert not _copied(texts[0], first[name][1]), first[name][1]
+    assert sorted(first) == sorted(rest) == ["wide_step"]
+    assert first["wide_step"][2] == rest["wide_step"][2]
+    assert not _copied(texts[0], first["wide_step"][1]), first["wide_step"][1]
 
 
 @pytest.mark.parametrize("n", [1, 4])
@@ -210,10 +208,10 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
     writes = [o for o in table.values() if o.scopes == (halo, "unpack")]
     assert {o.source.split(":")[0] for o in writes} == {
         "mpi4jax_tpu/parallel/halo.py"}
-    # what the model's two rounds leave beside their kernels (the
-    # scalars they are handed, their results taken apart; the calls' own
-    # line the reader does not find yet, PERF.md section 7) is the
-    # programs', not the halo's
+    # what the model's step leaves beside its kernel (the scalars it is
+    # handed, its results taken apart; the call's own line the reader
+    # does not find yet, PERF.md section 7) is the programs', not the
+    # halo's
     model = [o for o in table.values()
              if o.source and o.source.startswith("mpi4jax_tpu/models/")]
     assert model and all(
