@@ -16,8 +16,8 @@ JSON line per payload size from 1 KB up to ``--mb``, covering both
 sides of the tree->ring switchover (``T4J_RING_MIN_BYTES``, see
 docs/performance.md "TCP-tier algorithm selection"); every record
 carries the chosen data plane (``tree|ring|hier|shm``) plus the
-local/leader world sizes and active knob values so BENCH trajectories
-can attribute wins.  ``--pairs`` (with ``T4J_EMU_LOCAL=k`` to emulate
+local/leader world sizes and active knob values, so a reader of
+the records can attribute wins.  ``--pairs`` (with ``T4J_EMU_LOCAL=k`` to emulate
 multiple nodes on one host) measures hier-vs-flat interleaved
 same-conditions pairs (docs/performance.md "hierarchical
 collectives").  To measure the TCP tier on one host, disable the
@@ -58,7 +58,7 @@ def main():
     ap.add_argument(
         "--sweep", action="store_true",
         help="one JSON line per payload size, 1 KB -> --mb in x4 steps: "
-        "the tree->ring switchover trajectory for BENCH records",
+        "both sides of the tree->ring switchover",
     )
     ap.add_argument(
         "--pairs", action="store_true",
@@ -219,8 +219,7 @@ def main():
     rec, busbw, tok = _measure(args, comm, args.mb)
     factor = _busbw_factor(args.op, n)
     if args.op == "allreduce":
-        # In-run machine-relative ceiling (the same calibration pattern
-        # as bench.py's HBM probe): the shm arena must move
+        # In-run machine-relative ceiling: the shm arena must move
         # (5n+1)*S bytes of memory traffic per S-byte allreduce
         # (n stage-in copies, an (n+1)-stream fold, n copy-outs — see
         # docs/performance.md), and every byte moves through however
@@ -793,7 +792,7 @@ def _wire_backend_main(args, comm):
     binfo = runtime.wire_backend_info() or {}
     launched = binfo.get("wire_backend", "auto")
     if "uring" in backends and not binfo.get("uring_supported"):
-        # explicit skip record: BENCH_history must show the arm was
+        # explicit skip record: the output must show the arm was
         # dropped for a reason, not silently measure sendmsg twice
         if comm.rank() == 0:
             print(json.dumps({
@@ -1023,8 +1022,8 @@ def _autotune_pair_main(args, comm):
     """Mis-default recovery: interleaved same-conditions allreduce
     batches at --mb under three segment sizes — a deliberately
     mis-defaulted 16K, the autotuner's in-run fit, and the hand-tuned
-    1M default — so the BENCH trajectory shows the autotuner clawing
-    back what a wrong shipped default costs.  Run with T4J_NO_SHM=1:
+    1M default — so the records show the autotuner clawing back
+    what a wrong shipped default costs.  Run with T4J_NO_SHM=1:
     T4J_SEG_BYTES governs the segmented ring, and on a same-host arena
     comm the knob never serves."""
     import jax.numpy as jnp

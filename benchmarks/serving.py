@@ -50,11 +50,11 @@ epochs::
     python -m mpi4jax_tpu.launch -np 8 --elastic rejoin --autoscale \\
         benchmarks/serving.py --arms ramp --ramp 1,10,1 --slo 4000
 
-Rank 0 prints one JSON record per metric (the bench.py serving leg
-consumes ``serving_p50_ms_procN`` / ``serving_p99_ms_procN`` /
+Rank 0 prints one JSON record per metric (the serving arms print
+``serving_p50_ms_procN`` / ``serving_p99_ms_procN`` /
 ``serving_rps_procN`` / ``serving_shed_rate_procN`` /
 ``serving_slo_attainment_procN`` + the ``_admit_off`` contrasts; the
-autoscale leg consumes ``serving_autoscale_slo_attainment_procN`` /
+autoscale arm prints ``serving_autoscale_slo_attainment_procN`` /
 ``goodput_per_rank_second_{auto,static}_procN``).
 """
 

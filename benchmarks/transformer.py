@@ -1,10 +1,10 @@
 """Transformer train-step throughput (tokens/s) on the device mesh.
 
-Model-level companion to the solver bench (bench.py) and the collective
-micro-bench (benchmarks/collectives.py): times the flagship dense
-dp×tp×sp transformer train step (models/transformer.py — Megatron f/g +
-ring attention + DP, all collectives on the mesh) end to end, forward +
-backward + SGD in one jitted shard_map executable.
+Times the flagship dense dp×tp×sp transformer train step
+(models/transformer.py — Megatron f/g + ring attention + DP, all
+collectives on the mesh) end to end, forward + backward + SGD in one
+jitted shard_map executable.  No cell of the benchmark (perfbench/) runs
+it yet: its numbers are for orientation.
 
 Prints one JSON line: tokens/s, the model-FLOPs estimate (6·N·tokens
 per step, the standard convention), and the config.  The rate is the
@@ -161,7 +161,7 @@ def build(
     """
     if ce_chunk and mode != "dense":
         # same contract as main()'s CLI guard, enforced for in-process
-        # callers (bench.py sweeps): only the dense TransformerConfig
+        # callers: only the dense TransformerConfig
         # threads ce_chunk — a silent fallback to streaming CE would
         # mislabel the benchmark record
         raise ValueError(
@@ -274,8 +274,7 @@ def run(
     micro=None, remat=False, attn_impl="auto", ce_chunk=0,
 ):
     """Measure the train step :func:`build` constructs; returns the
-    JSON-ready record dict.  Importable so ``bench.py`` can run it
-    in-process (one process holds the chip)."""
+    JSON-ready record dict."""
     import jax
 
     built = build(
@@ -497,7 +496,7 @@ def run_overlap(mode="pairs", layers=6, d_model=1024, batch=16, reps=3,
     the same interleaved-pairs convention as the hier-vs-flat busbw
     comparison (PRs 2/3/5).  Rank 0 prints one record per side plus
     the speedup ratio; the records carry the bucket/knob context so
-    BENCH trajectories can attribute wins.
+    a reader can attribute wins.
     """
     import os
 
@@ -609,6 +608,31 @@ def run_overlap(mode="pairs", layers=6, d_model=1024, batch=16, reps=3,
     return recs
 
 
+def force_cpu_mesh(n):
+    """Force an n-device virtual CPU mesh (must run before jax
+    initialises a backend).  Pins the platform in code as well as the
+    device count, so a child started by a parent that holds the chip
+    never reaches for it, whatever JAX_PLATFORMS says."""
+    import os
+    import re
+
+    flags = os.environ.get("XLA_FLAGS", "")
+    key = "--xla_force_host_platform_device_count"
+    if key in flags:
+        flags = re.sub(rf"{key}=\d+", f"{key}={n}", flags)
+    else:
+        flags = (flags + f" {key}={n}").strip()
+    os.environ["XLA_FLAGS"] = flags
+
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    assert len(jax.devices()) == n, (
+        f"requested {n} CPU devices, got {len(jax.devices())} "
+        "(was jax imported before force_cpu_mesh?)"
+    )
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument(
@@ -698,8 +722,6 @@ def main(argv=None):
         return
 
     if args.cpu_mesh:
-        from benchmarks.collectives import force_cpu_mesh
-
         force_cpu_mesh(args.cpu_mesh)
 
     preset = dict(SIZES[args.size]) if args.size else {}

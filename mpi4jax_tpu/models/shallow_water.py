@@ -119,10 +119,10 @@ class SWConfig:
 
     def bench_size(self):
         """The published-benchmark domain: 100× the demo cell count
-        (docs/shallow-water.rst:49-51 → 3600×1800), on the wide-halo
-        schedule (the fastest single-chip configuration — on one chip
-        permutes are elided, so the ghost=4 schedule's fewer rounds buy
-        nothing and its extra masking costs; numerics identical)."""
+        (docs/shallow-water.rst:49-51 → 3600×1800), at ``ghost=2``, the
+        schedule whose step is one kernel on a TPU.  The benchmark does
+        not call this: ``perfbench/configs/shallow-water.json`` states
+        the domain and the schedule itself."""
         return replace(self, ny=1800, nx=3600, ghost=2)
 
 

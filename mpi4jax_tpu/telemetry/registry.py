@@ -260,8 +260,7 @@ class MetricsRegistry:
         return row.stats() if row is not None else None
 
     def bytes_by_plane(self):
-        """Total payload bytes per data plane over the op rows (the
-        per-plane byte counters BENCH records track)."""
+        """Total payload bytes per data plane over the op rows."""
         out = {}
         for (_c, _o, plane), row in self.rows.items():
             out[plane] = out.get(plane, 0) + row.bytes

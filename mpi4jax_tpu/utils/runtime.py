@@ -1,4 +1,4 @@
-"""Runtime helpers shared by the bench/example drivers.
+"""Runtime helpers shared by the entry scripts and examples.
 
 ``drain`` is the flush of the reference's exit/loop hygiene
 (mpi4jax/_src/flush.py:1-12 — device_put+0 noop as a work barrier):

@@ -6,6 +6,7 @@ without chip time.  The chip run itself is `python chip_smoke.py`
 (through the chip tool); nothing here is a device result.
 """
 
+import ast
 import json
 import pathlib
 import sys
@@ -61,6 +62,36 @@ def test_ops_check_catches_a_wrong_value(monkeypatch):
     )
     with pytest.raises(AssertionError, match="bcast"):
         chip_smoke.ops_check(jax.devices()[:4])
+
+
+def test_cpu_child_check_on_the_cpu_backend():
+    # here the parent holds the CPU backend; on the chip it holds the TPU
+    out = chip_smoke.cpu_child_check()
+    assert out["max_diff"] == 0.0
+    assert "8 virtual CPU devices" in out["compared"]
+
+
+def test_cpu_child_check_catches_a_wrong_value(monkeypatch):
+    wrong = chip_smoke.CPU_CHILD.replace(
+        "m.allreduce(v, m.SUM", "m.allreduce(v + 1, m.SUM"
+    )
+    assert wrong != chip_smoke.CPU_CHILD
+    monkeypatch.setattr(chip_smoke, "CPU_CHILD", wrong)
+    with pytest.raises(AssertionError, match="max_diff=8.0"):
+        chip_smoke.cpu_child_check()
+
+
+def test_no_phase_record_carries_a_rate():
+    # every key of every phase's record is a string in the source; a
+    # rate printed here would be a number under no harness
+    tree = ast.parse((REPO / "chip_smoke.py").read_text())
+    strings = [
+        node.value for node in ast.walk(tree)
+        if isinstance(node, ast.Constant) and isinstance(node.value, str)
+    ]
+    assert len(strings) > 100  # f-string parts included
+    assert [x for x in strings if x.startswith("smoke_")] == []
+    assert [x for x in strings if x.endswith(("_per_s", "_per_sec"))] == []
 
 
 def test_grad_and_selfcomm_checks():

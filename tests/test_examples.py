@@ -19,16 +19,12 @@ def test_shallow_water_example_runs():
         sys.path.remove(str(examples))
 
 
-def test_bench_entrypoint_importable():
-    root = pathlib.Path(__file__).resolve().parent.parent
-    sys.path.insert(0, str(root))
-    try:
-        import bench
+def test_best_mesh_shape_is_the_most_square():
+    # the examples' default mesh
+    from mpi4jax_tpu.utils.runtime import best_mesh_shape
 
-        assert bench.best_mesh_shape(8) == (2, 4)
-        assert bench.best_mesh_shape(7) == (1, 7)
-    finally:
-        sys.path.remove(str(root))
+    assert best_mesh_shape(8) == (2, 4)
+    assert best_mesh_shape(7) == (1, 7)
 
 
 def _run_example(name, argv):

@@ -72,11 +72,6 @@
 #                endpoint must serve a schema-valid snapshot
 #                (docs/observability.md "diagnosing a slow step").
 #                ctypes only — runs on old-jax containers.
-#  11. bench   — bench.py --quick --out BENCH_quick.json: the cheap
-#                trajectory point.  Needs a TPU: without one bench.py
-#                exits non-zero and writes no record, and the lane
-#                fails.  Native legs that cannot run leave explicit
-#                "skipped" keys.
 #  12. elastic — tools/elastic_smoke.py twice: plain and under
 #                AddressSanitizer.  Elastic world membership
 #                (docs/failure-semantics.md "elastic membership"):
@@ -201,7 +196,7 @@ cd "$(dirname "$0")/.."
 lanes=("$@")
 if [ ${#lanes[@]} -eq 0 ]; then
   lanes=(tier1 fault proc asan tsan lint verify resilience telemetry
-         async diagnose bench elastic autotune postmortem stripe
+         async diagnose elastic autotune postmortem stripe
          serving autoscale compress uring)
 fi
 
@@ -281,13 +276,6 @@ for lane in "${lanes[@]}"; do
       run_lane diagnose-asan env T4J_SANITIZE=address timeout -k 10 900 \
         python tools/diagnose_smoke.py 8
       ;;
-    bench)
-      run_lane bench timeout -k 10 2400 \
-        python bench.py --quick --out BENCH_quick.json
-      run_lane bench-record python -c \
-        'import json; rec = json.load(open("BENCH_quick.json")); \
-assert rec.get("metric"), rec; print("BENCH record ok:", rec["metric"])'
-      ;;
     elastic)
       run_lane elastic-plain env -u T4J_SANITIZE timeout -k 10 1200 \
         python tools/elastic_smoke.py 8
@@ -346,7 +334,7 @@ assert rec.get("metric"), rec; print("BENCH record ok:", rec["metric"])'
         python tools/uring_smoke.py 4
       ;;
     *)
-      echo "unknown lane: $lane (want tier1|fault|proc|asan|tsan|lint|resilience|telemetry|async|diagnose|bench|elastic|autotune|postmortem|stripe|serving|autoscale|compress|uring)" >&2
+      echo "unknown lane: $lane (want tier1|fault|proc|asan|tsan|lint|resilience|telemetry|async|diagnose|elastic|autotune|postmortem|stripe|serving|autoscale|compress|uring)" >&2
       exit 2
       ;;
   esac
