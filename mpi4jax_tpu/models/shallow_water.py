@@ -49,7 +49,11 @@ from mpi4jax_tpu.ops import reductions
 from mpi4jax_tpu.ops._core import as_token
 from mpi4jax_tpu.ops.allreduce import allreduce
 from mpi4jax_tpu.ops.collectives import allgather, scan
-from mpi4jax_tpu.parallel.halo import halo_exchange_2d, halo_exchange_2d_batch
+from mpi4jax_tpu.parallel.halo import (
+    halo_exchange_2d,
+    halo_exchange_2d_batch,
+    halo_slabs_2d,
+)
 
 __all__ = [
     "SWConfig",
@@ -566,10 +570,15 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
     Where the step runs as the kernel of :mod:`sw_kernels`
     (:func:`_runs_as_kernels`) it is shorter still, on every mesh:
 
-        exchange h, u, v   → one kernel: round 1 on the interior and on
-                             ring 1 of u and v, then round 2
+        slabs of h, u, v   → one kernel: the ghost writes, round 1 on
+                             the interior and on ring 1 of u and v,
+                             then round 2
 
-    3 exchanges and 12 passes over a field: round 1 reads one ring round
+    3 exchanges and 12 passes over a field, and no ghost written outside
+    the kernel: ``halo_slabs_2d`` is the exchange without its last
+    phase, and the kernel, which reads and writes every tile that holds
+    a ghost cell anyway, stores the received slabs over the rows it
+    takes in.  Round 1 reads one ring round
     a cell and the exchange brings two, so the kernel computes on ring 1
     the very ``u``, ``v`` a second exchange would bring, and the two
     rounds are one walk over the rows.  There the state carries its
@@ -592,12 +601,13 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
 
     h, u, v, dh, du, dv = state
 
-    # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
-    h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
-    u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
-    v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
-
     if _runs_as_kernels(cfg, comm):
+        # --- the exchange without its ghost writes: the kernel, which
+        # reads and writes every tile that holds a ghost cell anyway,
+        # places the received slabs itself ---
+        for_h, token = halo_slabs_2d(h, comm, periodic=per, token=token, width=G)
+        for_u, token = halo_slabs_2d(u, comm, periodic=per, token=token, width=G)
+        for_v, token = halo_slabs_2d(v, comm, periodic=per, token=token, width=G)
         if first_step:
             # forward Euler is AB2 with (1, 0) on zero tendencies, and the
             # caller's, which this step does not read, may be of either shape
@@ -613,11 +623,17 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
                     "return them")
         iy, _ix = _device_coords(comm)
         state = sw_kernels.wide_step(
-            h, u, v, dh, du, dv, is_south, is_north, iy * ny_l, a, b,
+            h, u, v, dh, du, dv, (for_h, for_u, for_v), is_south, is_north,
+            iy * ny_l, a, b,
             nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
             gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
             coriolis_beta=cfg.coriolis_beta)
         return SWState(*state), token
+
+    # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
+    h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
+    u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
+    v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
 
     h, u, v, dh, du, dv = _tendency_round(
         h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)

@@ -36,6 +36,19 @@ zero.  Ring 1 of the returned ``u``, ``v`` holds round 1's values, not
 round 2's (the next step's exchange overwrites it); ``h``'s ghost ring
 and ring 2 of ``u``, ``v`` pass through.
 
+Who writes the ghosts.  The exchange does not: a write of two ghost
+columns touches a vector register's lanes in every row of the block, 29
+us a slab on a v5e for 58 KB, six a step, next to a kernel that reads
+and writes every one of those tiles anyway.  So the step calls
+``parallel.halo_slabs_2d``, the exchange without its last phase, and
+hands the kernel the fields **with stale ghosts and the received
+slabs**; the kernel writes the slabs' cells over each row as it enters
+the VMEM window (below), so that both rounds read, and the fields come
+back with, the ghosts an exchange would have written.  On every mesh:
+on one device a slab is a slice of the block itself, on four what the
+neighbour sent; a slab that is ``None`` (no wrap on an axis of one
+device) leaves those ghosts the block's own.
+
 Tiling (:func:`_walk`)
 ----------------------
 The field keeps its full width (x is not tiled), so an x-shift is a lane
@@ -56,7 +69,18 @@ step before, between the last strip of tile ``i - 2`` and the first
 strip of tile ``i``: 8 rows of old values either side, of which round 1
 reads one.  Every row is read from HBM once, before the step that writes
 it, and no tile reads what another has written; a field is one operand
-of the call and no other, so XLA has nothing to copy.  What is read and
+of the call and no other, so XLA has nothing to copy.  The received
+slabs are operands of their own, as the exchange returns them: a slab of
+columns ``(rows, 2)`` in blocks of a tile's rows, a slab of rows ``(2,
+width)`` whole.  Where rows enter the window (the first strip of the
+tile handed in, then the tile) the slabs' cells are stored over them,
+x before y as an exchange writes them: a slab's columns into the first
+or the last vector register of each row (two stores where they lie in
+two), its rows in the grid step that holds their strip.  Each store is
+lowered on its own, a few milliseconds of a program's set-up on a
+chip's host, which is why they are as few as that.  (One ``(rows, 128)``
+operand a field with both sides' columns, built by ``concatenate``, made
+XLA transpose the whole field to slice it lane-dense.)  What is read and
 written cell by cell (the tendencies) needs no window: its blocks are
 the tile being written.  Inside a tile the kernel walks strips of 8 rows
 (one float32 sublane tile), so that its working set is a few strips and
@@ -157,13 +181,20 @@ add, sub, mul, div, eq, select = (
     lax.add, lax.sub, lax.mul, lax.div, lax.eq, lax.select)
 
 
-def _walk(body, scalars, fields, pointwise, n_second, *, interpret):
+def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
     and written strip by strip where they lie, with no neighbours.
     ``scalars`` are small arrays kept in SMEM.  Returns the new
     ``fields`` and ``pointwise``, in that order.
+
+    ``slabs`` holds, field by field, the ``(west, east, south, north)``
+    that :func:`halo_slabs_2d` returned for it: the fields' own ghosts
+    are stale, and the rows a window takes in get theirs from the
+    slabs, so that the stages read, and the fields come back with, what
+    an exchange would have written.  Where a slab is ``None`` those
+    ghosts are the field's own.
 
     ``body(roll, *scalar_refs)`` runs once a grid step and returns the
     two stages ``(first, second)``.  ``first(g, col, fields,
@@ -196,24 +227,57 @@ def _walk(body, scalars, fields, pointwise, n_second, *, interpret):
     slots = strips + 2
     axes = vma_of(fields[0]) or ()
     scalars = [promote_vma(x, axes) for x in scalars]
+    # the slabs that came, field by field, each with what it is a slab
+    # of (0: columns, 1: rows) and its first ghost column or row
+    places = ((0, 0), (0, width - G), (1, 0), (1, rows - G))
+    came = [[(*place, x) for place, x in zip(places, sides) if x is not None]
+            for sides in slabs]
+    n_slabs = [len(sides) for sides in came]
+    arrived = [promote_vma(x, axes) for sides in came for *_, x in sides]
 
     def kernel(*refs):
         refs = iter(refs)
-        scalar_refs, taken, old, out, new, windows, rings = (
+        scalar_refs, taken, *brought, old, out, new, windows, rings = (
             tuple(itertools.islice(refs, n)) for n in
-            (n_scalars, n_fields, n_point, n_fields, n_point, n_fields,
-             n_second))
+            (n_scalars, n_fields, *n_slabs, n_point, n_fields, n_point,
+             n_fields, n_second))
         i = pl.program_id(0)
         first, second = body(pltpu.roll, *scalar_refs)
-        # a window's rows: the strip above tile i - 1, the tile, and the
-        # strip below it, which is the first of the block just handed in
-        for ref, win in zip(taken, windows):
-            win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
 
         shape = (STRIP, lanes)
         r = lax.broadcasted_iota(jnp.int32, shape, 0)
         col = lax.broadcasted_iota(jnp.int32, shape, 1)
         top, bottom = eq(r, 0), eq(r, STRIP - 1)
+        # the tile handed in: the walk's last steps are handed the
+        # field's last again
+        t = lax.min(i, tiles - 1)
+
+        def place(win, at, count, k):
+            """Rows ``[at, at + count)`` of field ``k``'s window have
+            just taken the first ``count`` rows of tile ``t`` as the
+            field holds them, ghosts stale: write the slabs' cells over
+            them, x before y as an exchange does (the y slabs hold the
+            corners).  A slab's columns in one store where they lie in
+            one vector register, its rows where they lie in one strip,
+            and in the grid step that holds that strip."""
+            for (of_rows, lo, _), ref in zip(came[k], brought[k]):
+                for start, n in _pieces(lo, G, STRIP if of_rows else LANES):
+                    mine = pl.ds(start - lo, n)
+                    if not of_rows:
+                        win[pl.ds(at, count), pl.ds(start, n)] = (
+                            ref[pl.ds(0, count), mine])
+                        continue
+                    holder, row = divmod(start, tile)
+                    if row < count:
+                        @pl.when(eq(t, holder))
+                        def _(row=row, n=n, mine=mine, ref=ref):
+                            win[pl.ds(at + row, n), :] = ref[mine, :]
+
+        # a window's rows: the strip above tile i - 1, the tile, and the
+        # strip below it, which is the first of the block just handed in
+        for k, (ref, win) in enumerate(zip(taken, windows)):
+            win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
+            place(win, tile + STRIP, STRIP, k)
 
         def strip(k):
             """The rows of strip ``k`` of a block, a window or a ring."""
@@ -267,9 +331,10 @@ def _walk(body, scalars, fields, pointwise, n_second, *, interpret):
             def _():
                 strips_through(False, True)
 
-        for ref, win in zip(taken, windows):
+        for k, (ref, win) in enumerate(zip(taken, windows)):
             win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
             win[pl.ds(STRIP, tile), :] = ref[...]
+            place(win, STRIP, tile, k)
 
     def block(lag):
         """Tile ``i - lag``, held to the field's own tiles."""
@@ -278,10 +343,16 @@ def _walk(body, scalars, fields, pointwise, n_second, *, interpret):
 
     struct = union_vma_struct(fields[0].shape, dtype, *fields, *scalars)
     in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    # a tile's rows of a slab of columns; a slab of rows whole, once
+    block_of_slab = (
+        pl.BlockSpec((tile, G), lambda i: (lax.min(i, tiles - 1), 0)),
+        pl.BlockSpec((G, lanes), lambda i: (0, 0)))
     results = pl.pallas_call(
         kernel,
         grid=(tiles + 1 + (n_second > 0),),
         in_specs=([in_smem] * n_scalars + [block(0)] * n_fields
+                  + [block_of_slab[of_rows] for sides in came
+                     for of_rows, *_ in sides]
                   + [block(1)] * n_point),
         out_specs=([block(1)] * n_plain + [block(2)] * n_second
                    + [block(1)] * n_point),
@@ -290,13 +361,23 @@ def _walk(body, scalars, fields, pointwise, n_second, *, interpret):
             [pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_fields
             + [pltpu.VMEM((slots * STRIP, lanes), dtype)] * n_second),
         input_output_aliases={
-            n_scalars + k: k for k in range(n_fields + n_point)},
+            **{n_scalars + k: k for k in range(n_fields)},
+            **{n_scalars + n_fields + len(arrived) + k: n_fields + k
+               for k in range(n_point)}},
         compiler_params=pltpu.CompilerParams(
             # in order: a step reads the window the step before left
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*scalars, *fields, *pointwise)
+    )(*scalars, *fields, *arrived, *pointwise)
     return results
+
+
+def _pieces(lo, n, every):
+    """``[lo, lo + n)`` cut at the multiples of ``every``, as ``(start,
+    length)``: the parts of a slab that lie in one vector register, or
+    in one strip."""
+    cuts = [lo, *range(-(-(lo + 1) // every) * every, lo + n, every), lo + n]
+    return [(a, b - a) for a, b in zip(cuts, cuts[1:])]
 
 
 def _walls(is_south, is_north):
@@ -307,11 +388,12 @@ def _walls(is_south, is_north):
     jax.jit,
     static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
                      "coriolis_beta", "interpret"))
-def wide_step(h, u, v, dh, du, dv, is_south, is_north, first_row, a, b,
-              *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
+def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
+              a, b, *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
               interpret=False):
-    """A step of :func:`shallow_water._step_wide` after its first halo
-    exchange, with no second one: the tendencies of ``h``, ``u`` and
+    """A step of :func:`shallow_water._step_wide` after the wire of its
+    first halo exchange, with no second one: the ghost writes of the
+    first, the tendencies of ``h``, ``u`` and
     ``v``, the Adams-Bashforth update ``x += dt * (a * new + b * old)``,
     lateral friction of ``u`` and ``v`` where ``nu > 0``, and ``v = 0``
     on the northern wall row after each round.  What
@@ -320,7 +402,11 @@ def wide_step(h, u, v, dh, du, dv, is_south, is_north, first_row, a, b,
     roundoff (a division by ``dx`` or ``dy`` is a multiplication here).
 
     ``h``, ``u``, ``v``: one device's ``(ny_l + 4, nx_l + 4)`` blocks,
-    both ghost rings fresh.  ``dh``, ``du``, ``dv``: the old tendencies
+    their ghost rings stale wherever ``slabs`` brings them: ``slabs``
+    holds for each the ``(west, east, south, north)`` that
+    ``halo_slabs_2d(x, width=2)`` returned, which the kernel writes
+    over the ghosts as it reads the rows (``None``: those ghosts are
+    fresh as they are).  ``dh``, ``du``, ``dv``: the old tendencies
     **at the same padded shape** (a strip of a field and of an
     interior-shaped tendency would lie two rows and two lanes apart),
     as the last step returned them: ring 1 of ``du`` and ``dv`` holds
@@ -467,5 +553,5 @@ def wide_step(h, u, v, dh, du, dv, is_south, is_north, first_row, a, b,
         return first, second
 
     return _walk(body, [_walls(is_south, is_north), floats], [h, u, v],
-                 [dh, du, dv], n_second=2 if nu > 0 else 0,
+                 slabs, [dh, du, dv], n_second=2 if nu > 0 else 0,
                  interpret=interpret)

@@ -7,7 +7,7 @@ from mpi4jax_tpu.parallel.comm import (
     set_default_comm,
 )
 from mpi4jax_tpu.parallel import distributed
-from mpi4jax_tpu.parallel.halo import halo_exchange_2d
+from mpi4jax_tpu.parallel.halo import halo_exchange_2d, halo_slabs_2d
 from mpi4jax_tpu.parallel.longseq import (
     zigzag_indices,
     zigzag_shard,
@@ -35,6 +35,7 @@ __all__ = [
     "ProcGridComm",
     "grid_comm",
     "halo_exchange_2d",
+    "halo_slabs_2d",
     "local_attention",
     "ring_attention",
     "zigzag_indices",

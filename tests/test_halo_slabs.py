@@ -1,0 +1,125 @@
+"""``halo_slabs_2d`` is ``halo_exchange_2d`` without its last phase: the
+four slabs a block receives, for a caller that places them itself.
+Written into the ghosts in the order they come, they are the exchange's
+result bit for bit, corners included, on every mesh."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mpi4jax_tpu as m
+from mpi4jax_tpu.ops._core import SCOPE_PREFIX
+from mpi4jax_tpu.parallel import halo_slabs_2d
+from mpi4jax_tpu.parallel.halo import halo_exchange_2d
+
+NY, NX = 6, 5  # one device's interior
+
+
+def _comm(mesh_shape):
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=jax.devices()[:py * px])
+    return m.MeshComm.from_mesh(mesh)
+
+
+def _regions(w):
+    """Where the slabs go, in the order they come: west, east, south,
+    north."""
+    return np.s_[:, :w], np.s_[:, -w:], np.s_[:w, :], np.s_[-w:, :]
+
+
+def _program(comm, width, periodic, what):
+    """A program over ``comm``'s mesh of blocks whose every cell, ghosts
+    too, holds a number of its own: ``what`` of a block, stacked."""
+    py, px = comm.axis_sizes
+    shape = (NY + 2 * width, NX + 2 * width)
+
+    def local(start):
+        arr = start[0] + jnp.arange(
+            shape[0] * shape[1], dtype=jnp.float32).reshape(shape)
+        return what(arr, comm, periodic, width)[None]
+
+    starts = 1000.0 * jnp.arange(py * px, dtype=jnp.float32)
+    return jax.shard_map(
+        local, mesh=comm.mesh, in_specs=jax.P(("y", "x")),
+        out_specs=jax.P(("y", "x"))), starts
+
+
+def _exchanged(arr, comm, periodic, width):
+    return halo_exchange_2d(arr, comm, periodic=periodic, width=width)[0]
+
+
+def _placed(arr, comm, periodic, width):
+    slabs, _ = halo_slabs_2d(arr, comm, periodic=periodic, width=width)
+    for slab, region in zip(slabs, _regions(width)):
+        if slab is not None:
+            assert slab.shape == arr[region].shape
+            arr = arr.at[region].set(slab)
+    return arr
+
+
+@pytest.mark.parametrize("width", [1, 2])
+@pytest.mark.parametrize(
+    "periodic", [(False, True), (True, True)], ids=["walls", "torus"])
+@pytest.mark.parametrize(
+    "mesh_shape", [(1, 1), (2, 1), (1, 2), (2, 2), (2, 4)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_slabs_written_in_order_are_the_exchange(mesh_shape, periodic, width):
+    comm = _comm(mesh_shape)
+    want, got = (
+        np.asarray(jax.jit(program)(starts)) for program, starts in
+        (_program(comm, width, periodic, what) for what in (_exchanged, _placed)))
+    # the exchange moved something: a ghost column is a neighbour's (or,
+    # on one device, the block's own far side), corners included
+    before = np.arange(want[0].size, dtype=np.float32).reshape(want[0].shape)
+    assert (want[0][:, :width] != before[:, :width]).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize(
+    "periodic", [(False, True), (True, True), (False, False)],
+    ids=["walls", "torus", "box"])
+@pytest.mark.parametrize(
+    "mesh_shape", [(1, 1), (2, 1), (1, 2), (2, 2)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_the_none_slabs_are_exactly_the_no_op_shifts(mesh_shape, periodic):
+    comm = _comm(mesh_shape)
+    found = []
+
+    def slabs(arr, comm, periodic, width):
+        out, _ = halo_slabs_2d(arr, comm, periodic=periodic, width=width)
+        found.append([slab is None for slab in out])
+        return arr
+
+    program, starts = _program(comm, 2, periodic, slabs)
+    jax.eval_shape(program, starts)
+    py, px = mesh_shape
+    per_y, per_x = periodic
+    # a shift is a no-op on the whole axis where the axis is one device
+    # and does not wrap; the two shifts of an axis go together
+    no_x, no_y = px == 1 and not per_x, py == 1 and not per_y
+    assert found == [[no_x, no_x, no_y, no_y]]
+
+
+def test_the_slabs_lower_under_pack_and_wire_and_nothing_is_unpacked():
+    comm = _comm((2, 2))
+
+    def slabs(arr, comm, periodic, width):
+        out, _ = halo_slabs_2d(arr, comm, periodic=periodic, width=width)
+        return out[2]
+
+    program, starts = _program(comm, 2, (False, True), slabs)
+    text = jax.jit(program).lower(starts).as_text(debug_info=True)
+    op = f"{SCOPE_PREFIX}halo_slabs_2d"
+    scopes = {
+        line.split(op + "/")[1].split("/")[0].split('"')[0]
+        for line in text.splitlines() if op + "/" in line}
+    assert scopes == {"pack", "wire"}
+    assert f"{op}/wire/{SCOPE_PREFIX}sendrecv" in text
+    assert "/unpack" not in text
+    # and the exchange beside it still has its three
+    program, starts = _program(comm, 2, (False, True), _exchanged)
+    text = jax.jit(program).lower(starts).as_text(debug_info=True)
+    assert f"{SCOPE_PREFIX}halo_exchange_2d/unpack" in text

@@ -97,18 +97,21 @@ def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
 
 def _kernels(text):
     """The Pallas calls of a compiled program's text: name -> (line,
-    the operands that are fields, the kernel's own text)."""
+    the operands that are fields, the kernel's own text, the fields'
+    places among the operands).  A field is an operand of the shape of
+    the call's first result; the slabs and the scalars are not."""
     found = {}
     for line in text.splitlines():
         if "tpu_custom_call" in line:
             name = line.split("=")[0].split("%")[1].split(".")[0]
+            shape = re.search(r"= \(?(\w+\[[\d,]*\])", line)[1]
             operands = line.split("custom-call(")[1].split(")")[0]
             operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
             layouts = line.split("operand_layout_constraints={")[1].split("}}")[0]
-            fields = [op for op, layout in zip(operands, layouts.split("}, "))
-                      if "," in layout.split("[")[1].split("]")[0]]
+            places = [k for k, layout in enumerate(layouts.split("}, "))
+                      if layout.startswith(shape)]
             body = line.split('"custom_call_config":{"body":"')[1].split('"')[0]
-            found[name] = line, fields, body
+            found[name] = line, [operands[k] for k in places], body, places
     return found
 
 
@@ -139,8 +142,9 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     assert sorted(kernels) == (["wide_step"] if ghost == 2 else [])
     assert text.count("tpu_custom_call") == (ghost == 2)  # one a step
     if kernels:
-        line, fields, _ = kernels["wide_step"]
-        aliasing = ", ".join(f"{{{k}}}: ({2 + k}, {{}})" for k in range(6))
+        line, fields, _, places = kernels["wide_step"]
+        aliasing = ", ".join(
+            f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
         assert f"output_to_operand_aliasing={{{aliasing}}}" in line
         assert len(fields) == 6 and not _copied(text, fields), fields
     mem = compiled.memory_analysis()
@@ -160,6 +164,72 @@ def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     assert sorted(first) == sorted(rest) == ["wide_step"]
     assert first["wide_step"][2] == rest["wide_step"][2]
     assert not _copied(texts[0], first["wide_step"][1]), first["wide_step"][1]
+
+
+def _step_body(text):
+    """The 10-step program's loop body: ``(instructions, types)``, the
+    instructions as ``(name, opcode, operand names, line)`` without
+    those that move nothing (parameters, tuples and their elements,
+    constants, bitcasts), the types by name."""
+    body = re.search(r"\bwhile\(.*?body=%([\w.\-]+)", text)[1]
+    lines = re.search(
+        rf"^%{re.escape(body)} \(.*?^\}}", text, re.S | re.M)[0].splitlines()
+    found, types = [], {}
+    for line in lines[1:-1]:
+        name, rest = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*)", line).groups()
+        types[name], opcode, operands = re.match(
+            r"(.*?) ([a-z][a-z\-]*)\((.*?)\)(?:, |$)", rest).groups()
+        if opcode not in ("parameter", "tuple", "get-tuple-element",
+                          "constant", "bitcast"):
+            found.append(
+                (name, opcode, re.findall(r"%([\w.\-]+)", operands), line))
+    return found, types
+
+
+# what the step's loop body holds that moves or computes something.  One
+# chip: the loop's counter, three fusions of two slices (a field's sent
+# columns), a scalar's broadcast and the kernel
+STEP_INSTRUCTIONS = {(1, 1): 6, (2, 2): 99}
+
+
+@pytest.mark.parametrize("mesh_shape", sorted(STEP_INSTRUCTIONS))
+def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
+    """The exchange hands the kernel its slabs and writes none: beside
+    the one call a step, nothing updates or scatters into a field on
+    any mesh, and the six arrays of the state are the call's own
+    operands, aliased to its results and no copies."""
+    chips = mesh_shape[0] * mesh_shape[1]
+    text = _compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
+    body, types = _step_body(text)
+
+    def on_a_field(opcode):
+        return [name for name, op, operands, _ in body if op == opcode and any(
+            types[x].startswith("f32[1804,3604]") for x in operands)]
+
+    assert not on_a_field("dynamic-update-slice") and not on_a_field("scatter")
+    # a copy of a field: none on one chip.  On four XLA wants the
+    # permutes' column slabs lane-dense and gets them by transposing
+    # the block they are sliced from, once a field, as it did before the
+    # kernel took the slabs: what is left of the exchange there
+    # (PERF.md section 7)
+    transposes = on_a_field("copy")
+    assert len(transposes) == (0 if chips == 1 else 3)
+    assert all(types[name].startswith("f32[1804,3604]{0,1") for name in transposes)
+    calls = [line for *_, line in body if "tpu_custom_call" in line]
+    assert len(calls) == 1  # one a step
+    (line, fields, _, places), = _kernels(text).values()
+    assert line == calls[0]
+    # the state's arrays stand round the slabs: two a field on one chip
+    # (the y shifts move nothing), four on four
+    slabs = 3 * (2 if chips == 1 else 4)
+    assert places == [2, 3, 4, *(5 + slabs + k for k in range(3))]
+    aliasing = ", ".join(
+        f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
+    assert f"output_to_operand_aliasing={{{aliasing}}}" in line
+    assert len(fields) == 6 and not _copied(text, fields), fields
+    opcodes = [opcode for _, opcode, *_ in body]
+    assert ("collective-permute-start" in opcodes) == (chips > 1)
+    assert len(body) == STEP_INSTRUCTIONS[mesh_shape], opcodes
 
 
 @pytest.mark.parametrize("n", [1, 4])
@@ -202,12 +272,24 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
     text = _compiled_multistep(v5e, mesh_shape, 2, 180, 360, 10).as_text()
     table = scopes.origins(text)
     lines = dict(re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", text, re.M))
-    halo = "mpi4jax_tpu.halo_exchange_2d"
+    # the step's exchange has no third phase: its kernel writes the ghosts
+    halo = "mpi4jax_tpu.halo_slabs_2d"
     phases = {o.scopes[1] for o in table.values() if len(o.scopes) > 1}
-    assert {"pack", "unpack"} <= phases <= {"pack", "wire", "unpack"}
-    writes = [o for o in table.values() if o.scopes == (halo, "unpack")]
-    assert {o.source.split(":")[0] for o in writes} == {
+    assert phases == ({"pack"} if py * px == 1 else {"pack", "wire"})
+    packs = [o for o in table.values() if o.scopes == (halo, "pack")]
+    assert {o.source.split(":")[0] for o in packs} == {
         "mpi4jax_tpu/parallel/halo.py"}
+    # the exchange that writes them (the benchmark's halo row, the
+    # solver's initial state) has all three
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px])
+    init = sw.make_init(
+        sw.SWConfig(ny=180 * py, nx=360 * px, ghost=2), m.MeshComm.from_mesh(mesh))
+    written = scopes.origins(init.lower().compile().as_text())
+    assert {("mpi4jax_tpu.halo_exchange_2d", phase)
+            for phase in ("pack", "unpack")} <= {
+        o.scopes for o in written.values()}
     # what the model's step leaves beside its kernel (the scalars it is
     # handed, its results taken apart; the call's own line the reader
     # does not find yet, PERF.md section 7) is the programs', not the
