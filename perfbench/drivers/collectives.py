@@ -12,6 +12,7 @@ host sync.  The last result of every row is compared, after the window,
 with the numpy reference, bit for bit.
 """
 
+import functools
 import statistics
 import time
 
@@ -25,6 +26,7 @@ from perfbench.harness.spans import ENQUEUE, SYNC, span
 
 AXES = ("y", "x")
 BLOCK = 1 << 18  # elements of one sampled block: 1 MiB
+ROLE_PAIRS, TABLE_PAIRS = 5, 3  # a probe's batches of library and plain
 
 
 def payload_bytes(row, n):
@@ -69,12 +71,66 @@ def library_op(row, comm):
     raise ValueError(f"no program for op {op!r}")
 
 
-def plain_op(row):
+def plain_op(row, grid):
     """The same call without the library, for the tax: jax's own
-    collective, as a user would write it by hand."""
-    if row["op"] == "allreduce":
+    collective, as a user would write it by hand inside the same
+    ``shard_map`` over the ``grid`` of chips.  Imports nothing of
+    ``mpi4jax_tpu``.  ``bcast`` is the hand-written idiom, a ``psum`` of
+    the payload masked to the root, which is the library's own schedule
+    too; ``halo`` is :func:`plain_halo`."""
+    op, n = row["op"], grid[0] * grid[1]
+    if op == "allreduce":
         return lambda x: lax.psum(x, AXES)
-    raise ValueError(f"no plain program for op {row['op']!r}")
+    if op == "allgather":
+        return lambda x: lax.all_gather(x, AXES)
+    if op == "alltoall":
+        return lambda x: lax.all_to_all(x, AXES, 0, 0)
+    if op == "bcast":
+        return lambda x: lax.psum(
+            jnp.where(lax.axis_index(AXES) == row["root"], x, 0.0), AXES)
+    if op == "sendrecv":
+        ring = [(r, (r + row["shift"]) % n) for r in range(n)]
+        return lambda x: lax.ppermute(x, AXES, ring)
+    if op == "halo":
+        return plain_halo(grid, row["width"], tuple(row["periodic"]))
+    raise ValueError(f"no plain program for op {op!r}")
+
+
+def plain_halo(grid, width, periodic):
+    """A halo exchange written by hand on a ``(y, x)`` grid of chips: the
+    two slabs of interior cells beside the ghost ring sliced with
+    ``jnp``, moved one chip along the axis by ``lax.ppermute`` and
+    written over the ghosts with ``lax.dynamic_update_slice``; x first
+    and then y over the full rows, so the corners arrive through the
+    second exchange.  A block at a wall keeps its ghost cells."""
+    w = width
+
+    def shifted(slab, kept, axis, size, disp, wraps):
+        """Every chip's ``slab`` from its neighbour ``disp`` chips back
+        along ``axis``; a chip with no such neighbour keeps ``kept``."""
+        pairs = [(i, (i + disp) % size) for i in range(size)
+                 if wraps or 0 <= i + disp < size]
+        if not pairs:
+            return kept
+        got = lax.ppermute(slab, axis, pairs)
+        if wraps:
+            return got
+        source = lax.axis_index(axis) - disp
+        return jnp.where((source >= 0) & (source < size), got, kept)
+
+    def exchange(x):
+        (py, px), (per_y, per_x) = grid, periodic
+        ny, nx = x.shape
+        west = shifted(x[:, nx - 2 * w:nx - w], x[:, :w], "x", px, 1, per_x)
+        east = shifted(x[:, w:2 * w], x[:, nx - w:], "x", px, -1, per_x)
+        x = lax.dynamic_update_slice(x, west, (0, 0))
+        x = lax.dynamic_update_slice(x, east, (0, nx - w))
+        south = shifted(x[ny - 2 * w:ny - w, :], x[:w, :], "y", py, 1, per_y)
+        north = shifted(x[w:2 * w, :], x[ny - w:, :], "y", py, -1, per_y)
+        x = lax.dynamic_update_slice(x, south, (0, 0))
+        return lax.dynamic_update_slice(x, north, (ny - w, 0))
+
+    return exchange
 
 
 def chained(op, reps, mesh, spec):
@@ -122,6 +178,7 @@ class Session:
             for name, row in self.rows.items()
         }
         self.last = {}
+        self.plain_checks = []  # a probe's comparisons, for check()
         for name in self.rows:  # warm up every program the window drives
             self.batch(name)
         print(f"perfbench: set-up: payloads {t1 - t0:.3f} s, compiling and "
@@ -161,6 +218,8 @@ class Session:
     # -- the window ----------------------------------------------------
 
     def batch(self, name, program=None):
+        """One batch of ``name``; through ``program`` (a probe's plain
+        one) the result is handed back and not kept as the row's last."""
         if program is None:
             self.last.pop(name, None)  # one result buffer a row, not two
         with span(ENQUEUE):
@@ -170,6 +229,7 @@ class Session:
             jax.block_until_ready(y)
         if program is None:
             self.last[name] = y
+        return y
 
     def units(self, name):
         """Calls in one batch of ``name``."""
@@ -199,6 +259,9 @@ class Session:
                  for s in samples if s.row == roles["latency"]]
         if small:
             out["coll_lat_p95_us"] = stats.percentile(small, 95)
+        table = [self.per_call(samples, name) for name in roles.get("table", ())]
+        if table and all(table):  # never a mean over fewer rows
+            out["coll_table_geomean_us"] = statistics.geometric_mean(table) * 1e6
         for name in self.rows:
             t = self.per_call(samples, name)
             if t:
@@ -212,40 +275,67 @@ class Session:
     def layer_probe(self):
         """After a traced window: for the rows whose tax is asked for,
         the library's program and jax's plain collective in the same
-        chained harness, in turn, five batches each."""
-        out = {}
-        for role, name in self.ctx.workload["roles"].items():
-            row = self.rows[name]
-            plain = self._program(row, plain_op(row))
-            self.batch(name, plain)  # compiles: outside the window
-            times = {"library": [], "plain": []}
-            for _ in range(5):
-                for kind, program in (("library", None), ("plain", plain)):
-                    t0 = time.perf_counter()
-                    self.batch(name, program)
-                    times[kind].append(time.perf_counter() - t0)
-            out[role] = {k: statistics.median(v) / row["reps"] for k, v in times.items()}
-            print(f"perfbench: tax {name}: {out[role]}", flush=True)
+        chained harness, in turn: five batches each for a role's one
+        row, under the role's name, and three for every row of a role
+        that lists rows, under ``rows``.  A plain program whose result
+        is not the reference's gives no times, and fails the run."""
+        out = {"rows": {}}
+        for role, names in self.ctx.workload["roles"].items():
+            if isinstance(names, str):
+                out[role] = self._tax(names, ROLE_PAIRS)
+            else:
+                out["rows"].update(
+                    (name, self._tax(name, TABLE_PAIRS)) for name in names)
         return out
+
+    def _tax(self, name, pairs):
+        """Seconds a call of the row ``name``, ``library`` and ``plain``:
+        the medians of ``pairs`` batches of each, taken in turn."""
+        row = self.rows[name]
+        plain = self._program(row, plain_op(row, self.grid))
+        y = self.batch(name, plain)  # compiles: outside the window
+        wrong = self._mismatches(name, row, y)
+        del y
+        limit = self.ctx.config["check"]["limits"]["mismatches"]
+        self.plain_checks.append(
+            {"name": f"plain_mismatches_{name}", "value": wrong, "limit": limit})
+        if wrong > limit:
+            return None
+        times = {"library": [], "plain": []}
+        for _ in range(pairs):
+            for kind, program in (("library", None), ("plain", plain)):
+                t0 = time.perf_counter()
+                self.batch(name, program)
+                times[kind].append(time.perf_counter() - t0)
+        pair = {k: statistics.median(v) / row["reps"] for k, v in times.items()}
+        print(f"perfbench: tax {name}: {pair}", flush=True)
+        return pair
 
     # -- after the window ----------------------------------------------
 
     def check(self, carry=None):
         """Every row's last result against the numpy reference, bit for
-        bit.  With ``carry``, the reference carried in that precision
-        stands in the program's place: the control."""
-        ref = files.load_module(
-            "references", self.ctx.config["reference"], self.ctx.bench_dir)
+        bit, and after a probe every plain program's too.  With
+        ``carry``, the reference carried in that precision stands in the
+        library's place: the control."""
         limit = self.ctx.config["check"]["limits"]["mismatches"]
-        out = []
-        for name, row in self.rows.items():
-            x, y = self._to_host(name, row)
-            if carry:
-                y = ref.expected(row, x, self.grid, carry)
-            want = ref.expected(row, x, self.grid)
-            out.append({"name": f"mismatches_{name}",
-                        "value": ref.mismatches(y, want), "limit": limit})
-        return out
+        out = [{"name": f"mismatches_{name}", "limit": limit,
+                "value": self._mismatches(name, row, self.last[name], carry)}
+               for name, row in self.rows.items()]
+        return out if carry else out + self.plain_checks
+
+    @functools.cached_property
+    def ref(self):
+        return files.load_module(
+            "references", self.ctx.config["reference"], self.ctx.bench_dir)
+
+    def _mismatches(self, name, row, y, carry=None):
+        """Elements of ``y``, a result of the row ``name``, that differ
+        from the reference's for the row's input."""
+        x, y = self._to_host(name, row, y)
+        if carry:
+            y = self.ref.expected(row, x, self.grid, carry)
+        return self.ref.mismatches(y, self.ref.expected(row, x, self.grid))
 
     def control(self):
         """The nearest precision below the configuration's float32:
@@ -253,11 +343,11 @@ class Session:
         correct."""
         return self.check(carry="bfloat16")
 
-    def _to_host(self, name, row):
-        """Every rank's input and last result of a row on the host; for
+    def _to_host(self, name, row, y):
+        """Every rank's input and the result ``y`` of a row on the host; for
         a row with ``sample_blocks``, that many 1 MiB blocks of each,
         at places drawn from the seed (an elementwise op only)."""
-        x, y = self.inputs[name], self.last[name]
+        x = self.inputs[name]
         blocks = row.get("sample_blocks")
         if blocks:
             if row["op"] != "allreduce":

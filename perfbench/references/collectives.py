@@ -78,7 +78,10 @@ def expected(row, x, grid, carry="float32"):
 
 
 def mismatches(got, want):
-    """Elements that differ bit for bit."""
+    """Elements that differ bit for bit; all of them where the shapes
+    differ (a gather that gathered nothing would broadcast to a match)."""
+    if np.shape(got) != np.shape(want):
+        return int(np.size(want))
     got = np.ascontiguousarray(got, np.float32).view(np.uint32)
     want = np.ascontiguousarray(want, np.float32).view(np.uint32)
     return int(np.count_nonzero(got != want))

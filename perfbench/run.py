@@ -272,6 +272,10 @@ def run_cell(args, devices, root=files.ROOT, bench_dir=files.BENCH_DIR):
                 metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
     else:
         for entry in files.metrics_of(benchmark, "end_to_end", cell["name"]):
+            if entry["name"] not in end_to_end:
+                raise RuntimeError(
+                    f"the window gave no {entry['name']}: a row it is taken "
+                    "over has no batch there; no result")
             metrics[entry["name"]] = {
                 "value": end_to_end[entry["name"]], "unit": entry["unit"]}
     for name, value in sorted(end_to_end.items()):
@@ -282,14 +286,17 @@ def run_cell(args, devices, root=files.ROOT, bench_dir=files.BENCH_DIR):
     print(f"perfbench: the comparison took {time.perf_counter() - t_check:.3f} s",
           flush=True)
     correct = failed == 0 and compiled == 0 and bool(samples)
+    compared = {}  # every number beside its limit: the line's last key
     for c in checks:
         ok = c["value"] <= c["limit"]
         correct = correct and ok
+        compared[c["name"]] = {"value": c["value"], "limit": c["limit"]}
         print(f"perfbench: check {c['name']}: {c['value']!r} against the "
-              f"limit {c['limit']!r}: {'ok' if ok else 'NOT CORRECT'}", flush=True)
+              f"limit {c['limit']!r}: {'ok' if ok else 'NOT CORRECT'}",
+              file=sys.stderr, flush=True)
     return {"correct": correct,
             "attempted": len(samples) + len(traced) + failed, "failed": failed,
-            "metrics": metrics, "device": device, **extra}
+            "metrics": metrics, "device": device, **extra, "checks": compared}
 
 
 def main(argv=None):

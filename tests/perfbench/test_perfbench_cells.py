@@ -19,9 +19,11 @@ from perfbench.harness.compilemeter import CompileMeter
 
 from perfbench_fixtures import ROOT, cell_args, make_copy
 
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 TOY_CELLS = ["sw-toy-1x1", "sw-toy-2x2", "coll-toy"]
+TABLE_ROWS = ["allreduce-4MiB", "allreduce-64MiB", "allgather-4MiB", "alltoall-4MiB",
+              "bcast-4MiB", "sendrecv-4MiB", "halo-1804x3604"]
 
 
 @pytest.fixture(scope="module")
@@ -58,9 +60,13 @@ def test_a_cell_added_as_new_files_runs_and_is_correct(copy, cell, capsys):
     assert set(result["metrics"]) == wanted and "setup_s" in wanted
     assert all(set(v) == {"value", "unit"} and v["value"] > 0
                for v in result["metrics"].values())
-    # every number compared is printed beside its limit
-    out = capsys.readouterr().out
-    assert "against the limit" in out and "NOT CORRECT" not in out
+    # every number compared is printed beside its limit: last on standard
+    # error, and under the line's last key
+    err = capsys.readouterr().err.splitlines()
+    assert list(result)[-1] == "checks" and result["checks"]
+    assert len(err) >= len(result["checks"])
+    for line, (name, c) in zip(err[-len(result["checks"]):], result["checks"].items()):
+        assert f"check {name}: {c['value']!r} against the limit {c['limit']!r}: ok" in line
     json.dumps(result)
 
 
@@ -81,12 +87,14 @@ def recorded_trace(monkeypatch):
                     "device_idle_share.sw"}, "coll_row_busbw"),
     ("coll-toy", {"compile_s", "setup_after_chips_s", "allreduce_tax_large",
                   "allreduce_tax_small", "coll_row_busbw",
-                  "device_idle_share.coll"}, "toy_batches"),
+                  "device_idle_share.coll", "coll_table_tax"}
+     | {f"row_tax.{row}" for row in TABLE_ROWS}, "toy_batches"),
 ])
 def test_a_traced_run_reports_the_cells_per_layer_metrics(
         copy, recorded_trace, cell, has, lacks):
     result = _run(copy, cell, trace=1)
     assert set(result) == LINE_KEYS | {"breakdown"}
+    assert list(result)[-1] == "checks"
     assert set(result["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
     assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
     assert set(result["metrics"]) == has and lacks not in result["metrics"]
@@ -119,6 +127,169 @@ def test_an_allreduce_that_leaves_out_the_exchange_is_not_correct(copy, monkeypa
     monkeypatch.setattr(m, "allreduce", lambda x, op, comm=None: (x, None))
     result = _run(copy, "coll-toy")
     assert result["correct"] is False
+
+
+# -- the table's other rows: their mean, and every row's plain program --
+
+
+@pytest.fixture(scope="module")
+def coll_session(copy):
+    return _session(copy, "coll-toy")
+
+
+def _batches(session, seconds_a_call):
+    """Two batches a row whose times a call average to the given ones."""
+    return [run.Sample(name, 0.0, per_call * share * session.units(name))
+            for name, per_call in seconds_a_call.items() for share in (0.5, 1.5)]
+
+
+def test_the_tables_geometric_mean_on_hand_made_samples(coll_session):
+    table = coll_session.ctx.workload["roles"]["table"]
+    assert table == TABLE_ROWS
+    made = {name: 2.0 ** (i - 20) for i, name in enumerate(table)}  # 1 .. 64 x 2**-20 s
+    made["allreduce-2GiB"] = made["allreduce-8B"] = 1.0  # no row of the table
+    got = coll_session.end_to_end(_batches(coll_session, made))
+    # the middle row's time, not the arithmetic mean, which the longest row owns
+    assert got["coll_table_geomean_us"] == pytest.approx(8 * 2.0 ** -20 * 1e6, rel=1e-14)
+
+
+@pytest.mark.parametrize("missing", ["allreduce-64MiB", "halo-1804x3604"])
+def test_a_table_row_with_no_sample_leaves_the_mean_out(coll_session, missing):
+    made = {name: 1e-5 for name in coll_session.rows if name != missing}
+    got = coll_session.end_to_end(_batches(coll_session, made))
+    assert "coll_table_geomean_us" not in got
+    assert {"coll_busbw", "coll_lat_p95_us"} <= set(got)
+
+
+def test_a_table_row_with_no_slot_fails_the_run(tmp_path):
+    """A cell added as new files whose table lists a row that never
+    comes: no mean over fewer rows, and no result line."""
+    root, bench = make_copy(tmp_path)
+    cell = json.loads((bench / "workloads/coll-toy.json").read_text())
+    cell["traffic"] = "coll-toy-gap"
+    for row in cell["rows"]:
+        if row["name"] == "bcast-4MiB":
+            row["slots"] = 0
+    (bench / "workloads/coll-toy-gap.json").write_text(json.dumps(cell))
+    benchmark = json.loads((root / "BENCHMARK.json").read_text())
+    benchmark["workloads"].append(
+        {k: cell[k] for k in ("config", "traffic", "chips", "why")}
+        | {"name": "coll-toy-gap"})
+    for metric in benchmark["end_to_end"]:
+        if "coll-toy" in metric.get("workloads", []):
+            metric["workloads"].append("coll-toy-gap")
+    (root / "BENCHMARK.json").write_text(json.dumps(benchmark))
+    with pytest.raises(RuntimeError, match="no coll_table_geomean_us"):
+        run.run_cell(cell_args("coll-toy-gap"), jax.devices(), root=root,
+                     bench_dir=bench)
+
+
+def _plain_against_the_reference(session, row):
+    """Mismatches of the row's plain program, chained as the probe
+    chains it, with the numpy reference."""
+    driver = files.load_module("drivers", "collectives")
+    plain = session._program(row, driver.plain_op(row, session.grid))
+    y = session.batch(row["name"], plain)
+    assert y is not session.last[row["name"]]
+    return session._mismatches(row["name"], row, y)
+
+
+@pytest.mark.parametrize("name", ["allreduce-8B", "allreduce-2GiB"] + TABLE_ROWS)
+def test_every_rows_plain_program_is_the_reference_bit_for_bit(coll_session, name):
+    row = coll_session.rows[name]
+    assert _plain_against_the_reference(coll_session, row) == 0
+    # and the comparison tells the result from the input it was made of
+    x, _ = coll_session._to_host(name, row, coll_session.inputs[name])
+    assert coll_session.ref.mismatches(
+        x, coll_session.ref.expected(row, x, coll_session.grid)) > 0
+
+
+@pytest.mark.parametrize("periodic", [
+    [False, True], [True, False], [False, False], [True, True]])
+@pytest.mark.parametrize("width", [1, 2])
+def test_the_plain_halo_on_periodic_and_walled_axes(coll_session, periodic, width):
+    row = dict(coll_session.rows["halo-1804x3604"], periodic=periodic, width=width)
+    assert _plain_against_the_reference(coll_session, row) == 0
+
+
+@pytest.mark.parametrize("grid", [(2, 4), (4, 2), (1, 8), (8, 1)])
+@pytest.mark.parametrize("periodic", [[False, True], [True, False]])
+def test_the_plain_halo_on_grids_with_chips_between_walls(grid, periodic, coll_reference):
+    """Chips with a neighbour on both sides of a walled axis, and an
+    axis of one chip: ``plain_halo`` by itself on the 8 virtual devices."""
+    driver = files.load_module("drivers", "collectives")
+    py, px = grid
+    mesh = jax.make_mesh(grid, driver.AXES, devices=jax.devices()[:py * px],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    ly, lx, w = 10, 12, 2
+    rng = np.random.default_rng(7)
+    whole = rng.integers(-9, 9, (py * ly, px * lx)).astype(np.float32)
+    spec = jax.P(*driver.AXES)
+    got = jax.jit(jax.shard_map(
+        driver.plain_halo(grid, w, tuple(periodic)), mesh=mesh,
+        in_specs=spec, out_specs=spec))(whole)
+
+    def blocks(a):
+        return (np.asarray(a).reshape(py, ly, px, lx).transpose(0, 2, 1, 3)
+                .reshape(py * px, ly, lx))
+
+    want = coll_reference.expected(
+        {"op": "halo", "width": w, "periodic": periodic}, blocks(whole), grid)
+    assert coll_reference.mismatches(blocks(got), want) == 0
+    assert coll_reference.mismatches(blocks(got), blocks(whole)) > 0
+
+
+BROKEN_PLAIN = {
+    # a ring shift that leaves out the exchange
+    "sendrecv-4MiB": ("lax.ppermute(x, AXES, ring)", "x * 1.0"),
+    # a halo exchange that forgets the walls: their ghosts take the
+    # zeros a permute gives a chip nobody sends to
+    "halo-1804x3604": (
+        "jnp.where((source >= 0) & (source < size), got, kept)", "got"),
+}
+
+
+@pytest.mark.parametrize("row", sorted(BROKEN_PLAIN))
+def test_a_plain_program_made_wrong_fails_the_probe_and_the_run(
+        tmp_path, recorded_trace, row):
+    root, bench = make_copy(tmp_path)
+    path = bench / "drivers/collectives.py"
+    sound, broken = BROKEN_PLAIN[row]
+    assert path.read_text().count(sound) == 1
+    path.write_text(path.read_text().replace(sound, broken))
+    result = run.run_cell(cell_args("coll-toy", trace=1), jax.devices(),
+                          root=root, bench_dir=bench)
+    assert result["correct"] is False and result["failed"] == 0
+    wrong = {k for k, c in result["checks"].items() if c["value"] > c["limit"]}
+    assert wrong == {f"plain_mismatches_{row}"}
+    # no time of a wrong program counts: its tax and the table's are left out
+    taxes = {f"row_tax.{r}" for r in TABLE_ROWS}
+    assert taxes - set(result["metrics"]) == {f"row_tax.{row}"}
+    assert "coll_table_tax" not in result["metrics"]
+    assert {"allreduce_tax_large", "allreduce_tax_small"} <= set(result["metrics"])
+
+
+def test_a_probe_compares_every_plain_program_and_reads_every_tax(
+        copy, recorded_trace):
+    result = _run(copy, "coll-toy", trace=1)
+    rows = ["allreduce-8B", "allreduce-2GiB"] + TABLE_ROWS
+    assert {k for k in result["checks"] if k.startswith("plain_")} == {
+        f"plain_mismatches_{r}" for r in rows}
+    assert all(c == {"value": 0, "limit": 0} for c in result["checks"].values())
+    values = {k: v["value"] for k, v in result["metrics"].items()
+              if k.startswith("row_tax.")}
+    assert set(values) == {f"row_tax.{r}" for r in TABLE_ROWS}
+    assert all(v > 0 for v in values.values())
+    # the table's tax is a mean of the rows' ratios, so it lies among them
+    assert min(values.values()) <= result["metrics"]["coll_table_tax"]["value"] <= max(
+        values.values())
+
+
+def test_the_control_fails_every_row_of_the_table(coll_session):
+    control = coll_session.control()
+    assert [c["name"] for c in control] == [
+        f"mismatches_{name}" for name in coll_session.rows]
+    assert all(c["value"] > c["limit"] for c in control), control
 
 
 def test_a_compilation_inside_the_window_is_counted():
@@ -363,6 +534,8 @@ def test_collectives_reference_halo(coll_reference):
     assert top_left[-1, -1] == 4  # the corner arrives through the second exchange
     assert coll_reference.mismatches(out, out) == 0
     assert coll_reference.mismatches(out, out + 1) == out.size
+    # a result of another shape never broadcasts to a match
+    assert coll_reference.mismatches(out[0], np.stack([out[0]] * 4)) == 4 * out[0].size
 
 
 def test_payloads_sum_exactly_in_float32():

@@ -83,6 +83,6 @@ def test_every_name_in_benchmark_json_has_its_file():
 def test_metrics_of_a_cell():
     benchmark = files.load_benchmark()
     names = {m["name"] for m in files.metrics_of(benchmark, "end_to_end", "coll-2x2")}
-    assert names == {"coll_busbw", "coll_lat_p95_us", "setup_s"}
+    assert names == {"coll_busbw", "coll_lat_p95_us", "coll_table_geomean_us", "setup_s"}
     layer = {m["name"] for m in files.metrics_of(benchmark, "per_layer", "sw-bench-1chip")}
     assert "compile_s" in layer and "allreduce_tax_large" not in layer

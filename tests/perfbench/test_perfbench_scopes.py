@@ -319,7 +319,7 @@ def test_the_tables_reader_reports_nothing_without_scopes(table, capsys):
         programs={"allreduce-4MiB": session._program(
             session.rows["allreduce-4MiB"],
             files.load_module("drivers", "collectives").plain_op(
-                session.rows["allreduce-4MiB"]))})
+                session.rows["allreduce-4MiB"], session.grid))})
     text = bare.programs["allreduce-4MiB"].lower(
         session.inputs["allreduce-4MiB"]).compile().as_text()
     event = _pick(text, lambda o, line: scopes.is_collective(line))
