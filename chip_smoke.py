@@ -69,6 +69,9 @@ TOL_SAME_ARITHMETIC = 1e-3
 # a cumulative sum down 1800 rows whose rounding depends on the scan
 # algorithm.
 TOL_CPU_REFERENCE = 2e-3
+# a snapshot against the block mean of the state it was taken from: a
+# float32 sum of 16 depths of 100 m against the same sum in float64
+TOL_BLOCK_MEAN = 1e-4
 
 # Worker of the staged phase, shared with
 # tests/proc/test_staged_backend.py: proc-backend ops on arrays that live
@@ -482,6 +485,96 @@ def solver_invariance_check(cfg, devices, *, steps_per_call=25,
     }
     if diff > TOL_SAME_ARITHMETIC:
         raise AssertionError(f"decomposition changes the answer: {out}")
+    return out
+
+
+def _job(cfg, devices, mesh_shape, snapshot, steps_per_call=10, calls=4):
+    """``make_init`` -> ``job.start`` -> ``calls`` calls through
+    ``make_job``; returns the job and the snapshots its callback was
+    handed, as ``[(step, {name: array})]``."""
+    import jax
+
+    import mpi4jax_tpu as m
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=_auto(2), devices=devices
+    )
+    comm = m.MeshComm.from_mesh(mesh)
+    got = []
+    job = sw.make_job(
+        cfg, comm, steps_per_call, snapshot,
+        snapshot and (lambda fields, step: got.append((step, fields))),
+    )
+    job.start(sw.make_init(cfg, comm)())
+    job.advance(calls)
+    job.drain()
+    return job, got
+
+
+def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
+                     steps_per_call=10, calls=4):
+    """The solver as a job that writes output (``make_job``): on the
+    first mesh a job with output returns bit for bit the state of a job
+    without (every call donates its input either way); every snapshot
+    comes once, in step order; the last is the block mean of the state
+    the job returns; and on every further mesh the snapshots are the
+    first mesh's to the rounding of another decomposition."""
+    import numpy as np
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    snapshot = sw.Snapshot(coarsen=coarsen, lag=2)
+    steps = [1 + steps_per_call * (k + 1) for k in range(calls)]
+    runs = []
+    for shape in mesh_shapes:
+        n = shape[0] * shape[1]
+        job, got = _job(cfg, devices[:n], shape, snapshot, steps_per_call, calls)
+        if [step for step, _ in got] != steps:
+            raise AssertionError(
+                f"{shape}: snapshots of steps {[s for s, _ in got]}, "
+                f"wanted {steps}")
+        stats = job.stats()
+        if stats["max_lag"] > snapshot.lag:
+            raise AssertionError(f"{shape}: a snapshot came late: {stats}")
+        runs.append((shape, job, got))
+    shape, job, got = runs[0]
+    quiet, _ = _job(cfg, devices[:shape[0] * shape[1]], shape, None,
+                    steps_per_call, calls)
+    for name, a, b in zip(job.state._fields, job.state, quiet.state):
+        if not np.array_equal(np.asarray(a), np.asarray(b)):
+            raise AssertionError(
+                f"{shape}: state.{name} of a job with output differs from "
+                "that of a job without")
+    c = coarsen
+    last = 0.0
+    for k, mine in got[-1][1].items():
+        whole = _interior(getattr(job.state, k), cfg.ghost, shape)
+        ny, nx = whole.shape
+        want = whole.reshape(ny // c, c, nx // c, c).mean(
+            axis=(1, 3), dtype=np.float64)
+        last = max(last, float(np.abs(mine - want).max()))
+    across = max(
+        (float(np.abs(a[k] - b[k]).max())
+         for _, _, other in runs[1:]
+         for (_, a), (_, b) in zip(got, other) for k in a),
+        default=0.0)
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost} as a job, {calls} "
+        f"calls of {steps_per_call} steps, h,u,v averaged {c}x{c} after "
+        f"each: with output bit for bit the state without; the last "
+        f"snapshot with the returned state's block mean (tol "
+        f"{TOL_BLOCK_MEAN}); snapshots on "
+        f"{' and '.join('x'.join(map(str, s)) for s in mesh_shapes)} "
+        f"(tol {TOL_SAME_ARITHMETIC})",
+        "last_snapshot_max_diff": last,
+        "meshes_max_diff": across,
+        "max_diff": max(last, across),
+    }
+    if last > TOL_BLOCK_MEAN:
+        raise AssertionError(f"the last snapshot is not the state's: {out}")
+    if across > TOL_SAME_ARITHMETIC:
+        raise AssertionError(f"decomposition changes the snapshots: {out}")
     return out
 
 
@@ -994,7 +1087,10 @@ def _solver():
 GROUPS = {
     1: {
         "staged": (420, {"staged": lambda: staged_check("tpu")}),
-        "solver": (420, {"solver": _solver}),
+        "solver": (420, {
+            "solver": _solver,
+            "solver.job": lambda: solver_job_check(_bench_cfg(), _one()),
+        }),
         "ops": (300, {
             "ops": lambda: ops_check(_one()),
             "ops.grad": lambda: grad_check(_one()),
@@ -1016,6 +1112,9 @@ GROUPS = {
             "solver4.weak": lambda: solver_weak_check(_bench_cfg(), _all()),
             "solver4.invariance": lambda: solver_invariance_check(
                 _bench_cfg(), _all()
+            ),
+            "solver4.job": lambda: solver_job_check(
+                _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
         }),
         "ops4": (300, {"ops4": lambda: ops_check(_all()[:4])}),

@@ -54,7 +54,17 @@ def main(argv=None):
         "--animate",
         metavar="FILE.gif",
         help="collect one frame per multistep chunk and save an "
-        "animation (the reference's matplotlib animation output)",
+        "animation (the reference's matplotlib animation output): the "
+        "solver's job writes a snapshot of h after every chunk and "
+        "copies it to the host beside the next chunks",
+    )
+    p.add_argument(
+        "--coarsen",
+        type=int,
+        default=1,
+        metavar="C",
+        help="a frame is the mean of h over CxC blocks of cells (1: "
+        "every cell); C has to divide a device's block",
     )
     p.add_argument(
         "--checkpoint",
@@ -102,6 +112,7 @@ def main(argv=None):
     if args.plot or args.animate:
         import matplotlib  # fail in ms, not after the whole run  # noqa: F401
 
+    if args.plot:
         specs = sw._mesh_specs(comm)
         gather = jax.jit(
             jax.shard_map(
@@ -113,14 +124,18 @@ def main(argv=None):
         )
 
     frames = []
-    on_chunk = None
+    on_chunk = snapshot = None
     if args.animate:
-        # frame collection rides the solver's chunk callback (timing
-        # then includes the gathers — not comparable to --benchmark)
-        def on_chunk(state, t):
-            # index on device: gather() is (n_dev, ny, nx) replicated
-            # over axis 0 — pull one global copy, not n_dev of them
-            frames.append(np.asarray(jax.device_get(gather(state)[0])))
+        # frames ride the solver's job (models/shallow_water.py
+        # SolverJob): after every chunk each device coarse-grains its
+        # own block of h, the copy to the host runs beside the next
+        # chunks (which still donate their input), and the callback is
+        # handed host arrays and the step they belong to, in order, at
+        # most `lag` chunks late; the live state is not handed out
+        snapshot = sw.Snapshot(fields=("h",), coarsen=args.coarsen, lag=4)
+
+        def on_chunk(snapshot, step):
+            frames.append(snapshot["h"])
 
     solve = sw.make_solver(
         cfg,
@@ -129,6 +144,7 @@ def main(argv=None):
         on_chunk=on_chunk,
         checkpoint_dir=args.checkpoint,
         checkpoint_every=args.checkpoint_every,
+        snapshot=snapshot,
     )
     state, wall, steps = solve(days * sw.DAY_IN_SECONDS)
 

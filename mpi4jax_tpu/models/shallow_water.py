@@ -33,7 +33,9 @@ TPU-first redesign (not a port):
   ``jit`` (shallow_water.py:415-420 does the same).
 """
 
+import collections
 import math
+import time
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import NamedTuple
@@ -46,7 +48,7 @@ from jax import lax
 
 from mpi4jax_tpu.models import sw_kernels
 from mpi4jax_tpu.ops import reductions
-from mpi4jax_tpu.ops._core import as_token
+from mpi4jax_tpu.ops._core import SCOPE_PREFIX, as_token
 from mpi4jax_tpu.ops.allreduce import allreduce
 from mpi4jax_tpu.ops.collectives import allgather, scan
 from mpi4jax_tpu.parallel.halo import (
@@ -61,6 +63,10 @@ __all__ = [
     "initial_state",
     "shallow_water_step",
     "make_multistep",
+    "Snapshot",
+    "SolverJob",
+    "make_snapshot",
+    "make_job",
     "make_solver",
     "gather_global",
 ]
@@ -863,6 +869,215 @@ def make_first_step(cfg, comm):
     )
 
 
+@dataclass(frozen=True)
+class Snapshot:
+    """What a job writes after every call, and how late it may deliver it.
+
+    ``fields``: the state's arrays a snapshot holds.  ``coarsen``: a
+    snapshot is the interior's mean over ``coarsen × coarsen`` blocks
+    of cells (1: the interior as it is); it has to divide both sides of
+    a device's block.  ``lag``: a snapshot is handed to the callback at
+    most this many snapshots after the newest one produced, so at most
+    ``lag + 1`` wait on the device at once.  ``ahead_bytes``: the most
+    bytes of snapshots whose copies to the host are asked for and not
+    yet fetched, the oldest's always; ``None``: every snapshot's copy is
+    asked for as it is produced.  A host that stages transfers through
+    a pinned buffer of a fixed size (a TPU's:
+    ``TPU_PREMAPPED_BUFFER_SIZE``, 4 GiB unless set) moves what is asked
+    for past it at a tenth of the speed: a job whose ``lag + 1``
+    snapshots do not fit there holds its copies under it with this."""
+
+    fields: tuple = ("h", "u", "v")
+    coarsen: int = 1
+    lag: int = 4
+    ahead_bytes: int = None
+
+
+# The snapshot's phase, a jax.named_scope segment inside its own
+# ``mpi4jax_tpu.snapshot`` scope (as parallel/halo.py names the
+# exchange's): what a device profile books the coarse-graining under.
+COARSEN = "coarsen"
+
+
+def _block_mean(block, ghost, coarsen):
+    """The mean over ``coarsen × coarsen`` blocks of cells of the
+    interior of one device's padded ``block``."""
+    c = coarsen
+    if c == 1:
+        return block[ghost:-ghost, ghost:-ghost]
+    # the windows are laid over the padded block as it is, the first
+    # `lead` of them before the interior's first: a slice of the
+    # interior first would be a pass over the field of its own
+    lead = -(-ghost // c)
+    pads = [(lead * c - ghost, -(n + lead * c - ghost) % c) for n in block.shape]
+    sums = lax.reduce_window(
+        block, jnp.zeros((), block.dtype), lax.add, (c, c), (c, c), pads)
+    ny, nx = (n - 2 * ghost for n in block.shape)
+    means = sums[lead:lead + ny // c, lead:lead + nx // c]
+    return means * jnp.asarray(1.0 / (c * c), block.dtype)
+
+
+def make_snapshot(cfg, comm, snapshot):
+    """Jitted global function ``(field, ...) -> (coarse field, ...)``:
+    each device coarse-grains the interior of its own block of each of
+    the state's arrays it is handed, and the results stay sharded as the
+    state is, so that each device sends the host its own share once.
+    Reads its arguments and donates nothing: a job enqueues it between
+    the call that made the state and the call that consumes it."""
+    c = snapshot.coarsen
+    ny_l, nx_l = cfg.local_interior(comm)
+    if c < 1 or ny_l % c or nx_l % c:
+        raise ValueError(
+            f"coarsen {c} does not divide a device's block of "
+            f"{ny_l}x{nx_l} cells")
+
+    def local_fn(*fields):
+        with jax.named_scope(SCOPE_PREFIX + "snapshot"), jax.named_scope(COARSEN):
+            return tuple(_block_mean(a, cfg.ghost, c) for a in fields)
+
+    spec = jax.P(*comm.axes)
+    n = len(snapshot.fields)
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(spec,) * n, out_specs=(spec,) * n))
+
+
+class SolverJob:
+    """The solver as a job that writes output: the loop ``state =
+    multistep(state)`` with, after every call, a snapshot of the state
+    on its way to the host while the next calls run.
+
+    Every call donates its input, output or not.  The snapshot program
+    reads the state after call ``k`` and is enqueued before call ``k +
+    1``, which consumes that state: the device runs them in the order
+    they were enqueued, so a snapshot holds the state of the step it
+    names and of no other.  The copy to the host is asked for as the
+    snapshot is produced, or as soon as ``snapshot.ahead_bytes`` lets
+    it, and ``on_chunk(snapshot, step)`` is handed
+    ``{name: numpy array}`` and the number of steps the state had made,
+    in step order, every one, at most ``snapshot.lag`` snapshots after
+    the newest produced; the live state is never handed out.
+
+    ``first``, ``multi`` and ``snap`` are the jitted programs; ``state``
+    and ``step`` the model as the last enqueued call leaves it.
+    """
+
+    def __init__(self, cfg, comm, num_multisteps, snapshot, on_chunk):
+        self.cfg, self.comm = cfg, comm
+        self.num_multisteps = num_multisteps
+        self.snapshot, self.on_chunk = snapshot, on_chunk
+        self.first = make_first_step(cfg, comm)
+        self.multi = make_multistep(cfg, comm, num_multisteps, donate=True)
+        self.snap = snapshot and make_snapshot(cfg, comm, snapshot)
+        self._multi, self._snap, self._kept = self.multi, self.snap, None
+        self.state, self.step = None, 0
+        self._pending = collections.deque()  # (step, device arrays), oldest first
+        self._asked = 0  # of them, from the oldest: their copies are on their way
+        self._asked_bytes = 0
+        self._stats = dict(
+            snapshots_produced=0, snapshots_delivered=0, max_lag=0,
+            bytes_to_host=0, output_wait_s=0.0, callback_s=0.0)
+
+    def start(self, state, step=0):
+        """Take ``state`` as the model after ``step`` steps.  A state at
+        step 0 (``make_init``'s, or the caller's own fields) is put
+        through the forward-Euler step, which does not donate it; a
+        later one (a checkpoint's) is taken as it is."""
+        self.drain()
+        if step == 0:
+            state, step = self.first(state), 1
+        self.state, self.step = state, step
+
+    def compile(self):
+        """Compile the call's programs for the state at hand without
+        running them: a resumed run has no warm-up call to spend."""
+        self._multi = self.multi.lower(self.state).compile()
+        if self.snap is not None:
+            self._snap = self.snap.lower(*self._written()).compile()
+
+    def advance(self, calls=1, *, keep_input=False):
+        """Enqueue ``calls`` multistep calls, after each the snapshot,
+        ask for the copies to the host that are next in line, and
+        deliver every snapshot that is due.  ``keep_input``: the first of the calls
+        does not donate its input (somebody else, an asynchronous
+        save, still reads it); it is the same program compiled without
+        the aliasing, built when first asked for."""
+        for k in range(calls):
+            multi = self._multi
+            if keep_input and k == 0:
+                if self._kept is None:
+                    self._kept = make_multistep(
+                        self.cfg, self.comm, self.num_multisteps)
+                multi = self._kept
+            self.state = multi(self.state)
+            self.step += self.num_multisteps
+            if self.snap is not None:
+                self._pending.append((self.step, self._snap(*self._written())))
+                self._stats["snapshots_produced"] += 1
+                self._ask()
+                self._deliver(self.snapshot.lag)
+        return self.state
+
+    def drain(self):
+        """Deliver every snapshot still on its way."""
+        self._deliver(0)
+
+    def stats(self):
+        """The job's counters: ``snapshots_produced`` and
+        ``snapshots_delivered``; ``max_lag``, the most snapshots one was
+        delivered behind the newest; ``bytes_to_host``;
+        ``output_wait_s``, host seconds spent fetching snapshots (blocked
+        on a copy that was not ready, or putting shards together); and
+        ``callback_s``, host seconds inside ``on_chunk``."""
+        return dict(self._stats)
+
+    def _written(self):
+        return tuple(getattr(self.state, k) for k in self.snapshot.fields)
+
+    def _ask(self):
+        """Start the copies to the host of the oldest snapshots not yet
+        asked for, as far as ``snapshot.ahead_bytes`` goes."""
+        most = self.snapshot.ahead_bytes
+        while self._asked < len(self._pending):
+            parts = self._pending[self._asked][1]
+            size = sum(part.nbytes for part in parts)
+            if self._asked and most is not None and self._asked_bytes + size > most:
+                break
+            for part in parts:
+                part.copy_to_host_async()
+            self._asked += 1
+            self._asked_bytes += size
+
+    def _deliver(self, keep):
+        stats = self._stats
+        while len(self._pending) > keep:
+            step, parts = self._pending.popleft()
+            t0 = time.perf_counter()
+            arrays = {k: np.asarray(part)
+                      for k, part in zip(self.snapshot.fields, parts)}
+            self._asked -= 1
+            self._asked_bytes -= sum(part.nbytes for part in parts)
+            self._ask()
+            t1 = time.perf_counter()
+            if self.on_chunk is not None:
+                self.on_chunk(arrays, step)
+            stats["callback_s"] += time.perf_counter() - t1
+            stats["output_wait_s"] += t1 - t0
+            stats["bytes_to_host"] += sum(a.nbytes for a in arrays.values())
+            stats["max_lag"] = max(stats["max_lag"], len(self._pending))
+            stats["snapshots_delivered"] += 1
+
+
+def make_job(cfg, comm, num_multisteps=10, snapshot=None, on_chunk=None):
+    """The solver's loop as an object (:class:`SolverJob`):
+    ``job.start(state)``, ``job.advance(calls)``, ``job.drain()``,
+    ``job.stats()``.  ``snapshot``: a :class:`Snapshot`,
+    or ``None`` for a job that writes nothing (``on_chunk`` alone asks
+    for whole fields of ``h``, ``u`` and ``v``)."""
+    if snapshot is None and on_chunk is not None:
+        snapshot = Snapshot()
+    return SolverJob(cfg, comm, num_multisteps, snapshot, on_chunk)
+
+
 def make_solver(
     cfg,
     comm,
@@ -870,36 +1085,37 @@ def make_solver(
     on_chunk=None,
     checkpoint_dir=None,
     checkpoint_every=1,
+    snapshot=None,
 ):
-    """Full driver: init → bootstrap step → repeated jitted multisteps.
+    """Full driver: init → bootstrap step → repeated jitted multisteps,
+    a loop over one :class:`SolverJob`.
 
     Returns ``solve(t1_seconds) -> (state, wall_seconds, n_steps)`` where
     wall time covers only the post-compile hot loop, matching the
     reference's benchmark methodology (shallow_water.py:450-470).
 
-    ``on_chunk(state, t_seconds)``, if given, is called after every
-    multistep chunk (including the warm-up one) — e.g. to collect
-    animation frames, as the reference's plotting loop does
-    (shallow_water.py:586-599 there).  Callback time is included in the
-    wall clock, so don't combine with benchmark timing.
+    ``on_chunk(snapshot, step)``, if given, is handed the output of
+    every multistep chunk (including the warm-up one): host arrays by
+    name, ``snapshot`` saying which fields and how coarse (a
+    :class:`Snapshot`; whole ``h``, ``u``, ``v`` if left out), and the
+    step they belong to, in step order and at most ``snapshot.lag``
+    chunks late; the rest arrive before ``solve`` returns.  E.g. to
+    collect animation frames, as the reference's plotting loop does
+    (shallow_water.py:586-599 there).  The snapshots' copies to the
+    host run beside the next chunks and every chunk donates its input,
+    output or not; callback time is included in the wall clock.
 
     ``checkpoint_dir`` enables resumable runs (SURVEY §5.4 — absent in
     the reference): every ``checkpoint_every`` chunks the sharded state
     and model time are saved via :mod:`mpi4jax_tpu.utils.checkpoint`,
     and a fresh ``solve`` in the same directory resumes from the latest
-    checkpoint instead of re-initialising.  Save time is included in
-    the wall clock — don't combine with benchmark timing either.
+    checkpoint instead of re-initialising.  The chunk after a save is
+    the one that does not donate its input (the asynchronous save still
+    reads it).  Save time is included in the wall clock — don't combine
+    with benchmark timing.
     """
-    import time
-
     init = make_init(cfg, comm)
-    first = make_first_step(cfg, comm)
-    # the loop below is `state = multi(state)`: update in place, unless a
-    # callback or an asynchronous save may still hold the old state
-    multi = make_multistep(
-        cfg, comm, num_multisteps,
-        donate=on_chunk is None and checkpoint_dir is None,
-    )
+    job = make_job(cfg, comm, num_multisteps, snapshot, on_chunk)
 
     def solve(t1):
         mgr = None
@@ -909,13 +1125,12 @@ def make_solver(
             mgr = _ckpt.Manager(checkpoint_dir)
         try:
             latest = mgr.latest_step() if mgr is not None else None
-            step_fn = multi
             if latest is not None:
                 # resume: restore against an ABSTRACT template (shapes
                 # from eval_shape + the solver's shardings) — no init /
                 # warm-up compute is spent on state that is about to be
-                # replaced.  AOT-compile the multistep so the timed loop
-                # still excludes compilation.
+                # replaced.  AOT-compile the chunk's programs so the
+                # timed loop still excludes compilation.
                 chunk = latest
                 resumed = True
                 specs = _mesh_specs(comm)
@@ -931,23 +1146,20 @@ def make_solver(
                 restored = mgr.restore(
                     chunk, like={"state": abstract, "t": np.float64(0.0)}
                 )
-                state = SWState(*restored["state"])
                 t = float(restored["t"])
-                step_fn = multi.lower(state).compile()
+                job.start(SWState(*restored["state"]), step=round(t / cfg.dt))
+                job.compile()
             else:
                 chunk = 0
                 resumed = False
-                state = init()
-                state = first(state)
-                t = cfg.dt
+                job.start(init())
                 # warm-up compile (excluded from timing, as in the
                 # reference)
-                state = multi(state)
-                t += cfg.dt * num_multisteps
-            jax.block_until_ready(state)
-            if on_chunk is not None:
-                on_chunk(state, t)
+                job.advance()
+                t = cfg.dt + cfg.dt * num_multisteps
+            jax.block_until_ready(job.state)
             steps = 0
+            saved = False
             start = time.perf_counter()
             # always time at least one multistep on a FRESH run, even if
             # the warm-up call already advanced past t1 (short runs /
@@ -955,21 +1167,19 @@ def make_solver(
             # completed run in the same directory would otherwise push
             # the trajectory past t1 and save checkpoints beyond it.
             while t < t1 or (steps == 0 and not resumed):
-                state = step_fn(state)
+                job.advance(keep_input=saved)
                 t += cfg.dt * num_multisteps
                 steps += num_multisteps
                 chunk += 1
-                if on_chunk is not None:
-                    on_chunk(state, t)
-                if mgr is not None:
-                    mgr.maybe_save(
-                        chunk,
-                        {"state": state, "t": np.float64(t)},
-                        every=checkpoint_every,
-                    )
-            jax.block_until_ready(state)
+                saved = mgr is not None and mgr.maybe_save(
+                    chunk,
+                    {"state": job.state, "t": np.float64(t)},
+                    every=checkpoint_every,
+                )
+            job.drain()
+            jax.block_until_ready(job.state)
             wall = time.perf_counter() - start
-            return state, wall, steps
+            return job.state, wall, steps
         finally:
             if mgr is not None:
                 mgr.close()

@@ -304,3 +304,52 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
     assert bool(permutes) == (py * px > 1)
     assert all(o.scopes == (halo, "wire", "mpi4jax_tpu.sendrecv")
                for o in permutes)
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
+        v5e, mesh_shape):
+    """``make_job`` with a snapshot: the call's multistep is the donated
+    program (no ``copy`` of a field: output does not turn donation off),
+    and the snapshot program, at the benchmark cell's size a chip, is one
+    ``reduce-window`` a field laid over the padded block as it is, under
+    ``mpi4jax_tpu.snapshot/coarsen``, with no temporary of a field's size
+    (a slice of the interior first would be one; the plain ``reshape``
+    to ``(ny/4, 4, nx/4, 4)`` takes 13.7 GB)."""
+    from perfbench.harness import scopes
+
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px])
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=7200 * py, nx=14400 * px, dx=1250.0, dy=1250.0, ghost=2)
+    job = sw.make_job(cfg, comm, 10, sw.Snapshot(coarsen=4), lambda *a: None)
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(sw.make_init(cfg, comm)))
+
+    multi = job.multi.lower(state).compile()
+    text = multi.as_text()
+    _, fields, _, _ = _kernels(text)["wide_step"]
+    assert len(fields) == 6 and not _copied(text, fields), fields
+    # on four chips XLA transposes each field once to slice its column
+    # slabs (PERF.md section 7); on one nothing of a field's size is copied
+    copies = re.findall(r"= f32\[7204,14404\]\S* copy\(", text)
+    assert len(copies) == (0 if py * px == 1 else 3)
+    if py * px == 1:
+        assert multi.memory_analysis().temp_size_in_bytes < 7204 * 14404 * 4
+
+    snap = job.snap.lower(state.h, state.u, state.v).compile()
+    table = scopes.origins(snap.as_text())
+    under = [o for o in table.values()
+             if o.scopes[:2] == ("mpi4jax_tpu.snapshot", "coarsen")]
+    assert {o.op_name.rsplit("/", 1)[1] for o in under} >= {"reduce_window", "mul"}
+    assert all(scopes.layer_of(o) == scopes.OP_SURFACE for o in under)
+    assert snap.as_text().count(" reduce-window(") == 3
+    assert "collective-permute" not in snap.as_text()  # each chip its own block
+    mem = snap.memory_analysis()
+    # three coarse fields, their rows filled up to whole vector registers
+    assert 3 * 1800 * 3600 * 4 <= mem.output_size_in_bytes <= 3 * 1800 * 3712 * 4 + 4096
+    assert mem.temp_size_in_bytes < 7204 * 14404 * 4
