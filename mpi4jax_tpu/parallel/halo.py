@@ -9,21 +9,27 @@ sub-communicator, which lowers to a single ``lax.ppermute`` — a
 nearest-neighbour ICI transfer, the physically native communication
 pattern on a TPU torus.
 
-Order: the x exchange moves full columns (including y-halo cells), then
-the y exchange moves full rows (including the just-filled x halos), so
-corner cells are correct after two rounds — same transitive-corner trick
-as the reference's clockwise ordering.
+Order: ``pack`` the two column slabs (full height) and send them over
+the x ``wire``; ``pack`` the two row slabs (full width), their ``width``
+x ``width`` ends taken from the column slabs just received, and send
+them over the y ``wire``; then, once, ``unpack``: west, east, south,
+north written over the block's ghosts.  No ghost is written between the
+two wires, and the corners are what the reference's clockwise order
+makes them: a row slab carries the x ghosts its sender has just
+received, patched on a slab of a few rows and not on the block.
 
 Two forms of one exchange: :func:`halo_exchange_2d` (and its batched
-sibling) writes the received slabs into the block's ghosts;
-:func:`halo_slabs_2d` stops before that and returns them.  Both slice
-(``pack``) and send (``wire``) through the same code.
+sibling) runs all three phases; :func:`halo_slabs_2d` stops before
+``unpack`` and returns the four slabs.  Both come out of
+:func:`_received`.
 """
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from mpi4jax_tpu.ops._core import as_token, publishes_token
 from mpi4jax_tpu.ops.p2p import sendrecv, sendrecv_multi
@@ -76,12 +82,19 @@ def halo_exchange_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
 
     Works for any decomposition including 1×1 (periodic wrap becomes a
     self-permute, so single-chip runs use the identical program).
-    Ghost slabs are written with dynamic-update-slices: on a v5e a
-    write of two columns of a 7204 x 14404 block takes 29 us, a whole
-    vector register's lanes a row for 58 KB (``PERF.md``, PR 29's
-    trace).  A caller whose next kernel reads and writes the whole
-    block anyway takes the slabs from :func:`halo_slabs_2d` and places
-    them there.
+    The ghosts are written once, after both wires, by four
+    ``dynamic_update_slice`` on one value: in place where the caller
+    gives the block up (``x = halo_exchange_2d(x, ...)[0]`` in a loop),
+    on one copy of it where the caller keeps ``arr``.  On the mesh tier
+    the block is held row-major for the exchange (:func:`_row_major`).
+    On a v5e 2×2, ``width`` 2 on a 1804 x 3604 block (``PERF.md``,
+    PR 35): 34 us a call in place, of which the two column writes are
+    7.6 us each (1804 pieces of 8 bytes), the row writes 1 us each and
+    the four permutes 8; 95 us where the input is kept, 41 of them the
+    copy.  A caller whose next kernel reads and writes the whole block
+    anyway takes the slabs from :func:`halo_slabs_2d` and places them
+    there: that saves the column writes and costs the kernel nothing
+    (``models/sw_kernels.py wide_step``).
     """
     arrs, token = _exchange(
         [arr], comm, periodic=periodic, token=token, width=width,
@@ -175,24 +188,90 @@ def _pack(arrs, sent, received):
         return [a[sent] for a in arrs], [a[received] for a in arrs]
 
 
-def _exchange(arrs, comm, *, periodic, token, width, stack):
-    """Shared four-direction exchange body: each shift's slabs are
-    written into the ghosts before the next shift packs its own, so the
-    y slabs carry the x ghosts just received."""
+def _received(arrs, comm, *, periodic, token, width, stack):
+    """The four shifts of every array with no ghost written between
+    them: ``(slabs, token)``, ``slabs[k][i]`` what array ``i`` receives
+    from shift ``k`` of :func:`_shifts` (``None`` where that shift is a
+    no-op on the whole axis).  The x slabs are sliced from the block; a
+    y slab is the block's rows with its ``width`` x ``width`` ends taken
+    from the x slabs just received, patched on a slab of a few rows."""
     token = as_token(token)
-    for axis, disp, per, sent, received in _shifts(width, periodic):
-        halo, token = _shift(
-            *_pack(arrs, sent, received), comm, axis, disp, per, token,
-            stack=stack,
-        )
-        # halo[i] is None on a global no-op shift: ghosts already hold
-        # the right values, skip the (identical) write
-        with jax.named_scope(UNPACK):
-            arrs = [
-                a if halo[i] is None else a.at[received].set(halo[i])
-                for i, a in enumerate(arrs)
-            ]
-    return arrs, token
+    w = width
+    slabs = []
+
+    def rows(i, region):
+        """A row slab of array ``i`` with the x slabs for its ends."""
+        west, east = slabs[0][i], slabs[1][i]
+        with jax.named_scope(PACK):
+            slab = arrs[i][region]
+            if west is None and east is None:
+                return slab
+            return jnp.concatenate([
+                slab[:, :w] if west is None else west[region],
+                slab[:, w:-w],
+                slab[:, -w:] if east is None else east[region],
+            ], axis=1)
+
+    for axis, disp, per, sent, received in _shifts(w, periodic):
+        if not comm.sub(axis).shift_perm(axis, disp, periodic=per):
+            # a no-op on the whole axis: nothing to pack
+            slabs.append([None] * len(arrs))
+            continue
+        if axis == "x":
+            parts = _pack(arrs, sent, received)
+        else:
+            parts = tuple(
+                [rows(i, region) for i in range(len(arrs))]
+                for region in (sent, received))
+        got, token = _shift(
+            *parts, comm, axis, disp, per, token, stack=stack)
+        slabs.append(got)
+    return slabs, token
+
+
+def _row_major(a):
+    """``a`` held to the layout a block has everywhere else in a program.
+    Left to itself the TPU compiler lays the whole carried block out
+    column-major to suit the two-column slabs sliced from it, and then
+    pays for every row slab's write (25 us for 29 KB on a v5e, ``PERF.md``
+    PR 35); held row-major it slices and writes the narrow slabs and
+    transposes those."""
+    return with_layout_constraint(
+        a, Layout(major_to_minor=tuple(range(a.ndim))))
+
+
+def _place(a, slab, region):
+    """``a`` with ``slab`` written over ``region``, a pair of static
+    slices: a ``dynamic_update_slice`` at constant offsets (``.at[].set``
+    is a scatter, which XLA gives a bounds test, a mask and a select
+    of the slab's size on every write)."""
+    start = tuple(s.indices(n)[0] for s, n in zip(region, a.shape))
+    return lax.dynamic_update_slice(a, slab, start)
+
+
+def _exchange(arrs, comm, *, periodic, token, width, stack):
+    """Both forms that write ghosts: the four received slabs of
+    :func:`_received`, then one placement phase over each block: west,
+    east, south, north onto one value, nothing else reading the values
+    between, so the writes can share one buffer (the caller's own where
+    it gives the block up, one copy of it where it keeps it)."""
+    if comm.backend == "mesh":
+        with jax.named_scope(PACK):
+            arrs = [_row_major(a) for a in arrs]
+    slabs, token = _received(
+        arrs, comm, periodic=periodic, token=token, width=width, stack=stack,
+    )
+    regions = [received for *_, received in _shifts(width, periodic)]
+    out = []
+    with jax.named_scope(UNPACK):
+        for i, a in enumerate(arrs):
+            for got, region in zip(slabs, regions):
+                # None on a global no-op shift: the ghosts already hold
+                # the right values, skip the (identical) write
+                if got[i] is not None:
+                    a = _place(a, got[i], region)
+            out.append(a)
+    return out, token
 
 
 @publishes_token
@@ -215,32 +294,7 @@ def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
     the block), and a device with no neighbour on that side gets its own
     ghost rows back patched likewise.
     """
-    token = as_token(token)
-    w = width
-    slabs = []
-
-    def rows(region):
-        """A row slab of ``arr`` with the x slabs for its ends."""
-        west, east = slabs[:2]
-        with jax.named_scope(PACK):
-            slab = arr[region]
-            if west is None and east is None:
-                return slab
-            return jnp.concatenate([
-                slab[:, :w] if west is None else west[region],
-                slab[:, w:-w],
-                slab[:, -w:] if east is None else east[region],
-            ], axis=1)
-
-    for axis, disp, per, sent, received in _shifts(w, periodic):
-        if not comm.sub(axis).shift_perm(axis, disp, periodic=per):
-            slabs.append(None)  # a no-op on the whole axis: nothing to pack
-            continue
-        if axis == "x":
-            parts = _pack([arr], sent, received)
-        else:
-            parts = [rows(sent)], [rows(received)]
-        (slab,), token = _shift(
-            *parts, comm, axis, disp, per, token, stack=False)
-        slabs.append(slab)
-    return tuple(slabs), token
+    slabs, token = _received(
+        [arr], comm, periodic=periodic, token=token, width=width, stack=False,
+    )
+    return tuple(got for got, in slabs), token

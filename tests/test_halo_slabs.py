@@ -1,7 +1,9 @@
 """``halo_slabs_2d`` is ``halo_exchange_2d`` without its last phase: the
 four slabs a block receives, for a caller that places them itself.
 Written into the ghosts in the order they come, they are the exchange's
-result bit for bit, corners included, on every mesh."""
+result bit for bit, corners included, on every mesh.  And the exchange
+itself, which writes no ghost between its two wires, is bit for bit the
+exchange that did (the order kept here as a helper)."""
 
 import jax
 import jax.numpy as jnp
@@ -9,9 +11,11 @@ import numpy as np
 import pytest
 
 import mpi4jax_tpu as m
-from mpi4jax_tpu.ops._core import SCOPE_PREFIX
+from mpi4jax_tpu.analysis.jaxpr_walk import walk_comm_jaxpr
+from mpi4jax_tpu.ops._core import SCOPE_PREFIX, as_token
 from mpi4jax_tpu.parallel import halo_slabs_2d
-from mpi4jax_tpu.parallel.halo import halo_exchange_2d
+from mpi4jax_tpu.parallel.halo import (
+    _shift, _shifts, halo_exchange_2d, halo_exchange_2d_batch)
 
 NY, NX = 6, 5  # one device's interior
 
@@ -123,3 +127,80 @@ def test_the_slabs_lower_under_pack_and_wire_and_nothing_is_unpacked():
     program, starts = _program(comm, 2, (False, True), _exchanged)
     text = jax.jit(program).lower(starts).as_text(debug_info=True)
     assert f"{SCOPE_PREFIX}halo_exchange_2d/unpack" in text
+
+
+def _written_between_the_shifts(arrs, comm, periodic, width, stack):
+    """The exchange as it was before its ghosts were written once: every
+    shift's slabs sliced from blocks that hold the shifts' before it,
+    and written before the next is sliced."""
+    token = as_token(None)
+    for axis, disp, per, sent, received in _shifts(width, periodic):
+        halo, token = _shift(
+            [a[sent] for a in arrs], [a[received] for a in arrs], comm, axis,
+            disp, per, token, stack=stack)
+        arrs = [a if got is None else a.at[received].set(got)
+                for a, got in zip(arrs, halo)]
+    return arrs
+
+
+def _single_then(arr, comm, periodic, width):
+    return _written_between_the_shifts([arr], comm, periodic, width, False)[0]
+
+
+def _pair(arr):
+    return [arr, 3.0 * arr + 0.5]
+
+
+def _batch_now(arr, comm, periodic, width):
+    outs, _ = halo_exchange_2d_batch(
+        _pair(arr), comm, periodic=periodic, width=width)
+    return jnp.stack(outs)
+
+
+def _batch_then(arr, comm, periodic, width):
+    return jnp.stack(_written_between_the_shifts(
+        _pair(arr), comm, periodic, width, True))
+
+
+@pytest.mark.parametrize("form", ["single", "batch"])
+@pytest.mark.parametrize("width", [1, 2, 4])
+@pytest.mark.parametrize(
+    "periodic", [(False, False), (False, True), (True, False), (True, True)],
+    ids=["box", "walls", "channel", "torus"])
+@pytest.mark.parametrize(
+    "mesh_shape", [(1, 1), (1, 4), (4, 1), (2, 2), (2, 4)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_ghosts_written_once_are_the_ghosts_written_between_the_shifts(
+        mesh_shape, periodic, width, form):
+    """Every cell of every block holds a number of its own, so a corner
+    patched from the wrong slab, or not at all, shows."""
+    comm = _comm(mesh_shape)
+    now, then = {"single": (_exchanged, _single_then),
+                 "batch": (_batch_now, _batch_then)}[form]
+    got, want = (
+        np.asarray(jax.jit(program)(starts)) for program, starts in
+        (_program(comm, width, periodic, what) for what in (now, then)))
+    np.testing.assert_array_equal(got, want)
+    # something moved wherever a shift is not a no-op on the whole axis
+    py, px = mesh_shape
+    first = got[0] if form == "single" else got[0][0]
+    before = np.arange(first.size, dtype=np.float32).reshape(first.shape)
+    inner = np.s_[width:-width]
+    if px > 1 or periodic[1]:  # the first block's west or east ghosts
+        assert (first[inner, :width] != before[inner, :width]).any() or (
+            first[inner, -width:] != before[inner, -width:]).any()
+    if py > 1 or periodic[0]:
+        assert (first[:width, inner] != before[:width, inner]).any() or (
+            first[-width:, inner] != before[-width:, inner]).any()
+
+
+@pytest.mark.parametrize("form,op", [
+    ("single", "halo_exchange_2d"), ("batch", "halo_exchange_2d_batch")])
+def test_an_exchange_is_one_op_and_four_sendrecvs(form, op):
+    comm = _comm((2, 2))
+    what = {"single": _exchanged, "batch": _batch_now}[form]
+    program, starts = _program(comm, 2, (False, True), what)
+    occurrences, findings = walk_comm_jaxpr(jax.make_jaxpr(program)(starts))
+    assert not findings
+    ops = [o.op for o in occurrences]
+    assert set(ops) == {op, "sendrecv"} and ops.count("sendrecv") == 4

@@ -232,6 +232,81 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
     assert len(body) == STEP_INSTRUCTIONS[mesh_shape], opcodes
 
 
+def _halo_row_blocks(v5e, program):
+    """The benchmark's halo row (``perfbench/workloads/coll-2x2.json``:
+    width 2 on 1804 x 3604 a chip, 2x2) under ``program(op, reps, mesh,
+    spec)``, compiled for the described chips: the loop body's
+    instructions whose result is a block, as ``(opcode, type, line)``."""
+    import json
+
+    from perfbench.drivers import collectives
+
+    mesh = jax.make_mesh(
+        (2, 2), collectives.AXES,
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=v5e.devices[:4])
+    with open("perfbench/workloads/coll-2x2.json") as f:
+        row, = (r for r in json.load(f)["rows"] if r["op"] == "halo")
+    ny, nx = row["shape"]
+    spec = jax.P(*collectives.AXES)
+    x = jax.ShapeDtypeStruct(
+        (2 * ny, 2 * nx), jnp.float32, sharding=NamedSharding(mesh, spec))
+    op = collectives.library_op(row, m.MeshComm.from_mesh(mesh))
+    text = program(op, row["reps"], mesh, spec).lower(x).compile().as_text()
+    body, types = _step_body(text)
+    return [(opcode, types[name], line) for name, opcode, _, line in body
+            if types[name].startswith(f"f32[{ny},{nx}]")]
+
+
+def _opcodes(blocks):
+    return sorted(opcode for opcode, *_ in blocks)
+
+
+def test_the_tables_halo_row_moves_its_block_once(v5e):
+    """The table calls the exchange with its input kept alive.  The
+    ghosts are four writes in place on one value, after both wires, in
+    a block that stays row-major (left to itself XLA lays the whole
+    block out to suit the two-column slabs sliced from it, and each row
+    write is then 3604 pieces: PERF.md, PR 35).  Three copies: the
+    result out to the carried output is the exchange's; the input saved
+    before those writes and handed back after the chain's own write are
+    what XLA makes of the benchmark's chain, inside its faster memory.
+    None transposes."""
+    from perfbench.drivers import collectives
+
+    blocks = _halo_row_blocks(v5e, collectives.chained)
+    assert all(kind.startswith("f32[1804,3604]{1,0") for _, kind, _ in blocks)
+    assert _opcodes(blocks) == ["copy"] * 3 + ["dynamic-update-slice"] * 5
+    placed = [line for opcode, _, line in blocks
+              if "halo_exchange_2d/unpack" in line]
+    assert len(placed) == 4 and all(
+        " dynamic-update-slice(" in line for line in placed)
+    # the one write that is not the exchange's is the chain's
+    assert len([1 for opcode, _, line in blocks
+                if opcode == "dynamic-update-slice" and line not in placed]) == 1
+    # one copy leaves the faster memory: the result; the chain's two stay
+    copies = [kind for opcode, kind, _ in blocks if opcode == "copy"]
+    assert sorted("S(1)" in kind for kind in copies) == [False, True, True]
+
+
+def test_an_exchange_in_place_copies_no_block(v5e):
+    """``x = halo_exchange_2d(x)`` alone in a loop whose carry is
+    donated: four writes on the carried block where it lies, and nothing
+    else of a block's size."""
+
+    def in_place(op, reps, mesh, spec):
+        def local(x):
+            return jax.lax.fori_loop(0, reps, lambda _, x: op(x), x)
+
+        return jax.jit(
+            jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec),
+            donate_argnums=0)
+
+    blocks = _halo_row_blocks(v5e, in_place)
+    assert all(kind.startswith("f32[1804,3604]{1,0") for _, kind, _ in blocks)
+    assert _opcodes(blocks) == ["dynamic-update-slice"] * 4
+    assert all("halo_exchange_2d/unpack" in line for *_, line in blocks)
+
+
 @pytest.mark.parametrize("n", [1, 4])
 def test_op_surface_compiles_for_v5e(v5e, n):
     """chip_smoke.py's 13-op program and its rendezvous ring (host
