@@ -578,6 +578,80 @@ def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
     return out
 
 
+def solver_restart_check(cfg, devices, *, mesh_shapes=((1, 1),),
+                         steps_per_call=10, calls=4, every=2):
+    """The solver as a job that is saved, killed and resumed
+    (``make_job(checkpoint=...)``): on every mesh a job saves its whole
+    state every ``every`` calls beside the calls that follow, each
+    chip's share in pieces, and is dropped after ``calls``; a new job
+    that knows the directory resumes from the newest save and runs
+    ``every`` calls more: bit for bit the state of a job that was never
+    stopped; the directory holds the newest saves and no temporary; and
+    the meshes agree to the rounding of another decomposition."""
+    import shutil
+    import tempfile
+
+    import numpy as np
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    ends = []
+    for shape in mesh_shapes:
+        n = shape[0] * shape[1]
+        whole, _ = _job(cfg, devices[:n], shape, None, steps_per_call,
+                        calls + every)
+        directory = tempfile.mkdtemp(prefix="chip-smoke-restart-")
+        try:
+            ck = sw.Checkpoint(directory, every_calls=every, keep=2)
+            comm = whole.comm
+            killed = sw.make_job(cfg, comm, steps_per_call, checkpoint=ck)
+            killed.start(sw.make_init(cfg, comm)())
+            killed.advance(calls)
+            killed.drain()
+            saved = killed.stats()
+            killed.state = None
+            del killed
+            resumed = sw.make_job(cfg, comm, steps_per_call, checkpoint=ck)
+            step = resumed.resume()
+            if step != 1 + steps_per_call * (calls - calls % every):
+                raise AssertionError(f"{shape}: resumed from step {step}")
+            resumed.advance(every + calls % every)
+            resumed.drain()
+            series = resumed.series
+            if series.leftovers() or len(series.steps()) != 2:
+                raise AssertionError(
+                    f"{shape}: the directory holds {series.steps()} and "
+                    f"{series.leftovers()}")
+            if saved["saves_started"] != saved["saves_acknowledged"]:
+                raise AssertionError(f"{shape}: a save was lost: {saved}")
+            for name, a, b in zip(whole.state._fields, resumed.state, whole.state):
+                if not np.array_equal(np.asarray(a), np.asarray(b)):
+                    raise AssertionError(
+                        f"{shape}: state.{name} of the resumed job differs "
+                        "from that of a job never stopped")
+            ends.append({k: _interior(getattr(resumed.state, k), cfg.ghost, shape)
+                         for k in ("h", "u", "v")})
+            record = resumed.saves[-1]
+        finally:
+            shutil.rmtree(directory, ignore_errors=True)
+    across = max((float(np.abs(ends[0][k] - other[k]).max())
+                  for other in ends[1:] for k in other), default=0.0)
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost} saved every {every} "
+        f"calls of {steps_per_call} steps, dropped after {calls} and resumed: "
+        f"bit for bit the job never stopped, on "
+        f"{' and '.join('x'.join(map(str, s)) for s in mesh_shapes)} "
+        f"(tol {TOL_SAME_ARITHMETIC} between them)",
+        "save_bytes": record["bytes"],
+        "save_commit_s": record["commit_s"],
+        "meshes_max_diff": across,
+        "max_diff": across,
+    }
+    if across > TOL_SAME_ARITHMETIC:
+        raise AssertionError(f"decomposition changes the resumed run: {out}")
+    return out
+
+
 # ------------------------------------------------------------------- ops
 
 _K = 128  # elements per device in the op checks
@@ -1090,6 +1164,7 @@ GROUPS = {
         "solver": (420, {
             "solver": _solver,
             "solver.job": lambda: solver_job_check(_bench_cfg(), _one()),
+            "solver.restart": lambda: solver_restart_check(_bench_cfg(), _one()),
         }),
         "ops": (300, {
             "ops": lambda: ops_check(_one()),
@@ -1114,6 +1189,9 @@ GROUPS = {
                 _bench_cfg(), _all()
             ),
             "solver4.job": lambda: solver_job_check(
+                _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
+            ),
+            "solver4.restart": lambda: solver_restart_check(
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
         }),

@@ -67,11 +67,14 @@ def main(argv=None):
         "every cell); C has to divide a device's block",
     )
     p.add_argument(
+        "--checkpoint-dir",
         "--checkpoint",
+        dest="checkpoint",
         metavar="DIR",
-        help="save resumable checkpoints every --checkpoint-every "
-        "chunks; a rerun with the same DIR resumes from the latest "
-        "(timing then includes checkpoint writes)",
+        help="save the whole state every --checkpoint-every chunks, "
+        "beside the chunks that follow; a rerun with the same DIR "
+        "resumes from the newest save (the job's own: "
+        "docs/shallow-water.md, Saving and resuming)",
     )
     p.add_argument("--checkpoint-every", type=int, default=1)
     args = p.parse_args(argv)

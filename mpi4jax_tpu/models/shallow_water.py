@@ -56,6 +56,7 @@ from mpi4jax_tpu.parallel.halo import (
     halo_exchange_2d_batch,
     halo_slabs_2d,
 )
+from mpi4jax_tpu.utils import checkpoint as ckpt
 
 __all__ = [
     "SWConfig",
@@ -64,6 +65,7 @@ __all__ = [
     "shallow_water_step",
     "make_multistep",
     "Snapshot",
+    "Checkpoint",
     "SolverJob",
     "make_snapshot",
     "make_job",
@@ -941,41 +943,177 @@ def make_snapshot(cfg, comm, snapshot):
         local_fn, mesh=comm.mesh, in_specs=(spec,) * n, out_specs=(spec,) * n))
 
 
+@dataclass(frozen=True)
+class Checkpoint:
+    """Where a job saves its whole state, how often, and how.
+
+    ``directory``: a :class:`mpi4jax_tpu.utils.checkpoint.Series`, one
+    directory a save, named by the step it holds.  ``every_calls``: a
+    save after every call whose number, counted from the integration's
+    first, divides by this (0: only when :meth:`SolverJob.save` is
+    called).  ``keep``: the newest saves left in the directory; an
+    older one goes only once a new one is committed, so ``keep`` stand
+    committed at every moment from the ``keep``-th on.
+    ``ahead_bytes``: the most bytes of a save's pieces whose copies to
+    the host are asked for and not yet fetched, and of a restore's
+    whose copies to the device are not yet done, that the caller's host
+    takes (what :class:`Snapshot` says of a staging buffer holds here, a
+    state being larger than any snapshot); ``None``: the host sets no
+    bound.  The library holds every job under ``checkpoint.AHEAD_BYTES``
+    a device besides, whatever more the host would take: a device runs
+    copies and programs through one queue, so what is asked for ahead of
+    a call delays it (``AHEAD_BYTES`` has the measurement: 16e6 in
+    flight cost a 76 ms call nothing that shows, 160e6 cost the call
+    after a save 30 ms).  A piece is at most ``checkpoint.PIECE_BYTES`` a device and
+    at most half the bound, so that one copy runs while the next waits.
+    A job that has snapshots and saves gives each its bound and their
+    sum has to fit."""
+
+    directory: object
+    every_calls: int = 1
+    keep: int = 2
+    ahead_bytes: int = None
+
+    def ahead(self, devices=1):
+        """The bound a save and a restore of ``devices`` devices run
+        under: the host's, and the devices' queues'."""
+        queues = ckpt.AHEAD_BYTES * devices
+        return queues if self.ahead_bytes is None else min(self.ahead_bytes, queues)
+
+    def piece_bytes(self, devices=1):
+        """The most of a piece that one of ``devices`` devices holds."""
+        return min(ckpt.PIECE_BYTES, self.ahead(devices) // 2 // devices)
+
+
+# The phases of a save and of a restore on the device, jax.named_scope
+# segments inside ``mpi4jax_tpu.checkpoint``.
+STAGE, UNSTAGE = "stage", "unstage"
+# How a state carries its tendencies (``_step_wide`` says why there are
+# two): at the fields' padded shape, or at the interior's.
+PADDED, INTERIOR = "padded", "interior"
+
+
+def make_stage(comm, plan):
+    """Jitted ``state -> ((piece, ...), ...)``: each device cuts each of
+    the state's arrays into the bands of rows of its own block that
+    ``plan`` names (``[(lo, hi), ...]`` an array).  The pieces are
+    copies: enqueued after the call that made the state and before the
+    call that consumes it, they hold the step they were cut at whatever
+    runs next, and each is small enough for a host to take."""
+
+    def local_fn(state):
+        with jax.named_scope(SCOPE_PREFIX + "checkpoint"), jax.named_scope(STAGE):
+            return tuple(tuple(a[lo:hi] for lo, hi in rows)
+                         for a, rows in zip(state, plan))
+
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(_mesh_specs(comm),),
+        out_specs=tuple((spec,) * len(rows) for rows in plan)))
+
+
+def make_unstage(comm):
+    """Jitted ``(piece, ...) -> array``: each device puts its bands of
+    rows together again."""
+
+    def local_fn(*pieces):
+        with jax.named_scope(SCOPE_PREFIX + "checkpoint"), jax.named_scope(UNSTAGE):
+            return jnp.concatenate(pieces, axis=0)
+
+    spec = jax.P(*comm.axes)
+    return jax.jit(lambda *pieces: jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(spec,) * len(pieces),
+        out_specs=spec)(*pieces))
+
+
+def _reshape_tendencies(cfg, comm, to):
+    """Jitted ``(dh, du, dv) -> (dh, du, dv)`` between the two forms a
+    ``ghost=2`` state carries its tendencies in.  To ``INTERIOR``: the
+    interior of each padded block.  To ``PADDED``: zeros round each
+    block and, for ``du`` and ``dv``, the neighbours' edge cells in ring
+    1 (periodic in x, nothing beyond a wall), which is what the kernel
+    leaves there and steps from."""
+    G = cfg.ghost
+
+    def local_fn(dh, du, dv):
+        if to == INTERIOR:
+            return tuple(a[G:-G, G:-G] for a in (dh, du, dv))
+
+        def ringed(a):
+            a, _ = halo_exchange_2d(
+                jnp.pad(a, 1), comm, periodic=(False, cfg.periodic_x), width=1)
+            return jnp.pad(a, G - 1)
+
+        return jnp.pad(dh, G), ringed(du), ringed(dv)
+
+    spec = (jax.P(*comm.axes),) * 3
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=spec, out_specs=spec))
+
+
 class SolverJob:
-    """The solver as a job that writes output: the loop ``state =
-    multistep(state)`` with, after every call, a snapshot of the state
-    on its way to the host while the next calls run.
+    """The solver as a job that writes output and is saved, killed and
+    resumed: the loop ``state = multistep(state)`` with, after every
+    call, a snapshot of the state on its way to the host while the next
+    calls run, and, after every ``checkpoint.every_calls`` calls, the
+    whole state on its way to disk beside them.
 
-    Every call donates its input, output or not.  The snapshot program
-    reads the state after call ``k`` and is enqueued before call ``k +
-    1``, which consumes that state: the device runs them in the order
-    they were enqueued, so a snapshot holds the state of the step it
-    names and of no other.  The copy to the host is asked for as the
-    snapshot is produced, or as soon as ``snapshot.ahead_bytes`` lets
-    it, and ``on_chunk(snapshot, step)`` is handed
-    ``{name: numpy array}`` and the number of steps the state had made,
-    in step order, every one, at most ``snapshot.lag`` snapshots after
-    the newest produced; the live state is never handed out.
+    Every call donates its input, output or not, saved or not.  The
+    snapshot program and a save's staging program read the state after
+    call ``k`` and are enqueued before call ``k + 1``, which consumes
+    that state: the device runs them in the order they were enqueued,
+    so a snapshot and a save hold the state of the step they name and
+    of no other.  A snapshot's copy to the host is asked for as it is
+    produced, or as soon as ``snapshot.ahead_bytes`` lets it, and
+    ``on_chunk(snapshot, step)`` is handed ``{name: numpy array}`` and
+    the number of steps the state had made, in step order, every one,
+    at most ``snapshot.lag`` snapshots after the newest produced; the
+    live state is never handed out.
 
-    ``first``, ``multi`` and ``snap`` are the jitted programs; ``state``
-    and ``step`` the model as the last enqueued call leaves it.
+    A save (:meth:`save`; :class:`Checkpoint`) cuts the state into
+    pieces on the device, which is all the loop waits for; background
+    threads fetch the pieces under ``checkpoint.ahead()``, write them,
+    and commit the save by a rename, which acknowledges it.  A save does not
+    start before the one before it is acknowledged, so saves are
+    acknowledged in step order, and the time the loop waits for that is
+    counted.  :meth:`resume` takes the newest acknowledged save of a
+    directory as the job's state; what an interrupted save left there
+    is removed, never read.
+
+    ``first``, ``multi``, ``snap`` and ``stage`` are the jitted
+    programs; ``state``, ``step`` and ``calls`` the model as the last
+    enqueued call leaves it; ``series`` the checkpoint's directory (a
+    :class:`checkpoint.Series`) and ``saves`` the acknowledged saves'
+    records, in order.
     """
 
-    def __init__(self, cfg, comm, num_multisteps, snapshot, on_chunk):
+    def __init__(self, cfg, comm, num_multisteps, snapshot, on_chunk,
+                 checkpoint=None):
         self.cfg, self.comm = cfg, comm
         self.num_multisteps = num_multisteps
         self.snapshot, self.on_chunk = snapshot, on_chunk
+        self.checkpoint = checkpoint
         self.first = make_first_step(cfg, comm)
         self.multi = make_multistep(cfg, comm, num_multisteps, donate=True)
         self.snap = snapshot and make_snapshot(cfg, comm, snapshot)
-        self._multi, self._snap, self._kept = self.multi, self.snap, None
-        self.state, self.step = None, 0
+        self._multi, self._snap = self.multi, self.snap
+        self.state, self.step, self.calls = None, 0, 0
         self._pending = collections.deque()  # (step, device arrays), oldest first
         self._asked = 0  # of them, from the oldest: their copies are on their way
         self._asked_bytes = 0
+        self.series = checkpoint and ckpt.Series(
+            checkpoint.directory, keep=checkpoint.keep)
+        self.stage, self._plan, self._save = None, None, None
+        if checkpoint is not None:
+            self._plan = self._piece_plan()
+            self.stage = make_stage(comm, self._plan)
+        self._stage = self.stage
+        self.saves = []
         self._stats = dict(
             snapshots_produced=0, snapshots_delivered=0, max_lag=0,
-            bytes_to_host=0, output_wait_s=0.0, callback_s=0.0)
+            bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
+            saves_started=0, save_bytes=0, save_wait_s=0.0, save_enqueue_s=0.0,
+            restore_read_s=0.0, restore_to_device_s=0.0)
 
     def start(self, state, step=0):
         """Take ``state`` as the model after ``step`` steps.  A state at
@@ -986,6 +1124,7 @@ class SolverJob:
         if step == 0:
             state, step = self.first(state), 1
         self.state, self.step = state, step
+        self.calls = (step - 1) // self.num_multisteps
 
     def compile(self):
         """Compile the call's programs for the state at hand without
@@ -993,42 +1132,184 @@ class SolverJob:
         self._multi = self.multi.lower(self.state).compile()
         if self.snap is not None:
             self._snap = self.snap.lower(*self._written()).compile()
+        if self.stage is not None:
+            self._stage = self.stage.lower(self.state).compile()
 
-    def advance(self, calls=1, *, keep_input=False):
-        """Enqueue ``calls`` multistep calls, after each the snapshot,
-        ask for the copies to the host that are next in line, and
-        deliver every snapshot that is due.  ``keep_input``: the first of the calls
-        does not donate its input (somebody else, an asynchronous
-        save, still reads it); it is the same program compiled without
-        the aliasing, built when first asked for."""
-        for k in range(calls):
-            multi = self._multi
-            if keep_input and k == 0:
-                if self._kept is None:
-                    self._kept = make_multistep(
-                        self.cfg, self.comm, self.num_multisteps)
-                multi = self._kept
-            self.state = multi(self.state)
+    def advance(self, calls=1):
+        """Enqueue ``calls`` multistep calls, after each the snapshot
+        and, where ``checkpoint.every_calls`` says so, a save; ask for
+        the snapshots' copies to the host that are next in line, and
+        deliver every snapshot that is due."""
+        every = self.checkpoint.every_calls if self.checkpoint else 0
+        for _ in range(calls):
+            self.state = self._multi(self.state)
             self.step += self.num_multisteps
+            self.calls += 1
             if self.snap is not None:
                 self._pending.append((self.step, self._snap(*self._written())))
                 self._stats["snapshots_produced"] += 1
                 self._ask()
                 self._deliver(self.snapshot.lag)
+            if every and self.calls % every == 0:
+                self.save()
         return self.state
 
     def drain(self):
-        """Deliver every snapshot still on its way."""
+        """Deliver every snapshot still on its way, wait for the save
+        on its way to be acknowledged, and leave the directory with its
+        committed saves and nothing else (the files a series keeps for
+        its next save go)."""
         self._deliver(0)
+        self._settle()
+        if self.series is not None:
+            self.series.clean()
 
     def stats(self):
         """The job's counters: ``snapshots_produced`` and
         ``snapshots_delivered``; ``max_lag``, the most snapshots one was
         delivered behind the newest; ``bytes_to_host``;
         ``output_wait_s``, host seconds spent fetching snapshots (blocked
-        on a copy that was not ready, or putting shards together); and
-        ``callback_s``, host seconds inside ``on_chunk``."""
-        return dict(self._stats)
+        on a copy that was not ready, or putting shards together);
+        ``callback_s``, host seconds inside ``on_chunk``;
+        ``saves_started``, ``saves_acknowledged`` and ``save_bytes`` (of
+        the saves started); ``save_wait_s``, host seconds the loop was
+        blocked because of a save (waiting for the save before to be
+        acknowledged); ``save_enqueue_s``, host seconds starting saves
+        (enqueueing the staging program and handing its pieces on: host
+        time beside whatever the device has queued); ``save_stage_s`` and
+        ``save_commit_s``, summed over the acknowledged saves: from a
+        save's start to its last piece on the host, and to its rename
+        (both pass beside the loop; ``saves`` has them save by save);
+        ``restore_read_s`` and ``restore_to_device_s``, host seconds a
+        resume spent reading files and handing them to the device."""
+        saves = list(self.saves)
+        return dict(
+            self._stats, saves_acknowledged=len(saves),
+            save_stage_s=sum(r["stage_s"] for r in saves),
+            save_commit_s=sum(r["commit_s"] for r in saves))
+
+    # -- saving and resuming ---------------------------------------------
+
+    def form(self):
+        """What a save of this job holds besides numbers: the global
+        grid, the mesh, the ghost width and the form of the tendencies.
+        A state restores bit for bit into the form it was saved in."""
+        interior = self.cfg.ghost == 2 and not _runs_as_kernels(self.cfg, self.comm)
+        return {
+            "grid": [self.cfg.ny, self.cfg.nx],
+            "mesh": list(self.comm.axis_sizes), "ghost": self.cfg.ghost,
+            "dtype": jnp.dtype(self.cfg.dtype).name,
+            "tendencies": INTERIOR if interior else PADDED,
+        }
+
+    def save(self):
+        """Start a save of the state at hand: wait for the save before
+        it to be acknowledged, enqueue the staging program, and hand its
+        pieces to the threads that fetch and write them.  Returns the
+        :class:`checkpoint.Save`; ``drain()`` waits for it."""
+        if self.checkpoint is None:
+            raise ValueError("the job was made without a `checkpoint`")
+        t0 = time.perf_counter()
+        late = self._save is not None and not self._save.committed
+        self._settle()
+        t1 = time.perf_counter() if late else t0  # nothing awaited, nothing waited
+        if not all(a.is_fully_addressable for a in self.state):
+            raise NotImplementedError(
+                "a job's save goes through the process that enqueued it; a "
+                "mesh over several processes is saved with "
+                "utils.checkpoint.Manager")
+        # a piece is one band of rows of every device's block: in its
+        # file an array's pieces lie one after the other, rows by device
+        py = self.comm.axis_sizes[0]
+        files = {f"{name}.npy": (a.shape, a.dtype)
+                 for name, a in zip(SWState._fields, self.state)}
+        pieces = [((file, py * lo), piece)
+                  for file, plan, of_array in zip(
+                      files, self._plan, self._stage(self.state))
+                  for (lo, _), piece in zip(plan, of_array)]
+        manifest = {
+            "format": 1, "step": self.step, "form": self.form(),
+            "arrays": {name: {"file": file, "shape": list(a.shape), "bands": plan}
+                       for name, a, file, plan in zip(
+                           SWState._fields, self.state, files, self._plan)}}
+        self._save = ckpt.Save(
+            self.series, self.step, manifest, files, pieces,
+            ahead_bytes=self.checkpoint.ahead(self.comm.size),
+            on_commit=self.saves.append)
+        self._stats["saves_started"] += 1
+        self._stats["save_bytes"] += self._save.bytes
+        self._stats["save_wait_s"] += t1 - t0
+        self._stats["save_enqueue_s"] += time.perf_counter() - t1
+        return self._save
+
+    def resume(self, directory=None):
+        """Take the newest acknowledged save of ``directory`` (the
+        ``checkpoint``'s unless given) as the job's state, and compile
+        the call's programs for it.  What an interrupted save left in
+        the directory is removed.  The state is read and sent to the
+        device in the pieces it was saved in, under
+        ``checkpoint.ahead()``.  A save of another grid, mesh or
+        ghost width is refused with both named; one that carries its
+        tendencies in the other form (made where the step is array code
+        and resumed where it is the kernel, or the reverse) is
+        converted.  Returns the step resumed from, or ``None`` where the
+        directory holds no save (the job is then as it was)."""
+        self.drain()
+        series = self.series if directory is None else ckpt.Series(directory)
+        if series is None:
+            raise ValueError("no directory: the job has no `checkpoint`")
+        series.clean()
+        step = series.latest()
+        if step is None:
+            return None
+        manifest = series.manifest(step)
+        mine, saved = self.form(), manifest["form"]
+        for key in ("grid", "mesh", "ghost", "dtype"):
+            if mine[key] != saved[key]:
+                raise ValueError(
+                    f"{series.path(step)} holds a state of {key} {saved[key]} "
+                    f"(its form: {saved}), this job runs {key} {mine[key]} "
+                    f"(its form: {mine}): a save is read back by a job of "
+                    "the same grid, mesh, ghost width and dtype")
+        ahead = (self.checkpoint or Checkpoint(None)).ahead(self.comm.size)
+        sharding = jax.NamedSharding(self.comm.mesh, jax.P(*self.comm.axes))
+        unstage = make_unstage(self.comm)
+        arrays, py = [], self.comm.axis_sizes[0]
+        for name in SWState._fields:
+            held = manifest["arrays"][name]
+            pieces, read_s, to_device_s = ckpt.read_pieces(
+                series.path(step) / held["file"],
+                [(py * lo, py * hi) for lo, hi in held["bands"]], sharding, ahead)
+            arrays.append(unstage(*pieces))
+            self._stats["restore_read_s"] += read_s
+            self._stats["restore_to_device_s"] += to_device_s
+        if saved["tendencies"] != mine["tendencies"]:
+            arrays[3:] = _reshape_tendencies(
+                self.cfg, self.comm, mine["tendencies"])(*arrays[3:])
+        self.start(SWState(*arrays), step=manifest["step"])
+        self.compile()
+        return step
+
+    def _piece_plan(self):
+        """The bands of rows of a device's block that a save cuts each
+        of the state's arrays into (``Checkpoint.piece_bytes``)."""
+        G = self.cfg.ghost
+        ny_l, nx_l = self.cfg.local_interior(self.comm)
+        padded, devices = (ny_l + 2 * G, nx_l + 2 * G), self.comm.size
+        tendency = (ny_l, nx_l) if self.form()["tendencies"] == INTERIOR else padded
+        itemsize = jnp.dtype(self.cfg.dtype).itemsize
+        return tuple(
+            ckpt.piece_rows(rows, width * itemsize,
+                            self.checkpoint.piece_bytes(devices))
+            for rows, width in (padded,) * 3 + (tendency,) * 3)
+
+    def _settle(self):
+        """Wait for the save on its way, if any, to be acknowledged."""
+        if self._save is not None:
+            save, self._save = self._save, None
+            save.wait()
+
+    # -- output ------------------------------------------------------------
 
     def _written(self):
         return tuple(getattr(self.state, k) for k in self.snapshot.fields)
@@ -1067,15 +1348,17 @@ class SolverJob:
             stats["snapshots_delivered"] += 1
 
 
-def make_job(cfg, comm, num_multisteps=10, snapshot=None, on_chunk=None):
+def make_job(cfg, comm, num_multisteps=10, snapshot=None, on_chunk=None,
+             checkpoint=None):
     """The solver's loop as an object (:class:`SolverJob`):
-    ``job.start(state)``, ``job.advance(calls)``, ``job.drain()``,
-    ``job.stats()``.  ``snapshot``: a :class:`Snapshot`,
+    ``job.start(state)`` or ``job.resume()``, ``job.advance(calls)``,
+    ``job.drain()``, ``job.stats()``.  ``snapshot``: a :class:`Snapshot`,
     or ``None`` for a job that writes nothing (``on_chunk`` alone asks
-    for whole fields of ``h``, ``u`` and ``v``)."""
+    for whole fields of ``h``, ``u`` and ``v``).  ``checkpoint``: a
+    :class:`Checkpoint`, or ``None`` for a job that is never saved."""
     if snapshot is None and on_chunk is not None:
         snapshot = Snapshot()
-    return SolverJob(cfg, comm, num_multisteps, snapshot, on_chunk)
+    return SolverJob(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint)
 
 
 def make_solver(
@@ -1106,83 +1389,51 @@ def make_solver(
     output or not; callback time is included in the wall clock.
 
     ``checkpoint_dir`` enables resumable runs (SURVEY §5.4 — absent in
-    the reference): every ``checkpoint_every`` chunks the sharded state
-    and model time are saved via :mod:`mpi4jax_tpu.utils.checkpoint`,
-    and a fresh ``solve`` in the same directory resumes from the latest
-    checkpoint instead of re-initialising.  The chunk after a save is
-    the one that does not donate its input (the asynchronous save still
-    reads it).  Save time is included in the wall clock — don't combine
-    with benchmark timing.
+    the reference): after every ``checkpoint_every`` chunks (counted
+    from the run's first, the warm-up one) the job saves its whole state
+    there (:class:`Checkpoint`, :meth:`SolverJob.save`), and a ``solve``
+    in a directory that holds a save resumes from the newest instead of
+    re-initialising, its programs compiled ahead of the clock.  A save
+    costs the loop the staging program's enqueue; the copies to the
+    host, the files and the commit pass beside the next chunks, unless
+    the next save comes due before the last is acknowledged: then the
+    loop waits, inside the wall clock (``stats()['save_wait_s']``).
+    Every save is acknowledged before ``solve`` returns.
     """
     init = make_init(cfg, comm)
-    job = make_job(cfg, comm, num_multisteps, snapshot, on_chunk)
+    checkpoint = None
+    if checkpoint_dir is not None:
+        checkpoint = Checkpoint(checkpoint_dir, every_calls=checkpoint_every or 0)
+    job = make_job(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint)
+    chunk_s = cfg.dt * num_multisteps
 
     def solve(t1):
-        mgr = None
-        if checkpoint_dir is not None:
-            from mpi4jax_tpu.utils import checkpoint as _ckpt
-
-            mgr = _ckpt.Manager(checkpoint_dir)
-        try:
-            latest = mgr.latest_step() if mgr is not None else None
-            if latest is not None:
-                # resume: restore against an ABSTRACT template (shapes
-                # from eval_shape + the solver's shardings) — no init /
-                # warm-up compute is spent on state that is about to be
-                # replaced.  AOT-compile the chunk's programs so the
-                # timed loop still excludes compilation.
-                chunk = latest
-                resumed = True
-                specs = _mesh_specs(comm)
-                abstract = jax.tree.map(
-                    lambda s, sp: jax.ShapeDtypeStruct(
-                        s.shape,
-                        s.dtype,
-                        sharding=jax.NamedSharding(comm.mesh, sp),
-                    ),
-                    jax.eval_shape(init),
-                    specs,
-                )
-                restored = mgr.restore(
-                    chunk, like={"state": abstract, "t": np.float64(0.0)}
-                )
-                t = float(restored["t"])
-                job.start(SWState(*restored["state"]), step=round(t / cfg.dt))
-                job.compile()
-            else:
-                chunk = 0
-                resumed = False
-                job.start(init())
-                # warm-up compile (excluded from timing, as in the
-                # reference)
-                job.advance()
-                t = cfg.dt + cfg.dt * num_multisteps
-            jax.block_until_ready(job.state)
-            steps = 0
-            saved = False
-            start = time.perf_counter()
-            # always time at least one multistep on a FRESH run, even if
-            # the warm-up call already advanced past t1 (short runs /
-            # large chunks).  A resumed run must not: rerunning a
-            # completed run in the same directory would otherwise push
-            # the trajectory past t1 and save checkpoints beyond it.
-            while t < t1 or (steps == 0 and not resumed):
-                job.advance(keep_input=saved)
-                t += cfg.dt * num_multisteps
-                steps += num_multisteps
-                chunk += 1
-                saved = mgr is not None and mgr.maybe_save(
-                    chunk,
-                    {"state": job.state, "t": np.float64(t)},
-                    every=checkpoint_every,
-                )
-            job.drain()
-            jax.block_until_ready(job.state)
-            wall = time.perf_counter() - start
-            return job.state, wall, steps
-        finally:
-            if mgr is not None:
-                mgr.close()
+        resumed = checkpoint is not None and job.resume() is not None
+        if not resumed:
+            job.start(init())
+            # warm-up compile (excluded from timing, as in the reference)
+            job.advance()
+        # model time summed chunk by chunk, as the loop below sums it,
+        # so that a resumed run stops where an uninterrupted one does
+        t = cfg.dt + chunk_s
+        for _ in range(job.calls - 1):
+            t += chunk_s
+        jax.block_until_ready(job.state)
+        steps = 0
+        start = time.perf_counter()
+        # always time at least one multistep on a FRESH run, even if
+        # the warm-up call already advanced past t1 (short runs /
+        # large chunks).  A resumed run must not: rerunning a
+        # completed run in the same directory would otherwise push
+        # the trajectory past t1 and save checkpoints beyond it.
+        while t < t1 or (steps == 0 and not resumed):
+            job.advance()
+            t += chunk_s
+            steps += num_multisteps
+        job.drain()
+        jax.block_until_ready(job.state)
+        wall = time.perf_counter() - start
+        return job.state, wall, steps
 
     return solve
 

@@ -3,29 +3,58 @@ no checkpointing — its closest analog is gathering the solution to rank
 0 for post-processing, examples/shallow_water.py:586-593 there; this
 module makes resumable state a first-class subsystem).
 
-Built on orbax (the TPU-native checkpoint stack): each device writes its
-own shards (OCDBT), so saving a pod-sharded pytree never funnels the
-whole state through one host — the distributed analog of the
-reference's gather-to-root, without the gather.
+Two ways to disk, one directory layout (``<directory>/<step>/``, a save
+in progress under another name until it is whole):
 
-    from mpi4jax_tpu.utils import checkpoint as ckpt
+* **orbax** (:func:`save`, :func:`restore`, :class:`Manager`): any
+  pytree; each device writes its own shards (OCDBT), so saving a
+  pod-sharded pytree never funnels the whole state through one host.
+  Orbax hands whole arrays to the runtime, which is right for a train
+  step's parameters and wrong for a solver's 415 MB fields under a
+  host's finite staging buffer (``Snapshot.ahead_bytes`` has the
+  numbers).
 
-    ckpt.save(path, {"state": state, "step": step})
-    restored = ckpt.restore(path, like={"state": state, "step": step})
+      ckpt.save(path, {"state": state, "step": step})
+      restored = ckpt.restore(path, like={"state": state, "step": step})
 
-``like`` supplies shapes/dtypes/shardings (pass the live pytree or one
-built from ``jax.eval_shape``); restored arrays come back with the same
-sharding they were saved from, ready to feed the next jitted step.
+  ``like`` supplies shapes/dtypes/shardings (pass the live pytree or one
+  built from ``jax.eval_shape``); restored arrays come back with the
+  same sharding they were saved from.
+
+* **streamed** (:class:`Series`, :class:`Save`, :func:`to_host`,
+  :func:`read_pieces`): arrays that their owner has cut into pieces on
+  the device go to the host with a bound on the bytes asked for and not
+  yet fetched, are written into one ``.npy`` file an array by background
+  threads beside whatever the caller runs next, and are committed by one
+  rename; a restore reads them back under the same bound.  What
+  ``models.shallow_water``'s job saves and resumes through.
 """
 
+import collections
+import functools
+import json
+import os
 import pathlib
+import queue
+import shutil
+import threading
+import time
+
+import numpy as np
 
 import jax
 
-__all__ = ["save", "restore", "latest_step", "Manager"]
+__all__ = [
+    "save", "restore", "latest_step", "Manager",
+    "Series", "Save", "piece_rows", "begin_npy", "write_at", "to_host",
+    "read_pieces",
+]
 
 
+@functools.cache
 def _checkpointer():
+    """The one-shot functions' checkpointer, built when first asked for
+    and kept: it holds no path."""
     import orbax.checkpoint as ocp
 
     return ocp.StandardCheckpointer()
@@ -37,11 +66,9 @@ def save(path, tree, *, force=True):
     Safe for sharded arrays: every process writes only its addressable
     shards.  ``force=True`` overwrites an existing checkpoint.
     """
-    path = pathlib.Path(path).absolute()
     ckptr = _checkpointer()
-    ckptr.save(path, tree, force=force)
+    ckptr.save(pathlib.Path(path).absolute(), tree, force=force)
     ckptr.wait_until_finished()
-    ckptr.close()
 
 
 def restore(path, *, like):
@@ -52,13 +79,8 @@ def restore(path, *, like):
     not read) or abstract leaves from ``jax.eval_shape`` with shardings
     attached.
     """
-    path = pathlib.Path(path).absolute()
-    abstract = jax.tree.map(_abstractify, like)
-    ckptr = _checkpointer()
-    try:
-        return ckptr.restore(path, abstract)
-    finally:
-        ckptr.close()
+    return _checkpointer().restore(
+        pathlib.Path(path).absolute(), jax.tree.map(_abstractify, like))
 
 
 def _abstractify(leaf):
@@ -69,18 +91,9 @@ def _abstractify(leaf):
 
 
 def latest_step(directory):
-    """Highest step number saved by a :class:`Manager` in ``directory``,
-    or None."""
-    import orbax.checkpoint as ocp
-
-    directory = pathlib.Path(directory).absolute()
-    if not directory.exists():
-        return None
-    mgr = ocp.CheckpointManager(directory)
-    try:
-        return mgr.latest_step()
-    finally:
-        mgr.close()
+    """Highest step committed in ``directory`` by a :class:`Manager` or a
+    :class:`Series`, or None: a directory listing, nothing is opened."""
+    return Series(directory).latest()
 
 
 class Manager:
@@ -145,3 +158,322 @@ class Manager:
     def __exit__(self, *exc):
         self.close()
         return False
+
+
+# -- streamed saves -----------------------------------------------------
+
+MANIFEST = "manifest.json"
+# In the name of whatever is not a committed save: a save on its way, or
+# a committed one on its way out.  Orbax's own temporaries are not ours.
+TEMPORARY = ".partial-"
+# The most of a piece that one device holds, and the most bytes a device
+# of a save's pieces asked for and not yet fetched, whatever more the
+# caller's host would take.  Measured on a v5e's host beside a running solver
+# (PERF.md, PR 36): a device runs its copies to the host and its
+# programs through one queue, so a step's launch waits for the copies
+# asked for ahead of it, and a save costs the loop what is in flight:
+# 58 ms a save at 160e6 bytes of 8 MiB pieces, 33 at 64e6, 20 at 32e6,
+# and 12 ms (8 of them the staging program's own) at 16e6 of 4 MiB
+# pieces, which still reach the host at 2.5-5 GB/s.  Pieces of 69 MB
+# cost 150-300 ms a save whatever the bound (a host buffer over glibc's
+# largest mmap threshold, 32 MiB, is mapped and faulted in afresh for
+# every piece).  So the bound is the device's queue's before it is a
+# host's staging buffer's, and the library holds it for every caller.
+PIECE_BYTES = 4 << 20
+AHEAD_BYTES = 16_000_000
+# A piece is written from a buffer its writer keeps, in calls of this
+# size, by this many threads, over the files of a save that has gone
+# where there is one (`Series.begin`).  Read on two filesystems
+# (PERF.md, PR 36), 2.49 GB a save in 4 MiB pieces.  A local ext4 disk,
+# host only: over a save's files 0.36-0.57 s where new files take 0.5 s
+# to 9 s (the disk's own pace, once its cache is full); two writers
+# through their buffers as fast as two without, one writer slower
+# through its buffer (0.55 against 0.38); calls of 256 KiB as fast as
+# calls of 4 MiB (0.57, 0.45).  9p under a sandbox, the chip's machine,
+# beside a running solver: a freshly fetched buffer goes to a file at
+# 1.2 GB/s and one the filesystem has seen before at 2.7-4.4, so two
+# writers through their buffers commit a save in 0.9-1.7 s where two
+# without took 2.2-2.7 and one 2.7; new files were throttled for
+# seconds from a process's fourth save on where files written over
+# never were; and a write holds up whatever else the process asks of
+# its host while it lasts: beside calls of 4 MiB one or two of the
+# loop's batches a run lost 10-40 ms enqueueing, beside calls of
+# 256 KiB none did, and calls of 64 KiB commit too late (2.4-2.6 s).
+WRITE_BYTES = 256 << 10
+WRITERS = 2
+
+
+class Series:
+    """A directory of numbered saves, ``<directory>/<step>/`` each: one
+    ``.npy`` file an array and a ``manifest.json``, written last.  A save
+    is written under ``<step>.partial-<pid>`` and committed by one
+    rename, so a directory named by digits alone is whole; whatever an
+    interrupted save left is never taken for one, and :meth:`clean`
+    removes it.  ``keep``: the newest saves left standing (``None``:
+    all).  A save beyond them goes only once the new one is committed,
+    so from the ``keep``-th commit on ``keep`` saves stand committed at
+    every moment; the newest of those that go is kept under a temporary
+    name as the next save's files (:meth:`prune`, :meth:`begin`), so a
+    series holds ``keep + 1`` saves' bytes, as it would while a save is
+    written, and from then on no save makes or removes a file.  Durable
+    against the death of the process, not of the machine: nothing is
+    ``fsync``ed."""
+
+    def __init__(self, directory, *, keep=None):
+        self.directory = pathlib.Path(directory).absolute()
+        self.keep = keep
+
+    def steps(self):
+        """The committed steps, ascending."""
+        if not self.directory.is_dir():
+            return []
+        return sorted(int(p.name) for p in self.directory.iterdir()
+                      if p.name.isdigit() and p.is_dir())
+
+    def latest(self):
+        steps = self.steps()
+        return steps[-1] if steps else None
+
+    def path(self, step):
+        return self.directory / str(step)
+
+    def leftovers(self):
+        return sorted(self.directory.glob(f"*{TEMPORARY}*"))
+
+    def clean(self):
+        """Remove what an interrupted save left behind, and the spare
+        files kept for the next one; returns how many there were.  For
+        the one process that writes the directory, before it writes or
+        when it is done."""
+        left = self.leftovers()
+        for path in left:
+            shutil.rmtree(path, ignore_errors=True)
+        return len(left)
+
+    def begin(self, step):
+        """The directory a save of ``step`` is written in: the spare one
+        that :meth:`prune` left, files and all, for the new save to
+        write over, or a new one."""
+        tmp = self.directory / f"{step}{TEMPORARY}{os.getpid()}"
+        shutil.rmtree(tmp, ignore_errors=True)
+        if self._spare().is_dir():
+            os.rename(self._spare(), tmp)
+            (tmp / MANIFEST).unlink(missing_ok=True)
+        else:
+            tmp.mkdir(parents=True)
+        return tmp
+
+    def commit(self, tmp, step):
+        """``tmp`` becomes the save of ``step``, in the place of an
+        older one of that step."""
+        final = self.path(step)
+        if final.exists():
+            self._retire(final)
+        os.rename(tmp, final)
+
+    def prune(self):
+        """The saves beyond the newest ``keep`` go: out of the committed
+        names first (a removal cut short leaves a leftover, not half a
+        save), the newest of them to be the next save's files."""
+        if self.keep:
+            for old in self.steps()[:-self.keep]:
+                self._retire(self.path(old))
+
+    def _spare(self):
+        return self.directory / f"spare{TEMPORARY}{os.getpid()}"
+
+    def _retire(self, path):
+        shutil.rmtree(self._spare(), ignore_errors=True)
+        os.rename(path, self._spare())
+
+    def manifest(self, step):
+        path = self.path(step) / MANIFEST
+        if not path.is_file():
+            raise ValueError(
+                f"{self.path(step)} holds no {MANIFEST}: not a streamed "
+                "save of this module (one of orbax's is read by `restore` "
+                "or `Manager.restore`)")
+        return json.loads(path.read_text())
+
+
+def piece_rows(rows, row_bytes, piece_bytes=PIECE_BYTES):
+    """``[(lo, hi), ...]``: ``rows`` rows of ``row_bytes`` each cut into
+    the fewest bands of at most ``piece_bytes``, as even as they come
+    (a row wider than that is a band of its own)."""
+    most = max(int(piece_bytes // row_bytes), 1)
+    n = -(-rows // most)
+    edges = [round(i * rows / n) for i in range(n + 1)]
+    return list(zip(edges, edges[1:]))
+
+
+def begin_npy(path, shape, dtype):
+    """Make ``path`` the ``.npy`` file of an array of ``shape`` and
+    ``dtype`` whose rows are still to come, over a file that is there
+    (its bytes stay where the new array's will lie) or anew; returns
+    ``(fd, where its first row goes)``."""
+    fd = os.open(path, os.O_WRONLY | os.O_CREAT, 0o644)
+    with os.fdopen(os.dup(fd), "wb") as f:
+        np.lib.format.write_array_header_1_0(f, {
+            "descr": np.lib.format.dtype_to_descr(np.dtype(dtype)),
+            "fortran_order": False, "shape": tuple(shape)})
+        start = f.tell()
+    os.ftruncate(fd, start + int(np.prod(shape)) * np.dtype(dtype).itemsize)
+    return fd, start
+
+
+def write_at(fd, offset, array, bounce):
+    """``array``'s bytes into ``fd`` at ``offset``, in calls of
+    ``bounce``'s size, each from ``bounce`` (a ``uint8`` buffer the
+    caller keeps).  Positional, so threads may share ``fd``."""
+    data = np.ascontiguousarray(array).reshape(-1).view(np.uint8)
+    for at in range(0, data.size, bounce.size):
+        n = min(bounce.size, data.size - at)
+        np.copyto(bounce[:n], data[at:at + n])
+        os.pwrite(fd, memoryview(bounce)[:n], offset + at)
+
+
+def to_host(pieces, ahead_bytes=None):
+    """``(key, device array)`` pairs → ``(key, numpy array)`` pairs,
+    in order.  The copies to the host are asked for ahead of the fetch,
+    oldest first, as far as ``ahead_bytes`` goes: at most that many
+    bytes asked for and not yet fetched, the oldest piece's always
+    (``None``: all of them at once).  Takes ``pieces`` (a list) apart as
+    it goes, so that each device array is released once it is fetched."""
+    waiting = collections.deque(pieces)
+    del pieces[:]
+    asked = asked_bytes = 0
+    while waiting:
+        while asked < len(waiting):
+            size = waiting[asked][1].nbytes
+            if asked and ahead_bytes is not None and asked_bytes + size > ahead_bytes:
+                break
+            waiting[asked][1].copy_to_host_async()
+            asked += 1
+            asked_bytes += size
+        name, piece = waiting.popleft()
+        host = np.asarray(piece)
+        asked -= 1
+        asked_bytes -= piece.nbytes
+        del piece
+        yield name, host
+
+
+class Save:
+    """One streamed save on its way.  ``files``: ``{file name: (shape,
+    dtype)}``, the arrays as they will lie on disk; ``pieces``:
+    ``((file name, first row), device array)`` pairs, bands of rows of
+    those arrays, which the save takes over.  The pieces go to the host
+    under ``ahead_bytes`` on one thread and into their files on
+    ``WRITERS`` others, then ``manifest`` is written and the save
+    committed; ``on_commit(record)`` is then called from the first
+    thread, ``record`` holding ``step``, ``bytes``, ``stage_s`` (start
+    to the last piece on the host) and ``commit_s`` (start to the
+    rename).  :meth:`wait` blocks until the save is committed and the
+    series pruned, and raises what stopped it.
+
+    A file an array, not a file a piece (six files a save, not 606),
+    written by position so that the writers share it."""
+
+    def __init__(self, series, step, manifest, files, pieces, *,
+                 ahead_bytes=None, on_commit=None):
+        self.step = step
+        self.bytes = sum(piece.nbytes for _, piece in pieces)
+        self.record = None
+        self._error = None
+        self._t0 = time.perf_counter()
+        self._host = queue.Queue()
+        self._done = threading.Event()
+        threading.Thread(
+            target=self._run, daemon=True,
+            args=(series, manifest, files, pieces, ahead_bytes, on_commit)).start()
+
+    @property
+    def committed(self):
+        return self.record is not None
+
+    def wait(self):
+        self._done.wait()
+        if self._error is not None:
+            raise RuntimeError(
+                f"the save of step {self.step} failed") from self._error
+        return self.record
+
+    def _run(self, series, manifest, files, pieces, ahead_bytes, on_commit):
+        opened = {}
+        try:
+            tmp = series.begin(self.step)
+            for stale in set(p.name for p in tmp.iterdir()) - set(files):
+                (tmp / stale).unlink()
+            for name, (shape, dtype) in files.items():
+                fd, start = begin_npy(tmp / name, shape, dtype)
+                row_bytes = int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+                opened[name] = fd, start, row_bytes
+            writers = [threading.Thread(target=self._write, args=(opened,), daemon=True)
+                       for _ in range(WRITERS)]
+            for writer in writers:
+                writer.start()
+            try:
+                for item in to_host(pieces, ahead_bytes):
+                    self._host.put(item)
+                staged_s = time.perf_counter() - self._t0
+            finally:
+                for writer in writers:
+                    self._host.put(None)
+                for writer in writers:
+                    writer.join()
+                while opened:
+                    os.close(opened.popitem()[1][0])
+            if self._error is None:
+                (tmp / MANIFEST).write_text(json.dumps(manifest))
+                series.commit(tmp, self.step)
+                self.record = {
+                    "step": self.step, "bytes": self.bytes, "stage_s": staged_s,
+                    "commit_s": time.perf_counter() - self._t0}
+                if on_commit is not None:
+                    on_commit(self.record)
+                series.prune()
+        except BaseException as error:  # handed to whoever waits
+            self._error = self._error or error
+        finally:
+            self._done.set()
+
+    def _write(self, opened):
+        bounce = np.empty(WRITE_BYTES, np.uint8)
+        while (item := self._host.get()) is not None:
+            try:
+                if self._error is None:
+                    (name, row), host = item
+                    fd, start, row_bytes = opened[name]
+                    write_at(fd, start + row * row_bytes, host, bounce)
+            except BaseException as error:
+                self._error = self._error or error
+
+
+def read_pieces(path, bands, sharding, ahead_bytes=None):
+    """The bands of rows ``bands`` (``[(lo, hi), ...]``, ascending) of
+    the ``.npy`` file ``path`` as device arrays of ``sharding``, in
+    order, with the copies to the device that are not yet done held
+    under ``ahead_bytes`` (the newest's always; ``None``: no bound).
+    Returns ``(arrays, read_s, to_device_s)``: host seconds reading the
+    file, and handing its bands to the device or waiting for it."""
+    arrays, flying, flying_bytes = [], collections.deque(), 0
+    read_s = to_device_s = 0.0
+    with open(path, "rb") as f:
+        np.lib.format.read_magic(f)
+        shape, _, dtype = np.lib.format.read_array_header_1_0(f)
+        start, row = f.tell(), int(np.prod(shape[1:]))
+        for lo, hi in bands:
+            t0 = time.perf_counter()
+            f.seek(start + lo * row * dtype.itemsize)
+            host = np.fromfile(f, dtype, (hi - lo) * row).reshape(
+                (hi - lo,) + tuple(shape[1:]))
+            t1 = time.perf_counter()
+            while (flying and ahead_bytes is not None
+                   and flying_bytes + host.nbytes > ahead_bytes):
+                flying_bytes -= jax.block_until_ready(flying.popleft()).nbytes
+            arrays.append(jax.device_put(host, sharding))
+            flying.append(arrays[-1])
+            flying_bytes += host.nbytes
+            read_s += t1 - t0
+            to_device_s += time.perf_counter() - t1
+    return arrays, read_s, to_device_s

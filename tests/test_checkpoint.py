@@ -3,6 +3,8 @@ reference, first-class here): sharded round-trips, stepped manager with
 retention, and bit-identical solver resume."""
 
 import dataclasses
+import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -179,3 +181,285 @@ def test_moe_transformer_resume_bit_identical(tmp_path):
     np.testing.assert_array_equal(np.asarray(loss_c), np.asarray(loss_r))
     for a, b in zip(jax.tree.leaves(cont), jax.tree.leaves(resumed)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- streamed saves: the directory, the bound on the copies, a real kill ----
+
+
+def test_a_series_commits_by_one_rename_and_keeps_the_newest(tmp_path):
+    series = ckpt.Series(tmp_path / "run", keep=2)
+    assert series.steps() == [] and series.latest() is None
+    assert ckpt.latest_step(tmp_path / "run") is None
+    for step in (11, 21, 31):
+        tmp = series.begin(step)
+        np.save(tmp / "x.0.npy", np.full(3, step))
+        # a save on its way is no save: not listed, not the latest
+        assert step not in series.steps() and series.leftovers() == [tmp]
+        (tmp / ckpt.MANIFEST).write_text('{"step": %d}' % step)
+        series.commit(tmp, step)
+        # an older save goes only after the commit: `keep` stand meanwhile
+        assert series.steps() == [s for s in (11, 21, 31) if s <= step]
+        series.prune()
+        assert series.latest() == step and series.steps() == [
+            s for s in (11, 21, 31) if step - 10 <= s <= step]
+    assert series.steps() == [21, 31] and ckpt.latest_step(tmp_path / "run") == 31
+    assert series.manifest(31) == {"step": 31}
+    # the save that went is the next one's directory, its files for the
+    # new save to write over and its manifest gone; it is no save
+    spare, = series.leftovers()
+    assert (spare / "x.0.npy").exists() and (spare / ckpt.MANIFEST).exists()
+    tmp = series.begin(41)
+    assert series.steps() == [21, 31] and series.leftovers() == [tmp]
+    assert [p.name for p in tmp.iterdir()] == ["x.0.npy"]
+    (tmp / ckpt.MANIFEST).write_text('{"step": 41}')
+    series.commit(tmp, 41)
+    series.prune()
+    assert series.steps() == [31, 41] and len(series.leftovers()) == 1
+    # committing a step again replaces it
+    tmp = series.begin(41)
+    assert series.steps() == [31, 41]
+    (tmp / ckpt.MANIFEST).write_text('{"step": 41, "again": true}')
+    series.commit(tmp, 41)
+    assert series.steps() == [31, 41] and series.manifest(41)["again"]
+    os.rename(series.path(31), series.path(21))
+    # what an interrupted save left, and the spare files, are cleaned,
+    # never read
+    (series.directory / "41.partial-99").mkdir()
+    assert series.latest() == 41 and series.clean() == 2
+    assert sorted(p.name for p in series.directory.iterdir()) == ["21", "41"]
+    # a directory without a manifest is somebody else's
+    (series.directory / "51").mkdir()
+    with pytest.raises(ValueError, match="not a streamed save"):
+        series.manifest(51)
+
+
+def test_latest_step_reads_a_managers_directory_too(tmp_path):
+    with ckpt.Manager(tmp_path / "m", max_to_keep=3) as mgr:
+        for step in (2, 4):
+            mgr.save(step, {"v": jnp.float32(step)})
+    assert ckpt.latest_step(tmp_path / "m") == 4
+    assert ckpt.Series(tmp_path / "m").steps() == [2, 4]
+
+
+@pytest.mark.parametrize("rows, row_bytes, piece_bytes, want", [
+    (7204, 14404 * 4, 80e6, [1201, 1200, 1201, 1201, 1200, 1201]),
+    (10, 100, 64 << 20, [10]),
+    (10, 100, 250, [2, 2, 2, 2, 2]),
+    (3, 100, 10, [1, 1, 1]),  # a row wider than a piece is a piece
+])
+def test_piece_rows_cuts_even_bands_under_the_bound(rows, row_bytes, piece_bytes, want):
+    bands = ckpt.piece_rows(rows, row_bytes, piece_bytes)
+    assert [hi - lo for lo, hi in bands] == want
+    assert bands[0][0] == 0 and bands[-1][1] == rows
+    assert all(a[1] == b[0] for a, b in zip(bands, bands[1:]))
+
+
+class _FakePiece:
+    """A device array that counts what is asked for and not fetched."""
+
+    flying = 0
+    most = 0
+    log = []
+
+    def __init__(self, k, nbytes):
+        self.k, self.nbytes, self.asked = k, nbytes, False
+
+    def copy_to_host_async(self):
+        assert not self.asked
+        self.asked = True
+        cls = _FakePiece
+        cls.flying += self.nbytes
+        cls.most = max(cls.most, cls.flying)
+        cls.log.append(("ask", self.k))
+
+    def __array__(self, dtype=None, copy=None):
+        assert self.asked, "fetched before its copy was asked for"
+        _FakePiece.flying -= self.nbytes
+        _FakePiece.log.append(("fetch", self.k))
+        return np.full(2, self.k)
+
+
+@pytest.mark.parametrize("ahead, most", [
+    (None, 600), (250, 200), (200, 200), (199, 100), (50, 100)])
+def test_copies_to_the_host_stay_under_ahead_bytes(ahead, most):
+    """At most ``ahead_bytes`` asked for and not yet fetched, the
+    oldest piece's always; asked and fetched oldest first; every piece
+    arrives, in order, and the list handed over is taken apart."""
+    _FakePiece.flying = _FakePiece.most = 0
+    _FakePiece.log = []
+    pieces = [(f"p{k}", _FakePiece(k, 100)) for k in range(6)]
+    got = [(name, int(host[0])) for name, host in ckpt.to_host(pieces, ahead)]
+    assert got == [(f"p{k}", k) for k in range(6)] and pieces == []
+    assert _FakePiece.most == most and _FakePiece.flying == 0
+    for kind in ("ask", "fetch"):
+        assert [k for what, k in _FakePiece.log if what == kind] == list(range(6))
+
+
+@pytest.mark.parametrize("ahead, most", [(None, 5), (2000, 2), (900, 1)])
+def test_a_restores_copies_to_the_device_stay_under_ahead_bytes(
+        tmp_path, monkeypatch, ahead, most):
+    whole = np.arange(5 * 200, dtype=np.float32).reshape(50, 20)
+    np.save(tmp_path / "a.npy", whole)
+    bands = [(10 * k, 10 * k + 10) for k in range(5)]  # 800 bytes each
+    flying, seen = [], []
+    put, done = jax.device_put, jax.block_until_ready
+
+    def counting_put(host, sharding):
+        flying.append(host.nbytes)
+        seen.append(len(flying))
+        return put(host, sharding)
+
+    def counting_done(x):
+        flying.pop(0)
+        return done(x)
+
+    monkeypatch.setattr(ckpt.jax, "device_put", counting_put)
+    monkeypatch.setattr(ckpt.jax, "block_until_ready", counting_done)
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    arrays, read_s, to_device_s = ckpt.read_pieces(
+        tmp_path / "a.npy", bands, sharding, ahead)
+    for (lo, hi), a in zip(bands, arrays):
+        np.testing.assert_array_equal(np.asarray(a), whole[lo:hi])
+    assert max(seen) == most and read_s > 0 and to_device_s > 0
+
+
+def test_a_saves_pieces_land_in_one_file_an_array_whatever_their_order(tmp_path):
+    """Two writers share a file by position: bands written out of
+    order, in calls smaller than a band, make the array ``numpy.load``
+    reads; and a save of host arrays goes through the same path."""
+    whole = np.arange(7 * 5, dtype=np.float32).reshape(7, 5)
+    fd, start = ckpt.begin_npy(tmp_path / "a.npy", whole.shape, whole.dtype)
+    bounce = np.empty(16, np.uint8)
+    for lo, hi in ((4, 7), (0, 2), (2, 4)):
+        ckpt.write_at(fd, start + lo * 5 * 4, whole[lo:hi], bounce)
+    __import__("os").close(fd)
+    np.testing.assert_array_equal(np.load(tmp_path / "a.npy"), whole)
+
+    series = ckpt.Series(tmp_path / "run", keep=1)
+    for step in (3, 4):
+        pieces = [(("a.npy", lo), _Host(whole[lo:hi] + step))
+                  for lo, hi in ((0, 3), (3, 7))]
+        ckpt.Save(series, step, {"step": step},
+                  {"a.npy": (whole.shape, whole.dtype)}, pieces, ahead_bytes=64).wait()
+    # the save that went stands by as the next one's files, until cleaned
+    assert series.steps() == [4] and len(series.leftovers()) == 1
+    assert series.clean() == 1 and series.steps() == [4]
+    np.testing.assert_array_equal(np.load(series.path(4) / "a.npy"), whole + 4)
+    assert series.manifest(4) == {"step": 4}
+
+
+class _Host:
+    """A piece that is on the host already."""
+
+    def __init__(self, array):
+        self.array, self.nbytes = array, array.nbytes
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        return self.array
+
+
+def test_a_failed_save_is_raised_to_whoever_waits_and_commits_nothing(tmp_path):
+    series = ckpt.Series(tmp_path / "run")
+
+    class Broken(_FakePiece):
+        def __array__(self, dtype=None, copy=None):
+            raise OSError("the copy failed")
+
+    save = ckpt.Save(series, 7, {"step": 7}, {"a.npy": ((2,), np.float32)},
+                     [(("a.npy", 0), Broken(0, 8))])
+    with pytest.raises(RuntimeError, match="save of step 7 failed") as info:
+        save.wait()
+    assert isinstance(info.value.__cause__, OSError) and not save.committed
+    assert series.steps() == [] and series.clean() == 1
+
+
+_KILLED_CHILD = '''
+import sys, time
+import numpy as np
+import jax
+import mpi4jax_tpu as m
+from mpi4jax_tpu.models import shallow_water as sw
+from mpi4jax_tpu.utils import checkpoint as ckpt
+
+directory, out, t1 = sys.argv[1], sys.argv[2], float(sys.argv[3])
+slow = float(sys.argv[4])
+write = ckpt.write_at
+ckpt.write_at = lambda *a: (time.sleep(slow), write(*a))  # a slow disk
+mesh = jax.make_mesh((2, 2), ("y", "x"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+cfg = sw.SWConfig(ny=16, nx=32, ghost=2)
+solve = sw.make_solver(cfg, m.MeshComm.from_mesh(mesh), num_multisteps=5,
+                       checkpoint_dir=directory)
+state, _, steps = solve(t1)
+np.savez(out, steps=steps, **{k: np.asarray(a) for k, a in state._asdict().items()})
+'''
+
+
+def test_a_killed_run_resumes_from_its_last_acknowledged_save(tmp_path):
+    """A real kill: a child process running ``make_solver(checkpoint_dir=
+    ...)`` is sent SIGKILL after its second acknowledged save and while
+    a third is being written; a second child resumes in the directory;
+    the end state is the uninterrupted run's bit for bit and the
+    directory holds no temporary."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    import time
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    cfg = sw.SWConfig(ny=16, nx=32, ghost=2)
+    t1 = cfg.dt * (1 + 5) + cfg.dt * 5 * 7  # the warm-up chunk and seven more
+    ck, script = tmp_path / "run", tmp_path / "child.py"
+    script.write_text(_KILLED_CHILD)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [str(pathlib.Path(__file__).resolve().parents[1])]
+                   + sys.path))
+
+    def child(out, slow):
+        return subprocess.Popen(
+            [sys.executable, str(script), str(ck), str(out), repr(t1), str(slow)],
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+
+    series = ckpt.Series(ck)
+    first = child(tmp_path / "killed.npz", 0.05)
+    deadline = time.time() + 120
+    seen = set()  # the saves acknowledged so far (the oldest goes as a new one starts)
+    try:
+        while True:
+            committed, left = series.steps(), series.leftovers()
+            seen.update(committed)
+            if len(seen) >= 2 and committed and left:
+                first.send_signal(signal.SIGKILL)
+                break
+            assert first.poll() is None, first.stdout.read()
+            assert time.time() < deadline, "no third save within 120 s"
+            time.sleep(0.002)
+    finally:
+        first.kill()
+    first.wait()
+    assert first.returncode == -signal.SIGKILL
+    assert not (tmp_path / "killed.npz").exists()
+    # what the kill left: an acknowledged save or two and half a save
+    committed = series.steps()
+    assert committed and series.leftovers()
+    assert series.latest() < 1 + 5 * 8
+
+    second = child(tmp_path / "resumed.npz", 0)
+    assert second.wait(timeout=120) == 0, second.stdout.read()
+    resumed = np.load(tmp_path / "resumed.npz")
+    assert int(resumed["steps"]) == 1 + 5 * 8 - committed[-1]
+    assert not series.leftovers() and series.latest() == 1 + 5 * 8
+
+    mesh = jax.make_mesh((2, 2), ("y", "x"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=jax.devices()[:4])
+    whole, _, _ = sw.make_solver(cfg, m.MeshComm.from_mesh(mesh), num_multisteps=5)(t1)
+    for name, want in whole._asdict().items():
+        np.testing.assert_array_equal(resumed[name], np.asarray(want), err_msg=name)

@@ -58,6 +58,16 @@ def test_solver_job_check_tiny():
     assert "on 2x2 and 1x1" in out["compared"]
 
 
+def test_solver_restart_check_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    out = chip_smoke.solver_restart_check(
+        cfg, cpu, mesh_shapes=((2, 2), (1, 1)), steps_per_call=5, calls=3)
+    assert 0 < out["meshes_max_diff"] <= chip_smoke.TOL_SAME_ARITHMETIC
+    assert out["save_bytes"] == 3 * (28 * 52 + 24 * 48) * 4
+    assert "dropped after 3 and resumed" in out["compared"]
+
+
 @pytest.mark.parametrize("n", [1, 4, 8])
 def test_ops_check(n):
     assert chip_smoke.ops_check(jax.devices()[:n])["max_diff"] == 0.0

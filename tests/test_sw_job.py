@@ -178,20 +178,22 @@ def test_a_job_with_output_returns_the_state_of_a_job_without(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_every_call_consumes_its_input_but_the_one_asked_to_keep_it(seeded):
+def test_every_call_consumes_its_input_output_or_not_saved_or_not(
+        seeded, tmp_path):
     cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
-    job, _ = _run(cfg, _comm((1, 1)), seeded, sw.Snapshot(coarsen=COARSEN),
-                  calls=1)
-    before = job.state
-    held = [np.asarray(a).copy() for a in before]
-    job.advance(keep_input=True)
-    for a, b in zip(before, held):  # somebody's asynchronous save reads on
-        np.testing.assert_array_equal(np.asarray(a), b)
-    taken = job.state
-    job.advance()
+    comm = _comm((1, 1))
+    job = sw.make_job(cfg, comm, STEPS_A_CALL, sw.Snapshot(coarsen=COARSEN),
+                      checkpoint=sw.Checkpoint(tmp_path / "run", every_calls=1))
+    job.start(_state(cfg, comm, seeded))
+    for _ in range(3):
+        job.advance()  # a snapshot and a save of what it returns
+        taken = job.state
+        job.advance()
+        # the call after them took every array of its input: what is
+        # written and what is saved are copies made before it ran
+        assert all(a.is_deleted() for a in taken)
     job.drain()
-    # output or not, the call took every array of its input
-    assert all(a.is_deleted() for a in taken)
+    assert job.stats()["saves_acknowledged"] == job.stats()["snapshots_delivered"] == 6
 
 
 def test_order_count_and_lag_hold_under_a_slow_callback(seeded):
@@ -329,3 +331,208 @@ def test_the_example_animates_through_the_job(tmp_path):
     example.main(["--check", "--force-cpu", "--mesh", "2", "2", "--multistep", "5",
                   "--animate", str(out), "--coarsen", "2"])
     assert out.stat().st_size > 0
+
+
+# -- the job saved, killed and resumed ------------------------------------
+
+
+def _steps_of(got):
+    return [step for step, _ in got]
+
+
+@pytest.mark.parametrize("with_output", [False, True])
+@pytest.mark.parametrize("ghost", [1, 2, 4])
+def test_a_resumed_job_continues_the_killed_one_bit_for_bit(
+        seeded, tmp_path, ghost, with_output):
+    """On ``comm2d``'s mesh: a job saved every two calls and dropped
+    after its fourth, a new job resumed from the directory: the state
+    two calls on is the uninterrupted job's in every bit of its six
+    arrays, tendencies among them (the step after a resume is
+    Adams-Bashforth, not forward Euler), and the two jobs' snapshots
+    are the uninterrupted one's, each once."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=ghost)
+    comm = _comm((2, 2))
+    snapshot = sw.Snapshot(coarsen=COARSEN, lag=1) if with_output else None
+    whole, whole_got = _run(cfg, comm, seeded, snapshot, calls=6)
+    got = []
+    ck = sw.Checkpoint(tmp_path / "run", every_calls=2)
+
+    def job():
+        return sw.make_job(
+            cfg, comm, STEPS_A_CALL, snapshot,
+            snapshot and (lambda s, step: got.append((step, s))), ck)
+
+    killed = job()
+    killed.start(_state(cfg, comm, seeded))
+    killed.advance(4)
+    killed.drain()
+    assert [r["step"] for r in killed.saves] == [21, 41]
+    del killed
+    resumed = job()
+    assert resumed.resume() == 41 and (resumed.step, resumed.calls) == (41, 4)
+    resumed.advance(2)
+    resumed.drain()
+    assert resumed.step == whole.step == 61
+    for name, a, b in zip(sw.SWState._fields, resumed.state, whole.state):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    assert [r["step"] for r in resumed.saves] == [61]
+    assert _steps_of(got) == _steps_of(whole_got)
+    for (_, mine), (_, theirs) in zip(got, whole_got):
+        for k in mine:
+            np.testing.assert_array_equal(mine[k], theirs[k])
+    # a resume that drops the tendencies is another run
+    dropped = job()
+    dropped.resume()
+    zeros = {k: jnp.zeros_like(getattr(dropped.state, k)) for k in ("dh", "du", "dv")}
+    dropped.state = dropped.state._replace(**zeros)
+    dropped.advance(2)
+    assert np.abs(np.asarray(dropped.state.h) - np.asarray(whole.state.h)).max() > 1e-4
+
+
+def test_a_save_holds_the_step_it_names_though_later_calls_run_first(
+        seeded, tmp_path, monkeypatch):
+    """No tearing under donation: the save's pieces are cut before the
+    next call is enqueued, and that call and two more have consumed the
+    state, each donating its input, before the first piece is fetched."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm((1, 1))
+    job = sw.make_job(cfg, comm, STEPS_A_CALL,
+                      checkpoint=sw.Checkpoint(tmp_path / "run", every_calls=0))
+    job.start(_state(cfg, comm, seeded))
+    job.advance(2)
+    saved_state = job.state
+    want = [np.asarray(a).copy() for a in saved_state]
+    go = __import__("threading").Event()
+    to_host = sw.ckpt.to_host
+
+    def late(pieces, ahead_bytes=None):
+        go.wait(60)
+        yield from to_host(pieces, ahead_bytes)
+
+    monkeypatch.setattr(sw.ckpt, "to_host", late)
+    save = job.save()
+    job.advance(3)
+    jax.block_until_ready(job.state)
+    assert all(a.is_deleted() for a in saved_state) and not save.committed
+    go.set()
+    job.drain()
+    assert save.committed and save.record["step"] == 21 and job.step == 51
+    fresh = sw.make_job(cfg, comm, STEPS_A_CALL)
+    assert fresh.resume(tmp_path / "run") == 21
+    for name, a, b in zip(sw.SWState._fields, fresh.state, want):
+        np.testing.assert_array_equal(np.asarray(a), b, err_msg=name)
+
+
+def test_saves_are_acknowledged_in_order_and_the_newest_are_kept(
+        seeded, tmp_path, monkeypatch):
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm((2, 2))
+    held = []
+    commit, prune = sw.ckpt.Series.commit, sw.ckpt.Series.prune
+
+    def watched(self, tmp, step):
+        commit(self, tmp, step)
+        held.append(self.steps())
+
+    def pruned(self):
+        prune(self)
+        held.append(self.steps())
+
+    monkeypatch.setattr(sw.ckpt.Series, "commit", watched)
+    monkeypatch.setattr(sw.ckpt.Series, "prune", pruned)
+    job = sw.make_job(
+        cfg, comm, STEPS_A_CALL,
+        checkpoint=sw.Checkpoint(tmp_path / "run", every_calls=1, keep=2))
+    job.start(_state(cfg, comm, seeded))
+    job.advance(5)
+    assert job.stats()["saves_started"] == 5  # the fifth may be on its way
+    job.drain()
+    stats = job.stats()
+    assert stats["saves_started"] == stats["saves_acknowledged"] == 5
+    assert [r["step"] for r in job.saves] == [11, 21, 31, 41, 51]
+    # an older save goes only once the new one is committed: from the
+    # `keep`-th commit on the directory never holds fewer than `keep`
+    assert held == [[11], [11], [11, 21], [11, 21], [11, 21, 31], [21, 31],
+                    [21, 31, 41], [31, 41], [31, 41, 51], [41, 51]]
+    assert not job.series.leftovers()  # drain() took the spare files away
+    state_bytes = sum(a.nbytes for a in job.state)
+    assert stats["save_bytes"] == 5 * state_bytes
+    assert all(r["bytes"] == state_bytes and 0 < r["stage_s"] <= r["commit_s"]
+               for r in job.saves)
+    # waiting for the save before is the loop blocked; starting one is not
+    assert stats["save_wait_s"] > 0 and stats["save_enqueue_s"] > 0
+    assert stats["save_commit_s"] > 0
+    # a job without a checkpoint saves nothing and says so
+    bare = sw.make_job(cfg, comm, STEPS_A_CALL)
+    with pytest.raises(ValueError, match="without a `checkpoint`"):
+        bare.save()
+    with pytest.raises(ValueError, match="no directory"):
+        bare.resume()
+    assert sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=sw.Checkpoint(
+        tmp_path / "empty")).resume() is None
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+@pytest.mark.parametrize("ahead", [None, 4096, 1500])
+def test_a_saves_pieces_are_bounded_by_ahead_bytes(
+        seeded, tmp_path, mesh_shape, ahead):
+    """A piece, all devices' bands of it together, is at most half of
+    ``ahead_bytes`` (a row of every block at least), so that one copy
+    runs while the next waits; an array's pieces lie in one file; a
+    restore reads them back under the same bound, bit for bit."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    ck = sw.Checkpoint(tmp_path / "run", every_calls=0, ahead_bytes=ahead)
+    job = sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=ck)
+    job.start(_state(cfg, comm, seeded))
+    job.advance(1)
+    job.save()
+    job.drain()
+    manifest = job.series.manifest(11)
+    assert manifest["step"] == 11 and manifest["form"] == job.form()
+    py, px = mesh_shape
+    row = (NX // px + 4) * 4 * py * px  # one row of every device's block
+    band = ck.piece_bytes(py * px)
+    most = max(band * py * px, row)
+    assert ck.ahead(py * px) == (ahead or sw.ckpt.AHEAD_BYTES * py * px)
+    assert band == min(sw.ckpt.PIECE_BYTES, ck.ahead(py * px) // 2 // (py * px))
+    for name, a, plan in zip(sw.SWState._fields, job.state, job._plan):
+        held = manifest["arrays"][name]
+        assert held["shape"] == list(a.shape) and held["file"] == f"{name}.npy"
+        bands = [tuple(band) for band in held["bands"]]
+        assert bands == plan and bands[0][0] == 0 and bands[-1][1] == a.shape[0] // py
+        assert all(a[1] == b[0] for a, b in zip(bands, bands[1:]))
+        width = a.shape[1] * a.dtype.itemsize
+        assert all((hi - lo) * py * width <= most for lo, hi in bands)
+        assert (len(bands) == 1) == (ahead is None)
+        # one file an array, whole
+        assert np.load(job.series.path(11) / held["file"]).shape == a.shape
+    fresh = sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=ck)
+    assert fresh.resume() == 11
+    for a, b in zip(fresh.state, job.state):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert fresh.stats()["restore_read_s"] > 0
+
+
+def test_a_save_of_another_grid_mesh_or_schedule_is_refused_with_both_named(
+        seeded, tmp_path):
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    ck = sw.Checkpoint(tmp_path / "run", every_calls=0)
+    job = sw.make_job(cfg, _comm((2, 2)), STEPS_A_CALL, checkpoint=ck)
+    job.start(_state(cfg, job.comm, seeded))
+    job.save()
+    job.drain()
+    assert job.form() == {"grid": [NY, NX], "mesh": [2, 2], "ghost": 2,
+                          "dtype": "float32", "tendencies": "interior"}
+    for other_cfg, shape, what in (
+            (cfg, (1, 1), r"mesh \[2, 2\].*runs mesh \[1, 1\]"),
+            (sw.SWConfig(ny=NY, nx=NX, ghost=4), (2, 2), r"ghost 2.*runs ghost 4"),
+            (sw.SWConfig(ny=NY, nx=2 * NX, ghost=2), (2, 2),
+             r"grid \[32, 64\].*runs grid \[32, 128\]")):
+        other = sw.make_job(other_cfg, _comm(shape), STEPS_A_CALL, checkpoint=ck)
+        with pytest.raises(ValueError, match=what):
+            other.resume()
+        assert other.state is None
+    # the schedules that carry padded tendencies say so
+    assert sw.make_job(sw.SWConfig(ny=NY, nx=NX, ghost=1), _comm((1, 1)),
+                       STEPS_A_CALL).form()["tendencies"] == "padded"

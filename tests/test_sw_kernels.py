@@ -405,6 +405,78 @@ def test_multistep_through_the_kernels_matches_the_array_path(
             assert (np.abs(c - b).max() > 20 * tolerance) == (py > 1), name
 
 
+@pytest.mark.parametrize("saved_as", ["array code", "kernel"])
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_save_resumes_where_the_step_is_the_other_backends(
+        mesh_shape, saved_as, monkeypatch, tmp_path):
+    """A checkpoint holds the model, not one backend's buffers (D14): a
+    job saved where the step is array code (interior-shaped tendencies)
+    resumes where it is the kernel (padded ones, ring 1 of ``du``,
+    ``dv`` the neighbours'), and the reverse; the run goes on as the
+    uninterrupted one does, to the rounding by which the two backends
+    differ anyway, where a resume that dropped the tendencies would be
+    off by a thousand times that."""
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=jax.devices()[:mesh_shape[0] * mesh_shape[1]])
+    comm = m.MeshComm.from_mesh(mesh)
+    py, px = mesh_shape
+    cfg = sw.SWConfig(ny=40, nx=48, ghost=G, coriolis_f=2e-2, depth=1e3,
+                      coriolis_beta=1e-7)
+    ck = sw.Checkpoint(tmp_path / "run", every_calls=0)
+    wide_step = sw_kernels.wide_step
+
+    def as_kernel(patch):
+        patch.setattr(sw_kernels, "wide_step", lambda *args, **kwargs: wide_step(
+            *args, **dict(kwargs, interpret=True)))
+        patch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: True)
+        patch.setattr(
+            jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
+
+    def job(kernel, patch):
+        if kernel:
+            as_kernel(patch)
+        made = sw.make_job(cfg, comm, 5, checkpoint=ck)
+        assert made.form()["tendencies"] == ("padded" if kernel else "interior")
+        return made
+
+    def interiors(x):
+        x = np.asarray(x)
+        if x.shape == (40, 48):
+            return x
+        return x.reshape(py, 40 // py + 2 * G, px, 48 // px + 2 * G)[
+            :, G:-G, :, G:-G].reshape(40, 48)
+
+    with monkeypatch.context() as patch:
+        first = job(saved_as == "kernel", patch)
+        first.start(sw.make_init(cfg, comm)())
+        first.advance(1)
+        first.save()
+        first.advance(1)  # the uninterrupted run, on the backend that saved
+        first.drain()
+        want = [interiors(a) for a in first.state]
+    with monkeypatch.context() as patch:
+        second = job(saved_as != "kernel", patch)
+        assert second.resume() == 6
+        assert second.state.dh.shape == (
+            second.state.h.shape if saved_as != "kernel" else (40, 48))
+        kept = jax.tree.map(jnp.copy, second.state)  # the call donates its input
+        second.advance(1)
+        got = [interiors(a) for a in second.state]
+        # the same resume with its tendencies dropped
+        second.start(kept._replace(**{
+            k: jnp.zeros_like(getattr(kept, k)) for k in ("dh", "du", "dv")}),
+            step=6)
+        second.advance(1)
+        dropped = [interiors(a) for a in second.state]
+    for name, a, b, c in zip(sw.SWState._fields, got, want, dropped):
+        tolerance = 2e-5 * max(1.0, np.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=0, atol=tolerance, err_msg=name)
+        if name in "huv":
+            assert np.abs(c - b).max() > 100 * tolerance, name
+
+
 def _comm_on(platform):
     devices = np.array([[types.SimpleNamespace(platform=platform)]])
     return types.SimpleNamespace(

@@ -428,3 +428,70 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
     # three coarse fields, their rows filled up to whole vector registers
     assert 3 * 1800 * 3600 * 4 <= mem.output_size_in_bytes <= 3 * 1800 * 3712 * 4 + 4096
     assert mem.temp_size_in_bytes < 7204 * 14404 * 4
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_jobs_save_leaves_the_step_alone_and_its_staging_carries_its_scope(
+        v5e, mesh_shape):
+    """``make_job`` with a checkpoint, at the benchmark cell's size a
+    chip: the call's multistep is the program of a job without one,
+    text for text (a save adds a program beside the step, nothing to
+    it); the staging program cuts each chip's six blocks into bands of
+    rows under ``mpi4jax_tpu.checkpoint/stage``, every band a copy of at
+    most ``checkpoint.PIECE_BYTES`` a chip, with no temporary and no
+    wire; the restore's program puts them together again under
+    ``.../unstage``."""
+    from mpi4jax_tpu.utils import checkpoint as ckpt
+    from perfbench.harness import scopes
+
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px])
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=7200 * py, nx=14400 * px, dx=1250.0, dy=1250.0, ghost=2)
+    job = sw.make_job(cfg, comm, 10, checkpoint=sw.Checkpoint(
+        "/nowhere", every_calls=32, ahead_bytes=160_000_000))
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(sw.make_init(cfg, comm)))
+    assert job.form()["tendencies"] == "padded"
+
+    def instructions(text):
+        """A program's instructions without where they were traced from."""
+        return [re.sub(r",? metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines() if " = " in line]
+
+    text = job.multi.lower(state).compile().as_text()
+    bare = sw.make_job(cfg, comm, 10).multi.lower(state).compile().as_text()
+    assert instructions(text) == instructions(bare) and len(instructions(text)) > 20
+    # every call donates, saved or not: the kernel's operands are its results
+    assert "input_output_alias" in text.split("ENTRY")[0]
+    assert not _copied(text, _kernels(text)["wide_step"][1])
+
+    stage = job.stage.lower(state).compile()
+    pieces = sum(len(rows) for rows in job._plan)
+    a_piece = max(hi - lo for rows in job._plan for lo, hi in rows) * 14404 * 4
+    assert a_piece <= ckpt.PIECE_BYTES < 2 * a_piece
+    assert pieces == 6 * len(job._plan[0]) and job._plan[0][-1][1] == 7204
+    table = scopes.origins(stage.as_text())
+    under = [o for o in table.values()
+             if o.scopes[:2] == ("mpi4jax_tpu.checkpoint", "stage")]
+    assert under and all(scopes.layer_of(o) == scopes.OP_SURFACE for o in under)
+    assert "collective-permute" not in stage.as_text()
+    mem = stage.memory_analysis()
+    state_bytes = 6 * 7204 * 14404 * 4
+    # the bands' rows and columns filled up to whole tiles of (8, 128)
+    assert state_bytes <= mem.output_size_in_bytes <= 1.02 * state_bytes
+    assert mem.temp_size_in_bytes < 1 << 20
+
+    a_field = tuple(
+        jax.ShapeDtypeStruct(((hi - lo) * py, 14404 * px), jnp.float32,
+                             sharding=sharding) for lo, hi in job._plan[0])
+    unstage = sw.make_unstage(comm).lower(*a_field).compile()
+    table = scopes.origins(unstage.as_text())
+    assert any(o.scopes[:2] == ("mpi4jax_tpu.checkpoint", "unstage")
+               for o in table.values())
+    assert unstage.memory_analysis().output_size_in_bytes >= 7204 * 14404 * 4
+    assert unstage.memory_analysis().temp_size_in_bytes < 1 << 20
