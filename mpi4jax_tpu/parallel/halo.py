@@ -88,13 +88,14 @@ def halo_exchange_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
     on one copy of it where the caller keeps ``arr``.  On the mesh tier
     the block is held row-major for the exchange (:func:`_row_major`).
     On a v5e 2×2, ``width`` 2 on a 1804 x 3604 block (``PERF.md``,
-    PR 35): 34 us a call in place, of which the two column writes are
-    7.6 us each (1804 pieces of 8 bytes), the row writes 1 us each and
-    the four permutes 8; 95 us where the input is kept, 41 of them the
-    copy.  A caller whose next kernel reads and writes the whole block
-    anyway takes the slabs from :func:`halo_slabs_2d` and places them
-    there: that saves the column writes and costs the kernel nothing
-    (``models/sw_kernels.py wide_step``).
+    PR 37): 26 us a call in place, of which the two column writes are
+    3.6 and 3.9 us (each the strip of lane tiles that holds its two
+    columns, read and written whole: :func:`_place`), the row writes
+    1 us each and the four permutes 8; 68 us where the input is kept,
+    41 of them the copy.  A caller whose next kernel reads and writes
+    the whole block anyway takes the slabs from :func:`halo_slabs_2d`
+    and places them there: that saves the column writes and costs the
+    kernel nothing (``models/sw_kernels.py wide_step``).
     """
     arrs, token = _exchange(
         [arr], comm, periodic=periodic, token=token, width=width,
@@ -240,13 +241,66 @@ def _row_major(a):
         a, Layout(major_to_minor=tuple(range(a.ndim))))
 
 
-def _place(a, slab, region):
+# the last dimension of a tile of TPU memory, in elements of any dtype:
+# f32 lies in (8, 128) tiles, bf16 in (16, 128), int8 in (32, 128)
+LANES = 128
+
+
+def _lane_tiles(block, slab, start):
+    """The columns ``(s0, s1)`` of the whole lane tiles that hold a
+    narrow column slab, or ``None`` where the slab is written as it is.
+    From shapes alone: ``block`` and ``slab`` are the two shapes and
+    ``start`` the slab's static offsets in the block.  A slab of every
+    row and fewer than ``LANES`` columns, in a block wider than one
+    tile, lies in the tiles from ``s0`` to ``s1`` (the block's edge cuts
+    the last one short); a row slab, a slab a tile wide or more and a
+    block of one tile have no tile path."""
+    nx, w = block[1], slab[1]
+    if slab[0] != block[0] or w >= LANES or nx <= LANES:
+        return None
+    s0 = start[1] // LANES * LANES
+    s1 = min(nx, -(-(start[1] + w) // LANES) * LANES)
+    return s0, s1
+
+
+def _place(a, slab, region, *, tiles=False):
     """``a`` with ``slab`` written over ``region``, a pair of static
     slices: a ``dynamic_update_slice`` at constant offsets (``.at[].set``
     is a scatter, which XLA gives a bounds test, a mask and a select
-    of the slab's size on every write)."""
+    of the slab's size on every write).
+
+    With ``tiles`` (the mesh tier, whose block is row-major) a slab
+    narrower than a lane tile is written as the whole tiles that hold
+    it (:func:`_lane_tiles`): the strip is read where it lies, the slab
+    selected into it and the strip written back at an aligned offset,
+    one in-place fusion.  Two columns of a row-major ``(8, 128)``-tiled
+    block are a piece of 8 bytes a row, and the TPU compiler writes each
+    piece by itself: 14.0 and 12.5 us for the two ``[1804, 2]`` slabs of
+    a 1804 x 3604 block on a v5e, 28 % of the whole exchange, where the
+    two row slabs, twice the bytes, take 1.7 (``PERF.md``, PR 35; in
+    HBM, for a caller in place, 7.7 and 7.6).  The strip is 226 tile
+    rows of 4 KB, loaded, selected into and stored whole: 0.9 us for
+    both slabs where XLA keeps the block in its faster memory, 3.6 and
+    3.9 us in HBM (one strided DMA in, one out), and the exchange 95 ->
+    68 us a call with its input kept and 34 -> 26 in place (``PERF.md``,
+    PR 37).  The same bits either way: written with ``concatenate`` the
+    TPU compiler pads the pieces and takes their ``maximum``, which is
+    not the same bits for a NaN or a negative zero."""
     start = tuple(s.indices(n)[0] for s, n in zip(region, a.shape))
-    return lax.dynamic_update_slice(a, slab, start)
+    cols = _lane_tiles(a.shape, slab.shape, start) if tiles else None
+    if cols is None:
+        return lax.dynamic_update_slice(a, slab, start)
+    s0, s1 = cols
+    before, w = start[1] - s0, slab.shape[1]
+    # a constant, so that XLA carries a literal and computes no mask
+    ghost = np.zeros(s1 - s0, bool)
+    ghost[before:before + w] = True
+    strip = lax.select(
+        jnp.broadcast_to(ghost, (a.shape[0], s1 - s0)),
+        lax.pad(slab, jnp.zeros((), slab.dtype),
+                [(0, 0, 0), (before, s1 - s0 - before - w, 0)]),
+        lax.slice_in_dim(a, s0, s1, axis=1))
+    return lax.dynamic_update_slice(a, strip, (0, s0))
 
 
 def _exchange(arrs, comm, *, periodic, token, width, stack):
@@ -254,8 +308,11 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
     :func:`_received`, then one placement phase over each block: west,
     east, south, north onto one value, nothing else reading the values
     between, so the writes can share one buffer (the caller's own where
-    it gives the block up, one copy of it where it keeps it)."""
-    if comm.backend == "mesh":
+    it gives the block up, one copy of it where it keeps it).  On the
+    mesh tier the block is row-major, so it is the column slabs that
+    are narrow: :func:`_place` writes those as whole lane tiles."""
+    mesh = comm.backend == "mesh"
+    if mesh:
         with jax.named_scope(PACK):
             arrs = [_row_major(a) for a in arrs]
     slabs, token = _received(
@@ -269,7 +326,7 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
                 # None on a global no-op shift: the ghosts already hold
                 # the right values, skip the (identical) write
                 if got[i] is not None:
-                    a = _place(a, got[i], region)
+                    a = _place(a, got[i], region, tiles=mesh)
             out.append(a)
     return out, token
 

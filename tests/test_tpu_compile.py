@@ -8,6 +8,7 @@ guard the shapes ``chip_smoke.py`` drives at no chip time.  A compile
 that passes is not a chip run and says nothing about results or speed.
 """
 
+import functools
 import os
 import re
 
@@ -166,14 +167,13 @@ def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     assert not _copied(texts[0], first["wide_step"][1]), first["wide_step"][1]
 
 
-def _step_body(text):
-    """The 10-step program's loop body: ``(instructions, types)``, the
-    instructions as ``(name, opcode, operand names, line)`` without
-    those that move nothing (parameters, tuples and their elements,
-    constants, bitcasts), the types by name."""
-    body = re.search(r"\bwhile\(.*?body=%([\w.\-]+)", text)[1]
+def _computation(text, name):
+    """A computation of a compiled program's text: ``(instructions,
+    types)``, the instructions as ``(name, opcode, operand names, line)``
+    without those that move nothing (parameters, tuples and their
+    elements, constants, bitcasts), the types by name."""
     lines = re.search(
-        rf"^%{re.escape(body)} \(.*?^\}}", text, re.S | re.M)[0].splitlines()
+        rf"^%{re.escape(name)} \(.*?^\}}", text, re.S | re.M)[0].splitlines()
     found, types = [], {}
     for line in lines[1:-1]:
         name, rest = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*)", line).groups()
@@ -184,6 +184,13 @@ def _step_body(text):
             found.append(
                 (name, opcode, re.findall(r"%([\w.\-]+)", operands), line))
     return found, types
+
+
+def _step_body(text):
+    """The body of a program's loop (the 10-step program's, the table's
+    chain's), as :func:`_computation` gives it."""
+    return _computation(
+        text, re.search(r"\bwhile\(.*?body=%([\w.\-]+)", text)[1])
 
 
 # what the step's loop body holds that moves or computes something.  One
@@ -232,11 +239,14 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
     assert len(body) == STEP_INSTRUCTIONS[mesh_shape], opcodes
 
 
-def _halo_row_blocks(v5e, program):
+@functools.cache  # four tests read two programs
+def _halo_row(v5e, program):
     """The benchmark's halo row (``perfbench/workloads/coll-2x2.json``:
     width 2 on 1804 x 3604 a chip, 2x2) under ``program(op, reps, mesh,
-    spec)``, compiled for the described chips: the loop body's
-    instructions whose result is a block, as ``(opcode, type, line)``."""
+    spec)``, compiled for the described chips: ``(blocks, updates)``, the
+    loop body's instructions whose result is a block as ``(opcode, type,
+    line)``, and the type of what every ``dynamic-update-slice`` of the
+    exchange writes there, those inside the body's fusions too."""
     import json
 
     from perfbench.drivers import collectives
@@ -253,8 +263,30 @@ def _halo_row_blocks(v5e, program):
     op = collectives.library_op(row, m.MeshComm.from_mesh(mesh))
     text = program(op, row["reps"], mesh, spec).lower(x).compile().as_text()
     body, types = _step_body(text)
-    return [(opcode, types[name], line) for name, opcode, _, line in body
-            if types[name].startswith(f"f32[{ny},{nx}]")]
+    blocks = [(opcode, types[name], line) for name, opcode, _, line in body
+              if types[name].startswith(f"f32[{ny},{nx}]")]
+    updates = []
+    for _, opcode, operands, line in body:
+        inside, kinds = [(None, opcode, operands, line)], types
+        if opcode == "fusion":
+            inside, kinds = _computation(
+                text, re.search(r"calls=%([\w.\-]+)", line)[1])
+        updates += [kinds[operands[1]].split("{")[0]
+                    for _, opcode, operands, line in inside
+                    if opcode == "dynamic-update-slice"
+                    and "halo_exchange_2d" in line]
+    return blocks, updates
+
+
+def _in_place(op, reps, mesh, spec):
+    """``x = op(x)`` alone in a loop whose carry is donated."""
+
+    def local(x):
+        return jax.lax.fori_loop(0, reps, lambda _, x: op(x), x)
+
+    return jax.jit(
+        jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec),
+        donate_argnums=0)
 
 
 def _opcodes(blocks):
@@ -266,23 +298,30 @@ def test_the_tables_halo_row_moves_its_block_once(v5e):
     ghosts are four writes in place on one value, after both wires, in
     a block that stays row-major (left to itself XLA lays the whole
     block out to suit the two-column slabs sliced from it, and each row
-    write is then 3604 pieces: PERF.md, PR 35).  Three copies: the
+    write is then 3604 pieces: PERF.md, PR 35): the two row slabs by a
+    ``dynamic-update-slice`` each, the two column slabs by a fusion each
+    that rewrites the lane tiles they lie in (PR 37).  Three copies: the
     result out to the carried output is the exchange's; the input saved
     before those writes and handed back after the chain's own write are
     what XLA makes of the benchmark's chain, inside its faster memory.
     None transposes."""
     from perfbench.drivers import collectives
 
-    blocks = _halo_row_blocks(v5e, collectives.chained)
+    blocks, _ = _halo_row(v5e, collectives.chained)
     assert all(kind.startswith("f32[1804,3604]{1,0") for _, kind, _ in blocks)
-    assert _opcodes(blocks) == ["copy"] * 3 + ["dynamic-update-slice"] * 5
-    placed = [line for opcode, _, line in blocks
+    assert _opcodes(blocks) == (
+        ["copy"] * 3 + ["dynamic-update-slice"] * 3 + ["fusion"] * 2)
+    placed = [(opcode, line) for opcode, _, line in blocks
               if "halo_exchange_2d/unpack" in line]
-    assert len(placed) == 4 and all(
-        " dynamic-update-slice(" in line for line in placed)
+    assert sorted(opcode for opcode, _ in placed) == (
+        ["dynamic-update-slice"] * 2 + ["fusion"] * 2)
+    # a fusion that writes where it reads: no block beside the block
+    assert all('"aliasing_operands":{"lists":[{"indices":["0",' in line
+               for opcode, line in placed if opcode == "fusion")
     # the one write that is not the exchange's is the chain's
     assert len([1 for opcode, _, line in blocks
-                if opcode == "dynamic-update-slice" and line not in placed]) == 1
+                if opcode == "dynamic-update-slice"
+                and "halo_exchange_2d" not in line]) == 1
     # one copy leaves the faster memory: the result; the chain's two stay
     copies = [kind for opcode, kind, _ in blocks if opcode == "copy"]
     assert sorted("S(1)" in kind for kind in copies) == [False, True, True]
@@ -292,19 +331,25 @@ def test_an_exchange_in_place_copies_no_block(v5e):
     """``x = halo_exchange_2d(x)`` alone in a loop whose carry is
     donated: four writes on the carried block where it lies, and nothing
     else of a block's size."""
-
-    def in_place(op, reps, mesh, spec):
-        def local(x):
-            return jax.lax.fori_loop(0, reps, lambda _, x: op(x), x)
-
-        return jax.jit(
-            jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec),
-            donate_argnums=0)
-
-    blocks = _halo_row_blocks(v5e, in_place)
+    blocks, _ = _halo_row(v5e, _in_place)
     assert all(kind.startswith("f32[1804,3604]{1,0") for _, kind, _ in blocks)
-    assert _opcodes(blocks) == ["dynamic-update-slice"] * 4
+    assert _opcodes(blocks) == ["dynamic-update-slice"] * 2 + ["fusion"] * 2
     assert all("halo_exchange_2d/unpack" in line for *_, line in blocks)
+
+
+@pytest.mark.parametrize("form", ["chained", "in_place"])
+def test_no_write_of_the_exchange_is_two_columns_wide(v5e, form):
+    """A ``[1804, 2]`` slab in a row-major ``(8, 128)``-tiled block is
+    1804 pieces of 8 bytes, 13 us a write on a v5e where the row slabs'
+    take 1 (PERF.md, PRs 35 and 37): the column slabs land as the strips
+    of whole lane tiles that hold them, columns 0 to 128 and 3584 to
+    3604, and the row slabs as they are."""
+    from perfbench.drivers import collectives
+
+    program = collectives.chained if form == "chained" else _in_place
+    _, updates = _halo_row(v5e, program)
+    assert sorted(updates) == [
+        "f32[1804,128]", "f32[1804,20]", "f32[2,3604]", "f32[2,3604]"]
 
 
 @pytest.mark.parametrize("n", [1, 4])
