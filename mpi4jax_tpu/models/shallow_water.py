@@ -56,7 +56,7 @@ from mpi4jax_tpu.parallel.halo import (
     halo_exchange_2d_batch,
     halo_slabs_2d,
 )
-from mpi4jax_tpu.utils import checkpoint as ckpt
+from mpi4jax_tpu.utils import checkpoint as ckpt, spans
 
 __all__ = [
     "SWConfig",
@@ -1085,6 +1085,19 @@ class SolverJob:
     enqueued call leaves it; ``series`` the checkpoint's directory (a
     :class:`checkpoint.Series`) and ``saves`` the acknowledged saves'
     records, in order.
+
+    ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`) keeps what
+    the job's host code was doing, on ``time.perf_counter_ns()``:
+    ``job/advance`` a call of :meth:`advance`; ``job/enqueue`` a call of
+    a program (``program`` says which); ``job/ask`` a snapshot's copy
+    started; ``job/fetch`` and ``job/callback`` a delivery;
+    ``job/save`` with ``job/save_wait`` and ``job/save_start`` inside
+    it; ``job/drain``; ``job/resume`` with ``checkpoint/read`` and
+    ``checkpoint/to_device`` a piece and ``job/compile``; and, on a
+    save's threads, ``checkpoint/save``, ``/fetch``, ``/write``,
+    ``/commit`` and ``/prune``.  A span's ``key`` is the model step it
+    is about.  :meth:`spans` returns them; every time in :meth:`stats`
+    is the sum of its spans'.
     """
 
     def __init__(self, cfg, comm, num_multisteps, snapshot, on_chunk,
@@ -1109,6 +1122,7 @@ class SolverJob:
             self.stage = make_stage(comm, self._plan)
         self._stage = self.stage
         self.saves = []
+        self.trace = spans.Recorder(SCOPE_PREFIX)
         self._stats = dict(
             snapshots_produced=0, snapshots_delivered=0, max_lag=0,
             bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
@@ -1129,11 +1143,12 @@ class SolverJob:
     def compile(self):
         """Compile the call's programs for the state at hand without
         running them: a resumed run has no warm-up call to spend."""
-        self._multi = self.multi.lower(self.state).compile()
-        if self.snap is not None:
-            self._snap = self.snap.lower(*self._written()).compile()
-        if self.stage is not None:
-            self._stage = self.stage.lower(self.state).compile()
+        with self.trace.span("job/compile", key=self.step):
+            self._multi = self.multi.lower(self.state).compile()
+            if self.snap is not None:
+                self._snap = self.snap.lower(*self._written()).compile()
+            if self.stage is not None:
+                self._stage = self.stage.lower(self.state).compile()
 
     def advance(self, calls=1):
         """Enqueue ``calls`` multistep calls, after each the snapshot
@@ -1141,17 +1156,23 @@ class SolverJob:
         the snapshots' copies to the host that are next in line, and
         deliver every snapshot that is due."""
         every = self.checkpoint.every_calls if self.checkpoint else 0
-        for _ in range(calls):
-            self.state = self._multi(self.state)
-            self.step += self.num_multisteps
-            self.calls += 1
-            if self.snap is not None:
-                self._pending.append((self.step, self._snap(*self._written())))
-                self._stats["snapshots_produced"] += 1
-                self._ask()
-                self._deliver(self.snapshot.lag)
-            if every and self.calls % every == 0:
-                self.save()
+        span = self.trace.span
+        with span("job/advance", key=self.step + self.num_multisteps, calls=calls):
+            for _ in range(calls):
+                with span("job/enqueue", key=self.step + self.num_multisteps,
+                          program="multi"):
+                    self.state = self._multi(self.state)
+                self.step += self.num_multisteps
+                self.calls += 1
+                if self.snap is not None:
+                    with span("job/enqueue", key=self.step, program="snap"):
+                        parts = self._snap(*self._written())
+                    self._pending.append((self.step, parts))
+                    self._stats["snapshots_produced"] += 1
+                    self._ask()
+                    self._deliver(self.snapshot.lag)
+                if every and self.calls % every == 0:
+                    self.save()
         return self.state
 
     def drain(self):
@@ -1159,10 +1180,11 @@ class SolverJob:
         on its way to be acknowledged, and leave the directory with its
         committed saves and nothing else (the files a series keeps for
         its next save go)."""
-        self._deliver(0)
-        self._settle()
-        if self.series is not None:
-            self.series.clean()
+        with self.trace.span("job/drain", key=self.step):
+            self._deliver(0)
+            self._settle()
+            if self.series is not None:
+                self.series.clean()
 
     def stats(self):
         """The job's counters: ``snapshots_produced`` and
@@ -1181,12 +1203,18 @@ class SolverJob:
         save's start to its last piece on the host, and to its rename
         (both pass beside the loop; ``saves`` has them save by save);
         ``restore_read_s`` and ``restore_to_device_s``, host seconds a
-        resume spent reading files and handing them to the device."""
+        resume spent reading files and handing them to the device.
+        Every time is the sum of its spans' (:meth:`spans`)."""
         saves = list(self.saves)
         return dict(
             self._stats, saves_acknowledged=len(saves),
             save_stage_s=sum(r["stage_s"] for r in saves),
             save_commit_s=sum(r["commit_s"] for r in saves))
+
+    def spans(self):
+        """The finished spans of the job's host code and of its saves'
+        threads (``utils.spans.Span``), in the order they ended."""
+        return self.trace.spans()
 
     # -- saving and resuming ---------------------------------------------
 
@@ -1209,10 +1237,23 @@ class SolverJob:
         :class:`checkpoint.Save`; ``drain()`` waits for it."""
         if self.checkpoint is None:
             raise ValueError("the job was made without a `checkpoint`")
-        t0 = time.perf_counter()
-        late = self._save is not None and not self._save.committed
-        self._settle()
-        t1 = time.perf_counter() if late else t0  # nothing awaited, nothing waited
+        span, step = self.trace.span, self.step
+        with span("job/save", key=step,
+                  bytes=sum(a.nbytes for a in self.state)) as whole:
+            if self._save is not None and not self._save.committed:
+                with span("job/save_wait", key=self._save.step) as waited:
+                    self._settle()
+                self._stats["save_wait_s"] += waited.seconds
+            self._settle()  # a committed one's pruning is no wait for a save
+            with span("job/save_start", key=step) as started:
+                self._save = self._start_save(whole.id)
+            self._stats["saves_started"] += 1
+            self._stats["save_bytes"] += self._save.bytes
+            self._stats["save_enqueue_s"] += started.seconds
+        return self._save
+
+    def _start_save(self, cause):
+        """Enqueue the staging program and hand its pieces on."""
         if not all(a.is_fully_addressable for a in self.state):
             raise NotImplementedError(
                 "a job's save goes through the process that enqueued it; a "
@@ -1223,24 +1264,20 @@ class SolverJob:
         py = self.comm.axis_sizes[0]
         files = {f"{name}.npy": (a.shape, a.dtype)
                  for name, a in zip(SWState._fields, self.state)}
+        with self.trace.span("job/enqueue", key=self.step, program="stage"):
+            staged = self._stage(self.state)
         pieces = [((file, py * lo), piece)
-                  for file, plan, of_array in zip(
-                      files, self._plan, self._stage(self.state))
+                  for file, plan, of_array in zip(files, self._plan, staged)
                   for (lo, _), piece in zip(plan, of_array)]
         manifest = {
             "format": 1, "step": self.step, "form": self.form(),
             "arrays": {name: {"file": file, "shape": list(a.shape), "bands": plan}
                        for name, a, file, plan in zip(
                            SWState._fields, self.state, files, self._plan)}}
-        self._save = ckpt.Save(
+        return ckpt.Save(
             self.series, self.step, manifest, files, pieces,
             ahead_bytes=self.checkpoint.ahead(self.comm.size),
-            on_commit=self.saves.append)
-        self._stats["saves_started"] += 1
-        self._stats["save_bytes"] += self._save.bytes
-        self._stats["save_wait_s"] += t1 - t0
-        self._stats["save_enqueue_s"] += time.perf_counter() - t1
-        return self._save
+            on_commit=self.saves.append, trace=self.trace, cause=cause)
 
     def resume(self, directory=None):
         """Take the newest acknowledged save of ``directory`` (the
@@ -1254,14 +1291,20 @@ class SolverJob:
         and resumed where it is the kernel, or the reverse) is
         converted.  Returns the step resumed from, or ``None`` where the
         directory holds no save (the job is then as it was)."""
-        self.drain()
         series = self.series if directory is None else ckpt.Series(directory)
         if series is None:
             raise ValueError("no directory: the job has no `checkpoint`")
-        series.clean()
-        step = series.latest()
-        if step is None:
-            return None
+        with self.trace.span("job/resume") as whole:
+            self.drain()
+            series.clean()
+            step = whole.key = series.latest()
+            if step is not None:
+                whole.counts["bytes"] = self._restore(series, step)
+        return step
+
+    def _restore(self, series, step):
+        """The save of ``step`` as the job's state, its programs
+        compiled; returns the bytes read."""
         manifest = series.manifest(step)
         mine, saved = self.form(), manifest["form"]
         for key in ("grid", "mesh", "ghost", "dtype"):
@@ -1279,7 +1322,8 @@ class SolverJob:
             held = manifest["arrays"][name]
             pieces, read_s, to_device_s = ckpt.read_pieces(
                 series.path(step) / held["file"],
-                [(py * lo, py * hi) for lo, hi in held["bands"]], sharding, ahead)
+                [(py * lo, py * hi) for lo, hi in held["bands"]], sharding, ahead,
+                trace=self.trace, key=step)
             arrays.append(unstage(*pieces))
             self._stats["restore_read_s"] += read_s
             self._stats["restore_to_device_s"] += to_device_s
@@ -1288,7 +1332,7 @@ class SolverJob:
                 self.cfg, self.comm, mine["tendencies"])(*arrays[3:])
         self.start(SWState(*arrays), step=manifest["step"])
         self.compile()
-        return step
+        return sum(a.nbytes for a in arrays)
 
     def _piece_plan(self):
         """The bands of rows of a device's block that a save cuts each
@@ -1314,35 +1358,40 @@ class SolverJob:
     def _written(self):
         return tuple(getattr(self.state, k) for k in self.snapshot.fields)
 
-    def _ask(self):
+    def _ask(self, cause=None):
         """Start the copies to the host of the oldest snapshots not yet
-        asked for, as far as ``snapshot.ahead_bytes`` goes."""
+        asked for, as far as ``snapshot.ahead_bytes`` goes; returns the
+        seconds that took.  ``cause``: the fetch that made the room."""
         most = self.snapshot.ahead_bytes
+        seconds = 0.0
         while self._asked < len(self._pending):
-            parts = self._pending[self._asked][1]
+            step, parts = self._pending[self._asked]
             size = sum(part.nbytes for part in parts)
             if self._asked and most is not None and self._asked_bytes + size > most:
                 break
-            for part in parts:
-                part.copy_to_host_async()
+            with self.trace.span("job/ask", key=step, cause=cause, bytes=size) as asked:
+                for part in parts:
+                    part.copy_to_host_async()
             self._asked += 1
             self._asked_bytes += size
+            seconds += asked.seconds
+        return seconds
 
     def _deliver(self, keep):
-        stats = self._stats
+        stats, span = self._stats, self.trace.span
         while len(self._pending) > keep:
             step, parts = self._pending.popleft()
-            t0 = time.perf_counter()
-            arrays = {k: np.asarray(part)
-                      for k, part in zip(self.snapshot.fields, parts)}
+            size = sum(part.nbytes for part in parts)
+            with span("job/fetch", key=step, bytes=size) as fetched:
+                arrays = {k: np.asarray(part)
+                          for k, part in zip(self.snapshot.fields, parts)}
             self._asked -= 1
-            self._asked_bytes -= sum(part.nbytes for part in parts)
-            self._ask()
-            t1 = time.perf_counter()
+            self._asked_bytes -= size
+            stats["output_wait_s"] += fetched.seconds + self._ask(fetched.id)
             if self.on_chunk is not None:
-                self.on_chunk(arrays, step)
-            stats["callback_s"] += time.perf_counter() - t1
-            stats["output_wait_s"] += t1 - t0
+                with span("job/callback", key=step) as called:
+                    self.on_chunk(arrays, step)
+                stats["callback_s"] += called.seconds
             stats["bytes_to_host"] += sum(a.nbytes for a in arrays.values())
             stats["max_lag"] = max(stats["max_lag"], len(self._pending))
             stats["snapshots_delivered"] += 1

@@ -27,10 +27,14 @@ in progress under another name until it is whole):
   yet fetched, are written into one ``.npy`` file an array by background
   threads beside whatever the caller runs next, and are committed by one
   rename; a restore reads them back under the same bound.  What
-  ``models.shallow_water``'s job saves and resumes through.
+  ``models.shallow_water``'s job saves and resumes through.  Each takes
+  a ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`, its owner's;
+  one of its own where none is given) and records what its threads did
+  under ``checkpoint/...``: every time it reports is its spans'.
 """
 
 import collections
+import contextlib
 import functools
 import json
 import os
@@ -38,11 +42,12 @@ import pathlib
 import queue
 import shutil
 import threading
-import time
 
 import numpy as np
 
 import jax
+
+from mpi4jax_tpu.utils.spans import Recorder
 
 __all__ = [
     "save", "restore", "latest_step", "Manager",
@@ -332,13 +337,15 @@ def write_at(fd, offset, array, bounce):
         os.pwrite(fd, memoryview(bounce)[:n], offset + at)
 
 
-def to_host(pieces, ahead_bytes=None):
+def to_host(pieces, ahead_bytes=None, span=None):
     """``(key, device array)`` pairs → ``(key, numpy array)`` pairs,
     in order.  The copies to the host are asked for ahead of the fetch,
     oldest first, as far as ``ahead_bytes`` goes: at most that many
     bytes asked for and not yet fetched, the oldest piece's always
     (``None``: all of them at once).  Takes ``pieces`` (a list) apart as
-    it goes, so that each device array is released once it is fetched."""
+    it goes, so that each device array is released once it is fetched.
+    ``span(piece)``, where given, is entered round each fetch (a
+    save's ``checkpoint/fetch``)."""
     waiting = collections.deque(pieces)
     del pieces[:]
     asked = asked_bytes = 0
@@ -351,7 +358,8 @@ def to_host(pieces, ahead_bytes=None):
             asked += 1
             asked_bytes += size
         name, piece = waiting.popleft()
-        host = np.asarray(piece)
+        with span(piece) if span else contextlib.nullcontext():
+            host = np.asarray(piece)
         asked -= 1
         asked_bytes -= piece.nbytes
         del piece
@@ -371,21 +379,33 @@ class Save:
     rename).  :meth:`wait` blocks until the save is committed and the
     series pruned, and raises what stopped it.
 
+    The spans of ``trace``, all under the key ``step``: on the first
+    thread (``checkpoint-save``) ``checkpoint/save`` from its start to
+    the rename (``cause``: the span of the caller that handed the save
+    over), inside it ``checkpoint/fetch`` a piece and
+    ``checkpoint/commit`` (the manifest and the rename), after it
+    ``checkpoint/prune``; on the writers (``checkpoint-write-<i>``)
+    ``checkpoint/write`` a piece, its cause the ``checkpoint/save``.
+    ``commit_s`` is the first's length and ``stage_s`` the end of its
+    last fetch.
+
     A file an array, not a file a piece (six files a save, not 606),
     written by position so that the writers share it."""
 
     def __init__(self, series, step, manifest, files, pieces, *,
-                 ahead_bytes=None, on_commit=None):
+                 ahead_bytes=None, on_commit=None, trace=None, cause=None):
         self.step = step
         self.bytes = sum(piece.nbytes for _, piece in pieces)
         self.record = None
         self._error = None
-        self._t0 = time.perf_counter()
+        self._trace = trace or Recorder()
+        self._fetched = None  # the newest `checkpoint/fetch`
         self._host = queue.Queue()
         self._done = threading.Event()
         threading.Thread(
-            target=self._run, daemon=True,
-            args=(series, manifest, files, pieces, ahead_bytes, on_commit)).start()
+            target=self._run, daemon=True, name="checkpoint-save",
+            args=(series, manifest, files, pieces, ahead_bytes, on_commit,
+                  cause)).start()
 
     @property
     def committed(self):
@@ -398,64 +418,87 @@ class Save:
                 f"the save of step {self.step} failed") from self._error
         return self.record
 
-    def _run(self, series, manifest, files, pieces, ahead_bytes, on_commit):
-        opened = {}
+    def _run(self, series, manifest, files, pieces, ahead_bytes, on_commit, cause):
+        span, step = self._trace.span, self.step
         try:
-            tmp = series.begin(self.step)
-            for stale in set(p.name for p in tmp.iterdir()) - set(files):
-                (tmp / stale).unlink()
-            for name, (shape, dtype) in files.items():
-                fd, start = begin_npy(tmp / name, shape, dtype)
-                row_bytes = int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
-                opened[name] = fd, start, row_bytes
-            writers = [threading.Thread(target=self._write, args=(opened,), daemon=True)
-                       for _ in range(WRITERS)]
-            for writer in writers:
-                writer.start()
-            try:
-                for item in to_host(pieces, ahead_bytes):
-                    self._host.put(item)
-                staged_s = time.perf_counter() - self._t0
-            finally:
-                for writer in writers:
-                    self._host.put(None)
-                for writer in writers:
-                    writer.join()
-                while opened:
-                    os.close(opened.popitem()[1][0])
+            with span("checkpoint/save", key=step, cause=cause,
+                      bytes=self.bytes) as whole:
+                tmp = series.begin(step)
+                self._stream(tmp, files, pieces, ahead_bytes, whole.id)
+                if self._error is None:
+                    with span("checkpoint/commit", key=step):
+                        (tmp / MANIFEST).write_text(json.dumps(manifest))
+                        series.commit(tmp, step)
             if self._error is None:
-                (tmp / MANIFEST).write_text(json.dumps(manifest))
-                series.commit(tmp, self.step)
+                staged_ns = self._fetched.end_ns if self._fetched else whole.start_ns
                 self.record = {
-                    "step": self.step, "bytes": self.bytes, "stage_s": staged_s,
-                    "commit_s": time.perf_counter() - self._t0}
+                    "step": step, "bytes": self.bytes,
+                    "stage_s": (staged_ns - whole.start_ns) / 1e9,
+                    "commit_s": whole.seconds}
                 if on_commit is not None:
                     on_commit(self.record)
-                series.prune()
+                with span("checkpoint/prune", key=step, cause=whole.id):
+                    series.prune()
         except BaseException as error:  # handed to whoever waits
             self._error = self._error or error
         finally:
             self._done.set()
 
-    def _write(self, opened):
+    def _stream(self, tmp, files, pieces, ahead_bytes, cause):
+        """The pieces through the host into their files under ``tmp``."""
+        opened = {}
+        for stale in set(p.name for p in tmp.iterdir()) - set(files):
+            (tmp / stale).unlink()
+        for name, (shape, dtype) in files.items():
+            fd, start = begin_npy(tmp / name, shape, dtype)
+            row_bytes = int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+            opened[name] = fd, start, row_bytes
+        writers = [threading.Thread(target=self._write, args=(opened, cause),
+                                    daemon=True, name=f"checkpoint-write-{i}")
+                   for i in range(WRITERS)]
+        for writer in writers:
+            writer.start()
+        try:
+            for item in to_host(pieces, ahead_bytes, span=self._fetching):
+                self._host.put(item)
+        finally:
+            for writer in writers:
+                self._host.put(None)
+            for writer in writers:
+                writer.join()
+            while opened:
+                os.close(opened.popitem()[1][0])
+
+    def _fetching(self, piece):
+        self._fetched = self._trace.span(
+            "checkpoint/fetch", key=self.step, bytes=piece.nbytes)
+        return self._fetched
+
+    def _write(self, opened, cause):
         bounce = np.empty(WRITE_BYTES, np.uint8)
         while (item := self._host.get()) is not None:
             try:
                 if self._error is None:
                     (name, row), host = item
                     fd, start, row_bytes = opened[name]
-                    write_at(fd, start + row * row_bytes, host, bounce)
+                    with self._trace.span("checkpoint/write", key=self.step,
+                                          cause=cause, bytes=host.nbytes):
+                        write_at(fd, start + row * row_bytes, host, bounce)
             except BaseException as error:
                 self._error = self._error or error
 
 
-def read_pieces(path, bands, sharding, ahead_bytes=None):
+def read_pieces(path, bands, sharding, ahead_bytes=None, trace=None, key=None):
     """The bands of rows ``bands`` (``[(lo, hi), ...]``, ascending) of
     the ``.npy`` file ``path`` as device arrays of ``sharding``, in
     order, with the copies to the device that are not yet done held
     under ``ahead_bytes`` (the newest's always; ``None``: no bound).
     Returns ``(arrays, read_s, to_device_s)``: host seconds reading the
-    file, and handing its bands to the device or waiting for it."""
+    file, and handing its bands to the device or waiting for it, each
+    the sum of ``trace``'s spans ``checkpoint/read`` and
+    ``checkpoint/to_device``, one a band, under ``key`` (the step of
+    the save that is read)."""
+    span = (trace or Recorder()).span
     arrays, flying, flying_bytes = [], collections.deque(), 0
     read_s = to_device_s = 0.0
     with open(path, "rb") as f:
@@ -463,17 +506,18 @@ def read_pieces(path, bands, sharding, ahead_bytes=None):
         shape, _, dtype = np.lib.format.read_array_header_1_0(f)
         start, row = f.tell(), int(np.prod(shape[1:]))
         for lo, hi in bands:
-            t0 = time.perf_counter()
-            f.seek(start + lo * row * dtype.itemsize)
-            host = np.fromfile(f, dtype, (hi - lo) * row).reshape(
-                (hi - lo,) + tuple(shape[1:]))
-            t1 = time.perf_counter()
-            while (flying and ahead_bytes is not None
-                   and flying_bytes + host.nbytes > ahead_bytes):
-                flying_bytes -= jax.block_until_ready(flying.popleft()).nbytes
-            arrays.append(jax.device_put(host, sharding))
-            flying.append(arrays[-1])
-            flying_bytes += host.nbytes
-            read_s += t1 - t0
-            to_device_s += time.perf_counter() - t1
+            size = (hi - lo) * row * dtype.itemsize
+            with span("checkpoint/read", key=key, bytes=size) as read:
+                f.seek(start + lo * row * dtype.itemsize)
+                host = np.fromfile(f, dtype, (hi - lo) * row).reshape(
+                    (hi - lo,) + tuple(shape[1:]))
+            with span("checkpoint/to_device", key=key, bytes=size) as sent:
+                while (flying and ahead_bytes is not None
+                       and flying_bytes + host.nbytes > ahead_bytes):
+                    flying_bytes -= jax.block_until_ready(flying.popleft()).nbytes
+                arrays.append(jax.device_put(host, sharding))
+                flying.append(arrays[-1])
+                flying_bytes += host.nbytes
+            read_s += read.seconds
+            to_device_s += sent.seconds
     return arrays, read_s, to_device_s

@@ -376,6 +376,88 @@ def test_a_failed_save_is_raised_to_whoever_waits_and_commits_nothing(tmp_path):
     assert series.steps() == [] and series.clean() == 1
 
 
+def test_a_save_and_a_restore_keep_their_spans_in_the_recorder_they_are_given(tmp_path):
+    """A save on its own (no job): its record's times are its spans',
+    its writes are caused by its ``checkpoint/save``, that by whoever
+    handed it over; a failed save commits no span of a commit; and a
+    restore's seconds are the sums of its bands' spans."""
+    from mpi4jax_tpu.utils.spans import Recorder
+
+    whole = np.arange(8 * 5, dtype=np.float32).reshape(8, 5)
+    series, trace = ckpt.Series(tmp_path / "run", keep=1), Recorder("t.")
+    pieces = [(("a.npy", lo), _Host(whole[lo:lo + 2])) for lo in (0, 2, 4, 6)]
+    with trace.span("caller") as caller:
+        save = ckpt.Save(series, 9, {"step": 9}, {"a.npy": (whole.shape, whole.dtype)},
+                         pieces, ahead_bytes=64, trace=trace, cause=caller.id)
+    record = save.wait()
+    by_name = {}
+    for s in trace.spans():
+        by_name.setdefault(s.name, []).append(s)
+    assert sorted(by_name) == ["caller", "checkpoint/commit", "checkpoint/fetch",
+                               "checkpoint/prune", "checkpoint/save", "checkpoint/write"]
+    (saved,) = by_name["checkpoint/save"]
+    assert saved.cause == caller.id and saved.key == 9 and saved.counts == {"bytes": 160}
+    assert record["commit_s"] == saved.seconds
+    fetches, writes = by_name["checkpoint/fetch"], by_name["checkpoint/write"]
+    assert record["stage_s"] == (fetches[-1].end_ns - saved.start_ns) / 1e9
+    assert len(fetches) == len(writes) == 4
+    assert all(s.key == 9 and s.cause == saved.id and s.counts == {"bytes": 40}
+               for s in fetches + writes)
+    assert {s.thread for s in fetches} == {"checkpoint-save"} == {saved.thread}
+    assert {s.thread for s in writes} <= {"checkpoint-write-0", "checkpoint-write-1"}
+    assert all(saved.start_ns <= s.start_ns <= s.end_ns <= saved.end_ns
+               for s in fetches + writes + by_name["checkpoint/commit"])
+    # a save that is given no recorder keeps one of its own
+    ckpt.Save(series, 10, {"step": 10}, {"a.npy": (whole.shape, whole.dtype)},
+              [(("a.npy", 0), _Host(whole))]).wait()
+    assert len(trace.spans()) == 12
+
+    class Broken(_FakePiece):
+        def __array__(self, dtype=None, copy=None):
+            raise OSError("the copy failed")
+
+    failed = Recorder()
+    with pytest.raises(RuntimeError):
+        ckpt.Save(series, 11, {"step": 11}, {"a.npy": ((2,), np.float32)},
+                  [(("a.npy", 0), Broken(0, 8))], trace=failed).wait()
+    assert [s.name for s in failed.spans()] == ["checkpoint/fetch", "checkpoint/save"]
+
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    arrays, read_s, to_device_s = ckpt.read_pieces(
+        series.path(10) / "a.npy", [(0, 3), (3, 8)], sharding, 64, trace=trace, key=10)
+    np.testing.assert_array_equal(np.concatenate([np.asarray(a) for a in arrays]), whole)
+    reads = [s for s in trace.spans() if s.name == "checkpoint/read"]
+    sent = [s for s in trace.spans() if s.name == "checkpoint/to_device"]
+    assert [s.counts["bytes"] for s in reads] == [60, 100] == [
+        s.counts["bytes"] for s in sent]
+    assert read_s == sum(s.seconds for s in reads) > 0
+    assert to_device_s == sum(s.seconds for s in sent) > 0
+    assert {s.key for s in reads + sent} == {10}
+
+
+def test_to_host_enters_the_callers_span_round_each_fetch():
+    _FakePiece.flying = _FakePiece.most = 0
+    _FakePiece.log = []
+    pieces = [(f"p{k}", _FakePiece(k, 100)) for k in range(3)]
+    seen = []
+
+    class Round:
+        def __init__(self, piece):
+            self.k = piece.k
+
+        def __enter__(self):
+            seen.append(("in", self.k, len(_FakePiece.log)))
+
+        def __exit__(self, *exc):
+            seen.append(("out", self.k, _FakePiece.log[-1]))
+
+    assert [name for name, _ in ckpt.to_host(pieces, 100, span=Round)] == ["p0", "p1", "p2"]
+    # entered after the asks, left once the piece is fetched
+    assert [(what, k) for what, k, _ in seen] == [
+        (what, k) for k in range(3) for what in ("in", "out")]
+    assert all(last == ("fetch", k) for what, k, last in seen if what == "out")
+
+
 _KILLED_CHILD = '''
 import sys, time
 import numpy as np

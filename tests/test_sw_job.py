@@ -3,7 +3,9 @@
 same on every mesh and schedule, donation on with output, delivery in
 order and at most ``lag`` late, and ``make_solver`` as a loop over it."""
 
+import contextlib
 import pathlib
+import re
 import time
 
 import jax
@@ -243,11 +245,12 @@ def test_copies_to_the_host_are_held_under_ahead_bytes(
         lambda s, step: seen.append(step))
     ask = sw.SolverJob._ask
 
-    def counting(self):
+    def counting(self, cause=None):
         before = self._asked
-        ask(self)
+        seconds = ask(self, cause)
         asked.extend(step for step, _ in list(self._pending)[before:self._asked])
         assert self._asked <= at_once and self._asked_bytes == self._asked * one
+        return seconds
 
     monkeypatch.setattr(sw.SolverJob, "_ask", counting)
     job.start(_state(cfg, job.comm, seeded))
@@ -405,9 +408,9 @@ def test_a_save_holds_the_step_it_names_though_later_calls_run_first(
     go = __import__("threading").Event()
     to_host = sw.ckpt.to_host
 
-    def late(pieces, ahead_bytes=None):
+    def late(pieces, ahead_bytes=None, **spans):
         go.wait(60)
-        yield from to_host(pieces, ahead_bytes)
+        yield from to_host(pieces, ahead_bytes, **spans)
 
     monkeypatch.setattr(sw.ckpt, "to_host", late)
     save = job.save()
@@ -536,3 +539,178 @@ def test_a_save_of_another_grid_mesh_or_schedule_is_refused_with_both_named(
     # the schedules that carry padded tendencies say so
     assert sw.make_job(sw.SWConfig(ny=NY, nx=NX, ghost=1), _comm((1, 1)),
                        STEPS_A_CALL).form()["tendencies"] == "padded"
+
+
+# -- the job's host spans (utils/spans.py) ----------------------------------
+
+
+def _named(job, name, **counts):
+    return [s for s in job.spans() if s.name == name
+            and all(s.counts.get(k) == v for k, v in counts.items())]
+
+
+def _total(spans):
+    return sum(s.seconds for s in spans)
+
+
+@pytest.mark.parametrize("ahead", [None, 1.5])
+def test_every_time_of_a_job_with_output_is_the_sum_of_its_spans(seeded, ahead):
+    """``output_wait_s`` is the fetches and the asks that a fetch made
+    room for, ``callback_s`` the callbacks; a span's key is the step it
+    is about, and a call's spans lie inside its ``job/advance``."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    one = 3 * (NY // COARSEN) * (NX // COARSEN) * 4
+    job = sw.make_job(
+        cfg, _comm((1, 1)), STEPS_A_CALL,
+        sw.Snapshot(coarsen=COARSEN, lag=2, ahead_bytes=ahead and int(ahead * one)),
+        lambda s, step: time.sleep(0.002))
+    job.start(_state(cfg, job.comm, seeded))
+    job.advance(3)
+    job.advance(2)
+    job.drain()
+    stats, steps = job.stats(), [11, 21, 31, 41, 51]
+    assert job.trace.dropped == 0 and job.trace.prefix == sw.SCOPE_PREFIX
+    fetches, asks = _named(job, "job/fetch"), _named(job, "job/ask")
+    after_a_fetch = [a for a in asks if a.cause in {f.id for f in fetches}]
+    assert stats["output_wait_s"] == pytest.approx(
+        _total(fetches) + _total(after_a_fetch), rel=1e-9)
+    assert stats["callback_s"] == pytest.approx(
+        _total(_named(job, "job/callback")), rel=1e-9) and stats["callback_s"] > 0.01
+    # without a bound every copy is asked for as its snapshot is produced
+    assert bool(after_a_fetch) == (ahead is not None)
+    for name in ("job/fetch", "job/ask", "job/callback"):
+        assert [s.key for s in _named(job, name)] == steps
+    assert all(s.counts["bytes"] == one for s in fetches + asks)
+    assert stats["bytes_to_host"] == sum(s.counts["bytes"] for s in fetches)
+    for program in ("multi", "snap"):
+        assert [s.key for s in _named(job, "job/enqueue", program=program)] == steps
+    # a call of advance, its first step and its calls; the drain
+    advances = _named(job, "job/advance")
+    assert [(s.key, s.counts["calls"], s.cause) for s in advances] == [
+        (11, 3, None), (41, 2, None)]
+    drains = _named(job, "job/drain")
+    assert [s.key for s in drains] == [0, 51]  # start() drains first
+    roots = {s.id for s in advances + drains}
+    by_id = {s.id: s for s in job.spans()}
+    for s in job.spans():
+        assert s.thread == advances[0].thread
+        if s.id not in roots:  # caused by a span that was open, or by a fetch
+            cause = by_id[s.cause]
+            assert cause.id in roots or cause.name == "job/fetch"
+            outer = cause if cause.id in roots else by_id[cause.cause]
+            assert outer.start_ns <= s.start_ns <= s.end_ns <= outer.end_ns
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_every_time_of_a_saved_and_resumed_job_is_the_sum_of_its_spans(
+        seeded, tmp_path, mesh_shape):
+    """``save_wait_s``, ``save_enqueue_s``, a save's ``stage_s`` and
+    ``commit_s``, ``restore_read_s`` and ``restore_to_device_s``; every
+    span of a save's threads under the step it holds, every write caused
+    by the ``checkpoint/save`` of its own save, that one by its
+    ``job/save``."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    ck = sw.Checkpoint(tmp_path / "run", every_calls=1, keep=2, ahead_bytes=4096)
+    job = sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=ck)
+    job.start(_state(cfg, comm, seeded))
+    job.advance(3)
+    job.drain()
+    stats = job.stats()
+    assert stats["save_wait_s"] == pytest.approx(
+        _total(_named(job, "job/save_wait")), rel=1e-9) and stats["save_wait_s"] > 0
+    assert stats["save_enqueue_s"] == pytest.approx(
+        _total(_named(job, "job/save_start")), rel=1e-9)
+    saves = {s.key: s for s in _named(job, "job/save")}
+    assert sorted(saves) == [11, 21, 31] == [r["step"] for r in job.saves]
+    state_bytes = sum(a.nbytes for a in job.state)
+    pieces = sum(len(plan) for plan in job._plan)
+    main = saves[11].thread
+    for record in job.saves:
+        step = record["step"]
+        mine = [s for s in job.spans() if s.key == step and s.name.startswith("checkpoint/")]
+        (whole,) = [s for s in mine if s.name == "checkpoint/save"]
+        assert whole.cause == saves[step].id and whole.thread == "checkpoint-save"
+        assert whole.counts["bytes"] == saves[step].counts["bytes"] == state_bytes
+        assert record["commit_s"] == whole.seconds
+        fetches = [s for s in mine if s.name == "checkpoint/fetch"]
+        writes = [s for s in mine if s.name == "checkpoint/write"]
+        assert len(fetches) == len(writes) == pieces
+        assert record["stage_s"] == (fetches[-1].end_ns - whole.start_ns) / 1e9
+        assert sum(s.counts["bytes"] for s in fetches) == state_bytes
+        assert sum(s.counts["bytes"] for s in writes) == state_bytes
+        assert {s.cause for s in fetches} == {whole.id}
+        assert {s.thread for s in fetches} == {"checkpoint-save"}
+        # a write is caused by the save, whichever writer took it
+        assert {s.cause for s in writes} == {whole.id}
+        assert {s.thread for s in writes} <= {"checkpoint-write-0", "checkpoint-write-1"}
+        (commit,) = [s for s in mine if s.name == "checkpoint/commit"]
+        (prune,) = [s for s in mine if s.name == "checkpoint/prune"]
+        assert commit.cause == prune.cause == whole.id
+        assert whole.start_ns <= fetches[0].start_ns and commit.end_ns <= whole.end_ns
+        assert whole.end_ns <= prune.start_ns  # commit_s ends at the rename
+        # the staging program's enqueue lies in the loop's job/save_start
+        (staged,) = [s for s in _named(job, "job/enqueue", program="stage")
+                     if s.key == step]
+        (started,) = [s for s in _named(job, "job/save_start") if s.key == step]
+        assert staged.cause == started.id and started.cause == saves[step].id
+        assert staged.thread == main
+    assert stats["save_commit_s"] == sum(r["commit_s"] for r in job.saves)
+    # a save waited for is named by the step it holds, not the next one's
+    assert [s.key for s in _named(job, "job/save_wait")] == [
+        s.key - STEPS_A_CALL for s in saves.values()
+        if any(w.cause == s.id for w in _named(job, "job/save_wait"))]
+    # resumed: every band read and handed to the device is a span
+    fresh = sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=ck)
+    assert fresh.resume() == 31
+    stats = fresh.stats()
+    (whole,) = _named(fresh, "job/resume")
+    reads, sent = _named(fresh, "checkpoint/read"), _named(fresh, "checkpoint/to_device")
+    assert 0 < stats["restore_read_s"] == pytest.approx(_total(reads), rel=1e-9)
+    assert 0 < stats["restore_to_device_s"] == pytest.approx(_total(sent), rel=1e-9)
+    assert len(reads) == len(sent) == pieces
+    assert {s.cause for s in reads + sent} == {whole.id}
+    assert {s.key for s in reads + sent} == {31} and whole.key == 31
+    assert whole.counts["bytes"] == state_bytes == sum(s.counts["bytes"] for s in reads)
+    (compiled,) = _named(fresh, "job/compile")
+    assert compiled.cause == whole.id and compiled.key == 31
+    assert whole.seconds >= _total(reads) + _total(sent) + compiled.seconds
+
+
+def _without_callers(text):
+    """A compiled text less what says where it was traced from (the
+    job's programs are traced from ``advance``, a bare one from its
+    caller): the tables of files, functions and frames, and each
+    instruction's frame.  Names, scopes, shapes and schedule stay."""
+    text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:\d+ .*\n)+", "", text, flags=re.M)
+    return re.sub(r" stack_frame_id=\d+", "", text)
+
+
+def test_the_recorder_leaves_the_jobs_compiled_programs_as_they_are(seeded, tmp_path):
+    """``multi``, ``snap`` and ``stage`` compiled inside an open span of
+    the job's recorder, after calls made through it, are to the letter
+    the programs made with no job and no recorder."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm((2, 2))
+    snapshot = sw.Snapshot(coarsen=COARSEN)
+    job = sw.make_job(cfg, comm, STEPS_A_CALL, snapshot, lambda s, step: None,
+                      sw.Checkpoint(tmp_path / "run", every_calls=2, ahead_bytes=4096))
+    job.start(_state(cfg, comm, seeded))
+    job.advance(2)
+    job.drain()
+    written = job._written()
+    programs = {
+        True: [(job.multi, (job.state,)), (job.snap, written), (job.stage, (job.state,))],
+        False: [(sw.make_multistep(cfg, comm, STEPS_A_CALL, donate=True), (job.state,)),
+                (sw.make_snapshot(cfg, comm, snapshot), written),
+                (sw.make_stage(comm, job._plan), (job.state,))]}
+    texts = {}
+    for mine in (True, False):
+        with job.trace.span("job/enqueue") if mine else contextlib.nullcontext():
+            texts[mine] = [_without_callers(program.lower(*args).compile().as_text())
+                           for program, args in programs[mine]]
+    assert texts[True] == texts[False]
+    assert "job/" not in "".join(texts[True])
+    assert sw.SCOPE_PREFIX + "checkpoint" in texts[True][2]
+    assert sw.SCOPE_PREFIX + "snapshot" in texts[True][1]
