@@ -129,6 +129,7 @@ class Session:
             initial, mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec,) * 6))
         self._interior = jax.jit(jax.shard_map(
             interior, mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec,) * 3))
+        self._text = None
         # warm up the two programs the window and the check drive
         self.state = self.multi(self.first(self._initial_state()))
         jax.block_until_ready(self.state)
@@ -163,12 +164,16 @@ class Session:
         }
 
     def facts(self):
-        G = self.ghost
-        return {
-            "steps_per_call": self.steps_per_call,
-            "cells": self.ny * self.nx,
-            "padded_field_bytes": (self.ny + 2 * G) * (self.nx + 2 * G) * 4,
-        }
+        return {"steps_per_call": self.steps_per_call, "cells": self.ny * self.nx}
+
+    def compiled_text(self, key):
+        """The text of the multistep, the window's one program whatever
+        ``key`` a reader knows it by, as compiled for the state at hand
+        (what ``harness/scopes.py attribute`` and ``signature`` read),
+        compiled once however many readers ask."""
+        if self._text is None:
+            self._text = self.multi.lower(self.state).compile().as_text()
+        return self._text
 
     # -- after the window ----------------------------------------------
 

@@ -18,7 +18,10 @@ that keeps the last ``kept`` and does no arithmetic.
 
 The profiler of a traced run therefore stops while the window's last
 snapshot runs, and records part of it: the readers of this cell go
-through ``Session.traced_programs``, which leaves a cut one out.
+through ``Session.traced_programs``, which leaves a cut one out.  A call
+is the programs the job holds (``Session.programs``): a job without a
+snapshot program of its own is a call of one program, and the trace's
+count of modules decides nothing by itself.
 """
 
 import collections
@@ -33,7 +36,7 @@ from perfbench.harness.trace import Trace
 
 plain = files.load_module("drivers", "shallow_water")
 FIELDS = plain.FIELDS
-MULTI, SNAPSHOT = "multistep", "snapshot"  # the programs of a call, in order
+MULTI, SNAPSHOT = "multistep", "snapshot"  # the keys of a call's programs
 
 
 class Session:
@@ -157,40 +160,42 @@ class Session:
         }
 
     def facts(self):
-        G, c = self.ghost, self.coarsen
-        return {
-            "steps_per_call": self.steps_per_call,
-            "cells": self.ny * self.nx,
-            "padded_field_bytes": (self.ny + 2 * G) * (self.nx + 2 * G) * 4,
-            "snapshot_fields": len(FIELDS),
-            "coarse_field_bytes": (self.ny // c) * (self.nx // c) * 4,
-        }
+        return {"steps_per_call": self.steps_per_call, "cells": self.ny * self.nx}
+
+    def programs(self):
+        """The keys of the programs a call runs, in order: the multistep
+        and, where the job holds one, the snapshot program."""
+        return (MULTI,) if self.job.snap is None else (MULTI, SNAPSHOT)
 
     def traced_programs(self, trace, traced):
         """``(trace, executions)`` for ``harness/scopes.py``: the
         program each device execution of the traced batches ran, in
-        order (a call's multistep, then its snapshot), and the trace
-        they are matched with.  The window's last snapshot is running
-        when the profiler stops: where a chip's trace holds fewer of
-        its operations than of the snapshot before, or not its
+        order (a call's ``programs()``), and the trace they are matched
+        with.  Where a call ends in a snapshot the window's last one is
+        running when the profiler stops: where a chip's trace holds
+        fewer of its operations than of the snapshot before, or not its
         execution at all, both are returned without it, so that a
         reader takes whole executions only and divides a program's
         time by the executions it has."""
+        a_call = self.programs()
         executions = [key for s in traced
                       for _ in range(self.rows[s.row]["reps"])
-                      for key in (MULTI, SNAPSHOT)]
-        n = len(executions)
+                      for key in a_call]
+        n, per = len(executions), len(a_call)
         ordered = {plane: sorted(trace.modules.get(plane, ()),
                                  key=lambda m: m.start_ns)
                    for plane in trace.device_ops}
-        if n < 4 or any(len(modules) not in (n - 1, n)
-                        for modules in ordered.values()):
+        # a batch ends when its last multistep's state is ready: only
+        # what a call runs after that can be cut
+        if (a_call[-1] == MULTI or n < 2 * per
+                or any(len(modules) not in (n - 1, n)
+                       for modules in ordered.values())):
             return trace, executions  # the harness says what does not match
         whole = Trace(host=trace.host)
         cut = False
         for plane, modules in ordered.items():
             events = trace.device_ops[plane]
-            before, end = modules[n - 3], modules[n - 2].end_ns
+            before, end = modules[n - 1 - per], modules[n - 2].end_ns
             of_last = sum(e.start_ns >= end for e in events)
             of_before = sum(before.start_ns <= e.start_ns < before.end_ns
                             for e in events)
@@ -205,13 +210,15 @@ class Session:
 
     def compiled_text(self, key):
         """The text of one of the call's programs as compiled for the
-        state at hand (what ``harness/scopes.py attribute`` reads),
-        compiled once however many readers ask."""
+        state at hand (what ``harness/scopes.py attribute`` and
+        ``signature`` read), compiled once however many readers ask."""
         if key not in self._texts:
             job = self.job
             written = tuple(getattr(job.state, k) for k in FIELDS)
             program, args = {MULTI: (job.multi, (job.state,)),
                              SNAPSHOT: (job.snap, written)}[key]
+            if program is None:
+                raise KeyError(f"the job holds no {key!r} program")
             self._texts[key] = program.lower(*args).compile().as_text()
         return self._texts[key]
 

@@ -237,13 +237,7 @@ class Session:
               f"{now.ru_stime - then.ru_stime:.2f} s system", flush=True)
 
     def facts(self):
-        G = self.ghost
-        return {
-            "steps_per_call": self.steps_per_call,
-            "cells": self.ny * self.nx,
-            "padded_field_bytes": (self.ny + 2 * G) * (self.nx + 2 * G) * 4,
-            "state_bytes": sum(a.nbytes for a in self.job.state),
-        }
+        return {"steps_per_call": self.steps_per_call, "cells": self.ny * self.nx}
 
     def window_saves(self):
         """The records of the saves the window's job has had

@@ -17,14 +17,23 @@ contains it, and the module executions of a chip are matched, in order,
 with the programs the traced batches ran.  Where trace and programs do
 not belong together the reader says so and returns ``None``; it never
 guesses, and never reports 0 for "not found".
+
+The same text says the least a program can move: what it is handed and
+what it hands back, each once (``signature``).  A floor read there
+follows the program; a count written down by hand goes stale with the
+first change that moves fewer bytes, and a share of a roofline then
+reads over 100.
 """
 
 import bisect
+import functools
+import math
 import re
+import typing
 from dataclasses import dataclass
 
 from perfbench.harness import files
-from perfbench.harness.trace import short_name
+from perfbench.harness.trace import short_name, union_ns
 
 # the benchmark's own copy of the program's prefix (ops/_core.py
 # SCOPE_PREFIX): the parent's programs carry the scopes without exporting it
@@ -44,8 +53,15 @@ _TABLE = re.compile(
     r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n((?:\d+ .*\n)+)",
     re.M)
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$", re.M)
-_METADATA = re.compile(r'metadata=\{((?:[^{}"]|"[^"]*")*)\}')
+# anchored: a kernel call's line has `frontend_attributes={kernel_metadata={}}`
+# before its own `metadata={op_name=... stack_frame_id=...}`
+_METADATA = re.compile(r'(?<![\w])metadata=\{((?:[^{}"]|"[^"]*")*)\}')
 _FIELD = re.compile(r'(\w+)=(?:"([^"]*)"|(\d+))')
+_ENTRY = re.compile(r"^ENTRY %?[\w.\-]+ \((.*)\) -> (.*) \{$", re.M)
+_ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)\w*)\[([\d,]*)\]")
+_COMMENT = re.compile(r"/\*.*?\*/")
+
+KERNEL_CALL = "custom-call"  # the opcode of a Pallas kernel's instruction
 
 
 @dataclass(frozen=True)
@@ -140,11 +156,11 @@ def layer_of(origin):
     return PROGRAMS if MODELS_DIR in origin.source else CALLER
 
 
-def opcode(event_name):
-    """``%psum_invariant.17 = f32[8]{0:T(128)} all-reduce(..)`` ->
-    ``all-reduce``: what follows the shape, which may be a tuple and
-    holds brackets of all three kinds."""
-    rest = event_name.partition(" = ")[2]
+def _parts(rest):
+    """``(result shape, opcode, operands)`` of what follows an
+    instruction's `` = ``: the shape may be a tuple and holds brackets of
+    all three kinds; the operands are what the opcode's own brackets
+    hold.  ``None`` where nothing follows the shape."""
     depth = 0
     for i, c in enumerate(rest):
         if c in "([{":
@@ -152,8 +168,23 @@ def opcode(event_name):
         elif c in ")]}":
             depth -= 1
         elif c == " " and depth == 0:
-            return rest[i + 1:].partition("(")[0]
-    return None
+            break
+    else:
+        return None
+    op, _, tail = rest[i + 1:].partition("(")
+    depth = 1
+    for j, c in enumerate(tail):
+        depth += (c in "([{") - (c in ")]}")
+        if depth == 0:
+            return rest[:i], op, tail[:j]
+    return rest[:i], op, tail
+
+
+def opcode(event_name):
+    """``%psum_invariant.17 = f32[8]{0:T(128)} all-reduce(..)`` ->
+    ``all-reduce``: what follows the shape."""
+    parts = _parts(event_name.partition(" = ")[2])
+    return parts[1] if parts else None
 
 
 def is_collective(event_name):
@@ -163,6 +194,99 @@ def is_collective(event_name):
     for suffix in ("-start", "-done"):
         op = op.removesuffix(suffix)
     return op in _COLLECTIVES
+
+
+class Signature(typing.NamedTuple):
+    """Logical bytes (shape x element size) of the arrays a program, or
+    one instruction of it, is handed and hands back."""
+
+    taken: int
+    handed_back: int
+
+    @property
+    def bytes(self):
+        return self.taken + self.handed_back
+
+
+def shape_bytes(shape_text):
+    """Bytes of every array a printed shape names, a tuple's added up:
+    ``(f32[7204,2]{1,0:T(8,128)S(1)}, s32[])`` -> 57636.  A token or an
+    opaque value has no element size and counts nothing."""
+    return sum(int(bits or 8) * math.prod(int(d) for d in dims.split(",") if d) // 8
+               for _name, bits, dims in _ARRAY.findall(shape_text))
+
+
+def _split_operands(text):
+    depth, start = 0, 0
+    for i, c in enumerate(text):
+        depth += (c in "([{") - (c in ")]}")
+        if c == "," and depth == 0:
+            yield text[start:i]
+            start = i + 1
+    yield text[start:]
+
+
+@functools.lru_cache(maxsize=8)
+def _signatures(compiled_text):
+    """``{instruction: Signature}`` of one compiled text, the program's
+    own under ``None``.  A compiled text names an operand (``%p.1``) and
+    the trace's event names print its shape before the name; an operand
+    without a shape of its own is the result of the instruction it
+    names, and what names none (a parameter's number, a constant's
+    value) is no array."""
+    lines = [(name, _parts(rest))
+             for name, rest in _INSTRUCTION.findall(compiled_text)]
+    results = {name: shape_bytes(parts[0]) for name, parts in lines if parts}
+    table = {}
+    for name, parts in lines:
+        if not parts:
+            continue
+        taken = 0
+        for operand in _split_operands(_COMMENT.sub("", parts[2])):
+            shape, _, named = operand.strip().rpartition(" ")
+            taken += (shape_bytes(shape) if shape
+                      else results.get(named.lstrip("%"), 0))
+        table[name] = Signature(taken, results[name])
+    entry = _ENTRY.search(compiled_text)
+    if entry:
+        table[None] = Signature(*(shape_bytes(side) for side in entry.groups()))
+    return table
+
+
+def signature(compiled_text, instruction=None):
+    """The least ``compiled_text``'s program can move, or one
+    ``instruction`` of it: what it is handed (the ``ENTRY`` computation's
+    parameters; the instruction's array operands) and what it hands back
+    (its results), each once.  A program cannot run in less than it
+    takes to read the one and write the other.  Scalars and operands in
+    SMEM are counted: they are noise.  An operand's shape overstates
+    what an instruction reads where it reads part of it (a ``slice``), so
+    only a caller that knows the instruction reads all it is handed
+    takes ``bytes``; ``handed_back`` is safe of any.  ``None`` where the
+    text has no such instruction, or no ``ENTRY``."""
+    return _signatures(compiled_text).get(instruction)
+
+
+def floor_share(trace, executions, key, text_of, hbm_gbps, noun):
+    """Share of the HBM roofline the program ``key`` reaches, in per
+    cent: the least time its signature bytes could take at ``hbm_gbps``
+    over the device time of one execution of it (the union of its leaf
+    events, a mean over the executions ``trace`` holds); ``text_of(key)``
+    gives its compiled text, asked for only when the counts match.
+    ``None``, with the reason printed, where trace and ``executions`` do
+    not belong together."""
+    placed = by_execution(trace, executions)
+    if placed is None:
+        return None
+    mine = [events for of_chip in placed.values()
+            for k, events in of_chip if k == key]
+    seconds = sum(union_ns(events) for events in mine) / len(mine) / 1e9
+    least = signature(text_of(key))
+    least_s = least.bytes / (hbm_gbps * 1e9)
+    print(f"perfbench: {noun} takes {seconds * 1e6:.3f} us of device time, "
+          f"the least its {least.taken} bytes in and {least.handed_back} out "
+          f"could {least_s * 1e6:.3f} us", flush=True)
+    return 100.0 * least_s / seconds
 
 
 @dataclass(frozen=True)

@@ -24,10 +24,13 @@ def spread(values):
 
 
 # nccl-tests' bus-bandwidth factors (doc/PERFORMANCE.md there): what the
-# busiest link carries per byte of the per-rank payload.
+# busiest link carries per byte of the per-rank payload, the payload being
+# what a rank sends (`drivers/collectives.py payload_bytes`).  nccl-tests
+# counts an allgather by the n shards a rank holds after it and multiplies
+# them by (n - 1) / n; by the one shard it sends, that is n - 1.
 BUSBW_FACTOR = {
     "allreduce": lambda n: 2 * (n - 1) / n,
-    "allgather": lambda n: (n - 1) / n,
+    "allgather": lambda n: n - 1,
     "alltoall": lambda n: (n - 1) / n,
     "bcast": lambda n: 1.0,
     "sendrecv": lambda n: 1.0,

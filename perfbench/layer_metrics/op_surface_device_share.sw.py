@@ -17,9 +17,7 @@ def read(view):
     session = view.session
     executions = [MULTI for s in view.traced
                   for _ in range(session.rows[s.row]["reps"])]
-    rows = scopes.attribute(
-        view.trace, executions,
-        lambda key: session.multi.lower(session.state).compile().as_text())
+    rows = scopes.attribute(view.trace, executions, session.compiled_text)
     if rows is None:
         return None
     busy = trace.busy_s(view.trace)

@@ -1,31 +1,80 @@
-"""Share of the job's device time spent making snapshots, in per cent:
-the leaf events of the trace whose instruction lies under the program's
-``mpi4jax_tpu.snapshot`` scope in the text of the program that ran it,
-over the device time of all leaf events.  A call of the job runs two
-programs, the multistep and the snapshot; each event is read against the
-text of its own, and each program's time is taken per execution the
-trace holds whole (the profiler stops inside the window's last
-snapshot).  Prints where all the device time goes, by layer and by
-source."""
+"""Share of a call's device time that the job spends because it has
+output, in per cent, wherever it spends it: in a snapshot program of its
+own, in a longer last step, in a finish after it.
+
+    100 x (call - k x period) / call
+
+``call``: the device time from one multistep's start to the next's,
+every program between them (the union of their leaf events, a mean over
+the calls the trace holds whole).  ``k``: the kernel calls of one
+multistep's execution.  ``period``: the median, over the window's kernel
+calls, of the device time from one kernel call's start to the next's
+(the last of an execution: to the execution's end).  A step repeats and
+output comes once a call, so the median is deaf to the one period that
+carries output, and nothing in it asks how many steps a kernel call
+advances.  While a call is the multistep and a snapshot program that
+holds all of output's work, this is that program's share of the call.
+
+Where the multistep runs no kernel call (the array code: no cell)
+there is no period, and nothing is reported.  Prints where all the
+device time goes, by layer and by source."""
 
 import collections
+import statistics
 
 from perfbench.harness import scopes, trace
 
-SCOPE = scopes.SCOPE_PREFIX + "snapshot"
+MULTI = "multistep"
+
+
+def periods(events):
+    """Device time from each kernel call's start to the next's, the
+    last one's to the end of ``events`` (one execution's)."""
+    events = sorted(events, key=lambda e: e.start_ns)
+    starts = [i for i, e in enumerate(events)
+              if scopes.opcode(e.name) == scopes.KERNEL_CALL]
+    return [trace.union_ns(events[a:b])
+            for a, b in zip(starts, starts[1:] + [len(events)])]
 
 
 def read(view):
     session = view.session
     whole, executions = session.traced_programs(view.trace, view.traced)
-    rows = scopes.attribute(whole, executions, session.compiled_text)
-    if rows is None:
+    placed = scopes.by_execution(whole, executions)
+    if placed is None:
         return None
-    per = collections.Counter(executions)
-    busy = trace.busy_s(whole)
-    scopes.print_layers("device time by layer", rows, busy)
-    scopes.print_table("device time by origin", rows, busy, per, "call")
-    a_call = sum(r.seconds / per[r.program] for r in rows)
-    mine = sum(r.seconds / per[r.program] for r in rows
-               if r.scopes[:1] == (SCOPE,))
-    return 100.0 * mine / a_call
+    rows = scopes.attribute(whole, executions, session.compiled_text)
+    if rows is not None:
+        per = collections.Counter(executions)
+        busy = trace.busy_s(whole)
+        scopes.print_layers("device time by layer", rows, busy)
+        scopes.print_table("device time by origin", rows, busy, per, "call")
+    calls, of_kernels = [], []
+    per_call = len(session.programs())
+    for of_chip in placed.values():
+        starts = [i for i, (key, _) in enumerate(of_chip) if key == MULTI]
+        for a, b in zip(starts, starts[1:] + [len(of_chip)]):
+            if b - a == per_call:  # a last call without its cut snapshot is not whole
+                calls.append(sum(trace.union_ns(events)
+                                 for _, events in of_chip[a:b]))
+            of_kernels.append(periods(of_chip[a][1]))
+    if not calls:
+        print("perfbench: the trace holds no whole call: nothing is reported",
+              flush=True)
+        return None
+    k = {len(found) for found in of_kernels}
+    if k == {0}:
+        print("perfbench: the multistep ran no kernel call: there is no "
+              "period to hold a call against; nothing is reported", flush=True)
+        return None
+    if len(k) != 1:
+        print(f"perfbench: the multistep's executions ran {sorted(k)} kernel "
+              "calls, not one number: nothing is reported", flush=True)
+        return None
+    k, = k
+    call = statistics.fmean(calls)
+    period = statistics.median(p for found in of_kernels for p in found)
+    print(f"perfbench: a call takes {call / 1e3:.3f} us of device time, its "
+          f"{k} kernel calls' periods {period / 1e3:.3f} us at the median: "
+          f"{(call - k * period) / 1e3:.3f} us a call are output's", flush=True)
+    return 100.0 * (call - k * period) / call

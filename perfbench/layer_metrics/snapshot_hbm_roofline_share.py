@@ -1,37 +1,34 @@
 """Share of the HBM roofline the snapshot program reaches, in per cent.
 
-The least a snapshot can move: each of its fields' padded arrays read
-once (the interior's blocks lie in every tile of it) and the coarse
-field written once.  fields x ((ny+2G)(nx+2G) + (ny/c)(nx/c)) x 4 bytes
-over the table's HBM bandwidth, divided by the device time of one
-execution of the snapshot program from the trace (the union of its leaf
-events, a mean over the executions the trace holds whole).  Bound:
-bandwidth (a mean of c x c cells is one addition a
-cell)."""
+The least a snapshot program can move is what it is handed and what it
+hands back, each once: its signature bytes, read from its compiled text
+(``harness/scopes.py signature``).  As the job makes it since PR 34:
+each field's padded array in and its coarse field out, fields x
+((ny+2G)(nx+2G) + (ny/c)(nx/c)) x 4 bytes; a program that is handed
+coarse sums has a floor of their bytes.  Over the table's HBM bandwidth,
+divided by the device time of one execution of the snapshot program from
+the trace (the union of its leaf events, a mean over the executions the
+trace holds whole).  Bound: bandwidth (a mean of c x c cells is one
+addition a cell).
 
-from perfbench.harness import scopes, trace
+Where a call has no program of its own for the snapshot, what output
+costs lies in the step's programs: ``snapshot_device_share.sw`` counts
+it there, and this reader says so and reports nothing."""
+
+from perfbench.harness import scopes
 
 SNAPSHOT = "snapshot"
 
 
-def least_bytes_per_snapshot(fields, padded_field_bytes, coarse_field_bytes):
-    return fields * (padded_field_bytes + coarse_field_bytes)
-
-
 def read(view):
-    placed = scopes.by_execution(
-        *view.session.traced_programs(view.trace, view.traced))
-    if placed is None:
+    session = view.session
+    whole, executions = session.traced_programs(view.trace, view.traced)
+    if SNAPSHOT not in executions:
+        print("perfbench: a call has no program of its own for the snapshot: "
+              "what output costs is in the step's programs, where "
+              "snapshot_device_share.sw counts it; nothing is reported",
+              flush=True)
         return None
-    mine = [events for of_chip in placed.values()
-            for key, events in of_chip if key == SNAPSHOT]
-    if not mine:
-        return None
-    per_snapshot = sum(trace.union_ns(events) for events in mine) / len(mine) / 1e9
-    facts = view.facts
-    least_s = least_bytes_per_snapshot(
-        facts["snapshot_fields"], facts["padded_field_bytes"],
-        facts["coarse_field_bytes"]) / (view.peaks["hbm_gbps"] * 1e9)
-    print(f"perfbench: a snapshot takes {per_snapshot * 1e6:.3f} us of device "
-          f"time, the least its bytes could {least_s * 1e6:.3f} us", flush=True)
-    return 100.0 * least_s / per_snapshot
+    return scopes.floor_share(
+        whole, executions, SNAPSHOT, session.compiled_text,
+        view.peaks["hbm_gbps"], "a snapshot")

@@ -313,14 +313,19 @@ def test_a_saves_busy_shares_are_unions_over_its_own_span(capsys):
     assert not any("| 1 | -9" in line for line in lines)
 
 
-def test_every_reader_has_its_file_and_none_is_registered_yet():
-    """The seven readers are files beside the accepted ones; the cells'
-    lists of readers are pinned by tests this PR may not edit, so
-    ``BENCHMARK.json`` names none of them until a benchmark PR does."""
-    named = {m["name"] for m in files.load_benchmark()["per_layer"]}
+def test_every_reader_has_its_file_and_the_job_cells_list_it():
+    """The seven readers are files beside the accepted ones, and since
+    PR 39 the cells whose trace and spans they read list them: the five
+    of the loop's thread on both job cells, the save's two on the
+    restarted one."""
+    benchmark = files.load_benchmark()
+    listed = {m["name"]: m for m in benchmark["per_layer"]}
     for name in READERS:
         assert hasattr(files.load_module("layer_metrics", name), "read")
-        assert name not in named
+        assert listed[name]["moves"] == "solver_rate"
+        assert listed[name]["workloads"] == (
+            ["sw-restart-1chip"] if name.startswith("save_")
+            else ["sw-job-1chip", "sw-restart-1chip"])
 
 
 def test_the_harness_runs_the_readers_once_a_cell_lists_them(

@@ -19,13 +19,19 @@ from perfbench import run
 from perfbench.harness import files, scopes
 from perfbench.harness.trace import Event, Trace
 
-from perfbench_fixtures import ROOT, cell_args, make_copy
+from perfbench_fixtures import (
+    ROOT, a_step, cell_args, event_lines, made_job_session, make_copy,
+    multistep_text, program_text)
 
 CHIP = "/device:TPU:0"
 JOB_CELLS = ["sw-job-toy-1x1", "sw-job-toy-2x2"]
 NEW_READERS = ["snapshot_device_share.sw", "snapshot_hbm_roofline_share",
                "output_wait_share.sw", "state_copy_bytes_per_call.sw",
                "sw_hbm_roofline_share.job", "op_surface_device_share.job"]
+# PR 38's readers of the job's spans, listed since PR 39
+HOST_SPANS = ["host_device_clock_bracket_us", "idle_in_sync_share.sw",
+              "idle_in_job_share.sw", "idle_unnamed_share.sw",
+              "job_issue_us_per_call.sw"]
 
 
 @pytest.fixture(scope="module")
@@ -192,7 +198,7 @@ def _reader(copy, name):
     return files.load_module("layer_metrics", name, copy[1])
 
 
-def test_snapshot_readers_on_a_hand_made_trace(copy, job_session):
+def test_snapshot_readers_on_a_hand_made_trace(copy, job_session, capsys):
     multi = job_session.compiled_text("multistep")
     snap = job_session.compiled_text("snapshot")
     coarse = _pick(snap, lambda o, line: o.scopes[:2] == (
@@ -202,14 +208,17 @@ def test_snapshot_readers_on_a_hand_made_trace(copy, job_session):
     # a batch of three calls: the multistep 900 ns, the snapshot 100 ns
     made = _trace([[(step, 900)], [(coarse, 60), (coarse, 40)]] * 3)
     view = _view(job_session, made)
-    assert _reader(copy, "snapshot_device_share.sw").read(view) == pytest.approx(10.0)
+    # the snapshot program as the job makes it: three padded fields in,
+    # three coarse fields out, which is what its compiled text says
+    least = scopes.signature(snap)
+    assert least == (3 * 36 * 68 * 4, 3 * 16 * 32 * 4)
     reader = _reader(copy, "snapshot_hbm_roofline_share")
-    facts = job_session.facts()
-    least = reader.least_bytes_per_snapshot(
-        3, facts["padded_field_bytes"], facts["coarse_field_bytes"])
-    assert least == 3 * (36 * 68 + 16 * 32) * 4
-    assert reader.read(view) == pytest.approx(100 * (least / 819e9) / 100e-9)
+    assert reader.read(view) == pytest.approx(100 * (least.bytes / 819e9) / 100e-9)
     assert _reader(copy, "state_copy_bytes_per_call.sw").read(view) == 0.0
+    # on this backend the step is array code: no kernel call, no period
+    capsys.readouterr()
+    assert _reader(copy, "snapshot_device_share.sw").read(view) is None
+    assert "ran no kernel call" in capsys.readouterr().out
 
 
 def _step_and_snapshot_lines(job_session):
@@ -224,51 +233,71 @@ def _step_and_snapshot_lines(job_session):
     return step, halo, coarse
 
 
-def test_the_steps_own_readers_leave_the_snapshot_out(copy, job_session):
+@pytest.fixture(scope="module")
+def made():
+    """The job's two programs as the TPU backend compiles them, made by
+    hand at the toy cell's shapes (the CPU runs no kernel call): a
+    session round their texts, a call's multistep (ten steps of 270 ns,
+    30 of them the sent slabs' fusions) and its snapshot (300 ns)."""
+    texts = {"multistep": multistep_text(36, 68),
+             "snapshot": program_text((36, 68), (16, 32))}
+    means = event_lines(texts["snapshot"])
+    return (made_job_session(texts),
+            a_step(event_lines(texts["multistep"]), kernel_ns=239) * 10,
+            [(means["out.0"], 180), (means["out.1"], 120)])
+
+
+def test_the_steps_own_readers_leave_the_snapshot_out(copy, made, capsys):
     """``sw_hbm_roofline_share`` and ``op_surface_device_share.sw`` read
     one program a call; the job's own read the multistep's executions."""
-    step, halo, coarse = _step_and_snapshot_lines(job_session)
-    made = _trace([[(step, 800), (halo, 100)], [(coarse, 60), (coarse, 40)]] * 3)
-    view = _view(job_session, made)
+    session, multistep, snapshot = made
+    view = _view(session, _trace([multistep, snapshot] * 3))
     assert _reader(copy, "op_surface_device_share.job").read(view) == (
-        pytest.approx(100 * 100 / 900))
-    accepted = _reader(copy, "sw_hbm_roofline_share")
-    least = accepted.least_bytes_per_step(job_session.facts()["padded_field_bytes"])
+        pytest.approx(100 * 300 / 2700))
+    # a step's least bytes are the kernel call's signature (its operands
+    # and its results, once) and twice every other instruction's result
+    field, slab = 36 * 68 * 4, 36 * 2 * 4
+    least = (12 * field + 6 * slab + 8 + 12) + 3 * 2 * 2 * slab + 2 * 8
+    assert least == (scopes.signature(
+        session.compiled_text("multistep"), "wide_step.3").bytes + 12 * slab + 16)
     assert _reader(copy, "sw_hbm_roofline_share.job").read(view) == (
-        pytest.approx(100 * (least / 819e9) / (900e-9 / 10)))
-    # the accepted one books the snapshot's 100 ns and the gaps' to the step
-    assert accepted.read(view) < 100 * (least / 819e9) / (1000e-9 / 10)
+        pytest.approx(100 * (least / 819e9) / (2700e-9 / 10)))
+    assert f"a step moves {least} bytes at the least" in capsys.readouterr().out
+    # the accepted one reads one program a call: it finds the snapshot's
+    # events in no text of the step's and books them to nobody
+    assert _reader(copy, "sw_hbm_roofline_share").read(view) is None
+    assert "the multistep's text has no out.0" in capsys.readouterr().out
+    assert _reader(copy, "snapshot_device_share.sw").read(view) == pytest.approx(10.0)
 
 
 @pytest.mark.parametrize("recorded", ["part", "nothing", "all"])
 def test_a_last_snapshot_the_profiler_cut_is_left_out(
-        copy, job_session, capsys, recorded):
+        copy, made, capsys, recorded):
     """A batch ends when its last call's state is ready, so the profiler
     stops while the window's last snapshot runs.  What the trace has of
     it counts for nothing: every reader reads what it reads from the
     whole executions, a program's time over the executions it has."""
-    step, halo, coarse = _step_and_snapshot_lines(job_session)
-    call = [[(step, 800), (halo, 100)], [(coarse, 60), (coarse, 40)]]
-    last = {"part": [(coarse, 60)], "nothing": [], "all": call[1]}[recorded]
-    made = _trace(call * 2 + [call[0], last])
+    session, multistep, snapshot = made
+    last = {"part": snapshot[:1], "nothing": [], "all": snapshot}[recorded]
+    trace = _trace([multistep, snapshot] * 2 + [multistep, last])
     if recorded == "nothing":  # not even its execution
-        made.modules[CHIP].pop()
-    view = _view(job_session, made)
-    whole, executions = job_session.traced_programs(made, view.traced)
+        trace.modules[CHIP].pop()
+    view = _view(session, trace)
+    whole, executions = session.traced_programs(trace, view.traced)
     assert len(executions) == (6 if recorded == "all" else 5)
     assert len(whole.modules[CHIP]) == len(executions)
-    assert len(whole.device_ops[CHIP]) == 2 * len(executions)  # two each
+    assert len(whole.device_ops[CHIP]) == (  # fifty events and two a call
+        3 * 50 + 2 * (3 if recorded == "all" else 2))
     assert _reader(copy, "snapshot_device_share.sw").read(view) == pytest.approx(10.0)
     said = "leave that execution out" in capsys.readouterr().out
     assert said == (recorded != "all")
-    facts = job_session.facts()
-    reader = _reader(copy, "snapshot_hbm_roofline_share")
-    least = reader.least_bytes_per_snapshot(
-        3, facts["padded_field_bytes"], facts["coarse_field_bytes"])
-    assert reader.read(view) == pytest.approx(100 * (least / 819e9) / 100e-9)
+    least = scopes.signature(session.compiled_text("snapshot")).bytes
+    assert least == 3 * (36 * 68 + 16 * 32) * 4
+    assert _reader(copy, "snapshot_hbm_roofline_share").read(view) == (
+        pytest.approx(100 * (least / 819e9) / 300e-9))
     assert _reader(copy, "state_copy_bytes_per_call.sw").read(view) == 0.0
     assert _reader(copy, "op_surface_device_share.job").read(view) == (
-        pytest.approx(100 * 100 / 900))
+        pytest.approx(100 * 300 / 2700))
 
 
 def test_a_state_copied_in_every_call_is_counted(copy, job_session):
@@ -311,9 +340,9 @@ def test_the_real_cell_lists_its_readers_and_the_accepted_ones_that_read_true():
     benchmark = files.load_benchmark(ROOT)
     mine = {m["name"] for m in files.metrics_of(benchmark, "per_layer", "sw-job-1chip")}
     # `sw_device_ops_per_step` counts what the trace has of a cut snapshot
-    assert mine == set(NEW_READERS) | {
+    assert mine == set(NEW_READERS) | set(HOST_SPANS) | {
         "compile_s", "setup_after_chips_s", "device_idle_share.sw"}
-    for name in NEW_READERS:
+    for name in NEW_READERS + HOST_SPANS:
         assert hasattr(files.load_module("layer_metrics", name), "read")
     workload = files.load_json("workloads", "sw-job-1chip")
     cell = files.find_cell(benchmark, "sw-job-1chip")

@@ -18,7 +18,9 @@ from perfbench import run
 from perfbench.harness import files, scopes
 from perfbench.harness.trace import Event, Trace
 
-from perfbench_fixtures import ROOT, cell_args, make_copy
+from perfbench_fixtures import (
+    ROOT, a_step, cell_args, event_lines, make_copy, multistep_text,
+    program_text)
 
 CHIP = "/device:TPU:0"
 CELLS = ["sw-restart-toy-1x1", "sw-restart-toy-2x2"]
@@ -27,6 +29,12 @@ NEW_READERS = ["save_stall_share.sw", "save_commit_s",
                "checkpoint_stage_hbm_roofline_share", "resume_s"]
 ACCEPTED = ["device_idle_share.sw", "state_copy_bytes_per_call.sw",
             "sw_hbm_roofline_share.job", "op_surface_device_share.job"]
+# PR 38's readers of the job's spans, listed since PR 39: the first five
+# on both job cells, the save's two here alone
+HOST_SPANS = ["host_device_clock_bracket_us", "idle_in_sync_share.sw",
+              "idle_in_job_share.sw", "idle_unnamed_share.sw",
+              "job_issue_us_per_call.sw", "save_fetch_busy_share",
+              "save_write_busy_share"]
 STATE = ("h", "u", "v", "dh", "du", "dv")
 
 
@@ -205,28 +213,59 @@ def test_the_traced_window_holds_one_save_between_two_calls(session):
     assert len(executions) == 33
 
 
-def test_checkpoint_readers_on_a_hand_made_trace(copy, session):
+def test_checkpoint_readers_on_a_hand_made_trace(copy, session, capsys):
     step, halo, cut = _program_lines(session)
     call = [(step, 800), (halo, 100)]
     made = _trace([call, call, [(cut, 60), (cut, 40)], call, call])
     view = _view(session, made)
     assert _reader(copy, "checkpoint_device_share.sw").read(view) == (
         pytest.approx(100 * 100 / 3700))
-    reader = _reader(copy, "checkpoint_stage_hbm_roofline_share")
-    state_bytes = session.facts()["state_bytes"]
+    state_bytes = sum(a.nbytes for a in session.job.state)
     # on this backend the step is array code: interior-shaped tendencies
     assert state_bytes == 3 * (36 * 68 + 32 * 64) * 4
-    assert reader.least_bytes_per_save(state_bytes) == 2 * state_bytes
-    assert reader.read(view) == pytest.approx(
-        100 * (2 * state_bytes / 819e9) / 100e-9)
+    # the staging program as the job makes it: the state in, its pieces
+    # out, which is what its compiled text says
+    assert scopes.signature(session.compiled_text("stage")) == (
+        state_bytes, state_bytes)
+    assert _reader(copy, "checkpoint_stage_hbm_roofline_share").read(view) == (
+        pytest.approx(100 * (2 * state_bytes / 819e9) / 100e-9))
     # the accepted readers read the multistep's executions alone
     assert _reader(copy, "state_copy_bytes_per_call.sw").read(view) == 0.0
     assert _reader(copy, "op_surface_device_share.job").read(view) == (
         pytest.approx(100 * 100 / 900))
-    accepted = _reader(copy, "sw_hbm_roofline_share")
-    least = accepted.least_bytes_per_step(session.facts()["padded_field_bytes"])
+    # array code runs no kernel call: nothing says what a step's least is
+    capsys.readouterr()
+    assert _reader(copy, "sw_hbm_roofline_share.job").read(view) is None
+    assert "ran no kernel call" in capsys.readouterr().out
+
+
+def test_the_steps_floor_in_a_window_with_a_save_is_its_kernel_calls(
+        copy, session, capsys):
+    """The restarted job's programs as the TPU backend compiles them,
+    made by hand (the CPU runs no kernel call): the step's least bytes
+    are read from the multistep's executions alone, and a staging
+    program that hands back half the state has a floor of what it is
+    handed and what it hands back, not of twice the state."""
+    texts = {"multistep": multistep_text(36, 68),
+             "stage": program_text((36, 68), (18, 68), "mpi4jax_tpu.checkpoint/stage", 6)}
+    made_session = types.SimpleNamespace(
+        calls_at_setup=1, every=3, rows=session.rows,
+        ctx=session.ctx, compiled_text=texts.__getitem__)
+    made_session.traced_programs = (
+        lambda *a: type(session).traced_programs(made_session, *a))
+    call = a_step(event_lines(texts["multistep"]), kernel_ns=239) * 10
+    cut = [(event_lines(texts["stage"])[f"out.{i}"], 25) for i in range(6)]
+    view = _view(session, _trace([call, call, cut, call, call]))
+    view.session = made_session
+    field, slab = 36 * 68 * 4, 36 * 2 * 4
+    least = (12 * field + 6 * slab + 8 + 12) + 3 * 2 * 2 * slab + 2 * 8
     assert _reader(copy, "sw_hbm_roofline_share.job").read(view) == (
-        pytest.approx(100 * (least / 819e9) / (900e-9 / 10)))
+        pytest.approx(100 * (least / 819e9) / 270e-9))
+    assert _reader(copy, "checkpoint_stage_hbm_roofline_share").read(view) == (
+        pytest.approx(100 * (9 * field / 819e9) / 150e-9))
+    assert _reader(copy, "checkpoint_device_share.sw").read(view) == (
+        pytest.approx(100 * 150 / (4 * 2700 + 150)))
+    assert "do not belong together" not in capsys.readouterr().out
 
 
 def test_a_traced_window_without_its_one_save_reports_nothing(copy, session, capsys):
@@ -241,6 +280,7 @@ def test_a_traced_window_without_its_one_save_reports_nothing(copy, session, cap
     assert _reader(copy, "checkpoint_device_share.sw").read(view) is None
     assert "not one" in capsys.readouterr().out
     assert _reader(copy, "checkpoint_stage_hbm_roofline_share").read(view) is None
+    assert "ran no staging program" in capsys.readouterr().out
     # a trace of other programs is refused, never guessed at
     view = _view(session, _trace([call] * 3))
     assert _reader(copy, "checkpoint_device_share.sw").read(view) is None
@@ -275,9 +315,9 @@ def test_the_real_cell_lists_its_readers_and_the_accepted_ones_that_read_true():
     benchmark = files.load_benchmark(ROOT)
     mine = {m["name"] for m in
             files.metrics_of(benchmark, "per_layer", "sw-restart-1chip")}
-    assert mine == set(NEW_READERS) | set(ACCEPTED) | {
+    assert mine == set(NEW_READERS) | set(ACCEPTED) | set(HOST_SPANS) | {
         "compile_s", "setup_after_chips_s"}
-    for name in NEW_READERS:
+    for name in NEW_READERS + HOST_SPANS[5:]:
         assert hasattr(files.load_module("layer_metrics", name), "read")
         entry = next(m for m in benchmark["per_layer"] if m["name"] == name)
         assert entry["workloads"] == ["sw-restart-1chip"]
