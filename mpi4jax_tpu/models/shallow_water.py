@@ -545,6 +545,25 @@ def _runs_as_kernels(cfg, comm):
     )
 
 
+def _walks_two_steps(cfg, comm):
+    """Whether a walk of the step's kernel advances two time steps
+    (:func:`_step_wide`): where the step is the kernel, **the mesh is
+    one device**, and the second step's rings fit VMEM beside the
+    first's blocks.  On one device nothing a step needs is another
+    chip's: periodic in x, a row's ghost columns are the row's other
+    end, which the kernel sets itself between its two steps, and in y
+    both sides are walls.  With a neighbour on either axis the second
+    step's ghosts are that chip's second step's, and the walk stays one
+    step."""
+    ny_l, nx_l = cfg.local_interior(comm)
+    return (
+        _runs_as_kernels(cfg, comm)
+        and comm.mesh.devices.size == 1
+        and sw_kernels.tile_rows(
+            ny_l + 4, nx_l + 4, jnp.dtype(cfg.dtype), fields=6, steps=2) > 0
+    )
+
+
 def _kernels_ahead(cfg, comm):
     """Import Pallas before a step is traced, if the step will run the
     kernel (:func:`sw_kernels.pallas` says what that saves)."""
@@ -552,7 +571,7 @@ def _kernels_ahead(cfg, comm):
         sw_kernels.pallas()
 
 
-def _step_wide(state, cfg, comm, *, first_step=False, token=None):
+def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1):
     """Wide-halo (ghost=2) step: communicate prognostic fields only.
 
     The narrow schedule exchanges every intermediate field because a
@@ -582,9 +601,23 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
                              the interior and on ring 1 of u and v,
                              then round 2
 
-    3 exchanges and 12 passes over a field, and no ghost written outside
-    the kernel: ``halo_slabs_2d`` is the exchange without its last
-    phase, and the kernel, which reads and writes every tile that holds
+    3 exchanges and 12 passes over a field a walk of the kernel, and no
+    ghost written outside the kernel.  On a mesh of one device
+    (:func:`_walks_two_steps`) ``steps=2`` makes the one walk advance two
+    time steps: the same three ``halo_slabs_2d`` for the first, and for
+    the second nothing, because what its exchange would bring is the
+    block's own (periodic in x, a row's ghost columns are its other
+    end, which the kernel sets between the steps; walls in y, nothing
+    comes), so two steps are 12 passes and not 24 and the first step's
+    results never reach HBM.  With a neighbour on either axis the second
+    step's ghosts are another chip's and a walk is one step.  Either way
+    the state that comes back is the one single steps return, bit for
+    bit, ghosts and all.  There a first step is the same kernel with the
+    first of its two steps passed over (``wide_step(lone=True)``), so
+    that a process builds one kernel for ``make_first_step`` and
+    ``make_multistep``; only an odd count's last step is a walk of one.
+    ``halo_slabs_2d`` is the exchange without its last phase, and the
+    kernel, which reads and writes every tile that holds
     a ghost cell anyway, stores the received slabs over the rows it
     takes in.  Round 1 reads one ring round
     a cell and the exchange brings two, so the kernel computes on ring 1
@@ -630,12 +663,16 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None):
                     "carries them padded, as make_init and make_first_step "
                     "return them")
         iy, _ix = _device_coords(comm)
+        # where the rest of a run walks two steps at a time, its first
+        # step goes through that kernel too, the first of the two passed
+        # over: one kernel a process, traced once
+        lone = first_step and _walks_two_steps(cfg, comm)
         state = sw_kernels.wide_step(
             h, u, v, dh, du, dv, (for_h, for_u, for_v), is_south, is_north,
-            iy * ny_l, a, b,
+            iy * ny_l, a, b, lone,
             nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
             gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
-            coriolis_beta=cfg.coriolis_beta)
+            coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps)
         return SWState(*state), token
 
     # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
@@ -829,12 +866,22 @@ def make_multistep(cfg, comm, num_steps, *, donate=False):
     call — use it for ``state = multi(state)``-style driver loops.
     """
 
+    # steps a walk of the kernel (``_step_wide``): two on one device
+    stride = 2 if _walks_two_steps(cfg, comm) else 1
+
     def local_fn(state):
         def body(_, s):
-            s, _tok = shallow_water_step(s, cfg, comm)
+            if stride == 1:
+                s, _tok = shallow_water_step(s, cfg, comm)
+            else:
+                s, _tok = _step_wide(s, cfg, comm, steps=stride)
             return s
 
-        return lax.fori_loop(0, num_steps, body, state)
+        if num_steps >= stride:
+            state = lax.fori_loop(0, num_steps // stride, body, state)
+        if num_steps % stride:
+            state, _tok = shallow_water_step(state, cfg, comm)
+        return state
 
     _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)

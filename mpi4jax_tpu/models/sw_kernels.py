@@ -6,8 +6,10 @@ that copies the interior back into the padded field.  The kernel here
 (:func:`wide_step`) streams row tiles of the padded fields through VMEM
 instead: every intermediate stays on the chip, and each of the six state
 arrays (``h``, ``u``, ``v`` and their tendencies) is read once and
-written once, in place: 12 passes over a field, the least a step can
-move.
+written once, in place: 12 passes over a field a walk, the least a walk
+can move.  On a mesh of one device a walk advances **two time steps**
+("Two steps a walk", below): 12 passes where two walks move 24, under
+which the kernel is bound by its vector work and no longer by HBM.
 
 Schedule: three exchanges, not five
 -----------------------------------
@@ -95,11 +97,57 @@ are written two grid steps behind their input where the other four are
 written one behind; the grid is one step longer for it.  Without
 friction (``nu == 0``) the walk is the same with round 2 off.
 
+Two steps a walk, on one device
+------------------------------
+At 12 passes a step the kernel moved its bytes faster than a plain copy
+does on a v5e and its vector units waited a quarter of the time.  So
+where nothing a step needs is another chip's, a walk carries two steps
+(``wide_step(steps=2)``; ``shallow_water.make_multistep`` asks for it on
+a mesh of one device, ``_walks_two_steps``).  The four stages (round 1
+and round 2 of step *n*, round 1 and round 2 of step *n + 1*) run in the
+same pass of the same loop, **each a tile behind the one before it**, as
+round 2 runs behind round 1.  What step *n* produces goes to rings of
+strips in VMEM and never to HBM: ``h`` and the three new tendencies
+(step *n + 1*'s old ones) from its round 1, two tiles and a strip or two
+long because round 1 of step *n + 1* is two stages behind; the final
+``u``, ``v`` from its round 2, a tile and two strips.  Only step *n +
+1*'s six arrays are written, three and four grid steps behind their
+input; the grid is two steps longer.  The stages are ``first`` and
+``second`` as they are, applied twice: the same operations on the same
+values in the same order, so a walk of two steps returns bit for bit,
+ghosts and all, what two walks of one return.
+
+Why one device.  Step *n + 1* reads ghosts that an exchange would have
+brought after step *n*.  Periodic in x on one device, a row's ghost
+columns are the same row's other end: as a strip of ``h``, ``u`` or
+``v`` goes to its ring, columns ``width - 4, width - 3`` go to columns
+``0, 1`` and columns ``2, 3`` to ``width - 2, width - 1``, a lane
+rotation and a selection on the first and the last vector register of
+the strip (:func:`_ends_meet`), every row, as ``halo_slabs_2d`` slices
+them there.  In y one device has walls on both sides and the exchange
+brings nothing: those ghost rows keep their values.  Step *n* takes its
+slabs from the exchange exactly as a single walk does.  With a
+neighbour on either axis step *n + 1*'s ghosts are another chip's step
+*n*, two rings are not enough for two steps (round 1 on ring 1 reads
+ring 2), and the walk stays one step.  The returned state is a single
+walk's: ring 1 of ``du``, ``dv`` the step's own round 1 there, ring 1 of
+``u``, ``v`` round 1's values of the last step, the ghost columns of
+``h`` and ring 2 of ``u``, ``v`` what the exchange before the last step
+would have written (the other end as step *n* left it).
+
 Building a kernel is set-up a user waits for, so it is kept short:
 ``jax.experimental.pallas`` is imported by :func:`pallas` where a step is
 built for TPU devices (the array code, which every other backend runs,
-does not pay for it), a kernel's body is written in ``lax``, and the call
-is jitted, so that the programs of one process trace it once.
+does not pay for it), a kernel's body is written in ``lax``, the call
+is jitted, so that the programs of one process trace it once, and so
+are the two stages (:func:`_stages`), which are most of a body: the
+kernel of a single walk and both applications of a double one trace
+each once in a process.  A process on one device builds one kernel,
+the double walk's: a run's first step is that walk with its first
+step passed over by a scalar (``wide_step(lone=True)``), which hands
+the second zero tendencies, forward Euler's.  Only an odd count's last
+step builds the single walk's beside it.  (A kernel's trace is 0.15 to
+0.4 s on a chip's host: PERF.md, PR 41.)
 """
 
 import functools
@@ -117,7 +165,11 @@ STRIP = 8  # rows a kernel handles at once: float32's sublane tile
 LANES = 128  # columns of a vector register
 
 # of a v5e core's 128 MiB of VMEM: what a call's blocks and windows may
-# take, and the limit the compiler is given for them and its temporaries
+# take, and the limit the compiler is given for them and its temporaries.
+# A walk of two steps keeps its rings beside the same blocks, seven
+# buffers an array for five, and is given seven fifths of either, so
+# that its tiles are a single walk's: at 16 rows for 24 the cell's step
+# was 1 % slower (PERF.md, PR 41)
 _VMEM_BLOCK_BUDGET = 40 * 2**20
 _VMEM_LIMIT = 64 * 2**20
 
@@ -163,14 +215,25 @@ def _whole_registers(width):
     return -(-width // LANES) * LANES
 
 
-def tile_rows(rows, width, dtype, fields):
+def _buffers(steps):
+    """Tiles an array takes in VMEM: blocks in and out, both
+    double-buffered, and a window; two more for a second step."""
+    return 5 + 2 * (steps - 1)
+
+
+def tile_rows(rows, width, dtype, fields, steps=1):
     """Rows of a tile: the most (a multiple of ``STRIP``, at most the
     field's whole strips) for which the blocks of ``fields`` fields
     updated in place (double-buffered blocks in and out, and the
     window) fit the VMEM budget; 0 if not even one strip does, or the
-    field has none."""
+    field has none.  A walk of two ``steps`` keeps what the first hands
+    the second in rings beside them: two tiles more an array (a window
+    or a stage's ring for the fields, a ring two tiles long for what
+    waits a whole step's stages), seven where a single walk has five,
+    out of a budget that much larger."""
     row_bytes = _whole_registers(width) * jnp.dtype(dtype).itemsize
-    fit = _VMEM_BLOCK_BUDGET // (5 * fields * row_bytes)
+    buffers = _buffers(steps)
+    fit = _VMEM_BLOCK_BUDGET * buffers // 5 // (buffers * fields * row_bytes)
     return min(fit, rows) // STRIP * STRIP
 
 
@@ -181,7 +244,8 @@ add, sub, mul, div, eq, select = (
     lax.add, lax.sub, lax.mul, lax.div, lax.eq, lax.select)
 
 
-def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
+def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
+          interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
@@ -197,7 +261,8 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
     ghosts are the field's own.
 
     ``body(roll, *scalar_refs)`` runs once a grid step and returns the
-    two stages ``(first, second)``.  ``first(g, col, fields,
+    two stages ``(first, second)`` of each of the walk's steps, a list
+    of pairs.  ``first(g, col, fields,
     pointwise)`` is handed, for 8 rows: ``g`` and ``col``, each
     element's row and column in the block; for each field ``(c, n, s)``,
     the rows themselves and the rows north (``g + 1``) and south (``g -
@@ -212,6 +277,16 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
     ghost columns.  A stage
     masks what it updates itself: what it returns for a ghost cell is
     written too.
+
+    ``steps`` 2 (as many pointwise arrays as fields): two pairs of
+    stages run in the one walk, the second pair a step's stages of
+    tiles behind the first, on what the first returned: its fields and
+    its pointwise arrays go to rings of strips and not to HBM, the
+    fields with their ghost columns set to the row's other end (columns
+    ``width - 2 G`` on to ``0`` on, ``G`` on to ``width - G`` on), which
+    is what the next exchange brings a device that is alone on a
+    periodic axis; ghost rows stay what they are, as beyond a wall.
+    The second application's values are the call's results.
     """
     pl, pltpu = pallas()
     rows, width = fields[0].shape
@@ -219,12 +294,23 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
     dtype = fields[0].dtype
     n_scalars, n_fields, n_point = len(scalars), len(fields), len(pointwise)
     n_plain = n_fields - n_second
-    tile = tile_rows(rows, width, dtype, fields=n_fields + n_point)
+    rounds = 1 + (n_second > 0)  # stages a step
+    n_stages = steps * rounds
+    tile = tile_rows(rows, width, dtype, n_fields + n_point, steps)
     tiles = -(-rows // tile)
     strips = tile // STRIP
     # a ring of the second stage: first's strips of a tile, the one
     # before them and the one being written
     slots = strips + 2
+    # what a step hands the next: a ring for each field, as far behind
+    # the stage that reads it as the stage that writes it is ahead (a
+    # plain field's a whole step's stages, a second stage's one), and
+    # one for each pointwise array, which has no strips round it
+    field_slots = [lag * strips + 2
+                   for lag in [rounds] * n_plain + [1] * n_second]
+    point_slots = rounds * strips + 1
+    if steps > 1 and n_point != n_fields:
+        raise ValueError("a walk of two steps hands each field a pointwise array")
     axes = vma_of(fields[0]) or ()
     scalars = [promote_vma(x, axes) for x in scalars]
     # the slabs that came, field by field, each with what it is a slab
@@ -237,12 +323,14 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
 
     def kernel(*refs):
         refs = iter(refs)
-        scalar_refs, taken, *brought, old, out, new, windows, rings = (
+        (scalar_refs, taken, *brought, old, out, new, windows, rings,
+         held_fields, held_point, rings_again) = (
             tuple(itertools.islice(refs, n)) for n in
             (n_scalars, n_fields, *n_slabs, n_point, n_fields, n_point,
-             n_fields, n_second))
+             n_fields, n_second,
+             *((n_fields, n_point, n_second) if steps > 1 else (0, 0, 0))))
         i = pl.program_id(0)
-        first, second = body(pltpu.roll, *scalar_refs)
+        applied = body(pltpu.roll, *scalar_refs)
 
         shape = (STRIP, lanes)
         r = lax.broadcasted_iota(jnp.int32, shape, 0)
@@ -284,52 +372,116 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
             return pl.ds(pl.multiple_of(mul(k, STRIP), STRIP), STRIP)
 
         def around(ref, above, c, below):
-            """``(c, n, s)`` of strip ``c`` of ``ref``: the strips below
-            and above give the row that a rotation of the strip lacks."""
-            above, c, below = (ref[strip(k), :] for k in (above, c, below))
+            """``(c, n, s)`` of the rows ``c`` of ``ref``, a strip: the
+            strips below and above give the row that a rotation of the
+            strip lacks."""
+            above, c, below = (ref[part, :] for part in (above, c, below))
             n = pltpu.roll(select(top, below, c), STRIP - 1, 0)
             s = pltpu.roll(select(bottom, above, c), 1, 0)
             return c, n, s
 
-        def strips_through(run_first, run_second):
+        # the lanes of a vector register that hold ghost columns, for
+        # each stretch of them that `_ends_meet` fills from one place
+        lane = lax.broadcasted_iota(jnp.int32, (STRIP, LANES), 1)
+        ghosts = {
+            (lo, n): lax.bitwise_and(lax.ge(lane, lo), lax.lt(lane, lo + n))
+            for _, moves in (_ends_meet(width) if steps > 1 else ())
+            for _, _, lo, n in moves}
+
+        def of(value, start):
+            """The vector register of a strip whose columns start there."""
+            return lax.slice(value, (0, start), (STRIP, start + LANES))
+
+        def put(ref, rows, value):
+            ref[rows, :] = value
+
+        def hand_on(ring, rows, value):
+            """A field's strip as a step leaves it, into the ring the
+            next step reads: its ghost columns take the row's other end.
+            The strip as it is, then the vector registers that hold
+            ghost columns again, each once, the columns brought there by
+            a rotation of the register that holds them."""
+            ring[rows, :] = value
+            for home, moves in _ends_meet(width):
+                register = of(value, home)
+                for source, shift, lo, n in moves:
+                    register = select(
+                        ghosts[lo, n], pltpu.roll(of(value, source), shift, 1),
+                        register)
+                ring[rows, pl.ds(home, LANES)] = register
+
+        def strips_through(stages):
             def run(j, carry):
                 g = add(r, add(mul(sub(i, 1), tile), mul(j, STRIP)))
-                # first's strip, counted from the block's first: its slot
-                # in a ring and, a tile behind (`slots - 2` strips), the
-                # slots of second's strip and of the two round it
+                # first's strip, counted from the block's first: the rows
+                # of its slot in a ring and, as far behind as the ring
+                # is long less its two spare strips, of the slots of a
+                # later stage's strip and of the two round it
                 k = add(mul(sub(i, 1), strips), j)
-                behind = [lax.rem(add(k, d), slots) for d in range(4)]
-                if run_first:
-                    values = first(
-                        g, col,
-                        [around(win, j, add(j, 1), add(j, 2)) for win in windows],
-                        [ref[strip(j), :] for ref in old])
-                    homes = ([(ref, j) for ref in out[:n_plain]]
-                             + [(ring, behind[0]) for ring in rings]
-                             + [(ref, j) for ref in new])
-                    for (ref, at), value in zip(homes, values):
-                        ref[strip(at), :] = value
-                if run_second:
-                    values = second(
-                        sub(g, tile), col,
-                        [around(ring, *behind[1:]) for ring in rings])
-                    for ref, value in zip(out[n_plain:], values):
-                        ref[strip(j), :] = value
+                behind = functools.cache(
+                    lambda slots, d: strip(lax.rem(add(k, d), slots)))
+                here, north, beyond = strip(j), strip(add(j, 1)), strip(add(j, 2))
+                for stage in stages:
+                    step, is_second = divmod(stage, rounds)
+                    first, second = applied[step]
+                    last = step == steps - 1
+                    mine = sub(g, stage * tile) if stage else g
+                    second_rings = rings_again if step else rings
+                    if is_second:
+                        values = second(mine, col, [
+                            around(ring, *(behind(slots, d) for d in (1, 2, 3)))
+                            for ring in second_rings])
+                        if last:
+                            stores = [(put, ref, here) for ref in out[n_plain:]]
+                        else:
+                            stores = [(hand_on, ring, behind(n, 0)) for ring, n in zip(
+                                held_fields[n_plain:], field_slots[n_plain:])]
+                    else:
+                        if step:
+                            values = first(
+                                mine, col,
+                                [around(ring, *(behind(n, d) for d in (1, 2, 3)))
+                                 for ring, n in zip(held_fields, field_slots)],
+                                [ring[behind(point_slots, 1), :]
+                                 for ring in held_point])
+                        else:
+                            values = first(
+                                mine, col,
+                                [around(win, here, north, beyond)
+                                 for win in windows],
+                                [ref[here, :] for ref in old])
+                        if last:
+                            plain = [(put, ref, here) for ref in out[:n_plain]]
+                            point = [(put, ref, here) for ref in new]
+                        else:
+                            plain = [(hand_on, ring, behind(n, 0)) for ring, n in zip(
+                                held_fields[:n_plain], field_slots)]
+                            point = [(put, ring, behind(point_slots, 0))
+                                     for ring in held_point]
+                        stores = plain + [(put, ring, behind(slots, 0))
+                                          for ring in second_rings] + point
+                    for (store, ref, where), value in zip(stores, values):
+                        store(ref, where, value)
                 return carry
 
             lax.fori_loop(0, strips, run, 0)
 
-        # tile i - 1 goes through first while tile i - 2 goes through
-        # second (at i = 1 on the ring as it is found, into blocks that
-        # step 2 writes again); the walk's last step is second's alone
-        @pl.when(lax.bitwise_and(lax.gt(i, 0), lax.le(i, tiles)))
+        # stage q runs on tile i - 1 - q: tile i - 1 goes through first
+        # while tile i - 2 goes through second, and so on (at the walk's
+        # start on rings as they are found, into rings and blocks that a
+        # later grid step writes again; at its end the stages that have
+        # done the field's last tile run on, into rings nothing reads),
+        # until the last stage that writes blocks beside another has
+        # written the field's last; the walk's last step is second's alone
+        beside = n_stages - 1 - (n_second > 0)
+        @pl.when(lax.bitwise_and(lax.gt(i, 0), lax.le(i, tiles + beside)))
         def _():
-            strips_through(True, n_second > 0)
+            strips_through(range(n_stages))
 
         if n_second:
-            @pl.when(eq(i, tiles + 1))
+            @pl.when(eq(i, tiles + n_stages - 1))
             def _():
-                strips_through(False, True)
+                strips_through([n_stages - 1])
 
         for k, (ref, win) in enumerate(zip(taken, windows)):
             win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
@@ -347,26 +499,36 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, *, interpret):
     block_of_slab = (
         pl.BlockSpec((tile, G), lambda i: (lax.min(i, tiles - 1), 0)),
         pl.BlockSpec((G, lanes), lambda i: (0, 0)))
+
+    def ring(slots):
+        return pltpu.VMEM((slots * STRIP, lanes), dtype)
+
+    # the blocks of a stage are written as many grid steps behind their
+    # input as the stage is: the last step's first, the last stage
+    wrote_plain, wrote_last = n_stages - rounds + 1, n_stages
     results = pl.pallas_call(
         kernel,
-        grid=(tiles + 1 + (n_second > 0),),
+        grid=(tiles + n_stages,),
         in_specs=([in_smem] * n_scalars + [block(0)] * n_fields
                   + [block_of_slab[of_rows] for sides in came
                      for of_rows, *_ in sides]
                   + [block(1)] * n_point),
-        out_specs=([block(1)] * n_plain + [block(2)] * n_second
-                   + [block(1)] * n_point),
+        out_specs=([block(wrote_plain)] * n_plain + [block(wrote_last)] * n_second
+                   + [block(wrote_plain)] * n_point),
         out_shape=[struct] * (n_fields + n_point),
         scratch_shapes=(
             [pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_fields
-            + [pltpu.VMEM((slots * STRIP, lanes), dtype)] * n_second),
+            + [ring(slots)] * n_second
+            + ([ring(n) for n in field_slots] + [ring(point_slots)] * n_point
+               + [ring(slots)] * n_second if steps > 1 else [])),
         input_output_aliases={
             **{n_scalars + k: k for k in range(n_fields)},
             **{n_scalars + n_fields + len(arrived) + k: n_fields + k
                for k in range(n_point)}},
         compiler_params=pltpu.CompilerParams(
             # in order: a step reads the window the step before left
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT * _buffers(steps) // 5),
         interpret=interpret,
     )(*scalars, *fields, *arrived, *pointwise)
     return results
@@ -380,17 +542,163 @@ def _pieces(lo, n, every):
     return [(a, b - a) for a, b in zip(cuts, cuts[1:])]
 
 
-def _walls(is_south, is_north):
-    return jnp.stack([is_south, is_north]).astype(jnp.int32)
+def _ends_meet(width):
+    """What sets a row's ghost columns to its other end, as an exchange
+    along a periodic axis of one device does: for each vector register
+    that holds ghost columns, the column it starts at and its ``(source,
+    shift, lo, n)``: lanes ``[lo, lo + n)`` take the register that starts
+    at column ``source``, rotated by ``shift`` lanes."""
+    found = {}
+    for to, of in ((0, width - 2 * G), (width - G, G)):
+        for to, of in zip(range(to, to + G), range(of, of + G)):
+            home, source = to // LANES * LANES, of // LANES * LANES
+            moves = found.setdefault(home, [])
+            shift = (to - of) % LANES
+            if moves and moves[-1][:2] == (source, shift) and (
+                    moves[-1][2] + moves[-1][3] == to - home):
+                moves[-1] = (*moves[-1][:3], moves[-1][3] + 1)
+            else:
+                moves.append((source, shift, to - home, 1))
+    return list(found.items())
+
+
+@functools.lru_cache
+def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
+            coriolis_beta):
+    """The step's two stages for :func:`_walk`, ``(first, second)``, each
+    taking before its own arguments the ``scalars`` a kernel reads from
+    SMEM (:func:`wide_step` ``body``).  Jitted and kept: a process
+    traces each once, for the kernel of a single walk and both
+    applications of a double one (a body's two hundred operations are
+    most of a kernel's trace, 0.1 s of a program's set-up on a chip's
+    host); in a kernel's text they are inlined.  ``roll``: as
+    :func:`_walk` hands it to a body."""
+    lanes = _whole_registers(width)  # of a strip in the kernel
+    inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
+    cx, cy = nu / dx, nu / dy
+
+    def half(x):
+        return mul(x, 0.5)
+
+    def box(g, col, row_from, row_to, ring):
+        return functools.reduce(lax.bitwise_and, (
+            lax.ge(g, row_from), lax.lt(g, row_to),
+            lax.ge(col, G - ring), lax.lt(col, width - G + ring)))
+
+    def east(x):
+        return roll(x, lanes - 1, 1)
+
+    def west(x):
+        return roll(x, 1, 1)
+
+    def first(scalars, g, col, fields, old):
+        """Round 1: the tendencies and the Adams-Bashforth update,
+        of ``h`` on the interior, of ``u`` and ``v`` on ring 1 too."""
+        (a, b, first_row, south_ghost_row, north_wall_row,
+         inner_from, inner_to, reach_from, reach_to) = scalars
+        (h, h_n, h_s), (u, u_n, u_s), (v, v_n, v_s) = fields
+        zero = lax.full(h.shape, 0, dtype)
+        interior = box(g, col, inner_from, inner_to, 0)
+        reach = box(g, col, reach_from, reach_to, 1)
+        # the array code builds its ring-1 fields on every row and
+        # zeroes them on the walls' ghost rows (the northward flux
+        # on the northern wall's own row too); an interior row reads
+        # those rows only as the last row under a northern wall and
+        # as the first over a southern one
+        north = eq(g, north_wall_row)
+        south = eq(sub(g, 1), south_ghost_row)
+
+        def unless(wall, x):
+            return select(wall, zero, x)
+
+        # mean depths: twice the one on the eastern face, on this
+        # row and its neighbours; the array code's hc is h with the
+        # walls' ghost rows set to the wall's row, and of those the
+        # interior reads the northern one alone, in q's depth
+        h_e = east(h)
+        hx, hx_n, hx_s = add(h, h_e), add(h_n, east(h_n)), add(h_s, east(h_s))
+        # mass fluxes through the eastern and the northern face
+        fe = mul(half(hx), u)
+        fe_n = unless(north, mul(half(hx_n), u_n))
+        fn = unless(north, mul(half(add(h, h_n)), v))
+        fn_s = unless(south, mul(half(add(h_s, h)), v_s))
+
+        def vorticity(row, v, v_e, u_n, u, depth4):
+            """Potential vorticity at a row's north-eastern corners."""
+            y = mul(add(lax.convert_element_type(row, dtype), first_row), dy)
+            planetary = add(mul(y, coriolis_beta), coriolis_f)
+            relative = sub(mul(sub(v_e, v), inv_dx), mul(sub(u_n, u), inv_dy))
+            return div(add(planetary, relative), mul(depth4, 0.25))
+
+        q = vorticity(sub(g, G), v, east(v), u_n, u,
+                      add(hx, select(north, hx, hx_n)))
+        q_s = unless(south, vorticity(
+            sub(g, G + 1), v_s, east(v_s), u, u_s, add(hx_s, hx)))
+
+        # kinetic energy at the cell
+        uu, vv = mul(u, u), mul(v, v)
+        uu_n = mul(u_n, u_n)
+        ke = half(add(half(add(uu, west(uu))), half(add(vv, mul(v_s, v_s)))))
+        ke_n = unless(north, half(add(
+            half(add(uu_n, west(uu_n))), half(add(mul(v_n, v_n), vv)))))
+
+        fe_w = west(fe)
+        dh_new = sub(mul(sub(fe_w, fe), inv_dx), mul(sub(fn, fn_s), inv_dy))
+        du_new = sub(
+            add(mul(sub(h_e, h), -gravity * inv_dx),
+                half(add(mul(q, half(add(fn, east(fn)))),
+                         mul(q_s, half(add(fn_s, east(fn_s))))))),
+            mul(sub(east(ke), ke), inv_dx))
+        dv_new = sub(
+            sub(mul(sub(h_n, h), -gravity * inv_dy),
+                half(add(mul(q, half(add(fe, fe_n))),
+                         mul(west(q), half(add(fe_w, west(fe_n))))))),
+            mul(sub(ke_n, ke), inv_dy))
+
+        def stepped(where, x, new, old):
+            new = select(where, new, zero)
+            inc = select(where, mul(add(mul(new, a), mul(old, b)), dt), zero)
+            return add(x, inc), new
+
+        dh_old, du_old, dv_old = old
+        h, dh_new = stepped(interior, h, dh_new, dh_old)
+        u, du_new = stepped(reach, u, du_new, du_old)
+        v, dv_new = stepped(reach, v, dv_new, dv_old)
+        return h, u, unless(north, v), dh_new, du_new, dv_new
+
+    def second(scalars, g, col, fresh):
+        """Round 2: lateral friction of round 1's ``u`` and ``v``."""
+        _, _, _, south_ghost_row, north_wall_row, inner_from, inner_to, _, _ = scalars
+        zero = lax.full(g.shape, 0, dtype)
+        interior = box(g, col, inner_from, inner_to, 0)
+        # of the rows the array code zeroes in the y gradient, an
+        # interior cell reads one: the southern wall's ghost row
+        south_is_wall = eq(sub(g, 1), south_ghost_row)
+
+        def friction(c, n, s):
+            e, w = east(c), west(c)
+            # the gradients at the cell, and west and south of it
+            gx, gx_w = mul(sub(e, c), cx), mul(sub(c, w), cx)
+            gy = mul(sub(n, c), cy)
+            gy_s = select(south_is_wall, zero, mul(sub(c, s), cy))
+            inc = mul(add(mul(sub(gx, gx_w), inv_dx),
+                          mul(sub(gy, gy_s), inv_dy)), dt)
+            return add(c, select(interior, inc, zero))
+
+        u, v = fresh
+        return friction(*u), select(
+            eq(g, north_wall_row), zero, friction(*v))
+
+    return jax.jit(first), jax.jit(second)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
-                     "coriolis_beta", "interpret"))
+                     "coriolis_beta", "steps", "interpret"))
 def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
-              a, b, *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
-              interpret=False):
+              a, b, lone=False, *, nu, dx, dy, dt, gravity, coriolis_f,
+              coriolis_beta, steps=1, interpret=False):
     """A step of :func:`shallow_water._step_wide` after the wire of its
     first halo exchange, with no second one: the ghost writes of the
     first, the tendencies of ``h``, ``u`` and
@@ -417,141 +725,68 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     traced scalars: a first step is ``a = 1, b = 0`` on zero tendencies,
     exact in float32, so that a process's two programs trace one kernel,
     once.  The caller has checked :func:`tile_rows` for six fields.
+
+    ``steps`` 2: the call advances two time steps (both with ``a``,
+    ``b``) and returns, bit for bit on the whole padded blocks, what two
+    calls return with ``halo_slabs_2d`` between them **on a mesh of one
+    device**, which is the only place to ask for it: both walls this
+    device's (``is_south`` and ``is_north`` true), the x slabs the
+    block's own columns, no y slabs.  ``lone`` (traced, like the
+    walls): the first of the two steps is passed over, its stages
+    updating nothing and handing on zero tendencies, so that the call
+    returns what a call of one step returns from zero tendencies, which
+    is what a run's first step is: a process on one device then builds
+    one kernel for its first step and the rest (the walk takes a double
+    walk's time, once a run).  The caller has checked :func:`tile_rows`
+    for six fields and two steps.
     """
     rows, width = h.shape
-    lanes = _whole_registers(width)  # of a strip in the kernel
     dtype = h.dtype
-    inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
-    cx, cy = nu / dx, nu / dy
     floats = jnp.stack([jnp.asarray(x, dtype) for x in (a, b, first_row)])
 
-    def half(x):
-        return mul(x, 0.5)
-
-    def body(roll, wall_ref, float_ref):
+    def body(roll, flag_ref, float_ref):
+        first, second = _stages(
+            roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
+            coriolis_beta)
         a, b, first_row = float_ref[0], float_ref[1], float_ref[2]
-        # the rows the walls single out, -1 where this device has no
-        # wall: the southern wall's ghost row next to the interior, and
-        # the last interior row, which is the northern wall's
-        at_south, at_north = eq(wall_ref[0], 1), eq(wall_ref[1], 1)
-        absent = jnp.int32(-1)
-        south_ghost_row = select(at_south, jnp.int32(G - 1), absent)
-        north_wall_row = select(at_north, jnp.int32(rows - G - 1), absent)
-        # round 1's rows of u and v: the interior and ring 1, which is a
-        # neighbour's edge row unless a wall stands there.  (A block
-        # with a strip of 8 rows has four interior rows or more, so a
-        # neighbour's edge row is never its wall row too.)
-        reach_from = select(at_south, jnp.int32(G), jnp.int32(G - 1))
-        reach_to = select(at_north, jnp.int32(rows - G), jnp.int32(rows - G + 1))
+        at_south, at_north = eq(flag_ref[0], 1), eq(flag_ref[1], 1)
 
-        def box(g, col, row_from, row_to, ring):
-            return functools.reduce(lax.bitwise_and, (
-                lax.ge(g, row_from), lax.lt(g, row_to),
-                lax.ge(col, G - ring), lax.lt(col, width - G + ring)))
+        def scalars(live):
+            """What the stages of one step read.  ``live``: a flag, or
+            ``None`` for a step that always runs; where it is not set
+            the step updates nothing: no row is a wall's, and the
+            interior and round 1's rows are empty."""
+            def row(x, where=None, otherwise=-1):
+                """Row ``x`` as a scalar; ``otherwise`` (a row no strip
+                has) where ``where`` or ``live`` is given and not set."""
+                flags = [flag for flag in (where, live) if flag is not None]
+                if not flags:
+                    return jnp.int32(x)
+                return select(functools.reduce(lax.bitwise_and, flags),
+                              jnp.int32(x), jnp.int32(otherwise))
 
-        def east(x):
-            return roll(x, lanes - 1, 1)
+            # the rows the walls single out, -1 where this device has no
+            # wall: the southern wall's ghost row next to the interior,
+            # and the last interior row, which is the northern wall's
+            south_ghost_row = row(G - 1, at_south)
+            north_wall_row = row(rows - G - 1, at_north)
+            # the interior's rows, and round 1's rows of u and v: the
+            # interior and ring 1, which is a neighbour's edge row
+            # unless a wall stands there.  (A block with a strip of 8
+            # rows has four interior rows or more, so a neighbour's edge
+            # row is never its wall row too.)
+            inner_from, inner_to = row(G, otherwise=0), row(rows - G, otherwise=0)
+            reach_from = select(at_south, inner_from, row(G - 1, otherwise=0))
+            reach_to = select(at_north, inner_to, row(rows - G + 1, otherwise=0))
+            return (a, b, first_row, south_ghost_row, north_wall_row,
+                    inner_from, inner_to, reach_from, reach_to)
 
-        def west(x):
-            return roll(x, 1, 1)
+        # of a walk of two steps the first is passed over where `lone`
+        lives = [eq(flag_ref[2], 0)] * (steps - 1) + [None]
+        return [(functools.partial(first, x), functools.partial(second, x))
+                for x in map(scalars, lives)]
 
-        def first(g, col, fields, old):
-            """Round 1: the tendencies and the Adams-Bashforth update,
-            of ``h`` on the interior, of ``u`` and ``v`` on ring 1 too."""
-            (h, h_n, h_s), (u, u_n, u_s), (v, v_n, v_s) = fields
-            zero = lax.full(h.shape, 0, dtype)
-            interior = box(g, col, G, rows - G, 0)
-            reach = box(g, col, reach_from, reach_to, 1)
-            # the array code builds its ring-1 fields on every row and
-            # zeroes them on the walls' ghost rows (the northward flux
-            # on the northern wall's own row too); an interior row reads
-            # those rows only as the last row under a northern wall and
-            # as the first over a southern one
-            north = eq(g, north_wall_row)
-            south = eq(sub(g, 1), south_ghost_row)
-
-            def unless(wall, x):
-                return select(wall, zero, x)
-
-            # mean depths: twice the one on the eastern face, on this
-            # row and its neighbours; the array code's hc is h with the
-            # walls' ghost rows set to the wall's row, and of those the
-            # interior reads the northern one alone, in q's depth
-            h_e = east(h)
-            hx, hx_n, hx_s = add(h, h_e), add(h_n, east(h_n)), add(h_s, east(h_s))
-            # mass fluxes through the eastern and the northern face
-            fe = mul(half(hx), u)
-            fe_n = unless(north, mul(half(hx_n), u_n))
-            fn = unless(north, mul(half(add(h, h_n)), v))
-            fn_s = unless(south, mul(half(add(h_s, h)), v_s))
-
-            def vorticity(row, v, v_e, u_n, u, depth4):
-                """Potential vorticity at a row's north-eastern corners."""
-                y = mul(add(lax.convert_element_type(row, dtype), first_row), dy)
-                planetary = add(mul(y, coriolis_beta), coriolis_f)
-                relative = sub(mul(sub(v_e, v), inv_dx), mul(sub(u_n, u), inv_dy))
-                return div(add(planetary, relative), mul(depth4, 0.25))
-
-            q = vorticity(sub(g, G), v, east(v), u_n, u,
-                          add(hx, select(north, hx, hx_n)))
-            q_s = unless(south, vorticity(
-                sub(g, G + 1), v_s, east(v_s), u, u_s, add(hx_s, hx)))
-
-            # kinetic energy at the cell
-            uu, vv = mul(u, u), mul(v, v)
-            uu_n = mul(u_n, u_n)
-            ke = half(add(half(add(uu, west(uu))), half(add(vv, mul(v_s, v_s)))))
-            ke_n = unless(north, half(add(
-                half(add(uu_n, west(uu_n))), half(add(mul(v_n, v_n), vv)))))
-
-            fe_w = west(fe)
-            dh_new = sub(mul(sub(fe_w, fe), inv_dx), mul(sub(fn, fn_s), inv_dy))
-            du_new = sub(
-                add(mul(sub(h_e, h), -gravity * inv_dx),
-                    half(add(mul(q, half(add(fn, east(fn)))),
-                             mul(q_s, half(add(fn_s, east(fn_s))))))),
-                mul(sub(east(ke), ke), inv_dx))
-            dv_new = sub(
-                sub(mul(sub(h_n, h), -gravity * inv_dy),
-                    half(add(mul(q, half(add(fe, fe_n))),
-                             mul(west(q), half(add(fe_w, west(fe_n))))))),
-                mul(sub(ke_n, ke), inv_dy))
-
-            def stepped(where, x, new, old):
-                new = select(where, new, zero)
-                inc = select(where, mul(add(mul(new, a), mul(old, b)), dt), zero)
-                return add(x, inc), new
-
-            dh_old, du_old, dv_old = old
-            h, dh_new = stepped(interior, h, dh_new, dh_old)
-            u, du_new = stepped(reach, u, du_new, du_old)
-            v, dv_new = stepped(reach, v, dv_new, dv_old)
-            return h, u, unless(north, v), dh_new, du_new, dv_new
-
-        def second(g, col, fresh):
-            """Round 2: lateral friction of round 1's ``u`` and ``v``."""
-            zero = lax.full(g.shape, 0, dtype)
-            interior = box(g, col, G, rows - G, 0)
-            # of the rows the array code zeroes in the y gradient, an
-            # interior cell reads one: the southern wall's ghost row
-            south_is_wall = eq(sub(g, 1), south_ghost_row)
-
-            def friction(c, n, s):
-                e, w = east(c), west(c)
-                # the gradients at the cell, and west and south of it
-                gx, gx_w = mul(sub(e, c), cx), mul(sub(c, w), cx)
-                gy = mul(sub(n, c), cy)
-                gy_s = select(south_is_wall, zero, mul(sub(c, s), cy))
-                inc = mul(add(mul(sub(gx, gx_w), inv_dx),
-                              mul(sub(gy, gy_s), inv_dy)), dt)
-                return add(c, select(interior, inc, zero))
-
-            u, v = fresh
-            return friction(*u), select(
-                eq(g, north_wall_row), zero, friction(*v))
-
-        return first, second
-
-    return _walk(body, [_walls(is_south, is_north), floats], [h, u, v],
+    flags = [is_south, is_north] + [lone] * (steps == 2)
+    return _walk(body, [jnp.stack(flags).astype(jnp.int32), floats], [h, u, v],
                  slabs, [dh, du, dv], n_second=2 if nu > 0 else 0,
-                 interpret=interpret)
+                 steps=steps, interpret=interpret)

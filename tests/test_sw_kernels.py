@@ -54,16 +54,17 @@ UNIT = dict(dx=1.0, dy=0.8, gravity=1.0, depth=1.0, coriolis_f=1.0,
             coriolis_beta=4e-3, ghost=G)
 
 
-def _budget(monkeypatch, shape):
+def _budget(monkeypatch, shape, steps=1):
     """``SHAPES[shape]`` with its VMEM budget in place, scaled to the
-    call's six arrays so that the tiles are the name's."""
+    call's six arrays so that the tiles are the name's, of a walk of two
+    ``steps`` as of one."""
     rows, width, budget = SHAPES[shape]
     if budget is not None:
         # the budget is no argument of the jitted call: a trace under
         # another budget, of the same shapes, would be taken for this one's
         sw_kernels.wide_step.clear_cache()
         monkeypatch.setattr(sw_kernels, "_VMEM_BLOCK_BUDGET", budget * 3)
-        tile = sw_kernels.tile_rows(rows, width, jnp.float32, fields=6)
+        tile = sw_kernels.tile_rows(rows, width, jnp.float32, 6, steps)
         assert tile == int(shape.split("-")[2]) and rows > 3 * tile
     return rows, width
 
@@ -275,6 +276,79 @@ def test_the_kernel_reads_no_ghost_the_slabs_did_not_bring(
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
+@pytest.mark.parametrize("start", ["ab2", "euler"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_a_walk_of_two_steps_is_two_walks_of_one_bit_for_bit(
+        shape, start, nu, monkeypatch):
+    """On one device (walls on both sides, a row's ghost columns its own
+    other end) ``wide_step(steps=2)`` returns, bit for bit and on the
+    whole padded block of all six arrays, what two calls return with the
+    exchange between them that ``_step_wide`` makes there: the same
+    operations on the same values in the same order, the first step's
+    results never in HBM.  A pair in the middle of a run, and one that
+    starts from forward Euler's tendencies (what a run's second and
+    third steps read), with the Euler step itself both ways."""
+    rows, width = _budget(monkeypatch, shape, steps=2)
+    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    fields = [
+        mean + spread * jax.random.normal(key, (rows, width), jnp.float32)
+        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
+    inner = ~(_ring((rows, width), 1) | _ring((rows, width), 2))
+    old = [jnp.where(inner, 0.5 * jax.random.normal(key, (rows, width)), 0)
+           for key in keys[3:]]
+    wall = jnp.bool_(True)
+
+    def walk(state, steps, a=cfg.ab_a, b=cfg.ab_b, lone=False):
+        # what halo_slabs_2d hands the kernel on a mesh of one device:
+        # in x the block's own columns, in y nothing
+        slabs = tuple((x[:, -2 * G:-G], x[:, G:2 * G], None, None)
+                      for x in state[:3])
+        return sw_kernels.wide_step(
+            *state, slabs, wall, wall, 0, a, b, lone, steps=steps,
+            **_interpreted(cfg))
+
+    def one_by_one(state):
+        return walk(walk(state, 1), 1)
+
+    def at_once(state):
+        return walk(state, 2)
+
+    # unoptimised: the CPU backend contracts a product and a sum into one
+    # rounding in one program and not in another (a single walk's results
+    # differ in their last bit between two tilings of one block), and
+    # this compares programs, not roundings
+    plain = {"xla_backend_optimization_level": 0}
+    state = [*fields, *old]
+    if start == "euler":
+        # a run's first step, as a walk of one step and as `lone`, the
+        # walk of two with its first passed over, which is how a run on
+        # one device makes it: the same block, bit for bit
+        rest = [*fields, *(jnp.zeros_like(x) for x in old)]
+        state = jax.jit(
+            lambda rest: walk(rest, 1, 1.0, 0.0), compiler_options=plain)(rest)
+        alone = jax.jit(
+            lambda rest: walk(rest, 2, 1.0, 0.0, lone=True),
+            compiler_options=plain)(rest)
+        for name, x0, a, b in zip(sw.SWState._fields, rest, alone, state):
+            assert np.abs(np.asarray(b) - x0)[inner].max() > 0.001, name
+            np.testing.assert_array_equal(a, b, err_msg=name)
+    want = jax.jit(one_by_one, compiler_options=plain)(state)
+    got = jax.jit(at_once, compiler_options=plain)(state)
+    for name, x0, a, b in zip(sw.SWState._fields, state, got, want):
+        x0, a, b = np.asarray(x0), np.asarray(a), np.asarray(b)
+        assert np.isfinite(b).all(), name
+        # two steps did something everywhere they should
+        assert np.abs(b - x0)[inner].max() > 0.01, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    # the ghost columns are the row's other end as the first step left
+    # it: the second step's exchange, which nothing outside the kernel made
+    between = jax.jit(lambda state: walk(state, 1), compiler_options=plain)(state)
+    np.testing.assert_array_equal(
+        np.asarray(got[0])[:, :G], np.asarray(between[0])[:, -2 * G:-G])
+
+
 def _exchanges_a_step(multistep, state):
     """The halo exchanges in the traced one-step program, with their
     ghost writes or without."""
@@ -283,14 +357,17 @@ def _exchanges_a_step(multistep, state):
     return kinds.count("halo_exchange_2d") + kinds.count("halo_slabs_2d")
 
 
-@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 1), (1, 2), (2, 2)])
+@pytest.mark.parametrize("mesh_shape,num_steps", [
+    ((1, 1), 10), ((1, 1), 7), ((2, 1), 10), ((1, 2), 10), ((2, 2), 10)])
 def test_multistep_through_the_kernels_matches_the_array_path(
-        mesh_shape, monkeypatch):
+        mesh_shape, num_steps, monkeypatch):
     """``make_init``, ``make_first_step`` and ``make_multistep`` with
     the step forced through the kernel (interpreted) against the array
     path, after 1 + 10 steps: walls and the Coriolis parameter's rows on
     the right devices, ring 1 recomputed where the array path exchanges
-    a second time, the first step and the rest through one kernel."""
+    a second time, the first step and the rest through one kernel.  On
+    one device a walk of the kernel is two steps (the first step's with
+    one passed over), and an odd count's last step a walk of one."""
     mesh = jax.make_mesh(
         mesh_shape, ("y", "x"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -309,7 +386,7 @@ def test_multistep_through_the_kernels_matches_the_array_path(
         state = sw.make_init(cfg, comm)()
         state = sw.make_first_step(cfg, comm)(state)
         return jax.tree.map(
-            np.asarray, sw.make_multistep(cfg, comm, 10)(state))
+            np.asarray, sw.make_multistep(cfg, comm, num_steps)(state))
 
     def blocks(x):
         """A global array of padded blocks, a block at a time."""
@@ -323,11 +400,14 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     assert want.dh.shape == (40, 48)
     state = sw.make_init(cfg, comm)()
     assert _exchanges_a_step(sw.make_multistep(cfg, comm, 1), state) == 5
-    calls = []
+    calls, walks = [], []
     wide_step = sw_kernels.wide_step
+    # another case of this test traced these shapes: count this one's
+    wide_step.clear_cache()
 
     def interpreted(*args, **kwargs):
         calls.append(args[0].shape)
+        walks.append(kwargs["steps"])
         return wide_step(*args, **dict(kwargs, interpret=True))
 
     monkeypatch.setattr(sw_kernels, "wide_step", interpreted)
@@ -345,9 +425,15 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     # the step is built once in each of the two programs, on one
     # device's block; Pallas is asked for where each program is built,
     # and once more where the kernel is traced: the second program
-    # reuses the first's trace
-    assert calls == [block] * 2
-    assert len(imports) == 3
+    # reuses the first's trace.  On one device both are the kernel that
+    # walks two steps (the first step's with its first passed over),
+    # and only an odd count's last step is a walk of one, another
+    # kernel and another trace
+    alone = mesh_shape == (1, 1)
+    odd = alone and num_steps % 2
+    assert walks == [2, 2] + [1] * odd if alone else walks == [1, 1]
+    assert calls == [block] * len(walks)
+    assert len(imports) == 3 + odd
     # three exchanges a step where the array path has five
     state = sw.make_init(cfg, comm)()
     assert _exchanges_a_step(sw.make_multistep(cfg, comm, 1), state) == 3
@@ -477,10 +563,10 @@ def test_a_save_resumes_where_the_step_is_the_other_backends(
             assert np.abs(c - b).max() > 100 * tolerance, name
 
 
-def _comm_on(platform):
-    devices = np.array([[types.SimpleNamespace(platform=platform)]])
+def _comm_on(platform, mesh_shape=(1, 1)):
+    devices = np.full(mesh_shape, types.SimpleNamespace(platform=platform))
     return types.SimpleNamespace(
-        mesh=types.SimpleNamespace(devices=devices), axis_sizes=(1, 1))
+        mesh=types.SimpleNamespace(devices=devices), axis_sizes=mesh_shape)
 
 
 @pytest.mark.parametrize("platform,dtype,rows,width,expected", [
@@ -508,6 +594,27 @@ def test_the_step_picks_the_kernels_from_platform_dtype_and_shape(
     assert not sw._runs_as_kernels(replace(cfg, ghost=4), _comm_on(platform))
 
 
+@pytest.mark.parametrize("platform,mesh_shape,rows,width,expected", [
+    ("tpu", (1, 1), 7204, 14404, True),
+    ("tpu", (1, 1), 184, 364, True),
+    # a neighbour on either axis: the second step's ghosts are its
+    ("tpu", (2, 1), 7204, 14404, False),
+    ("tpu", (1, 2), 7204, 14404, False),
+    ("tpu", (2, 2), 1804, 3604, False),
+    ("cpu", (1, 1), 7204, 14404, False),   # no kernel, no walk
+    ("tpu", (1, 1), 7204, 40_000, True),   # one strip a tile
+    ("tpu", (1, 1), 7204, 100_000, False),  # not one
+], ids=lambda x: str(x))
+def test_a_walk_takes_two_steps_on_a_mesh_of_one_device_alone(
+        platform, mesh_shape, rows, width, expected):
+    py, px = mesh_shape
+    cfg = sw.SWConfig(ny=(rows - 2 * G) * py, nx=(width - 2 * G) * px, ghost=G)
+    comm = _comm_on(platform, mesh_shape)
+    assert sw._walks_two_steps(cfg, comm) is expected
+    assert sw._runs_as_kernels(cfg, comm) or not expected
+    assert not sw._walks_two_steps(replace(cfg, ghost=4), comm)
+
+
 def test_a_step_on_cpu_devices_is_the_array_code():
     mesh = jax.make_mesh(
         (1, 1), ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -520,21 +627,32 @@ def test_a_step_on_cpu_devices_is_the_array_code():
     assert "custom_call" not in text or "tpu_custom_call" not in text
 
 
-@pytest.mark.parametrize("rows,width,fields,expected", [
-    (7204, 14404, 2, 72),   # the benchmark's block: 40 MiB / (10 x 57856 B)
-    (1804, 3604, 2, 280),
-    (184, 364, 2, 184),     # the whole block when it fits
-    (52, 100, 2, 48),       # whole strips only
-    (7, 100, 2, 0),
-    (7204, 14404, 6, 24),   # round 1's six fields get shorter tiles
-    (1804, 3604, 6, 88),
-    (184, 364, 6, 184),
-    (52, 100, 6, 48),
-    (7204, 100_000, 6, 0),  # 8 rows x 30 blocks of 400 KB: over the budget
+@pytest.mark.parametrize("rows,width,fields,steps,expected", [
+    (7204, 14404, 2, 1, 72),   # the benchmark's block: 40 MiB / (10 x 57856 B)
+    (1804, 3604, 2, 1, 280),
+    (184, 364, 2, 1, 184),     # the whole block when it fits
+    (52, 100, 2, 1, 48),       # whole strips only
+    (7, 100, 2, 1, 0),
+    (7204, 14404, 6, 1, 24),   # round 1's six fields get shorter tiles
+    (1804, 3604, 6, 1, 88),
+    (184, 364, 6, 1, 184),
+    (52, 100, 6, 1, 48),
+    (7204, 100_000, 6, 1, 0),  # 8 rows x 30 blocks of 400 KB: over the budget
+    # a walk of two steps keeps two tiles more an array in rings, out
+    # of 56 MiB: 42 x 57856 B a row, and the tiles are a single walk's
+    (7204, 14404, 6, 2, 24),
+    (1804, 3604, 6, 2, 88),
+    (184, 364, 6, 2, 184),
+    (52, 100, 6, 2, 48),
+    (7204, 40_000, 6, 1, 8),
+    (7204, 40_000, 6, 2, 8),
+    (7204, 100_000, 6, 2, 0),
 ])
-def test_tile_rows(rows, width, fields, expected):
-    tile = sw_kernels.tile_rows(rows, width, jnp.float32, fields)
+def test_tile_rows(rows, width, fields, steps, expected):
+    tile = sw_kernels.tile_rows(rows, width, jnp.float32, fields, steps)
     assert tile == expected and tile % sw_kernels.STRIP == 0
+    if steps == 1:
+        assert tile == sw_kernels.tile_rows(rows, width, jnp.float32, fields)
 
 
 def _fresh_interpreter(code):

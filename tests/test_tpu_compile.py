@@ -96,6 +96,23 @@ def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
         state).compile()
 
 
+def _kernel_calls(text):
+    """Every Pallas call of a compiled program's text, in the text's
+    order, as :func:`_kernels` describes one."""
+    return [_kernels(line)["wide_step"] for line in text.splitlines()
+            if "tpu_custom_call" in line and "%wide_step" in line.split("=")[0]]
+
+
+def _trips(text):
+    """How often a program's loop runs: the constant its condition
+    compares the counter with."""
+    condition = re.search(r"\bwhile\(.*?condition=%([\w.\-]+)", text)[1]
+    lines = re.search(
+        rf"^%{re.escape(condition)} \(.*?^\}}", text, re.S | re.M)[0]
+    bound, = re.findall(r"s32\[\]\S* constant\((\d+)\)", lines)
+    return int(bound)
+
+
 def _kernels(text):
     """The Pallas calls of a compiled program's text: name -> (line,
     the operands that are fields, the kernel's own text, the fields'
@@ -141,9 +158,17 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     # through a second operand); the other two schedules are array code
     kernels = _kernels(text)
     assert sorted(kernels) == (["wide_step"] if ghost == 2 else [])
-    assert text.count("tpu_custom_call") == (ghost == 2)  # one a step
+    # one a step; on one chip a walk of the kernel is two steps, and the
+    # 25 are twelve walks of two in the loop and one of one after it
+    alone = py * px == 1
+    calls = _kernel_calls(text)
+    assert len(calls) == text.count("tpu_custom_call") == (
+        (ghost == 2) * (1 + alone))
     if kernels:
-        line, fields, _, places = kernels["wide_step"]
+        assert _trips(text) == (12 if alone else 25)
+        # the walk of one step is another kernel than the walk of two
+        assert len({body for _, _, body, _ in calls}) == len(calls)
+    for line, fields, _, places in calls:
         aliasing = ", ".join(
             f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
         assert f"output_to_operand_aliasing={{{aliasing}}}" in line
@@ -158,7 +183,9 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
 def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     """Forward Euler is the AB2 kernel with other scalars, so a process
-    traces and lowers the step's kernel once for its two programs."""
+    traces and lowers the step's kernel once for its two programs.  On
+    one chip that kernel walks two steps, and the first step's call
+    passes the first of them over by a scalar."""
     texts = [_compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, steps).as_text()
              for steps in (0, 10)]
     first, rest = (_kernels(text) for text in texts)
@@ -195,8 +222,10 @@ def _step_body(text):
 
 # what the step's loop body holds that moves or computes something.  One
 # chip: the loop's counter, three fusions of two slices (a field's sent
-# columns), a scalar's broadcast and the kernel
-STEP_INSTRUCTIONS = {(1, 1): 6, (2, 2): 99}
+# columns) and the kernel, which walks two steps (the broadcast that
+# built the kernel's two wall flags in the loop went when `lone` joined
+# them: on one chip the three are a constant)
+STEP_INSTRUCTIONS = {(1, 1): 5, (2, 2): 99}
 
 
 @pytest.mark.parametrize("mesh_shape", sorted(STEP_INSTRUCTIONS))
@@ -223,7 +252,9 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
     assert len(transposes) == (0 if chips == 1 else 3)
     assert all(types[name].startswith("f32[1804,3604]{0,1") for name in transposes)
     calls = [line for *_, line in body if "tpu_custom_call" in line]
-    assert len(calls) == 1  # one a step
+    # one a step; on one chip one for two steps, five times for ten
+    assert len(calls) == 1
+    assert _trips(text) == (5 if chips == 1 else 10)
     (line, fields, _, places), = _kernels(text).values()
     assert line == calls[0]
     # the state's arrays stand round the slabs: two a field on one chip
