@@ -19,6 +19,16 @@ Usage:
 
     # explicit decomposition (devices = py * px)
     python examples/shallow_water.py --mesh 2 4
+
+Every mode but --benchmark builds `SWConfig()` with its default
+`ghost=1`: upstream's layout, (ny+2, nx+2) arrays a device, and
+upstream's step as written, array code with one halo exchange after
+each of twelve fields a step.  --benchmark takes `bench_size()`'s
+`ghost=2`, which on a TPU in float32 is one Pallas kernel and three
+exchanges a step.  On one v5e chip at 14400x7200 cells the two read
+1 065 and 18 352 Mcell/s (PERF.md, PR 42: the cells
+`sw-as-written-1chip` and `sw-bench-1chip`): for a run that is timed,
+pass `ghost=2`.
 """
 
 import argparse
@@ -33,7 +43,14 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--benchmark", action="store_true")
+    p.add_argument(
+        "--benchmark",
+        action="store_true",
+        help="the published benchmark's grid at ghost=2 (on a TPU the "
+        "step's kernel); every other mode runs SWConfig()'s ghost=1, "
+        "upstream's step as written: twelve exchanges a step, some 17 "
+        "times slower on a v5e chip (PERF.md, PR 42)",
+    )
     p.add_argument("--check", action="store_true")
     p.add_argument("--mesh", nargs=2, type=int, metavar=("PY", "PX"))
     p.add_argument("--days", type=float, default=None, help="model days")

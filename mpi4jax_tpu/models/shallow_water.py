@@ -64,6 +64,7 @@ __all__ = [
     "initial_state",
     "shallow_water_step",
     "make_multistep",
+    "make_state",
     "Snapshot",
     "Checkpoint",
     "SolverJob",
@@ -92,12 +93,18 @@ class SWConfig:
     ab_a: float = 1.6  # Adams–Bashforth coefficients (reference :126-127)
     ab_b: float = -0.6
     dtype: str = "float32"
-    # Ghost-ring width. 1 = the reference's layout (~12 exchanges/step,
-    # shallow_water.py:277-412 there). 2 = wide-halo schedule: all
+    # Ghost-ring width. 1 = the reference's layout and its step as
+    # written (shallow_water.py:277-412 there): array code on every
+    # backend, one exchange after each of 12 fields a step, the state
+    # upstream's (ny+2, nx+2) arrays; what a caller's own stencil with
+    # the library's exchange looks like, and on a TPU some 17 times
+    # slower than ghost=2 (PERF.md, PR 42). 2 = wide-halo schedule: all
     # intermediate fields (fluxes, vorticity, kinetic energy, viscosity
     # gradients) are recomputed locally inside the ghost region, so a
-    # step needs only 2 exchange rounds of the prognostic fields (5
-    # exchanges). 4 = single-exchange schedule: one batched exchange of
+    # step needs only 2 exchange rounds of the prognostic fields: 5
+    # exchanges as array code and, on TPU devices in float32, 3
+    # halo_slabs_2d and one Pallas kernel call (_step_wide; PR 31).
+    # 4 = single-exchange schedule: one batched exchange of
     # (h, u, v) per step; the post-update viscosity operates on locally
     # recomputed ring-2 values and tendencies are never communicated
     # (they stay valid on ring-2 inductively). Identical numerics for
@@ -207,7 +214,6 @@ def initial_state(cfg, comm, *, token=None):
     h_geo = jnp.pad(local_cum + offset[None, :], ((G, G), (0, 0)), mode="edge")
 
     # centre around the mean depth: global mean via allreduce
-    ny_l, nx_l = cfg.local_interior(comm)
     local_sum = h_geo[G:-G, G:-G].sum()
     total, token = allreduce(local_sum, reductions.SUM, comm=comm, token=token)
     n_cells = float(cfg.ny * cfg.nx)
@@ -221,7 +227,17 @@ def initial_state(cfg, comm, *, token=None):
         * jnp.sin(xx / lx * 10.0 * jnp.pi)
         * jnp.cos(yy / ly * 8.0 * jnp.pi)
     ).astype(cfg.dtype)
+    return _ghosted_state(h0, u0, v0, cfg, comm, token)
 
+
+def _ghosted_state(h0, u0, v0, cfg, comm, token):
+    """A device's padded ``h0``, ``u0``, ``v0`` as the state a step
+    takes: ghosts filled by the exchange (a wall's keep what they
+    hold), zero tendencies in the form the schedule carries.  The one
+    place that knows that form, for :func:`initial_state`'s jet and for
+    a caller's own fields (:func:`make_state`)."""
+    G = cfg.ghost
+    ny_l, nx_l = cfg.local_interior(comm)
     per = (False, cfg.periodic_x)
     h0, token = halo_exchange_2d(h0, comm, periodic=per, token=token, width=G)
     u0, token = halo_exchange_2d(
@@ -272,14 +288,37 @@ def _set_interior(a, val):
     return a.at[1:-1, 1:-1].set(val)
 
 
+# The as-written step's phases and the fields it exchanges, as
+# jax.named_scope segments ``sw/<phase>`` and ``sw/exchange.<field>``
+# (the latter round the exchange's own ``mpi4jax_tpu.halo_exchange_2d``
+# scope and its ``pack``, ``wire``, ``unpack``): every instruction of
+# the ``ghost=1`` step carries one or the other in its ``op_name``, so a
+# device profile splits a step by phase and by exchange.  Metadata only.
+# None starts with SCOPE_PREFIX: that marks a communication op, for the
+# contract analyzer (analysis/jaxpr_walk.py) and for whoever gives
+# device time to the op surface, and a phase's array code is neither.
+STEP_SCOPE = "sw"
+STEP_PHASES = ("height", "mass_flux", "vorticity", "tendencies", "kinetic",
+               "ab2", "friction")
+STEP_EXCHANGES = ("hc", "fe", "fn", "q", "ke", "h", "u", "v",
+                  "gx_u", "gy_u", "gx_v", "gy_v")
+
+
+def _phase(name):
+    return jax.named_scope(f"{STEP_SCOPE}/{name}")
+
+
 def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
     """One model step (reference: shallow_water.py:277-412, same scheme).
 
-    ``cfg.ghost == 1``: the reference's schedule, ~12 halo exchanges per
-    step.  ``cfg.ghost == 2``: wide-halo schedule, 5 exchanges per step
-    (see :func:`_step_wide`).  ``cfg.ghost == 4``: single-exchange
-    schedule, one batched exchange per step (see :func:`_step_wide4`).
-    All numerically identical.
+    ``cfg.ghost == 1``: the reference's schedule as written, array code
+    with one ``halo_exchange_2d`` after each of twelve fields
+    (``STEP_EXCHANGES``), on every backend.  ``cfg.ghost == 2``:
+    wide-halo schedule, 5 exchanges per step as array code and, where
+    the step runs as the kernel (TPU devices, float32), 3
+    ``halo_slabs_2d`` and one kernel call (see :func:`_step_wide`).
+    ``cfg.ghost == 4``: single-exchange schedule, one batched exchange
+    per step (see :func:`_step_wide4`).  All numerically identical.
     """
     if cfg.ghost == 2:
         return _step_wide(state, cfg, comm, first_step=first_step, token=token)
@@ -289,106 +328,124 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
         raise ValueError(f"ghost width must be 1, 2 or 4, got {cfg.ghost}")
     token = as_token(token)
     per = (False, cfg.periodic_x)
-    exchange = partial(halo_exchange_2d, comm=comm, periodic=per)
     is_north, _is_south = _wall_masks(comm)
     dx, dy, g = cfg.dx, cfg.dy, cfg.gravity
 
     h, u, v, dh, du, dv = state
+
+    def exchange(a, name, token):
+        with jax.named_scope(f"{STEP_SCOPE}/exchange.{name}"):
+            return halo_exchange_2d(a, comm=comm, periodic=per, token=token)
 
     def wall_v(a):
         """v = 0 on the northern wall row (reference :401-402)."""
         return jnp.where(is_north, a.at[-2, :].set(0.0), a)
 
     # cell-centred height with edge-padded ghosts, then exchanged
-    hc = jnp.pad(h[1:-1, 1:-1], 1, mode="edge")
-    hc, token = exchange(hc, token=token)
+    with _phase("height"):
+        hc = jnp.pad(h[1:-1, 1:-1], 1, mode="edge")
+    hc, token = exchange(hc, "hc", token)
 
     # mass fluxes on cell faces
-    fe = _set_interior(jnp.zeros_like(u), 0.5 * (_i(hc) + _e(hc)) * _i(u))
-    fn = _set_interior(jnp.zeros_like(v), 0.5 * (_i(hc) + _n(hc)) * _i(v))
-    fe, token = exchange(fe, token=token)
-    fn, token = exchange(fn, token=token)
-    fn = wall_v(fn)
+    with _phase("mass_flux"):
+        fe = _set_interior(jnp.zeros_like(u), 0.5 * (_i(hc) + _e(hc)) * _i(u))
+        fn = _set_interior(jnp.zeros_like(v), 0.5 * (_i(hc) + _n(hc)) * _i(v))
+    fe, token = exchange(fe, "fe", token)
+    fn, token = exchange(fn, "fn", token)
+    with _phase("mass_flux"):
+        fn = wall_v(fn)
 
-    dh_new = _set_interior(
-        dh, -(_i(fe) - _w(fe)) / dx - (_i(fn) - _s(fn)) / dy
-    )
+    with _phase("tendencies"):
+        dh_new = _set_interior(
+            dh, -(_i(fe) - _w(fe)) / dx - (_i(fn) - _s(fn)) / dy
+        )
 
     # potential vorticity (planetary + relative, over face-mean depth)
-    yy, _xx = _local_mesh_coords(cfg, comm)
-    rel_vort = (_e(v) - _i(v)) / dx - (_n(u) - _i(u)) / dy
-    q_int = (_coriolis(cfg, yy)[1:-1, 1:-1] + rel_vort) / (
-        0.25 * (_i(hc) + _e(hc) + _n(hc) + _ne(hc))
-    )
-    q = _set_interior(jnp.zeros_like(h), q_int)
-    q, token = exchange(q, token=token)
+    with _phase("vorticity"):
+        yy, _xx = _local_mesh_coords(cfg, comm)
+        rel_vort = (_e(v) - _i(v)) / dx - (_n(u) - _i(u)) / dy
+        q_int = (_coriolis(cfg, yy)[1:-1, 1:-1] + rel_vort) / (
+            0.25 * (_i(hc) + _e(hc) + _n(hc) + _ne(hc))
+        )
+        q = _set_interior(jnp.zeros_like(h), q_int)
+    q, token = exchange(q, "q", token)
 
     # momentum tendencies: pressure gradient + PV flux (Sadourny 1975)
-    du_new = _set_interior(
-        du,
-        -g * (_e(h) - _i(h)) / dx
-        + 0.5
-        * (
-            _i(q) * 0.5 * (_i(fn) + _e(fn))
-            + _s(q) * 0.5 * (_s(fn) + fn[:-2, 2:])
-        ),
-    )
-    dv_new = _set_interior(
-        dv,
-        -g * (_n(h) - _i(h)) / dy
-        - 0.5
-        * (
-            _i(q) * 0.5 * (_i(fe) + _n(fe))
-            + _w(q) * 0.5 * (_w(fe) + fe[2:, :-2])
-        ),
-    )
+    with _phase("tendencies"):
+        du_new = _set_interior(
+            du,
+            -g * (_e(h) - _i(h)) / dx
+            + 0.5
+            * (
+                _i(q) * 0.5 * (_i(fn) + _e(fn))
+                + _s(q) * 0.5 * (_s(fn) + fn[:-2, 2:])
+            ),
+        )
+        dv_new = _set_interior(
+            dv,
+            -g * (_n(h) - _i(h)) / dy
+            - 0.5
+            * (
+                _i(q) * 0.5 * (_i(fe) + _n(fe))
+                + _w(q) * 0.5 * (_w(fe) + fe[2:, :-2])
+            ),
+        )
 
     # kinetic energy gradient
-    ke = _set_interior(
-        jnp.zeros_like(h),
-        0.5 * (0.5 * (_i(u) ** 2 + _w(u) ** 2) + 0.5 * (_i(v) ** 2 + _s(v) ** 2)),
-    )
-    ke, token = exchange(ke, token=token)
-    du_new = du_new.at[1:-1, 1:-1].add(-(_e(ke) - _i(ke)) / dx)
-    dv_new = dv_new.at[1:-1, 1:-1].add(-(_n(ke) - _i(ke)) / dy)
+    with _phase("kinetic"):
+        ke = _set_interior(
+            jnp.zeros_like(h),
+            0.5 * (0.5 * (_i(u) ** 2 + _w(u) ** 2)
+                   + 0.5 * (_i(v) ** 2 + _s(v) ** 2)),
+        )
+    ke, token = exchange(ke, "ke", token)
+    with _phase("kinetic"):
+        du_new = du_new.at[1:-1, 1:-1].add(-(_e(ke) - _i(ke)) / dx)
+        dv_new = dv_new.at[1:-1, 1:-1].add(-(_n(ke) - _i(ke)) / dy)
 
     # time step: forward Euler bootstrap, then AB2 (reference :345-371)
     dt = jnp.asarray(cfg.dt, h.dtype)
-    if first_step:
-        u = u.at[1:-1, 1:-1].add(dt * _i(du_new))
-        v = v.at[1:-1, 1:-1].add(dt * _i(dv_new))
-        h = h.at[1:-1, 1:-1].add(dt * _i(dh_new))
-    else:
-        a, b = cfg.ab_a, cfg.ab_b
-        u = u.at[1:-1, 1:-1].add(dt * (a * _i(du_new) + b * _i(du)))
-        v = v.at[1:-1, 1:-1].add(dt * (a * _i(dv_new) + b * _i(dv)))
-        h = h.at[1:-1, 1:-1].add(dt * (a * _i(dh_new) + b * _i(dh)))
+    with _phase("ab2"):
+        if first_step:
+            u = u.at[1:-1, 1:-1].add(dt * _i(du_new))
+            v = v.at[1:-1, 1:-1].add(dt * _i(dv_new))
+            h = h.at[1:-1, 1:-1].add(dt * _i(dh_new))
+        else:
+            a, b = cfg.ab_a, cfg.ab_b
+            u = u.at[1:-1, 1:-1].add(dt * (a * _i(du_new) + b * _i(du)))
+            v = v.at[1:-1, 1:-1].add(dt * (a * _i(dv_new) + b * _i(dv)))
+            h = h.at[1:-1, 1:-1].add(dt * (a * _i(dh_new) + b * _i(dh)))
 
-    h, token = exchange(h, token=token)
-    u, token = exchange(u, token=token)
-    v, token = exchange(v, token=token)
-    v = wall_v(v)
+    h, token = exchange(h, "h", token)
+    u, token = exchange(u, "u", token)
+    v, token = exchange(v, "v", token)
+    with _phase("ab2"):
+        v = wall_v(v)
 
     # lateral friction (the reference's v-branch reads u in two stencils,
     # shallow_water.py:395-400 — reproduced here as v for correct physics;
-    # flop/communication profile is identical)
+    # flop/communication profile is identical).  As in the reference, no
+    # exchange follows it: the state's ghosts of u and v are the exchange's
+    # above, of the fields before friction
     nu = cfg.lateral_viscosity
     if nu > 0:
-        gx = _set_interior(jnp.zeros_like(u), nu * (_e(u) - _i(u)) / dx)
-        gy = _set_interior(jnp.zeros_like(u), nu * (_n(u) - _i(u)) / dy)
-        gx, token = exchange(gx, token=token)
-        gy, token = exchange(gy, token=token)
-        u = u.at[1:-1, 1:-1].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
-        gx = _set_interior(jnp.zeros_like(v), nu * (_e(v) - _i(v)) / dx)
-        gy = _set_interior(jnp.zeros_like(v), nu * (_n(v) - _i(v)) / dy)
-        gx, token = exchange(gx, token=token)
-        gy, token = exchange(gy, token=token)
-        v = v.at[1:-1, 1:-1].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
-        v = wall_v(v)
+
+        def friction(w, name, token):
+            with _phase("friction"):
+                gx = _set_interior(jnp.zeros_like(w), nu * (_e(w) - _i(w)) / dx)
+                gy = _set_interior(jnp.zeros_like(w), nu * (_n(w) - _i(w)) / dy)
+            gx, token = exchange(gx, f"gx_{name}", token)
+            gy, token = exchange(gy, f"gy_{name}", token)
+            with _phase("friction"):
+                w = w.at[1:-1, 1:-1].add(
+                    dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
+                )
+            return w, token
+
+        u, token = friction(u, "u", token)
+        v, token = friction(v, "v", token)
+        with _phase("friction"):
+            v = wall_v(v)
 
     return SWState(h, u, v, dh_new, du_new, dv_new), token
 
@@ -903,6 +960,47 @@ def make_init(cfg, comm):
     specs = _mesh_specs(comm)
     return jax.jit(
         jax.shard_map(local_fn, mesh=comm.mesh, in_specs=(), out_specs=specs)
+    )
+
+
+def make_state(cfg, comm):
+    """Jitted global function ``(h, u, v) -> SWState``: a caller's own
+    fields as the state :func:`make_first_step` takes, where
+    :func:`make_init` builds the demo's jet.
+
+    ``h``, ``u``, ``v`` are the domain's interior cells, ``(cfg.ny,
+    cfg.nx)`` each, sharded over the mesh or not.  Each device pads its
+    block with ``cfg.ghost`` cells, a wall's ghost rows holding its edge
+    row, fills the rest of the ring by the library's own exchange, and
+    adds zero tendencies in the form the schedule carries: at the
+    fields' padded shape for ``ghost`` 1 and 4 and where the ``ghost`` 2
+    step runs as the kernel, at the interior's where it runs as array
+    code.  A caller need not
+    know which: :meth:`SolverJob.form` says, and ``make_init`` and this
+    share the code that decides.  The state comes back in the layout
+    the steps return: at ``ghost`` 1 upstream's arrays, ``(ny + 2,
+    nx + 2)`` a device.
+    """
+
+    interior = cfg.local_interior(comm)
+
+    def local_fn(h, u, v):
+        G = cfg.ghost
+        for a in (h, u, v):
+            if a.shape != interior:
+                raise ValueError(
+                    f"a field of {a.shape} a device: the interior of a "
+                    f"{cfg.ny}x{cfg.nx} grid on a mesh of {comm.axis_sizes} "
+                    f"is {interior} a device, without ghost cells")
+        padded = (jnp.pad(a.astype(cfg.dtype), G, mode="edge")
+                  for a in (h, u, v))
+        state, _tok = _ghosted_state(*padded, cfg, comm, as_token(None))
+        return state
+
+    spec = jax.P(*comm.axes)
+    return jax.jit(
+        jax.shard_map(local_fn, mesh=comm.mesh, in_specs=(spec,) * 3,
+                      out_specs=_mesh_specs(comm))
     )
 
 

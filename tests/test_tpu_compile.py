@@ -383,6 +383,84 @@ def test_no_write_of_the_exchange_is_two_columns_wide(v5e, form):
         "f32[1804,128]", "f32[1804,20]", "f32[2,3604]", "f32[2,3604]"]
 
 
+# what one trip of the as-written step's loop holds at a field's size
+# (7202 x 14402 padded, 7200 x 14400 interior), by opcode, on this tree:
+# the passes over a field that the next PR's diff should show it removed.
+# A fusion "in place" writes the exchange's lane-tile strips into fields
+# where they lie (up to four fields a fusion) and moves no field.
+AS_WRITTEN_TRIP = {
+    "fusion": 10, "fusion in place": 10, "pad": 8, "copy": 6,
+    "concatenate": 2, "dynamic-update-slice": 13, "slice": 1}
+
+
+def test_the_as_written_cell_compiles_for_v5e_and_its_passes_are_pinned(v5e):
+    """``sw-as-written-1chip``'s donated 10-step call (``ghost`` 1,
+    14400 x 7200 cells on one chip): it fits the chip, runs no kernel
+    call, carries the step's scopes, and a trip of its loop holds the
+    field-sized instructions counted above: some 87 passes over a field
+    a step by the benchmark's rules (``perfbench/layer_metrics/
+    sw_field_passes_per_step.py``), where the kernel cells make 6."""
+    from perfbench.harness import files, scopes
+
+    compiled = _compiled_multistep(v5e, (1, 1), 1, 7200, 14400, 10)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_bytes = 6 * 7202 * 14402 * 4  # filled up to whole (8, 128) tiles
+    assert state_bytes <= mem.argument_size_in_bytes <= 1.01 * state_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert "tpu_custom_call" not in text and "collective-permute" not in text
+    assert _trips(text) == 10
+    body, types = _step_body(text)
+    passes = files.load_module("layer_metrics", "sw_field_passes_per_step")
+    in_place = passes.in_place_writes(text)
+    counts = {}
+    for name, opcode, _, _ in body:
+        if re.search(r"f32\[720[02],1440[02]\]", types[name]):
+            if opcode == "fusion" and name in in_place:
+                opcode = "fusion in place"
+            counts[opcode] = counts.get(opcode, 0) + 1
+    assert counts == AS_WRITTEN_TRIP
+    # every ghost column is written in place as the strip of lane tiles
+    # that holds it, never as a piece of 4 bytes a row
+    strips = {in_place[name] for name, opcode, _, _ in body
+              if opcode == "fusion" and name in in_place}
+    assert strips <= {k * 7202 * cols * 4 for k in (1, 2, 4) for cols in (128, 66)}
+    # the phases and the exchanged fields reach the TPU's text
+    table = scopes.origins(text)
+    names = {o.op_name for o in table.values() if o.op_name}
+    for phase in sw.STEP_PHASES:
+        assert any(f"/{sw.STEP_SCOPE}/{phase}/" in n for n in names), phase
+    exchanged = {n.split(f"/{sw.STEP_SCOPE}/exchange.")[1].split("/")[0]
+                 for n in names if f"/{sw.STEP_SCOPE}/exchange." in n}
+    # one chip: no wire, and XLA writes the columns of several fields in
+    # one fusion under the first one's name; every exchange that is left
+    # a name is one of the twelve
+    assert exchanged and exchanged <= set(sw.STEP_EXCHANGES)
+    assert all(scopes.layer_of(o) == scopes.OP_SURFACE for o in table.values()
+               if o.op_name and f"/{sw.STEP_SCOPE}/exchange." in o.op_name)
+    assert all(scopes.layer_of(o) == scopes.PROGRAMS for o in table.values()
+               if o.op_name and re.search(
+                   rf"/{sw.STEP_SCOPE}/(?!exchange\.)", o.op_name))
+
+
+@pytest.mark.parametrize("ghost", [1, 2, 4])
+def test_make_state_builds_the_form_the_schedule_carries_on_a_tpu(v5e, ghost):
+    """Where the ``ghost`` 2 step runs as the kernel the tendencies are
+    padded, which no CPU run shows: ``make_state``'s shapes are
+    ``make_init``'s on the described chips for every schedule."""
+    mesh = jax.make_mesh(
+        (2, 2), ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:4])
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=3600, nx=7200, ghost=ghost)
+    field = jax.ShapeDtypeStruct(
+        (3600, 7200), jnp.float32, sharding=NamedSharding(mesh, jax.P("y", "x")))
+    made = jax.eval_shape(sw.make_state(cfg, comm), field, field, field)
+    assert made == jax.eval_shape(sw.make_init(cfg, comm))
+    assert made.dh.shape == made.h.shape == (3600 + 4 * ghost, 7200 + 4 * ghost)
+    sw.make_state(cfg, comm).lower(field, field, field).compile()
+
+
 @pytest.mark.parametrize("n", [1, 4])
 def test_op_surface_compiles_for_v5e(v5e, n):
     """chip_smoke.py's 13-op program and its rendezvous ring (host
