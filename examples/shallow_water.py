@@ -26,7 +26,7 @@ upstream's step as written, array code with one halo exchange after
 each of twelve fields a step.  --benchmark takes `bench_size()`'s
 `ghost=2`, which on a TPU in float32 is one Pallas kernel and three
 exchanges a step.  On one v5e chip at 14400x7200 cells the two read
-1 065 and 18 352 Mcell/s (PERF.md, PR 42: the cells
+2 202 and 18 358 Mcell/s (PERF.md, PR 43: the cells
 `sw-as-written-1chip` and `sw-bench-1chip`): for a run that is timed,
 pass `ghost=2`.
 """

@@ -97,8 +97,8 @@ class SWConfig:
     # written (shallow_water.py:277-412 there): array code on every
     # backend, one exchange after each of 12 fields a step, the state
     # upstream's (ny+2, nx+2) arrays; what a caller's own stencil with
-    # the library's exchange looks like, and on a TPU some 17 times
-    # slower than ghost=2 (PERF.md, PR 42). 2 = wide-halo schedule: all
+    # the library's exchange looks like, and on a TPU some 8 times
+    # slower than ghost=2 (PERF.md, PR 43). 2 = wide-halo schedule: all
     # intermediate fields (fluxes, vorticity, kinetic energy, viscosity
     # gradients) are recomputed locally inside the ghost region, so a
     # step needs only 2 exchange rounds of the prognostic fields: 5
@@ -256,8 +256,9 @@ def _ghosted_state(h0, u0, v0, cfg, comm, token):
     return SWState(h0, u0, v0, zeros, zeros, zeros), token
 
 
-# -- finite-difference helpers on (ny+2, nx+2) blocks ---------------------
-# interior view: [1:-1, 1:-1]; neighbours: e/w shift x, n/s shift y.
+# -- finite-difference helpers on padded blocks ---------------------------
+# Neighbours as slices, interior-shaped: [1:-1, 1:-1] of the block, e/w
+# shifted in x, n/s in y.  What the ghost=2 array code computes with.
 
 
 def _i(a):
@@ -280,12 +281,33 @@ def _s(a):
     return a[:-2, 1:-1]
 
 
-def _ne(a):
-    return a[2:, 2:]
+# The same neighbours as views of the whole padded block, at its shape.
+# What the ghost=1 step computes with: a result then has the block's own
+# shape, the unshifted operand lies under it tile for tile, and there is
+# no interior to place (shallow_water_step says what that is worth).
 
 
-def _set_interior(a, val):
-    return a.at[1:-1, 1:-1].set(val)
+def _shifted(a, dy, dx):
+    """``a`` read ``(dy, dx)`` away, at ``a``'s own shape: element
+    ``[i, j]`` is ``a[i + dy, j + dx]``, and zero where that lies
+    outside ``a``, which only a cell of the ghost ring reads."""
+    return lax.pad(a, jnp.zeros((), a.dtype), ((-dy, dy, 0), (-dx, dx, 0)))
+
+
+def _E(a):
+    return _shifted(a, 0, 1)
+
+
+def _W(a):
+    return _shifted(a, 0, -1)
+
+
+def _N(a):
+    return _shifted(a, 1, 0)
+
+
+def _S(a):
+    return _shifted(a, -1, 0)
 
 
 # The as-written step's phases and the fields it exchanges, as
@@ -313,7 +335,25 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
 
     ``cfg.ghost == 1``: the reference's schedule as written, array code
     with one ``halo_exchange_2d`` after each of twelve fields
-    (``STEP_EXCHANGES``), on every backend.  ``cfg.ghost == 2``:
+    (``STEP_EXCHANGES``), on every backend.  Every field it makes is
+    made **at the padded shape**, ``(ny + 2, nx + 2)`` a block: a
+    neighbour is a view of the whole padded array read one cell away
+    (:func:`_shifted`: what lies outside is read only by a cell of the
+    ghost ring), and a result goes where it belongs by a selection,
+    ``lax.select(inside, value, ring)``, with ``inside`` from two iotas:
+    zeros on the ring for the six fields upstream rings with zeros, the
+    old ring for the tendencies and for ``h``, ``u``, ``v``, so that the
+    state's ring holds what upstream's program leaves there.  An
+    interior-shaped result placed into a padded array
+    (``a.at[1:-1, 1:-1].set(value)``, or ``.add``) is the same field
+    and, on a TPU, three passes over it where this is one: the
+    interior is written, then read and written again a row and a lane
+    off the tiles, and a zero ring for it is a ``pad`` more (PERF.md,
+    PR 43: 87 passes over a field a step became 35 and the step half as
+    long).  Only what is one strip of a field is written where it lies:
+    the wall's row, and the ring of ``hc``, which is ``h`` but for its
+    ring.  A stencil of your own beside ``halo_exchange_2d`` wants the
+    same form.  ``cfg.ghost == 2``:
     wide-halo schedule, 5 exchanges per step as array code and, where
     the step runs as the kernel (TPU devices, float32), 3
     ``halo_slabs_2d`` and one kernel call (see :func:`_step_wide`).
@@ -332,89 +372,102 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
     dx, dy, g = cfg.dx, cfg.dy, cfg.gravity
 
     h, u, v, dh, du, dv = state
+    # the cells of a padded block, from two iotas: fused, no bytes
+    rows = lax.broadcasted_iota(jnp.int32, h.shape, 0)
+    cols = lax.broadcasted_iota(jnp.int32, h.shape, 1)
+    inside = ((rows > 0) & (rows < h.shape[0] - 1)
+              & (cols > 0) & (cols < h.shape[1] - 1))
+    zeros = jnp.zeros_like(h)
 
     def exchange(a, name, token):
         with jax.named_scope(f"{STEP_SCOPE}/exchange.{name}"):
             return halo_exchange_2d(a, comm=comm, periodic=per, token=token)
 
     def wall_v(a):
-        """v = 0 on the northern wall row (reference :401-402)."""
-        return jnp.where(is_north, a.at[-2, :].set(0.0), a)
+        """v = 0 on the northern wall row (reference :401-402): one row
+        written where it lies."""
+        return a.at[-2, :].set(jnp.where(is_north, 0.0, a[-2, :]))
 
-    # cell-centred height with edge-padded ghosts, then exchanged
+    # cell-centred height with edge-padded ghosts, then exchanged: h but
+    # for its ring, so a copy of h with the ring's four strips written
+    # where they lie, rows first (the corners are the corner cells')
     with _phase("height"):
-        hc = jnp.pad(h[1:-1, 1:-1], 1, mode="edge")
+        hc = h.at[0, :].set(h[1, :]).at[-1, :].set(h[-2, :])
+        hc = hc.at[:, 0].set(hc[:, 1]).at[:, -1].set(hc[:, -2])
     hc, token = exchange(hc, "hc", token)
 
     # mass fluxes on cell faces
     with _phase("mass_flux"):
-        fe = _set_interior(jnp.zeros_like(u), 0.5 * (_i(hc) + _e(hc)) * _i(u))
-        fn = _set_interior(jnp.zeros_like(v), 0.5 * (_i(hc) + _n(hc)) * _i(v))
+        fe = lax.select(inside, 0.5 * (hc + _E(hc)) * u, zeros)
+        fn = lax.select(inside, 0.5 * (hc + _N(hc)) * v, zeros)
     fe, token = exchange(fe, "fe", token)
     fn, token = exchange(fn, "fn", token)
     with _phase("mass_flux"):
         fn = wall_v(fn)
 
     with _phase("tendencies"):
-        dh_new = _set_interior(
-            dh, -(_i(fe) - _w(fe)) / dx - (_i(fn) - _s(fn)) / dy
-        )
+        dh_new = lax.select(
+            inside, -(fe - _W(fe)) / dx - (fn - _S(fn)) / dy, dh)
 
     # potential vorticity (planetary + relative, over face-mean depth)
     with _phase("vorticity"):
         yy, _xx = _local_mesh_coords(cfg, comm)
-        rel_vort = (_e(v) - _i(v)) / dx - (_n(u) - _i(u)) / dy
-        q_int = (_coriolis(cfg, yy)[1:-1, 1:-1] + rel_vort) / (
-            0.25 * (_i(hc) + _e(hc) + _n(hc) + _ne(hc))
+        rel_vort = (_E(v) - v) / dx - (_N(u) - u) / dy
+        q = lax.select(
+            inside,
+            (_coriolis(cfg, yy) + rel_vort)
+            / (0.25 * (hc + _E(hc) + _N(hc) + _shifted(hc, 1, 1))),
+            zeros,
         )
-        q = _set_interior(jnp.zeros_like(h), q_int)
     q, token = exchange(q, "q", token)
 
     # momentum tendencies: pressure gradient + PV flux (Sadourny 1975)
     with _phase("tendencies"):
-        du_new = _set_interior(
-            du,
-            -g * (_e(h) - _i(h)) / dx
+        du_new = lax.select(
+            inside,
+            -g * (_E(h) - h) / dx
             + 0.5
             * (
-                _i(q) * 0.5 * (_i(fn) + _e(fn))
-                + _s(q) * 0.5 * (_s(fn) + fn[:-2, 2:])
+                q * 0.5 * (fn + _E(fn))
+                + _S(q) * 0.5 * (_S(fn) + _shifted(fn, -1, 1))
             ),
+            du,
         )
-        dv_new = _set_interior(
-            dv,
-            -g * (_n(h) - _i(h)) / dy
+        dv_new = lax.select(
+            inside,
+            -g * (_N(h) - h) / dy
             - 0.5
             * (
-                _i(q) * 0.5 * (_i(fe) + _n(fe))
-                + _w(q) * 0.5 * (_w(fe) + fe[2:, :-2])
+                q * 0.5 * (fe + _N(fe))
+                + _W(q) * 0.5 * (_W(fe) + _shifted(fe, 1, -1))
             ),
+            dv,
         )
 
     # kinetic energy gradient
     with _phase("kinetic"):
-        ke = _set_interior(
-            jnp.zeros_like(h),
-            0.5 * (0.5 * (_i(u) ** 2 + _w(u) ** 2)
-                   + 0.5 * (_i(v) ** 2 + _s(v) ** 2)),
+        ke = lax.select(
+            inside,
+            0.5 * (0.5 * (u ** 2 + _W(u) ** 2) + 0.5 * (v ** 2 + _S(v) ** 2)),
+            zeros,
         )
     ke, token = exchange(ke, "ke", token)
     with _phase("kinetic"):
-        du_new = du_new.at[1:-1, 1:-1].add(-(_e(ke) - _i(ke)) / dx)
-        dv_new = dv_new.at[1:-1, 1:-1].add(-(_n(ke) - _i(ke)) / dy)
+        du_new = lax.select(inside, du_new - (_E(ke) - ke) / dx, du_new)
+        dv_new = lax.select(inside, dv_new - (_N(ke) - ke) / dy, dv_new)
 
     # time step: forward Euler bootstrap, then AB2 (reference :345-371)
     dt = jnp.asarray(cfg.dt, h.dtype)
     with _phase("ab2"):
         if first_step:
-            u = u.at[1:-1, 1:-1].add(dt * _i(du_new))
-            v = v.at[1:-1, 1:-1].add(dt * _i(dv_new))
-            h = h.at[1:-1, 1:-1].add(dt * _i(dh_new))
+            u = lax.select(inside, u + dt * du_new, u)
+            v = lax.select(inside, v + dt * dv_new, v)
+            h = lax.select(inside, h + dt * dh_new, h)
         else:
             a, b = cfg.ab_a, cfg.ab_b
-            u = u.at[1:-1, 1:-1].add(dt * (a * _i(du_new) + b * _i(du)))
-            v = v.at[1:-1, 1:-1].add(dt * (a * _i(dv_new) + b * _i(dv)))
-            h = h.at[1:-1, 1:-1].add(dt * (a * _i(dh_new) + b * _i(dh)))
+            u = lax.select(inside, u + dt * (a * du_new + b * du), u)
+            v = lax.select(inside, v + dt * (a * dv_new + b * dv), v)
+            h = lax.select(inside, h + dt * (a * dh_new + b * dh), h)
 
     h, token = exchange(h, "h", token)
     u, token = exchange(u, "u", token)
@@ -432,13 +485,15 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
 
         def friction(w, name, token):
             with _phase("friction"):
-                gx = _set_interior(jnp.zeros_like(w), nu * (_e(w) - _i(w)) / dx)
-                gy = _set_interior(jnp.zeros_like(w), nu * (_n(w) - _i(w)) / dy)
+                gx = lax.select(inside, nu * (_E(w) - w) / dx, zeros)
+                gy = lax.select(inside, nu * (_N(w) - w) / dy, zeros)
             gx, token = exchange(gx, f"gx_{name}", token)
             gy, token = exchange(gy, f"gy_{name}", token)
             with _phase("friction"):
-                w = w.at[1:-1, 1:-1].add(
-                    dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
+                w = lax.select(
+                    inside,
+                    w + dt * ((gx - _W(gx)) / dx + (gy - _S(gy)) / dy),
+                    w,
                 )
             return w, token
 

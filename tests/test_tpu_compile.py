@@ -384,28 +384,41 @@ def test_no_write_of_the_exchange_is_two_columns_wide(v5e, form):
 
 
 # what one trip of the as-written step's loop holds at a field's size
-# (7202 x 14402 padded, 7200 x 14400 interior), by opcode, on this tree:
-# the passes over a field that the next PR's diff should show it removed.
-# A fusion "in place" writes the exchange's lane-tile strips into fields
-# where they lie (up to four fields a fusion) and moves no field.
+# (7202 x 14402 padded), by opcode, since PR 43 made every field of the
+# step at the padded shape: five fusions (`fe`, `fn`, `q`, `ke`; the
+# tendencies and AB2 in two; friction in two more, the first of them
+# with the last of AB2), each writing its results whole, ring and all;
+# two copies (`hc` is `h` but for its ring, so a copy of it; one of the
+# new `h`, which XLA could not write over the old one the `du` tendency
+# still reads); no `pad`, no `concatenate`, no `slice`, no interior
+# placed into a live field.  A fusion "in place" writes the exchange's
+# lane-tile strips into fields where they lie (up to four fields a
+# fusion) and moves no field; a "strip in place" is a row or a column
+# written into a field where it lies (`hc`'s ring, four; the northern
+# wall's row of `fn` and `v`, three).  Before: ten fusions, ten whole
+# interiors written in place, eight pads, six copies, two concatenates
+# and a slice, 87.31 passes.
 AS_WRITTEN_TRIP = {
-    "fusion": 10, "fusion in place": 10, "pad": 8, "copy": 6,
-    "concatenate": 2, "dynamic-update-slice": 13, "slice": 1}
+    "fusion": 5, "fusion in place": 10, "copy": 2, "strip in place": 7}
+AS_WRITTEN_PASSES = 45  # the most a trip may make by the benchmark's rules
 
 
 def test_the_as_written_cell_compiles_for_v5e_and_its_passes_are_pinned(v5e):
     """``sw-as-written-1chip``'s donated 10-step call (``ghost`` 1,
     14400 x 7200 cells on one chip): it fits the chip, runs no kernel
     call, carries the step's scopes, and a trip of its loop holds the
-    field-sized instructions counted above: some 87 passes over a field
+    field-sized instructions counted above: 35.33 passes over a field
     a step by the benchmark's rules (``perfbench/layer_metrics/
     sw_field_passes_per_step.py``), where the kernel cells make 6."""
+    from types import SimpleNamespace
+
     from perfbench.harness import files, scopes
 
     compiled = _compiled_multistep(v5e, (1, 1), 1, 7200, 14400, 10)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
-    state_bytes = 6 * 7202 * 14402 * 4  # filled up to whole (8, 128) tiles
+    field_bytes = 7202 * 14402 * 4
+    state_bytes = 6 * field_bytes  # filled up to whole (8, 128) tiles
     assert state_bytes <= mem.argument_size_in_bytes <= 1.01 * state_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     assert "tpu_custom_call" not in text and "collective-permute" not in text
@@ -418,8 +431,20 @@ def test_the_as_written_cell_compiles_for_v5e_and_its_passes_are_pinned(v5e):
         if re.search(r"f32\[720[02],1440[02]\]", types[name]):
             if opcode == "fusion" and name in in_place:
                 opcode = "fusion in place"
+            elif opcode == "dynamic-update-slice":
+                # what it writes, not what it writes into: a row or a
+                # column, never an interior
+                assert in_place[name] <= 14402 * 4, (name, in_place[name])
+                opcode = "strip in place"
             counts[opcode] = counts.get(opcode, 0) + 1
     assert counts == AS_WRITTEN_TRIP
+    # by the benchmark's own rules, every instruction of the trip an
+    # event that ran once: the count the cell's traced run reports
+    events = [SimpleNamespace(name=line.strip().removeprefix("ROOT "))
+              for _, _, _, line in body]
+    moved, kernel_calls = passes.moved_bytes(events, text)
+    assert kernel_calls == 0
+    assert 30 < moved / field_bytes <= AS_WRITTEN_PASSES, moved / field_bytes
     # every ghost column is written in place as the strip of lane tiles
     # that holds it, never as a piece of 4 bytes a row
     strips = {in_place[name] for name, opcode, _, _ in body
