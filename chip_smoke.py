@@ -652,6 +652,103 @@ def solver_restart_check(cfg, devices, *, mesh_shapes=((1, 1),),
     return out
 
 
+def solver_output_restart_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
+                                steps_per_call=10, calls=4, every=2):
+    """The solver as the job a user keeps and restarts
+    (``make_job(snapshot, on_chunk, checkpoint)``): on every mesh a job
+    writes a snapshot after every call and saves its whole state every
+    ``every`` calls, both under one bound on what is on its way to the
+    host (two snapshots: a third, or a snapshot beside a save's window
+    of pieces, has to wait), and is dropped after ``calls`` with output
+    undelivered; a new job resumes from the directory and runs
+    ``every`` calls more.  The most bytes in flight never pass the
+    bound; the resumed job's snapshots and state are bit for bit those
+    of a job with output alone that was never stopped; and the meshes'
+    snapshots agree to the rounding of another decomposition."""
+    import shutil
+    import tempfile
+
+    import numpy as np
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    one = 3 * (cfg.ny // coarsen) * (cfg.nx // coarsen) * np.dtype(cfg.dtype).itemsize
+    bound = 2 * one + one // 2
+    runs, peaks, waited = [], [], 0.0
+    for shape in mesh_shapes:
+        n = shape[0] * shape[1]
+        snapshot = sw.Snapshot(coarsen=coarsen, lag=2, ahead_bytes=bound)
+        whole, whole_got = _job(cfg, devices[:n], shape, snapshot,
+                                steps_per_call, calls + every)
+        directory = tempfile.mkdtemp(prefix="chip-smoke-output-restart-")
+        try:
+            comm, got = whole.comm, []
+
+            def job():
+                return sw.make_job(
+                    cfg, comm, steps_per_call, snapshot,
+                    lambda fields, step: got.append((step, fields)),
+                    sw.Checkpoint(directory, every_calls=every, keep=2))
+
+            killed = job()
+            killed.start(sw.make_init(cfg, comm)())
+            killed.advance(calls)
+            killed._settle()  # the newest save acknowledged, output still pending
+            peaks.append(killed.stats()["host_in_flight_max_bytes"])
+            killed.state = None
+            del killed, got[:]
+            resumed = job()
+            step = resumed.resume()
+            if step != 1 + steps_per_call * (calls - calls % every):
+                raise AssertionError(f"{shape}: resumed from step {step}")
+            resumed.advance(every + calls % every)
+            resumed.drain()
+            stats = resumed.stats()
+            peaks.append(stats["host_in_flight_max_bytes"])
+            waited += stats["transfer_wait_s"]
+            after = [(s, f) for s, f in whole_got if s > step]
+            if [s for s, _ in got] != [s for s, _ in after]:
+                raise AssertionError(
+                    f"{shape}: the resumed job wrote steps {[s for s, _ in got]}, "
+                    f"the job never stopped {[s for s, _ in after]}")
+            for (s, mine), (_, theirs) in zip(got, after):
+                for k in mine:
+                    if not np.array_equal(mine[k], theirs[k]):
+                        raise AssertionError(
+                            f"{shape}: the snapshot of {k} at step {s} of the "
+                            "resumed job differs from the job never stopped")
+            for name, a, b in zip(whole.state._fields, resumed.state, whole.state):
+                if not np.array_equal(np.asarray(a), np.asarray(b)):
+                    raise AssertionError(
+                        f"{shape}: state.{name} of the resumed job differs "
+                        "from that of a job never stopped")
+            runs.append(got)
+        finally:
+            shutil.rmtree(directory, ignore_errors=True)
+    across = max((float(np.abs(a[k] - b[k]).max())
+                  for other in runs[1:]
+                  for (_, a), (_, b) in zip(runs[0], other) for k in a), default=0.0)
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost} as a job with output "
+        f"({coarsen}x{coarsen} means after every call of {steps_per_call} steps) "
+        f"and a save every {every} calls under one bound of {bound} bytes, "
+        f"dropped after {calls} and resumed: snapshots and state bit for bit "
+        "the job never stopped, on "
+        f"{' and '.join('x'.join(map(str, s)) for s in mesh_shapes)} "
+        f"(tol {TOL_SAME_ARITHMETIC} between them)",
+        "host_bound_bytes": bound,
+        "host_in_flight_max_bytes": max(peaks),
+        "transfer_wait_s": waited,
+        "meshes_max_diff": across,
+        "max_diff": across,
+    }
+    if max(peaks) > bound:
+        raise AssertionError(f"more on its way to the host than it takes: {out}")
+    if across > TOL_SAME_ARITHMETIC:
+        raise AssertionError(f"decomposition changes the resumed output: {out}")
+    return out
+
+
 # ------------------------------------------------------------------- ops
 
 _K = 128  # elements per device in the op checks
@@ -1192,6 +1289,9 @@ GROUPS = {
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
             "solver4.restart": lambda: solver_restart_check(
+                _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
+            ),
+            "solver4.output_restart": lambda: solver_output_restart_check(
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
         }),

@@ -73,7 +73,9 @@ def main(argv=None):
         help="collect one frame per multistep chunk and save an "
         "animation (the reference's matplotlib animation output): the "
         "solver's job writes a snapshot of h after every chunk and "
-        "copies it to the host beside the next chunks",
+        "copies it to the host beside the next chunks; with "
+        "--checkpoint-dir a rerun goes on writing frames from the newest "
+        "save, the same frames a run that was never stopped writes",
     )
     p.add_argument(
         "--coarsen",
@@ -91,7 +93,9 @@ def main(argv=None):
         help="save the whole state every --checkpoint-every chunks, "
         "beside the chunks that follow; a rerun with the same DIR "
         "resumes from the newest save (the job's own: "
-        "docs/shallow-water.md, Saving and resuming)",
+        "docs/shallow-water.md, Saving and resuming); with --animate the "
+        "snapshots' copies and a save's pieces share one bound on what is "
+        "on its way to the host",
     )
     p.add_argument("--checkpoint-every", type=int, default=1)
     args = p.parse_args(argv)

@@ -1087,7 +1087,9 @@ class Snapshot:
     a pinned buffer of a fixed size (a TPU's:
     ``TPU_PREMAPPED_BUFFER_SIZE``, 4 GiB unless set) moves what is asked
     for past it at a tenth of the speed: a job whose ``lag + 1``
-    snapshots do not fit there holds its copies under it with this."""
+    snapshots do not fit there holds its copies under it with this.  In
+    a job that is saved too it is the host's bound on all the job's
+    copies, a save's pieces among them (:class:`SolverJob`)."""
 
     fields: tuple = ("h", "u", "v")
     coarsen: int = 1
@@ -1166,8 +1168,8 @@ class Checkpoint:
     flight cost a 76 ms call nothing that shows, 160e6 cost the call
     after a save 30 ms).  A piece is at most ``checkpoint.PIECE_BYTES`` a device and
     at most half the bound, so that one copy runs while the next waits.
-    A job that has snapshots and saves gives each its bound and their
-    sum has to fit."""
+    A job that has snapshots and saves keeps both under the host's bound
+    together, one figure (:class:`SolverJob`)."""
 
     directory: object
     every_calls: int = 1
@@ -1278,7 +1280,35 @@ class SolverJob:
     acknowledged in step order, and the time the loop waits for that is
     counted.  :meth:`resume` takes the newest acknowledged save of a
     directory as the job's state; what an interrupted save left there
-    is removed, never read.
+    is removed, never read.  A resumed job has no snapshot pending: the
+    first it delivers is of the resumed step plus one call, and from
+    there on its output is, step for step and bit for bit where the form
+    is the same, what the job that was never stopped delivers.
+
+    **One bound on what is on its way to the host.**  A host takes so
+    many bytes of copies that are asked for and not yet fetched (its
+    staging buffer), whatever they are copies of, so a job has one
+    figure, ``ahead_bytes``: the least of ``snapshot.ahead_bytes`` and
+    ``checkpoint.ahead_bytes`` (``None``: neither sets one).  Snapshots'
+    copies, asked for by the loop, and a save's pieces', asked for by
+    the save's thread, count against it together
+    (:class:`checkpoint.HostBound`); the save's pieces stay under
+    ``checkpoint.AHEAD_BYTES`` a device besides, the device's queue's
+    bound.  First come, first served: the loop asks for a snapshot's
+    copy as soon as it fits and a save takes the room that is left
+    (PERF.md, PR 45, has the other form, a window set aside for a
+    streaming save, measured beside this one).  A snapshot that is due
+    and a save's oldest piece are waited for, under ``job/ask_wait`` on
+    the loop's thread and ``checkpoint/fetch_wait`` on the save's, and
+    neither kind takes room ahead of the other's wait.  A copy that
+    alone is over the bound goes alone, with nothing else in flight:
+    only then, and by that copy's size, does
+    ``stats()["host_in_flight_max_bytes"]`` read over the bound.
+    Snapshots block the loop when late, a save only when the next comes
+    due; while the loop waits for a save whose pieces wait for room
+    that snapshots hold, it delivers those snapshots early (so wait for
+    a save through :meth:`drain` or the next :meth:`save`).  A job with
+    only one half never waits for room, and is what it was.
 
     ``first``, ``multi``, ``snap`` and ``stage`` are the jitted
     programs; ``state``, ``step`` and ``calls`` the model as the last
@@ -1295,7 +1325,9 @@ class SolverJob:
     it; ``job/drain``; ``job/resume`` with ``checkpoint/read`` and
     ``checkpoint/to_device`` a piece and ``job/compile``; and, on a
     save's threads, ``checkpoint/save``, ``/fetch``, ``/write``,
-    ``/commit`` and ``/prune``.  A span's ``key`` is the model step it
+    ``/commit`` and ``/prune``; ``job/ask_wait`` and
+    ``checkpoint/fetch_wait`` a copy held back by the other kind's
+    bytes (``held_by``, ``bytes``).  A span's ``key`` is the model step it
     is about.  :meth:`spans` returns them; every time in :meth:`stats`
     is the sum of its spans'.
     """
@@ -1305,6 +1337,11 @@ class SolverJob:
         self.cfg, self.comm = cfg, comm
         self.num_multisteps = num_multisteps
         self.snapshot, self.on_chunk = snapshot, on_chunk
+        self.ahead_bytes = min(
+            (half.ahead_bytes for half in (snapshot, checkpoint)
+             if half is not None and half.ahead_bytes is not None), default=None)
+        if checkpoint is not None:  # its pieces are cut for the job's bound
+            checkpoint = replace(checkpoint, ahead_bytes=self.ahead_bytes)
         self.checkpoint = checkpoint
         self.first = make_first_step(cfg, comm)
         self.multi = make_multistep(cfg, comm, num_multisteps, donate=True)
@@ -1313,7 +1350,6 @@ class SolverJob:
         self.state, self.step, self.calls = None, 0, 0
         self._pending = collections.deque()  # (step, device arrays), oldest first
         self._asked = 0  # of them, from the oldest: their copies are on their way
-        self._asked_bytes = 0
         self.series = checkpoint and ckpt.Series(
             checkpoint.directory, keep=checkpoint.keep)
         self.stage, self._plan, self._save = None, None, None
@@ -1323,6 +1359,9 @@ class SolverJob:
         self._stage = self.stage
         self.saves = []
         self.trace = spans.Recorder(SCOPE_PREFIX)
+        self._host = ckpt.HostBound(self.ahead_bytes)
+        self._copies = ckpt.Side(self._host, ckpt.SNAPSHOT, partial(
+            self.trace.span, "job/ask_wait"))
         self._stats = dict(
             snapshots_produced=0, snapshots_delivered=0, max_lag=0,
             bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
@@ -1335,6 +1374,7 @@ class SolverJob:
         through the forward-Euler step, which does not donate it; a
         later one (a checkpoint's) is taken as it is."""
         self.drain()
+        self._host.peak = self._host.in_flight
         if step == 0:
             state, step = self.first(state), 1
         self.state, self.step = state, step
@@ -1403,13 +1443,23 @@ class SolverJob:
         save's start to its last piece on the host, and to its rename
         (both pass beside the loop; ``saves`` has them save by save);
         ``restore_read_s`` and ``restore_to_device_s``, host seconds a
-        resume spent reading files and handing them to the device.
+        resume spent reading files and handing them to the device;
+        ``host_in_flight_max_bytes``, the most bytes of copies to the
+        host asked for and not yet fetched at any moment since
+        :meth:`start` or :meth:`resume`, snapshots' and pieces'
+        together; ``transfer_wait_s``, seconds a copy was held back by
+        the other kind's bytes under the job's one bound, the loop's
+        (``job/ask_wait``) and the acknowledged saves'
+        (``checkpoint/fetch_wait``).
         Every time is the sum of its spans' (:meth:`spans`)."""
         saves = list(self.saves)
         return dict(
             self._stats, saves_acknowledged=len(saves),
             save_stage_s=sum(r["stage_s"] for r in saves),
-            save_commit_s=sum(r["commit_s"] for r in saves))
+            save_commit_s=sum(r["commit_s"] for r in saves),
+            host_in_flight_max_bytes=self._host.peak,
+            transfer_wait_s=self._copies.waited_s + sum(
+                r["fetch_wait_s"] for r in saves))
 
     def spans(self):
         """The finished spans of the job's host code and of its saves'
@@ -1477,7 +1527,8 @@ class SolverJob:
         return ckpt.Save(
             self.series, self.step, manifest, files, pieces,
             ahead_bytes=self.checkpoint.ahead(self.comm.size),
-            on_commit=self.saves.append, trace=self.trace, cause=cause)
+            on_commit=self.saves.append, trace=self.trace, cause=cause,
+            bound=self._host)
 
     def resume(self, directory=None):
         """Take the newest acknowledged save of ``directory`` (the
@@ -1548,9 +1599,13 @@ class SolverJob:
             for rows, width in (padded,) * 3 + (tendency,) * 3)
 
     def _settle(self):
-        """Wait for the save on its way, if any, to be acknowledged."""
+        """Wait for the save on its way, if any, to be acknowledged.
+        Where its pieces wait for room that snapshots' copies hold, the
+        oldest of those is delivered now: only this thread fetches them."""
         if self._save is not None:
             save, self._save = self._save, None
+            while not self._host.until(lambda: save.done, ckpt.SNAPSHOT):
+                self._deliver(len(self._pending) - 1)
             save.wait()
 
     # -- output ------------------------------------------------------------
@@ -1558,35 +1613,37 @@ class SolverJob:
     def _written(self):
         return tuple(getattr(self.state, k) for k in self.snapshot.fields)
 
-    def _ask(self, cause=None):
+    def _ask(self, cause=None, wait=False):
         """Start the copies to the host of the oldest snapshots not yet
-        asked for, as far as ``snapshot.ahead_bytes`` goes; returns the
-        seconds that took.  ``cause``: the fetch that made the room."""
-        most = self.snapshot.ahead_bytes
+        asked for, as far as the job's bound goes (``wait``: the oldest
+        is due, and is waited for where a save's pieces hold its room);
+        returns the seconds that took.  ``cause``: the fetch that made
+        the room."""
         seconds = 0.0
         while self._asked < len(self._pending):
             step, parts = self._pending[self._asked]
             size = sum(part.nbytes for part in parts)
-            if self._asked and most is not None and self._asked_bytes + size > most:
+            if not self._copies.take(size, wait and not self._asked, key=step):
                 break
             with self.trace.span("job/ask", key=step, cause=cause, bytes=size) as asked:
                 for part in parts:
                     part.copy_to_host_async()
             self._asked += 1
-            self._asked_bytes += size
             seconds += asked.seconds
         return seconds
 
     def _deliver(self, keep):
         stats, span = self._stats, self.trace.span
         while len(self._pending) > keep:
+            if not self._asked:  # its room was a save's pieces'
+                self._ask(wait=True)
             step, parts = self._pending.popleft()
             size = sum(part.nbytes for part in parts)
             with span("job/fetch", key=step, bytes=size) as fetched:
                 arrays = {k: np.asarray(part)
                           for k, part in zip(self.snapshot.fields, parts)}
             self._asked -= 1
-            self._asked_bytes -= size
+            self._copies.give(size)
             stats["output_wait_s"] += fetched.seconds + self._ask(fetched.id)
             if self.on_chunk is not None:
                 with span("job/callback", key=step) as called:
@@ -1648,6 +1705,15 @@ def make_solver(
     the next save comes due before the last is acknowledged: then the
     loop waits, inside the wall clock (``stats()['save_wait_s']``).
     Every save is acknowledged before ``solve`` returns.
+
+    With both (``on_chunk`` and ``checkpoint_dir``) the job keeps the
+    snapshots' copies and a save's pieces under one bound on what is on
+    its way to the host, ``snapshot.ahead_bytes`` (:class:`SolverJob`),
+    and a restarted run's output is the uninterrupted run's: a ``solve``
+    that resumes hands ``on_chunk`` the chunks after the newest save,
+    step for step and bit for bit what the run that was never stopped
+    hands out (chunks that the stopped run had delivered after that save
+    are delivered again, the same).
     """
     init = make_init(cfg, comm)
     checkpoint = None

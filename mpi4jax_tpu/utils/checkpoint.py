@@ -27,7 +27,9 @@ in progress under another name until it is whole):
   yet fetched, are written into one ``.npy`` file an array by background
   threads beside whatever the caller runs next, and are committed by one
   rename; a restore reads them back under the same bound.  What
-  ``models.shallow_water``'s job saves and resumes through.  Each takes
+  ``models.shallow_water``'s job saves and resumes through.  Where the
+  owner has other copies on their way to the same host (a job's
+  snapshots), all of them count against one :class:`HostBound`.  Each takes
   a ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`, its owner's;
   one of its own where none is given) and records what its threads did
   under ``checkpoint/...``: every time it reports is its spans'.
@@ -52,7 +54,7 @@ from mpi4jax_tpu.utils.spans import Recorder
 __all__ = [
     "save", "restore", "latest_step", "Manager",
     "Series", "Save", "piece_rows", "begin_npy", "write_at", "to_host",
-    "read_pieces",
+    "read_pieces", "HostBound", "Side",
 ]
 
 
@@ -337,7 +339,130 @@ def write_at(fd, offset, array, bounce):
         os.pwrite(fd, memoryview(bounce)[:n], offset + at)
 
 
-def to_host(pieces, ahead_bytes=None, span=None):
+# The kinds of copies to the host that one :class:`HostBound` counts.
+SNAPSHOT, SAVE = "snapshot", "save"
+
+
+class HostBound:
+    """The bytes of an owner's copies to the host that are asked for and
+    not yet fetched, of every kind and from every thread, under one
+    figure: ``most``, what the host takes (``None``: whatever is asked).
+    A job's snapshots, asked for and fetched by its loop, and the pieces
+    of its save, asked for and fetched by the save's thread, go through
+    one staging buffer and one device queue, so they count together.
+
+    :meth:`take` makes room for a copy of ``size`` bytes of ``kind``
+    before it is asked for and :meth:`give` returns it once the copy is
+    fetched.  A copy fits while what is in flight and it stay under
+    ``most``.  One that alone is over ``most`` goes alone, with nothing
+    else of either kind in flight: the one case in which ``peak``, the
+    most bytes in flight since it was last reset, reads over ``most``,
+    by that copy's size.
+
+    First come, first served, and neither kind starves: a thread that
+    can do nothing but wait (``wait=True``: none of its own copies is in
+    flight, so the room is the other kind's) is served before any copy
+    asked for later, and the other kind makes that room by fetching what
+    it has, which needs none.  Nothing is set aside for either kind
+    (PERF.md, PR 45, has the form that did, measured beside this one)."""
+
+    def __init__(self, most=None):
+        self.most = most
+        self.peak = 0
+        self._held = collections.Counter()
+        self._first = None  # the kind that waits for room: none goes ahead of it
+        self._changed = threading.Condition()
+
+    @property
+    def in_flight(self):
+        return sum(self._held.values())
+
+    def holder(self, but):
+        """The kind other than ``but`` that holds a copy of ``but`` back:
+        the one with the most bytes in flight, else the one whose wait
+        comes first."""
+        with self._changed:
+            held = {k: n for k, n in self._held.items() if k != but and n}
+            return max(held, key=held.get) if held else self._first
+
+    def take(self, kind, size, wait=False):
+        """Count ``size`` bytes of ``kind`` as in flight if they fit;
+        ``wait``: block until they do.  Returns whether they were."""
+        with self._changed:
+            while not self._fits(kind, size):
+                if not wait:
+                    return False
+                if self._first is None:  # whoever waits in `until` hears of it
+                    self._first = kind
+                    self._changed.notify_all()
+                self._changed.wait()
+            if self._first == kind:
+                self._first = None
+            self._held[kind] += size
+            self.peak = max(self.peak, self.in_flight)
+            self._changed.notify_all()
+            return True
+
+    def give(self, kind, size):
+        with self._changed:
+            self._held[kind] -= size
+            self._changed.notify_all()
+
+    def until(self, done, kind):
+        """Block until ``done()`` or until a copy of another kind waits
+        for room that ``kind``'s copies hold; returns ``done()``.
+        Whoever makes ``done()`` true calls :meth:`wake`."""
+        with self._changed:
+            while not done() and not (
+                    self._first not in (None, kind) and self._held[kind]):
+                self._changed.wait()
+            return done()
+
+    def wake(self):
+        with self._changed:
+            self._changed.notify_all()
+
+    def _fits(self, kind, size):
+        if self._first not in (None, kind):
+            return False
+        return (self.most is None or not self.in_flight
+                or self.in_flight + size <= self.most)
+
+
+class Side:
+    """One kind of copies under a :class:`HostBound`, for the one thread
+    that asks for them and fetches them.  ``span(**counts)`` makes the
+    span a wait is recorded under (``held_by``: the kind whose bytes
+    held the copy back; ``bytes``: the copy's); ``waited_s`` is the sum
+    of those spans."""
+
+    def __init__(self, bound, kind, span):
+        self.bound, self.kind, self.span = bound, kind, span
+        self.held, self.waited_s = 0, 0.0
+
+    def take(self, size, wait=False, **counts):
+        """Room for a copy of ``size`` bytes; ``wait``: block, under a
+        span, until there is.  Returns whether there is."""
+        if not self.bound.take(self.kind, size):
+            if not wait:
+                return False
+            with self.span(held_by=self.bound.holder(self.kind), bytes=size,
+                           **counts) as waited:
+                self.bound.take(self.kind, size, wait=True)
+            self.waited_s += waited.seconds
+        self.held += size
+        return True
+
+    def give(self, size):
+        self.held -= size
+        self.bound.give(self.kind, size)
+
+    def close(self):
+        """Return what is still held (copies that were never fetched)."""
+        self.give(self.held)
+
+
+def to_host(pieces, ahead_bytes=None, span=None, side=None):
     """``(key, device array)`` pairs → ``(key, numpy array)`` pairs,
     in order.  The copies to the host are asked for ahead of the fetch,
     oldest first, as far as ``ahead_bytes`` goes: at most that many
@@ -345,7 +470,10 @@ def to_host(pieces, ahead_bytes=None, span=None):
     (``None``: all of them at once).  Takes ``pieces`` (a list) apart as
     it goes, so that each device array is released once it is fetched.
     ``span(piece)``, where given, is entered round each fetch (a
-    save's ``checkpoint/fetch``)."""
+    save's ``checkpoint/fetch``).  ``side`` (a :class:`Side`), where
+    given, is the bound the pieces share with their owner's other
+    copies: a piece is asked for once it fits there too, and the oldest
+    is waited for where nothing of these is in flight."""
     waiting = collections.deque(pieces)
     del pieces[:]
     asked = asked_bytes = 0
@@ -353,6 +481,8 @@ def to_host(pieces, ahead_bytes=None, span=None):
         while asked < len(waiting):
             size = waiting[asked][1].nbytes
             if asked and ahead_bytes is not None and asked_bytes + size > ahead_bytes:
+                break
+            if side is not None and not side.take(size, wait=not asked):
                 break
             waiting[asked][1].copy_to_host_async()
             asked += 1
@@ -362,6 +492,8 @@ def to_host(pieces, ahead_bytes=None, span=None):
             host = np.asarray(piece)
         asked -= 1
         asked_bytes -= piece.nbytes
+        if side is not None:
+            side.give(piece.nbytes)
         del piece
         yield name, host
 
@@ -375,9 +507,17 @@ class Save:
     ``WRITERS`` others, then ``manifest`` is written and the save
     committed; ``on_commit(record)`` is then called from the first
     thread, ``record`` holding ``step``, ``bytes``, ``stage_s`` (start
-    to the last piece on the host) and ``commit_s`` (start to the
-    rename).  :meth:`wait` blocks until the save is committed and the
-    series pruned, and raises what stopped it.
+    to the last piece on the host), ``commit_s`` (start to the rename)
+    and ``fetch_wait_s`` (below).  :meth:`wait` blocks until the save is
+    committed and the series pruned, and raises what stopped it.
+
+    ``bound`` (a :class:`HostBound`, its owner's): the pieces' copies
+    count against it beside the owner's other copies to the host.  A
+    piece that other copies hold back, none of the save's own being in
+    flight, is waited for under ``checkpoint/fetch_wait`` (``held_by``,
+    ``bytes``), and ``fetch_wait_s`` is those spans' sum.  Such a save
+    goes on only as its owner fetches those copies: the owner waits for
+    it through :meth:`HostBound.until`, not :meth:`wait` alone.
 
     The spans of ``trace``, all under the key ``step``: on the first
     thread (``checkpoint-save``) ``checkpoint/save`` from its start to
@@ -393,7 +533,8 @@ class Save:
     written by position so that the writers share it."""
 
     def __init__(self, series, step, manifest, files, pieces, *,
-                 ahead_bytes=None, on_commit=None, trace=None, cause=None):
+                 ahead_bytes=None, on_commit=None, trace=None, cause=None,
+                 bound=None):
         self.step = step
         self.bytes = sum(piece.nbytes for _, piece in pieces)
         self.record = None
@@ -402,6 +543,9 @@ class Save:
         self._fetched = None  # the newest `checkpoint/fetch`
         self._host = queue.Queue()
         self._done = threading.Event()
+        self._bound = bound or HostBound()
+        self._side = Side(self._bound, SAVE, functools.partial(
+            self._trace.span, "checkpoint/fetch_wait", key=step))
         threading.Thread(
             target=self._run, daemon=True, name="checkpoint-save",
             args=(series, manifest, files, pieces, ahead_bytes, on_commit,
@@ -410,6 +554,11 @@ class Save:
     @property
     def committed(self):
         return self.record is not None
+
+    @property
+    def done(self):
+        """Committed and pruned, or failed: :meth:`wait` returns at once."""
+        return self._done.is_set()
 
     def wait(self):
         self._done.wait()
@@ -434,7 +583,8 @@ class Save:
                 self.record = {
                     "step": step, "bytes": self.bytes,
                     "stage_s": (staged_ns - whole.start_ns) / 1e9,
-                    "commit_s": whole.seconds}
+                    "commit_s": whole.seconds,
+                    "fetch_wait_s": self._side.waited_s}
                 if on_commit is not None:
                     on_commit(self.record)
                 with span("checkpoint/prune", key=step, cause=whole.id):
@@ -443,6 +593,7 @@ class Save:
             self._error = self._error or error
         finally:
             self._done.set()
+            self._bound.wake()
 
     def _stream(self, tmp, files, pieces, ahead_bytes, cause):
         """The pieces through the host into their files under ``tmp``."""
@@ -459,9 +610,11 @@ class Save:
         for writer in writers:
             writer.start()
         try:
-            for item in to_host(pieces, ahead_bytes, span=self._fetching):
+            for item in to_host(pieces, ahead_bytes, span=self._fetching,
+                                side=self._side):
                 self._host.put(item)
         finally:
+            self._side.close()
             for writer in writers:
                 self._host.put(None)
             for writer in writers:

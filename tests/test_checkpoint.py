@@ -3,8 +3,10 @@ reference, first-class here): sharded round-trips, stepped manager with
 retention, and bit-identical solver resume."""
 
 import dataclasses
+import functools
 import os
 import pathlib
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -545,3 +547,71 @@ def test_a_killed_run_resumes_from_its_last_acknowledged_save(tmp_path):
     whole, _, _ = sw.make_solver(cfg, m.MeshComm.from_mesh(mesh), num_multisteps=5)(t1)
     for name, want in whole._asdict().items():
         np.testing.assert_array_equal(resumed[name], np.asarray(want), err_msg=name)
+
+
+# -- one bound on every kind of copy to the host ------------------------------
+
+
+def test_a_host_bound_counts_every_kind_and_lets_an_oversized_copy_go_alone():
+    bound = ckpt.HostBound(1000)
+    assert bound.take("snapshot", 600) and not bound.take("save", 500)
+    assert bound.take("save", 400) and bound.in_flight == bound.peak == 1000
+    assert not bound.take("snapshot", 1) and bound.holder("snapshot") == "save"
+    bound.give("snapshot", 600)
+    bound.give("save", 400)
+    # alone over the bound: only with nothing of either kind in flight
+    assert bound.take("save", 10) and not bound.take("snapshot", 5000)
+    bound.give("save", 10)
+    assert bound.take("snapshot", 5000) and not bound.take("save", 1)
+    assert bound.peak == 5000 and bound.in_flight == 5000
+    bound.give("snapshot", 5000)
+    # no bound: whatever is asked
+    free = ckpt.HostBound()
+    assert free.take("save", 1 << 40) and free.take("snapshot", 1 << 40)
+
+
+def test_a_wait_for_room_is_served_before_later_copies_and_is_a_span():
+    """The save's thread, none of its pieces in flight, waits for room
+    that snapshots hold: a snapshot asked for meanwhile is refused, the
+    owner that waits in ``until`` hears of it, and the wait is recorded
+    with what held the copy back."""
+    from mpi4jax_tpu.utils.spans import Recorder
+
+    trace = Recorder()
+    bound = ckpt.HostBound(1000)
+    assert bound.take("snapshot", 800)
+    side = ckpt.Side(bound, "save", functools.partial(trace.span, "checkpoint/fetch_wait", key=7))
+    thread = threading.Thread(target=side.take, args=(400, True), daemon=True)
+    thread.start()
+    assert bound.until(lambda: False, "snapshot") is False  # the save waits for a snapshot's room
+    assert thread.is_alive() and not bound.take("snapshot", 100)
+    bound.give("snapshot", 800)
+    thread.join(30)
+    assert not thread.is_alive() and side.held == 400 and bound.in_flight == 400
+    (waited,) = trace.spans()
+    assert waited.name == "checkpoint/fetch_wait" and waited.key == 7
+    assert waited.counts == {"held_by": "snapshot", "bytes": 400}
+    assert side.waited_s == waited.seconds > 0
+    assert bound.take("snapshot", 100)  # nobody waits any more
+    side.close()
+    assert side.held == 0 and bound.in_flight == 100
+    # `until` returns once its caller's own condition holds, after a wake
+    done = threading.Event()
+    threading.Timer(0.05, lambda: (done.set(), bound.wake())).start()
+    assert bound.until(done.is_set, "snapshot") is True
+
+
+@pytest.mark.parametrize("most, at_once", [(None, 3), (1000, 3), (250, 2), (150, 1)])
+def test_to_host_keeps_its_pieces_under_the_bound_it_shares(most, at_once):
+    """``to_host(side=)``: beside another kind's 50 bytes the pieces go
+    as far as both bounds let them, the oldest always."""
+    _FakePiece.flying = _FakePiece.most = 0
+    _FakePiece.log = []
+    bound = ckpt.HostBound(most)
+    assert bound.take("snapshot", 50)
+    side = ckpt.Side(bound, "save", None)
+    pieces = [(f"p{k}", _FakePiece(k, 100)) for k in range(6)]
+    got = [name for name, _ in ckpt.to_host(pieces, 300, side=side)]
+    assert got == [f"p{k}" for k in range(6)]
+    assert _FakePiece.most == 100 * at_once and bound.peak == 50 + 100 * at_once
+    assert bound.in_flight == 50 and side.held == 0

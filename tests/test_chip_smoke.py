@@ -68,6 +68,19 @@ def test_solver_restart_check_tiny():
     assert "dropped after 3 and resumed" in out["compared"]
 
 
+def test_solver_output_restart_check_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    out = chip_smoke.solver_output_restart_check(
+        cfg, cpu, mesh_shapes=((2, 2), (1, 1)), coarsen=2, steps_per_call=5, calls=3)
+    one = 3 * 12 * 24 * 4
+    assert out["host_bound_bytes"] == 2 * one + one // 2
+    assert 2 * one <= out["host_in_flight_max_bytes"] <= out["host_bound_bytes"]
+    assert 0 < out["meshes_max_diff"] <= chip_smoke.TOL_SAME_ARITHMETIC
+    assert "dropped after 3 and resumed" in out["compared"]
+    assert "solver4.output_restart" in chip_smoke.GROUPS[4]["solver4"][1]
+
+
 @pytest.mark.parametrize("n", [1, 4, 8])
 def test_ops_check(n):
     assert chip_smoke.ops_check(jax.devices()[:n])["max_diff"] == 0.0

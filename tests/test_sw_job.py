@@ -3,10 +3,12 @@
 same on every mesh and schedule, donation on with output, delivery in
 order and at most ``lag`` late, and ``make_solver`` as a loop over it."""
 
+import collections
 import contextlib
 import pathlib
 import re
 import time
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -249,7 +251,7 @@ def test_copies_to_the_host_are_held_under_ahead_bytes(
         before = self._asked
         seconds = ask(self, cause)
         asked.extend(step for step, _ in list(self._pending)[before:self._asked])
-        assert self._asked <= at_once and self._asked_bytes == self._asked * one
+        assert self._asked <= at_once and self._copies.held == self._asked * one
         return seconds
 
     monkeypatch.setattr(sw.SolverJob, "_ask", counting)
@@ -262,7 +264,7 @@ def test_copies_to_the_host_are_held_under_ahead_bytes(
     assert asked == [11 + 10 * k for k in range(min(at_once + 2, 8))]
     job.drain()
     assert seen == asked == [11 + 10 * k for k in range(8)]
-    assert job._asked == job._asked_bytes == 0
+    assert job._asked == job._copies.held == 0
 
 
 def test_a_snapshot_taken_a_call_late_fails_the_comparison(seeded, reference):
@@ -714,3 +716,334 @@ def test_the_recorder_leaves_the_jobs_compiled_programs_as_they_are(seeded, tmp_
     assert "job/" not in "".join(texts[True])
     assert sw.SCOPE_PREFIX + "checkpoint" in texts[True][2]
     assert sw.SCOPE_PREFIX + "snapshot" in texts[True][1]
+
+
+# -- a job with both halves: one bound on what is on its way to the host ------
+
+
+ONE = 3 * (NY // COARSEN) * (NX // COARSEN) * 4  # a snapshot of h, u, v
+
+
+def _within(seconds, work):
+    """``work()`` on a thread of its own, which is then the job's loop's:
+    a deadlock fails the test instead of hanging the run."""
+    import threading
+
+    out = []
+
+    def run():
+        try:
+            out.append((work(), None))
+        except BaseException as error:  # handed to the test
+            out.append((None, error))
+
+    thread = threading.Thread(target=run, daemon=True)
+    thread.start()
+    thread.join(seconds)
+    assert not thread.is_alive(), f"still waiting after {seconds} s"
+    value, error = out[0]
+    if error is not None:
+        raise error
+    return value
+
+
+def _both(cfg, comm, directory, got, ahead, lag=3, every=2, keep=2):
+    """A job with snapshots and saves whose host takes ``ahead`` bytes:
+    given once, on either half."""
+    return sw.make_job(
+        cfg, comm, STEPS_A_CALL,
+        sw.Snapshot(coarsen=COARSEN, lag=lag, ahead_bytes=ahead),
+        lambda s, step: got.append((step, s)),
+        sw.Checkpoint(directory, every_calls=every, keep=keep))
+
+
+def _waits(job, name):
+    return [s for s in job.spans() if s.name == name]
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_job_with_both_halves_keeps_one_bound_that_bites(
+        seeded, tmp_path, mesh_shape):
+    """``host_bound``: snapshots' copies and a save's pieces together
+    never over the job's one figure, which here holds two snapshots and
+    not a piece beside them, so that the save's first piece has to wait
+    for a snapshot to be fetched; and the job's output and saves are
+    those of jobs that have one half each."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    bound = int(2.5 * ONE)
+    got = []
+    job = _both(cfg, comm, tmp_path / "run", got, bound, every=3)
+    assert job.ahead_bytes == job.checkpoint.ahead_bytes == bound
+    band = max((hi - lo) for plan in job._plan for lo, hi in plan)
+    piece = band * mesh_shape[0] * (NX + 4 * mesh_shape[1]) * 4
+    assert ONE < bound and piece <= bound // 2 and 2 * ONE + piece > bound
+
+    def run():
+        job.start(_state(cfg, comm, seeded))
+        job.advance(3)  # two snapshots asked for, the third waiting; a save started
+        deadline = time.monotonic() + 60
+        while job._host._first != sw.ckpt.SAVE and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert job._host._first == sw.ckpt.SAVE  # its first piece waits for room
+        job.advance(3)
+        job.drain()
+
+    _within(120, run)
+    stats = job.stats()
+    assert 2 * ONE <= stats["host_in_flight_max_bytes"] <= bound
+    assert job._host.in_flight == 0
+    held = _waits(job, "checkpoint/fetch_wait")
+    assert held and all(s.counts["held_by"] == "snapshot" and s.thread == "checkpoint-save"
+                        and s.key in (31, 61) for s in held)
+    assert all(s.counts["held_by"] == "save" for s in _waits(job, "job/ask_wait"))
+    assert stats["transfer_wait_s"] == pytest.approx(
+        _total(held) + _total(_waits(job, "job/ask_wait")), rel=1e-9)
+    assert stats["transfer_wait_s"] > 0
+    assert sum(r["fetch_wait_s"] for r in job.saves) == pytest.approx(_total(held))
+    # what it wrote and what it saved are a one-half job's
+    assert _steps_of(got) == [11 + 10 * k for k in range(6)] and stats["max_lag"] <= 3
+    assert [r["step"] for r in job.saves] == [31, 61]
+    _, alone = _run(cfg, comm, seeded, sw.Snapshot(coarsen=COARSEN), calls=6)
+    for (_, mine), (_, theirs) in zip(got, alone):
+        for k in FIELDS:
+            np.testing.assert_array_equal(mine[k], theirs[k])
+    fresh = sw.make_job(cfg, comm, STEPS_A_CALL)
+    assert fresh.resume(tmp_path / "run") == 61
+    for a, b in zip(fresh.state, job.state):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_copy_that_alone_is_over_the_bound_goes_alone(seeded, tmp_path, mesh_shape):
+    """Under a bound smaller than a snapshot every snapshot goes alone,
+    with nothing of either kind in flight, the most in flight is that
+    one copy, and the job still finishes: every snapshot, every save."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    bound = ONE // 2
+    got = []
+    job = _both(cfg, comm, tmp_path / "run", got, bound, lag=1, every=1)
+
+    def run():
+        job.start(_state(cfg, comm, seeded))
+        job.advance(5)
+        job.drain()
+
+    _within(120, run)
+    stats = job.stats()
+    assert stats["host_in_flight_max_bytes"] == ONE  # over the bound by that copy alone
+    assert _steps_of(got) == [11 + 10 * k for k in range(5)] and stats["max_lag"] <= 1
+    assert stats["saves_started"] == stats["saves_acknowledged"] == 5
+    # no piece was in flight beside a snapshot, nor a snapshot beside a piece
+    flights = sorted(
+        [(s.start_ns, +1, "snapshot") for s in _waits(job, "job/ask")]
+        + [(s.end_ns, -1, "snapshot") for s in _waits(job, "job/fetch")],)
+    pieces = [(s.start_ns, s.end_ns) for s in _waits(job, "checkpoint/fetch")]
+    asked_at = {s.key: s.start_ns for s in _waits(job, "job/ask")}
+    for fetch in _waits(job, "job/fetch"):
+        a, b = asked_at[fetch.key], fetch.end_ns
+        assert not any(a < end and start < b for start, end in pieces), fetch.key
+    assert flights
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_output_across_a_kill_and_a_resume_is_the_uninterrupted_jobs(
+        seeded, tmp_path, mesh_shape):
+    """``seamless_output``: a job with both halves under a bound that
+    bites, dropped after its fourth call with snapshots asked for and
+    undelivered; a new job resumed from the directory starts with no
+    snapshot pending, its first is of the resumed step plus one call,
+    and from there on its snapshots are the uninterrupted job's bit for
+    bit, as its state is."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    bound = int(2.5 * ONE)
+    _, whole_got = _run(cfg, comm, seeded, sw.Snapshot(coarsen=COARSEN), calls=7)
+    before, after = [], []
+
+    def run():
+        killed = _both(cfg, comm, tmp_path / "run", before, bound)
+        killed.start(_state(cfg, comm, seeded))
+        killed.advance(4)
+        killed._settle()  # the save of step 41 acknowledged; output still pending
+        # (the wait may have delivered some early: the save's pieces wanted their room)
+        assert killed._pending and _steps_of(before) + [
+            step for step, _ in killed._pending] == [11, 21, 31, 41]
+        del killed
+        resumed = _both(cfg, comm, tmp_path / "run", after, bound)
+        assert resumed.resume() == 41
+        assert not resumed._pending and resumed.snap is not None
+        assert resumed.stats()["host_in_flight_max_bytes"] == 0
+        resumed.advance(3)
+        resumed.drain()
+        return resumed
+
+    resumed = _within(120, run)
+    assert _steps_of(after) == [51, 61, 71] and resumed.step == 71
+    assert resumed.stats()["host_in_flight_max_bytes"] <= bound
+    for (step, mine), (at, theirs) in zip(after, whole_got[4:]):
+        assert step == at
+        for k in FIELDS:
+            np.testing.assert_array_equal(mine[k], theirs[k], err_msg=f"{k} at {step}")
+    # a snapshot one call off is another run's: the comparison sees it
+    assert np.abs(after[1][1]["h"] - whole_got[4][1]["h"]).max() > 1e-4
+
+
+def test_max_lag_holds_while_a_save_is_waited_for(seeded, tmp_path, monkeypatch):
+    """A save every call into a slow file: the loop waits for the save
+    before in every call, fetches no snapshot meanwhile, and still
+    hands every snapshot over in order, none more than ``lag`` late."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm((1, 1))
+    write_at = sw.ckpt.write_at
+
+    def slow(fd, offset, array, bounce):
+        time.sleep(0.002)
+        write_at(fd, offset, array, bounce)
+
+    monkeypatch.setattr(sw.ckpt, "write_at", slow)
+    got = []
+    job = _both(cfg, comm, tmp_path / "run", got, 4 * ONE, lag=2, every=1)
+
+    def run():
+        job.start(_state(cfg, comm, seeded))
+        job.advance(6)
+        assert len(job._pending) == 2
+        job.drain()
+
+    _within(120, run)
+    stats = job.stats()
+    assert stats["save_wait_s"] > 0.01 and len(_waits(job, "job/save_wait")) == 5
+    assert stats["max_lag"] == 2 and _steps_of(got) == [11 + 10 * k for k in range(6)]
+    assert stats["snapshots_delivered"] == stats["saves_acknowledged"] == 6
+    assert stats["host_in_flight_max_bytes"] <= 4 * ONE
+    # no snapshot was fetched inside a wait for a save
+    for wait in _waits(job, "job/save_wait"):
+        assert not any(wait.start_ns < s.start_ns < wait.end_ns
+                       for s in _waits(job, "job/fetch"))
+
+
+# what the loop's thread records over `advance(3); advance(2); drain()`
+# from a fresh start, as the job before PR 45 recorded it (made there)
+SNAPSHOT_ONLY = (
+    "job/drain job/enqueue job/enqueue job/ask job/enqueue job/enqueue "
+    "job/enqueue job/enqueue job/fetch job/ask job/callback job/advance "
+    "job/enqueue job/enqueue job/fetch job/ask job/callback job/enqueue "
+    "job/enqueue job/fetch job/ask job/callback job/advance job/fetch job/ask "
+    "job/callback job/fetch job/callback job/drain").split()
+CHECKPOINT_ONLY = (
+    "job/drain job/enqueue job/enqueue job/enqueue job/save_start job/save "
+    "job/enqueue job/advance job/enqueue job/save_wait job/enqueue "
+    "job/save_start job/save job/enqueue job/advance job/drain").split()
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_job_with_one_half_is_what_it_was(seeded, tmp_path, mesh_shape):
+    """A job with only a ``Snapshot`` or only a ``Checkpoint`` records
+    the span names it recorded before the two halves shared a bound,
+    never one of the two waits, and the three programs of a job with
+    both halves are, to the letter, those of the jobs with one."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    comm = _comm(mesh_shape)
+    snapshot = sw.Snapshot(coarsen=COARSEN, lag=2, ahead_bytes=2500)
+    checkpoint = sw.Checkpoint(tmp_path / "run", every_calls=2, ahead_bytes=4096)
+    halves = {
+        "snapshot": sw.make_job(cfg, comm, STEPS_A_CALL, snapshot, lambda s, k: None),
+        "checkpoint": sw.make_job(cfg, comm, STEPS_A_CALL, checkpoint=checkpoint)}
+    for job in halves.values():
+        job.start(_state(cfg, comm, seeded))
+        job.advance(3)
+        job.advance(2)
+        job.drain()
+        assert job.stats()["transfer_wait_s"] == 0
+    names = {}
+    for key, job in halves.items():
+        main = _named(job, "job/advance")[0].thread
+        names[key] = [s.name for s in job.spans() if s.thread == main]
+        assert not _waits(job, "job/ask_wait") + _waits(job, "checkpoint/fetch_wait")
+    assert names == {"snapshot": SNAPSHOT_ONLY, "checkpoint": CHECKPOINT_ONLY}
+    others = collections.Counter(
+        s.name for s in halves["checkpoint"].spans() if s.thread.startswith("checkpoint-"))
+    pieces = sum(len(plan) for plan in halves["checkpoint"]._plan)
+    assert others == {"checkpoint/save": 2, "checkpoint/commit": 2, "checkpoint/prune": 2,
+                      "checkpoint/fetch": 2 * pieces, "checkpoint/write": 2 * pieces}
+    assert halves["snapshot"].stats()["host_in_flight_max_bytes"] == ONE
+    assert 0 < halves["checkpoint"].stats()["host_in_flight_max_bytes"] <= 4096
+    # both halves, under the same figure: the same three programs
+    both = sw.make_job(cfg, comm, STEPS_A_CALL,
+                       replace(snapshot, ahead_bytes=4096), lambda s, k: None, checkpoint)
+    assert both._plan == halves["checkpoint"]._plan
+    state = halves["snapshot"].state
+    written = halves["snapshot"]._written()
+    for program, of, args in (("multi", "snapshot", (state,)), ("snap", "snapshot", written),
+                              ("multi", "checkpoint", (state,)), ("stage", "checkpoint", (state,))):
+        mine, theirs = (_without_callers(
+            getattr(job, program).lower(*args).compile().as_text())
+            for job in (both, halves[of]))
+        assert mine == theirs, (program, of)
+
+
+def test_make_solver_keeps_and_restarts_its_output(comm2d, tmp_path):
+    """``make_solver(on_chunk=, checkpoint_dir=)`` under a snapshot's
+    bound is the job with both halves: a run stopped half way and
+    restarted hands out, between its two legs, the snapshots of the run
+    that was never stopped."""
+    cfg = sw.SWConfig(ny=16, nx=32, ghost=2)
+    n = 5
+    t_half = cfg.dt * (1 + n) + cfg.dt * n * 2
+    t_full = t_half + cfg.dt * n * 3
+    seen = []
+    one = 3 * 8 * 16 * 4
+
+    def solver(**kw):
+        return sw.make_solver(
+            cfg, comm2d, num_multisteps=n,
+            snapshot=sw.Snapshot(coarsen=2, lag=2, ahead_bytes=2 * one),
+            on_chunk=lambda s, step: seen.append((step, {k: a.copy() for k, a in s.items()})),
+            checkpoint_every=2, **kw)
+
+    _within(120, lambda: solver(checkpoint_dir=tmp_path / "run")(t_half))
+    _within(120, lambda: solver(checkpoint_dir=tmp_path / "run")(t_full))
+    chained = list(seen)
+    del seen[:]
+    _within(120, lambda: solver()(t_full))
+    # the first leg ended on a chunk that was not saved: the second
+    # leg hands it out again, the same, and goes on
+    assert [s for s, _ in seen] == [1 + n * k for k in range(1, 7)]
+    assert [s for s, _ in chained] == [6, 11, 16, 16, 21, 26, 31]
+    whole = dict(seen)
+    for step, mine in chained:
+        for k in FIELDS:
+            np.testing.assert_array_equal(mine[k], whole[step][k])
+
+
+def test_the_example_animates_a_run_it_restarts(tmp_path, capsys):
+    """``--animate`` with ``--checkpoint-dir``: a short run writes its
+    frames and its saves; a longer rerun in the same directory resumes
+    from the newest save and goes on writing frames from there."""
+    pytest.importorskip("matplotlib")
+    import importlib.util
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "examples/shallow_water.py"
+    spec = importlib.util.spec_from_file_location("sw_example_restart", path)
+    example = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(example)
+    common = ["--check", "--force-cpu", "--mesh", "2", "2", "--multistep", "5",
+              "--coarsen", "2", "--checkpoint-dir", str(tmp_path / "run"),
+              "--checkpoint-every", "2"]
+
+    def frames(days, name):
+        example.main(common + ["--days", str(days), "--animate", str(tmp_path / name)])
+        said = capsys.readouterr().err
+        assert (tmp_path / name).stat().st_size > 0
+        return int(re.search(r"\((\d+) frames\)", said).group(1))
+
+    first = frames(0.01, "first.gif")
+    saved = sw.ckpt.Series(tmp_path / "run").latest()
+    assert first >= 3 and saved is not None
+    # twice as long, from the newest save on: the chunks after it
+    whole = 2 * first
+    again = frames(0.02, "again.gif")
+    assert again == whole - (saved - 1) // 5
+    assert sw.ckpt.Series(tmp_path / "run").latest() > saved

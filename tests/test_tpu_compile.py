@@ -674,3 +674,53 @@ def test_a_jobs_save_leaves_the_step_alone_and_its_staging_carries_its_scope(
                for o in table.values())
     assert unstage.memory_analysis().output_size_in_bytes >= 7204 * 14404 * 4
     assert unstage.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_job_with_both_halves_compiles_the_programs_of_its_halves(v5e, mesh_shape):
+    """``make_job`` with a snapshot and a checkpoint under one bound, at
+    the size of the benchmark cell ``sw-output-restart-1chip`` a chip:
+    its three programs are, instruction for instruction, those of the
+    jobs that have one half each (the one bound is the host's code, and
+    adds nothing to a program); the call's multistep still donates
+    through the kernel; and the bound the cell states holds two
+    snapshots and a piece, not three snapshots."""
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px])
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=7200 * py, nx=14400 * px, dx=1250.0, dy=1250.0, ghost=2)
+    bound = 160_000_000
+    snapshot = sw.Snapshot(coarsen=4, lag=4, ahead_bytes=bound)
+    checkpoint = sw.Checkpoint("/nowhere", every_calls=32, ahead_bytes=bound)
+    both = sw.make_job(cfg, comm, 10, snapshot, lambda *a: None, checkpoint)
+    written = sw.make_job(cfg, comm, 10, snapshot, lambda *a: None)
+    saved = sw.make_job(cfg, comm, 10, checkpoint=checkpoint)
+    assert both.ahead_bytes == both.checkpoint.ahead_bytes == bound
+    assert both._plan == saved._plan
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(sw.make_init(cfg, comm)))
+    fields = (state.h, state.u, state.v)
+
+    def instructions(program, *args):
+        """A program's instructions without where they were traced from."""
+        text = program.lower(*args).compile().as_text()
+        return text, [re.sub(r",? metadata=\{[^}]*\}", "", line)
+                      for line in text.splitlines() if " = " in line]
+
+    text, multi = instructions(both.multi, state)
+    assert multi == instructions(written.multi, state)[1] and len(multi) > 20
+    assert "input_output_alias" in text.split("ENTRY")[0]
+    assert not _copied(text, _kernels(text)["wide_step"][1])
+    assert instructions(both.snap, *fields)[1] == instructions(written.snap, *fields)[1]
+    assert instructions(both.stage, state)[1] == instructions(saved.stage, state)[1]
+    # what the bound holds, a chip's share each: copies of 77.76 MB and 4 MiB
+    one = 3 * 1800 * 3600 * 4
+    a_piece = max(hi - lo for rows in both._plan for lo, hi in rows) * 14404 * 4
+    assert one == 77_760_000 and a_piece <= sw.ckpt.PIECE_BYTES
+    if py * px == 1:
+        assert sum(len(rows) for rows in both._plan) == 606
+        assert 2 * one + a_piece <= bound < 3 * one
