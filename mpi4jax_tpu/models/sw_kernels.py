@@ -8,8 +8,8 @@ instead: every intermediate stays on the chip, and each of the six state
 arrays (``h``, ``u``, ``v`` and their tendencies) is read once and
 written once, in place: 12 passes over a field a walk, the least a walk
 can move.  On a mesh of one device a walk advances **two time steps**
-("Two steps a walk", below): 12 passes where two walks move 24, under
-which the kernel is bound by its vector work and no longer by HBM.
+("Two steps a walk", below): 12 passes where two walks move 24, which
+its vector work keeps up with to within a few per cent of HBM's pace.
 
 Schedule: three exchanges, not five
 -----------------------------------
@@ -96,6 +96,37 @@ field), round 2 reads three of them, and the blocks of ``u`` and ``v``
 are written two grid steps behind their input where the other four are
 written one behind; the grid is one step longer for it.  Without
 friction (``nu == 0``) the walk is the same with round 2 off.
+
+Each row's faces and corners once.  Round 1 at row ``g`` reads the
+eastward flux and the kinetic energy of rows ``g`` and ``g + 1``, and
+the northward flux and the vorticity of rows ``g`` and ``g - 1``.  A
+strip makes each **once a row**: the first two for the row *north* of
+each of its rows (from ``(c, n)`` of ``h``, ``u``, ``v``), the other two
+for the rows themselves.  A row's own flux and energy, and its southern
+neighbour's northward flux and vorticity (as the product with the flux
+that ``du`` takes of it), are then the same values one row down: seven
+rows by a rotation of sublanes, and the strip's first row from **the
+strip before**, whose four values the stage hands itself in VMEM (four
+strips a step's round 1, ``n_carried``; a walk runs the strips of a
+field in order, across its tiles).  There is no lag to it: the strip
+before has been through the same stage one iteration earlier, so a
+ring of three strips at a strip's lag (``ROADMAP.md`` S17's sketch)
+would hold two strips nobody reads, and blocks are written where they
+were.  The walls' zeros ride on the values: the flux and the energy of
+the row north are zeroed on the northern wall's row (where they belong
+to the ghost row beyond it), the northward flux there and on the
+southern wall's ghost row, the vorticity on that ghost row.  That is
+where the stages that evaluated each neighbour again zeroed them, but
+for a row's *own* values on the two ghost rows next to a wall, and
+those rows nothing updates.  The rows' masks, the Coriolis parameter
+and the strip's last-row mask are made on one vector register and laid
+across the strip (a ``concatenate`` of registers: names, no
+arithmetic), the columns' masks in the registers alone that hold a
+ghost column; powers of two are folded where that is exact (a quarter
+of the depth's sum under the division against the quarters of the sums
+that take the vorticity; two halves of a sum of halves).  The
+schedule's count is in ``PERF.md`` section 5: 142 bundles a vector
+register for two steps became 95.
 
 Two steps a walk, on one device
 ------------------------------
@@ -244,8 +275,8 @@ add, sub, mul, div, eq, select = (
     lax.add, lax.sub, lax.mul, lax.div, lax.eq, lax.select)
 
 
-def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
-          interpret):
+def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
+          steps=1, *, interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
@@ -262,21 +293,24 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
 
     ``body(roll, *scalar_refs)`` runs once a grid step and returns the
     two stages ``(first, second)`` of each of the walk's steps, a list
-    of pairs.  ``first(g, col, fields,
-    pointwise)`` is handed, for 8 rows: ``g`` and ``col``, each
-    element's row and column in the block; for each field ``(c, n, s)``,
-    the rows themselves and the rows north (``g + 1``) and south (``g -
-    1``) of them, all as they were before the call; and each pointwise
-    array's rows.  It returns the rows' new values, fields first.  The
-    last ``n_second`` fields' values are not final: ``second(g, col,
-    fresh)`` is handed ``(c, n, s)`` of each as ``first`` left them, a
-    tile later, and returns the rows' final values (``n_second`` 0: no
-    second stage).  East and west neighbours are lane rotations
-    (``roll``, which is ``pltpu.roll``, along axis 1 by the strip's
-    lanes less 1 and by 1), whose wrap lands in or past the outermost
-    ghost columns.  A stage
-    masks what it updates itself: what it returns for a ghost cell is
-    written too.
+    of pairs.  ``first(g, fields, pointwise, before)`` is handed, for 8
+    rows: ``g``, the rows' numbers in the block, on one vector register
+    (a row's number is the same in all its columns); for each field
+    ``(c, n)``, the rows themselves and the rows north (``g + 1``) of
+    them, all as they were before the call; each pointwise array's
+    rows; and ``before``, the ``n_carried`` strips it returned last for
+    the strip before (the 8 rows south; at a walk's first strip
+    whatever VMEM held, which only that strip's first row reads, a
+    ghost row).  It returns the rows' new values, fields first, and
+    then its ``n_carried`` strips for the strip after.  The last
+    ``n_second`` fields' values are not final: ``second(g, fresh)`` is
+    handed ``(c, n, s)`` of each (``s``: the rows south, ``g - 1``) as
+    ``first`` left them, a tile later, and returns the rows' final
+    values (``n_second`` 0: no second stage).  East and west neighbours
+    are lane rotations (``roll``, which is ``pltpu.roll``, along axis 1
+    by the strip's lanes less 1 and by 1), whose wrap lands in or past
+    the outermost ghost columns.  A stage masks what it updates itself:
+    what it returns for a ghost cell is written too.
 
     ``steps`` 2 (as many pointwise arrays as fields): two pairs of
     stages run in the one walk, the second pair a step's stages of
@@ -296,6 +330,7 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
     n_plain = n_fields - n_second
     rounds = 1 + (n_second > 0)  # stages a step
     n_stages = steps * rounds
+    n_kept = steps * n_carried
     tile = tile_rows(rows, width, dtype, n_fields + n_point, steps)
     tiles = -(-rows // tile)
     strips = tile // STRIP
@@ -323,19 +358,20 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
 
     def kernel(*refs):
         refs = iter(refs)
-        (scalar_refs, taken, *brought, old, out, new, windows, rings,
+        (scalar_refs, taken, *brought, old, out, new, windows, rings, kept,
          held_fields, held_point, rings_again) = (
             tuple(itertools.islice(refs, n)) for n in
             (n_scalars, n_fields, *n_slabs, n_point, n_fields, n_point,
-             n_fields, n_second,
+             n_fields, n_second, n_kept,
              *((n_fields, n_point, n_second) if steps > 1 else (0, 0, 0))))
         i = pl.program_id(0)
         applied = body(pltpu.roll, *scalar_refs)
 
-        shape = (STRIP, lanes)
-        r = lax.broadcasted_iota(jnp.int32, shape, 0)
-        col = lax.broadcasted_iota(jnp.int32, shape, 1)
-        top, bottom = eq(r, 0), eq(r, STRIP - 1)
+        # a strip's rows: on one vector register for the stages, which
+        # need a row's number once, and on the strip for its ends
+        r = lax.broadcasted_iota(jnp.int32, (STRIP, LANES), 0)
+        row = lax.broadcasted_iota(jnp.int32, (STRIP, lanes), 0)
+        top, bottom = eq(row, 0), eq(row, STRIP - 1)
         # the tile handed in: the walk's last steps are handed the
         # field's last again
         t = lax.min(i, tiles - 1)
@@ -371,14 +407,16 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
             """The rows of strip ``k`` of a block, a window or a ring."""
             return pl.ds(pl.multiple_of(mul(k, STRIP), STRIP), STRIP)
 
+        def northward(ref, c, below):
+            """``(c, n)`` of the rows ``c`` of ``ref``, a strip: the strip
+            below gives the row that a rotation of the strip lacks."""
+            c, below = ref[c, :], ref[below, :]
+            return c, pltpu.roll(select(top, below, c), STRIP - 1, 0)
+
         def around(ref, above, c, below):
-            """``(c, n, s)`` of the rows ``c`` of ``ref``, a strip: the
-            strips below and above give the row that a rotation of the
-            strip lacks."""
-            above, c, below = (ref[part, :] for part in (above, c, below))
-            n = pltpu.roll(select(top, below, c), STRIP - 1, 0)
-            s = pltpu.roll(select(bottom, above, c), 1, 0)
-            return c, n, s
+            """``(c, n, s)`` likewise, the last from the strip above."""
+            c, n = northward(ref, c, below)
+            return c, n, pltpu.roll(select(bottom, ref[above, :], c), 1, 0)
 
         # the lanes of a vector register that hold ghost columns, for
         # each stretch of them that `_ends_meet` fills from one place
@@ -428,7 +466,7 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
                     mine = sub(g, stage * tile) if stage else g
                     second_rings = rings_again if step else rings
                     if is_second:
-                        values = second(mine, col, [
+                        values = second(mine, [
                             around(ring, *(behind(slots, d) for d in (1, 2, 3)))
                             for ring in second_rings])
                         if last:
@@ -437,19 +475,20 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
                             stores = [(hand_on, ring, behind(n, 0)) for ring, n in zip(
                                 held_fields[n_plain:], field_slots[n_plain:])]
                     else:
+                        mine_kept = kept[step * n_carried:][:n_carried]
+                        before = [ref[...] for ref in mine_kept]
                         if step:
                             values = first(
-                                mine, col,
-                                [around(ring, *(behind(n, d) for d in (1, 2, 3)))
+                                mine,
+                                [northward(ring, *(behind(n, d) for d in (2, 3)))
                                  for ring, n in zip(held_fields, field_slots)],
                                 [ring[behind(point_slots, 1), :]
-                                 for ring in held_point])
+                                 for ring in held_point], before)
                         else:
                             values = first(
-                                mine, col,
-                                [around(win, here, north, beyond)
-                                 for win in windows],
-                                [ref[here, :] for ref in old])
+                                mine,
+                                [northward(win, north, beyond) for win in windows],
+                                [ref[here, :] for ref in old], before)
                         if last:
                             plain = [(put, ref, here) for ref in out[:n_plain]]
                             point = [(put, ref, here) for ref in new]
@@ -459,7 +498,8 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
                             point = [(put, ring, behind(point_slots, 0))
                                      for ring in held_point]
                         stores = plain + [(put, ring, behind(slots, 0))
-                                          for ring in second_rings] + point
+                                          for ring in second_rings] + point + [
+                            (put, ref, slice(None)) for ref in mine_kept]
                     for (store, ref, where), value in zip(stores, values):
                         store(ref, where, value)
                 return carry
@@ -518,7 +558,7 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, steps=1, *,
         out_shape=[struct] * (n_fields + n_point),
         scratch_shapes=(
             [pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_fields
-            + [ring(slots)] * n_second
+            + [ring(slots)] * n_second + [ring(1)] * n_kept
             + ([ring(n) for n in field_slots] + [ring(point_slots)] * n_point
                + [ring(slots)] * n_second if steps > 1 else [])),
         input_output_aliases={
@@ -569,21 +609,33 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
     taking before its own arguments the ``scalars`` a kernel reads from
     SMEM (:func:`wide_step` ``body``).  Jitted and kept: a process
     traces each once, for the kernel of a single walk and both
-    applications of a double one (a body's two hundred operations are
-    most of a kernel's trace, 0.1 s of a program's set-up on a chip's
-    host); in a kernel's text they are inlined.  ``roll``: as
+    applications of a double one (a body's hundred and twenty
+    operations are most of a kernel's trace); in a kernel's text they
+    are inlined.  ``roll``: as
     :func:`_walk` hands it to a body."""
     lanes = _whole_registers(width)  # of a strip in the kernel
     inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
     cx, cy = nu / dx, nu / dy
 
-    def half(x):
-        return mul(x, 0.5)
+    def across(register):
+        """A vector register's value in every register of a strip: names
+        registers, computes nothing."""
+        return lax.concatenate([register] * (lanes // LANES), 1)
 
-    def box(g, col, row_from, row_to, ring):
-        return functools.reduce(lax.bitwise_and, (
-            lax.ge(g, row_from), lax.lt(g, row_to),
-            lax.ge(col, G - ring), lax.lt(col, width - G + ring)))
+    def box(g, row_from, row_to, ring):
+        """Rows ``[row_from, row_to)`` of the block less its ghost
+        columns outside ``ring``: the rows' predicate, made on one
+        register, in every register, and the columns' in those alone
+        that hold a column outside (the first and the last of a row;
+        two a side where the ghost columns lie astride them)."""
+        rows = lax.bitwise_and(lax.ge(g, row_from), lax.lt(g, row_to))
+        lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+        lo, hi = G - ring, width - G + ring
+        return lax.concatenate([
+            rows if lo <= at and at + LANES <= hi else functools.reduce(
+                lax.bitwise_and,
+                (rows, lax.ge(lane, lo - at), lax.lt(lane, hi - at)))
+            for at in range(0, lanes, LANES)], 1)
 
     def east(x):
         return roll(x, lanes - 1, 1)
@@ -591,68 +643,84 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
     def west(x):
         return roll(x, 1, 1)
 
-    def first(scalars, g, col, fields, old):
+    def first(scalars, g, fields, old, before):
         """Round 1: the tendencies and the Adams-Bashforth update,
-        of ``h`` on the interior, of ``u`` and ``v`` on ring 1 too."""
+        of ``h`` on the interior, of ``u`` and ``v`` on ring 1 too.
+        What lives on a face or a corner is made once a row: the
+        fluxes, the energy and the vorticity's product with the flux are
+        returned after the rows' new values, for the strip after, which
+        is handed them as ``before`` and takes its first row's southern
+        (or own) values from them, its other rows' from its own by a
+        rotation of sublanes."""
         (a, b, first_row, south_ghost_row, north_wall_row,
          inner_from, inner_to, reach_from, reach_to) = scalars
-        (h, h_n, h_s), (u, u_n, u_s), (v, v_n, v_s) = fields
+        (h, h_n), (u, u_n), (v, v_n) = fields
         zero = lax.full(h.shape, 0, dtype)
-        interior = box(g, col, inner_from, inner_to, 0)
-        reach = box(g, col, reach_from, reach_to, 1)
+        interior = box(g, inner_from, inner_to, 0)
+        reach = box(g, reach_from, reach_to, 1)
         # the array code builds its ring-1 fields on every row and
         # zeroes them on the walls' ghost rows (the northward flux
         # on the northern wall's own row too); an interior row reads
         # those rows only as the last row under a northern wall and
-        # as the first over a southern one
-        north = eq(g, north_wall_row)
-        south = eq(sub(g, 1), south_ghost_row)
+        # as the first over a southern one.  So a row's values are
+        # zeroed where the row north of the northern wall's holds
+        # them or the southern wall's ghost row does: those rows
+        # themselves nothing updates
+        north = across(eq(g, north_wall_row))
+        wall = across(lax.bitwise_or(
+            eq(g, north_wall_row), eq(g, south_ghost_row)))
+        south = across(eq(g, south_ghost_row))
+        last = across(eq(
+            lax.broadcasted_iota(jnp.int32, g.shape, 0), STRIP - 1))
 
-        def unless(wall, x):
-            return select(wall, zero, x)
+        def south_of(x, before):
+            """The rows south of ``x``'s: its own but for the first,
+            which is the last of ``before``, the strip before."""
+            return roll(select(last, before, x), 1, 0)
+
+        def unless(where, x):
+            return select(where, zero, x)
 
         # mean depths: twice the one on the eastern face, on this
-        # row and its neighbours; the array code's hc is h with the
-        # walls' ghost rows set to the wall's row, and of those the
-        # interior reads the northern one alone, in q's depth
+        # row and the one north of it; the array code's hc is h with
+        # the walls' ghost rows set to the wall's row, and of those
+        # the interior reads the northern one alone, in q's depth
         h_e = east(h)
-        hx, hx_n, hx_s = add(h, h_e), add(h_n, east(h_n)), add(h_s, east(h_s))
-        # mass fluxes through the eastern and the northern face
-        fe = mul(half(hx), u)
-        fe_n = unless(north, mul(half(hx_n), u_n))
-        fn = unless(north, mul(half(add(h, h_n)), v))
-        fn_s = unless(south, mul(half(add(h_s, h)), v_s))
+        hx, hx_n = add(h, h_e), add(h_n, east(h_n))
+        # mass fluxes through the eastern face, of the row north and
+        # (from it) of this row, and through the northern face, of this
+        # row and of the one south of it
+        fe_n = unless(north, mul(mul(hx_n, 0.5), u_n))
+        fn = unless(wall, mul(mul(add(h, h_n), 0.5), v))
+        before_fe, before_fn, before_qf, before_ke = before
+        fe, fn_s = south_of(fe_n, before_fe), south_of(fn, before_fn)
 
-        def vorticity(row, v, v_e, u_n, u, depth4):
-            """Potential vorticity at a row's north-eastern corners."""
-            y = mul(add(lax.convert_element_type(row, dtype), first_row), dy)
-            planetary = add(mul(y, coriolis_beta), coriolis_f)
-            relative = sub(mul(sub(v_e, v), inv_dx), mul(sub(u_n, u), inv_dy))
-            return div(add(planetary, relative), mul(depth4, 0.25))
+        # potential vorticity at the row's north-eastern corners, a
+        # quarter of it: the depth is four times the mean depth there,
+        # and the tendencies take a quarter of their sums instead
+        y = mul(add(lax.convert_element_type(sub(g, G), dtype), first_row), dy)
+        planetary = across(add(mul(y, coriolis_beta), coriolis_f))
+        relative = sub(mul(sub(east(v), v), inv_dx), mul(sub(u_n, u), inv_dy))
+        q = unless(south, div(
+            add(planetary, relative), add(hx, select(north, hx, hx_n))))
+        # what du takes of it, on this row and the one south, and dv,
+        # at this corner and the one west
+        qf = mul(q, add(fn, east(fn)))
+        qf_s = south_of(qf, before_qf)
+        qe = mul(q, add(fe, fe_n))
 
-        q = vorticity(sub(g, G), v, east(v), u_n, u,
-                      add(hx, select(north, hx, hx_n)))
-        q_s = unless(south, vorticity(
-            sub(g, G + 1), v_s, east(v_s), u, u_s, add(hx_s, hx)))
-
-        # kinetic energy at the cell
-        uu, vv = mul(u, u), mul(v, v)
+        # kinetic energy at the cell, of the row north and of this row
         uu_n = mul(u_n, u_n)
-        ke = half(add(half(add(uu, west(uu))), half(add(vv, mul(v_s, v_s)))))
-        ke_n = unless(north, half(add(
-            half(add(uu_n, west(uu_n))), half(add(mul(v_n, v_n), vv)))))
+        ke_n = unless(north, mul(add(
+            add(uu_n, west(uu_n)), add(mul(v_n, v_n), mul(v, v))), 0.25))
+        ke = south_of(ke_n, before_ke)
 
-        fe_w = west(fe)
-        dh_new = sub(mul(sub(fe_w, fe), inv_dx), mul(sub(fn, fn_s), inv_dy))
+        dh_new = sub(mul(sub(west(fe), fe), inv_dx), mul(sub(fn, fn_s), inv_dy))
         du_new = sub(
-            add(mul(sub(h_e, h), -gravity * inv_dx),
-                half(add(mul(q, half(add(fn, east(fn)))),
-                         mul(q_s, half(add(fn_s, east(fn_s))))))),
+            add(mul(sub(h_e, h), -gravity * inv_dx), add(qf, qf_s)),
             mul(sub(east(ke), ke), inv_dx))
         dv_new = sub(
-            sub(mul(sub(h_n, h), -gravity * inv_dy),
-                half(add(mul(q, half(add(fe, fe_n))),
-                         mul(west(q), half(add(fe_w, west(fe_n))))))),
+            sub(mul(sub(h_n, h), -gravity * inv_dy), add(qe, west(qe))),
             mul(sub(ke_n, ke), inv_dy))
 
         def stepped(where, x, new, old):
@@ -664,30 +732,30 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
         h, dh_new = stepped(interior, h, dh_new, dh_old)
         u, du_new = stepped(reach, u, du_new, du_old)
         v, dv_new = stepped(reach, v, dv_new, dv_old)
-        return h, u, unless(north, v), dh_new, du_new, dv_new
+        return (h, u, unless(north, v), dh_new, du_new, dv_new,
+                fe_n, fn, qf, ke_n)
 
-    def second(scalars, g, col, fresh):
+    def second(scalars, g, fresh):
         """Round 2: lateral friction of round 1's ``u`` and ``v``."""
         _, _, _, south_ghost_row, north_wall_row, inner_from, inner_to, _, _ = scalars
-        zero = lax.full(g.shape, 0, dtype)
-        interior = box(g, col, inner_from, inner_to, 0)
+        interior = box(g, inner_from, inner_to, 0)
+        zero = lax.full(interior.shape, 0, dtype)
         # of the rows the array code zeroes in the y gradient, an
         # interior cell reads one: the southern wall's ghost row
-        south_is_wall = eq(sub(g, 1), south_ghost_row)
+        south_is_wall = across(eq(sub(g, 1), south_ghost_row))
 
         def friction(c, n, s):
-            e, w = east(c), west(c)
             # the gradients at the cell, and west and south of it
-            gx, gx_w = mul(sub(e, c), cx), mul(sub(c, w), cx)
+            gx = mul(sub(east(c), c), cx)
             gy = mul(sub(n, c), cy)
             gy_s = select(south_is_wall, zero, mul(sub(c, s), cy))
-            inc = mul(add(mul(sub(gx, gx_w), inv_dx),
+            inc = mul(add(mul(sub(gx, west(gx)), inv_dx),
                           mul(sub(gy, gy_s), inv_dy)), dt)
             return add(c, select(interior, inc, zero))
 
         u, v = fresh
         return friction(*u), select(
-            eq(g, north_wall_row), zero, friction(*v))
+            across(eq(g, north_wall_row)), zero, friction(*v))
 
     return jax.jit(first), jax.jit(second)
 
@@ -789,4 +857,4 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     flags = [is_south, is_north] + [lone] * (steps == 2)
     return _walk(body, [jnp.stack(flags).astype(jnp.int32), floats], [h, u, v],
                  slabs, [dh, du, dv], n_second=2 if nu > 0 else 0,
-                 steps=steps, interpret=interpret)
+                 n_carried=4, steps=steps, interpret=interpret)

@@ -349,6 +349,191 @@ def test_a_walk_of_two_steps_is_two_walks_of_one_bit_for_bit(
         np.asarray(got[0])[:, :G], np.asarray(between[0])[:, -2 * G:-G])
 
 
+def _as_first_written(cfg, g, col, south, north, fields, old, a, b):
+    """One step of the kernel by the formulae its stages had before each
+    row's faces and corners were made once (PR 47): every value of the
+    row north and of the row south evaluated again from ``(c, n, s)``,
+    the halves where they stood, on the whole block at once (``g``,
+    ``col``: each cell's row and column).  Plain ``jax.numpy``; a
+    shift's wrap lands in the outermost ghost ring, which no mask
+    admits."""
+    dtype = jnp.float32
+    inv_dx, inv_dy = 1.0 / cfg.dx, 1.0 / cfg.dy
+    cx, cy = cfg.nu / cfg.dx, cfg.nu / cfg.dy
+    rows, width = g.shape
+    south_ghost_row = jnp.where(south, G - 1, -1)
+    north_wall_row = jnp.where(north, rows - G - 1, -1)
+    reach_from = jnp.where(south, G, G - 1)
+    reach_to = jnp.where(north, rows - G, rows - G + 1)
+
+    def box(row_from, row_to, ring):
+        return ((g >= row_from) & (g < row_to)
+                & (col >= G - ring) & (col < width - G + ring))
+
+    def around(x):
+        return x, jnp.roll(x, -1, 0), jnp.roll(x, 1, 0)
+
+    def east(x):
+        return jnp.roll(x, -1, 1)
+
+    def west(x):
+        return jnp.roll(x, 1, 1)
+
+    def half(x):
+        return x * dtype(0.5)
+
+    (h, h_n, h_s), (u, u_n, u_s), (v, v_n, v_s) = map(around, fields)
+    zero = jnp.zeros((rows, width), dtype)
+    interior, reach = box(G, rows - G, 0), box(reach_from, reach_to, 1)
+    at_north, at_south = g == north_wall_row, g - 1 == south_ghost_row
+
+    def unless(wall, x):
+        return jnp.where(wall, zero, x)
+
+    h_e = east(h)
+    hx, hx_n, hx_s = h + h_e, h_n + east(h_n), h_s + east(h_s)
+    fe = half(hx) * u
+    fe_n = unless(at_north, half(hx_n) * u_n)
+    fn = unless(at_north, half(h + h_n) * v)
+    fn_s = unless(at_south, half(h_s + h) * v_s)
+
+    def vorticity(row, v, v_e, u_n, u, depth4):
+        y = (row.astype(dtype) + dtype(0)) * dtype(cfg.dy)
+        planetary = y * dtype(cfg.coriolis_beta) + dtype(cfg.coriolis_f)
+        relative = (v_e - v) * dtype(inv_dx) - (u_n - u) * dtype(inv_dy)
+        return (planetary + relative) / (depth4 * dtype(0.25))
+
+    q = vorticity(g - G, v, east(v), u_n, u, hx + jnp.where(at_north, hx, hx_n))
+    q_s = unless(at_south, vorticity(
+        g - (G + 1), v_s, east(v_s), u, u_s, hx_s + hx))
+    uu, vv, uu_n = u * u, v * v, u_n * u_n
+    ke = half(half(uu + west(uu)) + half(vv + v_s * v_s))
+    ke_n = unless(at_north, half(half(uu_n + west(uu_n)) + half(v_n * v_n + vv)))
+    fe_w = west(fe)
+    dh_new = (fe_w - fe) * dtype(inv_dx) - (fn - fn_s) * dtype(inv_dy)
+    du_new = (((h_e - h) * dtype(-cfg.gravity * inv_dx)
+               + half(q * half(fn + east(fn)) + q_s * half(fn_s + east(fn_s))))
+              - (east(ke) - ke) * dtype(inv_dx))
+    dv_new = (((h_n - h) * dtype(-cfg.gravity * inv_dy)
+               - half(q * half(fe + fe_n) + west(q) * half(fe_w + west(fe_n))))
+              - (ke_n - ke) * dtype(inv_dy))
+
+    def stepped(where, x, new, old):
+        new = jnp.where(where, new, zero)
+        inc = jnp.where(
+            where, (new * dtype(a) + old * dtype(b)) * dtype(cfg.dt), zero)
+        return x + inc, new
+
+    h, dh_new = stepped(interior, h, dh_new, old[0])
+    u, du_new = stepped(reach, u, du_new, old[1])
+    v, dv_new = stepped(reach, v, dv_new, old[2])
+    v = unless(at_north, v)
+    if cfg.nu > 0:
+        def friction(x):
+            c, n, s = around(x)
+            e, w = east(c), west(c)
+            gx, gx_w = (e - c) * dtype(cx), (c - w) * dtype(cx)
+            gy = (n - c) * dtype(cy)
+            gy_s = jnp.where(at_south, zero, (c - s) * dtype(cy))
+            inc = ((gx - gx_w) * dtype(inv_dx)
+                   + (gy - gy_s) * dtype(inv_dy)) * dtype(cfg.dt)
+            return c + jnp.where(interior, inc, zero)
+
+        u, v = friction(u), unless(at_north, friction(v))
+    return h, u, v, dh_new, du_new, dv_new
+
+
+@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
+@pytest.mark.parametrize("walls", ["both", "neither"])
+@pytest.mark.parametrize("shape", ["astride-33x129", "ragged-52x100"])
+def test_a_rows_values_made_once_are_the_values_made_twice_bit_for_bit(
+        shape, walls, nu, monkeypatch):
+    """The stages make what lives on a face or a corner once a row and
+    take the neighbouring row's from the strip before or by a rotation
+    of sublanes, with the powers of two folded: the same roundings of
+    the same values as the formulae that evaluated each neighbour again,
+    so the whole padded block of all six arrays comes back bit for bit
+    (where a zeroed row's value differs, nothing is updated from it)."""
+    rows, width = _budget(monkeypatch, shape)
+    south, north = WALLS[walls]
+    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
+    keys = jax.random.split(jax.random.PRNGKey(4), 6)
+    fields = [
+        mean + spread * jax.random.normal(key, (rows, width), jnp.float32)
+        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
+    inner = ~(_ring((rows, width), 1) | _ring((rows, width), 2))
+    old = [jnp.where(inner, 0.5 * jax.random.normal(key, (rows, width)), 0)
+           for key in keys[3:]]
+    a, b = cfg.ab_a, cfg.ab_b
+    plain = {"xla_backend_optimization_level": 0}  # as the walks' test
+    # the block's rows, columns and walls are the programs' arguments,
+    # so that neither folds a mask away and rounds otherwise for it
+    g, col = jnp.indices((rows, width), dtype=jnp.int32)
+    want = jax.jit(
+        functools.partial(_as_first_written, cfg), static_argnums=(6, 7),
+        compiler_options=plain)(g, col, south, north, fields, old, a, b)
+    got = jax.jit(
+        lambda fields, old, south, north: sw_kernels.wide_step(
+            *fields, *old, ((None,) * 4,) * 3, south, north, 0, a, b,
+            **_interpreted(cfg)),
+        compiler_options=plain)(fields, old, jnp.bool_(south), jnp.bool_(north))
+    for name, x0, x, y in zip(sw.SWState._fields, [*fields, *old], got, want):
+        x0, x, y = np.asarray(x0), np.asarray(x), np.asarray(y)
+        assert np.isfinite(y).all(), name
+        assert np.abs(y - x0)[inner].max() > 0.01, name
+        np.testing.assert_array_equal(x, y, err_msg=name)
+
+
+def _vector_primitives(jaxpr, shape):
+    """The equations of ``jaxpr`` (those of its inner jaxprs with them)
+    that compute a value of ``shape``, counted by primitive; laying one
+    register's value across a strip computes nothing and is left out."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        inner = [x.jaxpr for x in eqn.params.values() if hasattr(x, "jaxpr")]
+        for sub in inner:
+            for name, n in _vector_primitives(sub, shape).items():
+                found[name] = found.get(name, 0) + n
+        name = eqn.primitive.name
+        if not inner and name not in ("concatenate", "broadcast_in_dim") and any(
+                getattr(x.aval, "shape", None) == shape for x in eqn.outvars):
+            found[name] = found.get(name, 0) + 1
+    return found
+
+
+def test_a_strips_stages_make_no_value_twice():
+    """The vector work of one strip's two stages at the benchmark cells'
+    width, counted on the CPU from their jaxprs: operations on a whole
+    strip of 113 vector registers.  200 before PR 47 (round 1 151: two
+    divisions, 13 lane rotations, 54 products; round 2 49), when every
+    face's and corner's value was made for its own row and again for the
+    neighbour's, every mask on the whole strip and every factor of a
+    half where it stood.  An edit that puts a second evaluation back
+    moves these numbers: say why with the new ones."""
+    _, pltpu = sw_kernels.pallas()
+    width = 14404
+    lanes = sw_kernels._whole_registers(width)
+    first, second = sw_kernels._stages(
+        pltpu.roll, 7204, width, jnp.dtype(jnp.float32), 0.2, 1250.0, 1250.0,
+        2.0, 9.81, 2e-4, 2e-11)
+    strip = jax.ShapeDtypeStruct((sw_kernels.STRIP, lanes), jnp.float32)
+    g = jax.ShapeDtypeStruct((sw_kernels.STRIP, sw_kernels.LANES), jnp.int32)
+    scalars = (*(jnp.float32(x) for x in (1.6, -0.6, 0.0)),
+               *(jnp.int32(x) for x in (1, 7201, 2, 7202, 2, 7202)))
+    round1 = jax.make_jaxpr(
+        lambda g, *x: first(scalars, g, [x[0:2], x[2:4], x[4:6]], x[6:9], x[9:]))(
+            g, *[strip] * 13)
+    round2 = jax.make_jaxpr(
+        lambda g, *x: second(scalars, g, [x[0:3], x[3:6]]))(g, *[strip] * 6)
+    round1, round2 = (
+        _vector_primitives(x.jaxpr, strip.shape) for x in (round1, round2))
+    assert round1 == {"add": 19, "sub": 13, "mul": 27, "div": 1,
+                      "select_n": 16, "roll": 12}, round1
+    assert round2 == {"add": 4, "sub": 10, "mul": 12, "select_n": 5,
+                      "roll": 4}, round2
+    assert sum(round1.values()) + sum(round2.values()) == 88 + 35
+
+
 def _exchanges_a_step(multistep, state):
     """The halo exchanges in the traced one-step program, with their
     ghost writes or without."""

@@ -194,6 +194,49 @@ def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     assert not _copied(texts[0], first["wide_step"][1]), first["wide_step"][1]
 
 
+def _scoped_vmem(line):
+    """``(used, limit)`` of a compiled Pallas call's line: the bytes of
+    VMEM the TPU compiler laid out for it (blocks, scratch and its own
+    spills) and the most it was allowed (``None``: the compiler's own)."""
+    size = r'scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"'
+    used, = re.findall('"used_' + size, line)
+    limit = re.findall('"' + size, line)
+    return int(used), int(limit[0]) if limit else None
+
+
+# the VMEM the compiler laid out for the step's kernel at 1800x3600 a
+# chip.  PR 47 (a row's fluxes, energy and vorticity product made once
+# and kept for the strip after: four strips of 29 vector registers more
+# a step in scratch, fewer 113-register values alive at once and so
+# fewer spilled): (1, 1), the walk of two steps, 58 318 848 before, up
+# by 188 416 (eight strips of 118 784 bytes in, 761 856 of spills out);
+# (2, 2), the walk of one, 89 575 424 before, down by 622 592 (four
+# strips in, 1 097 728 of spills out)
+KERNEL_VMEM = {(1, 1): 58_507_264, (2, 2): 88_952_832}
+
+
+@pytest.mark.parametrize("mesh_shape", sorted(KERNEL_VMEM))
+def test_the_steps_kernel_takes_the_vmem_it_took(v5e, mesh_shape):
+    text = _compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
+    (line, *_), = _kernel_calls(text)
+    assert _scoped_vmem(line)[0] == KERNEL_VMEM[mesh_shape]
+
+
+def test_the_cells_kernel_keeps_its_tiles_under_its_vmem_limit(v5e):
+    """At the benchmark cells' 7204 x 14404 the walk of two steps keeps
+    tiles of 24 rows (16 cost the step 1 %, PERF.md, PR 41) with the
+    strips of row values PR 47 keeps beside its rings, and what the
+    compiler lays out for the call stays under the limit it is given."""
+    from mpi4jax_tpu.models import sw_kernels
+
+    assert sw_kernels.tile_rows(7204, 14404, jnp.float32, 6, steps=2) == 24
+    text = _compiled_multistep(v5e, (1, 1), 2, 7200, 14400, 10).as_text()
+    (line, *_), = _kernel_calls(text)
+    used, limit = _scoped_vmem(line)
+    assert limit == sw_kernels._VMEM_LIMIT * sw_kernels._buffers(2) // 5
+    assert 60e6 < used < limit
+
+
 def _computation(text, name):
     """A computation of a compiled program's text: ``(instructions,
     types)``, the instructions as ``(name, opcode, operand names, line)``
