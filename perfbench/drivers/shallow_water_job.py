@@ -22,6 +22,11 @@ through ``Session.traced_programs``, which leaves a cut one out.  A call
 is the programs the job holds (``Session.programs``): a job without a
 snapshot program of its own is a call of one program, and the trace's
 count of modules decides nothing by itself.
+
+A program's compiled text is asked for with what the job handed that
+program (``Handed``, ``watch``, ``text_of``): a job whose last step
+writes coarse sums hands its snapshot program those, not ``h, u, v``,
+and a reader has to read the program that ran.
 """
 
 import collections
@@ -37,6 +42,55 @@ from perfbench.harness.trace import Trace
 plain = files.load_module("drivers", "shallow_water")
 FIELDS = plain.FIELDS
 MULTI, SNAPSHOT = "multistep", "snapshot"  # the keys of a call's programs
+# a program's key and the job's name for it: `job.multi`, and `job._multi`
+# for what its loop calls (the same, or its executable after `job.compile()`)
+PROGRAMS = {MULTI: "multi", SNAPSHOT: "snap", "stage": "stage"}
+
+
+class Handed:
+    """A program as the job's loop calls it, and the shapes, dtypes and
+    shardings of what it was handed at its first call."""
+
+    def __init__(self, program):
+        self.program, self.handed = program, None
+
+    def __call__(self, *args):
+        if self.handed is None:
+            self.handed = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+                args)
+        return self.program(*args)
+
+    def __getattr__(self, name):  # whatever else the job asks of its program
+        return getattr(self.program, name)
+
+
+def watch(job):
+    """From here on, what ``job``'s loop hands each of its programs at
+    the program's first call is kept for ``text_of``."""
+    for name in PROGRAMS.values():
+        called = getattr(job, "_" + name, None)
+        if called is not None and not isinstance(called, Handed):
+            setattr(job, "_" + name, Handed(called))
+
+
+def text_of(job, key):
+    """The compiled text of ``job``'s program ``key``, lowered with what
+    the job handed it (``watch``).  A program the loop has not called
+    yet, or calls through a name this file does not know, is lowered
+    with the state, or the written fields, by assumption, and that is
+    said."""
+    program = getattr(job, PROGRAMS[key])
+    if program is None:
+        raise KeyError(f"the job holds no {key!r} program")
+    handed = getattr(getattr(job, "_" + PROGRAMS[key], None), "handed", None)
+    if handed is None:
+        print(f"perfbench: the job was not seen handing its {key!r} program "
+              "anything: its text is lowered with the state at hand, by "
+              "assumption", flush=True)
+        handed = (tuple(getattr(job.state, k) for k in FIELDS)
+                  if key == SNAPSHOT else (job.state,))
+    return program.lower(*handed).compile().as_text()
 
 
 class Session:
@@ -86,6 +140,7 @@ class Session:
             sw.Snapshot(fields=tuple(output["fields"]), coarsen=self.coarsen,
                         lag=self.lag, ahead_bytes=output["ahead_bytes"]),
             self._on_chunk)
+        watch(self.job)
         self.modes = plain.mode_table(
             ctx.seed, ctx.config["assumed"]["perturbation"])
         spec = jax.P("y", "x")
@@ -209,17 +264,12 @@ class Session:
         return whole, executions[:-1]
 
     def compiled_text(self, key):
-        """The text of one of the call's programs as compiled for the
-        state at hand (what ``harness/scopes.py attribute`` and
-        ``signature`` read), compiled once however many readers ask."""
+        """The text of one of the call's programs as compiled for what
+        the job handed it in the window (what ``harness/scopes.py
+        attribute`` and ``signature`` read), compiled once however many
+        readers ask."""
         if key not in self._texts:
-            job = self.job
-            written = tuple(getattr(job.state, k) for k in FIELDS)
-            program, args = {MULTI: (job.multi, (job.state,)),
-                             SNAPSHOT: (job.snap, written)}[key]
-            if program is None:
-                raise KeyError(f"the job holds no {key!r} program")
-            self._texts[key] = program.lower(*args).compile().as_text()
+            self._texts[key] = text_of(self.job, key)
         return self._texts[key]
 
     # -- after the window ----------------------------------------------

@@ -28,9 +28,10 @@ executions with a staging program among the snapshots, the checks of
 A batch is ``reps`` calls, each followed by its snapshot, and one sync
 on the last call's state; after every ``restart.every_calls``-th call
 of the integration the job starts a save.  The job resumed in set-up
-stands after call 1, so with ``trace_batches`` 8 (32 calls) a traced
-window holds 32 snapshots and exactly one save's staging program,
-whole, between the third and the fourth call of its last batch; the
+stands after call 1, so in the cell, 48 calls a save and
+``trace_batches`` 12 (48 calls), a traced window holds 48 snapshots and
+exactly one save's staging program, whole, between the third and the
+fourth call of its last batch; the
 window's last snapshot may be cut by the profiler's stop and is then
 left out, as in the job cell.
 """
@@ -46,7 +47,7 @@ from perfbench.harness import files
 from perfbench.harness.trace import Trace
 
 restart = files.load_module("drivers", "shallow_water_restart")
-output = files.load_module("drivers", "shallow_water_job")
+output = restart.job_driver  # drivers/shallow_water_job.py, loaded once
 FIELDS = restart.FIELDS
 MULTI, SNAPSHOT, STAGE = restart.MULTI, output.SNAPSHOT, restart.STAGE
 PEAK = "host_in_flight_max_bytes"  # the counter `host_bound` is read from
@@ -146,14 +147,6 @@ class Session(restart.Session):
         print("perfbench: the profiler stopped inside the window's last "
               "snapshot: the readers leave that execution out", flush=True)
         return whole, executions[:-1]
-
-    def compiled_text(self, key):
-        if key != SNAPSHOT:
-            return super().compiled_text(key)
-        if key not in self._texts:
-            written = tuple(getattr(self.job.state, k) for k in FIELDS)
-            self._texts[key] = self.job.snap.lower(*written).compile().as_text()
-        return self._texts[key]
 
     # -- after the window ----------------------------------------------
 

@@ -23,9 +23,10 @@ integration, and the copies to the host, the files and the commit pass
 beside the next batches.
 
 The job resumed in set-up stands after call 1, so the first save of a
-window falls after its 31st call: with ``trace_batches`` 8 (32 calls) a
-traced window holds exactly one save's staging program, whole, between
-the third and the fourth call of its last batch.
+window falls after its ``every_calls - 1``-th call: in the cell, 48
+calls a save and ``trace_batches`` 12 (48 calls), a traced window holds
+exactly one save's staging program, whole, between the third and the
+fourth call of its last batch.
 """
 
 import dataclasses
@@ -38,12 +39,12 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from perfbench.harness import files, stats
 from perfbench.harness.spans import ENQUEUE, SYNC, span
 
 plain = files.load_module("drivers", "shallow_water")
+job_driver = files.load_module("drivers", "shallow_water_job")  # `watch`, `text_of`
 FIELDS = plain.FIELDS
 MULTI, STAGE = "multistep", "stage"  # the programs of a window, by key
 STATE = ("h", "u", "v", "dh", "du", "dv")
@@ -159,6 +160,7 @@ class Session:
         resumed = self.job.resume()
         jax.block_until_ready(self.job.state)
         self.resume_s = time.perf_counter() - t_kill
+        job_driver.watch(self.job)
         self.at_setup = self.job.stats()
         self.calls_at_setup = self.job.calls
         print(f"perfbench: resumed from step {resumed} in {self.resume_s:.3f} s "
@@ -261,11 +263,11 @@ class Session:
         return trace, executions
 
     def compiled_text(self, key):
-        """The text of one of the window's programs as compiled for the
-        state at hand, compiled once however many readers ask."""
+        """The text of one of the window's programs as compiled for what
+        the job handed it in the window (``drivers/shallow_water_job.py
+        text_of``), compiled once however many readers ask."""
         if key not in self._texts:
-            program = {MULTI: self.job.multi, STAGE: self.job.stage}[key]
-            self._texts[key] = program.lower(self.job.state).compile().as_text()
+            self._texts[key] = job_driver.text_of(self.job, key)
         return self._texts[key]
 
     # -- after the window ----------------------------------------------

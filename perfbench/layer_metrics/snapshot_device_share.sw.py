@@ -37,6 +37,48 @@ def periods(events):
             for a, b in zip(starts, starts[1:] + [len(events)])]
 
 
+def whole_calls(session, placed):
+    """``(by_program, of_kernels)`` from the executions of a traced
+    window (``scopes.by_execution`` of ``session.traced_programs``'s
+    pair): the device ns of each program of a call (the union of its
+    leaf events, a mean over the calls the trace holds whole), by key
+    and in the call's order, and every multistep execution's
+    ``periods``.  ``None`` for the first, with the reason printed,
+    where the trace holds no whole call."""
+    keys = session.programs()
+    of_programs, of_kernels = [], []
+    for of_chip in placed.values():
+        starts = [i for i, (key, _) in enumerate(of_chip) if key == MULTI]
+        for a, b in zip(starts, starts[1:] + [len(of_chip)]):
+            if b - a == len(keys):  # a last call without its cut snapshot is not whole
+                of_programs.append([trace.union_ns(events)
+                                    for _, events in of_chip[a:b]])
+            of_kernels.append(periods(of_chip[a][1]))
+    if not of_programs:
+        print("perfbench: the trace holds no whole call: nothing is reported",
+              flush=True)
+        return None, of_kernels
+    return ({key: statistics.fmean(ns) for key, ns in zip(keys, zip(*of_programs))},
+            of_kernels)
+
+
+def kernel_period(of_kernels):
+    """``(k, period)``: the kernel calls of one multistep execution and
+    their median period, in ns; ``None``, with the reason printed, where
+    the multistep ran none, or not one number of them."""
+    k = {len(found) for found in of_kernels}
+    if k == {0}:
+        print("perfbench: the multistep ran no kernel call: there is no "
+              "period to hold a call against; nothing is reported", flush=True)
+        return None
+    if len(k) != 1:
+        print(f"perfbench: the multistep's executions ran {sorted(k)} kernel "
+              "calls, not one number: nothing is reported", flush=True)
+        return None
+    k, = k
+    return k, statistics.median(p for found in of_kernels for p in found)
+
+
 def read(view):
     session = view.session
     whole, executions = session.traced_programs(view.trace, view.traced)
@@ -49,31 +91,11 @@ def read(view):
         busy = trace.busy_s(whole)
         scopes.print_layers("device time by layer", rows, busy)
         scopes.print_table("device time by origin", rows, busy, per, "call")
-    calls, of_kernels = [], []
-    per_call = len(session.programs())
-    for of_chip in placed.values():
-        starts = [i for i, (key, _) in enumerate(of_chip) if key == MULTI]
-        for a, b in zip(starts, starts[1:] + [len(of_chip)]):
-            if b - a == per_call:  # a last call without its cut snapshot is not whole
-                calls.append(sum(trace.union_ns(events)
-                                 for _, events in of_chip[a:b]))
-            of_kernels.append(periods(of_chip[a][1]))
-    if not calls:
-        print("perfbench: the trace holds no whole call: nothing is reported",
-              flush=True)
+    by_program, of_kernels = whole_calls(session, placed)
+    found = by_program and kernel_period(of_kernels)
+    if not found:
         return None
-    k = {len(found) for found in of_kernels}
-    if k == {0}:
-        print("perfbench: the multistep ran no kernel call: there is no "
-              "period to hold a call against; nothing is reported", flush=True)
-        return None
-    if len(k) != 1:
-        print(f"perfbench: the multistep's executions ran {sorted(k)} kernel "
-              "calls, not one number: nothing is reported", flush=True)
-        return None
-    k, = k
-    call = statistics.fmean(calls)
-    period = statistics.median(p for found in of_kernels for p in found)
+    call, (k, period) = sum(by_program.values()), found
     print(f"perfbench: a call takes {call / 1e3:.3f} us of device time, its "
           f"{k} kernel calls' periods {period / 1e3:.3f} us at the median: "
           f"{(call - k * period) / 1e3:.3f} us a call are output's", flush=True)

@@ -41,7 +41,7 @@ TRACE_DIR = ".perfbench_trace"
 # run a few processes (PERF.md Finding 10).  The benchmark asks for 256 MiB:
 # 1.7 s, the same in every run.  The two job cells move data to the host
 # inside their windows (a snapshot of 77.76 MB a call, a save of 2.49 GB
-# every 32 calls) and live within it by their own bounds: `output.ahead_bytes`
+# every 48 calls) and live within it by their own bounds: `output.ahead_bytes`
 # 160e6 (two snapshots asked for at once) and the library's `AHEAD_BYTES`
 # 16e6; more than the buffer asked for at once moves at 0.35 GB/s and stalls
 # the device's queue (PERF.md, PRs 34 and 36).

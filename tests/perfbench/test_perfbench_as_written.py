@@ -366,11 +366,21 @@ def test_the_real_cell_lists_its_readers_and_the_accepted_ones_that_read_true():
     # a call is 0.97 s on the chip: one makes a batch of 0.27 s or more
     assert workload["rows"] == [
         {"name": "multistep", "slots": 1, "reps": 1, "trace_batches": 3}]
-    # appended, nothing before them moved
-    assert benchmark["workloads"][-1]["name"] == CELL
-    assert benchmark["configs"][-1]["name"] == "shallow-water-as-written"
-    assert [m["name"] for m in benchmark["per_layer"][-3:]] == NEW_READERS
-    assert [c["chips"] for c in benchmark["workloads"]].count(4) == 1
+    # appended after the cells PR 42 found, nothing before them moved;
+    # what later PRs append after them is theirs to pin
+    cells = [c["name"] for c in benchmark["workloads"]]
+    assert cells[:cells.index(CELL) + 1] == [
+        "sw-bench-1chip", "coll-2x2", "sw-job-1chip", "sw-restart-1chip", CELL]
+    configs = [c["name"] for c in benchmark["configs"]]
+    assert configs[:configs.index("shallow-water-as-written") + 1] == [
+        "shallow-water", "collectives", "shallow-water-job",
+        "shallow-water-restart", "shallow-water-as-written"]
+    readers = [m["name"] for m in benchmark["per_layer"]]
+    first = readers.index(NEW_READERS[0])
+    assert readers[first:first + 3] == NEW_READERS
+    assert all(readers.index(name) < first for name in ACCEPTED)
+    chips = [c["chips"] for c in benchmark["workloads"]]
+    assert chips.count(4) <= max(1, len(chips) // 4)  # the driver's share
 
 
 def test_the_configuration_is_upstreams_with_one_ghost_cell():

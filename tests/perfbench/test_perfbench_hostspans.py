@@ -323,9 +323,10 @@ def test_every_reader_has_its_file_and_the_job_cells_list_it():
     for name in READERS:
         assert hasattr(files.load_module("layer_metrics", name), "read")
         assert listed[name]["moves"] == "solver_rate"
-        assert listed[name]["workloads"] == (
-            ["sw-restart-1chip"] if name.startswith("save_")
-            else ["sw-job-1chip", "sw-restart-1chip"])
+        # membership: a later cell that the reader reads true on lists it too
+        assert set(listed[name]["workloads"]) >= (
+            {"sw-restart-1chip"} if name.startswith("save_")
+            else {"sw-job-1chip", "sw-restart-1chip"})
 
 
 def test_the_harness_runs_the_readers_once_a_cell_lists_them(

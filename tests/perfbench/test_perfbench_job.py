@@ -6,7 +6,6 @@ made-up traces whose values are computed by hand, a trace that the
 profiler cut inside the last snapshot among them; and the reference's
 two block means against each other."""
 
-import importlib.util
 import json
 import re
 import types
@@ -213,12 +212,44 @@ def test_snapshot_readers_on_a_hand_made_trace(copy, job_session, capsys):
     least = scopes.signature(snap)
     assert least == (3 * 36 * 68 * 4, 3 * 16 * 32 * 4)
     reader = _reader(copy, "snapshot_hbm_roofline_share")
+    assert reader.output_bytes(job_session) == [
+        ("snapshot", f"the snapshot program ({least.taken} in, "
+         f"{least.handed_back} out)", least.bytes)]
+    # a program that is all output's is held against its own device time
     assert reader.read(view) == pytest.approx(100 * (least.bytes / 819e9) / 100e-9)
     assert _reader(copy, "state_copy_bytes_per_call.sw").read(view) == 0.0
     # on this backend the step is array code: no kernel call, no period
     capsys.readouterr()
     assert _reader(copy, "snapshot_device_share.sw").read(view) is None
     assert "ran no kernel call" in capsys.readouterr().out
+
+
+def test_a_programs_text_is_lowered_with_what_the_job_handed_it(job_session, capsys):
+    """``compiled_text`` asks the job nothing about what its programs
+    take: what the loop handed each at its first call was kept."""
+    driver = files.load_module("drivers", "shallow_water_job")
+    seen = job_session.job._snap.handed
+    assert [(a.shape, a.dtype) for a in seen] == [((36, 68), np.float32)] * 3
+    assert all(a.sharding == job_session.job.state.h.sharding for a in seen)
+    state, = job_session.job._multi.handed
+    assert [a.shape for a in state] == [a.shape for a in job_session.job.state]
+    # a job whose last step hands its snapshot program coarse sums, and
+    # not the fields the state holds
+    job = types.SimpleNamespace(
+        multi=jax.jit(lambda s: s), snap=jax.jit(lambda *sums: tuple(a / 4 for a in sums)),
+        stage=None, state=job_session.job.state)
+    job._multi, job._snap, job._stage = job.multi, job.snap, None
+    driver.watch(job)
+    job._snap(*(jax.numpy.ones((16, 32), "float32"),) * 3)
+    assert scopes.signature(driver.text_of(job, "snapshot")) == (
+        3 * 16 * 32 * 4, 3 * 16 * 32 * 4)
+    assert "by assumption" not in capsys.readouterr().out
+    # a program the loop was not seen calling: the state at hand, and said
+    assert scopes.signature(driver.text_of(job, "multistep")).taken == sum(
+        a.nbytes for a in job.state)
+    assert "by assumption" in capsys.readouterr().out
+    with pytest.raises(KeyError, match="holds no 'stage' program"):
+        driver.text_of(job, "stage")
 
 
 def _step_and_snapshot_lines(job_session):

@@ -205,12 +205,15 @@ def test_the_traced_window_holds_one_save_between_two_calls(session):
     traced = [run.Sample("multistep", 0.0, 1.0)] * 2
     assert session.traced_programs(None, traced)[1] == [
         "multistep", "multistep", "stage", "multistep", "multistep"]
-    # the real cell: eight batches of four from call 1, a save every 32
+    # the real cell: twelve batches of four from call 1, a save every 48
+    workload = files.load_json("workloads", "sw-restart-1chip")
     real = types.SimpleNamespace(
-        calls_at_setup=1, every=32, rows={"multistep": {"reps": 4}})
-    executions = type(session).traced_programs(real, None, traced * 4)[1]
-    assert executions.count("stage") == 1 and executions.index("stage") == 31
-    assert len(executions) == 33
+        calls_at_setup=1, rows={r["name"]: r for r in workload["rows"]},
+        every=files.load_json("configs", "shallow-water-restart")["restart"]["every_calls"])
+    batches = workload["rows"][0]["trace_batches"]
+    executions = type(session).traced_programs(real, None, traced[:1] * batches)[1]
+    assert executions.count("stage") == 1 and executions.index("stage") == 47
+    assert len(executions) == 49 and batches == 12
 
 
 def test_checkpoint_readers_on_a_hand_made_trace(copy, session, capsys):
@@ -320,7 +323,8 @@ def test_the_real_cell_lists_its_readers_and_the_accepted_ones_that_read_true():
     for name in NEW_READERS + HOST_SPANS[5:]:
         assert hasattr(files.load_module("layer_metrics", name), "read")
         entry = next(m for m in benchmark["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == ["sw-restart-1chip"]
+        # membership: a later cell that the reader reads true on lists it too
+        assert "sw-restart-1chip" in entry["workloads"]
     assert {m["name"] for m in
             files.metrics_of(benchmark, "end_to_end", "sw-restart-1chip")} == {
                 "solver_rate", "solver_step_p95_us", "setup_s"}
@@ -331,15 +335,19 @@ def test_the_real_cell_lists_its_readers_and_the_accepted_ones_that_read_true():
     bench_cell = files.load_json("workloads", "sw-bench-1chip")
     assert workload["grid"] == bench_cell["grid"]
     assert workload["mesh"] == bench_cell["mesh"]
-    assert workload["rows"][0]["reps"] == bench_cell["rows"][0]["reps"] == 4
+    # a batch of four calls (0.16 s): the job's calls are enqueued ahead
+    # and its saves fall between them; the bench cell's is eight (0.31 s)
+    assert workload["rows"][0]["reps"] == 4 and bench_cell["rows"][0]["reps"] == 8
+    assert cell["traffic"] == "bench-domain-save-every-48-calls"
     config = files.load_json("configs", "shallow-water-restart")
     assert config["model"] == files.load_json("configs", "shallow-water")["model"]
     assert config["architecture"] is None and list(config["reduced"]) == ["restart"]
     assert config["restart"] | {"what": 0} == {
-        "every_calls": 32, "keep": 2, "asynchronous": True,
+        "every_calls": 48, "keep": 2, "asynchronous": True,
         "ahead_bytes": 160000000, "fields": list(STATE), "what": 0}
     assert config["check"]["bit_for_bit"] == 0
-    assert [c["chips"] for c in benchmark["workloads"]].count(4) == 1
+    chips = [c["chips"] for c in benchmark["workloads"]]
+    assert chips.count(4) <= max(1, len(chips) // 4)  # the driver's share
 
 
 # -- the reference ---------------------------------------------------------

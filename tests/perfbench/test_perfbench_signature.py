@@ -160,8 +160,11 @@ def test_a_call_of_one_program_gives_every_reader_a_number_or_a_reason(capsys):
     assert got["snapshot_device_share.sw"] == pytest.approx(
         100 * (call - 10 * 731) / call)
     assert f"{(call - 7310) / 1e3:.3f} us a call are output's" in said
-    assert got["snapshot_hbm_roofline_share"] is None
-    assert "no program of its own for the snapshot" in said
+    # output's bytes are the coarse fields the last step hands back,
+    # over the 41 ns a call spends because it has output
+    assert got["snapshot_hbm_roofline_share"] == pytest.approx(
+        100 * (3 * 16 * 32 * 4 / 819e9) / ((call - 7310) * 1e-9))
+    assert "the multistep's results beyond its operands 6144 bytes" in said
     assert 0 < got["sw_hbm_roofline_share.job"] < 100
     assert got["op_surface_device_share.job"] == pytest.approx(
         100 * (9 * 30 + 6 * 5 + 3 * 4) / call)
@@ -180,15 +183,76 @@ def test_a_snapshot_program_of_coarse_sums_has_a_floor_of_their_bytes(capsys):
     means = [(event_lines(texts["snapshot"])[f"out.{i}"], 10) for i in range(3)]
     made = made_trace([a_step(lines) * 10, means] * 3)
     view = _view(made_job_session(texts), made)
-    got = _reader("snapshot_hbm_roofline_share").read(view)
-    assert got == pytest.approx(100 * (6 * 16 * 32 * 4 / 819e9) / 30e-9)
-    assert got < 100
+    # every kernel call of this made trace takes as long as the others:
+    # the sums the multistep hands back have no time of their own, and
+    # their bytes are not held against the scaling program's
+    assert _reader("snapshot_hbm_roofline_share").read(view) is None
+    assert "no device time of their own" in capsys.readouterr().out
     # the same trace by the hand count of three whole fields: 1 250 %
     assert 100 * (3 * (F + 16 * 32 * 4) / 819e9) / 30e-9 > 100
     # what output costs a call: the program, and nothing of the steps
     call = 10 * 731 + 30
     assert _reader("snapshot_device_share.sw").read(view) == pytest.approx(
         100 * 30 / call)
+    capsys.readouterr()
+
+
+C = 16 * 32 * 4  # a coarse field
+OUTPUT = {
+    # today's: a multistep and a snapshot program of three whole fields
+    "a call of two programs": (
+        {"multistep": multistep_text(), "snapshot": program_text((36, 68), (16, 32))},
+        "plain", 60, 3 * (F + C), 60),
+    # PR 40's: the last kernel call writes the coarse fields, three fusions finish them
+    "a last step that hands back coarse fields": (
+        {"multistep": multistep_text(coarse=(16, 32))}, "last", 0, 3 * C, 41),
+    # the last step writes coarse sums, a program of its own scales them
+    "a snapshot program handed coarse sums": (
+        {"multistep": multistep_text(coarse=(16, 32)),
+         "snapshot": program_text((16, 32), (16, 32))}, "last", 30, 9 * C, 71),
+    # every kernel call sums: the median period holds output's work
+    "sums in every kernel call": (
+        {"multistep": multistep_text(coarse=(16, 32))}, "plain", 0, 3 * C, 0),
+    "no output at all": ({"multistep": multistep_text()}, "plain", 0, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(OUTPUT))
+def test_outputs_share_of_the_roofline_wherever_its_work_runs(case, capsys):
+    """``snapshot_hbm_roofline_share``: the bytes a call moves because it
+    has output (every program but the multistep by its signature, and
+    what the multistep hands back beyond what it is handed) over the
+    time ``snapshot_device_share.sw`` says output costs a call.  No
+    reading passes 100, and where there are no bytes or no time the
+    reader says which and reports nothing."""
+    texts, steps, snapshot_ns, moved, output_ns = OUTPUT[case]
+    lines = event_lines(texts["multistep"])
+    call = a_step(lines) * 10
+    if steps == "last":  # the tenth step's kernel call and the finish after it
+        call = a_step(lines) * 9 + [(lines[f"slab.{i}"], 5) for i in range(6)] + [
+            (lines["wide_step_out.4"], 730)] + [(lines[f"finish.{i}"], 4) for i in range(3)]
+    executions = [call]
+    if "snapshot" in texts:
+        of_snapshot = event_lines(texts["snapshot"])
+        executions.append([(of_snapshot[f"out.{i}"], snapshot_ns // 3) for i in range(3)])
+    session = made_job_session(texts)
+    view = _view(session, made_trace(executions * 3))
+    reader = _reader("snapshot_hbm_roofline_share")
+    assert sum(n for _, _, n in reader.output_bytes(session)) == moved
+    got = reader.read(view)
+    said = capsys.readouterr().out
+    assert "do not belong together" not in said
+    if moved and output_ns:
+        assert got == pytest.approx(100 * (moved / 819e9) / (output_ns * 1e-9))
+        assert 0 < got < 100
+        assert f"took {output_ns / 1e3:.3f} us of device time" in said
+        # the time is the other reader's, to the digit
+        share = _reader("snapshot_device_share.sw").read(view)
+        total = 100 * output_ns / share
+        assert total == pytest.approx(sum(ns for e in executions for _, ns in e))
+    else:
+        assert got is None and said.count("nothing is reported") == 1
+        assert ("adds no bytes" if not moved else "no device time of their own") in said
     capsys.readouterr()
 
 
