@@ -519,7 +519,10 @@ def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
     without (every call donates its input either way); every snapshot
     comes once, in step order; the last is the block mean of the state
     the job returns; and on every further mesh the snapshots are the
-    first mesh's to the rounding of another decomposition."""
+    first mesh's to the rounding of another decomposition.  On the chip
+    the step is the kernel and the snapshots start in it: the record's
+    ``snapshots_summed_in_step`` says for how many on each mesh (all of
+    them where ``coarsen`` divides a strip; none on the CPU)."""
     import numpy as np
 
     from mpi4jax_tpu.models import shallow_water as sw
@@ -570,6 +573,9 @@ def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
         "last_snapshot_max_diff": last,
         "meshes_max_diff": across,
         "max_diff": max(last, across),
+        "snapshots_summed_in_step": {
+            "x".join(map(str, shape)): job.stats()["snapshots_summed_in_step"]
+            for shape, job, _ in runs},
     }
     if last > TOL_BLOCK_MEAN:
         raise AssertionError(f"the last snapshot is not the state's: {out}")

@@ -361,7 +361,9 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
     per step (see :func:`_step_wide4`).  All numerically identical.
     """
     if cfg.ghost == 2:
-        return _step_wide(state, cfg, comm, first_step=first_step, token=token)
+        (state, _), token = _step_wide(
+            state, cfg, comm, first_step=first_step, token=token)
+        return state, token
     if cfg.ghost == 4:
         return _step_wide4(state, cfg, comm, first_step=first_step, token=token)
     if cfg.ghost != 1:
@@ -683,7 +685,8 @@ def _kernels_ahead(cfg, comm):
         sw_kernels.pallas()
 
 
-def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1):
+def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
+               sums=(), coarsen=0, summing=True):
     """Wide-halo (ghost=2) step: communicate prognostic fields only.
 
     The narrow schedule exchanges every intermediate field because a
@@ -739,6 +742,16 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1):
     ``dv`` holding the neighbours' (round 1 on ring 1 steps from them):
     hand a later step the tendencies a step returned.  A first step
     reads none, and takes either shape.
+
+    Returns ``((state, sums), token)``, ``sums`` empty but here:
+    ``coarsen`` with ``sums`` (where the step is the kernel,
+    :func:`_sums_in_step`; the room :func:`make_sums_room` makes, as a
+    step before left it): the step runs the kernel that can also write
+    the sums over ``coarsen`` rows of the new ``h``, ``u``, ``v`` into
+    ``sums``, does so where ``summing`` (traced) is set, and hands the
+    room back either way; where it is not set the sums are nobody's and
+    cost nothing.  A job's programs ask for the one kernel in every
+    step, so that a process builds one.
     """
     G = 2
     if not cfg.periodic_x:
@@ -779,13 +792,14 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1):
         # step goes through that kernel too, the first of the two passed
         # over: one kernel a process, traced once
         lone = first_step and _walks_two_steps(cfg, comm)
-        state = sw_kernels.wide_step(
+        *state, = sw_kernels.wide_step(
             h, u, v, dh, du, dv, (for_h, for_u, for_v), is_south, is_north,
-            iy * ny_l, a, b, lone,
+            iy * ny_l, a, b, lone, *([summing, tuple(sums)] if coarsen else []),
             nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
             gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
-            coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps)
-        return SWState(*state), token
+            coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps,
+            coarsen=coarsen)
+        return (SWState(*state[:6]), tuple(state[6:])), token
 
     # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
     h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
@@ -801,7 +815,7 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1):
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
         u, v = _viscosity_round(u, v, cfg, is_south, is_north)
 
-    return SWState(h, u, v, dh, du, dv), token
+    return (SWState(h, u, v, dh, du, dv), ()), token
 
 
 def _step_wide4(state, cfg, comm, *, first_step=False, token=None):
@@ -968,7 +982,7 @@ def _mesh_specs(comm):
     return SWState(*([spec] * 6))
 
 
-def make_multistep(cfg, comm, num_steps, *, donate=False):
+def make_multistep(cfg, comm, num_steps, *, donate=False, snapshot=None):
     """Jitted global function advancing the model ``num_steps`` steps —
     the reference's ``do_multistep`` (shallow_water.py:415-420): the whole
     loop is one XLA executable.
@@ -976,32 +990,65 @@ def make_multistep(cfg, comm, num_steps, *, donate=False):
     ``donate=True`` donates the input state's buffers (in-place update;
     the passed-in state is consumed).  Saves one full state copy per
     call — use it for ``state = multi(state)``-style driver loops.
+
+    ``snapshot`` (a :class:`Snapshot`; a job's): where the step is the
+    kernel and the snapshot's blocks are made of its strips' rows
+    (:func:`_sums_in_step`), the function is ``(state, sums) ->
+    (state, sums)``: the call's last walk is taken out of the loop and
+    writes, beside the state, the sums over ``coarsen`` rows of ``h``,
+    ``u`` and ``v``, in that order, which ``make_snapshot(from_sums=
+    True)`` finishes, **into the room it is handed**
+    (:func:`make_sums_room`'s, or what the call before returned;
+    ``donate`` donates it with the state, so that a call allocates
+    nothing).  The walks in the loop run the same kernel with its sums
+    switched off by a scalar (they write none and do none of that
+    work), so that the program holds one kernel text and a process that
+    has built ``make_first_step(snapshot=)`` builds nothing new.
+    Anywhere else the function is ``state -> state``, whatever
+    ``snapshot`` is.
     """
 
     # steps a walk of the kernel (``_step_wide``): two on one device
     stride = 2 if _walks_two_steps(cfg, comm) else 1
+    coarsen = snapshot.coarsen if _sums_in_step(cfg, comm, snapshot) else 0
+    # the walks of a call, the last one apart where it writes the sums
+    # (an odd count's on one device is a single step's walk)
+    last = num_steps % stride or stride
+    looped = (num_steps - last * bool(coarsen)) // stride
 
-    def local_fn(state):
-        def body(_, s):
-            if stride == 1:
+    def local_fn(state, sums=()):
+        def body(_, carry):
+            s, sums = carry
+            if coarsen:  # the kernel the last walk runs, its sums off
+                (s, sums), _tok = _step_wide(
+                    s, cfg, comm, steps=stride, sums=sums, coarsen=coarsen,
+                    summing=False)
+            elif stride == 1:
                 s, _tok = shallow_water_step(s, cfg, comm)
             else:
-                s, _tok = _step_wide(s, cfg, comm, steps=stride)
-            return s
+                (s, _), _tok = _step_wide(s, cfg, comm, steps=stride)
+            return s, sums
 
-        if num_steps >= stride:
-            state = lax.fori_loop(0, num_steps // stride, body, state)
+        if looped:
+            state, sums = lax.fori_loop(0, looped, body, (state, sums))
+        if coarsen:
+            return _step_wide(
+                state, cfg, comm, steps=last, sums=sums, coarsen=coarsen)[0]
         if num_steps % stride:
             state, _tok = shallow_water_step(state, cfg, comm)
         return state
 
     _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)
+    if coarsen:
+        specs = (specs, (jax.P(*comm.axes),) * 3)
     return jax.jit(
         jax.shard_map(
-            local_fn, mesh=comm.mesh, in_specs=(specs,), out_specs=specs
+            local_fn, mesh=comm.mesh,
+            in_specs=specs if coarsen else (specs,), out_specs=specs
         ),
-        donate_argnums=(0,) if donate else (),
+        # the state, and with it the room for the sums
+        donate_argnums=((0, 1) if coarsen else (0,)) if donate else (),
     )
 
 
@@ -1059,16 +1106,29 @@ def make_state(cfg, comm):
     )
 
 
-def make_first_step(cfg, comm):
+def make_first_step(cfg, comm, snapshot=None):
+    """Jitted global function: the forward-Euler step that starts a
+    run.  ``snapshot`` (a job's): where :func:`make_multistep` would
+    run the kernel that writes row sums, the step runs that kernel too,
+    its sums off, so that a process builds one kernel for both, and
+    returns ``(state, sums)``: the room for the sums that
+    ``make_multistep(snapshot=)`` takes, made here so that a run's
+    set-up holds no program of its own for it."""
+    coarsen = snapshot.coarsen if _sums_in_step(cfg, comm, snapshot) else 0
+
     def local_fn(state):
+        if coarsen:
+            return _step_wide(
+                state, cfg, comm, first_step=True, coarsen=coarsen, summing=False,
+                sums=_zero_sums(state.h, coarsen))[0]
         state, _tok = shallow_water_step(state, cfg, comm, first_step=True)
         return state
 
     _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)
-    return jax.jit(
-        jax.shard_map(local_fn, mesh=comm.mesh, in_specs=(specs,), out_specs=specs)
-    )
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(specs,),
+        out_specs=(specs, (jax.P(*comm.axes),) * 3) if coarsen else specs))
 
 
 @dataclass(frozen=True)
@@ -1089,7 +1149,16 @@ class Snapshot:
     for past it at a tenth of the speed: a job whose ``lag + 1``
     snapshots do not fit there holds its copies under it with this.  In
     a job that is saved too it is the host's bound on all the job's
-    copies, a save's pieces among them (:class:`SolverJob`)."""
+    copies, a save's pieces among them (:class:`SolverJob`).
+
+    Where the step is the kernel of :mod:`sw_kernels` and the blocks
+    are made of its strips' rows (``h``, ``u`` and ``v`` at ``coarsen``
+    2, 4 or 8: :func:`_sums_in_step`) a snapshot starts in the call's
+    last walk, which writes the fields' sums over ``coarsen`` rows, and
+    the snapshot program finishes a ``coarsen``-th of a field where it
+    would read three fields again; the same means to roundoff.  Nothing
+    to ask for: it follows from the configuration, the devices and
+    these fields."""
 
     fields: tuple = ("h", "u", "v")
     coarsen: int = 1
@@ -1101,6 +1170,24 @@ class Snapshot:
 # ``mpi4jax_tpu.snapshot`` scope (as parallel/halo.py names the
 # exchange's): what a device profile books the coarse-graining under.
 COARSEN = "coarsen"
+
+
+def _sums_in_step(cfg, comm, snapshot):
+    """Whether a call's last walk writes the sums over ``coarsen`` rows
+    of what ``snapshot`` holds, for :func:`make_snapshot` to finish:
+    where the step is the kernel (:func:`_runs_as_kernels`), which has
+    every row of the final ``h``, ``u``, ``v`` in VMEM a strip at a
+    time, the snapshot holds those three, and its blocks are made of a
+    strip's rows (``coarsen`` > 1 divides ``sw_kernels.STRIP`` and a
+    device's block).  From what the job is built on, like the kernel
+    itself: the array code, ``coarsen`` 1 and blocks of 3 or 16 rows
+    read the fields, as every backend did before."""
+    if snapshot is None or not _runs_as_kernels(cfg, comm):
+        return False
+    c = snapshot.coarsen
+    return (c > 1 and sw_kernels.STRIP % c == 0
+            and not any(n % c for n in cfg.local_interior(comm))
+            and sorted(snapshot.fields) == ["h", "u", "v"])
 
 
 def _block_mean(block, ghost, coarsen):
@@ -1121,22 +1208,91 @@ def _block_mean(block, ghost, coarsen):
     return means * jnp.asarray(1.0 / (c * c), block.dtype)
 
 
-def make_snapshot(cfg, comm, snapshot):
+def _row_sums_mean(sums, ghost, coarsen, shape):
+    """:func:`_block_mean` of a block of ``shape`` interior cells from
+    the sums over ``coarsen`` rows that the step's kernel wrote
+    (:mod:`sw_kernels`, "Output in the last walk": whole width, the
+    interior's first group in row ``lead``): the sums along the rows
+    and the division."""
+    c = coarsen
+    lead = -(-ghost // c)
+    ny, nx = shape
+    width = sums.shape[1]
+    # as `_block_mean` lays its windows: over the rows as they lie
+    sums = lax.reduce_window(
+        sums, jnp.zeros((), sums.dtype), lax.add, (1, c), (1, c),
+        [(0, 0), (lead * c - ghost, -(width + lead * c - ghost) % c)])
+    means = sums[lead:lead + ny // c, lead:lead + nx // c]
+    return means * jnp.asarray(1.0 / (c * c), sums.dtype)
+
+
+def _zero_sums(field, coarsen):
+    """Room for the sums over ``coarsen`` rows of ``h``, ``u``, ``v``,
+    one device's, beside its padded ``field`` and varying over the mesh
+    as it does (so that the kernel that takes it is traced once)."""
+    shape = sw_kernels.row_sums_shape(field.shape, field.dtype, coarsen)
+    return tuple(jnp.zeros_like(field, shape=shape) for _ in range(3))
+
+
+def make_sums_room(cfg, comm, snapshot):
+    """Jitted global function ``() -> (sums, sums, sums)``: the room for
+    the sums over ``coarsen`` rows of ``h``, ``u``, ``v`` that
+    ``make_multistep(snapshot=)`` takes beside the state, writes and
+    hands back (zeros, sharded as the state is), for a run that starts
+    from a later step than ``make_first_step(snapshot=)``'s, which
+    returns one; ``None`` where the step makes no sums
+    (:func:`_sums_in_step`)."""
+    if not _sums_in_step(cfg, comm, snapshot):
+        return None
+    padded = tuple(n + 2 * cfg.ghost for n in cfg.local_interior(comm))
+    shape = sw_kernels.row_sums_shape(padded, cfg.dtype, snapshot.coarsen)
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        lambda: tuple(jnp.zeros(shape, cfg.dtype) for _ in range(3)),
+        mesh=comm.mesh, in_specs=(), out_specs=(spec,) * 3))
+
+
+def make_snapshot(cfg, comm, snapshot, from_sums=False):
     """Jitted global function ``(field, ...) -> (coarse field, ...)``:
     each device coarse-grains the interior of its own block of each of
     the state's arrays it is handed, and the results stay sharded as the
     state is, so that each device sends the host its own share once.
     Reads its arguments and donates nothing: a job enqueues it between
-    the call that made the state and the call that consumes it."""
+    the call that made the state and the call that consumes it.
+
+    ``from_sums``: the function is handed, in each field's place, the
+    field's sums over ``coarsen`` rows as the call's last walk wrote
+    them (``make_multistep(snapshot=)`` returns them beside the state;
+    only where :func:`_sums_in_step` holds), a ``coarsen``-th of a
+    field each, and finishes them: the sums along a row and the
+    division, the same means to roundoff (the rows are added first).
+    Either form refuses an operand of the other's shape."""
     c = snapshot.coarsen
-    ny_l, nx_l = cfg.local_interior(comm)
+    interior = ny_l, nx_l = cfg.local_interior(comm)
     if c < 1 or ny_l % c or nx_l % c:
         raise ValueError(
             f"coarsen {c} does not divide a device's block of "
             f"{ny_l}x{nx_l} cells")
+    if from_sums and not _sums_in_step(cfg, comm, snapshot):
+        raise ValueError(
+            f"no step of this configuration on these devices makes {snapshot}'s "
+            "row sums: make_snapshot(from_sums=True) has nothing to finish")
+    expected = tuple(n + 2 * cfg.ghost for n in interior)
+    if from_sums:
+        expected = sw_kernels.row_sums_shape(expected, cfg.dtype, c)
 
     def local_fn(*fields):
+        for a in fields:
+            if a.shape != expected:
+                raise ValueError(
+                    f"an operand of {a.shape} a device where "
+                    f"make_snapshot(from_sums={from_sums}) takes "
+                    + ("a field's row sums" if from_sums else "a padded field")
+                    + f" of {expected}")
         with jax.named_scope(SCOPE_PREFIX + "snapshot"), jax.named_scope(COARSEN):
+            if from_sums:
+                return tuple(_row_sums_mean(a, cfg.ghost, c, interior)
+                             for a in fields)
             return tuple(_block_mean(a, cfg.ghost, c) for a in fields)
 
     spec = jax.P(*comm.axes)
@@ -1311,7 +1467,22 @@ class SolverJob:
     only one half never waits for room, and is what it was.
 
     ``first``, ``multi``, ``snap`` and ``stage`` are the jitted
-    programs; ``state``, ``step`` and ``calls`` the model as the last
+    programs.  Where the step is the kernel and the snapshot's blocks
+    are made of its strips' rows (:func:`_sums_in_step`), ``first`` and
+    ``multi`` run the one kernel that can sum rows (its sums on in a
+    call's last walk alone), ``multi`` is ``(state, row sums) ->
+    (state, row sums)`` and ``snap`` is handed **those sums**, not
+    ``h``, ``u``, ``v``.  The sums' room (a ``coarsen``-th of three
+    fields) is made when the job starts (``first`` returns it beside
+    the state), written by each call's last walk and donated to the
+    next call with the state, which the device runs after the ``snap``
+    that reads it, as it does for the fields; it is no part of
+    ``state`` and is not saved: a resumed job makes its own
+    (:func:`make_sums_room`)
+    (``stats()["snapshots_summed_in_step"]`` counts such snapshots, and
+    the ``snap`` program's ``job/enqueue`` span says ``handed``
+    ``row_sums`` or ``fields``).  ``state``, ``step`` and ``calls`` are
+    the model as the last
     enqueued call leaves it; ``series`` the checkpoint's directory (a
     :class:`checkpoint.Series`) and ``saves`` the acknowledged saves'
     records, in order.
@@ -1343,9 +1514,16 @@ class SolverJob:
         if checkpoint is not None:  # its pieces are cut for the job's bound
             checkpoint = replace(checkpoint, ahead_bytes=self.ahead_bytes)
         self.checkpoint = checkpoint
-        self.first = make_first_step(cfg, comm)
-        self.multi = make_multistep(cfg, comm, num_multisteps, donate=True)
-        self.snap = snapshot and make_snapshot(cfg, comm, snapshot)
+        self.first = make_first_step(cfg, comm, snapshot)
+        self.multi = make_multistep(
+            cfg, comm, num_multisteps, donate=True, snapshot=snapshot)
+        # the room for the snapshot's row sums, which `multi` takes and
+        # returns beside the state where the step makes them: `first`
+        # returns it too, and this makes it for a run that starts later
+        self._room = make_sums_room(cfg, comm, snapshot)
+        self._summed, self._sums = self._room is not None, ()
+        self.snap = snapshot and make_snapshot(
+            cfg, comm, snapshot, from_sums=self._summed)
         self._multi, self._snap = self.multi, self.snap
         self.state, self.step, self.calls = None, 0, 0
         self._pending = collections.deque()  # (step, device arrays), oldest first
@@ -1363,7 +1541,8 @@ class SolverJob:
         self._copies = ckpt.Side(self._host, ckpt.SNAPSHOT, partial(
             self.trace.span, "job/ask_wait"))
         self._stats = dict(
-            snapshots_produced=0, snapshots_delivered=0, max_lag=0,
+            snapshots_produced=0, snapshots_summed_in_step=0,
+            snapshots_delivered=0, max_lag=0,
             bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
             saves_started=0, save_bytes=0, save_wait_s=0.0, save_enqueue_s=0.0,
             restore_read_s=0.0, restore_to_device_s=0.0)
@@ -1376,7 +1555,10 @@ class SolverJob:
         self.drain()
         self._host.peak = self._host.in_flight
         if step == 0:
-            state, step = self.first(state), 1
+            out, step = self.first(state), 1
+            state, self._sums = out if self._summed else (out, ())
+        elif self._summed:
+            self._sums = self._room()
         self.state, self.step = state, step
         self.calls = (step - 1) // self.num_multisteps
 
@@ -1384,9 +1566,10 @@ class SolverJob:
         """Compile the call's programs for the state at hand without
         running them: a resumed run has no warm-up call to spend."""
         with self.trace.span("job/compile", key=self.step):
-            self._multi = self.multi.lower(self.state).compile()
+            self._multi = self.multi.lower(
+                self.state, *[self._sums] * self._summed).compile()
             if self.snap is not None:
-                self._snap = self.snap.lower(*self._written()).compile()
+                self._snap = self.snap.lower(*self._handed()).compile()
             if self.stage is not None:
                 self._stage = self.stage.lower(self.state).compile()
 
@@ -1401,14 +1584,17 @@ class SolverJob:
             for _ in range(calls):
                 with span("job/enqueue", key=self.step + self.num_multisteps,
                           program="multi"):
-                    self.state = self._multi(self.state)
+                    out = self._multi(self.state, *[self._sums] * self._summed)
+                self.state, self._sums = out if self._summed else (out, ())
                 self.step += self.num_multisteps
                 self.calls += 1
                 if self.snap is not None:
-                    with span("job/enqueue", key=self.step, program="snap"):
-                        parts = self._snap(*self._written())
+                    with span("job/enqueue", key=self.step, program="snap",
+                              handed="row_sums" if self._summed else "fields"):
+                        parts = self._snap(*self._handed())
                     self._pending.append((self.step, parts))
                     self._stats["snapshots_produced"] += 1
+                    self._stats["snapshots_summed_in_step"] += self._summed
                     self._ask()
                     self._deliver(self.snapshot.lag)
                 if every and self.calls % every == 0:
@@ -1427,7 +1613,11 @@ class SolverJob:
                 self.series.clean()
 
     def stats(self):
-        """The job's counters: ``snapshots_produced`` and
+        """The job's counters: ``snapshots_produced``,
+        ``snapshots_summed_in_step`` (of them, those whose sums over
+        ``coarsen`` rows the call's own last walk made: all where the
+        step is the kernel and :func:`_sums_in_step` holds, none
+        elsewhere) and
         ``snapshots_delivered``; ``max_lag``, the most snapshots one was
         delivered behind the newest; ``bytes_to_host``;
         ``output_wait_s``, host seconds spent fetching snapshots (blocked
@@ -1612,6 +1802,14 @@ class SolverJob:
 
     def _written(self):
         return tuple(getattr(self.state, k) for k in self.snapshot.fields)
+
+    def _handed(self):
+        """What ``snap`` reads: the fields' row sums the call's last
+        walk wrote, or the fields."""
+        if not self._summed:
+            return self._written()
+        return tuple(self._sums[SWState._fields.index(k)]
+                     for k in self.snapshot.fields)
 
     def _ask(self, cause=None, wait=False):
         """Start the copies to the host of the oldest snapshots not yet

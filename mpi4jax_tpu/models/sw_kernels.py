@@ -166,6 +166,88 @@ walk's: ring 1 of ``du``, ``dv`` the step's own round 1 there, ring 1 of
 ``h`` and ring 2 of ``u``, ``v`` what the exchange before the last step
 would have written (the other end as step *n* left it).
 
+Output in the last walk
+-----------------------
+A job that writes snapshots (``shallow_water.SolverJob``) wants, after
+every call, the means of ``h``, ``u``, ``v`` over ``c × c`` blocks of
+cells.  Every row of those fields passes through VMEM in final form in
+the call's last walk, so that walk (``wide_step(coarsen=c)`` with
+``summing`` set: "One kernel a job", below) also writes, for each
+field, **the sums over** ``c`` **rows**, whole width: a ``c``-th of a
+field, which the snapshot program finishes along the rows
+(``shallow_water.make_snapshot``) instead of reading three fields
+again.  Rows only, float32, by rotations of sublanes and
+additions: sums along a row would have to bring every fourth lane of a
+register together, which the vector units do not do without the matrix
+unit or a pass of transposes.
+
+When.  A tile's final values are in its output block when the grid step's
+strips are through (``h`` and, without friction, ``u`` and ``v`` from the
+last step's first stage, ``u`` and ``v`` from its second, a grid step
+later), so the sums are made **after the strips' loop, a tile at a
+time**, from the block in VMEM, and their blocks are written as many
+grid steps behind as the field's own (``wrote_plain``, ``wrote_last``).
+Not in the strips' loop: there every value is 113 vector registers wide
+and what a stage keeps beyond its 64 registers it spills, a store each,
+in a loop that the store slot already fills; after it the sums run over
+the tile's registers eight at a time, and nothing is spilled.
+
+Which rows.  The interior starts ``G`` = 2 rows into a block and a strip
+is 8 rows, so with ``c`` = 4 a strip ends one group begun in the strip
+before it (its rows 6 and 7, this strip's 0 and 1) and holds one whole
+(rows 2 to 5).  Sums double (:func:`_spans`): each row plus the next,
+each such pair plus the pair two rows on, for ``c`` = 8 each four plus
+the four four rows on; before a doubling that reaches across a strip's
+end, the sublanes of the groups astride it (:func:`_astride`) take **the
+strip before's partial sums** in place of this strip's, so that the
+doubling's rotation, which wraps round the strip, brings the right rows
+together; a tile's last strip leaves its partial sums in VMEM for the
+next tile's first, as ``first`` hands itself its row values
+(``n_carried``).  A group's sum is therefore ``(r0 + r1) + (r2 + r3)``,
+pairs first, whichever strips its rows lie in.  After the doublings a
+strip holds the sums of the ``8 / c`` groups that *end* in it, on the
+sublanes of their first rows (:func:`_finished`).
+
+Where they go.  Row ``lead + m`` of a field's sums holds the sum of the
+block's rows ``G + c m`` on, ``lead = ceil(G / c)`` (1 for every ``c``
+that rides): the rows before it are the group that ends in the ghost
+rows, the rows after the interior's last whatever the field's last tile
+held.  A tile of 24 rows ends 6 groups and a block's rows have to be
+whole strips, so a block of sums is 24 rows, the groups of four tiles
+(``phases``), revisited over four grid steps and written back once.  A
+tile's sums are rotated onto neighbouring sublanes, rotated once more
+by the tile's place in the block (the one rotation whose amount is not
+known when the kernel is built), and selected into the one or two strips
+of the block they fall in (:func:`_row_sums`, jitted like the stages: a
+process traces it once for three fields).  One loop over the registers
+for the three fields; in the walk's first and last grid steps, where a
+field has no new tile, its first or last tile is summed again, to the
+same rows.  Some 10 bundles a vector register of 24 rows, 3 630 bundles
+a tile beside the strips' 32 470 (``PERF.md``, PR 49).
+
+One kernel a job.  A second kernel text in a job's programs is a second
+trace and a second lowering in every run of a process (a program is
+lowered before its cache key exists), 0.44 s on a chip's host, as much
+as the sums save in ten seconds (``PERF.md``, PR 49).  So the walk that
+can sum takes a scalar, ``summing``, before the grid starts
+(``PrefetchScalarGridSpec``): where it is not set the sums' loop is
+passed over and the blocks of sums stay on their first block, which is
+written back once, as found, at the walk's end, and a job runs this
+one kernel in its first step and in every walk of a call, the sums on
+in the last.  A job without output runs the kernel without sums, whose
+text this leaves as it was.
+
+The caller brings the room.  The three arrays of sums (a ``c``-th of a
+field each, 315 MB together at the benchmark's size) are operands the
+kernel never reads (``pl.ANY``: no block of them is brought in) aliased
+to the results it writes, as the fields are: a job makes them once,
+hands them from walk to walk and from call to call and donates them
+with the state, so that a call allocates nothing and the walks of a
+call's loop, which name the sums as results like the last, need no
+room of their own (fresh results every call were 315 MB allocated and
+as much again as a temporary of the loop, and runs with them stalled:
+``PERF.md``, PR 49).
+
 Building a kernel is set-up a user waits for, so it is kept short:
 ``jax.experimental.pallas`` is imported by :func:`pallas` where a step is
 built for TPU devices (the array code, which every other backend runs,
@@ -183,6 +265,7 @@ step builds the single walk's beside it.  (A kernel's trace is 0.15 to
 
 import functools
 import itertools
+import math
 import sys
 
 import jax
@@ -203,6 +286,9 @@ LANES = 128  # columns of a vector register
 # was 1 % slower (PERF.md, PR 41)
 _VMEM_BLOCK_BUDGET = 40 * 2**20
 _VMEM_LIMIT = 64 * 2**20
+# vector registers' columns of a tile that the row sums are made of at
+# once ("Output in the last walk")
+_SUMMED_AT_ONCE = 8
 
 
 def pallas():
@@ -276,7 +362,7 @@ add, sub, mul, div, eq, select = (
 
 
 def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
-          steps=1, *, interpret):
+          steps=1, summed=(), coarsen=0, summing=True, sums=(), *, interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
@@ -321,6 +407,19 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
     is what the next exchange brings a device that is alone on a
     periodic axis; ghost rows stay what they are, as beyond a wall.
     The second application's values are the call's results.
+
+    ``summed`` (the places in ``fields`` of those asked for),
+    ``coarsen`` (a divisor of ``STRIP``) and ``sums`` (for each such
+    field an array of :func:`row_sums_shape`, whatever it holds): after
+    the new ``fields`` and ``pointwise``, for each such field the sums
+    over ``coarsen`` rows of its new values, **written into** ``sums``,
+    which the call consumes as it does the fields ("Output in the last
+    walk", above, says which row holds which rows' sum, and why the
+    caller brings the room).  The fields and the pointwise arrays come
+    back as they do without.  ``summing`` (a traced scalar): where it
+    is not set the walk passes the sums over, writes none of their
+    blocks but the first, with whatever VMEM held, and hands ``sums``
+    back otherwise as they came.
     """
     pl, pltpu = pallas()
     rows, width = fields[0].shape
@@ -355,14 +454,26 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
             for sides in slabs]
     n_slabs = [len(sides) for sides in came]
     arrived = [promote_vma(x, axes) for sides in came for *_, x in sides]
+    # the blocks of a stage are written as many grid steps behind their
+    # input as the stage is: the last step's first, the last stage
+    wrote_plain, wrote_last = n_stages - rounds + 1, n_stages
+    # the row sums: a tile finishes `groups` of them; a block of theirs
+    # holds `phases` tiles', so that its rows are whole strips; a tile
+    # hands the next `held` strips of partial sums
+    n_summed = len(summed)
+    groups = tile // coarsen if summed else 0
+    phases = STRIP // math.gcd(groups, STRIP) if summed else 1
+    held = len(_spans(coarsen)) - 1 if summed else 0
 
     def kernel(*refs):
         refs = iter(refs)
-        (scalar_refs, taken, *brought, old, out, new, windows, rings, kept,
-         held_fields, held_point, rings_again) = (
+        on = next(refs) if summed else None
+        # `_room`: the sums as they came, which nobody reads
+        (scalar_refs, taken, *brought, old, _room, out, new, totals, windows,
+         rings, kept, partial, held_fields, held_point, rings_again) = (
             tuple(itertools.islice(refs, n)) for n in
-            (n_scalars, n_fields, *n_slabs, n_point, n_fields, n_point,
-             n_fields, n_second, n_kept,
+            (n_scalars, n_fields, *n_slabs, n_point, n_summed, n_fields, n_point,
+             n_summed, n_fields, n_second, n_kept, n_summed,
              *((n_fields, n_point, n_second) if steps > 1 else (0, 0, 0))))
         i = pl.program_id(0)
         applied = body(pltpu.roll, *scalar_refs)
@@ -506,6 +617,62 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
 
             lax.fori_loop(0, strips, run, 0)
 
+        def sum_rows():
+            """The sums over ``coarsen`` rows of the tiles that the last
+            step's stages have written to the summed fields' blocks,
+            into the fields' blocks of sums ("Output in the last walk"
+            has the arithmetic, :func:`_row_sums` makes it).  A tile's
+            strips are loaded again from its block, which is in VMEM,
+            some vector registers' columns at a time, so that what a sum
+            passes through stays in registers; the last columns are
+            done again with those before them where the registers do
+            not divide, and a walk's first and last grid steps, which
+            have written no new tile of a field, sum the field's first
+            and last again: either changes nothing, because a tile
+            reads the partial sums of the strip before it in one half
+            of ``partial`` and writes its own to the other.  One loop
+            for all the fields, and no branch: a process traces and
+            lowers this on every run."""
+            registers = lanes // LANES
+            wide = min(_SUMMED_AT_ONCE, registers)
+            sums = _row_sums(pltpu.roll, coarsen, wide * LANES)
+            work = []
+            for k, total, strips_kept in zip(summed, totals, partial):
+                # the tile the field's block holds; the row of the
+                # block of sums that its first group goes to, and the
+                # strips its groups fall in: one past the block's end is
+                # the block's last again
+                t = lax.clamp(
+                    0, sub(i, wrote_plain if k < n_plain else wrote_last), tiles - 1)
+                at = mul(lax.rem(t, phases), groups)
+                places = [
+                    strip(lax.min(add(lax.div(at, STRIP), n),
+                                  phases * groups // STRIP - 1))
+                    for n in range(-(-groups // STRIP) + 1)]
+                read = [strip(add(mul(lax.rem(t, 2), held), n)) for n in range(held)]
+                write = [strip(add(mul(lax.rem(add(t, 1), 2), held), n))
+                         for n in range(held)]
+                work.append((out[k], total, strips_kept, lax.rem(at, STRIP),
+                             places, read, write))
+
+            def run(n, carry):
+                columns = pl.ds(pl.multiple_of(mul(lax.min(
+                    mul(n, wide), registers - wide), LANES), LANES), wide * LANES)
+                for ref, total, strips_kept, at, places, read, write in work:
+                    new, kept = sums(
+                        at, ref[:, columns],
+                        [strips_kept[where, columns] for where in read],
+                        [total[where, columns] for where in places])
+                    # from the last, which holds nothing new where it
+                    # is the one before it again
+                    for where, value in reversed(list(zip(places, new))):
+                        total[where, columns] = value
+                    for where, value in zip(write, kept):
+                        strips_kept[where, columns] = value
+                return carry
+
+            lax.fori_loop(0, -(-registers // wide), run, 0)
+
         # stage q runs on tile i - 1 - q: tile i - 1 goes through first
         # while tile i - 2 goes through second, and so on (at the walk's
         # start on rings as they are found, into rings and blocks that a
@@ -523,6 +690,9 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
             def _():
                 strips_through([n_stages - 1])
 
+        if summed:
+            pl.when(eq(on[0], 1))(sum_rows)
+
         for k, (ref, win) in enumerate(zip(taken, windows)):
             win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
             win[pl.ds(STRIP, tile), :] = ref[...]
@@ -531,47 +701,167 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
     def block(lag):
         """Tile ``i - lag``, held to the field's own tiles."""
         return pl.BlockSpec(
-            (tile, lanes), lambda i: (lax.clamp(0, i - lag, tiles - 1), 0))
+            (tile, lanes), lambda i, *_: (lax.clamp(0, i - lag, tiles - 1), 0))
 
     struct = union_vma_struct(fields[0].shape, dtype, *fields, *scalars)
     in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     # a tile's rows of a slab of columns; a slab of rows whole, once
     block_of_slab = (
-        pl.BlockSpec((tile, G), lambda i: (lax.min(i, tiles - 1), 0)),
-        pl.BlockSpec((G, lanes), lambda i: (0, 0)))
+        pl.BlockSpec((tile, G), lambda i, *_: (lax.min(i, tiles - 1), 0)),
+        pl.BlockSpec((G, lanes), lambda i, *_: (0, 0)))
 
     def ring(slots):
         return pltpu.VMEM((slots * STRIP, lanes), dtype)
 
-    # the blocks of a stage are written as many grid steps behind their
-    # input as the stage is: the last step's first, the last stage
-    wrote_plain, wrote_last = n_stages - rounds + 1, n_stages
-    results = pl.pallas_call(
-        kernel,
+    def block_of_sums(lag):
+        """The sums of ``phases`` tiles, the last of them tile ``i -
+        lag``: a block stays while its tiles are written, and is
+        written back when the walk moves on to the next.  A walk that
+        does not sum stays on the first block, and so writes nothing
+        back but that block, once, with whatever VMEM held."""
+        return pl.BlockSpec(
+            (phases * groups, lanes),
+            lambda i, on: (mul(on[0], lax.div(
+                lax.clamp(0, i - lag, tiles - 1), phases)), 0))
+
+    if len(sums) != n_summed:
+        raise ValueError(f"{n_summed} fields to sum and room for {len(sums)}")
+    sums_struct = summed and union_vma_struct(
+        row_sums_shape(fields[0].shape, dtype, coarsen, n_fields + n_point),
+        dtype, *fields, *scalars)
+    # the room is the caller's and comes back written: no block of it is
+    # brought in, the results' blocks are written where it lies
+    sums = [promote_vma(x, axes) for x in sums]
+    specs = dict(
         grid=(tiles + n_stages,),
         in_specs=([in_smem] * n_scalars + [block(0)] * n_fields
                   + [block_of_slab[of_rows] for sides in came
                      for of_rows, *_ in sides]
-                  + [block(1)] * n_point),
+                  + [block(1)] * n_point
+                  + [pl.BlockSpec(memory_space=pl.ANY)] * n_summed),
         out_specs=([block(wrote_plain)] * n_plain + [block(wrote_last)] * n_second
-                   + [block(wrote_plain)] * n_point),
-        out_shape=[struct] * (n_fields + n_point),
+                   + [block(wrote_plain)] * n_point
+                   + [block_of_sums(wrote_plain if k < n_plain else wrote_last)
+                      for k in summed]),
         scratch_shapes=(
             [pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_fields
             + [ring(slots)] * n_second + [ring(1)] * n_kept
+            + [ring(max(1, 2 * held))] * n_summed  # at `coarsen` 2 nobody's
             + ([ring(n) for n in field_slots] + [ring(point_slots)] * n_point
-               + [ring(slots)] * n_second if steps > 1 else [])),
+               + [ring(slots)] * n_second if steps > 1 else [])))
+    # whether the walk sums is known to the blocks' index maps: an
+    # operand before the others, in SMEM before the grid starts
+    ahead = [promote_vma(jnp.reshape(summing, (1,)).astype(jnp.int32), axes)] * bool(summed)
+    if summed:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+    first_field = len(ahead) + n_scalars
+    results = pl.pallas_call(
+        kernel,
+        **specs,
+        out_shape=[struct] * (n_fields + n_point) + [sums_struct] * n_summed,
         input_output_aliases={
-            **{n_scalars + k: k for k in range(n_fields)},
-            **{n_scalars + n_fields + len(arrived) + k: n_fields + k
-               for k in range(n_point)}},
+            **{first_field + k: k for k in range(n_fields)},
+            **{first_field + n_fields + len(arrived) + k: n_fields + k
+               for k in range(n_point + n_summed)}},
         compiler_params=pltpu.CompilerParams(
             # in order: a step reads the window the step before left
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT * _buffers(steps) // 5),
         interpret=interpret,
-    )(*scalars, *fields, *arrived, *pointwise)
+    )(*ahead, *scalars, *fields, *arrived, *pointwise, *sums)
     return results
+
+
+def row_sums_shape(shape, dtype, coarsen, arrays=6):
+    """The shape of a field's sums over ``coarsen`` rows as a walk over
+    ``arrays`` arrays of ``shape`` (a step's six) writes them: whole
+    blocks of ``phases`` tiles' groups ("Output in the last walk"), the
+    same for a walk of one step and of two, whose tiles are the same
+    (:func:`tile_rows`)."""
+    rows, width = shape
+    tile = tile_rows(rows, width, dtype, arrays)
+    groups = tile // coarsen
+    phases = STRIP // math.gcd(groups, STRIP)
+    tiles = -(-rows // tile)
+    return -(-tiles // phases) * phases * groups, width
+
+
+def _spans(coarsen):
+    """The rows a group's partial sums hold as they double: 1, 2, ...
+    up to half of ``coarsen``."""
+    return [2 ** k for k in range(coarsen.bit_length() - 1)]
+
+
+def _astride(rows):
+    """The sublanes at which a group of ``rows`` rows that starts in a
+    strip ends in the next: groups start ``G`` rows into a block."""
+    return tuple(k for k in range(G % rows, STRIP, rows) if k + rows > STRIP)
+
+
+def _finished(coarsen):
+    """The sublanes that hold, after a strip's doubling, the sums of the
+    groups of ``coarsen`` rows that end in the strip, in the groups'
+    order: the sublane of a group's first row, the group astride the
+    strip before first."""
+    astride = _astride(coarsen)
+    starts = range(G % coarsen, STRIP, coarsen)
+    return [*astride, *(k for k in starts if k not in astride)]
+
+
+@functools.lru_cache
+def _row_sums(roll, coarsen, width):
+    """What makes a tile's sums over ``coarsen`` rows, on ``width``
+    columns: ``sums(at, tile, before, block)`` takes the tile's
+    rows, the partial sums the strip before the tile's
+    first left (``before``: for each span of :func:`_spans` but the
+    first, a strip), and the strips of the block of sums that the
+    tile's groups go to (``block``: the strip that holds the row of the
+    tile's first group, ``at`` rows into it, and those after it), and
+    returns those strips with the groups' sums in place and the partial
+    sums of the tile's last strip.  Jitted and kept, like the stages: a
+    process traces it once, for three fields.  ``roll``: as
+    :func:`_walk` hands it to a body."""
+    registers = width // LANES
+
+    def across(register):
+        return lax.concatenate([register] * registers, 1)
+
+    def sums(at, tile, before, block):
+        row = lax.broadcasted_iota(jnp.int32, (STRIP, LANES), 0)
+        found = []  # (sums, sublane) of the tile's groups, in order
+        for j in range(0, tile.shape[0], STRIP):
+            x, kept = lax.slice_in_dim(tile, j, j + STRIP), []
+            for span in _spans(coarsen):
+                if span > 1:
+                    # the half sums of a group that ends in this strip
+                    # and began in the one before
+                    kept.append(x)
+                    astride = functools.reduce(lax.bitwise_or, (
+                        eq(row, k) for k in _astride(2 * span)))
+                    x = select(across(astride), before[len(kept) - 1], x)
+                x = add(x, roll(x, STRIP - span, 0))
+            before = kept
+            found += [(x, k) for k in _finished(coarsen)]
+        block = list(block)
+        # a strip's worth of groups at a time: their rows of the block
+        # are as many sublanes, from `at` on and round to the strip after
+        for first in range(0, len(found), STRIP):
+            some = found[first:first + STRIP]
+            placed = None
+            for n, (x, k) in enumerate(some):
+                if (n - k) % STRIP:
+                    x = roll(x, (n - k) % STRIP, 0)
+                placed = x if placed is None else select(across(eq(row, n)), x, placed)
+            placed = roll(placed, at, 0)
+            member = lax.lt(lax.rem(add(sub(row, at), STRIP), STRIP), len(some))
+            low = lax.ge(row, at)
+            for k, rows in ((first // STRIP, low), (first // STRIP + 1, lax.bitwise_not(low))):
+                block[k] = select(
+                    across(lax.bitwise_and(member, rows)), placed, block[k])
+        return block, before
+
+    return jax.jit(sums)
 
 
 def _pieces(lo, n, every):
@@ -763,10 +1053,11 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
 @functools.partial(
     jax.jit,
     static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
-                     "coriolis_beta", "steps", "interpret"))
+                     "coriolis_beta", "steps", "coarsen", "interpret"))
 def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
-              a, b, lone=False, *, nu, dx, dy, dt, gravity, coriolis_f,
-              coriolis_beta, steps=1, interpret=False):
+              a, b, lone=False, summing=True, sums=(), *, nu, dx, dy, dt,
+              gravity, coriolis_f, coriolis_beta, steps=1, coarsen=0,
+              interpret=False):
     """A step of :func:`shallow_water._step_wide` after the wire of its
     first halo exchange, with no second one: the ghost writes of the
     first, the tendencies of ``h``, ``u`` and
@@ -857,4 +1148,6 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     flags = [is_south, is_north] + [lone] * (steps == 2)
     return _walk(body, [jnp.stack(flags).astype(jnp.int32), floats], [h, u, v],
                  slabs, [dh, du, dv], n_second=2 if nu > 0 else 0,
-                 n_carried=4, steps=steps, interpret=interpret)
+                 n_carried=4, steps=steps,
+                 summed=(0, 1, 2) if coarsen else (), coarsen=coarsen,
+                 summing=summing, sums=sums, interpret=interpret)

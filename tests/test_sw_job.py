@@ -134,6 +134,114 @@ def test_snapshots_against_the_plain_reference(
                       ).max() <= 3e-5
 
 
+def _through_the_kernel(monkeypatch):
+    """The ``ghost`` 2 step as it runs on TPU devices, here: the kernel
+    in Pallas's interpret mode, as ``tests/test_sw_kernels.py`` forces
+    it."""
+    import functools
+
+    from mpi4jax_tpu.models import sw_kernels
+
+    wide_step = sw_kernels.wide_step
+    monkeypatch.setattr(
+        sw_kernels, "wide_step",
+        lambda *a, **kw: wide_step(*a, **dict(kw, interpret=True)))
+    monkeypatch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: cfg.ghost == 2)
+    monkeypatch.setattr(
+        jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_where_the_step_is_the_kernel_a_snapshot_is_finished_from_its_row_sums(
+        reference, seeded, on_one_device, mesh_shape, monkeypatch, tmp_path):
+    """Where the step is the kernel and the blocks are made of a strip's
+    rows, a call takes the room for the row sums of ``h``, ``u``, ``v``
+    beside the state, donates both, and returns them written, and
+    ``snap`` is handed those sums: the snapshots are the array
+    code's to roundoff and the block means of the state they name, the
+    state is a job without output's bit for bit, the counter and the
+    span say what happened, and ``compile()`` and ``resume()`` lower
+    ``snap`` with what ``advance`` hands it."""
+    comm = _comm(mesh_shape)
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=2)
+    _through_the_kernel(monkeypatch)
+    got = []
+    job = sw.make_job(
+        cfg, comm, STEPS_A_CALL, sw.Snapshot(coarsen=COARSEN),
+        lambda s, step: got.append((step, s)),
+        sw.Checkpoint(tmp_path / "saves", every_calls=0))
+    start = _state(cfg, comm, seeded)
+    job.start(start)
+    job.advance(2)
+    lowered = job.multi.lower(job.state, job._sums)
+    handed = lowered.out_info[1]
+    assert [(a.shape, a.dtype) for a in handed] == [
+        (a.shape, a.dtype) for a in job._sums] and len(handed) == 3
+    assert [a.shape[1] for a in handed] == [a.shape[1] for a in job.state[:3]]
+    assert all(a.shape[0] < job.state.h.shape[0] // 2 for a in handed)
+    # a call allocates nothing: the state and the room are both donated
+    assert all(a.donated for a in jax.tree.leaves(lowered.args_info))
+    with pytest.raises(ValueError, match="from_sums=True.*row sums"):
+        job.snap(*job._written())
+    with pytest.raises(ValueError, match="from_sums=False.*padded field"):
+        sw.make_snapshot(cfg, comm, job.snapshot)(*job._sums)
+    job.save()
+    job.compile()  # from here on the executables, lowered with the sums
+    assert not hasattr(job._snap, "lower")
+    job.advance(2)
+    job.drain()
+    assert [step for step, _ in got] == STEPS
+    stats = job.stats()
+    assert stats["snapshots_summed_in_step"] == stats["snapshots_produced"] == CALLS
+    assert len(_named(job, "job/enqueue", program="snap", handed="row_sums")) == CALLS
+    for (_, snapshot), (_, array_code) in zip(got, on_one_device(2)):
+        for k in FIELDS:
+            assert snapshot[k].shape == (NY // COARSEN, NX // COARSEN)
+            assert snapshot[k].dtype == np.float32
+            assert np.abs(snapshot[k] - array_code[k]).max() <= 1e-4
+    for k in FIELDS:
+        whole = _interior(getattr(job.state, k), 2, mesh_shape)
+        assert np.abs(got[-1][1][k] - reference.block_mean(whole, COARSEN)
+                      ).max() <= 3e-5
+    # the state: what a job without output returns
+    plain = sw.make_job(cfg, comm, STEPS_A_CALL)
+    plain.start(start)
+    plain.advance(CALLS)
+    for a, b in zip(job.state, plain.state):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # resumed from the save after two calls: the same output from there on
+    again = []
+    other = sw.make_job(
+        cfg, comm, STEPS_A_CALL, sw.Snapshot(coarsen=COARSEN),
+        lambda s, step: again.append((step, s)),
+        sw.Checkpoint(tmp_path / "saves", every_calls=0))
+    assert other.resume() == STEPS[1]
+    assert not hasattr(other._snap, "lower")
+    other.advance(2)
+    other.drain()
+    assert [step for step, _ in again] == STEPS[2:]
+    for (_, a), (_, b) in zip(again, got[2:]):
+        for k in FIELDS:
+            np.testing.assert_array_equal(a[k], b[k])
+
+
+@pytest.mark.parametrize("ghost,coarsen", [(1, 4), (4, 4), (2, 1), (2, 4)])
+def test_the_array_code_hands_its_snapshot_program_the_fields(seeded, ghost, coarsen):
+    """On every backend but TPU devices, and at ``coarsen`` 1, ``snap``
+    reads ``h``, ``u``, ``v`` as it did: the call returns the state
+    alone and the counter stays at zero."""
+    cfg = sw.SWConfig(ny=NY, nx=NX, ghost=ghost)
+    job, got = _run(cfg, _comm((1, 1)), seeded, sw.Snapshot(coarsen=coarsen), calls=2)
+    assert isinstance(job.multi.lower(job.state).out_info, sw.SWState)
+    assert job._sums == () and sw.make_sums_room(cfg, job.comm, job.snapshot) is None
+    with pytest.raises(ValueError, match="nothing to finish"):
+        sw.make_snapshot(cfg, job.comm, job.snapshot, from_sums=True)
+    stats = job.stats()
+    assert stats["snapshots_produced"] == 2 and stats["snapshots_summed_in_step"] == 0
+    assert len(_named(job, "job/enqueue", program="snap", handed="fields")) == 2
+    assert not _named(job, "job/enqueue", program="snap", handed="row_sums")
+
+
 @pytest.mark.parametrize("ghost", [1, 2])
 @pytest.mark.parametrize("coarsen", [1, 2, 8])
 def test_coarsen_one_is_the_field_and_any_divisor_its_block_mean(

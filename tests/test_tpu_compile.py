@@ -121,7 +121,7 @@ def _kernels(text):
     found = {}
     for line in text.splitlines():
         if "tpu_custom_call" in line:
-            name = line.split("=")[0].split("%")[1].split(".")[0]
+            name = line.split("=")[0].split("%")[1].split(".")[0].strip()
             shape = re.search(r"= \(?(\w+\[[\d,]*\])", line)[1]
             operands = line.split("custom-call(")[1].split(")")[0]
             operands = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")
@@ -606,13 +606,22 @@ def test_the_v5e_programs_text_says_where_an_instruction_came_from(
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
 def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
         v5e, mesh_shape):
-    """``make_job`` with a snapshot: the call's multistep is the donated
-    program (no ``copy`` of a field: output does not turn donation off),
-    and the snapshot program, at the benchmark cell's size a chip, is one
-    ``reduce-window`` a field laid over the padded block as it is, under
-    ``mpi4jax_tpu.snapshot/coarsen``, with no temporary of a field's size
-    (a slice of the interior first would be one; the plain ``reshape``
-    to ``(ny/4, 4, nx/4, 4)`` takes 13.7 GB)."""
+    """``make_job`` with a snapshot, at the benchmark cell's size a
+    chip: the call's multistep is the donated program (no ``copy`` of a
+    field: output does not turn donation off) and holds **one** kernel
+    text in two places: in its loop with the sums switched off by a
+    scalar, and after the loop the one walk that writes the sums over
+    four rows of ``h``, ``u``, ``v``, a quarter of a field each, into
+    the room the program is handed beside the state and hands back:
+    both donated, every result in an operand's place, so that a call
+    allocates nothing and has no temporary of the sums' size; the
+    job's first step is that kernel too, so a process builds one.  A
+    job without output runs the kernel without sums, as it did.  The snapshot program, lowered with the sums, is
+    one ``reduce-window`` a field along the rows of sums as they lie,
+    under ``mpi4jax_tpu.snapshot/coarsen``, with no temporary (a slice
+    of the interior first would be one; on whole fields the plain
+    ``reshape`` to ``(ny/4, 4, nx/4, 4)`` takes 13.7 GB)."""
+    from mpi4jax_tpu.models import sw_kernels
     from perfbench.harness import scopes
 
     py, px = mesh_shape
@@ -627,18 +636,59 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         jax.eval_shape(sw.make_init(cfg, comm)))
 
-    multi = job.multi.lower(state).compile()
+    room = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(job._room))
+    lowered = job.multi.lower(state, room)
+    multi = lowered.compile()
     text = multi.as_text()
-    _, fields, _, _ = _kernels(text)["wide_step"]
-    assert len(fields) == 6 and not _copied(text, fields), fields
-    # on four chips XLA transposes each field once to slice its column
-    # slabs (PERF.md section 7); on one nothing of a field's size is copied
+    in_loop, last = _kernel_calls(text)
+    quiet = sw.make_job(cfg, comm, 10).multi.lower(state).compile().as_text()
+    (_, _, without, _), = _kernel_calls(quiet)
+    first, = _kernel_calls(job.first.lower(state).compile().as_text())
+    # the first step makes the room it returns beside the state
+    assert [(a.shape, a.dtype) for a in job.first.lower(state).out_info[1]] == [
+        (a.shape, a.dtype) for a in room]
+    assert in_loop[2] == last[2] == first[2] != without
+    # the loop runs every walk but the call's last
+    walks = 5 if py * px == 1 else 10
+    assert _trips(text) == walks - 1 and _trips(quiet) == walks
+    for line, fields, _, _ in (in_loop, last):
+        assert len(fields) == 6 and not _copied(text, fields), fields
+        # the room: the call's last three operands, no copies either
+        operands = line.split("custom-call(")[1].split(")")[0].split(", ")
+        assert not _copied(text, operands[-3:]), operands
+    # on four chips XLA transposes each field once a step to slice its
+    # column slabs (PERF.md section 7), in the loop's step and in the
+    # last; on one nothing of a field's size is copied
     copies = re.findall(r"= f32\[7204,14404\]\S* copy\(", text)
-    assert len(copies) == (0 if py * px == 1 else 3)
+    assert len(copies) == (0 if py * px == 1 else 2 * 3)
+    # three arrays of row sums beside the state: 76 blocks of 24 rows,
+    # four tiles' sums each, whole width, in and out
+    new_state, sums = lowered.out_info
+    assert [a.shape for a in new_state] == [a.shape for a in state]
+    assert [(a.shape, a.dtype) for a in sums] == [(a.shape, a.dtype) for a in room] == [
+        ((1824 * py, 14404 * px), jnp.float32)] * 3
+    assert all(a.donated for a in jax.tree.leaves(lowered.args_info))
+    memory = multi.memory_analysis()
+    # every argument is a result's room (the results' tuple is the rest)
+    assert memory.argument_size_in_bytes == memory.alias_size_in_bytes >= (
+        (6 * 7204 + 3 * 1824) * 14404 * 4)
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
+    # the walks in the loop name the sums as results like the last, and
+    # write them where the room lies: no temporary of the sums' size
     if py * px == 1:
-        assert multi.memory_analysis().temp_size_in_bytes < 7204 * 14404 * 4
+        assert memory.temp_size_in_bytes < 2**20
+    # what the compiler laid out in VMEM for the walk that can sum: over
+    # the walk without, under the limit the walk is given
+    used, limit = _scoped_vmem(last[0])
+    assert limit == sw_kernels._VMEM_LIMIT * sw_kernels._buffers(
+        2 if py * px == 1 else 1) // 5
+    assert _scoped_vmem(_kernel_calls(quiet)[0][0])[0] < used < limit
+    if py * px == 1:
+        assert sw_kernels.tile_rows(7204, 14404, jnp.float32, 6, steps=2) == 24
 
-    snap = job.snap.lower(state.h, state.u, state.v).compile()
+    snap = job.snap.lower(*room).compile()
     table = scopes.origins(snap.as_text())
     under = [o for o in table.values()
              if o.scopes[:2] == ("mpi4jax_tpu.snapshot", "coarsen")]
@@ -647,9 +697,13 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
     assert snap.as_text().count(" reduce-window(") == 3
     assert "collective-permute" not in snap.as_text()  # each chip its own block
     mem = snap.memory_analysis()
-    # three coarse fields, their rows filled up to whole vector registers
+    # a quarter of a field in (and the rows that fill a block), a
+    # sixteenth out, its rows filled up to whole vector registers
+    assert 3 * 1824 * 14404 * 4 <= mem.argument_size_in_bytes <= (
+        3 * 1824 * 14464 * 4 + 4096)
     assert 3 * 1800 * 3600 * 4 <= mem.output_size_in_bytes <= 3 * 1800 * 3712 * 4 + 4096
-    assert mem.temp_size_in_bytes < 7204 * 14404 * 4
+    assert mem.temp_size_in_bytes == 0
+    assert scopes.signature(snap.as_text()).taken == 3 * 1824 * 14404 * 4
 
 
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
@@ -746,7 +800,11 @@ def test_a_job_with_both_halves_compiles_the_programs_of_its_halves(v5e, mesh_sh
     state = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         jax.eval_shape(sw.make_init(cfg, comm)))
-    fields = (state.h, state.u, state.v)
+    # where the step is the kernel a call takes the row sums' room beside
+    # the state and its snapshot program reads that
+    room = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(both._room))
 
     def instructions(program, *args):
         """A program's instructions without where they were traced from."""
@@ -754,11 +812,11 @@ def test_a_job_with_both_halves_compiles_the_programs_of_its_halves(v5e, mesh_sh
         return text, [re.sub(r",? metadata=\{[^}]*\}", "", line)
                       for line in text.splitlines() if " = " in line]
 
-    text, multi = instructions(both.multi, state)
-    assert multi == instructions(written.multi, state)[1] and len(multi) > 20
+    text, multi = instructions(both.multi, state, room)
+    assert multi == instructions(written.multi, state, room)[1] and len(multi) > 20
     assert "input_output_alias" in text.split("ENTRY")[0]
     assert not _copied(text, _kernels(text)["wide_step"][1])
-    assert instructions(both.snap, *fields)[1] == instructions(written.snap, *fields)[1]
+    assert instructions(both.snap, *room)[1] == instructions(written.snap, *room)[1]
     assert instructions(both.stage, state)[1] == instructions(saved.stage, state)[1]
     # what the bound holds, a chip's share each: copies of 77.76 MB and 4 MiB
     one = 3 * 1800 * 3600 * 4
