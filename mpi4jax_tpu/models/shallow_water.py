@@ -111,6 +111,15 @@ class SWConfig:
     # all widths (tested equal to the narrow path).
     ghost: int = 1
 
+    def __post_init__(self):
+        if self.ghost not in (1, 2, 4):
+            raise ValueError(
+                f"ghost={self.ghost!r}: the ghost ring is 1 cell wide (the "
+                "reference's step as written, twelve exchanges a step), 2 "
+                "(the wide-halo schedule: the step's kernel on TPU devices "
+                "in float32, array code with five exchanges elsewhere) or 4 "
+                "(the single-exchange schedule, array code)")
+
     @property
     def lateral_viscosity(self):
         return 1e-3 * self.coriolis_f * self.dx**2
@@ -366,8 +375,6 @@ def shallow_water_step(state, cfg, comm, *, first_step=False, token=None):
         return state, token
     if cfg.ghost == 4:
         return _step_wide4(state, cfg, comm, first_step=first_step, token=token)
-    if cfg.ghost != 1:
-        raise ValueError(f"ghost width must be 1, 2 or 4, got {cfg.ghost}")
     token = as_token(token)
     per = (False, cfg.periodic_x)
     is_north, _is_south = _wall_masks(comm)

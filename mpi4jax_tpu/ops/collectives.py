@@ -217,26 +217,6 @@ def barrier(*, comm=None, token=None):
     raise _unsupported("barrier", comm)
 
 
-def _bcast_schedule(size, nbytes):
-    """Pick the bcast schedule.
-
-    ``tree`` (binomial ppermute ladder) does ``ceil(log2 n)`` rounds —
-    latency-optimal on high-latency fabrics, total traffic
-    ``~payload*log2(n)``.  ``psum`` (masked all-reduce) costs one ring
-    all-reduce, ``~2*(n-1)/n*payload``.  Measured on the 8-device
-    virtual mesh (docs/performance.md "bcast schedule measurement")
-    psum wins at every payload from 4 KB to 64 MB, so it is the
-    default; override with MPI4JAX_TPU_BCAST=tree|psum.
-    """
-    import os
-
-    del size, nbytes
-    forced = os.environ.get("MPI4JAX_TPU_BCAST")
-    if forced in ("tree", "psum"):
-        return forced
-    return "psum"
-
-
 def _bcast_psum(xv, root, comm):
     """Masked all-reduce: non-root contributions zeroed, one psum
     delivers the root's value everywhere."""
@@ -245,34 +225,15 @@ def _bcast_psum(xv, root, comm):
     return reductions.group_psum(masked, comm.axes, comm.groups)
 
 
-def _bcast_tree(xv, root, comm):
-    """Binomial-tree broadcast: round k ppermutes the payload from the
-    first ``2**k`` (root-relative) ranks to the next ``2**k``."""
-    size = comm.size
-    rank = comm.rank()
-    vrank = (rank - root) % size  # traced; perms below are static
-    acc = jnp.where(rank == root, xv, jnp.zeros_like(xv))
-    k = 1
-    while k < size:
-        pairs = [
-            ((v + root) % size, (v + k + root) % size)
-            for v in range(min(k, size - k))
-        ]
-        shifted = lax.ppermute(acc, comm.axes, comm.expand_perm(pairs))
-        acc = jnp.where((vrank >= k) & (vrank < 2 * k), shifted, acc)
-        k *= 2
-    return acc
-
-
 @publishes_token
 def bcast(x, root, *, comm=None, token=None):
     """Broadcast ``x`` from ``root`` to every rank (reference:
     mpi4jax/_src/collective_ops/bcast.py:36-72).
 
-    Two mesh schedules (selected by :func:`_bcast_schedule`): masked
-    ``psum`` by default (measured fastest at every payload size), with
-    a binomial ``ppermute`` tree available via ``MPI4JAX_TPU_BCAST=tree``
-    for high-latency fabrics.
+    On a mesh it is a masked ``psum`` (:func:`_bcast_psum`): the one
+    schedule, faster than a binomial ``ppermute`` tree at 8 B and at
+    4 MiB on a 2x2 of v5e chips (docs/performance.md "bcast schedule
+    measurement").
     """
     x, comm, token = _prologue(x, comm, token)
     root = check_root(root, comm)
@@ -284,10 +245,7 @@ def bcast(x, root, *, comm=None, token=None):
         as_int = x.dtype == jnp.bool_
         xv = x.astype(jnp.int8) if as_int else x
         xv = promote_vma(xv, comm.axes)
-        if _bcast_schedule(comm.size, xv.size * xv.dtype.itemsize) == "tree":
-            y = _bcast_tree(xv, root, comm)
-        else:
-            y = _bcast_psum(xv, root, comm)
+        y = _bcast_psum(xv, root, comm)
         if as_int:
             y = y.astype(jnp.bool_)
         token, (y,) = fence_out(token, y)

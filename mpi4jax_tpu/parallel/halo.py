@@ -112,9 +112,12 @@ def halo_exchange_2d_batch(arrs, comm, *, periodic=(False, True), token=None,
     Same contract as :func:`halo_exchange_2d`, but the per-direction
     slabs of all arrays travel in a single stacked ``sendrecv`` — one
     ``ppermute`` per direction for the whole field group instead of one
-    per field.  Fewer, larger ICI transfers win on real multi-chip
-    meshes; on a single chip permutes are elided and the stacking copies
-    cost, so the per-field function is preferred there.
+    per field.  Whether fewer, larger transfers win on a mesh of chips
+    no chip has been asked: three fields stacked against three single
+    exchanges is an A/B nobody has run (``ROADMAP.md`` D13 names its
+    sizes).  The multi-process tier's coalesced frame goes through this
+    form, and ``SWConfig(ghost=4)``'s step makes its one exchange with
+    it.
 
     Returns ``(list_of_arrs, token)``.
     """

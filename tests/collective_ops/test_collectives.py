@@ -79,13 +79,29 @@ def test_bcast(comm1d, root):
 
 @pytest.mark.parametrize("schedule", ["tree", "psum"])
 @pytest.mark.parametrize("root", [0, 3])
-def test_bcast_schedules_agree(comm1d, root, schedule, monkeypatch):
-    monkeypatch.setenv("MPI4JAX_TPU_BCAST", schedule)
+def test_bcast_takes_no_schedule_from_the_environment(
+        comm1d, root, schedule, monkeypatch):
+    """``MPI4JAX_TPU_BCAST`` switched the mesh tier's ``bcast`` to a
+    binomial ``ppermute`` tree until the chip had it lose at 8 B and at
+    4 MiB (PERF.md, PR 50): a process that still sets it gets the masked
+    ``psum``, the lowered text of the call without it, and the root's
+    value."""
 
     def fn(x):
         y, _ = m.bcast(x * 10, root, comm=comm1d)
         return y
 
+    def text():
+        # a jit of its own each time: a trace is cached by the function
+        f = jax.jit(jax.shard_map(
+            fn, mesh=comm1d.mesh, in_specs=jax.P(comm1d.axes),
+            out_specs=jax.P(comm1d.axes)))
+        return f.lower(world_input()).as_text()
+
+    unset = text()
+    monkeypatch.setenv("MPI4JAX_TPU_BCAST", schedule)
+    assert text() == unset
+    assert "collective_permute" not in unset and "all_reduce" in unset
     out = _run(comm1d, fn)
     assert np.array_equal(np.asarray(out), np.full(SIZE, 10.0 * root))
 

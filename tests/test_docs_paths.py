@@ -4,9 +4,12 @@ A backticked path under one of the repository's top-level directories
 that ends in ``.py``, ``.sh`` or ``.json`` (a ``:line`` or ``::test``
 suffix and a ``*`` wildcard allowed) must name a file of this checkout:
 a document that describes a tool that is gone sends every reader after
-it.
+it.  And an environment variable of the library's (``MPI4JAX_TPU_*``,
+``T4J_*``) that a document names must be one the sources read: a switch
+that is gone, still documented, is set by a reader and does nothing.
 """
 
+import functools
 import pathlib
 import re
 
@@ -53,3 +56,51 @@ def test_every_path_a_document_names_exists(doc):
     assert named, f"{doc} names no file: the pattern has gone blind"
     missing = [p for p in named if not any(REPO.glob(p))]
     assert missing == []
+
+
+_VARIABLE = re.compile(r"\b((?:MPI4JAX_TPU|T4J)_(?:[A-Z0-9_]*[A-Z0-9])?)(_?\*)?")
+
+
+def variables_named(text):
+    """The library's environment variables in ``text``, as ``(name,
+    is_prefix)``: ``T4J_BACKOFF_*`` names the prefix ``T4J_BACKOFF``."""
+    return sorted({(name, bool(star)) for name, star in _VARIABLE.findall(text)
+                   if star or not name.endswith("_")})
+
+
+@functools.cache
+def variables_read():
+    """Every such name in the sources that can read one: the package's
+    ``.py``, ``.cc`` and ``.h``, and ``setup.py`` (the install-time
+    prebuild's switch)."""
+    sources = [REPO / "setup.py", *(
+        p for ext in ("py", "cc", "h")
+        for p in (REPO / "mpi4jax_tpu").rglob(f"*.{ext}"))]
+    return frozenset(
+        name for p in sources
+        for name, _ in variables_named(p.read_text(errors="replace")))
+
+
+def test_variables_named_reads_names_and_prefixes():
+    text = ("`T4J_RETRY_MAX=0`, `T4J_BACKOFF_*`/`T4J_RETRY_*`, every `T4J_*` "
+            "and MPI4JAX_TPU_NO_FENCE, not MY_T4J_THING")
+    assert variables_named(text) == [
+        ("MPI4JAX_TPU_NO_FENCE", False), ("T4J_", True), ("T4J_BACKOFF", True),
+        ("T4J_RETRY", True), ("T4J_RETRY_MAX", False)]
+
+
+@pytest.mark.parametrize("doc", [
+    "docs/api.md", "docs/performance.md", "docs/observability.md",
+    "docs/failure-semantics.md", "docs/serving.md", "docs/async.md",
+    "PARITY.md",
+])
+def test_every_variable_a_document_names_is_one_the_sources_read(doc):
+    named = variables_named((REPO / doc).read_text())
+    assert named, f"{doc} names no variable: the pattern has gone blind"
+    read = variables_read()
+
+    def is_read(name, prefix):
+        return any(r.startswith(name) for r in read) if prefix else name in read
+
+    assert [name + "*" * prefix for name, prefix in named
+            if not is_read(name, prefix)] == []

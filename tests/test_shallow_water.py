@@ -4,6 +4,8 @@ distributed-correctness property the reference cannot check easily:
 bit-level-ish decomposition invariance).
 """
 
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,10 +146,23 @@ def test_wide_equals_narrow(shape):
     np.testing.assert_allclose(h_wide, h_narrow, rtol=0, atol=1e-3)
 
 
-def test_wide_decomposition_invariance():
+@pytest.mark.parametrize("shape", [(2, 4), (4, 2), (2, 1)])
+def test_wide_decomposition_invariance(shape):
+    # a mesh taller than wide, and one cut along y alone: walls on two of
+    # four and on both of two blocks, as test_wide_equals_narrow's meshes
     h_ref = run_h((1, 1), cfg=WIDE)
-    h = run_h((2, 4), cfg=WIDE)
+    h = run_h(shape, cfg=WIDE)
     np.testing.assert_allclose(h, h_ref, atol=2e-4)
+
+
+@pytest.mark.parametrize("ghost", [0, 3, 5])
+def test_a_ghost_width_without_a_schedule_is_refused_where_it_is_given(ghost):
+    """One place knows the widths a step exists for: the configuration,
+    which names each and what it is, before anything is built on it."""
+    with pytest.raises(ValueError, match=r"ghost=%d.* 1 cell.* 2 \(.* or 4 \(" % ghost):
+        sw.SWConfig(ny=24, nx=48, ghost=ghost)
+    with pytest.raises(ValueError, match=f"ghost={ghost}"):
+        replace(WIDE, ghost=ghost)
 
 
 WIDE4 = sw.SWConfig(ny=24, nx=48, ghost=4)

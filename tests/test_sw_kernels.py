@@ -14,7 +14,7 @@ import os
 import subprocess
 import sys
 import types
-from dataclasses import dataclass, replace
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -25,216 +25,9 @@ import mpi4jax_tpu as m
 from mpi4jax_tpu.analysis import verify_comm
 from mpi4jax_tpu.models import shallow_water as sw
 from mpi4jax_tpu.models import sw_kernels
-
-G = 2
-
-# rows x width of one device's padded block, and the VMEM budget the
-# tiling is given (None: its own): 52 rows leave a last tile of 4 under
-# tiles of 48; 184 x 364 is the demo grid's block, one tile; 21 rows are
-# no multiple of 8; the small budgets cut 100 rows into tiles of 8, 24;
-# 256 columns fill their vector registers, so that a rotation's wrap
-# lands in the ghost columns and not past them; 33 rows of 129 columns
-# have their northern ghost rows in two tiles and their eastern ghost
-# columns in two vector registers
-SHAPES = {
-    "aligned-36x256": (36, 256, None),
-    "astride-33x129": (33, 129, None),
-    "ragged-52x100": (52, 100, None),
-    "demo-184x364": (184, 364, None),
-    "odd-21x40": (21, 40, None),
-    "tiles-of-8-100x140": (100, 140, 8 * 10 * 1024),
-    "tiles-of-24-100x140": (100, 140, 24 * 10 * 1024),
-}
-WALLS = {"south": (True, False), "north": (False, True),
-         "both": (True, True), "neither": (False, False)}
-# round 1 in units of its own, so that every term of every tendency is
-# of order one and float32's roundoff of order 1e-7: a rotation that
-# changes by half from the first row to the last of the tallest block
-UNIT = dict(dx=1.0, dy=0.8, gravity=1.0, depth=1.0, coriolis_f=1.0,
-            coriolis_beta=4e-3, ghost=G)
-
-
-def _budget(monkeypatch, shape, steps=1):
-    """``SHAPES[shape]`` with its VMEM budget in place, scaled to the
-    call's six arrays so that the tiles are the name's, of a walk of two
-    ``steps`` as of one."""
-    rows, width, budget = SHAPES[shape]
-    if budget is not None:
-        # the budget is no argument of the jitted call: a trace under
-        # another budget, of the same shapes, would be taken for this one's
-        sw_kernels.wide_step.clear_cache()
-        monkeypatch.setattr(sw_kernels, "_VMEM_BLOCK_BUDGET", budget * 3)
-        tile = sw_kernels.tile_rows(rows, width, jnp.float32, 6, steps)
-        assert tile == int(shape.split("-")[2]) and rows > 3 * tile
-    return rows, width
-
-
-def _ring(shape, ring):
-    """The cells of a padded block's ghost ring ``ring`` (2: outermost)."""
-    inside = np.zeros(shape, bool)
-    inside[G - ring:shape[0] - G + ring, G - ring:shape[1] - G + ring] = True
-    inside[G - ring + 1:shape[0] - G + ring - 1,
-           G - ring + 1:shape[1] - G + ring - 1] = False
-    return inside
-
-
-@dataclass(frozen=True)
-class _Viscous(sw.SWConfig):
-    """A configuration whose friction is set apart from its rotation:
-    round 1 in ``UNIT`` with a friction strong enough to see (``dt * nu
-    / dx**2`` is 0.02, not the 1e-4 of the unit rotation's own), so that
-    an error in a stencil or a mask of either round is four orders of
-    magnitude over float32's roundoff."""
-
-    nu: float = 0.0
-
-    @property
-    def lateral_viscosity(self):
-        return self.nu
-
-
-def _as_a_step_finds_it(fresh, south, north, stale):
-    """A block with fresh ghosts as the step's kernel is handed it: its
-    ghost cells ``stale`` wherever a slab brings them, and the four
-    slabs an exchange would bring, west, east, south, north.  Beyond a
-    wall no neighbour sends: that slab is ``None``, as on a mesh one
-    device high, and those ghost rows are the block's own but for their
-    ends, which the x slabs bring."""
-    fresh = np.asarray(fresh)
-    block = fresh.copy()
-    block[:, :G] = block[:, -G:] = stale
-    if not south:
-        block[:G] = stale
-    if not north:
-        block[-G:] = stale
-    slabs = (fresh[:, :G], fresh[:, -G:],
-             None if south else fresh[:G], None if north else fresh[-G:])
-    return block, slabs
-
-
-def _interpreted(cfg):
-    """The kernel's keywords for ``cfg``, in Pallas's interpret mode."""
-    return dict(
-        nu=cfg.nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt, gravity=cfg.gravity,
-        coriolis_f=cfg.coriolis_f, coriolis_beta=cfg.coriolis_beta,
-        interpret=True)
-
-
-@functools.lru_cache
-def _definition(cfg, first_step, south, north):
-    """The array code of ``sw._step_wide`` after its first exchange on
-    one device's block of ``cfg.ny + 4`` x ``cfg.nx + 4``, which it asks
-    its mesh the place of.  Round 1 runs on a block **one ring larger**
-    wherever no wall stands (a row more on a side without a wall, a
-    column more on either side), whose interior is the block's interior
-    and ring 1: there ring 1 is fresh, as the second exchange would
-    make it.  Round 2 runs on that result cut back to the block.
-    Returns the block's ``h``, ``u``, ``v`` after both rounds and the
-    tendencies at the larger interior's shape."""
-    mesh = jax.make_mesh(
-        (1, 1), ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    comm = m.MeshComm.from_mesh(mesh)
-    below, above = int(not south), int(not north)
-    larger = replace(cfg, ny=cfg.ny + below + above, nx=cfg.nx + 2)
-    walls = jnp.bool_(south), jnp.bool_(north)
-
-    def rounds(h, u, v, dh, du, dv):
-        h, u, v, dh, du, dv = sw._tendency_round(
-            h, u, v, dh, du, dv, larger, comm, *walls, first_step)
-        h, u, v = (x[below:x.shape[0] - above, 1:-1] for x in (h, u, v))
-        if cfg.nu > 0:
-            u, v = sw._viscosity_round(u, v, cfg, *walls)
-        return h, u, v, dh, du, dv
-
-    block = jax.P("y", "x")
-    return jax.jit(jax.shard_map(
-        rounds, mesh=mesh, in_specs=(block,) * 6, out_specs=(block,) * 6))
-
-
-@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
-@pytest.mark.parametrize("first_step", [False, True], ids=["ab2", "euler"])
-@pytest.mark.parametrize("walls", sorted(WALLS))
-@pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_the_kernel_matches_the_array_code(
-        shape, walls, first_step, nu, monkeypatch):
-    rows, width = _budget(monkeypatch, shape)
-    south, north = WALLS[walls]
-    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
-    below, above = int(not south), int(not north)
-    # the larger block, and where the kernel's lies in it
-    big = (rows + below + above, width + 2)
-    cut = (slice(below, below + rows), slice(1, 1 + width))
-    keys = jax.random.split(jax.random.PRNGKey(1), 6)
-    fields = [
-        mean + spread * jax.random.normal(key, big, jnp.float32)
-        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
-    old = [0.5 * jax.random.normal(
-        key, (big[0] - 2 * G, big[1] - 2 * G), jnp.float32)
-        for key in keys[3:]]
-    want = [np.asarray(x) for x in _definition(
-        cfg, first_step, south, north)(*fields, *old)]
-
-    ring1, ring2 = _ring((rows, width), 1), _ring((rows, width), 2)
-    inner = ~(ring1 | ring2)
-    # ring 1 beyond a wall is no neighbour's: nothing there is touched
-    beyond = np.zeros((rows, width), bool)
-    beyond[G - 1], beyond[rows - G] = south, north
-    beyond &= ring1
-    fresh = ring1 & ~beyond
-
-    def padded(x, name):
-        """A tendency of the larger block's interior at the kernel's
-        block's shape: zero on ring 2, beyond a wall and, dh's, on
-        ring 1."""
-        x = np.pad(np.asarray(x), G)[cut]
-        return np.where(inner | (fresh if name != "dh" else False), x, 0)
-
-    names = ("dh", "du", "dv")
-    if first_step:
-        a, b, mine = 1.0, 0.0, [np.zeros((rows, width), np.float32)] * 3
-    else:
-        a, b = cfg.ab_a, cfg.ab_b
-        mine = [padded(x, name) for x, name in zip(old, names)]
-    # the kernel starts from ghosts that would wreck every stencil next
-    # to them, and from the slabs that hold the fresh ones
-    blocks, slabs = zip(*(
-        _as_a_step_finds_it(x[cut], south, north, 1e3) for x in fields))
-    got = sw_kernels.wide_step(
-        *blocks, *mine, slabs, jnp.bool_(south), jnp.bool_(north),
-        below, a, b, **_interpreted(cfg))
-    got = [np.asarray(x) for x in got]
-
-    before = [np.asarray(x[cut]) for x in fields]
-    round1 = want[:3] if not nu else [np.asarray(x) for x in _definition(
-        replace(cfg, nu=0.0), first_step, south, north)(*fields, *old)[:3]]
-    for name, x0, x1, x2, x in zip("huv", before, round1, want, got):
-        # what the kernel steps: the interior, and ring 1 of u and v
-        # where it is a neighbour's
-        stepped = inner | (fresh if name != "h" else False)
-        # the rounds did something there, and the kernel did the same
-        assert np.abs(x1 - x0)[inner].max() > 0.1, name
-        if name != "h":
-            assert np.abs(x1 - x0)[fresh].max() > 0.05, name
-            assert (np.abs(x2 - x1)[inner].max() > 0.01) == (nu > 0), name
-        np.testing.assert_allclose(
-            x[stepped], x2[stepped], rtol=0, atol=2e-6, err_msg=name)
-        # the rest goes through, bit for bit (the wall condition zeroes
-        # its row from end to end, as the array code's)
-        still = ~stepped
-        if name == "v":
-            still[-(G + 1)] = False
-        np.testing.assert_array_equal(x[still], x0[still], err_msg=name)
-    # the new tendencies at the fields' shape: du's and dv's ring 1 the
-    # neighbour's, the rest of the ghost ring zero
-    for name, x, x1 in zip(names, got[3:], want[3:]):
-        x1 = padded(x1, name)
-        kept = inner | (fresh if name != "dh" else False)
-        assert min(np.abs(x1[zone]).max()
-                   for zone in (inner, kept & ring1) if zone.any()) > 0.5, name
-        np.testing.assert_allclose(x, x1, rtol=0, atol=2e-6, err_msg=name)
-        assert not x[~kept].any(), name
-    wall_row = got[2][-(G + 1)]
-    assert (wall_row == 0).all() == north
+from tests.sw_kernels_cases import (
+    UNIT, WALLS, G, _as_a_step_finds_it, _budget, _interpreted, _ring, _Viscous,
+)
 
 
 @pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
@@ -274,168 +67,6 @@ def test_the_kernel_reads_no_ghost_the_slabs_did_not_bring(
     for name, a, b in zip(sw.SWState._fields, got, want):
         assert np.isfinite(b).all(), name
         np.testing.assert_array_equal(a, b, err_msg=name)
-
-
-@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
-@pytest.mark.parametrize("start", ["ab2", "euler"])
-@pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_a_walk_of_two_steps_is_two_walks_of_one_bit_for_bit(
-        shape, start, nu, monkeypatch):
-    """On one device (walls on both sides, a row's ghost columns its own
-    other end) ``wide_step(steps=2)`` returns, bit for bit and on the
-    whole padded block of all six arrays, what two calls return with the
-    exchange between them that ``_step_wide`` makes there: the same
-    operations on the same values in the same order, the first step's
-    results never in HBM.  A pair in the middle of a run, and one that
-    starts from forward Euler's tendencies (what a run's second and
-    third steps read), with the Euler step itself both ways."""
-    rows, width = _budget(monkeypatch, shape, steps=2)
-    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
-    keys = jax.random.split(jax.random.PRNGKey(3), 6)
-    fields = [
-        mean + spread * jax.random.normal(key, (rows, width), jnp.float32)
-        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
-    inner = ~(_ring((rows, width), 1) | _ring((rows, width), 2))
-    old = [jnp.where(inner, 0.5 * jax.random.normal(key, (rows, width)), 0)
-           for key in keys[3:]]
-    wall = jnp.bool_(True)
-
-    def walk(state, steps, a=cfg.ab_a, b=cfg.ab_b, lone=False):
-        # what halo_slabs_2d hands the kernel on a mesh of one device:
-        # in x the block's own columns, in y nothing
-        slabs = tuple((x[:, -2 * G:-G], x[:, G:2 * G], None, None)
-                      for x in state[:3])
-        return sw_kernels.wide_step(
-            *state, slabs, wall, wall, 0, a, b, lone, steps=steps,
-            **_interpreted(cfg))
-
-    def one_by_one(state):
-        return walk(walk(state, 1), 1)
-
-    def at_once(state):
-        return walk(state, 2)
-
-    # unoptimised: the CPU backend contracts a product and a sum into one
-    # rounding in one program and not in another (a single walk's results
-    # differ in their last bit between two tilings of one block), and
-    # this compares programs, not roundings
-    plain = {"xla_backend_optimization_level": 0}
-    state = [*fields, *old]
-    if start == "euler":
-        # a run's first step, as a walk of one step and as `lone`, the
-        # walk of two with its first passed over, which is how a run on
-        # one device makes it: the same block, bit for bit
-        rest = [*fields, *(jnp.zeros_like(x) for x in old)]
-        state = jax.jit(
-            lambda rest: walk(rest, 1, 1.0, 0.0), compiler_options=plain)(rest)
-        alone = jax.jit(
-            lambda rest: walk(rest, 2, 1.0, 0.0, lone=True),
-            compiler_options=plain)(rest)
-        for name, x0, a, b in zip(sw.SWState._fields, rest, alone, state):
-            assert np.abs(np.asarray(b) - x0)[inner].max() > 0.001, name
-            np.testing.assert_array_equal(a, b, err_msg=name)
-    want = jax.jit(one_by_one, compiler_options=plain)(state)
-    got = jax.jit(at_once, compiler_options=plain)(state)
-    for name, x0, a, b in zip(sw.SWState._fields, state, got, want):
-        x0, a, b = np.asarray(x0), np.asarray(a), np.asarray(b)
-        assert np.isfinite(b).all(), name
-        # two steps did something everywhere they should
-        assert np.abs(b - x0)[inner].max() > 0.01, name
-        np.testing.assert_array_equal(a, b, err_msg=name)
-    # the ghost columns are the row's other end as the first step left
-    # it: the second step's exchange, which nothing outside the kernel made
-    between = jax.jit(lambda state: walk(state, 1), compiler_options=plain)(state)
-    np.testing.assert_array_equal(
-        np.asarray(got[0])[:, :G], np.asarray(between[0])[:, -2 * G:-G])
-
-
-# rows x width whose interior rows divide by `coarsen`, for the walk that
-# writes its row sums: the widths above that do (ghost columns inside
-# their registers and in a register that they fill), tiles of one strip
-# (every group astride two) and of three (a block of sums four tiles
-# long), and 129 columns, whose eastern ghost columns lie astride two
-# vector registers, on 36 rows
-SUMMED = [
-    ("astride-36x129", 2), ("astride-36x129", 4), ("astride-36x129", 8),
-    ("tiles-of-8-100x140", 2), ("tiles-of-8-100x140", 4),
-    ("tiles-of-8-100x140", 8), ("tiles-of-24-100x140", 4),
-    ("tiles-of-24-100x140", 8), ("ragged-52x100", 4), ("aligned-36x256", 8),
-]
-
-
-def _rows_summed(field, coarsen):
-    """The sums over ``coarsen`` rows of the interior rows of a padded
-    block, whole width, in the kernel's order of additions: neighbours
-    first, then neighbouring pairs, then fours."""
-    rows = np.asarray(field)[G:-G]
-    parts = [rows[k::coarsen] for k in range(coarsen)]
-    while len(parts) > 1:
-        parts = [a + b for a, b in zip(parts[::2], parts[1::2])]
-    return parts[0]
-
-
-@pytest.mark.parametrize("nu", [0.2, 0.0], ids=["nu", "nu0"])
-@pytest.mark.parametrize("walk", ["one", "two", "lone"])
-@pytest.mark.parametrize("shape,coarsen", SUMMED, ids=lambda x: str(x))
-def test_a_walk_that_writes_its_row_sums_returns_the_plain_walks_state(
-        shape, coarsen, walk, nu, monkeypatch):
-    """``wide_step(coarsen=c, sums=room)``: the six arrays of the state
-    bit for bit, ghosts and all, what the walk without returns (with
-    the sums switched off by ``summing`` too), and after them, written
-    into the room the caller brought (of ``row_sums_shape``, the same
-    for a walk of one step and of two),
-    the sums over ``c`` rows of the new ``h``, ``u``, ``v``: row ``1 +
-    m`` of a field's sums is, exactly, the sum in the kernel's order of
-    the block's rows ``2 + c m`` on, ghost columns included; the rows
-    before and after are nobody's."""
-    if shape == "astride-36x129":
-        monkeypatch.setitem(SHAPES, shape, (36, 129, None))
-    steps = 1 if walk == "one" else 2
-    rows, width = _budget(monkeypatch, shape, steps=steps)
-    cfg = _Viscous(ny=rows - 2 * G, nx=width - 2 * G, nu=nu, **UNIT)
-    keys = jax.random.split(jax.random.PRNGKey(4), 6)
-    fields = [
-        mean + spread * jax.random.normal(key, (rows, width), jnp.float32)
-        for key, mean, spread in zip(keys, (1.0, 0.0, 0.0), (0.1, 0.5, 0.5))]
-    inner = ~(_ring((rows, width), 1) | _ring((rows, width), 2))
-    old = [jnp.where(inner, 0.5 * jax.random.normal(key, (rows, width)), 0)
-           for key in keys[3:]]
-    wall = jnp.bool_(True)
-    assert (sw_kernels.tile_rows(rows, width, jnp.float32, 6, 1)
-            == sw_kernels.tile_rows(rows, width, jnp.float32, 6, 2))
-
-    def walked(coarsen, summing=True):
-        def run(*state):
-            slabs = tuple((x[:, -2 * G:-G], x[:, G:2 * G], None, None)
-                          for x in state[:3])
-            # the room: whatever it holds, here something no sum is
-            room = coarsen and [jnp.full(sw_kernels.row_sums_shape(
-                (rows, width), jnp.float32, coarsen), jnp.nan)] * 3
-            return sw_kernels.wide_step(
-                *state, slabs, wall, wall, 0, cfg.ab_a, cfg.ab_b, walk == "lone",
-                summing, room or (), steps=steps, coarsen=coarsen, **_interpreted(cfg))
-
-        # unoptimised, as two programs are compared bit for bit
-        return [np.asarray(x) for x in jax.jit(run, compiler_options={
-            "xla_backend_optimization_level": 0})(*fields, *old)]
-
-    want, got = walked(0), walked(coarsen)
-    assert len(want) == 6 and len(got) == 9
-    for name, x0, a, b in zip(sw.SWState._fields, [*fields, *old], got, want):
-        assert np.abs(b - np.asarray(x0))[inner].max() > 1e-3, name
-        np.testing.assert_array_equal(a, b, err_msg=name)
-    groups = (rows - 2 * G) // coarsen
-    for name, x, sums in zip("huv", got[:3], got[6:]):
-        assert sums.shape == sw_kernels.row_sums_shape(
-            (rows, width), jnp.float32, coarsen), name
-        assert sums.shape[1] == width and sums.shape[0] >= groups + 1, name
-        np.testing.assert_array_equal(
-            sums[1:1 + groups], _rows_summed(x, coarsen), err_msg=name)
-    if walk == "two" and nu:
-        # the same kernel with its sums switched off, as the walks of a
-        # call's loop run it: the state again, the sums nobody's
-        for name, a, b in zip(sw.SWState._fields, walked(coarsen, False), want):
-            np.testing.assert_array_equal(a, b, err_msg=name)
 
 
 @pytest.mark.parametrize("platform,ghost,snapshot,rows,width,expected", [
