@@ -760,6 +760,90 @@ def solver_output_restart_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=
 _K = 128  # elements per device in the op checks
 
 
+def solver_monitor_check(cfg, devices, *, mesh_shapes=((1, 1),), lag=2,
+                         steps_per_call=10, calls=3):
+    """The solver as a job that watches itself (``make_job(monitor=)``):
+    on every mesh a line after every call, in step order, the mass of
+    each within 1e-5 of the first's, every chip holding the same line;
+    then a NaN is written into the last chip's block of ``h`` and the
+    job has to stop by itself within ``lag + 1`` further calls, naming
+    the first step whose state held it; and the meshes' lines agree to
+    the rounding of another decomposition."""
+    import jax
+    import numpy as np
+
+    import mpi4jax_tpu as m
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    steps = [1 + steps_per_call * (k + 1) for k in range(calls)]
+    runs, stops = [], {}
+    for shape in mesh_shapes:
+        n = shape[0] * shape[1]
+        mesh = jax.make_mesh(
+            shape, ("y", "x"), axis_types=_auto(2), devices=devices[:n])
+        comm = m.MeshComm.from_mesh(mesh)
+        lines = []
+        job = sw.make_job(cfg, comm, steps_per_call,
+                          monitor=sw.Monitor(lag=lag), on_monitor=lines.append)
+        job.start(sw.make_init(cfg, comm)())
+        job.advance(calls)
+        job.drain()
+        if [line["step"] for line in lines] != steps:
+            raise AssertionError(
+                f"{shape}: lines of steps {[l['step'] for l in lines]}, "
+                f"wanted {steps}")
+        for line in lines:
+            if (line["nonfinite"] or not 0 < line["cfl"] < 0.5
+                    or abs(line["mass"] / lines[0]["mass"] - 1) > 1e-5):
+                raise AssertionError(f"{shape}: a line of a sound run: {line}")
+        every = np.asarray(job.mon(*job.state[:3])).reshape(n, -1)
+        if not (every == every[0]).all():
+            raise AssertionError(f"{shape}: the chips hold different lines: {every}")
+        # a NaN in the last chip's block, three cells inside its corner
+        h = job.state.h
+        job.state = job.state._replace(
+            h=h.at[h.shape[0] - cfg.ghost - 3, h.shape[1] - cfg.ghost - 3].set(
+                float("nan")))
+        runs.append(list(lines))
+        bad_from, before = job.step + steps_per_call, job.calls
+        try:
+            for _ in range(lag + 2):
+                job.advance()
+            job.drain()
+        except sw.MonitorStop as stop:
+            stops[shape] = {"step": stop.line["step"],
+                            "calls_enqueued": job.calls - before,
+                            "nonfinite": stop.line["nonfinite"]}
+        if shape not in stops:
+            raise AssertionError(f"{shape}: a NaN in h did not stop the job")
+        if (stops[shape]["step"] != bad_from
+                or stops[shape]["calls_enqueued"] > lag + 1
+                or job.stats()["monitor_stops"] != 1):
+            raise AssertionError(
+                f"{shape}: {stops[shape]}; the NaN was in the state from step "
+                f"{bad_from} on and the lag is {lag}; {job.stats()}")
+    across = max(
+        (abs(a[k] - b[k]) / (abs(a["mass"]) if k == "mass" else 1.0)
+         for other in runs[1:] for a, b in zip(runs[0], other)
+         for k in ("cfl", "h_min", "mass")), default=0.0)
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost} as a job that "
+        f"watches itself, a line after each of {calls} calls of "
+        f"{steps_per_call} steps, lag {lag}: in order, every chip the same "
+        "line; a NaN written into the last chip's block stops the job by "
+        f"the first line that can hold it, within {lag + 1} calls; lines on "
+        f"{' and '.join('x'.join(map(str, s)) for s in mesh_shapes)} "
+        f"(tol {TOL_SAME_ARITHMETIC}, mass as a share)",
+        "stops": {"x".join(map(str, k)): v for k, v in stops.items()},
+        "last_line": runs[0][-1],
+        "meshes_max_diff": across,
+        "max_diff": across,
+    }
+    if across > TOL_SAME_ARITHMETIC:
+        raise AssertionError(f"decomposition changes the lines: {out}")
+    return out
+
+
 def ops_program(devices):
     """The twelve primitives plus reduce_scatter in one jitted
     ``shard_map`` program on one token chain; returns it with its
@@ -1268,6 +1352,7 @@ GROUPS = {
             "solver": _solver,
             "solver.job": lambda: solver_job_check(_bench_cfg(), _one()),
             "solver.restart": lambda: solver_restart_check(_bench_cfg(), _one()),
+            "solver.monitor": lambda: solver_monitor_check(_bench_cfg(), _one()),
         }),
         "ops": (300, {
             "ops": lambda: ops_check(_one()),
@@ -1298,6 +1383,9 @@ GROUPS = {
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
             "solver4.output_restart": lambda: solver_output_restart_check(
+                _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
+            ),
+            "solver4.monitor": lambda: solver_monitor_check(
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
             ),
         }),

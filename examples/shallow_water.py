@@ -20,6 +20,9 @@ Usage:
     # explicit decomposition (devices = py * px)
     python examples/shallow_water.py --mesh 2 4
 
+    # the run that watches itself: a monitor line after every chunk
+    python examples/shallow_water.py --monitor
+
 Every mode but --benchmark builds `SWConfig()` with its default
 `ghost=1`: upstream's layout, (ny+2, nx+2) arrays a device, and
 upstream's step as written, array code with one halo exchange after
@@ -98,6 +101,16 @@ def main(argv=None):
         "on its way to the host",
     )
     p.add_argument("--checkpoint-every", type=int, default=1)
+    p.add_argument(
+        "--monitor",
+        action="store_true",
+        help="watch the solution as it goes: after every chunk the "
+        "job prints a line (non-finite values, the largest CFL number, "
+        "the thinnest layer, the mass: each a reduction on the chip "
+        "that holds a block and the library's allreduce over the mesh) "
+        "and stops the run at most four chunks after the one that went "
+        "bad, where a run without it is paid for to its end",
+    )
     args = p.parse_args(argv)
 
     import jax
@@ -161,6 +174,20 @@ def main(argv=None):
         def on_chunk(snapshot, step):
             frames.append(snapshot["h"])
 
+    monitor = on_monitor = None
+    if args.monitor:
+        # the lines ride the job too (SolverJob, "A job that watches
+        # itself"): read at most `lag` chunks late, and a bad one raises
+        monitor = sw.Monitor(lag=4)
+
+        def on_monitor(line):
+            print(
+                f"monitor: step {line['step']}: cfl {line['cfl']:.4f}, "
+                f"h_min {line['h_min']:.3f} m, mass {line['mass']:.9e} m^3, "
+                f"{line['nonfinite']} values not finite",
+                file=sys.stderr,
+            )
+
     solve = sw.make_solver(
         cfg,
         comm,
@@ -169,8 +196,13 @@ def main(argv=None):
         checkpoint_dir=args.checkpoint,
         checkpoint_every=args.checkpoint_every,
         snapshot=snapshot,
+        monitor=monitor,
+        on_monitor=on_monitor,
     )
-    state, wall, steps = solve(days * sw.DAY_IN_SECONDS)
+    try:
+        state, wall, steps = solve(days * sw.DAY_IN_SECONDS)
+    except sw.MonitorStop as stop:
+        sys.exit(f"shallow_water: {stop}")
 
     h_local = np.asarray(jax.device_get(state.h))
     assert np.isfinite(h_local).all(), "solution diverged"

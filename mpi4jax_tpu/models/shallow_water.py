@@ -67,8 +67,11 @@ __all__ = [
     "make_state",
     "Snapshot",
     "Checkpoint",
+    "Monitor",
+    "MonitorStop",
     "SolverJob",
     "make_snapshot",
+    "make_monitor",
     "make_job",
     "make_solver",
     "gather_global",
@@ -1309,6 +1312,125 @@ def make_snapshot(cfg, comm, snapshot, from_sums=False):
 
 
 @dataclass(frozen=True)
+class Monitor:
+    """What a job watches of its own solution as it goes, and when a
+    line of it stops the job: Veros's ``cfl_monitor`` and
+    ``tracer_monitor`` diagnostics and its ``sanity_check`` (a
+    ``global_and`` of ``isfinite``), each a reduction on the chip that
+    holds a block and the library's ``allreduce`` over the job's
+    communicator (:func:`make_monitor`).
+
+    ``every_calls``: a line after every call whose number, counted from
+    the integration's first, divides by this: upstream's
+    ``output_frequency`` in calls, and kept for that alone (every use
+    in this repository is 1, a line a call).  ``lag``: a line is read
+    on the host, and handed to ``on_monitor``, at most this many calls
+    after the newest call enqueued, so the loop waits for none that is
+    younger, and a bad line stops the job before ``lag + 1`` more calls
+    are enqueued.  ``cfl_limit``: the largest advective CFL number,
+    ``max(|u| dt/dx, |v| dt/dy)`` over the domain, that is not a stop.
+    A line also stops the job where a value of ``h``, ``u``, ``v`` is
+    not finite, or where the thinnest layer is not above zero
+    (``thickness`` is the potential vorticity's denominator, and a
+    layer that dries is how this solver dies; the library's own, no
+    diagnostic of Veros's).
+
+    A state is vetted before it is saved: :meth:`SolverJob.save` first
+    reads every line made so far (the host waits there for the newest
+    call's, once a save), so no save is started of a state at or after
+    a line that stops the job, and :meth:`SolverJob.resume` takes up
+    none the monitor called bad.  With ``every_calls`` above 1 a save
+    is vetted as far as the newest line."""
+
+    every_calls: int = 1
+    lag: int = 4
+    cfl_limit: float = 0.5
+
+    def why_bad(self, line):
+        """What of ``line`` stops a job, in words; ``None`` for a line
+        that stops none."""
+        if line["nonfinite"] > 0:
+            return f"{line['nonfinite']} values of h, u, v are not finite"
+        if not line["h_min"] > 0:
+            return f"the thinnest layer is {line['h_min']!r} m"
+        if not line["cfl"] <= self.cfl_limit:
+            return f"the CFL number {line['cfl']!r} is over {self.cfl_limit!r}"
+        return None
+
+
+class MonitorStop(RuntimeError):
+    """A line of the job's :class:`Monitor` stopped the job: ``line``
+    is that line (``line["step"]`` the step it is of), and no call was
+    enqueued after it was read."""
+
+    def __init__(self, line, why, calls_since):
+        super().__init__(
+            f"the monitor's line of step {line['step']} stops the job: {why} "
+            f"(read {calls_since} calls after the call that made it; no "
+            f"further call is enqueued): {line}")
+        self.line = line
+
+
+# The monitor's phase (``sw/monitor``, beside the as-written step's): its
+# local reductions are the program's array code, and the three
+# ``allreduce``s carry the op surface's own scope inside it.
+MONITOR = "monitor"
+# a line's numbers as the monitor program hands them back, in order
+MONITOR_LINE = ("nonfinite", "cfl", "h_min", "mass")
+
+
+def make_monitor(cfg, comm):
+    """Jitted global function ``(h, u, v) -> line``: each device reduces
+    the **interior** of its own block (a ghost cell is a neighbour's
+    interior cell, or a wall's copy of one, and is counted by nobody) to
+    four numbers, and three ``allreduce``s over ``comm``, one a kind of
+    reduction and each over all of the mesh at once, ordered by their
+    token, make them the domain's:
+
+    ``nonfinite``  how many values of ``h``, ``u``, ``v`` are not finite
+                   (``SUM``; counted in float32 beside ``mass``, so
+                   that a field's count and its other reductions are
+                   one pass over it: exact to 2**24 a device, and never
+                   0 for a count that is not);
+    ``cfl``        ``max(|u| dt/dx, |v| dt/dy)`` (``MAX``);
+    ``h_min``      the thinnest layer (``MIN``);
+    ``mass``       ``sum(h) dx dy`` (``SUM``), which the flux form
+                   conserves between walls and a periodic x; a float32
+                   sum, in the order the compiler adds a block up.
+
+    ``line`` is float32 ``(py, px, 4)``, every device's own copy of the
+    one line in :data:`MONITOR_LINE`'s order.  Reads its arguments once
+    and donates nothing: a job enqueues it between the call that made
+    the state and the call that consumes it, as it does the snapshot
+    program.  On a mesh of one device the ``allreduce`` is the identity
+    and is still the call that is made."""
+    G = cfg.ghost
+    cfl_x, cfl_y = cfg.dt / cfg.dx, cfg.dt / cfg.dy
+
+    def local_fn(h, u, v):
+        with _phase(MONITOR):
+            h, u, v = (a[G:-G, G:-G] for a in (h, u, v))
+            # counted in the fields' own type, so that a field's count
+            # and its other reductions are one pass over it
+            bad = sum(jnp.sum((~jnp.isfinite(a)).astype(a.dtype))
+                      for a in (h, u, v))
+            mass = jnp.sum(h) * jnp.asarray(cfg.dx * cfg.dy, h.dtype)
+            sums = jnp.stack([bad, mass])
+            cfl = jnp.maximum(jnp.max(jnp.abs(u)) * jnp.asarray(cfl_x, h.dtype),
+                              jnp.max(jnp.abs(v)) * jnp.asarray(cfl_y, h.dtype))
+            sums, token = allreduce(sums, reductions.SUM, comm=comm)
+            cfl, token = allreduce(cfl, reductions.MAX, comm=comm, token=token)
+            h_min, token = allreduce(
+                jnp.min(h), reductions.MIN, comm=comm, token=token)
+            return jnp.stack([sums[0], cfl, h_min, sums[1]]).reshape(1, 1, 4)
+
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(spec,) * 3,
+        out_specs=jax.P(*comm.axes, None)))
+
+
+@dataclass(frozen=True)
 class Checkpoint:
     """Where a job saves its whole state, how often, and how.
 
@@ -1473,6 +1595,25 @@ class SolverJob:
     a save through :meth:`drain` or the next :meth:`save`).  A job with
     only one half never waits for room, and is what it was.
 
+    **A job that watches itself** (``monitor``, a :class:`Monitor`).
+    After every ``monitor.every_calls`` calls the monitor program
+    (:func:`make_monitor`; ``mon``) reads ``h``, ``u``, ``v`` once,
+    enqueued after the call that made them and before the call that
+    consumes them, as the snapshot program is, so a line is of the
+    state of the step it names and of no other.  Its line starts for
+    the host at once, is read there at most ``monitor.lag`` calls after
+    the newest call enqueued (the loop waits, under ``job/monitor_wait``,
+    only for a line that old) and is handed to ``on_monitor(line)`` as
+    ``{"step", "nonfinite", "cfl", "h_min", "mass"}``, in step order,
+    every one.  A line that :meth:`Monitor.why_bad` names stops the
+    job: :meth:`advance` (or :meth:`drain`) raises :class:`MonitorStop`
+    with the line, ``stopped`` keeps it, no call is enqueued after it
+    is read, and every later :meth:`advance` raises again until the job
+    is given a state anew (:meth:`start`, :meth:`resume`); the lines of
+    the calls enqueued before the stop are still handed out, by
+    :meth:`drain`.  A resumed job has no line pending: its first is of
+    the resumed step plus ``every_calls`` calls.
+
     ``first``, ``multi``, ``snap`` and ``stage`` are the jitted
     programs.  Where the step is the kernel and the snapshot's blocks
     are made of its strips' rows (:func:`_sums_in_step`), ``first`` and
@@ -1505,16 +1646,23 @@ class SolverJob:
     save's threads, ``checkpoint/save``, ``/fetch``, ``/write``,
     ``/commit`` and ``/prune``; ``job/ask_wait`` and
     ``checkpoint/fetch_wait`` a copy held back by the other kind's
-    bytes (``held_by``, ``bytes``).  A span's ``key`` is the model step it
-    is about.  :meth:`spans` returns them; every time in :meth:`stats`
+    bytes (``held_by``, ``bytes``); ``job/monitor_wait`` a monitor's
+    line read on the host and ``job/monitor_callback`` ``on_monitor``.
+    A span's ``key`` is the model step it is about.  :meth:`spans`
+    returns them; every time in :meth:`stats`
     is the sum of its spans'.
     """
 
     def __init__(self, cfg, comm, num_multisteps, snapshot, on_chunk,
-                 checkpoint=None):
+                 checkpoint=None, monitor=None, on_monitor=None):
         self.cfg, self.comm = cfg, comm
         self.num_multisteps = num_multisteps
         self.snapshot, self.on_chunk = snapshot, on_chunk
+        self.monitor, self.on_monitor = monitor, on_monitor
+        self.mon = monitor and make_monitor(cfg, comm)
+        self._mon = self.mon
+        self._lines = collections.deque()  # (step, call, device array), oldest first
+        self._stop = None  # the MonitorStop that stopped the job
         self.ahead_bytes = min(
             (half.ahead_bytes for half in (snapshot, checkpoint)
              if half is not None and half.ahead_bytes is not None), default=None)
@@ -1552,14 +1700,20 @@ class SolverJob:
             snapshots_delivered=0, max_lag=0,
             bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
             saves_started=0, save_bytes=0, save_wait_s=0.0, save_enqueue_s=0.0,
-            restore_read_s=0.0, restore_to_device_s=0.0)
+            restore_read_s=0.0, restore_to_device_s=0.0,
+            monitor_lines=0, monitor_max_lag_calls=0, monitor_wait_s=0.0,
+            monitor_stops=0)
 
     def start(self, state, step=0):
         """Take ``state`` as the model after ``step`` steps.  A state at
         step 0 (``make_init``'s, or the caller's own fields) is put
         through the forward-Euler step, which does not donate it; a
-        later one (a checkpoint's) is taken as it is."""
-        self.drain()
+        later one (a checkpoint's) is taken as it is.  What the run
+        before still had on its way is finished first; a line of its
+        that stops a job is handed out and counted, and not raised: the
+        job is given a state anew."""
+        self._drain(raises=False)
+        self._stop = None
         self._host.peak = self._host.in_flight
         if step == 0:
             out, step = self.first(state), 1
@@ -1579,14 +1733,21 @@ class SolverJob:
                 self._snap = self.snap.lower(*self._handed()).compile()
             if self.stage is not None:
                 self._stage = self.stage.lower(self.state).compile()
+            if self.mon is not None:
+                self._mon = self.mon.lower(*self.state[:3]).compile()
 
     def advance(self, calls=1):
-        """Enqueue ``calls`` multistep calls, after each the snapshot
-        and, where ``checkpoint.every_calls`` says so, a save; ask for
+        """Enqueue ``calls`` multistep calls, after each the snapshot,
+        where ``monitor.every_calls`` says so the monitor program and,
+        where ``checkpoint.every_calls`` says so, a save; ask for
         the snapshots' copies to the host that are next in line, and
-        deliver every snapshot that is due."""
+        deliver every snapshot and read every monitor's line that is
+        due.  Raises :class:`MonitorStop` where a line read stops the
+        job, before the next call is enqueued, and from then on."""
         every = self.checkpoint.every_calls if self.checkpoint else 0
         span = self.trace.span
+        if self._stop is not None:
+            raise self._stop
         with span("job/advance", key=self.step + self.num_multisteps, calls=calls):
             for _ in range(calls):
                 with span("job/enqueue", key=self.step + self.num_multisteps,
@@ -1604,20 +1765,37 @@ class SolverJob:
                     self._stats["snapshots_summed_in_step"] += self._summed
                     self._ask()
                     self._deliver(self.snapshot.lag)
+                if self.mon is not None:
+                    if self.calls % self.monitor.every_calls == 0:
+                        with span("job/enqueue", key=self.step, program="mon"):
+                            line = self._mon(*self.state[:3])
+                        # every device holds the one line: the first's goes
+                        line = line.addressable_shards[0].data
+                        line.copy_to_host_async()
+                        self._lines.append((self.step, self.calls, line))
+                    self._read_lines(self.monitor.lag)
                 if every and self.calls % every == 0:
                     self.save()
         return self.state
 
     def drain(self):
-        """Deliver every snapshot still on its way, wait for the save
-        on its way to be acknowledged, and leave the directory with its
-        committed saves and nothing else (the files a series keeps for
-        its next save go)."""
+        """Deliver every snapshot and read every monitor's line still
+        on its way, wait for the save on its way to be acknowledged,
+        and leave the directory with its committed saves and nothing
+        else (the files a series keeps for its next save go).  Raises
+        :class:`MonitorStop` where a line read now stops the job, with
+        all of that done."""
+        self._drain()
+
+    def _drain(self, raises=True):
+        # the lines last: one that stops the job leaves no save
+        # unacknowledged and no spare file behind
         with self.trace.span("job/drain", key=self.step):
             self._deliver(0)
             self._settle()
             if self.series is not None:
                 self.series.clean()
+            self._read_lines(0, raises)
 
     def stats(self):
         """The job's counters: ``snapshots_produced``,
@@ -1647,7 +1825,13 @@ class SolverJob:
         together; ``transfer_wait_s``, seconds a copy was held back by
         the other kind's bytes under the job's one bound, the loop's
         (``job/ask_wait``) and the acknowledged saves'
-        (``checkpoint/fetch_wait``).
+        (``checkpoint/fetch_wait``); ``monitor_lines``, the monitor's
+        lines read and handed to ``on_monitor``;
+        ``monitor_max_lag_calls``, the most calls one was read after
+        the call that made it; ``monitor_wait_s``, host seconds reading
+        lines (blocked where one was not ready: ``job/monitor_wait``);
+        ``monitor_stops``, the lines that stopped the job (one a state
+        the job was given).
         Every time is the sum of its spans' (:meth:`spans`)."""
         saves = list(self.saves)
         return dict(
@@ -1681,9 +1865,15 @@ class SolverJob:
         """Start a save of the state at hand: wait for the save before
         it to be acknowledged, enqueue the staging program, and hand its
         pieces to the threads that fetch and write them.  Returns the
-        :class:`checkpoint.Save`; ``drain()`` waits for it."""
+        :class:`checkpoint.Save`; ``drain()`` waits for it.  A job that
+        watches itself reads every line made so far first, and a
+        stopped one saves nothing (:class:`MonitorStop`): the state at
+        hand is at or after the line that stopped it."""
         if self.checkpoint is None:
             raise ValueError("the job was made without a `checkpoint`")
+        self._read_lines(0)
+        if self._stop is not None:
+            raise self._stop
         span, step = self.trace.span, self.step
         with span("job/save", key=step,
                   bytes=sum(a.nbytes for a in self.state)) as whole:
@@ -1743,7 +1933,7 @@ class SolverJob:
         if series is None:
             raise ValueError("no directory: the job has no `checkpoint`")
         with self.trace.span("job/resume") as whole:
-            self.drain()
+            self._drain(raises=False)  # as `start` does
             series.clean()
             step = whole.key = series.latest()
             if step is not None:
@@ -1805,6 +1995,41 @@ class SolverJob:
                 self._deliver(len(self._pending) - 1)
             save.wait()
 
+    # -- the monitor -------------------------------------------------------
+
+    @property
+    def stopped(self):
+        """The line that stopped the job; ``None`` while none has."""
+        return self._stop and self._stop.line
+
+    def _read_lines(self, lag, raises=True):
+        """Read, oldest first, every line made ``lag`` calls or more
+        before the newest call enqueued, hand each to ``on_monitor``,
+        and raise :class:`MonitorStop` at the first that stops the job
+        (once a state: a stopped job's later lines are handed out and
+        decide nothing).  ``raises`` false: the stop is kept and
+        counted, and every line due is handed out."""
+        stats, span = self._stats, self.trace.span
+        while self._lines and self.calls - self._lines[0][1] >= lag:
+            step, call, on_device = self._lines.popleft()
+            with span("job/monitor_wait", key=step) as waited:
+                numbers = np.asarray(on_device).ravel()
+            stats["monitor_wait_s"] += waited.seconds
+            line = dict(zip(MONITOR_LINE, numbers.tolist()), step=step)
+            line["nonfinite"] = int(round(line["nonfinite"]))
+            stats["monitor_lines"] += 1
+            stats["monitor_max_lag_calls"] = max(
+                stats["monitor_max_lag_calls"], self.calls - call)
+            if self.on_monitor is not None:
+                with span("job/monitor_callback", key=step):
+                    self.on_monitor(line)
+            why = self.monitor.why_bad(line)
+            if why is not None and self._stop is None:
+                self._stop = MonitorStop(line, why, self.calls - call)
+                stats["monitor_stops"] += 1
+                if raises:
+                    raise self._stop
+
     # -- output ------------------------------------------------------------
 
     def _written(self):
@@ -1860,16 +2085,22 @@ class SolverJob:
 
 
 def make_job(cfg, comm, num_multisteps=10, snapshot=None, on_chunk=None,
-             checkpoint=None):
+             checkpoint=None, monitor=None, on_monitor=None):
     """The solver's loop as an object (:class:`SolverJob`):
     ``job.start(state)`` or ``job.resume()``, ``job.advance(calls)``,
     ``job.drain()``, ``job.stats()``.  ``snapshot``: a :class:`Snapshot`,
     or ``None`` for a job that writes nothing (``on_chunk`` alone asks
     for whole fields of ``h``, ``u`` and ``v``).  ``checkpoint``: a
-    :class:`Checkpoint`, or ``None`` for a job that is never saved."""
+    :class:`Checkpoint`, or ``None`` for a job that is never saved.
+    ``monitor``: a :class:`Monitor`, or ``None`` for a job that does not
+    watch its solution; ``on_monitor(line)`` is handed every line of a
+    job that has one."""
     if snapshot is None and on_chunk is not None:
         snapshot = Snapshot()
-    return SolverJob(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint)
+    if monitor is None and on_monitor is not None:
+        raise ValueError("`on_monitor` without a `monitor`: nothing makes a line")
+    return SolverJob(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint,
+                     monitor, on_monitor)
 
 
 def make_solver(
@@ -1880,6 +2111,8 @@ def make_solver(
     checkpoint_dir=None,
     checkpoint_every=1,
     snapshot=None,
+    monitor=None,
+    on_monitor=None,
 ):
     """Full driver: init → bootstrap step → repeated jitted multisteps,
     a loop over one :class:`SolverJob`.
@@ -1919,12 +2152,18 @@ def make_solver(
     step for step and bit for bit what the run that was never stopped
     hands out (chunks that the stopped run had delivered after that save
     are delivered again, the same).
+
+    ``monitor`` (a :class:`Monitor`) and ``on_monitor(line)``: the job
+    watches its solution as it goes, and ``solve`` raises
+    :class:`MonitorStop` at most ``monitor.lag`` chunks after the one
+    that went bad, where a run without would be paid for to its end.
     """
     init = make_init(cfg, comm)
     checkpoint = None
     if checkpoint_dir is not None:
         checkpoint = Checkpoint(checkpoint_dir, every_calls=checkpoint_every or 0)
-    job = make_job(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint)
+    job = make_job(cfg, comm, num_multisteps, snapshot, on_chunk, checkpoint,
+                   monitor, on_monitor)
     chunk_s = cfg.dt * num_multisteps
 
     def solve(t1):

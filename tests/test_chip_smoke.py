@@ -68,6 +68,22 @@ def test_solver_restart_check_tiny():
     assert "dropped after 3 and resumed" in out["compared"]
 
 
+def test_solver_monitor_check_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    out = chip_smoke.solver_monitor_check(
+        cfg, cpu, mesh_shapes=((2, 2), (1, 1)), steps_per_call=5, calls=3)
+    # the NaN is in the state from the fourth call on (step 21), and the
+    # job stops by that call's line with `lag` more calls enqueued
+    assert out["stops"] == {
+        shape: {"step": 21, "calls_enqueued": 3, "nonfinite": out["stops"]["2x2"]["nonfinite"]}
+        for shape in ("2x2", "1x1")}
+    assert out["stops"]["2x2"]["nonfinite"] > 0
+    assert 0 < out["meshes_max_diff"] <= chip_smoke.TOL_SAME_ARITHMETIC
+    assert "solver.monitor" in chip_smoke.GROUPS[1]["solver"][1]
+    assert "solver4.monitor" in chip_smoke.GROUPS[4]["solver4"][1]
+
+
 def test_solver_output_restart_check_tiny():
     cpu = jax.devices("cpu")
     cfg = sw.SWConfig(ny=24, nx=48, ghost=2)

@@ -825,3 +825,81 @@ def test_a_job_with_both_halves_compiles_the_programs_of_its_halves(v5e, mesh_sh
     if py * px == 1:
         assert sum(len(rows) for rows in both._plan) == 606
         assert 2 * one + a_piece <= bound < 3 * one
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_job_that_watches_itself_adds_a_program_and_leaves_the_step_alone(
+        v5e, mesh_shape):
+    """``make_job(monitor=)`` at the blocks of the cells
+    ``sw-monitored-1chip`` and ``sw-monitored-2x2-weak``: the call's
+    multistep is the program of a job without a monitor, instruction for
+    instruction (so the accepted cells' programs are what they were);
+    the monitor program reads each chip's ``h``, ``u``, ``v`` once, a
+    fusion a field, with no copy of a field and no temporary of a
+    field's size; on four chips it holds one all-reduce a kind of
+    reduction (sum, max, min), each over both mesh axes at once
+    (``replica_groups={{0,1,2,3}}``, not one an axis), and on one chip
+    none; its reductions lie under ``sw/monitor`` (the programs' layer)
+    and its all-reduces under ``mpi4jax_tpu.allreduce`` inside it (the
+    op surface's)."""
+    from perfbench.harness import scopes
+
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px])
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=7200 * py, nx=14400 * px, dx=1250.0 / py, dy=1250.0 / py,
+                      ghost=2)
+    job = sw.make_job(cfg, comm, 10, monitor=sw.Monitor())
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(sw.make_init(cfg, comm)))
+
+    def instructions(text):
+        return [re.sub(r",? metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines() if " = " in line]
+
+    text = job.multi.lower(state).compile().as_text()
+    bare = sw.make_job(cfg, comm, 10).multi.lower(state).compile().as_text()
+    assert instructions(text) == instructions(bare) and len(instructions(text)) > 20
+    assert ("collective-permute" in text) == (py * px > 1)
+    assert " all-reduce(" not in text  # the step's program holds none
+
+    mon = job.mon.lower(*state[:3]).compile()
+    text = mon.as_text()
+    field = 7204 * 14404 * 4
+    mem = mon.memory_analysis()
+    assert mem.argument_size_in_bytes >= 3 * field and mem.temp_size_in_bytes < 1 << 20
+    assert scopes.signature(text) == (3 * field, 16)
+    entry = text[text.index("\nENTRY"):]
+    assert not re.findall(r"= f32\[7204,14404\]\S* copy\(", text)
+    # each field is the operand of one fusion, and of nothing else
+    params = re.findall(r"%([\w.\-]+) = f32\[7204,14404\]\S* parameter\(", entry)
+    assert len(params) == 3
+    for name in params:
+        readers = [line for line in entry.splitlines()
+                   if f"%{name}" in line.split(" = ", 1)[-1] and " parameter(" not in line]
+        assert len(readers) == 1 and " fusion(" in readers[0], readers
+    reduces = [line for line in entry.splitlines() if " all-reduce(" in line]
+    if py * px == 1:
+        assert not reduces and "collective" not in text
+        return
+    assert len(reduces) == 3
+    assert all("replica_groups={{0,1,2,3}}" in line for line in reduces)
+    kinds = set()
+    for line in reduces:
+        region = re.search(r"to_apply=%([\w.\-]+)", line)[1]
+        body = re.search(rf"^%{re.escape(region)} \(.*?^\}}", text, re.S | re.M)[0]
+        kinds |= set(re.findall(r" (add|maximum|minimum)\(", body))
+    assert kinds == {"add", "maximum", "minimum"}
+    table = scopes.origins(text)
+    lines = {name: rest for name, rest in scopes._INSTRUCTION.findall(text)}
+    for name, origin in table.items():
+        if " all-reduce(" in lines[name]:
+            assert "sw/monitor/mpi4jax_tpu.allreduce" in origin.op_name
+            assert scopes.layer_of(origin) == scopes.OP_SURFACE
+        elif " fusion(" in lines[name] and "reduce" in name:
+            assert "sw/monitor" in origin.op_name
+            assert scopes.layer_of(origin) == scopes.PROGRAMS
