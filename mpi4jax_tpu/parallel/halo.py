@@ -21,7 +21,10 @@ received, patched on a slab of a few rows and not on the block.
 Two forms of one exchange: :func:`halo_exchange_2d` (and its batched
 sibling) runs all three phases; :func:`halo_slabs_2d` stops before
 ``unpack`` and returns the four slabs.  Both come out of
-:func:`_received`.
+:func:`_received`, and on the mesh tier that is where both hold the
+block row-major (:func:`_row_major`): wherever a column slab is cut, it
+is cut from a block in the layout the rest of the program keeps, and no
+form of the exchange has the compiler transpose a block.
 """
 
 import numpy as np
@@ -194,11 +197,17 @@ def _pack(arrs, sent, received):
 
 def _received(arrs, comm, *, periodic, token, width, stack):
     """The four shifts of every array with no ghost written between
-    them: ``(slabs, token)``, ``slabs[k][i]`` what array ``i`` receives
-    from shift ``k`` of :func:`_shifts` (``None`` where that shift is a
-    no-op on the whole axis).  The x slabs are sliced from the block; a
-    y slab is the block's rows with its ``width`` x ``width`` ends taken
-    from the x slabs just received, patched on a slab of a few rows."""
+    them: ``(arrs, slabs, token)``, ``slabs[k][i]`` what array ``i``
+    receives from shift ``k`` of :func:`_shifts` (``None`` where that
+    shift is a no-op on the whole axis).  The x slabs are sliced from
+    the block; a y slab is the block's rows with its ``width`` x
+    ``width`` ends taken from the x slabs just received, patched on a
+    slab of a few rows.  ``arrs`` are the blocks as the slabs were cut
+    from them: on the mesh tier held row-major (:func:`_row_major`), for
+    a caller that goes on to write into them."""
+    if comm.backend == "mesh":
+        with jax.named_scope(PACK):
+            arrs = [_row_major(a) for a in arrs]
     token = as_token(token)
     w = width
     slabs = []
@@ -230,16 +239,21 @@ def _received(arrs, comm, *, periodic, token, width, stack):
         got, token = _shift(
             *parts, comm, axis, disp, per, token, stack=stack)
         slabs.append(got)
-    return slabs, token
+    return arrs, slabs, token
 
 
 def _row_major(a):
     """``a`` held to the layout a block has everywhere else in a program.
     Left to itself the TPU compiler lays the whole carried block out
     column-major to suit the two-column slabs sliced from it, and then
-    pays for every row slab's write (25 us for 29 KB on a v5e, ``PERF.md``
-    PR 35); held row-major it slices and writes the narrow slabs and
-    transposes those."""
+    pays for it wherever the block is used row-major: in
+    :func:`halo_exchange_2d` for every row slab's write (25 us for 29 KB
+    on a v5e, ``PERF.md`` PR 35), and round a kernel that takes the block
+    as it lies (:func:`halo_slabs_2d`'s caller) with a transpose of the
+    whole block, once a field a step (three ``copy`` of 415 MB, a third
+    of the step at 7204 x 14404 a chip on 2x2: ``PERF.md`` PR 52).  Held
+    row-major it slices and writes the narrow slabs and transposes
+    those.  Both forms are held, in :func:`_received`."""
     return with_layout_constraint(
         a, Layout(major_to_minor=tuple(range(a.ndim))))
 
@@ -315,10 +329,7 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
     mesh tier the block is row-major, so it is the column slabs that
     are narrow: :func:`_place` writes those as whole lane tiles."""
     mesh = comm.backend == "mesh"
-    if mesh:
-        with jax.named_scope(PACK):
-            arrs = [_row_major(a) for a in arrs]
-    slabs, token = _received(
+    arrs, slabs, token = _received(
         arrs, comm, periodic=periodic, token=token, width=width, stack=stack,
     )
     regions = [received for *_, received in _shifts(width, periodic)]
@@ -352,9 +363,12 @@ def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
     the ``width`` x ``width`` ends of a row slab are patched from the
     received x slabs before it is sent (on a slab of a few rows, not on
     the block), and a device with no neighbour on that side gets its own
-    ghost rows back patched likewise.
+    ghost rows back patched likewise.  On the mesh tier the block is held
+    row-major where the slabs are cut from it (:func:`_row_major`), the
+    layout a kernel takes it in: the caller's program transposes the
+    sent slabs, a few rows or columns each, and never the block.
     """
-    slabs, token = _received(
+    _, slabs, token = _received(
         [arr], comm, periodic=periodic, token=token, width=width, stack=False,
     )
     return tuple(got for got, in slabs), token

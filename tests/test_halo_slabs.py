@@ -129,6 +129,48 @@ def test_the_slabs_lower_under_pack_and_wire_and_nothing_is_unpacked():
     assert f"{SCOPE_PREFIX}halo_exchange_2d/unpack" in text
 
 
+def test_the_mesh_tier_holds_the_block_row_major_and_the_proc_tier_does_not(
+        monkeypatch):
+    """On the mesh tier the block a column slab is cut from is held
+    row-major, once, under the op's ``pack`` scope, as the exchange that
+    writes the ghosts holds it (left to itself the TPU compiler lays the
+    carried block out to suit the slabs and transposes it back for the
+    caller's kernel: PERF.md, PR 52); the multi-process tier, whose
+    slabs leave through the host, is handed no layout."""
+    from mpi4jax_tpu.native import runtime
+    from mpi4jax_tpu.parallel import ProcGridComm, halo
+
+    def third(arr, comm, periodic, width):
+        return halo_slabs_2d(arr, comm, periodic=periodic, width=width)[0][2]
+
+    op = f"{SCOPE_PREFIX}halo_slabs_2d"
+    program, starts = _program(_comm((2, 2)), 2, (False, True), third)
+    text = jax.jit(program).lower(starts).as_text(debug_info=True)
+    held = [line for line in text.splitlines() if "@LayoutConstraint" in line]
+    assert len(held) == 1 and "result_layouts = [dense<[1, 0]>" in held[0]
+    assert f'loc("{op}/pack/layout_constraint"' in text
+    # and the exchange that writes them holds its block once too
+    program, starts = _program(_comm((2, 2)), 2, (False, True), _exchanged)
+    text = jax.jit(program).lower(starts).as_text(debug_info=True)
+    assert text.count("@LayoutConstraint") == 1
+    assert f'loc("{SCOPE_PREFIX}halo_exchange_2d/pack/layout_constraint"' in text
+
+    # the same call on a 2x2 grid of processes, traced as rank 0 with the
+    # wire taken out (no native runtime here): slices and no constraint
+    grid = ProcGridComm(
+        ranks=(0, 1, 2, 3), context=52, axes=("y", "x"), axis_sizes=(2, 2))
+    assert grid.backend == "proc"
+    monkeypatch.setattr(runtime, "world_rank", lambda: 0)
+    monkeypatch.setattr(
+        halo, "sendrecv_multi",
+        lambda slabs, templates, *, token, **_: (list(templates), token))
+    arr = jnp.zeros((NY + 4, NX + 4), jnp.float32)
+    text = jax.jit(lambda a: third(a, grid, (False, True), 2)).lower(
+        arr).as_text(debug_info=True)
+    assert f"{op}/pack/slice" in text
+    assert "LayoutConstraint" not in text and "layout_constraint" not in text
+
+
 def _written_between_the_shifts(arrs, comm, periodic, width, stack):
     """The exchange as it was before its ghosts were written once: every
     shift's slabs sliced from blocks that hold the shifts' before it,

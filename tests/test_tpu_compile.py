@@ -74,6 +74,7 @@ def test_flash_compiles_for_v5e(v5e, size, direction):
     assert "tpu_custom_call" in text
 
 
+@functools.cache  # a dozen tests read five programs
 def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
     """The donated ``steps``-step call at ``ny`` x ``nx`` cells a chip,
     compiled for the described chips; ``steps`` 0: the first step."""
@@ -133,6 +134,14 @@ def _kernels(text):
     return found
 
 
+def _aliased_in_place(line, places):
+    """Whether a Pallas call's line aliases its results, in order, to
+    the operands at ``places`` (:func:`_kernels`'s fields)."""
+    aliasing = ", ".join(
+        f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
+    return f"output_to_operand_aliasing={{{aliasing}}}" in line
+
+
 def _copied(text, fields):
     """Those of a call's field operands that are copies, but for a
     field XLA kept in its faster memory (``S(1)``: a field of a few
@@ -169,9 +178,7 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
         # the walk of one step is another kernel than the walk of two
         assert len({body for _, _, body, _ in calls}) == len(calls)
     for line, fields, _, places in calls:
-        aliasing = ", ".join(
-            f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
-        assert f"output_to_operand_aliasing={{{aliasing}}}" in line
+        assert _aliased_in_place(line, places)
         assert len(fields) == 6 and not _copied(text, fields), fields
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 2**30  # six fields of ~26 MB
@@ -197,8 +204,10 @@ def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
 def _scoped_vmem(line):
     """``(used, limit)`` of a compiled Pallas call's line: the bytes of
     VMEM the TPU compiler laid out for it (blocks, scratch and its own
-    spills) and the most it was allowed (``None``: the compiler's own)."""
-    size = r'scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"'
+    spills) and the most it was allowed (``None``: the compiler's own).
+    The room starts past whatever XLA keeps in that memory across the
+    call (8 KB of slabs in the job's program on 2x2 since PR 52)."""
+    size = r'scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"'
     used, = re.findall('"used_' + size, line)
     limit = re.findall('"' + size, line)
     return int(used), int(limit[0]) if limit else None
@@ -211,8 +220,14 @@ def _scoped_vmem(line):
 # fewer spilled): (1, 1), the walk of two steps, 58 318 848 before, up
 # by 188 416 (eight strips of 118 784 bytes in, 761 856 of spills out);
 # (2, 2), the walk of one, 89 575 424 before, down by 622 592 (four
-# strips in, 1 097 728 of spills out)
-KERNEL_VMEM = {(1, 1): 58_507_264, (2, 2): 88_952_832}
+# strips in, 1 097 728 of spills out).  PR 52, (2, 2): 88 952 832 was
+# 53 731 328 of two fields that XLA kept in that memory across the call,
+# beside the transposes it made of them, and 35 221 504 of the call's
+# own after them; with the block held row-major no field lies there, the
+# call's room starts at 0, and its own is 5 226 496 more: two windows of
+# 88 rows of 3712 for each of those two fields, which it reads from HBM
+# again like the other four
+KERNEL_VMEM = {(1, 1): 58_507_264, (2, 2): 40_448_000}
 
 
 @pytest.mark.parametrize("mesh_shape", sorted(KERNEL_VMEM))
@@ -263,12 +278,28 @@ def _step_body(text):
         text, re.search(r"\bwhile\(.*?body=%([\w.\-]+)", text)[1])
 
 
+def _moves_a_field(text, field):
+    """The instructions of a compiled program's text that hand back a
+    field's bytes in another place or layout: a ``copy``, a
+    ``copy-start``, a ``copy-done`` or a ``transpose`` whose result, or
+    the first element of it, is of the shape ``field``."""
+    return re.findall(
+        rf"%([\w.\-]+) = \(?{re.escape(field)}[^=]*? "
+        r"(?:copy|copy-start|copy-done|transpose)\(", text)
+
+
 # what the step's loop body holds that moves or computes something.  One
 # chip: the loop's counter, three fusions of two slices (a field's sent
 # columns) and the kernel, which walks two steps (the broadcast that
 # built the kernel's two wall flags in the loop went when `lone` joined
-# them: on one chip the three are a constant)
-STEP_INSTRUCTIONS = {(1, 1): 5, (2, 2): 99}
+# them: on one chip the three are a constant).  Four: 99 while XLA
+# transposed each field once a step (three `copy` of a field, five
+# `copy-start` and `copy-done` of fields and flags into and out of its
+# faster memory, four `slice-start` and `slice-done` and the
+# `ConcatBitcast` that put a field together again); since PR 52 those
+# 22 are gone, and each of the six sent column slabs is transposed by
+# itself for its permute, as the six received ones were and are
+STEP_INSTRUCTIONS = {(1, 1): 5, (2, 2): 83}
 
 
 @pytest.mark.parametrize("mesh_shape", sorted(STEP_INSTRUCTIONS))
@@ -286,14 +317,11 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
             types[x].startswith("f32[1804,3604]") for x in operands)]
 
     assert not on_a_field("dynamic-update-slice") and not on_a_field("scatter")
-    # a copy of a field: none on one chip.  On four XLA wants the
-    # permutes' column slabs lane-dense and gets them by transposing
-    # the block they are sliced from, once a field, as it did before the
-    # kernel took the slabs: what is left of the exchange there
-    # (PERF.md section 7)
-    transposes = on_a_field("copy")
-    assert len(transposes) == (0 if chips == 1 else 3)
-    assert all(types[name].startswith("f32[1804,3604]{0,1") for name in transposes)
+    # no copy of a field on any mesh: XLA wants the permutes' column
+    # slabs lane-dense, and the block they are sliced from is held
+    # row-major (parallel/halo.py _row_major), so it transposes the
+    # slabs and not the block (PERF.md section 6, PR 52)
+    assert not _moves_a_field(text, "f32[1804,3604]")
     calls = [line for *_, line in body if "tpu_custom_call" in line]
     # one a step; on one chip one for two steps, five times for ten
     assert len(calls) == 1
@@ -304,13 +332,39 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
     # (the y shifts move nothing), four on four
     slabs = 3 * (2 if chips == 1 else 4)
     assert places == [2, 3, 4, *(5 + slabs + k for k in range(3))]
-    aliasing = ", ".join(
-        f"{{{k}}}: ({place}, {{}})" for k, place in enumerate(places))
-    assert f"output_to_operand_aliasing={{{aliasing}}}" in line
+    assert _aliased_in_place(line, places)
     assert len(fields) == 6 and not _copied(text, fields), fields
     opcodes = [opcode for _, opcode, *_ in body]
     assert ("collective-permute-start" in opcodes) == (chips > 1)
     assert len(body) == STEP_INSTRUCTIONS[mesh_shape], opcodes
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_the_cells_step_copies_no_block(v5e, mesh_shape):
+    """The 10-step call at the benchmark cells' own block, 7200 x 14400
+    cells a chip (`sw-monitored-2x2-weak` on 2x2, the six one-chip cells
+    on 1x1): nowhere in the program is a field's bytes handed back by a
+    ``copy``, a ``copy-start`` or a ``transpose`` (three ``copy`` of a
+    415 MB field to ``{0,1}`` a step on 2x2 until PR 52, a third of the
+    step's device time), the program's temporaries are the slabs and
+    not a field, the six arrays of the state are the kernel call's own
+    operands, aliased to its results, and the wire is what it was: on
+    four chips twelve permutes a step, two column slabs and two row
+    slabs a field, on one none."""
+    chips = mesh_shape[0] * mesh_shape[1]
+    compiled = _compiled_multistep(v5e, mesh_shape, 2, 7200, 14400, 10)
+    text = compiled.as_text()
+    assert not _moves_a_field(text, "f32[7204,14404]")
+    body, types = _step_body(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    (line, fields, _, places), = _kernel_calls(text)
+    assert _aliased_in_place(line, places)
+    assert len(fields) == 6 and not _copied(text, fields), fields
+    assert _trips(text) == (5 if chips == 1 else 10)
+    sent = sorted(types[name].split("{")[0] for name, opcode, *_ in body
+                  if opcode == "collective-permute-start")
+    assert sent == (["(f32[2,14404]"] * 6 + ["(f32[7204,2]"] * 6) * (chips > 1)
+    assert text.count(" collective-permute-start(") == len(sent)
 
 
 @functools.cache  # four tests read two programs
@@ -658,11 +712,10 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
         # the room: the call's last three operands, no copies either
         operands = line.split("custom-call(")[1].split(")")[0].split(", ")
         assert not _copied(text, operands[-3:]), operands
-    # on four chips XLA transposes each field once a step to slice its
-    # column slabs (PERF.md section 7), in the loop's step and in the
-    # last; on one nothing of a field's size is copied
-    copies = re.findall(r"= f32\[7204,14404\]\S* copy\(", text)
-    assert len(copies) == (0 if py * px == 1 else 2 * 3)
+    # nothing of a field's size is copied on any mesh, in the loop's
+    # step or in the last (until PR 52 XLA transposed each field once a
+    # step on four chips to slice its column slabs: 2 * 3 here)
+    assert not _moves_a_field(text, "f32[7204,14404]")
     # three arrays of row sums beside the state: 76 blocks of 24 rows,
     # four tiles' sums each, whole width, in and out
     new_state, sums = lowered.out_info
