@@ -522,7 +522,9 @@ def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
     first mesh's to the rounding of another decomposition.  On the chip
     the step is the kernel and the snapshots start in it: the record's
     ``snapshots_summed_in_step`` says for how many on each mesh (all of
-    them where ``coarsen`` divides a strip; none on the CPU)."""
+    them where ``coarsen`` divides a strip; none on the CPU), and
+    ``steps_per_walk`` how many time steps a walk of it advances (2 on
+    every mesh, beside neighbours from deeper slabs; 1 on the CPU)."""
     import numpy as np
 
     from mpi4jax_tpu.models import shallow_water as sw
@@ -575,6 +577,9 @@ def solver_job_check(cfg, devices, *, mesh_shapes=((1, 1),), coarsen=4,
         "max_diff": max(last, across),
         "snapshots_summed_in_step": {
             "x".join(map(str, shape)): job.stats()["snapshots_summed_in_step"]
+            for shape, job, _ in runs},
+        "steps_per_walk": {
+            "x".join(map(str, shape)): job.stats()["steps_per_walk"]
             for shape, job, _ in runs},
     }
     if last > TOL_BLOCK_MEAN:

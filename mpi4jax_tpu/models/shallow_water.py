@@ -669,23 +669,30 @@ def _runs_as_kernels(cfg, comm):
     )
 
 
+def _reaches_further(comm):
+    """``(rows, columns)`` beyond its block's ring that a device needs
+    of its neighbours for a walk of two steps: the ring's width again on
+    an axis where it has a neighbour, nothing on an axis of one device,
+    whose ghosts are the block's own other end (x) or a wall's (y)."""
+    return tuple(sw_kernels.G * (n > 1) for n in comm.axis_sizes)
+
+
 def _walks_two_steps(cfg, comm):
     """Whether a walk of the step's kernel advances two time steps
-    (:func:`_step_wide`): where the step is the kernel, **the mesh is
-    one device**, and the second step's rings fit VMEM beside the
-    first's blocks.  On one device nothing a step needs is another
-    chip's: periodic in x, a row's ghost columns are the row's other
-    end, which the kernel sets itself between its two steps, and in y
-    both sides are walls.  With a neighbour on either axis the second
-    step's ghosts are that chip's second step's, and the walk stays one
-    step."""
+    (:func:`_step_wide`): where the step is the kernel, the second
+    step's rings fit VMEM beside the first's blocks, and, on an axis
+    with a neighbour, the block has room in VMEM for the two rings more
+    that the walk then computes on (``sw_kernels.holds_further``: lanes
+    past a row's last column, rows past the field's last in its last
+    tile).  The second step's ghosts are the first step's results next
+    to the block, and the kernel makes them itself: on an axis of one
+    device from the block's own other end (periodic x) or not at all
+    (walls), on an axis with a neighbour from slabs cut four deep of the
+    fields and the tendencies, by the code that the neighbour runs on
+    the same cells.  A block without that room walks one step."""
     ny_l, nx_l = cfg.local_interior(comm)
-    return (
-        _runs_as_kernels(cfg, comm)
-        and comm.mesh.devices.size == 1
-        and sw_kernels.tile_rows(
-            ny_l + 4, nx_l + 4, jnp.dtype(cfg.dtype), fields=6, steps=2) > 0
-    )
+    return _runs_as_kernels(cfg, comm) and sw_kernels.holds_further(
+        ny_l + 4, nx_l + 4, jnp.dtype(cfg.dtype), _reaches_further(comm))
 
 
 def _kernels_ahead(cfg, comm):
@@ -727,19 +734,28 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
                              then round 2
 
     3 exchanges and 12 passes over a field a walk of the kernel, and no
-    ghost written outside the kernel.  On a mesh of one device
-    (:func:`_walks_two_steps`) ``steps=2`` makes the one walk advance two
-    time steps: the same three ``halo_slabs_2d`` for the first, and for
-    the second nothing, because what its exchange would bring is the
-    block's own (periodic in x, a row's ghost columns are its other
-    end, which the kernel sets between the steps; walls in y, nothing
-    comes), so two steps are 12 passes and not 24 and the first step's
-    results never reach HBM.  With a neighbour on either axis the second
-    step's ghosts are another chip's and a walk is one step.  Either way
-    the state that comes back is the one single steps return, bit for
-    bit, ghosts and all.  There a first step is the same kernel with the
-    first of its two steps passed over (``wide_step(lone=True)``), so
-    that a process builds one kernel for ``make_first_step`` and
+    ghost written outside the kernel.  Where :func:`_walks_two_steps`
+    holds, ``steps=2`` makes the one walk advance two time steps, so
+    two steps are 12 passes and not 24 and the first step's results
+    never reach HBM.  The second step has no exchange of its own: on a
+    mesh of one device what it would bring is the block's own (periodic
+    in x, a row's ghost columns are its other end, which the kernel
+    sets between the steps; walls in y, nothing comes), and the walk
+    starts from the same three ``halo_slabs_2d`` as a single one.  On
+    an axis with a neighbour it would bring that chip's first step's
+    results, which the kernel computes itself, two rings further out
+    than its block, from **one exchange four cells deep of the fields
+    and of the tendencies** (``halo_slabs_2d(depth=)`` of all six
+    arrays, 24 permutes every second step where single walks send 12 a
+    step; :mod:`sw_kernels`, "Two steps a walk").  The state that comes
+    back is the one single steps return, bit for bit on the interior of
+    all six arrays (on one device on the ghosts too; beside a neighbour
+    the ghost cells of ``h`` and ring 2 of ``u``, ``v`` hold the kernel's
+    own first step there, which the next exchange overwrites).  A first
+    step is the same kernel with the first of its two steps passed over
+    (``wide_step(lone=True)``; beside neighbours from the same deep
+    slabs of ``h``, ``u``, ``v``, and zeros in place of the tendencies'),
+    so that a process builds one kernel for ``make_first_step`` and
     ``make_multistep``; only an odd count's last step is a walk of one.
     ``halo_slabs_2d`` is the exchange without its last phase, and the
     kernel, which reads and writes every tile that holds
@@ -781,9 +797,33 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
         # --- the exchange without its ghost writes: the kernel, which
         # reads and writes every tile that holds a ghost cell anyway,
         # places the received slabs itself ---
-        for_h, token = halo_slabs_2d(h, comm, periodic=per, token=token, width=G)
-        for_u, token = halo_slabs_2d(u, comm, periodic=per, token=token, width=G)
-        for_v, token = halo_slabs_2d(v, comm, periodic=per, token=token, width=G)
+        if not first_step and dh.shape != h.shape:
+            raise ValueError(
+                f"tendencies of shape {dh.shape} beside fields of shape "
+                f"{h.shape}: where the step runs as a kernel its state "
+                "carries them padded, as make_init and make_first_step "
+                "return them")
+        # where the rest of a run walks two steps at a time, its first
+        # step goes through that kernel too, the first of the two passed
+        # over: one kernel a process, traced once
+        lone = first_step and _walks_two_steps(cfg, comm)
+        further = _reaches_further(comm) if lone or steps == 2 else (0, 0)
+        if any(further):
+            # a walk of two steps beside neighbours: their four columns
+            # and rows next to the block, of the fields and (the first
+            # step's update out there reads them) of the tendencies,
+            # of which a run's first step has none to send
+            slabs, token = halo_slabs_2d(
+                [h, u, v] if first_step else list(state), comm, periodic=per,
+                token=token, width=G, depth=tuple(G + n for n in further))
+            if first_step:
+                slabs += [jax.tree.map(jnp.zeros_like, slabs[0])] * 3
+            slabs = tuple(slabs)
+        else:
+            for_h, token = halo_slabs_2d(h, comm, periodic=per, token=token, width=G)
+            for_u, token = halo_slabs_2d(u, comm, periodic=per, token=token, width=G)
+            for_v, token = halo_slabs_2d(v, comm, periodic=per, token=token, width=G)
+            slabs = for_h, for_u, for_v
         if first_step:
             # forward Euler is AB2 with (1, 0) on zero tendencies, and the
             # caller's, which this step does not read, may be of either shape
@@ -791,19 +831,9 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
             dh = du = dv = jnp.zeros_like(h)
         else:
             a, b = cfg.ab_a, cfg.ab_b
-            if dh.shape != h.shape:
-                raise ValueError(
-                    f"tendencies of shape {dh.shape} beside fields of shape "
-                    f"{h.shape}: where the step runs as a kernel its state "
-                    "carries them padded, as make_init and make_first_step "
-                    "return them")
         iy, _ix = _device_coords(comm)
-        # where the rest of a run walks two steps at a time, its first
-        # step goes through that kernel too, the first of the two passed
-        # over: one kernel a process, traced once
-        lone = first_step and _walks_two_steps(cfg, comm)
         *state, = sw_kernels.wide_step(
-            h, u, v, dh, du, dv, (for_h, for_u, for_v), is_south, is_north,
+            h, u, v, dh, du, dv, slabs, is_south, is_north,
             iy * ny_l, a, b, lone, *([summing, tuple(sums)] if coarsen else []),
             nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
             gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
@@ -1018,11 +1048,11 @@ def make_multistep(cfg, comm, num_steps, *, donate=False, snapshot=None):
     ``snapshot`` is.
     """
 
-    # steps a walk of the kernel (``_step_wide``): two on one device
+    # steps a walk of the kernel (``_step_wide``)
     stride = 2 if _walks_two_steps(cfg, comm) else 1
     coarsen = snapshot.coarsen if _sums_in_step(cfg, comm, snapshot) else 0
     # the walks of a call, the last one apart where it writes the sums
-    # (an odd count's on one device is a single step's walk)
+    # (an odd count's is a single step's walk)
     last = num_steps % stride or stride
     looped = (num_steps - last * bool(coarsen)) // stride
 
@@ -1696,6 +1726,7 @@ class SolverJob:
         self._copies = ckpt.Side(self._host, ckpt.SNAPSHOT, partial(
             self.trace.span, "job/ask_wait"))
         self._stats = dict(
+            steps_per_walk=2 if _walks_two_steps(cfg, comm) else 1,
             snapshots_produced=0, snapshots_summed_in_step=0,
             snapshots_delivered=0, max_lag=0,
             bytes_to_host=0, output_wait_s=0.0, callback_s=0.0,
@@ -1798,7 +1829,11 @@ class SolverJob:
             self._read_lines(0, raises)
 
     def stats(self):
-        """The job's counters: ``snapshots_produced``,
+        """The job's counters: ``steps_per_walk``, the time steps that
+        a pass over the state advances in the calls' loop (2 where the
+        step is the kernel and :func:`_walks_two_steps` holds, on one
+        device and beside neighbours; 1 where it is array code or the
+        block has no room); ``snapshots_produced``,
         ``snapshots_summed_in_step`` (of them, those whose sums over
         ``coarsen`` rows the call's own last walk made: all where the
         step is the kernel and :func:`_sums_in_step` holds, none

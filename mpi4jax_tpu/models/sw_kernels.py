@@ -7,9 +7,10 @@ that copies the interior back into the padded field.  The kernel here
 instead: every intermediate stays on the chip, and each of the six state
 arrays (``h``, ``u``, ``v`` and their tendencies) is read once and
 written once, in place: 12 passes over a field a walk, the least a walk
-can move.  On a mesh of one device a walk advances **two time steps**
-("Two steps a walk", below): 12 passes where two walks move 24, which
-its vector work keeps up with to within a few per cent of HBM's pace.
+can move.  A walk advances **two time steps**, on one device and beside
+neighbours ("Two steps a walk", below): 12 passes where two walks move
+24, which its vector work keeps up with to within a few per cent of
+HBM's pace.
 
 Schedule: three exchanges, not five
 -----------------------------------
@@ -128,43 +129,92 @@ that take the vorticity; two halves of a sum of halves).  The
 schedule's count is in ``PERF.md`` section 5: 142 bundles a vector
 register for two steps became 95.
 
-Two steps a walk, on one device
-------------------------------
+Two steps a walk
+----------------
 At 12 passes a step the kernel moved its bytes faster than a plain copy
-does on a v5e and its vector units waited a quarter of the time.  So
-where nothing a step needs is another chip's, a walk carries two steps
-(``wide_step(steps=2)``; ``shallow_water.make_multistep`` asks for it on
-a mesh of one device, ``_walks_two_steps``).  The four stages (round 1
-and round 2 of step *n*, round 1 and round 2 of step *n + 1*) run in the
-same pass of the same loop, **each a tile behind the one before it**, as
-round 2 runs behind round 1.  What step *n* produces goes to rings of
-strips in VMEM and never to HBM: ``h`` and the three new tendencies
-(step *n + 1*'s old ones) from its round 1, two tiles and a strip or two
-long because round 1 of step *n + 1* is two stages behind; the final
-``u``, ``v`` from its round 2, a tile and two strips.  Only step *n +
-1*'s six arrays are written, three and four grid steps behind their
-input; the grid is two steps longer.  The stages are ``first`` and
-``second`` as they are, applied twice: the same operations on the same
-values in the same order, so a walk of two steps returns bit for bit,
-ghosts and all, what two walks of one return.
+does on a v5e and its vector units waited a quarter of the time.  So a
+walk carries two steps (``wide_step(steps=2)``;
+``shallow_water.make_multistep`` asks for it wherever
+``_walks_two_steps`` holds, on one device and beside neighbours).  The
+four stages (round 1 and round 2 of step *n*, round 1 and round 2 of
+step *n + 1*) run in the same pass of the same loop, **each a tile
+behind the one before it**, as round 2 runs behind round 1.  What step
+*n* produces goes to rings of strips in VMEM and never to HBM: ``h`` and
+the three new tendencies (step *n + 1*'s old ones) from its round 1, two
+tiles and a strip or two long because round 1 of step *n + 1* is two
+stages behind; the final ``u``, ``v`` from its round 2, a tile and two
+strips.  Only step *n + 1*'s six arrays are written, three and four
+grid steps behind their input; the grid is two steps longer.  The stages
+are ``first`` and ``second`` as they are, applied twice: the same
+operations on the same values in the same order, so a walk of two steps
+returns bit for bit, on the interior of all six arrays, what two walks
+of one return with the exchange between them.
 
-Why one device.  Step *n + 1* reads ghosts that an exchange would have
-brought after step *n*.  Periodic in x on one device, a row's ghost
-columns are the same row's other end: as a strip of ``h``, ``u`` or
-``v`` goes to its ring, columns ``width - 4, width - 3`` go to columns
-``0, 1`` and columns ``2, 3`` to ``width - 2, width - 1``, a lane
-rotation and a selection on the first and the last vector register of
-the strip (:func:`_ends_meet`), every row, as ``halo_slabs_2d`` slices
-them there.  In y one device has walls on both sides and the exchange
-brings nothing: those ghost rows keep their values.  Step *n* takes its
-slabs from the exchange exactly as a single walk does.  With a
-neighbour on either axis step *n + 1*'s ghosts are another chip's step
-*n*, two rings are not enough for two steps (round 1 on ring 1 reads
-ring 2), and the walk stays one step.  The returned state is a single
-walk's: ring 1 of ``du``, ``dv`` the step's own round 1 there, ring 1 of
-``u``, ``v`` round 1's values of the last step, the ghost columns of
-``h`` and ring 2 of ``u``, ``v`` what the exchange before the last step
-would have written (the other end as step *n* left it).
+Where the second step's ghosts come from.  Step *n + 1* reads ghosts
+that an exchange would have brought after step *n*: the final ``h``,
+``u``, ``v`` of step *n* on rings 1 and 2.  Nothing brings them; the
+kernel makes them, axis by axis, from what ``comm`` says of the axis:
+
+* **x, one device, periodic.**  A row's ghost columns are the same row's
+  other end: as a strip of ``h``, ``u`` or ``v`` goes to its ring,
+  columns ``width - 4, width - 3`` go to columns ``0, 1`` and columns
+  ``2, 3`` to ``width - 2, width - 1``, a lane rotation and a selection
+  on the first and the last vector register of the strip
+  (:func:`_ends_meet`), every row, as ``halo_slabs_2d`` slices them
+  there.
+* **y, one device.**  Walls on both sides; the exchange brings nothing
+  and those ghost rows keep their values.
+* **an axis with a neighbour.**  Those cells are the neighbour's
+  interior, and the kernel computes them **as the neighbour does, from
+  the same inputs by the same code**, as it already makes ring 1 of
+  ``u``, ``v`` between its two rounds.  Step *n*'s round 2 out to ring 2
+  reads its round 1 out to ring 3 (``h`` to ring 2), which reads ring
+  4; its Adams-Bashforth update out there reads last step's tendencies
+  on rings 1 to 3, which no walk of this chip computed.  So the walk
+  starts from **one exchange four cells deep of** ``h``, ``u``, ``v``
+  **and of** ``dh``, ``du``, ``dv`` (``halo_slabs_2d(depth=)``), every
+  second step, and the first application of ``first`` and ``second``
+  gets the wider reach (two rings more of rows by the scalars in SMEM,
+  of columns by ``_stages(out=)``), the second application a single
+  walk's.  Beyond a wall nothing comes, there as on one device, and the
+  reach ends at the wall.
+
+Where rings 3 and 4 live.  **Not in HBM**: the state keeps its shape,
+padded by 2, and everything that reads it (the monitor, snapshots,
+saves, ``gather_global``) reads what it read.  In VMEM a block's rows
+are :func:`_whole_registers` lanes wide, wider than the field, and a lane
+rotation wraps over all of them: the two columns east of the block lie
+in the lanes after its last column, the two west of it **in the row's
+last two lanes**, where the rotation that brings column 0 its western
+neighbour finds them (``_stages`` ``box`` knows).  The rows north of the
+block lie in the rows after the field's last in its last tile, which the
+strips' loop runs over anyway.  The rows south of it are **a strip
+before the block's first**: the window's first strip, which the walk's
+first grid step leaves free, takes the southern slabs' first two rows
+into its last two, and the first step's first stage runs on that one
+strip more, once a walk (``before_the_block``), for what row 0 reads of
+row -1: round 1's ``u``, ``v`` there on their way to round 2, and the
+fluxes, the energy and the vorticity the stage hands the strip after.
+The slabs of a row reach over the deeper x slabs (their ends are those
+slabs' rows, so that corners fill transitively, four deep) and are laid
+along the block's lanes before the call, the western columns last.  The
+tendencies have no window: their slabs are stored over the block of
+tile ``i - 1`` where the pipeline brought it in (the lanes and rows past
+the field's are the block's too), before the first stage reads it.
+:func:`holds_further` is what all this needs of a shape: four lanes to
+spare, two rows to spare in the last tile, a strip of interior rows (a
+neighbour's ring computed here must not feel the neighbour's other
+wall); a block without walks one step, as before PR 53.
+
+The returned state is a single walk's on the interior, and on the
+ghosts where the next walk reads them: ring 1 of ``du``, ``dv`` the
+last step's own round 1 there, ring 1 of ``u``, ``v`` round 1's values
+of the last step.  The ghost cells of ``h`` and ring 2 of ``u``, ``v``
+hold what the walk computed for the second step (on one device the
+row's other end as step *n* left it, beside a neighbour the kernel's
+own step *n* there, which is the neighbour's bit for bit): what an
+exchange before the last step would have written, and what the next
+exchange overwrites.
 
 Output in the last walk
 -----------------------
@@ -255,11 +305,14 @@ does not pay for it), a kernel's body is written in ``lax``, the call
 is jitted, so that the programs of one process trace it once, and so
 are the two stages (:func:`_stages`), which are most of a body: the
 kernel of a single walk and both applications of a double one trace
-each once in a process.  A process on one device builds one kernel,
-the double walk's: a run's first step is that walk with its first
-step passed over by a scalar (``wide_step(lone=True)``), which hands
-the second zero tendencies, forward Euler's.  Only an odd count's last
-step builds the single walk's beside it.  (A kernel's trace is 0.15 to
+each once in a process (beside a neighbour in x the first application
+has its wider columns and is traced beside the second).  A process
+builds one kernel, the double walk's: a run's first step is that walk
+with its first step passed over by a scalar (``wide_step(lone=True)``),
+which hands the second zero tendencies, forward Euler's (beside
+neighbours from the same deep slabs of the fields, and zeros for the
+tendencies').  Only an odd count's last step builds the single walk's
+beside it.  (A kernel's trace is 0.15 to
 0.4 s on a chip's host: PERF.md, PR 41.)
 """
 
@@ -361,8 +414,35 @@ add, sub, mul, div, eq, select = (
     lax.add, lax.sub, lax.mul, lax.div, lax.eq, lax.select)
 
 
+def _further(slabs):
+    """``(rows, columns)`` by which the slabs of a call's first field
+    are deeper than the ring: 0 on an axis that brings the ring's
+    ``G``, or nothing."""
+    west, _, south, _ = slabs[0]
+    return (0 if south is None else south.shape[0] - G,
+            0 if west is None else west.shape[1] - G)
+
+
+def holds_further(rows, width, dtype, further, arrays=6):
+    """Whether a walk of two steps over ``arrays`` arrays of ``rows`` x
+    ``width`` has room for ``further`` (rows, columns) beyond the block
+    on every side ("Two steps a walk"): the columns in the lanes past
+    the field's last (the western ones at the very last, where a
+    rotation's wrap finds them), the northern rows in the last tile's
+    rows past the field's last; and enough interior rows that no ring
+    of a neighbour's that is computed here feels the neighbour's other
+    wall."""
+    ey, ex = further
+    tile = tile_rows(rows, width, dtype, arrays, steps=2)
+    return bool(
+        tile and _whole_registers(width) - width >= 2 * ex
+        and -(-rows // tile) * tile - rows >= ey
+        and (not ey or rows - 2 * G >= STRIP))
+
+
 def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
-          steps=1, summed=(), coarsen=0, summing=True, sums=(), *, interpret):
+          steps=1, summed=(), coarsen=0, summing=True, sums=(),
+          point_slabs=(), *, interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
@@ -408,6 +488,16 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
     periodic axis; ghost rows stay what they are, as beyond a wall.
     The second application's values are the call's results.
 
+    Slabs deeper than ``G`` (``steps`` 2 alone; ``point_slabs``: the
+    pointwise arrays' slabs beside the fields', as deep) are a walk's
+    whose first step reaches further than the block ("Two steps a
+    walk": where the rows and columns beyond the block lie, and the one
+    strip more the first stage runs on).  ``body`` gives its first pair
+    of stages the wider reach; this function places the slabs, the
+    pointwise arrays' too, and leaves the ghost columns of what the
+    first step hands on as that step computed them where the x slabs
+    are deep (no other end to take them from).
+
     ``summed`` (the places in ``fields`` of those asked for),
     ``coarsen`` (a divisor of ``STRIP``) and ``sums`` (for each such
     field an array of :func:`row_sums_shape`, whatever it holds): after
@@ -447,11 +537,32 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         raise ValueError("a walk of two steps hands each field a pointwise array")
     axes = vma_of(fields[0]) or ()
     scalars = [promote_vma(x, axes) for x in scalars]
-    # the slabs that came, field by field, each with what it is a slab
-    # of (0: columns, 1: rows) and its first ghost column or row
-    places = ((0, 0), (0, width - G), (1, 0), (1, rows - G))
-    came = [[(*place, x) for place, x in zip(places, sides) if x is not None]
-            for sides in slabs]
+    # how far beyond the block the slabs reach: rows, columns
+    ey, ex = _further(slabs)
+    if (ey or ex) and not (
+            steps > 1 and len(point_slabs) == n_point
+            and holds_further(rows, width, dtype, (ey, ex), n_fields + n_point)):
+        raise ValueError(
+            "slabs deeper than the ring are a walk of two steps', with the "
+            "pointwise arrays' beside the fields', on a block with lanes "
+            "and rows to spare (holds_further)")
+
+    def laid(of_rows, x):
+        """A slab as the kernel takes it: a slab of rows that reaches
+        beyond the block's columns as a block's rows lie in VMEM, the
+        columns west of the block at the row's last lanes."""
+        if not (of_rows and ex):
+            return x
+        spare = jnp.zeros_like(x, shape=(x.shape[0], lanes - x.shape[1]))
+        return lax.concatenate([x[:, ex:], spare, x[:, :ex]], 1)
+
+    # the slabs that came, field by field and then pointwise array by
+    # array, each with what it is a slab of (0: columns, 1: rows) and
+    # the first column or row it holds, counted from the block's first
+    places = ((0, -ex), (0, width - G), (1, -ey), (1, rows - G))
+    came = [[(*place, laid(place[0], x))
+             for place, x in zip(places, sides) if x is not None]
+            for sides in (*slabs, *point_slabs)]
     n_slabs = [len(sides) for sides in came]
     arrived = [promote_vma(x, axes) for sides in came for *_, x in sides]
     # the blocks of a stage are written as many grid steps behind their
@@ -470,11 +581,13 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         on = next(refs) if summed else None
         # `_room`: the sums as they came, which nobody reads
         (scalar_refs, taken, *brought, old, _room, out, new, totals, windows,
-         rings, kept, partial, held_fields, held_point, rings_again) = (
+         rings, kept, partial, held_fields, held_point, rings_again,
+         old_before) = (
             tuple(itertools.islice(refs, n)) for n in
             (n_scalars, n_fields, *n_slabs, n_point, n_summed, n_fields, n_point,
              n_summed, n_fields, n_second, n_kept, n_summed,
-             *((n_fields, n_point, n_second) if steps > 1 else (0, 0, 0))))
+             *((n_fields, n_point, n_second) if steps > 1 else (0, 0, 0)),
+             n_point * bool(ey)))
         i = pl.program_id(0)
         applied = body(pltpu.roll, *scalar_refs)
 
@@ -487,32 +600,75 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         # field's last again
         t = lax.min(i, tiles - 1)
 
-        def place(win, at, count, k):
+        def pieces(k):
+            """The slabs of array ``k`` (the fields, then the pointwise
+            arrays) in the parts a store takes: ``(of_rows, start, n,
+            ref, mine)``, ``n`` columns that lie in one vector register
+            or rows that lie in one strip, from column or row ``start``
+            of the block on, which are ``ref``'s ``mine``; x before y,
+            as an exchange writes them (the y slabs hold the corners)."""
+            for (of_rows, lo, x), ref in zip(came[k], brought[k]):
+                for start, n in _pieces(
+                        lo, x.shape[1 - of_rows], STRIP if of_rows else LANES):
+                    yield of_rows, start, n, ref, pl.ds(start - lo, n)
+
+        def place(win, at, count, k, rows):
             """Rows ``[at, at + count)`` of field ``k``'s window have
             just taken the first ``count`` rows of tile ``t`` as the
             field holds them, ghosts stale: write the slabs' cells over
-            them, x before y as an exchange does (the y slabs hold the
-            corners).  A slab's columns in one store where they lie in
-            one vector register, its rows where they lie in one strip,
-            and in the grid step that holds that strip."""
-            for (of_rows, lo, _), ref in zip(came[k], brought[k]):
-                for start, n in _pieces(lo, G, STRIP if of_rows else LANES):
-                    mine = pl.ds(start - lo, n)
-                    if not of_rows:
-                        win[pl.ds(at, count), pl.ds(start, n)] = (
-                            ref[pl.ds(0, count), mine])
-                        continue
-                    holder, row = divmod(start, tile)
-                    if row < count:
-                        @pl.when(eq(t, holder))
-                        def _(row=row, n=n, mine=mine, ref=ref):
-                            win[pl.ds(at + row, n), :] = ref[mine, :]
+            them.  A slab's columns in one store where they lie in
+            one vector register (those west of the block at the row's
+            last lanes); its rows where they lie in one strip, in the
+            grid step that holds that strip, which ``in_their_tile``
+            does for every array at once, from ``rows`` (those before
+            the block are ``before_the_block``'s)."""
+            for of_rows, start, n, ref, mine in pieces(k):
+                if not of_rows:
+                    win[pl.ds(at, count), pl.ds(start % lanes, n)] = (
+                        ref[pl.ds(0, count), mine])
+                    continue
+                holder, row = divmod(start, tile)
+                if start >= 0 and row < count:
+                    rows.setdefault(holder, []).append(
+                        (win, pl.ds(at + row, n), ref, mine))
+
+        def in_their_tile(rows, held):
+            """The slabs' rows over the arrays' rows, after their
+            columns: ``rows`` by the tile that holds them, each
+            ``(to, its rows, slab, the slab's rows)``; ``held``: the
+            tile in VMEM.  One branch a tile, whatever the arrays
+            (a branch an array a slab a place were 21 in the kernel of
+            a walk beside neighbours, traced and lowered in every run
+            of a process; these are 9)."""
+            for holder, stores in rows.items():
+                @pl.when(eq(held, holder))
+                def _(stores=stores):
+                    for to, where, ref, mine in stores:
+                        to[where, :] = ref[mine, :]
 
         # a window's rows: the strip above tile i - 1, the tile, and the
         # strip below it, which is the first of the block just handed in
+        rows = {}
         for k, (ref, win) in enumerate(zip(taken, windows)):
             win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
-            place(win, tile + STRIP, STRIP, k)
+            place(win, tile + STRIP, STRIP, k, rows)
+        in_their_tile(rows, t)
+
+        # the pointwise arrays' slabs (a walk that reaches further): over
+        # the block of tile i - 1 where it was brought in, which the
+        # first stage is about to read; the lanes past the field's last
+        # column and the rows past its last row are the block's too
+        rows = {}
+        for k, ref in enumerate(old if ey or ex else ()):
+            for of_rows, start, n, slab, mine in pieces(n_fields + k):
+                if not of_rows:
+                    ref[:, pl.ds(start % lanes, n)] = slab[:, mine]
+                elif start >= 0:
+                    holder, row = divmod(start, tile)
+                    rows.setdefault(holder, []).append(
+                        (ref, pl.ds(row, n), slab, mine))
+        if rows:
+            in_their_tile(rows, sub(i, 1))
 
         def strip(k):
             """The rows of strip ``k`` of a block, a window or a ring."""
@@ -534,7 +690,7 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         lane = lax.broadcasted_iota(jnp.int32, (STRIP, LANES), 1)
         ghosts = {
             (lo, n): lax.bitwise_and(lax.ge(lane, lo), lax.lt(lane, lo + n))
-            for _, moves in (_ends_meet(width) if steps > 1 else ())
+            for _, moves in (_ends_meet(width) if steps > 1 and not ex else ())
             for _, _, lo, n in moves}
 
         def of(value, start):
@@ -551,7 +707,8 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
             ghost columns again, each once, the columns brought there by
             a rotation of the register that holds them."""
             ring[rows, :] = value
-            for home, moves in _ends_meet(width):
+            # (with a neighbour in x the step has computed them itself)
+            for home, moves in () if ex else _ends_meet(width):
                 register = of(value, home)
                 for source, shift, lo, n in moves:
                     register = select(
@@ -566,7 +723,9 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
                 # of its slot in a ring and, as far behind as the ring
                 # is long less its two spare strips, of the slots of a
                 # later stage's strip and of the two round it
-                k = add(mul(sub(i, 1), strips), j)
+                # (one on where the strip before the block's first has
+                # a slot of its own, the rings' first)
+                k = add(mul(sub(i, 1), strips), add(j, 1) if ey else j)
                 behind = functools.cache(
                     lambda slots, d: strip(lax.rem(add(k, d), slots)))
                 here, north, beyond = strip(j), strip(add(j, 1)), strip(add(j, 2))
@@ -616,6 +775,29 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
                 return carry
 
             lax.fori_loop(0, strips, run, 0)
+
+        def before_the_block():
+            """The strip before the block's first, which only a walk
+            that reaches ``ey`` rows further has: its last ``ey`` rows
+            are the southern slabs' first.  The first step's first
+            stage on it, for what the block's first row reads of the
+            row south of it: round 1's values on their way to round 2,
+            and what the stage hands the strip after."""
+            for k, ref in enumerate((*windows, *old_before)):
+                for of_rows, start, n, slab, mine in pieces(k):
+                    if of_rows and start < 0:
+                        ref[pl.ds(STRIP + start, n), :] = slab[mine, :]
+            first, _ = applied[0]
+            mine_kept = kept[:n_carried]
+            values = first(
+                sub(r, STRIP),
+                [northward(win, pl.ds(0, STRIP), pl.ds(STRIP, STRIP))
+                 for win in windows],
+                [ref[...] for ref in old_before], [ref[...] for ref in mine_kept])
+            for ring, value in zip(rings, values[n_plain:n_fields]):
+                ring[pl.ds(0, STRIP), :] = value
+            for ref, value in zip(mine_kept, values[n_fields + n_point:]):
+                ref[...] = value
 
         def sum_rows():
             """The sums over ``coarsen`` rows of the tiles that the last
@@ -681,6 +863,9 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         # until the last stage that writes blocks beside another has
         # written the field's last; the walk's last step is second's alone
         beside = n_stages - 1 - (n_second > 0)
+        if ey:
+            pl.when(eq(i, 1))(before_the_block)
+
         @pl.when(lax.bitwise_and(lax.gt(i, 0), lax.le(i, tiles + beside)))
         def _():
             strips_through(range(n_stages))
@@ -693,10 +878,12 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         if summed:
             pl.when(eq(on[0], 1))(sum_rows)
 
+        rows = {}
         for k, (ref, win) in enumerate(zip(taken, windows)):
             win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
             win[pl.ds(STRIP, tile), :] = ref[...]
-            place(win, STRIP, tile, k)
+            place(win, STRIP, tile, k, rows)
+        in_their_tile(rows, t)
 
     def block(lag):
         """Tile ``i - lag``, held to the field's own tiles."""
@@ -705,10 +892,17 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
 
     struct = union_vma_struct(fields[0].shape, dtype, *fields, *scalars)
     in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    # a tile's rows of a slab of columns; a slab of rows whole, once
-    block_of_slab = (
-        pl.BlockSpec((tile, G), lambda i, *_: (lax.min(i, tiles - 1), 0)),
-        pl.BlockSpec((G, lanes), lambda i, *_: (0, 0)))
+
+    def block_of_slab(of_rows, x, lag=0):
+        """A slab of rows whole, once; of a slab of columns the rows of
+        the tile handed in, or of the tile ``lag`` steps behind it (a
+        pointwise array's: its blocks are the tile being written)."""
+        if of_rows:
+            return pl.BlockSpec((x.shape[0], lanes), lambda i, *_: (0, 0))
+        return pl.BlockSpec(
+            (tile, x.shape[1]),
+            lambda i, *_: (lax.clamp(0, i - lag, tiles - 1) if lag
+                           else lax.min(i, tiles - 1), 0))
 
     def ring(slots):
         return pltpu.VMEM((slots * STRIP, lanes), dtype)
@@ -735,8 +929,9 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
     specs = dict(
         grid=(tiles + n_stages,),
         in_specs=([in_smem] * n_scalars + [block(0)] * n_fields
-                  + [block_of_slab[of_rows] for sides in came
-                     for of_rows, *_ in sides]
+                  + [block_of_slab(of_rows, x, lag=k >= n_fields)
+                     for k, sides in enumerate(came)
+                     for of_rows, _, x in sides]
                   + [block(1)] * n_point
                   + [pl.BlockSpec(memory_space=pl.ANY)] * n_summed),
         out_specs=([block(wrote_plain)] * n_plain + [block(wrote_last)] * n_second
@@ -748,7 +943,8 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
             + [ring(slots)] * n_second + [ring(1)] * n_kept
             + [ring(max(1, 2 * held))] * n_summed  # at `coarsen` 2 nobody's
             + ([ring(n) for n in field_slots] + [ring(point_slots)] * n_point
-               + [ring(slots)] * n_second if steps > 1 else [])))
+               + [ring(slots)] * n_second if steps > 1 else [])
+            + [ring(1)] * (n_point * bool(ey))))
     # whether the walk sums is known to the blocks' index maps: an
     # operand before the others, in SMEM before the grid starts
     ahead = [promote_vma(jnp.reshape(summing, (1,)).astype(jnp.int32), axes)] * bool(summed)
@@ -894,7 +1090,7 @@ def _ends_meet(width):
 
 @functools.lru_cache
 def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
-            coriolis_beta):
+            coriolis_beta, out=0):
     """The step's two stages for :func:`_walk`, ``(first, second)``, each
     taking before its own arguments the ``scalars`` a kernel reads from
     SMEM (:func:`wide_step` ``body``).  Jitted and kept: a process
@@ -902,7 +1098,11 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
     applications of a double one (a body's hundred and twenty
     operations are most of a kernel's trace); in a kernel's text they
     are inlined.  ``roll``: as
-    :func:`_walk` hands it to a body."""
+    :func:`_walk` hands it to a body.  ``out``: the rings of columns
+    further out than a single walk's that the stages update (the first
+    step's of a walk of two with a neighbour in x: ``h`` and round 2 on
+    rings 1 and 2, round 1 of ``u``, ``v`` on ring 3 too); the rows'
+    reach is the scalars'."""
     lanes = _whole_registers(width)  # of a strip in the kernel
     inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
     cx, cy = nu / dx, nu / dy
@@ -921,6 +1121,17 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
         rows = lax.bitwise_and(lax.ge(g, row_from), lax.lt(g, row_to))
         lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
         lo, hi = G - ring, width - G + ring
+        if lo < 0:
+            # the columns west of the block lie at a row's last lanes
+            def columns(at):
+                inside = lax.lt(lane, hi - at)
+                if at + LANES <= lanes + lo:
+                    return inside
+                return lax.bitwise_or(inside, lax.ge(lane, lanes + lo - at))
+
+            return lax.concatenate([
+                rows if at + LANES <= hi else lax.bitwise_and(rows, columns(at))
+                for at in range(0, lanes, LANES)], 1)
         return lax.concatenate([
             rows if lo <= at and at + LANES <= hi else functools.reduce(
                 lax.bitwise_and,
@@ -946,8 +1157,8 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
          inner_from, inner_to, reach_from, reach_to) = scalars
         (h, h_n), (u, u_n), (v, v_n) = fields
         zero = lax.full(h.shape, 0, dtype)
-        interior = box(g, inner_from, inner_to, 0)
-        reach = box(g, reach_from, reach_to, 1)
+        interior = box(g, inner_from, inner_to, out)
+        reach = box(g, reach_from, reach_to, 1 + out)
         # the array code builds its ring-1 fields on every row and
         # zeroes them on the walls' ghost rows (the northward flux
         # on the northern wall's own row too); an interior row reads
@@ -1028,7 +1239,7 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
     def second(scalars, g, fresh):
         """Round 2: lateral friction of round 1's ``u`` and ``v``."""
         _, _, _, south_ghost_row, north_wall_row, inner_from, inner_to, _, _ = scalars
-        interior = box(g, inner_from, inner_to, 0)
+        interior = box(g, inner_from, inner_to, out)
         zero = lax.full(interior.shape, 0, dtype)
         # of the rows the array code zeroes in the y gradient, an
         # interior cell reads one: the southern wall's ghost row
@@ -1073,8 +1284,10 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     holds for each the ``(west, east, south, north)`` that
     ``halo_slabs_2d(x, width=2)`` returned, which the kernel writes
     over the ghosts as it reads the rows (``None``: those ghosts are
-    fresh as they are).  ``dh``, ``du``, ``dv``: the old tendencies
-    **at the same padded shape** (a strip of a field and of an
+    fresh as they are); with ``steps`` 2 beside neighbours, of all six
+    arrays what ``halo_slabs_2d(depth=)`` returned, two cells deeper on
+    each axis that has a neighbour.  ``dh``, ``du``, ``dv``: the old
+    tendencies **at the same padded shape** (a strip of a field and of an
     interior-shaped tendency would lie two rows and two lanes apart),
     as the last step returned them: ring 1 of ``du`` and ``dv`` holds
     the neighbours' (the module's docstring says why), the rest of the
@@ -1086,38 +1299,48 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     once.  The caller has checked :func:`tile_rows` for six fields.
 
     ``steps`` 2: the call advances two time steps (both with ``a``,
-    ``b``) and returns, bit for bit on the whole padded blocks, what two
-    calls return with ``halo_slabs_2d`` between them **on a mesh of one
-    device**, which is the only place to ask for it: both walls this
-    device's (``is_south`` and ``is_north`` true), the x slabs the
-    block's own columns, no y slabs.  ``lone`` (traced, like the
+    ``b``) and returns what two calls return with ``halo_slabs_2d``
+    between them.  On an axis of one device from the ring's slabs as a
+    single walk takes them (in x the block's own columns, in y none:
+    on a mesh of one device bit for bit on the whole padded blocks);
+    on an axis with a neighbour from slabs ``2 G`` deep **of all six
+    arrays**, ``slabs[3:]`` the tendencies' (bit for bit on the
+    interior, and on the ghosts a next walk reads; the module's
+    docstring says what the others hold).  The caller has checked
+    :func:`holds_further`.  ``lone`` (traced, like the
     walls): the first of the two steps is passed over, its stages
     updating nothing and handing on zero tendencies, so that the call
     returns what a call of one step returns from zero tendencies, which
-    is what a run's first step is: a process on one device then builds
-    one kernel for its first step and the rest (the walk takes a double
-    walk's time, once a run).  The caller has checked :func:`tile_rows`
-    for six fields and two steps.
+    is what a run's first step is: a process then builds one kernel for
+    its first step and the rest (the walk takes a double walk's time,
+    once a run).
     """
     rows, width = h.shape
     dtype = h.dtype
     floats = jnp.stack([jnp.asarray(x, dtype) for x in (a, b, first_row)])
 
+    ey, ex = _further(slabs)
+
     def body(roll, flag_ref, float_ref):
-        first, second = _stages(
+        # a first step that reaches further, and a step as a single walk's
+        stages = [_stages(
             roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
-            coriolis_beta)
+            coriolis_beta, out) for out in (ex, 0)]
         a, b, first_row = float_ref[0], float_ref[1], float_ref[2]
         at_south, at_north = eq(flag_ref[0], 1), eq(flag_ref[1], 1)
 
-        def scalars(live):
+        def scalars(live, ey=0):
             """What the stages of one step read.  ``live``: a flag, or
             ``None`` for a step that always runs; where it is not set
             the step updates nothing: no row is a wall's, and the
-            interior and round 1's rows are empty."""
-            def row(x, where=None, otherwise=-1):
+            interior and round 1's rows are empty.  ``ey``: the rows
+            further out than a single walk's that the step updates
+            where no wall stands (``_stages`` has the columns)."""
+            def row(x, where=None, otherwise=-1 - STRIP if ey else -1):
                 """Row ``x`` as a scalar; ``otherwise`` (a row no strip
-                has) where ``where`` or ``live`` is given and not set."""
+                has: the strip before the block's first is one, where
+                the step reaches further) where ``where`` or ``live`` is
+                given and not set."""
                 flags = [flag for flag in (where, live) if flag is not None]
                 if not flags:
                     return jnp.int32(x)
@@ -1135,19 +1358,28 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
             # rows has four interior rows or more, so a neighbour's edge
             # row is never its wall row too.)
             inner_from, inner_to = row(G, otherwise=0), row(rows - G, otherwise=0)
-            reach_from = select(at_south, inner_from, row(G - 1, otherwise=0))
-            reach_to = select(at_north, inner_to, row(rows - G + 1, otherwise=0))
+            reach_from = select(at_south, inner_from, row(G - 1 - ey, otherwise=0))
+            reach_to = select(at_north, inner_to, row(rows - G + 1 + ey, otherwise=0))
+            if ey:
+                # h, and round 2 of u and v: on the two rings that the
+                # walk's second step reads, where a neighbour holds them
+                inner_from = select(at_south, inner_from, row(G - ey, otherwise=0))
+                inner_to = select(at_north, inner_to, row(rows - G + ey, otherwise=0))
             return (a, b, first_row, south_ghost_row, north_wall_row,
                     inner_from, inner_to, reach_from, reach_to)
 
-        # of a walk of two steps the first is passed over where `lone`
+        # of a walk of two steps the first is passed over where `lone`,
+        # and reaches as much further as the slabs do
         lives = [eq(flag_ref[2], 0)] * (steps - 1) + [None]
+        reaches = [ey] * (steps - 1) + [0]
         return [(functools.partial(first, x), functools.partial(second, x))
-                for x in map(scalars, lives)]
+                for (first, second), x in zip(
+                    stages[2 - steps:], map(scalars, lives, reaches))]
 
     flags = [is_south, is_north] + [lone] * (steps == 2)
     return _walk(body, [jnp.stack(flags).astype(jnp.int32), floats], [h, u, v],
-                 slabs, [dh, du, dv], n_second=2 if nu > 0 else 0,
+                 slabs[:3], [dh, du, dv], n_second=2 if nu > 0 else 0,
                  n_carried=4, steps=steps,
                  summed=(0, 1, 2) if coarsen else (), coarsen=coarsen,
-                 summing=summing, sums=sums, interpret=interpret)
+                 summing=summing, sums=sums, point_slabs=slabs[3:],
+                 interpret=interpret)

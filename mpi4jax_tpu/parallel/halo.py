@@ -130,19 +130,23 @@ def halo_exchange_2d_batch(arrs, comm, *, periodic=(False, True), token=None,
     )
 
 
-def _shifts(width, periodic):
+def _shifts(width, periodic, depth=None):
     """The exchange's four shifts in order, as ``(axis, disp, per, sent,
     received)``: whether the axis wraps, the region a block sends and
     the ghost region its neighbour's lands in (west, east, south,
     north).  x first, full height; then y, full width, so that corners
-    fill transitively."""
+    fill transitively.  ``depth`` (y, x), each the ring's ``width``
+    unless given: the interior columns and rows next to an edge that a
+    block sends, which may be more than its ring holds
+    (:func:`halo_slabs_2d`)."""
     w = width
+    dy, dx = depth or (w, w)
     per_y, per_x = periodic
     return (
-        ("x", +1, per_x, np.s_[:, -2 * w : -w], np.s_[:, :w]),
-        ("x", -1, per_x, np.s_[:, w : 2 * w], np.s_[:, -w:]),
-        ("y", +1, per_y, np.s_[-2 * w : -w, :], np.s_[:w, :]),
-        ("y", -1, per_y, np.s_[w : 2 * w, :], np.s_[-w:, :]),
+        ("x", +1, per_x, np.s_[:, -(w + dx) : -w], np.s_[:, :w]),
+        ("x", -1, per_x, np.s_[:, w : w + dx], np.s_[:, -w:]),
+        ("y", +1, per_y, np.s_[-(w + dy) : -w, :], np.s_[:w, :]),
+        ("y", -1, per_y, np.s_[w : w + dy, :], np.s_[-w:, :]),
     )
 
 
@@ -195,7 +199,17 @@ def _pack(arrs, sent, received):
         return [a[sent] for a in arrs], [a[received] for a in arrs]
 
 
-def _received(arrs, comm, *, periodic, token, width, stack):
+def _beyond(ghosts, axis, disp, extra):
+    """A block's own ghost columns or rows as the template of a slab
+    ``extra`` deeper than its ring: zeros where the block holds
+    nothing, on the side away from the interior (``disp`` +1: the slab
+    lands west or south of the block)."""
+    pads = [(0, 0, 0)] * ghosts.ndim
+    pads[axis] = (extra, 0, 0) if disp > 0 else (0, extra, 0)
+    return lax.pad(ghosts, jnp.zeros((), ghosts.dtype), pads)
+
+
+def _received(arrs, comm, *, periodic, token, width, stack, depth=None):
     """The four shifts of every array with no ghost written between
     them: ``(arrs, slabs, token)``, ``slabs[k][i]`` what array ``i``
     receives from shift ``k`` of :func:`_shifts` (``None`` where that
@@ -204,12 +218,15 @@ def _received(arrs, comm, *, periodic, token, width, stack):
     ``width`` ends taken from the x slabs just received, patched on a
     slab of a few rows.  ``arrs`` are the blocks as the slabs were cut
     from them: on the mesh tier held row-major (:func:`_row_major`), for
-    a caller that goes on to write into them."""
+    a caller that goes on to write into them.  ``depth``: as
+    :func:`_shifts` takes it; a slab deeper than the ring is
+    :func:`halo_slabs_2d`'s."""
     if comm.backend == "mesh":
         with jax.named_scope(PACK):
             arrs = [_row_major(a) for a in arrs]
     token = as_token(token)
     w = width
+    dy, dx = depth or (w, w)
     slabs = []
 
     def rows(i, region):
@@ -225,7 +242,7 @@ def _received(arrs, comm, *, periodic, token, width, stack):
                 slab[:, -w:] if east is None else east[region],
             ], axis=1)
 
-    for axis, disp, per, sent, received in _shifts(w, periodic):
+    for axis, disp, per, sent, received in _shifts(w, periodic, depth):
         if not comm.sub(axis).shift_perm(axis, disp, periodic=per):
             # a no-op on the whole axis: nothing to pack
             slabs.append([None] * len(arrs))
@@ -236,6 +253,12 @@ def _received(arrs, comm, *, periodic, token, width, stack):
             parts = tuple(
                 [rows(i, region) for i in range(len(arrs))]
                 for region in (sent, received))
+        extra = (dx if axis == "x" else dy) - w
+        if extra:
+            # the templates' ghosts are the block's, its ring deep
+            with jax.named_scope(PACK):
+                parts = parts[0], [
+                    _beyond(x, int(axis == "x"), disp, extra) for x in parts[1]]
         got, token = _shift(
             *parts, comm, axis, disp, per, token, stack=stack)
         slabs.append(got)
@@ -346,7 +369,8 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
 
 
 @publishes_token
-def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
+def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1,
+                  depth=None):
     """:func:`halo_exchange_2d` without its last phase: the four slabs
     a block receives, for a caller that places them itself (a kernel
     that reads and writes the block's every tile anyway:
@@ -367,8 +391,29 @@ def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1):
     row-major where the slabs are cut from it (:func:`_row_major`), the
     layout a kernel takes it in: the caller's program transposes the
     sent slabs, a few rows or columns each, and never the block.
+
+    ``arr`` may be a list of same-shaped blocks: the slabs of each, in a
+    list, every array's x slabs over the wire before any y slab.
+
+    ``depth`` (y, x): slabs cut **deeper than the block's ring**, for a
+    caller that computes further out than its block holds (a walk of two
+    time steps from one exchange): a block padded by ``width`` sends the
+    ``depth`` interior columns and rows next to each edge, and the slabs
+    are what an exchange ``depth`` deep would write round a block padded
+    that much.  West holds the columns from ``depth[1] - width`` left of
+    the block to its ring's last, over the block's rows; south the rows
+    from ``depth[0] - width`` below the block likewise, as wide as the
+    block **and its deeper x slabs**, whose rows are its ends, so that
+    corners fill transitively, ``depth`` deep.  A device with no
+    neighbour on a side gets its own ghosts back with zeros beyond them.
+    With ``depth`` equal to ``width`` on both axes (or not given) the
+    traced program is the same, equation for equation.
     """
+    several = isinstance(arr, (list, tuple))
     _, slabs, token = _received(
-        [arr], comm, periodic=periodic, token=token, width=width, stack=False,
+        list(arr) if several else [arr], comm, periodic=periodic, token=token,
+        width=width, stack=False, depth=depth and tuple(depth),
     )
+    if several:
+        return [tuple(got[i] for got in slabs) for i in range(len(arr))], token
     return tuple(got for got, in slabs), token

@@ -246,3 +246,110 @@ def test_an_exchange_is_one_op_and_four_sendrecvs(form, op):
     assert not findings
     ops = [o.op for o in occurrences]
     assert set(ops) == {op, "sendrecv"} and ops.count("sendrecv") == 4
+
+
+# -- slabs cut deeper than the block's ring ---------------------------------
+
+
+def _deep(arr, comm, periodic, width, depth):
+    """The four slabs ``depth`` deep of a block padded by ``width``."""
+    return halo_slabs_2d(arr, comm, periodic=periodic, width=width, depth=depth)[0]
+
+
+@pytest.mark.parametrize("width, deep", [(2, 4), (1, 2), (1, 3)])
+@pytest.mark.parametrize(
+    "periodic", [(False, True), (True, True)], ids=["walls", "torus"])
+@pytest.mark.parametrize(
+    "mesh_shape", [(2, 2), (2, 1), (1, 2), (2, 4)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_deep_slabs_are_the_ghosts_of_an_exchange_that_deep(
+        mesh_shape, periodic, width, deep):
+    """A block padded by ``width`` sends its ``deep`` interior columns
+    and rows next to each edge: the slabs are, bit for bit and corners
+    included, what ``halo_exchange_2d(width=deep)`` writes round the
+    same block padded that much, zeros where the block holds nothing; a
+    device beyond whose edge nobody is keeps its own ghosts there."""
+    comm = _comm(mesh_shape)
+    e = deep - width
+
+    def slabs(arr, comm, periodic, width):
+        got = _deep(arr, comm, periodic, width, (deep, deep))
+        want = halo_exchange_2d(
+            jnp.pad(arr, e), comm, periodic=periodic, width=deep)[0]
+        # (the x slabs over the interior's rows: the y slabs, which hold
+        # the corners, are written over their ends)
+        got = [x if x is None or k > 1 else x[width:-width]
+               for k, x in enumerate(got)]
+        want = (want[deep:-deep, :deep], want[deep:-deep, -deep:],
+                want[:deep], want[-deep:])
+        assert all(a.shape == b.shape for a, b in zip(got, want) if a is not None)
+        # a slab is None exactly where the exchange moved nothing
+        return jnp.stack([
+            jnp.zeros(()) if a is None else jnp.abs(a - b).max()
+            for a, b in zip(got, want)])
+
+    program, starts = _program(comm, width, periodic, slabs)
+    assert not np.asarray(jax.jit(program)(starts)).any()
+
+
+@pytest.mark.parametrize("depth", [(4, 4), (4, 2), (2, 4)], ids=str)
+@pytest.mark.parametrize(
+    "mesh_shape", [(2, 2), (2, 4)], ids=lambda s: "x".join(map(str, s)))
+def test_deep_slabs_of_several_arrays_are_slices_of_the_global_arrays(
+        mesh_shape, depth):
+    """On a torus every cell of a deep slab that is a neighbour's is a
+    cell of the global array: the x slabs over the block's interior rows
+    (their ghost rows are the block's own, stale), the y slabs wholly,
+    with the corners that came by way of the x slabs, each axis as deep
+    as it was asked for, for each array of a list."""
+    comm = _comm(mesh_shape)
+    py, px = mesh_shape
+    w, (dy, dx) = 2, depth
+    whole = [k + np.arange(py * NY * px * NX, dtype=np.float32).reshape(
+        py * NY, px * NX) for k in (0.0, 0.5)]
+
+    def local(*arrs):
+        blocks = [jnp.pad(a, w, constant_values=-1.0) for a in arrs]
+        got, _ = halo_slabs_2d(
+            blocks, comm, periodic=(True, True), width=w, depth=depth)
+        return tuple(got)
+
+    spec = jax.P("y", "x")
+    got = jax.jit(jax.shard_map(
+        local, mesh=comm.mesh, in_specs=(spec,) * 2,
+        out_specs=((spec,) * 4,) * 2))(*map(jnp.asarray, whole))
+    def take(a, rows, cols):
+        return a[np.ix_(rows % (py * NY), cols % (px * NX))]
+
+    def of_device(x, iy, ix):
+        ny, nx = x.shape[0] // py, x.shape[1] // px
+        return x[iy * ny:(iy + 1) * ny, ix * nx:(ix + 1) * nx]
+
+    for a, slabs in zip(whole, got):
+        for (iy, ix), _ in np.ndenumerate(np.empty(mesh_shape)):
+            west, east, south, north = (
+                of_device(np.asarray(x), iy, ix) for x in slabs)
+            rows = np.arange(iy * NY, (iy + 1) * NY)
+            cols = np.arange(ix * NX - dx, (ix + 1) * NX + dx)
+            np.testing.assert_array_equal(west[w:-w], take(a, rows, cols[:dx]))
+            np.testing.assert_array_equal(east[w:-w], take(a, rows, cols[-dx:]))
+            np.testing.assert_array_equal(
+                south, take(a, rows[0] - dy + np.arange(dy), cols))
+            np.testing.assert_array_equal(
+                north, take(a, rows[-1] + 1 + np.arange(dy), cols))
+
+
+@pytest.mark.parametrize(
+    "mesh_shape", [(1, 1), (2, 2)], ids=lambda s: "x".join(map(str, s)))
+def test_depth_equal_to_width_traces_the_exchange_as_it_was(mesh_shape):
+    """``depth`` equal to ``width`` on both axes is not another program:
+    equation for equation the jaxpr of the slabs without it."""
+    comm = _comm(mesh_shape)
+    texts = []
+    for depth in (None, (2, 2)):
+        def slabs(arr, comm, periodic, width, depth=depth):
+            got = _deep(arr, comm, periodic, width, depth)
+            return jnp.stack([x.sum() for x in got if x is not None])
+        program, starts = _program(comm, 2, (False, True), slabs)
+        texts.append(str(jax.make_jaxpr(program)(starts)))
+    assert texts[0] == texts[1]

@@ -193,6 +193,9 @@ def test_where_the_step_is_the_kernel_a_snapshot_is_finished_from_its_row_sums(
     assert [step for step, _ in got] == STEPS
     stats = job.stats()
     assert stats["snapshots_summed_in_step"] == stats["snapshots_produced"] == CALLS
+    # a walk of the kernel is two steps, beside neighbours too: the
+    # sums ride in the last of a call's five
+    assert stats["steps_per_walk"] == 2
     assert len(_named(job, "job/enqueue", program="snap", handed="row_sums")) == CALLS
     for (_, snapshot), (_, array_code) in zip(got, on_one_device(2)):
         for k in FIELDS:
@@ -238,6 +241,7 @@ def test_the_array_code_hands_its_snapshot_program_the_fields(seeded, ghost, coa
         sw.make_snapshot(cfg, job.comm, job.snapshot, from_sums=True)
     stats = job.stats()
     assert stats["snapshots_produced"] == 2 and stats["snapshots_summed_in_step"] == 0
+    assert stats["steps_per_walk"] == 1
     assert len(_named(job, "job/enqueue", program="snap", handed="fields")) == 2
     assert not _named(job, "job/enqueue", program="snap", handed="row_sums")
 

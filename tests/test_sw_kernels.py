@@ -296,9 +296,13 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     the step forced through the kernel (interpreted) against the array
     path, after 1 + 10 steps: walls and the Coriolis parameter's rows on
     the right devices, ring 1 recomputed where the array path exchanges
-    a second time, the first step and the rest through one kernel.  On
-    one device a walk of the kernel is two steps (the first step's with
-    one passed over), and an odd count's last step a walk of one."""
+    a second time, the first step and the rest through one kernel.  A
+    walk of the kernel is two steps (the first step's with one passed
+    over), on one device and, through ``halo_slabs_2d``'s slabs four
+    deep of all six arrays, beside neighbours on either axis or both
+    (PR 53; 44 rows leave a block of a mesh two devices high two rows
+    to spare in its last tile); an odd count's last step is a walk of
+    one."""
     mesh = jax.make_mesh(
         mesh_shape, ("y", "x"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -308,9 +312,9 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     # these steps (the published coefficients: 1e-4), and h stays
     # positive; a beta plane on which the rotation doubles from wall to
     # wall, so that a device that took another's rows would show
-    cfg = sw.SWConfig(ny=40, nx=48, ghost=G, coriolis_f=2e-2, depth=1e3,
+    cfg = sw.SWConfig(ny=44, nx=48, ghost=G, coriolis_f=2e-2, depth=1e3,
                       coriolis_beta=1e-7)
-    ny_l, nx_l = 40 // py, 48 // px
+    ny_l, nx_l = 44 // py, 48 // px
     block = (ny_l + 2 * G, nx_l + 2 * G)
 
     def run():
@@ -325,10 +329,10 @@ def test_multistep_through_the_kernels_matches_the_array_path(
 
     def interiors(x):
         """A global array of padded blocks without their ghost rings."""
-        return blocks(x)[..., G:-G, G:-G].transpose(0, 2, 1, 3).reshape(40, 48)
+        return blocks(x)[..., G:-G, G:-G].transpose(0, 2, 1, 3).reshape(44, 48)
 
     want = run()
-    assert want.dh.shape == (40, 48)
+    assert want.dh.shape == (44, 48)
     state = sw.make_init(cfg, comm)()
     assert _exchanges_a_step(sw.make_multistep(cfg, comm, 1), state) == 5
     calls, walks = [], []
@@ -356,13 +360,13 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     # the step is built once in each of the two programs, on one
     # device's block; Pallas is asked for where each program is built,
     # and once more where the kernel is traced: the second program
-    # reuses the first's trace.  On one device both are the kernel that
-    # walks two steps (the first step's with its first passed over),
-    # and only an odd count's last step is a walk of one, another
-    # kernel and another trace
-    alone = mesh_shape == (1, 1)
-    odd = alone and num_steps % 2
-    assert walks == [2, 2] + [1] * odd if alone else walks == [1, 1]
+    # reuses the first's trace.  Both are the kernel that walks two
+    # steps (the first step's with its first passed over), and only an
+    # odd count's last step is a walk of one, another kernel and
+    # another trace
+    assert sw._walks_two_steps(cfg, comm)
+    odd = num_steps % 2
+    assert walks == [2, 2] + [1] * odd
     assert calls == [block] * len(walks)
     assert len(imports) == 3 + odd
     # three exchanges a step where the array path has five
@@ -373,7 +377,7 @@ def test_multistep_through_the_kernels_matches_the_array_path(
     # who builds a state of their own hands them in (the benchmark)
     assert got.dh.shape == got.h.shape
     bare = sw.SWState(
-        *sw.make_init(cfg, comm)()[:3], *(jnp.zeros((40, 48)),) * 3)
+        *sw.make_init(cfg, comm)()[:3], *(jnp.zeros((44, 48)),) * 3)
     np.testing.assert_array_equal(
         sw.make_first_step(cfg, comm)(bare).dv,
         sw.make_first_step(cfg, comm)(sw.make_init(cfg, comm)()).dv)
@@ -529,14 +533,22 @@ def test_the_step_picks_the_kernels_from_platform_dtype_and_shape(
     ("tpu", (1, 1), 7204, 14404, True),
     ("tpu", (1, 1), 184, 364, True),
     # a neighbour on either axis: the second step's ghosts are its
-    ("tpu", (2, 1), 7204, 14404, False),
-    ("tpu", (1, 2), 7204, 14404, False),
-    ("tpu", (2, 2), 1804, 3604, False),
+    # first step's results, which the walk computes from deeper slabs
+    ("tpu", (2, 1), 7204, 14404, True),
+    ("tpu", (1, 2), 7204, 14404, True),
+    ("tpu", (2, 2), 7204, 14404, True),
+    ("tpu", (2, 2), 1804, 3604, True),
+    # no lanes past a row's last column for the two columns more
+    ("tpu", (1, 2), 7204, 14336, False),
+    ("tpu", (2, 1), 7204, 14336, True),    # none needed
+    # no rows past the field's last in its last tile (300 tiles of 24)
+    ("tpu", (2, 1), 7200, 14404, False),
+    ("tpu", (1, 2), 7200, 14404, True),
     ("cpu", (1, 1), 7204, 14404, False),   # no kernel, no walk
     ("tpu", (1, 1), 7204, 40_000, True),   # one strip a tile
     ("tpu", (1, 1), 7204, 100_000, False),  # not one
 ], ids=lambda x: str(x))
-def test_a_walk_takes_two_steps_on_a_mesh_of_one_device_alone(
+def test_a_walk_takes_two_steps_where_the_block_has_room_for_its_rings(
         platform, mesh_shape, rows, width, expected):
     py, px = mesh_shape
     cfg = sw.SWConfig(ny=(rows - 2 * G) * py, nx=(width - 2 * G) * px, ghost=G)

@@ -167,14 +167,13 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
     # through a second operand); the other two schedules are array code
     kernels = _kernels(text)
     assert sorted(kernels) == (["wide_step"] if ghost == 2 else [])
-    # one a step; on one chip a walk of the kernel is two steps, and the
-    # 25 are twelve walks of two in the loop and one of one after it
-    alone = py * px == 1
+    # a walk of the kernel is two steps, beside neighbours too (PR 53),
+    # and the 25 are twelve walks of two in the loop and one of one
+    # after it
     calls = _kernel_calls(text)
-    assert len(calls) == text.count("tpu_custom_call") == (
-        (ghost == 2) * (1 + alone))
+    assert len(calls) == text.count("tpu_custom_call") == (ghost == 2) * 2
     if kernels:
-        assert _trips(text) == (12 if alone else 25)
+        assert _trips(text) == 12
         # the walk of one step is another kernel than the walk of two
         assert len({body for _, _, body, _ in calls}) == len(calls)
     for line, fields, _, places in calls:
@@ -190,9 +189,9 @@ def test_solver_multistep_compiles_for_v5e(v5e, mesh_shape, ghost):
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
 def test_the_first_step_and_the_rest_hold_one_kernel_text(v5e, mesh_shape):
     """Forward Euler is the AB2 kernel with other scalars, so a process
-    traces and lowers the step's kernel once for its two programs.  On
-    one chip that kernel walks two steps, and the first step's call
-    passes the first of them over by a scalar."""
+    traces and lowers the step's kernel once for its two programs.
+    That kernel walks two steps, on one chip and on four, and the first
+    step's call passes the first of them over by a scalar."""
     texts = [_compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, steps).as_text()
              for steps in (0, 10)]
     first, rest = (_kernels(text) for text in texts)
@@ -226,8 +225,16 @@ def _scoped_vmem(line):
 # own after them; with the block held row-major no field lies there, the
 # call's room starts at 0, and its own is 5 226 496 more: two windows of
 # 88 rows of 3712 for each of those two fields, which it reads from HBM
-# again like the other four
-KERNEL_VMEM = {(1, 1): 58_507_264, (2, 2): 40_448_000}
+# again like the other four.  PR 53, (2, 2): the walk of two steps there
+# too, (1, 1)'s blocks, windows and rings and beside them the slabs of
+# six arrays for three, four deep for two (a slab of columns in blocks
+# of a tile's rows and a register's lanes, a slab of rows whole, both
+# double-buffered) and three strips for the tendencies of the strip
+# before the block's first; at this size XLA keeps `h` in that memory
+# across the call again (34 496 512 before the call's own room: the
+# field's 26 MB and the slabs'), as it did before PR 52, now that six
+# arrays' packing and permutes stand between the loop's top and the call
+KERNEL_VMEM = {(1, 1): 58_507_264, (2, 2): 90_509_312}
 
 
 @pytest.mark.parametrize("mesh_shape", sorted(KERNEL_VMEM))
@@ -298,8 +305,14 @@ def _moves_a_field(text, field):
 # faster memory, four `slice-start` and `slice-done` and the
 # `ConcatBitcast` that put a field together again); since PR 52 those
 # 22 are gone, and each of the six sent column slabs is transposed by
-# itself for its permute, as the six received ones were and are
-STEP_INSTRUCTIONS = {(1, 1): 5, (2, 2): 83}
+# itself for its permute, as the six received ones were and are.  PR 53:
+# a walk of two steps there too, from slabs of six arrays four deep, 83
+# a step became 219 for two (twice the slabs of twice the arrays, each
+# row slab laid out along a block's lanes for the kernel, and the seven
+# instructions with which XLA keeps `h` in its faster memory across the
+# call at this size: test_the_cells_step_copies_no_block has the size
+# at which no field fits there)
+STEP_INSTRUCTIONS = {(1, 1): 5, (2, 2): 219}
 
 
 @pytest.mark.parametrize("mesh_shape", sorted(STEP_INSTRUCTIONS))
@@ -320,17 +333,22 @@ def test_the_step_writes_no_ghost_outside_its_kernel(v5e, mesh_shape):
     # no copy of a field on any mesh: XLA wants the permutes' column
     # slabs lane-dense, and the block they are sliced from is held
     # row-major (parallel/halo.py _row_major), so it transposes the
-    # slabs and not the block (PERF.md section 6, PR 52)
-    assert not _moves_a_field(text, "f32[1804,3604]")
+    # slabs and not the block (PERF.md section 6, PR 52); on four chips
+    # a field of this size goes to XLA's faster memory and back round
+    # the call, which is no transpose and no `copy` (`_copied` below)
+    moved = _moves_a_field(text, "f32[1804,3604]")
+    assert not [name for name in moved if not name.startswith(
+        ("copy-start", "copy-done") if chips > 1 else ())], moved
     calls = [line for *_, line in body if "tpu_custom_call" in line]
-    # one a step; on one chip one for two steps, five times for ten
+    # one for two steps, five times for ten
     assert len(calls) == 1
-    assert _trips(text) == (5 if chips == 1 else 10)
+    assert _trips(text) == 5
     (line, fields, _, places), = _kernels(text).values()
     assert line == calls[0]
     # the state's arrays stand round the slabs: two a field on one chip
-    # (the y shifts move nothing), four on four
-    slabs = 3 * (2 if chips == 1 else 4)
+    # (the y shifts move nothing), four an array on four, the
+    # tendencies' too
+    slabs = 3 * 2 if chips == 1 else 6 * 4
     assert places == [2, 3, 4, *(5 + slabs + k for k in range(3))]
     assert _aliased_in_place(line, places)
     assert len(fields) == 6 and not _copied(text, fields), fields
@@ -348,22 +366,27 @@ def test_the_cells_step_copies_no_block(v5e, mesh_shape):
     415 MB field to ``{0,1}`` a step on 2x2 until PR 52, a third of the
     step's device time), the program's temporaries are the slabs and
     not a field, the six arrays of the state are the kernel call's own
-    operands, aliased to its results, and the wire is what it was: on
-    four chips twelve permutes a step, two column slabs and two row
-    slabs a field, on one none."""
+    operands, aliased to its results, and the wire is counted a call of
+    the kernel, which is two steps (PR 53): on four chips 24 permutes,
+    on one none."""
     chips = mesh_shape[0] * mesh_shape[1]
     compiled = _compiled_multistep(v5e, mesh_shape, 2, 7200, 14400, 10)
     text = compiled.as_text()
     assert not _moves_a_field(text, "f32[7204,14404]")
     body, types = _step_body(text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    # (a slab of four columns takes a lane tile a row: 3.7 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2**20 if chips == 1 else 10 * 2**20)
     (line, fields, _, places), = _kernel_calls(text)
     assert _aliased_in_place(line, places)
     assert len(fields) == 6 and not _copied(text, fields), fields
-    assert _trips(text) == (5 if chips == 1 else 10)
+    assert _trips(text) == 5
+    # a walk of two steps: on four chips 24 permutes a walk, two column
+    # slabs and two row slabs of each of the state's six arrays, four
+    # deep, the row slabs as wide as the block and its deeper x slabs
     sent = sorted(types[name].split("{")[0] for name, opcode, *_ in body
                   if opcode == "collective-permute-start")
-    assert sent == (["(f32[2,14404]"] * 6 + ["(f32[7204,2]"] * 6) * (chips > 1)
+    assert sent == (["(f32[4,14408]"] * 12 + ["(f32[7204,4]"] * 12) * (chips > 1)
     assert text.count(" collective-permute-start(") == len(sent)
 
 
@@ -704,8 +727,9 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
     assert [(a.shape, a.dtype) for a in job.first.lower(state).out_info[1]] == [
         (a.shape, a.dtype) for a in room]
     assert in_loop[2] == last[2] == first[2] != without
-    # the loop runs every walk but the call's last
-    walks = 5 if py * px == 1 else 10
+    # the loop runs every walk but the call's last; a walk is two
+    # steps, on four chips too
+    walks = 5
     assert _trips(text) == walks - 1 and _trips(quiet) == walks
     for line, fields, _, _ in (in_loop, last):
         assert len(fields) == 6 and not _copied(text, fields), fields
@@ -735,11 +759,9 @@ def test_a_job_with_output_donates_and_its_snapshot_carries_its_scope(
     # what the compiler laid out in VMEM for the walk that can sum: over
     # the walk without, under the limit the walk is given
     used, limit = _scoped_vmem(last[0])
-    assert limit == sw_kernels._VMEM_LIMIT * sw_kernels._buffers(
-        2 if py * px == 1 else 1) // 5
+    assert limit == sw_kernels._VMEM_LIMIT * sw_kernels._buffers(2) // 5
     assert _scoped_vmem(_kernel_calls(quiet)[0][0])[0] < used < limit
-    if py * px == 1:
-        assert sw_kernels.tile_rows(7204, 14404, jnp.float32, 6, steps=2) == 24
+    assert sw_kernels.tile_rows(7204, 14404, jnp.float32, 6, steps=2) == 24
 
     snap = job.snap.lower(*room).compile()
     table = scopes.origins(snap.as_text())
