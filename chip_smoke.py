@@ -488,6 +488,59 @@ def solver_invariance_check(cfg, devices, *, steps_per_call=25,
     return out
 
 
+def solver_adjoint_check(cfg, devices, *, calls=2, steps_per_call=5, observe=2,
+                         tol=1e-4):
+    """The gradient of a small window on 2x2 against the gradient of the
+    same fields on 1x1 (``models/shallow_water.py make_gradient``): the
+    adjoint exchange's reversed permutes cross chips on the one side and
+    wrap onto the same block on the other.  The jet's own fields, and
+    observations a seeded way off what the window makes of them."""
+    import jax
+    import numpy as np
+
+    import mpi4jax_tpu as m
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    def gradient(mesh_shape, some, fields=None, obs=None):
+        mesh = jax.make_mesh(
+            mesh_shape, ("y", "x"), axis_types=_auto(2), devices=some
+        )
+        comm = m.MeshComm.from_mesh(mesh)
+        if fields is None:
+            state = sw.make_init(cfg, comm)()
+            fields = tuple(
+                _interior(getattr(state, k), cfg.ghost, mesh_shape)
+                for k in ("h", "u", "v"))
+            coarse = fields[0].reshape(
+                cfg.ny // observe, observe, cfg.nx // observe, observe
+            ).mean(axis=(1, 3))
+            noise = np.random.default_rng(54).normal(
+                size=(calls + 1, *coarse.shape))
+            obs = (coarse + 0.05 * noise).astype(np.float32)
+        out = sw.make_gradient(
+            cfg, comm, calls=calls, num_steps=steps_per_call, observe=observe
+        )(*fields, obs)
+        return fields, obs, [np.asarray(a) for a in out]
+
+    fields, obs, one = gradient((1, 1), devices[:1])
+    _, _, four = gradient((2, 2), devices[:4], fields, obs)
+    rel = {
+        k: float(np.linalg.norm(a - b) / np.linalg.norm(b))
+        for k, a, b in zip(("h", "u", "v"), four[1:], one[1:])
+    }
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost}: dJ/dh0, dJ/du0, "
+        f"dJ/dv0 of a window of {1 + calls * steps_per_call} steps, h "
+        f"observed over {observe}x{observe} cells: 2x2 with 1x1, relative "
+        f"L2 (tol {tol})",
+        "cost": [float(four[0][0, 0]), float(one[0][0, 0])],
+        "rel_l2": rel,
+    }
+    if not all(np.isfinite(a).all() for a in four) or max(rel.values()) > tol:
+        raise AssertionError(f"decomposition changes the gradient: {out}")
+    return out
+
+
 def _job(cfg, devices, mesh_shape, snapshot, steps_per_call=10, calls=4):
     """``make_init`` -> ``job.start`` -> ``calls`` calls through
     ``make_job``; returns the job and the snapshots its callback was
@@ -1321,6 +1374,14 @@ def _bench_cfg():
     return sw.SWConfig().bench_size()
 
 
+def _refined(cfg, refine):
+    """``cfg``'s domain cut into ``refine`` times the cells each way."""
+    from dataclasses import replace
+
+    return replace(cfg, ny=cfg.ny * refine, nx=cfg.nx * refine,
+                   dx=cfg.dx / refine, dy=cfg.dy / refine)
+
+
 def _large(check, devices):
     """``check`` on ``SIZES["large"]`` as ``run`` constructs it."""
     from benchmarks.transformer import SIZES
@@ -1392,6 +1453,19 @@ GROUPS = {
             ),
             "solver4.monitor": lambda: solver_monitor_check(
                 _bench_cfg(), _all(), mesh_shapes=((2, 2), (1, 1))
+            ),
+        }),
+        # a group of its own: `import chip_smoke;
+        # chip_smoke.child("solver4.adjoint")` is a four-chip call of two
+        # minutes where the whole of --chips 4 is six.  At the domain
+        # refined once, 1800x3600 cells a chip, the block at which the
+        # mesh's forward programs were held bit for bit on the chip
+        # (PR 53): at `_bench_cfg()`'s 900x1800 a chip the kernel path
+        # itself gives NaN or hangs in programs that do not donate
+        # their state (ROADMAP.md S28), the gradient's among them
+        "solver4.adjoint": (420, {
+            "solver4.adjoint": lambda: solver_adjoint_check(
+                _refined(_bench_cfg(), 2), _all()
             ),
         }),
         "ops4": (300, {"ops4": lambda: ops_check(_all()[:4])}),

@@ -23,6 +23,10 @@ Usage:
     # the run that watches itself: a monitor line after every chunk
     python examples/shallow_water.py --monitor
 
+    # the run that is differentiated: fit the initial fields to
+    # observations of a truth run by 5 steps of steepest descent
+    python examples/shallow_water.py --assimilate 5
+
 Every mode but --benchmark builds `SWConfig()` with its default
 `ghost=1`: upstream's layout, (ny+2, nx+2) arrays a device, and
 upstream's step as written, array code with one halo exchange after
@@ -42,6 +46,54 @@ import numpy as np
 
 # allow running straight from a checkout
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+
+
+def assimilate(cfg, comm, iterations, num_steps, calls=4, observe=2):
+    """The twin experiment of ``--assimilate``: returns the costs."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    interior = sw.make_snapshot(cfg, comm, sw.Snapshot(coarsen=1))
+    observed = sw.make_snapshot(
+        cfg, comm, sw.Snapshot(fields=("h",), coarsen=observe))
+    truth = interior(*sw.make_init(cfg, comm)()[:3])
+    # the truth run's h as the window's misfit sees it
+    state = sw.make_first_step(cfg, comm)(sw.make_state(cfg, comm)(*truth))
+    multi = sw.make_multistep(cfg, comm, num_steps)
+    obs = [observed(state.h)[0]]
+    for _ in range(calls):
+        state = multi(state)
+        obs.append(observed(state.h)[0])
+    obs = jnp.stack(obs)
+    # the first guess: the balanced jet without the demo's perturbation
+    y = (jnp.arange(cfg.ny, dtype=jnp.float32) * cfg.dy)[:, None]
+    x = (jnp.arange(cfg.nx, dtype=jnp.float32) * cfg.dx)[None, :]
+    bump = 0.2 * jnp.sin(x / cfg.length_x * 10 * jnp.pi) * jnp.cos(
+        y / cfg.length_y * 8 * jnp.pi)
+    guess = (truth[0] - bump.astype(truth[0].dtype), truth[1], truth[2])
+
+    fit = sw.Descent(cfg, comm, calls=calls, num_steps=num_steps, observe=observe)
+    # one step length for the run, under what the roughest direction
+    # of the cost allows
+    rate, _curvatures, _cost = fit.step_length(*guess, obs)
+    print(
+        f"assimilate: a window of {1 + calls * num_steps} steps, h observed "
+        f"over {observe}x{observe} cells {calls + 1} times; step length "
+        f"{rate:.4g}",
+        file=sys.stderr,
+    )
+    fit.start(*guess, obs, rate)
+    fit.iterate(iterations)
+    fit.wait()
+    costs = fit.costs()
+    for i, c in enumerate(costs):
+        print(f"iteration {i}: cost {c:.6g}")
+    final = float(fit.gradient(*fit.fields, obs)[0][0, 0])
+    print(f"after {iterations} steps: cost {final:.6g}")
+    jax.block_until_ready(fit.fields)
+    return costs + [final]
 
 
 def main(argv=None):
@@ -111,6 +163,19 @@ def main(argv=None):
         "and stops the run at most four chunks after the one that went "
         "bad, where a run without it is paid for to its end",
     )
+    p.add_argument(
+        "--assimilate",
+        type=int,
+        default=0,
+        metavar="N",
+        help="a twin experiment (Courtier and Talagrand 1990): observe h "
+        "of a truth run after the first step and after each of 4 chunks "
+        "of --multistep steps, start from the jet without its "
+        "perturbation, and take N steps of steepest descent on the "
+        "misfit, each along the gradient that make_gradient takes back "
+        "through every step and every halo exchange of the window; "
+        "prints the cost before each step",
+    )
     args = p.parse_args(argv)
 
     import jax
@@ -144,6 +209,9 @@ def main(argv=None):
         f"devices {n_dev}, dt {cfg.dt:.1f}s, {days} model days",
         file=sys.stderr,
     )
+
+    if args.assimilate:
+        return assimilate(cfg, comm, args.assimilate, args.multistep)
 
     gather = None
     if args.plot or args.animate:

@@ -65,6 +65,9 @@ __all__ = [
     "shallow_water_step",
     "make_multistep",
     "make_state",
+    "make_gradient",
+    "make_descent_step",
+    "Descent",
     "Snapshot",
     "Checkpoint",
     "Monitor",
@@ -779,67 +782,56 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
     cost nothing.  A job's programs ask for the one kernel in every
     step, so that a process builds one.
     """
-    G = 2
     if not cfg.periodic_x:
         raise NotImplementedError(
             "wide-halo schedule currently requires periodic_x=True "
             "(x-boundary clamps are not implemented); use ghost=1"
         )
     token = as_token(token)
-    per = (False, True)
-    ny_l, _nx_l = cfg.local_interior(comm)
-    is_north, is_south = _wall_masks(comm)
-    nu = cfg.lateral_viscosity
-
-    h, u, v, dh, du, dv = state
+    h, dh = state[0], state[3]
 
     if _runs_as_kernels(cfg, comm):
-        # --- the exchange without its ghost writes: the kernel, which
-        # reads and writes every tile that holds a ghost cell anyway,
-        # places the received slabs itself ---
         if not first_step and dh.shape != h.shape:
             raise ValueError(
                 f"tendencies of shape {dh.shape} beside fields of shape "
                 f"{h.shape}: where the step runs as a kernel its state "
                 "carries them padded, as make_init and make_first_step "
                 "return them")
-        # where the rest of a run walks two steps at a time, its first
-        # step goes through that kernel too, the first of the two passed
-        # over: one kernel a process, traced once
-        lone = first_step and _walks_two_steps(cfg, comm)
-        further = _reaches_further(comm) if lone or steps == 2 else (0, 0)
-        if any(further):
-            # a walk of two steps beside neighbours: their four columns
-            # and rows next to the block, of the fields and (the first
-            # step's update out there reads them) of the tendencies,
-            # of which a run's first step has none to send
-            slabs, token = halo_slabs_2d(
-                [h, u, v] if first_step else list(state), comm, periodic=per,
-                token=token, width=G, depth=tuple(G + n for n in further))
-            if first_step:
-                slabs += [jax.tree.map(jnp.zeros_like, slabs[0])] * 3
-            slabs = tuple(slabs)
-        else:
-            for_h, token = halo_slabs_2d(h, comm, periodic=per, token=token, width=G)
-            for_u, token = halo_slabs_2d(u, comm, periodic=per, token=token, width=G)
-            for_v, token = halo_slabs_2d(v, comm, periodic=per, token=token, width=G)
-            slabs = for_h, for_u, for_v
-        if first_step:
-            # forward Euler is AB2 with (1, 0) on zero tendencies, and the
-            # caller's, which this step does not read, may be of either shape
-            a, b = 1.0, 0.0
-            dh = du = dv = jnp.zeros_like(h)
-        else:
-            a, b = cfg.ab_a, cfg.ab_b
-        iy, _ix = _device_coords(comm)
-        *state, = sw_kernels.wide_step(
-            h, u, v, dh, du, dv, slabs, is_south, is_north,
-            iy * ny_l, a, b, lone, *([summing, tuple(sums)] if coarsen else []),
-            nu=nu, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
-            gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
-            coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps,
-            coarsen=coarsen)
-        return (SWState(*state[:6]), tuple(state[6:])), token
+        walk = partial(_kernel_walk, cfg=cfg, comm=comm, first_step=first_step,
+                       steps=steps, coarsen=coarsen)
+        if coarsen:  # a job's walk, with its sums: nobody's derivative
+            return walk(state, token, tuple(sums), summing)
+
+        def forward(state, token):
+            (state, _), token = walk(state, token, (), True)
+            return state, token
+
+        # the kernel has no transpose: the derivative of a walk is that
+        # of the array code of its steps, at the state the walk started
+        # from (docs/shallow-water.md, "The differentiated run")
+        state, token = _kept_at_its_start(
+            forward,
+            partial(_walk_as_arrays, cfg=cfg, comm=comm, first_step=first_step,
+                    steps=steps),
+            f"{ADJOINT_SCOPE}/{STEP_VJP}")(
+                # a run's first step reads no tendency: the caller's are
+                # no operand of the derivative, and a program nobody
+                # differentiates drops them as unused, as it did
+                SWState(*state[:3], *((None,) * 3 if first_step else state[3:])),
+                token)
+        return (state, ()), token
+
+    return _step_wide_arrays(state, cfg, comm, first_step, token)
+
+
+def _step_wide_arrays(state, cfg, comm, first_step, token):
+    """:func:`_step_wide` as array code: five exchanges and both rounds
+    (plain jax, which differentiates it by its own rules).  Returns
+    ``((state, ()), token)``."""
+    G = 2
+    per = (False, True)
+    is_north, is_south = _wall_masks(comm)
+    h, u, v, dh, du, dv = state
 
     # --- round 1: refresh prognostic ghosts (2-deep, corners valid) ---
     h, token = halo_exchange_2d(h, comm, periodic=per, token=token, width=G)
@@ -850,12 +842,123 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
         h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)
 
     # --- round 2: refresh u/v ghosts for the viscosity stencils ---
-    if nu > 0:
+    if cfg.lateral_viscosity > 0:
         u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
         u, v = _viscosity_round(u, v, cfg, is_south, is_north)
 
     return (SWState(h, u, v, dh, du, dv), ()), token
+
+
+def _kernel_walk(state, token, sums, summing, *, cfg, comm, first_step, steps,
+                 coarsen):
+    """:func:`_step_wide` where the step is the kernel: the exchange
+    without its ghost writes, then the kernel, which reads and writes
+    every tile that holds a ghost cell anyway and places the received
+    slabs itself.  Returns ``((state, sums), token)``."""
+    G = 2
+    per = (False, True)
+    ny_l, _nx_l = cfg.local_interior(comm)
+    is_north, is_south = _wall_masks(comm)
+    h, u, v, dh, du, dv = state
+    # where the rest of a run walks two steps at a time, its first
+    # step goes through that kernel too, the first of the two passed
+    # over: one kernel a process, traced once
+    lone = first_step and _walks_two_steps(cfg, comm)
+    further = _reaches_further(comm) if lone or steps == 2 else (0, 0)
+    if any(further):
+        # a walk of two steps beside neighbours: their four columns
+        # and rows next to the block, of the fields and (the first
+        # step's update out there reads them) of the tendencies,
+        # of which a run's first step has none to send
+        slabs, token = halo_slabs_2d(
+            [h, u, v] if first_step else list(state), comm, periodic=per,
+            token=token, width=G, depth=tuple(G + n for n in further))
+        if first_step:
+            slabs += [jax.tree.map(jnp.zeros_like, slabs[0])] * 3
+        slabs = tuple(slabs)
+    else:
+        for_h, token = halo_slabs_2d(h, comm, periodic=per, token=token, width=G)
+        for_u, token = halo_slabs_2d(u, comm, periodic=per, token=token, width=G)
+        for_v, token = halo_slabs_2d(v, comm, periodic=per, token=token, width=G)
+        slabs = for_h, for_u, for_v
+    if first_step:
+        # forward Euler is AB2 with (1, 0) on zero tendencies, and the
+        # caller's, which this step does not read, may be of either shape
+        a, b = 1.0, 0.0
+        dh = du = dv = jnp.zeros_like(h)
+    else:
+        a, b = cfg.ab_a, cfg.ab_b
+    iy, _ix = _device_coords(comm)
+    *state, = sw_kernels.wide_step(
+        h, u, v, dh, du, dv, slabs, is_south, is_north,
+        iy * ny_l, a, b, lone, *([summing, tuple(sums)] if coarsen else []),
+        nu=cfg.lateral_viscosity, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
+        gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
+        coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps,
+        coarsen=coarsen)
+    return (SWState(*state[:6]), tuple(state[6:])), token
+
+
+# The differentiated run's phases, jax.named_scope segments
+# ``sw/adjoint/<phase>`` (docs/observability.md): the window run forwards
+# (`forward`), a call's steps run again with every state kept
+# (`recompute`), one step's derivative (`step_vjp`: the array code of
+# the step run at a kept state and then backwards, its exchanges the
+# adjoint exchange of parallel/halo.py), the misfit (`cost`) and the
+# descent step (`update`).  In a backward sweep jax wraps what it
+# transposes in ``transpose(jvp(...))``: the innermost
+# ``sw/adjoint/<phase>`` of an ``op_name`` says whose an instruction is.
+ADJOINT_SCOPE = f"{STEP_SCOPE}/adjoint"
+FORWARD, RECOMPUTE, STEP_VJP, COST, UPDATE = (
+    "forward", "recompute", "step_vjp", "cost", "update")
+
+
+def _adjoint_scope(phase):
+    return jax.named_scope(f"{ADJOINT_SCOPE}/{phase}")
+
+
+def _kept_at_its_start(forward, twin, scope):
+    """``forward`` (operands -> results) as a function whose derivative
+    is ``twin``'s, taken where the call started: the one thing kept for
+    the backward pass is the call's operands, and the backward pass runs
+    ``twin`` there forwards and backwards, under ``scope``.  With
+    ``twin`` the function itself this is ``jax.checkpoint`` with a name
+    on its backward half; with another program of the same function (a
+    kernel's array code; a call's steps one by one where ``forward``
+    walks two at a time) it is how that function gets a derivative or a
+    cheaper one to keep."""
+    kept = jax.custom_vjp(forward)
+
+    def backward(operands, cotangents):
+        with jax.named_scope(scope):
+            _, vjp = jax.vjp(twin, *operands)
+            return vjp(cotangents)
+
+    kept.defvjp(lambda *operands: (forward(*operands), operands), backward)
+    return kept
+
+
+def _walk_as_arrays(state, token, *, cfg, comm, first_step, steps):
+    """What a walk of the step's kernel computes (:func:`_kernel_walk`:
+    ``steps`` steps, a first step always one) as the array code of
+    :func:`_step_wide_arrays` on the kernel's state, whose tendencies
+    are padded: the interior of each goes in, and comes back with a ring
+    of zeros.  The same function of a block's interior and of a wall's
+    ghost rows as the kernel's, to roundoff
+    (``tests/test_sw_kernels*.py``), which is what a derivative is
+    taken of; the ghost cells a kernel leaves behind (the neighbours'
+    tendencies on ring 1 of ``du``, ``dv``, the first step's results
+    beyond the block) are its own means to that end and carry none."""
+    G = 2
+    h = state[0]
+    state = SWState(*state[:3], *(
+        a if a is None or a.shape != h.shape else a[G:-G, G:-G]
+        for a in state[3:]))
+    for _ in range(1 if first_step else steps):
+        (state, _), token = _step_wide_arrays(
+            state, cfg, comm, first_step, token)
+    return SWState(*state[:3], *(jnp.pad(a, G) for a in state[3:])), token
 
 
 def _step_wide4(state, cfg, comm, *, first_step=False, token=None):
@@ -1022,6 +1125,44 @@ def _mesh_specs(comm):
     return SWState(*([spec] * 6))
 
 
+def _call_of_steps(state, sums=(), *, cfg, comm, num_steps, coarsen=0):
+    """One device's part of :func:`make_multistep`: ``num_steps`` steps
+    in one loop, two a walk where the kernel can
+    (:func:`_walks_two_steps`), an odd count's last a single step's
+    walk after the loop.  With ``coarsen`` (a job's, where the step
+    writes the snapshot's sums) the call's last walk stands apart and
+    writes them into ``sums``, and ``(state, sums)`` comes back; without
+    it the state does.  :func:`make_gradient`'s forward sweep runs its
+    calls through this too: what is differentiated is what is timed."""
+    # steps a walk of the kernel (``_step_wide``)
+    stride = 2 if _walks_two_steps(cfg, comm) else 1
+    # the walks of a call, the last one apart where it writes the sums
+    # (an odd count's is a single step's walk)
+    last = num_steps % stride or stride
+    looped = (num_steps - last * bool(coarsen)) // stride
+
+    def body(_, carry):
+        s, sums = carry
+        if coarsen:  # the kernel the last walk runs, its sums off
+            (s, sums), _tok = _step_wide(
+                s, cfg, comm, steps=stride, sums=sums, coarsen=coarsen,
+                summing=False)
+        elif stride == 1:
+            s, _tok = shallow_water_step(s, cfg, comm)
+        else:
+            (s, _), _tok = _step_wide(s, cfg, comm, steps=stride)
+        return s, sums
+
+    if looped:
+        state, sums = lax.fori_loop(0, looped, body, (state, sums))
+    if coarsen:
+        return _step_wide(
+            state, cfg, comm, steps=last, sums=sums, coarsen=coarsen)[0]
+    if num_steps % stride:
+        state, _tok = shallow_water_step(state, cfg, comm)
+    return state
+
+
 def make_multistep(cfg, comm, num_steps, *, donate=False, snapshot=None):
     """Jitted global function advancing the model ``num_steps`` steps —
     the reference's ``do_multistep`` (shallow_water.py:415-420): the whole
@@ -1048,35 +1189,11 @@ def make_multistep(cfg, comm, num_steps, *, donate=False, snapshot=None):
     ``snapshot`` is.
     """
 
-    # steps a walk of the kernel (``_step_wide``)
-    stride = 2 if _walks_two_steps(cfg, comm) else 1
     coarsen = snapshot.coarsen if _sums_in_step(cfg, comm, snapshot) else 0
-    # the walks of a call, the last one apart where it writes the sums
-    # (an odd count's is a single step's walk)
-    last = num_steps % stride or stride
-    looped = (num_steps - last * bool(coarsen)) // stride
 
     def local_fn(state, sums=()):
-        def body(_, carry):
-            s, sums = carry
-            if coarsen:  # the kernel the last walk runs, its sums off
-                (s, sums), _tok = _step_wide(
-                    s, cfg, comm, steps=stride, sums=sums, coarsen=coarsen,
-                    summing=False)
-            elif stride == 1:
-                s, _tok = shallow_water_step(s, cfg, comm)
-            else:
-                (s, _), _tok = _step_wide(s, cfg, comm, steps=stride)
-            return s, sums
-
-        if looped:
-            state, sums = lax.fori_loop(0, looped, body, (state, sums))
-        if coarsen:
-            return _step_wide(
-                state, cfg, comm, steps=last, sums=sums, coarsen=coarsen)[0]
-        if num_steps % stride:
-            state, _tok = shallow_water_step(state, cfg, comm)
-        return state
+        return _call_of_steps(state, sums, cfg=cfg, comm=comm,
+                              num_steps=num_steps, coarsen=coarsen)
 
     _kernels_ahead(cfg, comm)
     specs = _mesh_specs(comm)
@@ -1124,26 +1241,29 @@ def make_state(cfg, comm):
     nx + 2)`` a device.
     """
 
-    interior = cfg.local_interior(comm)
-
-    def local_fn(h, u, v):
-        G = cfg.ghost
-        for a in (h, u, v):
-            if a.shape != interior:
-                raise ValueError(
-                    f"a field of {a.shape} a device: the interior of a "
-                    f"{cfg.ny}x{cfg.nx} grid on a mesh of {comm.axis_sizes} "
-                    f"is {interior} a device, without ghost cells")
-        padded = (jnp.pad(a.astype(cfg.dtype), G, mode="edge")
-                  for a in (h, u, v))
-        state, _tok = _ghosted_state(*padded, cfg, comm, as_token(None))
-        return state
-
     spec = jax.P(*comm.axes)
     return jax.jit(
-        jax.shard_map(local_fn, mesh=comm.mesh, in_specs=(spec,) * 3,
+        jax.shard_map(partial(_state_of_fields, cfg=cfg, comm=comm),
+                      mesh=comm.mesh, in_specs=(spec,) * 3,
                       out_specs=_mesh_specs(comm))
     )
+
+
+def _state_of_fields(h, u, v, *, cfg, comm):
+    """One device's part of :func:`make_state`: its block of each field
+    padded, a wall's ghost rows its edge row's, the ring exchanged."""
+    G = cfg.ghost
+    interior = cfg.local_interior(comm)
+    for a in (h, u, v):
+        if a.shape != interior:
+            raise ValueError(
+                f"a field of {a.shape} a device: the interior of a "
+                f"{cfg.ny}x{cfg.nx} grid on a mesh of {comm.axis_sizes} "
+                f"is {interior} a device, without ghost cells")
+    padded = (jnp.pad(a.astype(cfg.dtype), G, mode="edge")
+              for a in (h, u, v))
+    state, _tok = _ghosted_state(*padded, cfg, comm, as_token(None))
+    return state
 
 
 def make_first_step(cfg, comm, snapshot=None):
@@ -1169,6 +1289,319 @@ def make_first_step(cfg, comm, snapshot=None):
     return jax.jit(jax.shard_map(
         local_fn, mesh=comm.mesh, in_specs=(specs,),
         out_specs=(specs, (jax.P(*comm.axes),) * 3) if coarsen else specs))
+
+
+class _Window(NamedTuple):
+    """One device's parts of a differentiated window
+    (:func:`make_gradient`), to be run and differentiated inside the
+    model's ``shard_map``, where the adjoint exchanges carry every other
+    device's share of a cotangent to the cells it came from."""
+
+    first: object  # (h0, u0, v0) -> the state after the first step
+    call: object  # state -> state, as make_multistep's loop runs it
+    step_by_step: object  # the same steps one by one, each state kept
+    misfit: object  # (h, y) -> this device's share of one term of J
+
+
+def _window(cfg, comm, num_steps, observe):
+    G = cfg.ghost
+
+    def one_step(state):
+        return shallow_water_step(state, cfg, comm)[0]
+
+    if not _runs_as_kernels(cfg, comm):
+        # array code: a step keeps its input and is run again backwards
+        # (a kernel's walk is kept so by _step_wide itself)
+        one_step = _kept_at_its_start(
+            one_step, one_step, f"{ADJOINT_SCOPE}/{STEP_VJP}")
+
+    def first(h0, u0, v0):
+        state = _state_of_fields(h0, u0, v0, cfg=cfg, comm=comm)
+        return shallow_water_step(state, cfg, comm, first_step=True)[0]
+
+    def step_by_step(state):
+        return SWState(*lax.scan(
+            lambda s, _: (one_step(s), None), state, None, length=num_steps)[0])
+
+    def call(state):
+        return SWState(*_call_of_steps(
+            state, cfg=cfg, comm=comm, num_steps=num_steps))
+
+    def misfit(h, y):
+        with _adjoint_scope(COST):
+            d = _observed(h, G, observe) - y
+            return 0.5 * jnp.sum(d * d)
+
+    return _Window(first, call, step_by_step, misfit)
+
+
+def _observed(block, ghost, coarsen):
+    """:func:`_block_mean` as an observation operator, with its
+    transpose written out: a coarse cell's cotangent, over ``coarsen``
+    squared, to each cell it is the mean of, and nothing to the ghost
+    ring.  jax's own rule for a window's sum gives the same as a
+    window's sum over the cotangent dilated, which the TPU compiler
+    makes one ``reduce-window`` with a base dilation; on a v5e that read
+    a gradient uncorrelated with the cost's slope at 3604 x 7204 and
+    the right one at 516 x 1028 (PERF.md, PR 54).  Broadcast, reshape
+    and pad are what every program here uses."""
+    c = coarsen
+
+    @jax.custom_vjp
+    def observe(block):
+        return _block_mean(block, ghost, c)
+
+    def transposed(_, coarse):
+        ny, nx = coarse.shape
+        cells = jnp.broadcast_to(
+            coarse[:, None, :, None], (ny, c, nx, c)).reshape(ny * c, nx * c)
+        # the division where the cells are written, not a pass before
+        return (jnp.pad(cells * jnp.asarray(1.0 / (c * c), cells.dtype), ghost),)
+
+    observe.defvjp(lambda block: (observe(block), None), transposed)
+    return observe(block)
+
+
+def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
+    """Global function ``(h0, u0, v0, obs) -> (J, dJ/dh0, dJ/du0,
+    dJ/dv0)``: the misfit of a window of the model to observations and
+    its gradient with respect to the window's initial fields, by one
+    forward and one backward sweep through the model's own steps.  The
+    adjoint of a variational assimilation (Courtier and Talagrand 1990
+    on these equations), of a parameter fit, of a solver inside a loss.
+
+    The window is ``1 + calls * num_steps`` steps from rest tendencies,
+    forward Euler first, as every driver's.  ``h0``, ``u0``, ``v0``:
+    the domain's interior cells, ``(cfg.ny, cfg.nx)`` each, as
+    :func:`make_state` takes them.  ``obs``: ``(calls + 1, cfg.ny //
+    observe, cfg.nx // observe)``, sharded over the mesh on its last two
+    axes: observations of ``h`` as means over ``observe x observe``
+    cells (``observe`` has to divide a device's block) after the first
+    step and after every call.  ``J = 1/2 sum_k sum_blocks (H(h_k) -
+    obs_k)^2``, summed over the mesh by ``allreduce`` and handed back as
+    every device's copy of it, one element a device (``J[0, 0]`` on the
+    host); the gradients come back interior-shaped and sharded as the
+    fields are.
+
+    Every exchange of every step is under the derivative, transposed to
+    the adjoint exchange (``parallel/halo.py``: the cotangents of ghost
+    cells sent back along the reversed permutes and added to the edges
+    they were copied from).  Where the step is the kernel
+    (:func:`_runs_as_kernels`) a step's derivative is that of its array
+    code at the step's input state.
+
+    Checkpointed at two levels, as two jitted programs a gradient (the
+    function's ``forward`` and ``backward``).  The forward sweep runs
+    the window's calls as :func:`make_multistep` runs them
+    (:func:`_call_of_steps`) and hands back the cost
+    and **the state each call starts from** (and the last state's
+    ``h``, for its misfit): the first level, arrays on the mesh between
+    the two programs.  The backward sweep takes the calls last to first:
+    it runs a call's steps again one by one, keeps all ``num_steps``
+    states of that call (the second level) while it takes the call's
+    steps backwards (each step's array code run once more at its kept
+    state, and transposed), then lets them go.  At any time ``calls +
+    num_steps`` states and one step's residuals are held, where keeping
+    everything holds every step's: ``Descent.stats()`` gives the bytes.
+    The values are those of plain ``jax.value_and_grad`` of the window
+    to rounding (``tests/test_sw_adjoint.py`` builds that program from
+    the same parts: the compiler fuses, and so rounds, each program in
+    its own way).
+
+    **On a mesh of TPU chips, take it at blocks of 1800 x 3600 cells a
+    chip or more** (there the 2x2 gradient is the 1x1 gradient on the
+    chip: ``chip_smoke.py`` ``solver4.adjoint``).  At 900 x 1800 a chip
+    the kernel path itself, forwards, has returned NaN or hung on a 2x2
+    mesh in programs that do not donate their state (``ROADMAP.md``
+    S28), and this function's two are such.
+    """
+    ny_l, nx_l = cfg.local_interior(comm)
+    if observe < 1 or ny_l % observe or nx_l % observe:
+        raise ValueError(
+            f"observe {observe} does not divide a device's block of "
+            f"{ny_l}x{nx_l} cells")
+    window = _window(cfg, comm, num_steps, observe)
+
+    observed = (calls + 1, ny_l // observe, nx_l // observe)
+
+    def checked(obs):
+        if obs.shape != observed:
+            raise ValueError(
+                f"observations of {obs.shape} a device where a window of "
+                f"{calls} calls observed over {observe}x{observe} cells "
+                f"takes {observed}")
+        return obs
+
+    def summed(mine):
+        with _adjoint_scope(COST):
+            total, _tok = allreduce(mine, reductions.SUM, comm=comm)
+        return total.reshape(1, 1)
+
+    def forward(h0, u0, v0, obs):
+        with _adjoint_scope(FORWARD):
+            state = window.first(h0, u0, v0)
+        mine, starts = window.misfit(state.h, obs[0]), []
+        for k in range(calls):
+            starts.append(state)
+            with _adjoint_scope(FORWARD):
+                state = window.call(state)
+            mine = mine + window.misfit(state.h, obs[k + 1])
+        return summed(mine), tuple(starts), state.h
+
+    def backward(h0, u0, v0, obs, starts, last_h):
+        def of_misfit(h, y, like):
+            """A term of J's cotangent, as a state's."""
+            return SWState(jax.grad(window.misfit)(h, y),
+                           *(jnp.zeros_like(a) for a in like[1:]))
+
+        ct = of_misfit(last_h, obs[calls], starts[-1])
+        for k in reversed(range(calls)):
+            # the call's steps again, every state kept, then backwards
+            with _adjoint_scope(RECOMPUTE):
+                _, vjp = jax.vjp(window.step_by_step, SWState(*starts[k]))
+                ct, = vjp(ct)
+            mine = of_misfit(starts[k].h, obs[k], starts[k])
+            ct = SWState(ct.h + mine.h, *ct[1:])
+        with _adjoint_scope(RECOMPUTE):
+            _, vjp = jax.vjp(window.first, h0, u0, v0)
+            return vjp(ct)
+
+    _kernels_ahead(cfg, comm)
+    spec = jax.P(*comm.axes)
+    fields = (spec, spec, spec, jax.P(None, *comm.axes))
+    kept = ((_mesh_specs(comm),) * calls, spec)
+    run_forward = jax.jit(jax.shard_map(
+        lambda *args: forward(*args[:3], checked(args[3])), mesh=comm.mesh,
+        in_specs=fields, out_specs=(spec, *kept)))
+    run_backward = jax.jit(jax.shard_map(
+        backward, mesh=comm.mesh, in_specs=(*fields, *kept),
+        out_specs=(spec,) * 3))
+
+    def gradient(h0, u0, v0, obs):
+        cost, starts, last_h = run_forward(h0, u0, v0, obs)
+        return (cost, *run_backward(h0, u0, v0, obs, starts, last_h))
+
+    gradient.forward, gradient.backward = run_forward, run_backward
+    return gradient
+
+
+def make_descent_step(cfg, comm):
+    """Jitted global function ``(h0, u0, v0, gh, gu, gv, rate) -> (h0,
+    u0, v0)``: a steepest-descent step of length ``rate`` (a scalar),
+    the fields donated."""
+
+    def local_fn(h0, u0, v0, gh, gu, gv, rate):
+        with _adjoint_scope(UPDATE):
+            return tuple(x - rate.astype(x.dtype) * g
+                         for x, g in zip((h0, u0, v0), (gh, gu, gv)))
+
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        local_fn, mesh=comm.mesh, in_specs=(spec,) * 6 + (jax.P(),),
+        out_specs=(spec,) * 3), donate_argnums=(0, 1, 2))
+
+
+class Descent:
+    """A fit of a window's initial fields to observations by steepest
+    descent, as a host drives it: every iteration one call of
+    :func:`make_gradient`'s programs (the forward sweep, the backward
+    sweep) and one of :func:`make_descent_step`'s, enqueued without a
+    wait between; the costs stay on the device until :meth:`costs` asks.
+
+        fit = Descent(cfg, comm, calls=4, num_steps=10, observe=2)
+        rate, _, _ = fit.step_length(h0, u0, v0, obs)
+        fit.start(h0, u0, v0, obs, rate)
+        fit.iterate(5)
+        fit.wait()
+        fit.costs()      # J before each of the five steps
+
+    ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`) keeps the
+    host's spans, ``mpi4jax_tpu.adjoint/enqueue`` an iteration and
+    ``mpi4jax_tpu.adjoint/wait`` a wait.  :meth:`stats` counts what was
+    run and what the two checkpoint levels hold.
+    """
+
+    def __init__(self, cfg, comm, *, calls, num_steps, observe=1):
+        self.cfg, self.comm = cfg, comm
+        self.calls, self.num_steps = calls, num_steps
+        self.gradient = make_gradient(
+            cfg, comm, calls=calls, num_steps=num_steps, observe=observe)
+        self.update = make_descent_step(cfg, comm)
+        self.trace = spans.Recorder(SCOPE_PREFIX)
+        self.fields = self.obs = self.rate = None
+        self._costs = []
+
+    def step_length(self, h0, u0, v0, obs, *, iterations=5, seed=0,
+                    nudge=1e-2):
+        """A step length that a whole run of steepest descent can keep:
+        ``1 / (2 L)``, ``L`` the largest curvature of the cost found by
+        ``iterations`` steps of the power method on differences of
+        gradients, ``(grad J(x + e v) - grad J(x)) / e``, from a seeded
+        rough direction (``1 + iterations`` gradients).  A fixed step
+        has to stay under ``2 / L`` or the roughest directions grow;
+        the Cauchy step of the first gradient, which is smooth, is
+        several times that at a fine grid (``PERF.md``, PR 54: 0.40
+        against a bound of 0.15 at 7200x3600, and the cost rose
+        25-fold in eight steps).  The power method comes to ``L`` from
+        below; the factor of two is its room.  Returns ``(rate, the
+        curvatures found, the cost at the fields)``."""
+        fields = (h0, u0, v0)
+        cost, *at = self.gradient(*fields, obs)
+        keys = jax.random.split(jax.random.key(seed), 3)
+        v = [jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, fields)]
+        v = [jax.device_put(x, a.sharding) for x, a in zip(v, fields)]
+        found = []
+        for _ in range(iterations):
+            there = self.gradient(
+                *(a + nudge * x for a, x in zip(fields, v)), obs)[1:]
+            w = [(b - a) / nudge for a, b in zip(at, there)]
+            found.append(
+                sum(float(jnp.vdot(x, y)) for x, y in zip(v, w))
+                / sum(float(jnp.vdot(x, x)) for x in v))
+            size = sum(float(jnp.vdot(y, y)) for y in w) ** 0.5
+            cells = sum(y.size for y in w) ** 0.5
+            v = [y * (cells / size) for y in w]  # elements of order one
+        return 0.5 / max(found), found, float(cost[0, 0])
+
+    def start(self, h0, u0, v0, obs, rate):
+        """The first guess (consumed by the first step), the
+        observations and the step length."""
+        self.fields, self.obs = (h0, u0, v0), obs
+        self.rate = jnp.asarray(rate, jnp.float32)
+        self._costs = []
+
+    def iterate(self, n=1):
+        """Enqueue ``n`` iterations; nothing is waited for."""
+        for _ in range(n):
+            with self.trace.span("adjoint/enqueue", key=len(self._costs)):
+                cost, *grads = self.gradient(*self.fields, self.obs)
+                self.fields = self.update(*self.fields, *grads, self.rate)
+                self._costs.append(cost)
+
+    def wait(self):
+        with self.trace.span("adjoint/wait", key=len(self._costs)):
+            jax.block_until_ready(self.fields)
+
+    def costs(self):
+        """The cost before each descent step so far, as floats (waits)."""
+        return [float(c[0, 0]) for c in self._costs]
+
+    def stats(self):
+        """``gradients``: iterations enqueued.  ``window_steps``: the
+        model steps of a window.  ``trajectory_bytes``: what the two
+        checkpoint levels keep on the mesh at their fullest, from
+        shapes: the state at each call's start and the states of one
+        call's steps.  ``costs``: the cost before each step.  (What a
+        gradient runs again is not counted here: a device trace shows
+        it, under ``sw/adjoint/recompute``.)"""
+        state = jax.eval_shape(make_init(self.cfg, self.comm))
+        state_bytes = sum(a.size * a.dtype.itemsize for a in state)
+        return {
+            "gradients": len(self._costs),
+            "window_steps": 1 + self.calls * self.num_steps,
+            "trajectory_bytes": (self.calls + self.num_steps) * state_bytes,
+            "costs": self.costs(),
+        }
 
 
 @dataclass(frozen=True)

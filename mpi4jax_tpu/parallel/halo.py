@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from mpi4jax_tpu.ops._core import as_token, publishes_token
+from mpi4jax_tpu.ops._core import (
+    as_token, promote_vma, publishes_token, vma_of)
 from mpi4jax_tpu.ops.p2p import sendrecv, sendrecv_multi
 
 __all__ = ["halo_exchange_2d", "halo_exchange_2d_batch", "halo_slabs_2d"]
@@ -47,6 +48,12 @@ __all__ = ["halo_exchange_2d", "halo_exchange_2d_batch", "halo_slabs_2d"]
 # caller writes them).  Metadata only; none may start with
 # SCOPE_PREFIX (the analyzer takes the innermost such segment as the op).
 PACK, WIRE, UNPACK = "pack", "wire", "unpack"
+# The transposed exchange (what ``jax.grad`` / ``jax.vjp`` runs in place
+# of an exchange, on the mesh tier: :func:`_adjoint`) names the same
+# three phases inside this marker, so that a backward sweep's events
+# read ``.../mpi4jax_tpu.halo_exchange_2d))/transpose/pack|wire|unpack``
+# where the forward's read ``.../mpi4jax_tpu.halo_exchange_2d/pack|...``.
+TRANSPOSE = "transpose"
 
 
 def _axis_shift(arr_slice, template, comm, axis, disp, periodic, token):
@@ -303,11 +310,13 @@ def _lane_tiles(block, slab, start):
     return s0, s1
 
 
-def _place(a, slab, region, *, tiles=False):
+def _place(a, slab, region, *, tiles=False, add=False):
     """``a`` with ``slab`` written over ``region``, a pair of static
     slices: a ``dynamic_update_slice`` at constant offsets (``.at[].set``
     is a scatter, which XLA gives a bounds test, a mask and a select
-    of the slab's size on every write).
+    of the slab's size on every write).  With ``add`` the slab is added
+    to what lies there (the transposed exchange's write: a returned
+    cotangent into the edge it was copied from).
 
     With ``tiles`` (the mesh tier, whose block is row-major) a slab
     narrower than a lane tile is written as the whole tiles that hold
@@ -329,17 +338,21 @@ def _place(a, slab, region, *, tiles=False):
     start = tuple(s.indices(n)[0] for s, n in zip(region, a.shape))
     cols = _lane_tiles(a.shape, slab.shape, start) if tiles else None
     if cols is None:
+        if add:
+            slab = slab + a[region]
         return lax.dynamic_update_slice(a, slab, start)
     s0, s1 = cols
     before, w = start[1] - s0, slab.shape[1]
+    padded = lax.pad(slab, jnp.zeros((), slab.dtype),
+                     [(0, 0, 0), (before, s1 - s0 - before - w, 0)])
+    strip = lax.slice_in_dim(a, s0, s1, axis=1)
+    if add:  # zeros beside the slab: the strip's other columns as they are
+        return lax.dynamic_update_slice(a, strip + padded, (0, s0))
     # a constant, so that XLA carries a literal and computes no mask
     ghost = np.zeros(s1 - s0, bool)
     ghost[before:before + w] = True
     strip = lax.select(
-        jnp.broadcast_to(ghost, (a.shape[0], s1 - s0)),
-        lax.pad(slab, jnp.zeros((), slab.dtype),
-                [(0, 0, 0), (before, s1 - s0 - before - w, 0)]),
-        lax.slice_in_dim(a, s0, s1, axis=1))
+        jnp.broadcast_to(ghost, (a.shape[0], s1 - s0)), padded, strip)
     return lax.dynamic_update_slice(a, strip, (0, s0))
 
 
@@ -350,7 +363,20 @@ def _exchange(arrs, comm, *, periodic, token, width, stack):
     between, so the writes can share one buffer (the caller's own where
     it gives the block up, one copy of it where it keeps it).  On the
     mesh tier the block is row-major, so it is the column slabs that
-    are narrow: :func:`_place` writes those as whole lane tiles."""
+    are narrow: :func:`_place` writes those as whole lane tiles; and
+    there the exchange carries its own transpose (:func:`_transposable`)."""
+    options = dict(periodic=periodic, width=width, stack=stack)
+    if comm.backend != "mesh":
+        return _placed(arrs, comm, token=token, **options)
+    return _transposable(
+        lambda arrs, token: _placed(arrs, comm, token=token, **options),
+        lambda blocks, token: _adjoint(
+            blocks, None, comm, token=token, **options),
+        arrs, token)
+
+
+def _placed(arrs, comm, *, periodic, token, width, stack):
+    """:func:`_exchange` as every tier runs it."""
     mesh = comm.backend == "mesh"
     arrs, slabs, token = _received(
         arrs, comm, periodic=periodic, token=token, width=width, stack=stack,
@@ -410,10 +436,115 @@ def halo_slabs_2d(arr, comm, *, periodic=(False, True), token=None, width=1,
     traced program is the same, equation for equation.
     """
     several = isinstance(arr, (list, tuple))
-    _, slabs, token = _received(
-        list(arr) if several else [arr], comm, periodic=periodic, token=token,
-        width=width, stack=False, depth=depth and tuple(depth),
-    )
-    if several:
-        return [tuple(got[i] for got in slabs) for i in range(len(arr))], token
-    return tuple(got for got, in slabs), token
+    arrs = list(arr) if several else [arr]
+    options = dict(periodic=periodic, width=width, stack=False)
+    if depth is not None:  # as deep as the ring: the exchange as it is
+        depth = None if tuple(depth) == (width, width) else tuple(depth)
+
+    def slabs_of(arrs, token):
+        _, slabs, token = _received(
+            arrs, comm, token=token, depth=depth, **options)
+        return [tuple(got[i] for got in slabs) for i in range(len(arrs))], token
+
+    def blocks_of(slabs, token):
+        # the blocks' own cotangent is nothing: the slabs are all that
+        # is handed back
+        zeros = [promote_vma(jnp.zeros(a.shape, a.dtype), comm.axes)
+                 for a in arrs]
+        return _adjoint(zeros, slabs, comm, token=token, **options)
+
+    if comm.backend == "mesh" and depth is None:
+        slabs, token = _transposable(slabs_of, blocks_of, arrs, token)
+    else:
+        # the other tiers, and slabs deeper than the ring, differentiate
+        # by the rules of what they are made of
+        slabs, token = slabs_of(arrs, token)
+    return (slabs if several else slabs[0]), token
+
+
+def _transposable(forward, backward, arrs, token):
+    """``forward(arrs, token)``, an exchange of the mesh tier, with
+    ``backward`` for its transpose: ``jax.vjp`` and ``jax.grad`` through
+    the exchange then run the adjoint exchange (:func:`_adjoint`) and
+    not the transposes of the exchange's parts.  Those are right and
+    dense: a slab sliced from a block transposes to a block of zeros
+    with the slab padded into it and an add of two blocks, once a slab,
+    and the lane-tile strip of :func:`_place` likewise, where the
+    adjoint exchange touches the slabs it moves and nothing else.  An
+    exchange is linear in its blocks and passes its token's stamp
+    through: nothing is kept for the backward pass, the adjoint
+    exchange threads the token's cotangent through its four shifts as
+    their token, and a stamp's own cotangent is zero.  A ``jax.custom_vjp``:
+    reverse mode only (``jax.jvp`` through a mesh-tier exchange raises;
+    ``jax.custom_derivatives.linear_call`` would give both modes and
+    binds symbolic zeros as arrays in jax 0.9).  A call that is not
+    differentiated lowers to what ``forward`` lowers to."""
+    token = as_token(token)
+    # a stamp's cotangent says nothing; it has to be of the stamp's type
+    stamps = [(x.shape, x.dtype, vma_of(x) or ()) for x in jax.tree.leaves(token)]
+
+    def transposed(_, cotangents):
+        blocks, after = backward(*cotangents)
+        nothing = [promote_vma(jnp.zeros(shape, dtype), vma)
+                   for shape, dtype, vma in stamps]
+        return blocks, jax.tree.unflatten(jax.tree.structure(token), nothing)
+
+    exchange = jax.custom_vjp(forward)
+    exchange.defvjp(
+        lambda arrs, token: (forward(arrs, token), None), transposed)
+    return exchange(list(arrs), token)
+
+
+def _adjoint(blocks, slabs, comm, *, periodic, token, width, stack):
+    """The transpose of both forms of the exchange on the mesh tier,
+    written as an exchange: for every ghost cell, its cotangent added to
+    the cell it was copied from.  ``blocks`` are the cotangents of the
+    blocks that :func:`_exchange` returns; ``slabs``, where given, those
+    of :func:`halo_slabs_2d`'s slabs (``slabs[i][k]`` for array ``i`` and
+    shift ``k``, ``None`` where the shift is none), which lie over the
+    blocks' ghost regions.  Returns the cotangents of the blocks handed
+    to the exchange, and the token.
+
+    The exchange is ``Y . X`` (the x ghosts over full height, then the y
+    ghosts over full width, fresh x ghosts and so corners included), its
+    transpose ``X^T . Y^T``: the shifts in reverse order, and for each
+    the ghost region ``pack``-ed (read), sent over the ``wire`` with the
+    displacement negated, so that it arrives where the ghosts came from,
+    and ``unpack``-ed by an add into the edge strip that was sent; the
+    ghost region itself is left zero, since the exchange overwrote it
+    (on a device that received nothing there, a walled side's, it keeps
+    what it holds: those ghosts passed through).  A corner's cotangent
+    goes back through both shifts.  The same slab-sized work as the
+    exchange: column strips by :func:`_place`'s aligned write, the block
+    held row-major (:func:`_row_major`), no pass over a block."""
+    w = width
+    with jax.named_scope(TRANSPOSE):
+        with jax.named_scope(PACK):
+            blocks = [_row_major(g) for g in blocks]
+        shifts = list(enumerate(_shifts(w, periodic)))
+        for k, (axis, disp, per, sent, received) in reversed(shifts):
+            sub = comm.sub(axis)
+            pairs = sub.shift_perm(axis, disp, periodic=per)
+            if not pairs:
+                continue  # no exchange on this axis: the ghosts pass through
+            with jax.named_scope(PACK):
+                ghosts = [g[received] for g in blocks]
+                if slabs is not None:
+                    ghosts = [g if c[k] is None else g + c[k]
+                              for g, c in zip(ghosts, slabs)]
+                zeros = [jnp.zeros_like(g) for g in ghosts]
+            back, token = _shift(
+                ghosts, zeros, comm, axis, -disp, per, token, stack=stack)
+            with jax.named_scope(UNPACK):
+                kept = zeros
+                if len(pairs) < sub.size:
+                    # a device nothing came to kept its ghosts
+                    got = np.zeros(sub.size, bool)
+                    got[[d for _, d in pairs]] = True
+                    got = jnp.asarray(got)[sub.rank()]
+                    kept = [jnp.where(got, z, g) for z, g in zip(zeros, ghosts)]
+                blocks = [
+                    _place(_place(g, r, sent, tiles=True, add=True),
+                           keep, received, tiles=True)
+                    for g, r, keep in zip(blocks, back, kept)]
+    return blocks, token

@@ -84,6 +84,25 @@ def test_solver_monitor_check_tiny():
     assert "solver4.monitor" in chip_smoke.GROUPS[4]["solver4"][1]
 
 
+def test_solver_adjoint_check_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    out = chip_smoke.solver_adjoint_check(cfg, cpu, calls=2, steps_per_call=3)
+    assert set(out["rel_l2"]) == {"h", "u", "v"}
+    assert 0 <= max(out["rel_l2"].values()) <= 1e-4
+    assert out["cost"][0] == pytest.approx(out["cost"][1], rel=1e-5)
+    assert "window of 7 steps" in out["compared"]
+    assert "solver4.adjoint" in chip_smoke.GROUPS[4]["solver4.adjoint"][1]
+    # the chip's run is at the domain refined once: 1800x3600 cells a chip
+    fine = chip_smoke._refined(sw.SWConfig().bench_size(), 2)
+    assert (fine.ny, fine.nx, fine.dx, fine.dy) == (3600, 7200, 2500.0, 2500.0)
+    assert fine.dt == pytest.approx(sw.SWConfig().bench_size().dt / 2)
+    # a decomposition that changed the gradient is refused
+    with pytest.raises(AssertionError, match="changes the gradient"):
+        chip_smoke.solver_adjoint_check(
+            cfg, cpu, calls=2, steps_per_call=3, tol=0.0)
+
+
 def test_solver_output_restart_check_tiny():
     cpu = jax.devices("cpu")
     cfg = sw.SWConfig(ny=24, nx=48, ghost=2)

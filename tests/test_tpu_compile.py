@@ -978,3 +978,125 @@ def test_a_job_that_watches_itself_adds_a_program_and_leaves_the_step_alone(
         elif " fusion(" in lines[name] and "reduce" in name:
             assert "sw/monitor" in origin.op_name
             assert scopes.layer_of(origin) == scopes.PROGRAMS
+
+
+# -- the differentiated run (PR 54) ---------------------------------------
+
+
+@functools.cache
+def _compiled_gradient(v5e, mesh_shape, ny, nx, calls, steps):
+    """``make_gradient``'s two programs at ``ny`` x ``nx`` cells a chip,
+    observed over 2 x 2 cells, compiled for the described chips:
+    ``(forward, backward)``."""
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px],
+    )
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=ny * py, nx=nx * px, dx=2500.0, dy=2500.0, ghost=2)
+    field = jax.ShapeDtypeStruct(
+        (cfg.ny, cfg.nx), jnp.float32,
+        sharding=NamedSharding(mesh, jax.P("y", "x")))
+    obs = jax.ShapeDtypeStruct(
+        (calls + 1, cfg.ny // 2, cfg.nx // 2), jnp.float32,
+        sharding=NamedSharding(mesh, jax.P(None, "y", "x")))
+    gradient = sw.make_gradient(cfg, comm, calls=calls, num_steps=steps, observe=2)
+    args = (field, field, field, obs)
+    _cost, *kept = jax.eval_shape(gradient.forward, *args)
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    kept = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), kept)
+    return (gradient.forward.lower(*args).compile(),
+            gradient.backward.lower(*args, *kept).compile())
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_call_that_is_not_differentiated_compiles_to_what_it_did(
+        v5e, mesh_shape, monkeypatch):
+    """The step's and the exchange's ``jax.custom_vjp`` leave nothing in
+    a program nobody differentiates: ``make_multistep``'s compiled text
+    is, instruction for instruction, the text of the same program with
+    both wrappers taken off (``_kept_at_its_start`` handing back the
+    kernel's walk, ``_transposable`` calling the exchange)."""
+    from mpi4jax_tpu.parallel import halo
+
+    def instructions(text):
+        return [re.sub(r",? metadata=\{[^}]*\}", "", line)
+                for line in text.splitlines() if " = " in line]
+
+    text = _compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
+    monkeypatch.setattr(
+        sw, "_kept_at_its_start", lambda forward, twin, scope: forward)
+    monkeypatch.setattr(
+        halo, "_transposable",
+        lambda forward, backward, arrs, token: forward(list(arrs), token))
+    bare = _compiled_multistep.__wrapped__(
+        v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
+    assert instructions(text) == instructions(bare)
+    assert len(instructions(text)) > 20 and _kernel_calls(text)
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_the_backward_sweep_moves_no_block_for_an_exchange(v5e, mesh_shape):
+    """``jax.vjp`` through the exchange runs the adjoint exchange
+    (``parallel/halo.py _adjoint``), slab-sized work like the exchange:
+    under a ``mpi4jax_tpu.halo_*`` scope the backward sweep's text holds
+    no ``copy``, ``pad``, ``add`` or other pass over a block; what
+    carries such a scope and a block's shape is a write in place (a
+    ``dynamic-update-slice``, or a fusion whose only block-shaped
+    instruction is one: the lane-tile strip of ``_place``).  By the
+    rules of what the exchange is made of, each slab sliced from a
+    block came back as a block of zeros with the slab padded into it
+    and an add of two blocks (PERF.md, PR 54).  The forward sweep's
+    kernel walks stand as every cell's: two steps a walk."""
+    ny, nx = 1800, 3600
+    forward, backward = _compiled_gradient(v5e, mesh_shape, ny, nx, 1, 4)
+    block = f"f32[{ny + 4},{nx + 4}]"
+    text = backward.as_text()
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M))
+    under, transposed = 0, 0
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        shaped = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = \(?{re.escape(block)}", line)
+        if not name or "mpi4jax_tpu.halo_" not in name[1] or not shaped:
+            continue
+        opcode = re.match(
+            r".*? ([a-z][a-z\-]*)\(", line.split(" = ", 1)[1])[1]
+        if opcode in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        under += 1
+        transposed += "/transpose/" in name[1]
+        if opcode == "fusion":
+            body = computations[re.search(r"calls=%([\w.\-]+)", line)[1]]
+            whole = [
+                found[1] for found in re.finditer(
+                    rf"= {re.escape(block)}\S* ([a-z\-]+)\(", body)
+                if found[1] not in ("parameter", "dynamic-update-slice", "bitcast")]
+            assert not whole, (line, whole)
+        else:
+            assert opcode == "dynamic-update-slice", line
+    assert under and transposed  # the adjoint exchange is there, and named
+    # both sweeps run the kernel: the window as every cell's program
+    # runs it, and a call's steps again
+    assert _kernel_calls(forward.as_text()) and _kernel_calls(text)
+
+
+def test_the_cells_gradient_fits_a_chip_with_room(v5e):
+    """``sw-adjoint-1chip``'s two programs at the cell's own size and
+    window: the backward sweep's peak by the compiler's buffer
+    assignment under 14e9 bytes (the issue's line for taking a call
+    off the window), and over a quarter of a chip."""
+    import json
+
+    with open("perfbench/workloads/sw-adjoint-1chip.json") as f:
+        grid = json.load(f)["grid"]
+    with open("perfbench/configs/shallow-water-adjoint.json") as f:
+        calls = json.load(f)["window"]["calls"]
+    forward, backward = _compiled_gradient(
+        v5e, (1, 1), grid["ny"], grid["nx"], calls, 10)
+    peak = backward.memory_analysis().peak_memory_in_bytes
+    assert 0.25 * 16e9 < peak < 14e9, peak
+    assert forward.memory_analysis().peak_memory_in_bytes < peak
