@@ -706,7 +706,7 @@ def _kernels_ahead(cfg, comm):
 
 
 def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
-               sums=(), coarsen=0, summing=True):
+               sums=(), coarsen=0, summing=True, read_whole=True):
     """Wide-halo (ghost=2) step: communicate prognostic fields only.
 
     The narrow schedule exchanges every intermediate field because a
@@ -772,6 +772,14 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
     hand a later step the tendencies a step returned.  A first step
     reads none, and takes either shape.
 
+    ``read_whole`` says what a caller that differentiates may read of
+    the state that comes back: every cell of it (what a user's
+    ``jax.grad`` has to be given), or, where it is not set, nothing of
+    the ghost cells that the next step's exchange overwrites
+    (:func:`_window`'s steps: the next step and the misfit read a state).
+    Their cotangents are then zeros by construction, and the backward
+    step does not send them home (:func:`_step_backwards`).
+
     Returns ``((state, sums), token)``, ``sums`` empty but here:
     ``coarsen`` with ``sums`` (where the step is the kernel,
     :func:`_sums_in_step`; the room :func:`make_sums_room` makes, as a
@@ -806,19 +814,27 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
             (state, _), token = walk(state, token, (), True)
             return state, token
 
-        # the kernel has no transpose: the derivative of a walk is that
-        # of the array code of its steps, at the state the walk started
-        # from (docs/shallow-water.md, "The differentiated run")
-        state, token = _kept_at_its_start(
-            forward,
-            partial(_walk_as_arrays, cfg=cfg, comm=comm, first_step=first_step,
-                    steps=steps),
-            f"{ADJOINT_SCOPE}/{STEP_VJP}")(
-                # a run's first step reads no tendency: the caller's are
-                # no operand of the derivative, and a program nobody
-                # differentiates drops them as unused, as it did
-                SWState(*state[:3], *((None,) * 3 if first_step else state[3:])),
-                token)
+        # a run's first step reads no tendency: the caller's are no
+        # operand of the derivative, and a program nobody differentiates
+        # drops them as unused, as it did
+        operands = SWState(
+            *state[:3], *((None,) * 3 if first_step else state[3:])), token
+        scope = f"{ADJOINT_SCOPE}/{STEP_VJP}"
+        how = dict(cfg=cfg, comm=comm, first_step=first_step, steps=steps)
+        if _derives_as_kernels(cfg, comm):
+            # the derivative of a walk is the adjoint kernel's, a step at
+            # a time, at the fields the step started from
+            state, token = _with_derivative(
+                forward,
+                partial(_kept_for_the_kernel, first_step=first_step, steps=steps),
+                partial(_walk_backwards, read_whole=read_whole, **how),
+                scope)(*operands)
+        else:
+            # no room for the adjoint kernel's blocks: the derivative is
+            # that of the array code of the walk's steps, at the state
+            # the walk started from
+            state, token = _kept_at_its_start(
+                forward, partial(_walk_as_arrays, **how), scope)(*operands)
         return (state, ()), token
 
     return _step_wide_arrays(state, cfg, comm, first_step, token)
@@ -918,6 +934,21 @@ def _adjoint_scope(phase):
     return jax.named_scope(f"{ADJOINT_SCOPE}/{phase}")
 
 
+def _with_derivative(forward, keep, backward, scope):
+    """``forward`` (operands -> results) as a function whose derivative
+    is ``backward(keep(*operands), cotangents)``, run under ``scope``:
+    what ``keep`` returns is all that is held for the backward pass."""
+    kept = jax.custom_vjp(forward)
+
+    def under_scope(held, cotangents):
+        with jax.named_scope(scope):
+            return backward(held, cotangents)
+
+    kept.defvjp(lambda *operands: (forward(*operands), keep(*operands)),
+                under_scope)
+    return kept
+
+
 def _kept_at_its_start(forward, twin, scope):
     """``forward`` (operands -> results) as a function whose derivative
     is ``twin``'s, taken where the call started: the one thing kept for
@@ -928,15 +959,149 @@ def _kept_at_its_start(forward, twin, scope):
     kernel's array code; a call's steps one by one where ``forward``
     walks two at a time) it is how that function gets a derivative or a
     cheaper one to keep."""
-    kept = jax.custom_vjp(forward)
-
     def backward(operands, cotangents):
-        with jax.named_scope(scope):
-            _, vjp = jax.vjp(twin, *operands)
-            return vjp(cotangents)
+        _, vjp = jax.vjp(twin, *operands)
+        return vjp(cotangents)
 
-    kept.defvjp(lambda *operands: (forward(*operands), operands), backward)
-    return kept
+    return _with_derivative(
+        forward, lambda *operands: operands, backward, scope)
+
+
+def _derives_as_kernels(cfg, comm):
+    """Whether the derivative of a step that runs as the kernel is the
+    adjoint kernel's (``sw_kernels.wide_step_vjp``): where its blocks,
+    nine arrays in and six out, fit VMEM.  From the block's shape and
+    dtype, as :func:`_runs_as_kernels` decides; a block without that
+    room is differentiated as its array code (:func:`_walk_as_arrays`)."""
+    ny_l, nx_l = cfg.local_interior(comm)
+    return _runs_as_kernels(cfg, comm) and sw_kernels.adjoint_tile_rows(
+        ny_l + 4, nx_l + 4, jnp.dtype(cfg.dtype)) > 0
+
+
+def _kept_for_the_kernel(state, token, *, first_step, steps):
+    """What a walk keeps for :func:`_walk_backwards`: the fields it
+    started from, which is all the adjoint kernel reads of a state (the
+    old tendencies enter a step linearly); a walk of two steps keeps its
+    tendencies too, for the state between the two."""
+    if steps == 2 and not first_step:
+        return state, token
+    return SWState(*state[:3], None, None, None), token
+
+
+def _walk_backwards(kept, cotangents, *, cfg, comm, first_step, steps,
+                    read_whole):
+    """The transpose of :func:`_walk_as_arrays` where the step's
+    derivative is a kernel (:func:`_derives_as_kernels`): for each of
+    the walk's steps, last to first, :func:`_step_backwards` at the
+    fields that step started from.  Those of a walk's second step are
+    not kept: the forward kernel makes them again, one walk of one
+    step.  ``read_whole``: :func:`_step_wide`'s, of the walk's last
+    step; the state between two steps of a walk nobody reads."""
+    state, token = kept
+    ct, ct_token = cotangents
+    back = partial(_step_backwards, ct_token=ct_token, cfg=cfg, comm=comm)
+    if first_step:
+        ct = back(state[:3], token, ct, first_step=True, read_whole=read_whole)
+        return SWState(*ct[:3], None, None, None), _no_stamp(token)
+    if steps == 2:
+        (between, _), after = _kernel_walk(
+            state, token, (), True, cfg=cfg, comm=comm, first_step=False,
+            steps=1, coarsen=0)
+        ct = back(between[:3], after, ct, read_whole=read_whole)
+        read_whole = False
+    return SWState(*back(state[:3], token, ct, read_whole=read_whole)), _no_stamp(token)
+
+
+def _outermost_ring(x, ct, scale, is_south, is_north, v_is_zero=False):
+    """``x`` with its ghost frame set for the exchange's transpose to
+    carry ring 2 of ``ct`` home, ``scale`` times: ring 2 of ``x`` is
+    that, ring 1 zero, a wall's ghost rows zero (``v_is_zero``: and the
+    northern wall's own row, where ``v`` is set to zero and its
+    cotangent says nothing).  Four slabs written, the columns' before
+    the rows', as an exchange writes them."""
+    G = 2
+    rows, width = ct.shape
+    for region, at in ((np.s_[:, :G], (0, 0)), (np.s_[:, -G:], (0, width - G)),
+                       (np.s_[:G, :], (0, 0)), (np.s_[-G:, :], (rows - G, 0))):
+        slab = ct[region]
+        r = lax.broadcasted_iota(jnp.int32, slab.shape, 0) + at[0]
+        c = lax.broadcasted_iota(jnp.int32, slab.shape, 1) + at[1]
+        outermost = (r == 0) | (r == rows - 1) | (c == 0) | (c == width - 1)
+        walled = (is_south & (r < G)) | (
+            is_north & (r >= rows - G - int(v_is_zero)))
+        slab = jnp.where(outermost & ~walled, slab * jnp.asarray(scale, slab.dtype),
+                         jnp.zeros((), slab.dtype))
+        x = lax.dynamic_update_slice(x, slab, at)
+    return x
+
+
+def _no_stamp(token):
+    """A token's cotangent: a stamp says nothing (``parallel/halo.py
+    _transposable``)."""
+    return jax.tree.map(jnp.zeros_like, token)
+
+
+def _step_backwards(fields, token, cotangents, *, ct_token, cfg, comm,
+                    read_whole, first_step=False):
+    """One step of the kernel's walk transposed: the six cotangents of
+    a step's results to the six of the state it read, ``fields`` being
+    the ``h``, ``u``, ``v`` it started from.
+
+    The step is its first exchange and then the kernel's schedule
+    (round 1 on ring 1 of ``u``, ``v`` too, round 2 after it with no
+    exchange between): the same function of the mesh's interiors as the
+    array code's five exchanges and two rounds, so the same derivative.
+    Backwards: ``sw_kernels.wide_step_vjp`` at the fields with fresh
+    ghosts hands back the cotangents of those padded fields, ghost cells
+    included, and **the exchange's own transpose** (``jax.vjp`` of
+    ``halo_exchange_2d``, which is linear: ``parallel/halo.py
+    _adjoint``) carries the ghost cells' home to the edges they were
+    copied from: one adjoint exchange a field.  Two more go the same
+    way: ring 1 of the old ``du``, ``dv`` is the neighbours'
+    (``sw_kernels``, "Schedule"), and what the kernel hands back there
+    is theirs.  The old ``dh``'s is the interior's alone.
+
+    The results' ghost cells.  The kernel takes the cotangents of the
+    interior and of every ghost cell that the step passed through as
+    they are: all of ``h``'s, a wall's ghost rows of ``u``, ``v``,
+    without friction all of theirs (they go home with the fields').
+    With friction the ghost cells of the results ``u``, ``v`` are what
+    the array code's second exchange brought, the neighbours' round 1:
+    ring 1 is this walk's own round 1 there and the kernel transposes it
+    in place; ring 2 only a neighbour computed, so its cotangent goes
+    home before the kernel, ``a dt`` of it on the outermost ring of the
+    new ``du``, ``dv``'s cotangents (:func:`_outermost_ring`; the
+    kernel's own pass-through carries the rest, to the fields and to
+    the old tendencies).  Only where ``read_whole``: a state nobody
+    reads whole hands zeros there (:func:`_step_wide`)."""
+    G = 2
+    ny_l, _nx_l = cfg.local_interior(comm)
+    is_north, is_south = _wall_masks(comm)
+    iy, _ix = _device_coords(comm)
+
+    def exchange(x, token):
+        return halo_exchange_2d(
+            x, comm, periodic=(False, True), token=token, width=G)
+
+    fresh, home = [], []
+    for x in fields:
+        (x, token), transposed = jax.vjp(exchange, x, token)
+        fresh.append(x)
+        home.append(lambda ct, transposed=transposed: transposed((ct, ct_token))[0])
+    _, to_u, to_v = home
+    ch, cu, cv, cdh, cdu, cdv = cotangents
+    a, b = (1.0, 0.0) if first_step else (cfg.ab_a, cfg.ab_b)
+    if cfg.lateral_viscosity > 0 and read_whole:
+        rest = (a * cfg.dt, is_south, is_north)
+        cdu = to_u(_outermost_ring(cdu, cu, *rest))
+        cdv = to_v(_outermost_ring(cdv, cv, *rest, v_is_zero=True))
+    ch, cu, cv, cdh, cdu, cdv = sw_kernels.wide_step_vjp(
+        *fresh, (ch, cu, cv, cdh, cdu, cdv), is_south, is_north, iy * ny_l, a, b,
+        nu=cfg.lateral_viscosity, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
+        gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
+        coriolis_beta=cfg.coriolis_beta)
+    ch, cu, cv = (send(ct) for send, ct in zip(home, (ch, cu, cv)))
+    return ch, cu, cv, cdh, to_u(cdu), to_v(cdv)
 
 
 def _walk_as_arrays(state, token, *, cfg, comm, first_step, steps):
@@ -1306,14 +1471,20 @@ class _Window(NamedTuple):
 def _window(cfg, comm, num_steps, observe):
     G = cfg.ghost
 
-    def one_step(state):
-        return shallow_water_step(state, cfg, comm)[0]
-
-    if not _runs_as_kernels(cfg, comm):
+    if _runs_as_kernels(cfg, comm):
+        # a kernel's walk keeps what its derivative reads by itself
+        # (_step_wide).  A state of the sweep is read by the next step,
+        # whose exchange overwrites its ghost cells, and by the misfit,
+        # which reads its interior
+        def one_step(state):
+            return _step_wide(state, cfg, comm, read_whole=False)[0][0]
+    else:
         # array code: a step keeps its input and is run again backwards
-        # (a kernel's walk is kept so by _step_wide itself)
+        def plain(state):
+            return shallow_water_step(state, cfg, comm)[0]
+
         one_step = _kept_at_its_start(
-            one_step, one_step, f"{ADJOINT_SCOPE}/{STEP_VJP}")
+            plain, plain, f"{ADJOINT_SCOPE}/{STEP_VJP}")
 
     def first(h0, u0, v0):
         state = _state_of_fields(h0, u0, v0, cfg=cfg, comm=comm)
@@ -1362,6 +1533,22 @@ def _observed(block, ghost, coarsen):
     return observe(block)
 
 
+def _behind(x, earlier, comm):
+    """``x`` as it is, but not before ``earlier`` is there: its first
+    element is read through the other's (a NaN there passes, into a
+    result that holds it anyway).  What orders two parts of a program
+    that share no data, where a barrier is only the compiler's: beside
+    neighbours their exchanges' permutes must not run side by side (the
+    CPU's runtime takes them by the data's order alone, and all share
+    one channel).  A device alone has no permute and its program is
+    left as it is."""
+    if comm.mesh.size == 1:
+        return x
+    mark = earlier[0, 0]
+    corner = jnp.where(jnp.isnan(mark), mark.astype(x.dtype), x[0, 0])
+    return lax.dynamic_update_slice(x, corner.reshape(1, 1), (0, 0))
+
+
 def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
     """Global function ``(h0, u0, v0, obs) -> (J, dJ/dh0, dJ/du0,
     dJ/dv0)``: the misfit of a window of the model to observations and
@@ -1387,8 +1574,11 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
     the adjoint exchange (``parallel/halo.py``: the cotangents of ghost
     cells sent back along the reversed permutes and added to the edges
     they were copied from).  Where the step is the kernel
-    (:func:`_runs_as_kernels`) a step's derivative is that of its array
-    code at the step's input state.
+    (:func:`_runs_as_kernels`) a step's derivative is a kernel too
+    (``sw_kernels.wide_step_vjp`` at the fields the step started from,
+    :func:`_step_backwards`); on a block whose adjoint walk has no room
+    in VMEM (:func:`_derives_as_kernels`) it is that of the step's
+    array code at the step's input state.
 
     Checkpointed at two levels, as two jitted programs a gradient (the
     function's ``forward`` and ``backward``).  The forward sweep runs
@@ -1398,11 +1588,14 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
     ``h``, for its misfit): the first level, arrays on the mesh between
     the two programs.  The backward sweep takes the calls last to first:
     it runs a call's steps again one by one, keeps all ``num_steps``
-    states of that call (the second level) while it takes the call's
-    steps backwards (each step's array code run once more at its kept
-    state, and transposed), then lets them go.  At any time ``calls +
-    num_steps`` states and one step's residuals are held, where keeping
-    everything holds every step's: ``Descent.stats()`` gives the bytes.
+    states of that call (the second level: where a step's derivative is
+    the kernel, the fields ``h``, ``u``, ``v`` of each, which is all
+    that kernel reads) while it takes the call's steps backwards (the
+    adjoint kernel at each kept state; the array code run once more
+    there and transposed, where it is not), then lets them go.  At any
+    time ``calls`` states and ``num_steps`` kept ones are held, where
+    keeping everything holds every step's residuals:
+    ``Descent.stats()`` gives the bytes.
     The values are those of plain ``jax.value_and_grad`` of the window
     to rounding (``tests/test_sw_adjoint.py`` builds that program from
     the same parts: the compiler fuses, and so rounds, each program in
@@ -1456,12 +1649,17 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
 
         ct = of_misfit(last_h, obs[calls], starts[-1])
         for k in reversed(range(calls)):
+            # one call at a time: its second run reads nothing of the
+            # call after it, so only this keeps it (its exchanges, and
+            # its stack of kept states) behind that call's way back
+            start = SWState(_behind(starts[k][0], ct.h, comm), *starts[k][1:])
             # the call's steps again, every state kept, then backwards
             with _adjoint_scope(RECOMPUTE):
-                _, vjp = jax.vjp(window.step_by_step, SWState(*starts[k]))
+                _, vjp = jax.vjp(window.step_by_step, start)
                 ct, = vjp(ct)
             mine = of_misfit(starts[k].h, obs[k], starts[k])
             ct = SWState(ct.h + mine.h, *ct[1:])
+        h0 = _behind(h0, ct.h, comm)
         with _adjoint_scope(RECOMPUTE):
             _, vjp = jax.vjp(window.first, h0, u0, v0)
             return vjp(ct)
@@ -1590,16 +1788,21 @@ class Descent:
         """``gradients``: iterations enqueued.  ``window_steps``: the
         model steps of a window.  ``trajectory_bytes``: what the two
         checkpoint levels keep on the mesh at their fullest, from
-        shapes: the state at each call's start and the states of one
-        call's steps.  ``costs``: the cost before each step.  (What a
+        shapes: the state at each call's start and what is kept of the
+        states of one call's steps (the fields alone, where a step's
+        derivative is the kernel that reads no more).  ``costs``: the
+        cost before each step.  (What a
         gradient runs again is not counted here: a device trace shows
         it, under ``sw/adjoint/recompute``.)"""
         state = jax.eval_shape(make_init(self.cfg, self.comm))
         state_bytes = sum(a.size * a.dtype.itemsize for a in state)
+        kept = state[:3] if _derives_as_kernels(self.cfg, self.comm) else state
+        kept_bytes = sum(a.size * a.dtype.itemsize for a in kept)
         return {
             "gradients": len(self._costs),
             "window_steps": 1 + self.calls * self.num_steps,
-            "trajectory_bytes": (self.calls + self.num_steps) * state_bytes,
+            "trajectory_bytes": (
+                self.calls * state_bytes + self.num_steps * kept_bytes),
             "costs": self.costs(),
         }
 

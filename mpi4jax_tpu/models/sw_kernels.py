@@ -10,7 +10,9 @@ written once, in place: 12 passes over a field a walk, the least a walk
 can move.  A walk advances **two time steps**, on one device and beside
 neighbours ("Two steps a walk", below): 12 passes where two walks move
 24, which its vector work keeps up with to within a few per cent of
-HBM's pace.
+HBM's pace.  A step's **derivative** is a kernel as well
+(:func:`wide_step_vjp`, "The adjoint walk" below): 15 passes, the three
+kept fields and six cotangents in, six cotangents out.
 
 Schedule: three exchanges, not five
 -----------------------------------
@@ -297,6 +299,58 @@ call's loop, which name the sums as results like the last, need no
 room of their own (fresh results every call were 315 MB allocated and
 as much again as a temporary of the loop, and runs with them stalled:
 ``PERF.md``, PR 49).
+
+The adjoint walk
+----------------
+Under ``jax.grad`` a step that ran as the kernel is transposed by a
+kernel too (:func:`wide_step_vjp`, which ``shallow_water._step_wide``'s
+backward calls once a step): the array code's derivative was XLA's
+fusions, which wrote every shifted intermediate and every residual to
+HBM, 143 passes over a field a step where this moves 15.  It **reads**
+the ``h``, ``u``, ``v`` the step started from, ghosts fresh (the old
+tendencies enter a step linearly: their values are not needed, so a
+backward sweep keeps three arrays a step and not six), and the six
+cotangents of the step's results; it **writes** the six cotangents of
+what the step read, each where its own result's cotangent lay (aliased,
+a tile behind, as a walk writes a field).  Every operand is read whole
+once and every result written whole once: a call's signature is what it
+moves.
+
+What it transposes is this module's schedule, not the array code's:
+round 1 on the interior and on ring 1 of ``u``, ``v``, then round 2,
+with no exchange between.  So the cotangent of round 1's ``u``, ``v``
+on ring 1 (which round 2's five points read) goes backwards through
+round 1 on this chip, by the same code as the interior's, and comes out
+as cotangents of the fields **after their exchange, ghost cells
+included**, and of ring 1 of the old ``du``, ``dv`` (the neighbours',
+"Schedule" above).  The caller sends those ghost cells home through the
+exchange's own transpose (``parallel/halo.py``): the step's three
+exchanges backwards, and two for ``du``, ``dv``.  The same derivative as
+the array code's five exchanges and two rounds give, because it is the
+same function of the mesh's interiors.  The masks are the stages': the
+interior, round 1's reach (ring 1 where no wall stands; without
+friction the interior, since nothing then reads ring 1), the rows a
+wall singles out.
+
+Tiling.  Tiles of :func:`adjoint_tile_rows` rows (32 at the benchmark's
+width), full width, one grid step behind their input in a window with a
+strip of the tiles before and after (:func:`_walk`'s window, for nine
+arrays and read only; nothing here is placed or carried, so the walk is
+not that function's: a dozen lines of its own).  A stage
+(:func:`_adjoint_stage`) takes ``_ADJOINT_STRIPS`` strips of rows and a
+strip of halo rows either side **as one value**: a row's neighbours are
+rotations of that value along its rows, whose wrap spoils one row at
+either end for every shift in a chain, three rows of the eight.  What
+a transposed stencil needs at a row's neighbours (the tendencies'
+cotangents, the fluxes', the vorticity's) is thereby computed where it
+is read, twice the rows that are written: the kernel still runs at
+HBM's pace (2.43 ms a step at 7204 columns, 78 % of the table's
+bandwidth for its 15 passes: ``PERF.md``, PR 55), so no ring of strips
+hands rows on here.  What lies beyond the block (the window's first
+strip in a walk's first tile, the rows past the field's last, the
+lanes past its width) is whatever VMEM held: the kept fields are set to
+rest there (``h`` one, ``u``, ``v`` zero), so that a zero cotangent
+times what is made of them is zero and not a NaN.
 
 Building a kernel is set-up a user waits for, so it is kept short:
 ``jax.experimental.pallas`` is imported by :func:`pallas` where a step is
@@ -1088,6 +1142,41 @@ def _ends_meet(width):
     return list(found.items())
 
 
+def _across(lanes, register):
+    """A vector register's value in every register of a strip ``lanes``
+    wide: names registers, computes nothing."""
+    return lax.concatenate([register] * (lanes // LANES), 1)
+
+
+def _box(width, g, row_from, row_to, ring):
+    """Rows ``[row_from, row_to)`` of a block ``width`` wide less its
+    ghost columns outside ``ring``, for the rows ``g`` (their numbers on
+    one vector register's columns): the rows' predicate, made on one
+    register, in every register, and the columns' in those alone that
+    hold a column outside (the first and the last of a row; two a side
+    where the ghost columns lie astride them)."""
+    lanes = _whole_registers(width)
+    rows = lax.bitwise_and(lax.ge(g, row_from), lax.lt(g, row_to))
+    lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    lo, hi = G - ring, width - G + ring
+    if lo < 0:
+        # the columns west of the block lie at a row's last lanes
+        def columns(at):
+            inside = lax.lt(lane, hi - at)
+            if at + LANES <= lanes + lo:
+                return inside
+            return lax.bitwise_or(inside, lax.ge(lane, lanes + lo - at))
+
+        return lax.concatenate([
+            rows if at + LANES <= hi else lax.bitwise_and(rows, columns(at))
+            for at in range(0, lanes, LANES)], 1)
+    return lax.concatenate([
+        rows if lo <= at and at + LANES <= hi else functools.reduce(
+            lax.bitwise_and,
+            (rows, lax.ge(lane, lo - at), lax.lt(lane, hi - at)))
+        for at in range(0, lanes, LANES)], 1)
+
+
 @functools.lru_cache
 def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
             coriolis_beta, out=0):
@@ -1107,36 +1196,8 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
     inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
     cx, cy = nu / dx, nu / dy
 
-    def across(register):
-        """A vector register's value in every register of a strip: names
-        registers, computes nothing."""
-        return lax.concatenate([register] * (lanes // LANES), 1)
-
-    def box(g, row_from, row_to, ring):
-        """Rows ``[row_from, row_to)`` of the block less its ghost
-        columns outside ``ring``: the rows' predicate, made on one
-        register, in every register, and the columns' in those alone
-        that hold a column outside (the first and the last of a row;
-        two a side where the ghost columns lie astride them)."""
-        rows = lax.bitwise_and(lax.ge(g, row_from), lax.lt(g, row_to))
-        lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
-        lo, hi = G - ring, width - G + ring
-        if lo < 0:
-            # the columns west of the block lie at a row's last lanes
-            def columns(at):
-                inside = lax.lt(lane, hi - at)
-                if at + LANES <= lanes + lo:
-                    return inside
-                return lax.bitwise_or(inside, lax.ge(lane, lanes + lo - at))
-
-            return lax.concatenate([
-                rows if at + LANES <= hi else lax.bitwise_and(rows, columns(at))
-                for at in range(0, lanes, LANES)], 1)
-        return lax.concatenate([
-            rows if lo <= at and at + LANES <= hi else functools.reduce(
-                lax.bitwise_and,
-                (rows, lax.ge(lane, lo - at), lax.lt(lane, hi - at)))
-            for at in range(0, lanes, LANES)], 1)
+    across = functools.partial(_across, lanes)
+    box = functools.partial(_box, width)
 
     def east(x):
         return roll(x, lanes - 1, 1)
@@ -1383,3 +1444,289 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
                  summed=(0, 1, 2) if coarsen else (), coarsen=coarsen,
                  summing=summing, sums=sums, point_slabs=slabs[3:],
                  interpret=interpret)
+
+
+# what the adjoint walk's blocks take of VMEM, in a forward walk's
+# fields of five buffers: nine arrays in (two blocks and a window) and
+# six out (two blocks), 39 tiles
+_ADJOINT_FIELDS = 8
+# strips of rows an adjoint walk's stage writes at once, beside a strip
+# of halo rows either side that it computes on and throws away
+_ADJOINT_STRIPS = 2
+# a row no block has, for a wall this device does not stand at: further
+# from the block's rows than the stage's rows reach beyond them
+_NO_ROW = -4 * STRIP
+
+
+def adjoint_tile_rows(rows, width, dtype):
+    """Rows of a tile of the adjoint walk (:func:`wide_step_vjp`) over
+    its fifteen arrays, 0 where its blocks do not fit VMEM: the step's
+    derivative is then its array code's."""
+    return tile_rows(rows, width, dtype, _ADJOINT_FIELDS)
+
+
+@functools.lru_cache
+def _adjoint_stage(roll, rows, width, dtype, nu, dx, dy, dt, gravity,
+                   coriolis_f, coriolis_beta):
+    """The transpose of :func:`_stages`' ``second`` and ``first``, in
+    that order, on rows taller than a strip: ``back(scalars, g, kept,
+    cotangents)`` is handed the rows' numbers ``g`` (on one vector
+    register's columns), the kept ``h``, ``u``, ``v`` with fresh ghosts
+    and the six cotangents of a step's results on the same rows, and
+    returns the six cotangents of what the step read (the fields after
+    their exchange, the old tendencies).  A row's neighbours are
+    rotations of the rows handed in, whose wrap spoils a row at either
+    end for every shift in a chain: three rows, of the strip of halo
+    rows the caller brings either side.  Jitted and kept like the
+    stages.
+
+    Forwards (``_stages``, whose names these are): ``fe``, ``fn``, ``q``
+    and ``ke`` from the fields, the tendencies from those, ``x1 = x +
+    dt (a T + b old)`` on the interior (``u``, ``v``: on ring 1 too),
+    ``v1 = 0`` on the northern wall's row, friction.  Backwards: the
+    friction's transpose gives the cotangent of ``u1``, ``v1``; ``g_*``
+    is the tendencies' (``a dt`` of a field's on the rows the step
+    updated, and the new tendency's own on the interior); from those
+    the cotangents of the fluxes, the vorticity's quarter and the
+    energy, and from those the fields'.  The step is nonlinear in the
+    products ``q (fn + fn_e)``, ``q (fe + fe_n)``, ``hx u``, ``(h + h_n)
+    v``, the squares and the division: ``fe``, ``fn``, ``q`` and the
+    depth are made again from the kept fields, nothing else."""
+    lanes = _whole_registers(width)
+    inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
+    kx, ky = nu * dt / (dx * dx), nu * dt / (dy * dy)
+    across = functools.partial(_across, lanes)
+    box = functools.partial(_box, width)
+
+    def back(scalars, g, kept, cotangents):
+        (a, b, first_row, south_ghost_row, south_wall_row, north_wall_row,
+         north_ghost_row, inner_from, inner_to, reach_from, reach_to,
+         open_from, open_to) = scalars
+        h, u, v = kept
+        ch, cu, cv, cdh, cdu, cdv = cotangents
+        tall = h.shape[0]
+        zero = lax.full(h.shape, 0, dtype)
+
+        def east(x):
+            return roll(x, lanes - 1, 1)
+
+        def west(x):
+            return roll(x, 1, 1)
+
+        def north(x):
+            return roll(x, tall - 1, 0)
+
+        def south(x):
+            return roll(x, 1, 0)
+
+        def only(where, x):
+            return select(where, x, zero)
+
+        def unless(where, x):
+            return select(where, zero, x)
+
+        interior = box(g, inner_from, inner_to, 0)
+        # round 1's cells of u, v: ring 1 too where round 2 reads it;
+        # and the rows no wall's ghost rows are among, every column
+        reach = box(g, reach_from, reach_to, 1) if nu > 0 else interior
+        open_rows = box(g, open_from, open_to, G) if nu > 0 else interior
+        # what lies beyond the block (rows before its first in the walk's
+        # first tile, rows past its last, lanes past its width) is
+        # whatever VMEM held: the kept fields are set to rest there, so
+        # that a cotangent of zero times what is made of them is zero
+        inside = box(g, 0, rows, G)
+        h = select(inside, h, lax.full(h.shape, 1, dtype))
+        u, v = only(inside, u), only(inside, v)
+        sg, sw = across(eq(g, south_ghost_row)), across(eq(g, south_wall_row))
+        nw, ng = across(eq(g, north_wall_row)), across(eq(g, north_ghost_row))
+        wall = lax.bitwise_or(nw, sg)
+
+        # round 2 backwards: u' = u1 + dt L u1 on the interior, L the
+        # five points' with no gradient across the southern wall's face;
+        # v' zero on the northern wall's row
+        cv = unless(nw, cv)
+        if nu > 0:
+            def friction(c):
+                w = only(interior, c)
+                wk = unless(sw, w)
+                return add(c, add(
+                    mul(add(sub(west(w), add(w, w)), east(w)), kx),
+                    mul(add(sub(sub(south(w), w), wk), north(wk)), ky)))
+
+            cu, cv = friction(cu), unless(nw, friction(cv))
+
+        # round 1 backwards.  The tendencies' cotangents, and what the
+        # old ones take
+        a_dt, b_dt = mul(a, dt), mul(b, dt)
+        g_h = only(interior, add(mul(ch, a_dt), cdh))
+        g_u = only(reach, add(mul(cu, a_dt), only(interior, cdu)))
+        g_v = only(reach, add(mul(cv, a_dt), only(interior, cdv)))
+        # (with friction a result's ghost cell is a neighbour's u1, v1,
+        # so its cotangent is b dt of that neighbour's old tendency's:
+        # ring 1's is this walk's own, ring 2's only passes through here)
+        old = (only(interior, mul(ch, b_dt)), only(open_rows, mul(cu, b_dt)),
+               only(open_rows, mul(cv, b_dt)))
+
+        # the step's products again, from the kept fields
+        hx = add(h, east(h))
+        hy = add(h, north(h))
+        fe = unless(ng, mul(mul(hx, 0.5), u))
+        fn = unless(wall, mul(mul(hy, 0.5), v))
+        depth = div(lax.full(h.shape, 1, dtype),
+                    add(hx, select(nw, hx, north(hx))))
+        y = mul(add(lax.convert_element_type(sub(g, G), dtype), first_row), dy)
+        vorticity = add(
+            across(add(mul(y, coriolis_beta), coriolis_f)),
+            sub(mul(sub(east(v), v), inv_dx), mul(sub(north(u), u), inv_dy)))
+        q = unless(sg, mul(vorticity, depth))
+
+        # du = .. + qf + qf_s - (ke_e - ke) / dx, qf = q (fn + fn_e);
+        # dv = .. - qe - qe_w - (ke_n - ke) / dy, qe = q (fe + fe_n)
+        c_qf = add(g_u, north(g_u))
+        c_qe = add(g_v, east(g_v))  # less
+        q_qf, q_qe = mul(q, c_qf), mul(q, c_qe)
+        c_fe = sub(mul(sub(east(g_h), g_h), inv_dx), add(q_qe, south(q_qe)))
+        c_fn = add(mul(sub(north(g_h), g_h), inv_dy), add(q_qf, west(q_qf)))
+        c_ke = unless(ng, add(mul(sub(g_u, west(g_u)), inv_dx),
+                              mul(sub(g_v, south(g_v)), inv_dy)))
+        c_q = sub(mul(c_qf, add(fn, east(fn))), mul(c_qe, add(fe, north(fe))))
+        c_vorticity = unless(sg, mul(c_q, depth))
+        c_depth = mul(mul(c_vorticity, vorticity), depth)  # less
+        c_fe, c_fn = unless(ng, mul(c_fe, 0.5)), unless(wall, mul(c_fn, 0.5))
+        c_hx = sub(mul(u, c_fe), add(
+            add(c_depth, only(nw, c_depth)), south(unless(nw, c_depth))))
+        c_hy = mul(v, c_fn)
+        c_h = add(
+            add(add(c_hx, west(c_hx)), add(c_hy, south(c_hy))),
+            add(mul(sub(g_u, west(g_u)), gravity * inv_dx),
+                mul(sub(g_v, south(g_v)), gravity * inv_dy)))
+        c_u = add(
+            add(mul(sub(c_vorticity, south(c_vorticity)), inv_dy), mul(hx, c_fe)),
+            mul(mul(u, 0.5), add(c_ke, east(c_ke))))
+        c_v = add(
+            add(mul(sub(west(c_vorticity), c_vorticity), inv_dx), mul(hy, c_fn)),
+            mul(mul(v, 0.5), add(c_ke, north(c_ke))))
+        return (add(ch, c_h), add(cu, c_u), add(cv, c_v), *old)
+
+    return jax.jit(back)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
+                     "coriolis_beta", "interpret"))
+def wide_step_vjp(h, u, v, cotangents, is_south, is_north, first_row, a, b,
+                  *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
+                  interpret=False):
+    """The transpose of one step of :func:`wide_step` at the state it
+    started from, as one kernel ("The adjoint walk" in the module's
+    docstring).
+
+    ``h``, ``u``, ``v``: the kept fields **with fresh ghosts**, as the
+    step's first exchange left them.  ``cotangents``: of the step's six
+    results, padded like them, **all taken as they are**.  The
+    interior's go through both rounds backwards.  A ghost cell's passes
+    through to the same cell of the field it came from (all of ``h``'s,
+    a wall's ghost rows of ``u``, ``v``, and without friction all of
+    theirs are the first exchange's values; the caller's transposed
+    exchange takes them home).  With friction the ghost cells of the
+    results ``u``, ``v`` are the neighbours' round 1: on ring 1, which
+    this walk computes itself, the cotangent also goes backwards
+    through round 1 here; on ring 2, which only the neighbour
+    computes, it passes through to the field and, ``b dt`` of it, to the
+    old tendency, and **the caller has sent** ``a dt`` **of it home on
+    the new tendency's cotangent** before the call
+    (``shallow_water._outermost_ring``), which is the one thing of a
+    ghost cell that cannot be done here.  The ghost cells of the
+    tendencies' cotangents are not read: a result's are the kernel's
+    own means (ring 1) or zero.
+
+    Returns the six cotangents of ``(h, u, v)`` **after their
+    exchange**, ghost cells included, and of the old tendencies, which
+    are ``b dt`` of a field's cotangent where the step updated it: on
+    the ghost cells of ``du``, ``dv`` too, which are the neighbours' and
+    go home with the fields' through the exchange's transpose.  The scalars are
+    :func:`wide_step`'s; the caller has checked
+    :func:`adjoint_tile_rows`.
+    """
+    pl, pltpu = pallas()
+    rows, width = h.shape
+    lanes = _whole_registers(width)
+    dtype = h.dtype
+    arrays = [h, u, v, *cotangents]
+    n_in, n_out = len(arrays), len(cotangents)
+    tile = adjoint_tile_rows(rows, width, dtype)
+    tiles = -(-rows // tile)
+    strips = tile // STRIP
+    group = max(n for n in range(1, _ADJOINT_STRIPS + 1) if strips % n == 0)
+    tall = (group + 2) * STRIP
+    axes = vma_of(h) or ()
+    flags = promote_vma(jnp.stack([is_south, is_north]).astype(jnp.int32), axes)
+    floats = promote_vma(
+        jnp.stack([jnp.asarray(x, dtype) for x in (a, b, first_row)]), axes)
+
+    def kernel(flag_ref, float_ref, *refs):
+        taken, out, windows = refs[:n_in], refs[n_in:n_in + n_out], refs[n_in + n_out:]
+        i = pl.program_id(0)
+        back = _adjoint_stage(
+            pltpu.roll, rows, width, dtype, nu, dx, dy, dt, gravity,
+            coriolis_f, coriolis_beta)
+        at_south, at_north = eq(flag_ref[0], 1), eq(flag_ref[1], 1)
+
+        def row(x, where):
+            return select(where, jnp.int32(x), jnp.int32(_NO_ROW))
+
+        scalars = (
+            float_ref[0], float_ref[1], float_ref[2],
+            row(G - 1, at_south), row(G, at_south),
+            row(rows - G - 1, at_north), row(rows - G, at_north),
+            jnp.int32(G), jnp.int32(rows - G),
+            select(at_south, jnp.int32(G), jnp.int32(G - 1)),
+            select(at_north, jnp.int32(rows - G), jnp.int32(rows - G + 1)),
+            select(at_south, jnp.int32(G), jnp.int32(0)),
+            select(at_north, jnp.int32(rows - G), jnp.int32(rows)))
+        r = lax.broadcasted_iota(jnp.int32, (tall, LANES), 0)
+
+        # a window's rows: the last strip of tile i - 2, tile i - 1, and
+        # the first strip of the tile just handed in (_walk's window)
+        for ref, win in zip(taken, windows):
+            win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
+
+        @pl.when(lax.gt(i, 0))
+        def _():
+            def run(j, carry):
+                first = pl.multiple_of(mul(j, group * STRIP), STRIP)
+                # the rows' numbers in the block, from the halo strip on
+                g = add(r, add(mul(sub(i, 1), tile), sub(first, STRIP)))
+                values = [win[pl.ds(first, tall), :] for win in windows]
+                new = back(scalars, g, values[:3], values[3:])
+                for ref, x in zip(out, new):
+                    ref[pl.ds(first, group * STRIP), :] = lax.slice_in_dim(
+                        x, STRIP, tall - STRIP)
+                return carry
+
+            lax.fori_loop(0, strips // group, run, 0)
+
+        for ref, win in zip(taken, windows):
+            win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
+            win[pl.ds(STRIP, tile), :] = ref[...]
+
+    struct = union_vma_struct(h.shape, dtype, *arrays, flags, floats)
+    in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        kernel,
+        grid=(tiles + 1,),
+        in_specs=[in_smem] * 2 + [pl.BlockSpec(
+            (tile, lanes), lambda i: (lax.min(i, tiles - 1), 0))] * n_in,
+        out_specs=[pl.BlockSpec(
+            (tile, lanes), lambda i: (lax.max(i - 1, 0), 0))] * n_out,
+        scratch_shapes=[pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_in,
+        out_shape=[struct] * n_out,
+        # a cotangent is written where it was read, a tile behind
+        input_output_aliases={2 + 3 + k: k for k in range(n_out)},
+        compiler_params=pltpu.CompilerParams(
+            # in order: a step reads the window the step before left
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT * 3 // 2),
+        interpret=interpret,
+    )(flags, floats, *(promote_vma(x, axes) for x in arrays))

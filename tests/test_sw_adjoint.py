@@ -159,24 +159,31 @@ def test_two_checkpoint_levels_give_what_none_gives(mesh_shape, ghost):
 
 
 def _through_the_interpreted_kernel(monkeypatch):
-    """The step forced through the kernel, interpreted
-    (``tests/test_sw_kernels.py`` does the same); returns the list the
-    walks' ``steps`` are noted in."""
-    walks = []
-    wide_step = sw_kernels.wide_step
+    """The step forced through its kernels, interpreted, the walk's and
+    the adjoint walk's (``tests/test_sw_kernels.py`` does the same);
+    returns the list the walks' ``steps`` are noted in, and the list of
+    the adjoint kernel's calls."""
+    walks, transposed = [], []
+    wide_step, wide_step_vjp = sw_kernels.wide_step, sw_kernels.wide_step_vjp
     wide_step.clear_cache()
+    wide_step_vjp.clear_cache()
 
     def interpreted(*args, **kwargs):
         walks.append(kwargs["steps"])
         return wide_step(*args, **dict(kwargs, interpret=True))
 
+    def backwards(*args, **kwargs):
+        transposed.append(args[0].shape)
+        return wide_step_vjp(*args, **dict(kwargs, interpret=True))
+
     monkeypatch.setattr(sw_kernels, "wide_step", interpreted)
+    monkeypatch.setattr(sw_kernels, "wide_step_vjp", backwards)
     monkeypatch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: True)
     # Pallas's interpreter slices blocks at indices that vary over no
     # mesh axis, which shard_map's checker refuses
     monkeypatch.setattr(
         jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
-    return walks
+    return walks, transposed
 
 
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 1), (2, 2)])
@@ -184,16 +191,23 @@ def test_the_kernels_walk_differentiates_as_its_array_code(mesh_shape, monkeypat
     """Where the step is the kernel, ``_step_wide`` is a ``custom_vjp``:
     the kernel forwards (two steps a walk in the forward sweep, one in
     the backward sweep's second run, the first step's with one passed
-    over), its array code at the walk's input backwards.  Against plain
-    AD of the array code, to float32's rounding of the kernel."""
+    over), **the adjoint kernel** at the fields each step started from
+    backwards (``sw_kernels.wide_step_vjp``; a walk of two: the forward
+    kernel once more for the state between, the adjoint kernel twice).
+    Against plain AD of the array code, to float32's rounding of the
+    kernels."""
     comm = _comm(mesh_shape)
     cfg = sw.SWConfig(ghost=2, **CFG)
     args = _seeded(cfg.ny, cfg.nx, jnp.float32)
     want = _plain(cfg, comm)(*args)  # the array code, plain AD
-    walks = _through_the_interpreted_kernel(monkeypatch)
-    assert sw._walks_two_steps(cfg, comm)
+    walks, transposed = _through_the_interpreted_kernel(monkeypatch)
+    assert sw._walks_two_steps(cfg, comm) and sw._derives_as_kernels(cfg, comm)
     got = sw.make_gradient(
         cfg, comm, calls=CALLS, num_steps=STEPS, observe=OBSERVE)(*args)
+    # a call's steps' derivative in its scan, each call's, and the first
+    # step's: every one the adjoint kernel, on a device's padded block
+    block = tuple(n + 2 * cfg.ghost for n in cfg.local_interior(comm))
+    assert len(transposed) >= CALLS + 1 and set(transposed) == {block}
     # the forward sweep: the first step's walk (of two, one passed over)
     # and each call's (two steps, then the odd one); the backward sweep:
     # a call's steps one by one, traced once in its scan, for each call,
@@ -207,8 +221,10 @@ def test_the_kernels_walk_differentiates_as_its_array_code(mesh_shape, monkeypat
     for a, b in zip(got[1:], want[1:]):
         assert _rel(a, b) < 2e-5
     # and plain jax.value_and_grad through the same walks
+    del transposed[:]
     for a, b in zip(_plain(cfg, comm)(*args)[1:], want[1:]):
         assert _rel(a, b) < 2e-5
+    assert len(transposed) == 1 + CALLS * STEPS
 
 
 @pytest.mark.parametrize("ghost", [1, 2, 4])
@@ -234,7 +250,9 @@ def test_jax_grad_goes_through_make_multisteps_program(ghost):
 
 def test_jax_grad_goes_through_the_kernels_double_walk(monkeypatch):
     """``make_multistep`` where the step is the kernel: a walk of two
-    steps is differentiated as two steps of its array code."""
+    steps is differentiated a step at a time, the forward kernel once
+    for the state between the two and the adjoint kernel twice: no
+    array code is left on the kernel's path."""
     comm = _comm((1, 1))
     cfg = sw.SWConfig(ghost=2, **CFG)
     state = sw.make_first_step(cfg, comm)(sw.make_init(cfg, comm)())
@@ -248,11 +266,13 @@ def test_jax_grad_goes_through_the_kernels_double_walk(monkeypatch):
 
     want = jax.grad(functools.partial(
         energy, sw.make_multistep(cfg, comm, 2), state))(state.h)
-    walks = _through_the_interpreted_kernel(monkeypatch)
+    walks, transposed = _through_the_interpreted_kernel(monkeypatch)
+    monkeypatch.setattr(sw, "_walk_as_arrays", None)  # nobody calls it
     padded = sw.make_first_step(cfg, comm)(sw.make_init(cfg, comm)())
     got = jax.grad(functools.partial(
         energy, sw.make_multistep(cfg, comm, 2), padded))(padded.h)
-    assert 2 in walks
+    # the walk of two, and a walk of one for the state between its steps
+    assert walks.count(2) >= 1 and 1 in walks and len(transposed) == 2
     assert _rel(got[G:-G, G:-G], want[G:-G, G:-G]) < 2e-5
 
 

@@ -98,10 +98,12 @@ def _compiled_multistep(v5e, mesh_shape, ghost, ny, nx, steps):
 
 
 def _kernel_calls(text):
-    """Every Pallas call of a compiled program's text, in the text's
-    order, as :func:`_kernels` describes one."""
+    """Every call of the step's kernel (the forward one) in a compiled
+    program's text, in the text's order, as :func:`_kernels` describes
+    one."""
     return [_kernels(line)["wide_step"] for line in text.splitlines()
-            if "tpu_custom_call" in line and "%wide_step" in line.split("=")[0]]
+            if "tpu_custom_call" in line
+            and re.match(r"\s*(?:ROOT )?%wide_step(?:\.\d+)? =", line)]
 
 
 def _trips(text):
@@ -1018,7 +1020,7 @@ def test_a_call_that_is_not_differentiated_compiles_to_what_it_did(
     """The step's and the exchange's ``jax.custom_vjp`` leave nothing in
     a program nobody differentiates: ``make_multistep``'s compiled text
     is, instruction for instruction, the text of the same program with
-    both wrappers taken off (``_kept_at_its_start`` handing back the
+    both wrappers taken off (``_with_derivative`` handing back the
     kernel's walk, ``_transposable`` calling the exchange)."""
     from mpi4jax_tpu.parallel import halo
 
@@ -1028,7 +1030,7 @@ def test_a_call_that_is_not_differentiated_compiles_to_what_it_did(
 
     text = _compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
     monkeypatch.setattr(
-        sw, "_kept_at_its_start", lambda forward, twin, scope: forward)
+        sw, "_with_derivative", lambda forward, keep, backward, scope: forward)
     monkeypatch.setattr(
         halo, "_transposable",
         lambda forward, backward, arrs, token: forward(list(arrs), token))
@@ -1084,11 +1086,58 @@ def test_the_backward_sweep_moves_no_block_for_an_exchange(v5e, mesh_shape):
     assert _kernel_calls(forward.as_text()) and _kernel_calls(text)
 
 
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_a_steps_derivative_is_a_kernel_call(v5e, mesh_shape):
+    """Where the step is the kernel its derivative is one
+    (``sw_kernels.wide_step_vjp``): under ``sw/adjoint/step_vjp`` the
+    backward sweep's text holds the kernel's calls, the sweep's scan's
+    and the first step's, and no pass over a block: what carries that
+    scope and a block's shape is a kernel call or a write in place (the
+    exchange's, forwards over the kept fields' ghosts and transposed).
+    The array code's derivative was a hundred and forty fusions there
+    (PERF.md, PR 54)."""
+    ny, nx = 1800, 3600
+    _, backward = _compiled_gradient(v5e, mesh_shape, ny, nx, 1, 4)
+    block = f"f32[{ny + 4},{nx + 4}]"
+    text = backward.as_text()
+    computations = dict(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M))
+    calls = 0
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        shaped = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = \(?{re.escape(block)}", line)
+        if not name or "sw/adjoint/step_vjp" not in name[1] or not shaped:
+            continue
+        opcode = re.match(r".*? ([a-z][a-z\-]*)\(", line.split(" = ", 1)[1])[1]
+        if opcode in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        if opcode == "custom-call":
+            assert "tpu_custom_call" in line and "wide_step_vjp" in name[1], line
+            # the kept fields and the six cotangents in, six out in place
+            assert len(re.findall(re.escape(block), line.split(" = ", 1)[1])) == 15
+            calls += 1
+        elif opcode == "fusion":
+            body = computations[re.search(r"calls=%([\w.\-]+)", line)[1]]
+            whole = [
+                found[1] for found in re.finditer(
+                    rf"= {re.escape(block)}\S* ([a-z\-]+)\(", body)
+                if found[1] not in ("parameter", "dynamic-update-slice", "bitcast")]
+            assert not whole, (line, whole)
+        else:
+            assert opcode == "dynamic-update-slice", line
+    assert calls == 2  # the sweep's scan's and the first step's
+
+
 def test_the_cells_gradient_fits_a_chip_with_room(v5e):
     """``sw-adjoint-1chip``'s two programs at the cell's own size and
     window: the backward sweep's peak by the compiler's buffer
-    assignment under 14e9 bytes (the issue's line for taking a call
-    off the window), and over a quarter of a chip."""
+    assignment under 14e9 bytes (PR 54's line for taking a call
+    off the window), and over a quarter of a chip.  Pinned: 12.64e9,
+    of which 6.64e9 are the ``cost`` scope's broadcast of a coarse
+    cotangent over its 2 x 2 cells (``f32[1800,2,3600,2]``, two columns
+    to a tile of 128 lanes), not a checkpoint: the second level is ten
+    states of three fields since the adjoint kernel reads no more
+    (PERF.md, PR 55; ``ROADMAP.md`` S27)."""
     import json
 
     with open("perfbench/workloads/sw-adjoint-1chip.json") as f:
@@ -1099,4 +1148,12 @@ def test_the_cells_gradient_fits_a_chip_with_room(v5e):
         v5e, (1, 1), grid["ny"], grid["nx"], calls, 10)
     peak = backward.memory_analysis().peak_memory_in_bytes
     assert 0.25 * 16e9 < peak < 14e9, peak
+    assert peak == pytest.approx(12.64e9, rel=0.01)
+    kept = re.findall(r"f32\[10,3604,7204\]", backward.as_text().split("ENTRY")[1])
+    assert kept  # a call's ten states, stacked: fields, no tendencies
+    whiles = [line for line in backward.as_text().splitlines()
+              if re.search(r" while\(", line)]
+    assert whiles and all(
+        len(re.findall(r"f32\[10,3604,7204\]", line.split(" while(")[0])) == 3
+        for line in whiles)
     assert forward.memory_analysis().peak_memory_in_bytes < peak
