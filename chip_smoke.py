@@ -270,39 +270,24 @@ def child(group):
     os._exit(0 if ok else 1)
 
 
-class _Meter:
-    """Counts what jax reports while a phase runs: backend-compile
-    seconds and the persistent cache's requests, hits and writes."""
+def _compiled_since(began_ns):
+    """``(compile_s, cache)`` of what jax compiled or loaded since
+    ``began_ns``: backend-compile seconds and the persistent cache's
+    requests, hits and writes, from the library's own record of the
+    process's builds (``utils/spans.py builds``, which listens to
+    ``jax.monitoring``; this file registers nothing).  The recorder
+    keeps its newest spans: where it has dropped some, ``cache`` says
+    how many under ``dropped`` and the figures are of what is left."""
+    from mpi4jax_tpu.utils import spans
 
-    def __init__(self):
-        self.compile_s = 0.0
-        self.cache = {"requests": 0, "hits": 0, "writes": 0}
-
-    def _on_event(self, event, **_):
-        key = {
-            "/jax/compilation_cache/compile_requests_use_cache": "requests",
-            "/jax/compilation_cache/cache_hits": "hits",
-            "/jax/compilation_cache/cache_misses": "writes",
-        }.get(event)
-        if key:
-            self.cache[key] += 1
-
-    def _on_duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def __enter__(self):
-        from jax import monitoring
-
-        monitoring.register_event_listener(self._on_event)
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        return self
-
-    def __exit__(self, *exc):
-        from jax import monitoring
-
-        monitoring.unregister_event_listener(self._on_event)
-        monitoring.unregister_event_duration_listener(self._on_duration)
+    compiles = [s for s in spans.builds.spans()
+                if s.name == spans.COMPILE and s.end_ns >= began_ns]
+    cache = {key: sum(bool(s.counts[flag]) for s in compiles)
+             for key, flag in (("requests", "asked"), ("hits", "cached"),
+                               ("writes", "written"))}
+    if spans.builds.dropped:
+        cache["dropped"] = spans.builds.dropped
+    return sum(s.seconds for s in compiles), cache
 
 
 def run_phase(name, check, *, holds_device=True):
@@ -314,18 +299,19 @@ def run_phase(name, check, *, holds_device=True):
 
     import jax
 
+    import mpi4jax_tpu.utils.spans  # noqa: F401  listens from here on
+
     rec = {"phase": name, "ok": False}
-    t0 = time.perf_counter()
-    with _Meter() as meter:
-        try:
-            rec.update(check())
-            rec["ok"] = True
-        except Exception as exc:  # noqa: BLE001 — report, then fail the run
-            traceback.print_exc()
-            rec["error"] = f"{type(exc).__name__}: {exc}"[:2000]
-    rec["wall_s"] = round(time.perf_counter() - t0, 3)
-    rec["compile_s"] = round(meter.compile_s, 3)
-    rec["cache"] = meter.cache
+    began_ns = time.perf_counter_ns()
+    try:
+        rec.update(check())
+        rec["ok"] = True
+    except Exception as exc:  # noqa: BLE001 — report, then fail the run
+        traceback.print_exc()
+        rec["error"] = f"{type(exc).__name__}: {exc}"[:2000]
+    rec["wall_s"] = round((time.perf_counter_ns() - began_ns) / 1e9, 3)
+    compile_s, cache = _compiled_since(began_ns)
+    rec["compile_s"], rec["cache"] = round(compile_s, 3), cache
     # memory_stats() is None on the CPU backend
     stats = jax.devices()[0].memory_stats() if holds_device else None
     if stats:

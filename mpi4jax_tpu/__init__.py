@@ -20,7 +20,11 @@ preserving the reference's token discipline (docs/sharp-bits.rst:6-34)
 via data dependence instead of side-effect annotations.
 """
 
+import time as _time
+
 import jax as _jax
+
+_import_began_ns = _time.perf_counter_ns()  # `build/import`: the package's own, not jax's
 
 from mpi4jax_tpu.utils.jax_compat import check_jax_version as _check_jax_version
 
@@ -85,6 +89,14 @@ from mpi4jax_tpu.parallel import (
     get_default_comm,
     set_default_comm,
 )
+from mpi4jax_tpu.utils import spans as _spans
+
+# what a process pays to have the library, jax's own import apart,
+# beside what its programs' builds cost: a span of the process's
+# recorder of builds
+_spans.builds.record(
+    _spans.IMPORT, (_time.perf_counter_ns() - _import_began_ns) / 1e9,
+    module=__name__)
 
 def __getattr__(name):
     # lazy: version resolution may shell out to git (checkout installs);

@@ -398,29 +398,29 @@ _VMEM_LIMIT = 64 * 2**20
 _SUMMED_AT_ONCE = 8
 
 
+@functools.cache
 def pallas():
-    """``(pl, pltpu)``: Pallas, imported for a TPU kernel.
-
-    Call it before a step is traced: under a trace the import takes
-    half as long again (0.56 against 0.40 s from bytecode on a v5e's
-    host).  And jax 0.9's ``pallas_call`` module ends by importing the
-    interpreter of Mosaic GPU kernels and, with it, the whole of
-    ``jax.experimental.mosaic.gpu``: 0.21 s of those 0.40 s for code no
-    TPU kernel reaches.  That module expects the import to fail where
-    the GPU stack is missing and then does without, so the import is
-    declined here, for this process's first import of Pallas only: who
-    has imported Pallas before has the interpreter, and who imports
-    ``jax.experimental.pallas.mosaic_gpu`` later gets the real modules,
-    but ``pallas_call(interpret=mosaic_gpu.InterpretParams())`` is then
-    unknown to this process.
-    """
+    """``(pl, pltpu)``: Pallas, imported for a TPU kernel, once a
+    process, under a ``build/import`` span of ``utils.spans.builds``
+    that parts it from a trace it lies in.  Call it before a step is
+    traced: under a trace the import takes half as long again (0.56
+    against 0.40 s from bytecode on a v5e's host).  jax 0.9's
+    ``pallas_call`` module ends by importing the interpreter of Mosaic
+    GPU kernels and with it ``jax.experimental.mosaic.gpu``: 0.21 s of
+    those 0.40 s for code no TPU kernel reaches.  It expects that
+    import to fail where the GPU stack is missing, so the import is
+    declined here unless Pallas was imported before: who imports
+    ``pallas.mosaic_gpu`` later gets the real modules, but to this process
+    ``pallas_call(interpret=mosaic_gpu.InterpretParams())`` is unknown."""
+    from mpi4jax_tpu.utils.spans import IMPORT, builds
     gpu_interpreter = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
     decline = not {"jax.experimental.pallas", gpu_interpreter} & sys.modules.keys()
     if decline:
         sys.modules[gpu_interpreter] = None  # importing it raises ImportError
     try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
+        with builds.span(IMPORT, module="jax.experimental.pallas"):
+            from jax.experimental import pallas as pl
+            from jax.experimental.pallas import tpu as pltpu
     finally:
         if decline:
             del sys.modules[gpu_interpreter]

@@ -522,9 +522,11 @@ class Save:
     The spans of ``trace``, all under the key ``step``: on the first
     thread (``checkpoint-save``) ``checkpoint/save`` from its start to
     the rename (``cause``: the span of the caller that handed the save
-    over), inside it ``checkpoint/fetch`` a piece and
-    ``checkpoint/commit`` (the manifest and the rename), after it
-    ``checkpoint/prune``; on the writers (``checkpoint-write-<i>``)
+    over), inside it ``checkpoint/fetch`` a piece, ``checkpoint/close``
+    (the files' descriptors, once the writers have ended) and
+    ``checkpoint/commit``, which is ``checkpoint/manifest`` (the
+    manifest written) and ``checkpoint/rename`` (:meth:`Series.commit`),
+    after it ``checkpoint/prune``; on the writers (``checkpoint-write-<i>``)
     ``checkpoint/write`` a piece, its cause the ``checkpoint/save``.
     ``commit_s`` is the first's length and ``stage_s`` the end of its
     last fetch.
@@ -576,8 +578,10 @@ class Save:
                 self._stream(tmp, files, pieces, ahead_bytes, whole.id)
                 if self._error is None:
                     with span("checkpoint/commit", key=step):
-                        (tmp / MANIFEST).write_text(json.dumps(manifest))
-                        series.commit(tmp, step)
+                        with span("checkpoint/manifest", key=step):
+                            (tmp / MANIFEST).write_text(json.dumps(manifest))
+                        with span("checkpoint/rename", key=step):
+                            series.commit(tmp, step)
             if self._error is None:
                 staged_ns = self._fetched.end_ns if self._fetched else whole.start_ns
                 self.record = {
@@ -619,8 +623,9 @@ class Save:
                 self._host.put(None)
             for writer in writers:
                 writer.join()
-            while opened:
-                os.close(opened.popitem()[1][0])
+            with self._trace.span("checkpoint/close", key=self.step):
+                while opened:
+                    os.close(opened.popitem()[1][0])
 
     def _fetching(self, piece):
         self._fetched = self._trace.span(

@@ -395,8 +395,10 @@ def test_a_save_and_a_restore_keep_their_spans_in_the_recorder_they_are_given(tm
     by_name = {}
     for s in trace.spans():
         by_name.setdefault(s.name, []).append(s)
-    assert sorted(by_name) == ["caller", "checkpoint/commit", "checkpoint/fetch",
-                               "checkpoint/prune", "checkpoint/save", "checkpoint/write"]
+    assert sorted(by_name) == [
+        "caller", "checkpoint/close", "checkpoint/commit", "checkpoint/fetch",
+        "checkpoint/manifest", "checkpoint/prune", "checkpoint/rename",
+        "checkpoint/save", "checkpoint/write"]
     (saved,) = by_name["checkpoint/save"]
     assert saved.cause == caller.id and saved.key == 9 and saved.counts == {"bytes": 160}
     assert record["commit_s"] == saved.seconds
@@ -409,10 +411,19 @@ def test_a_save_and_a_restore_keep_their_spans_in_the_recorder_they_are_given(tm
     assert {s.thread for s in writes} <= {"checkpoint-write-0", "checkpoint-write-1"}
     assert all(saved.start_ns <= s.start_ns <= s.end_ns <= saved.end_ns
                for s in fetches + writes + by_name["checkpoint/commit"])
+    # which of a commit's calls stands, the day one does: the files'
+    # descriptors closed, then the manifest and the rename, each a span
+    (commit,), (closed,) = by_name["checkpoint/commit"], by_name["checkpoint/close"]
+    (wrote,), (renamed,) = by_name["checkpoint/manifest"], by_name["checkpoint/rename"]
+    assert closed.cause == saved.id and wrote.cause == renamed.cause == commit.id
+    assert {s.key for s in (closed, wrote, renamed)} == {9}
+    assert (writes[-1].end_ns <= closed.start_ns <= closed.end_ns <= commit.start_ns
+            <= wrote.start_ns <= wrote.end_ns <= renamed.start_ns
+            <= renamed.end_ns <= commit.end_ns)
     # a save that is given no recorder keeps one of its own
     ckpt.Save(series, 10, {"step": 10}, {"a.npy": (whole.shape, whole.dtype)},
               [(("a.npy", 0), _Host(whole))]).wait()
-    assert len(trace.spans()) == 12
+    assert len(trace.spans()) == 15
 
     class Broken(_FakePiece):
         def __array__(self, dtype=None, copy=None):
@@ -422,7 +433,8 @@ def test_a_save_and_a_restore_keep_their_spans_in_the_recorder_they_are_given(tm
     with pytest.raises(RuntimeError):
         ckpt.Save(series, 11, {"step": 11}, {"a.npy": ((2,), np.float32)},
                   [(("a.npy", 0), Broken(0, 8))], trace=failed).wait()
-    assert [s.name for s in failed.spans()] == ["checkpoint/fetch", "checkpoint/save"]
+    assert [s.name for s in failed.spans()] == [
+        "checkpoint/fetch", "checkpoint/close", "checkpoint/save"]
 
     sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     arrays, read_s, to_device_s = ckpt.read_pieces(

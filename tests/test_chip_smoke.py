@@ -261,6 +261,49 @@ def test_run_phase_line(capsys):
     assert bad["ok"] is False and "ZeroDivisionError" in bad["error"]
 
 
+def test_a_phase_line_counts_what_the_phase_compiled_from_the_librarys_record(
+        capsys, monkeypatch):
+    """``compile_s`` and ``cache`` keep their names and come from the
+    process's build spans (``utils/spans.py``): the file registers no
+    listener of its own, and a phase is charged what it built, not what
+    the phase before it did."""
+    import inspect
+
+    import jax
+    import jax.numpy as jnp
+
+    from mpi4jax_tpu.utils import spans
+
+    # a test worker's recorder may be full and letting its oldest spans go
+    monkeypatch.setattr(spans, "builds", spans.Recorder("mpi4jax_tpu."))
+
+    def builds_one():
+        jax.jit(lambda x: x * 5 - 2)(jnp.arange(3.0)).block_until_ready()
+        return {"built": 1}
+
+    assert chip_smoke.run_phase("builds", builds_one) is True
+    assert chip_smoke.run_phase("idle", lambda: {}) is True
+    built, idle = map(json.loads, capsys.readouterr().out.splitlines())
+    assert built["compile_s"] > 0 and set(built["cache"]) == {"requests", "hits", "writes"}
+    assert all(isinstance(n, int) for n in built["cache"].values())
+    assert built["cache"]["hits"] <= built["cache"]["requests"]
+    assert idle["compile_s"] == 0 and idle["cache"] == {"requests": 0, "hits": 0, "writes": 0}
+    assert list(built)[-3:] == ["wall_s", "compile_s", "cache"]
+    # the sum the line prints is the spans' own
+    after = spans.builds.spans()[-1].end_ns + 1
+    assert chip_smoke._compiled_since(after) == (0, {"requests": 0, "hits": 0, "writes": 0})
+    assert "register_event" not in inspect.getsource(chip_smoke)
+
+
+def test_a_phase_line_says_when_the_record_of_builds_let_spans_go(monkeypatch):
+    from mpi4jax_tpu.utils import spans
+
+    monkeypatch.setattr(spans, "builds", spans.Recorder("mpi4jax_tpu."))
+    spans.builds.dropped = 3
+    _, cache = chip_smoke._compiled_since(0)
+    assert cache == {"requests": 0, "hits": 0, "writes": 0, "dropped": 3}
+
+
 def test_main_without_a_tpu_exits_nonzero_and_runs_no_phase(
     monkeypatch, capsys
 ):

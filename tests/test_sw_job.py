@@ -1079,6 +1079,7 @@ def test_a_job_with_one_half_is_what_it_was(seeded, tmp_path, mesh_shape):
         s.name for s in halves["checkpoint"].spans() if s.thread.startswith("checkpoint-"))
     pieces = sum(len(plan) for plan in halves["checkpoint"]._plan)
     assert others == {"checkpoint/save": 2, "checkpoint/commit": 2, "checkpoint/prune": 2,
+                      "checkpoint/close": 2, "checkpoint/manifest": 2, "checkpoint/rename": 2,
                       "checkpoint/fetch": 2 * pieces, "checkpoint/write": 2 * pieces}
     assert halves["snapshot"].stats()["host_in_flight_max_bytes"] == ONE
     assert 0 < halves["checkpoint"].stats()["host_in_flight_max_bytes"] <= 4096
