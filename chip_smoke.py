@@ -527,6 +527,47 @@ def solver_adjoint_check(cfg, devices, *, calls=2, steps_per_call=5, observe=2,
     return out
 
 
+def solver_observed_check(shapes, devices, *, ghost=2, observe=2):
+    """The observation operator's transpose alone
+    (``models/shallow_water.py _observed``, under ``jax.vjp``), on one
+    device at blocks of each of ``shapes`` interior cells, against
+    numpy's ``repeat`` of the same cotangent: equal, every cell of the
+    padded block.  At the benchmark's block a fast form of it was wrong
+    on the chip where the same form was right at 516 x 1028 (jax's own
+    rule, PERF.md, PR 54): a CPU test cannot see that, so the size is
+    this check's point."""
+    import jax
+    import numpy as np
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    c, g, wrong = observe, ghost, {}
+
+    def transposed(block, coarse):
+        return jax.vjp(lambda b: sw._observed(b, g, c), block)[1](coarse)[0]
+
+    for ny, nx in shapes:
+        rng = np.random.default_rng(57)
+        coarse = (rng.normal(size=(ny // c, nx // c)) * 10.0 ** rng.integers(
+            -6, 3, size=(ny // c, nx // c))).astype(np.float32)
+        block = np.zeros((ny + 2 * g, nx + 2 * g), np.float32)
+        got = np.asarray(jax.jit(transposed)(
+            *(jax.device_put(a, devices[0]) for a in (block, coarse))))
+        want = np.pad(np.repeat(np.repeat(coarse, c, 0), c, 1)
+                      * np.float32(1.0 / (c * c)), g)
+        wrong[f"{ny}x{nx}"] = (
+            int((got != want).sum()) if got.shape == want.shape else -1)
+    out = {
+        "compared": f"the transpose of h's mean over {c}x{c} cells at ghost "
+        f"{g}, a padded block from a coarse cotangent, with numpy's repeat: "
+        "cells that differ (limit 0)",
+        "wrong_cells": wrong,
+    }
+    if any(wrong.values()):
+        raise AssertionError(f"the observation's transpose is not numpy's: {out}")
+    return out
+
+
 def _job(cfg, devices, mesh_shape, snapshot, steps_per_call=10, calls=4):
     """``make_init`` -> ``job.start`` -> ``calls`` calls through
     ``make_job``; returns the job and the snapshots its callback was
@@ -1405,6 +1446,14 @@ GROUPS = {
             "solver.job": lambda: solver_job_check(_bench_cfg(), _one()),
             "solver.restart": lambda: solver_restart_check(_bench_cfg(), _one()),
             "solver.monitor": lambda: solver_monitor_check(_bench_cfg(), _one()),
+        }),
+        # a group of its own, as solver4.adjoint is: `import chip_smoke;
+        # chip_smoke.child("solver.observed")` is a one-chip call of a
+        # minute.  At the block of the differentiated run's cell (3600x7200
+        # cells a chip) and at the size where the wrong fast form was right
+        "solver.observed": (240, {
+            "solver.observed": lambda: solver_observed_check(
+                [(3600, 7200), (512, 1024)], _one()),
         }),
         "ops": (300, {
             "ops": lambda: ops_check(_one()),

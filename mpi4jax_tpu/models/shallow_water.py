@@ -1469,8 +1469,6 @@ class _Window(NamedTuple):
 
 
 def _window(cfg, comm, num_steps, observe):
-    G = cfg.ghost
-
     if _runs_as_kernels(cfg, comm):
         # a kernel's walk keeps what its derivative reads by itself
         # (_step_wide).  A state of the sweep is read by the next step,
@@ -1500,36 +1498,67 @@ def _window(cfg, comm, num_steps, observe):
 
     def misfit(h, y):
         with _adjoint_scope(COST):
-            d = _observed(h, G, observe) - y
+            d = _observed(h, cfg.ghost, observe) - y
             return 0.5 * jnp.sum(d * d)
 
     return _Window(first, call, step_by_step, misfit)
 
 
+def _spread(coarse, ghost, coarsen):
+    """The transpose of :func:`_block_mean`: a coarse cell's cotangent,
+    over ``coarsen`` squared, to each cell it is the mean of, and
+    nothing to the ghost ring; ``[ny / c, nx / c]`` to the padded
+    block's ``[ny + 2 G, nx + 2 G]``.
+
+    A row's columns are copied ``c`` times **by a matrix product**: 128
+    coarse columns, a vector register's, times a 0/1 matrix ``[128,
+    128 c]`` at ``precision=HIGHEST``, which is exact (each element of
+    the result is one product by one; a TPU's three bfloat16 parts of a
+    float32 add up to it again), so no array has ``c`` for its minor
+    dimension.  The rows are a broadcast along a major axis.  Written
+    as the definition, ``broadcast_to(coarse[:, None, :, None], (ny, c,
+    nx, c)).reshape(...)``, the TPU compiler laid ``f32[1800,2,3600,2]``
+    two columns to a tile of 128 lanes: 6.64e9 bytes and 18.3 ms for a
+    result of 104e6, where this takes 1.07 ms (``PERF.md``, PR 58;
+    ``tests/test_sw_observed.py`` holds the two equal bit for bit).
+    The one difference: a cotangent that is not finite spoils the 128
+    columns of its row that it is multiplied beside."""
+    c = coarsen
+    if c == 1:
+        return jnp.pad(coarse, ghost)
+    ny, nx = coarse.shape
+    lanes = sw_kernels.LANES
+    registers = -(-nx // lanes)
+    copies = (jnp.arange(lanes * c)[None, :] // c
+              == jnp.arange(lanes)[:, None]).astype(coarse.dtype)
+    rows = jnp.pad(coarse, ((0, 0), (0, registers * lanes - nx)))
+    rows = lax.dot_general(
+        rows.reshape(ny, registers, lanes), copies, (((2,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST)
+    # the division where the cells are written, not a pass before
+    rows = rows.reshape(ny, registers * lanes * c)[:, :nx * c] * jnp.asarray(
+        1.0 / (c * c), rows.dtype)
+    cells = jnp.broadcast_to(rows[:, None, :], (ny, c, nx * c))
+    return jnp.pad(cells.reshape(ny * c, nx * c), ghost)
+
+
 def _observed(block, ghost, coarsen):
     """:func:`_block_mean` as an observation operator, with its
-    transpose written out: a coarse cell's cotangent, over ``coarsen``
-    squared, to each cell it is the mean of, and nothing to the ghost
-    ring.  jax's own rule for a window's sum gives the same as a
-    window's sum over the cotangent dilated, which the TPU compiler
-    makes one ``reduce-window`` with a base dilation; on a v5e that read
-    a gradient uncorrelated with the cost's slope at 3604 x 7204 and
-    the right one at 516 x 1028 (PERF.md, PR 54).  Broadcast, reshape
-    and pad are what every program here uses."""
+    transpose written out (:func:`_spread`).  Not jax's own rule: for a
+    window's sum that is a window's sum over the cotangent dilated,
+    which the TPU compiler makes one ``reduce-window`` with a base
+    dilation; on a v5e that read a gradient uncorrelated with the
+    cost's slope at 3604 x 7204 and the right one at 516 x 1028
+    (PERF.md, PR 54; as wrong in PR 57's stand-alone run: relative L2
+    1.12 from numpy's there, equal to it at the small size)."""
     c = coarsen
 
     @jax.custom_vjp
     def observe(block):
         return _block_mean(block, ghost, c)
 
-    def transposed(_, coarse):
-        ny, nx = coarse.shape
-        cells = jnp.broadcast_to(
-            coarse[:, None, :, None], (ny, c, nx, c)).reshape(ny * c, nx * c)
-        # the division where the cells are written, not a pass before
-        return (jnp.pad(cells * jnp.asarray(1.0 / (c * c), cells.dtype), ghost),)
-
-    observe.defvjp(lambda block: (observe(block), None), transposed)
+    observe.defvjp(lambda block: (observe(block), None),
+                   lambda _, coarse: (_spread(coarse, ghost, c),))
     return observe(block)
 
 

@@ -103,6 +103,21 @@ def test_solver_adjoint_check_tiny():
             cfg, cpu, calls=2, steps_per_call=3, tol=0.0)
 
 
+def test_solver_observed_check_tiny(monkeypatch):
+    cpu = jax.devices("cpu")
+    out = chip_smoke.solver_observed_check([(24, 48), (16, 20)], cpu)
+    assert out["wrong_cells"] == {"24x48": 0, "16x20": 0}
+    assert "solver.observed" in chip_smoke.GROUPS[1]["solver.observed"][1]
+    # a transpose that is not numpy's is refused
+    monkeypatch.setattr(
+        sw, "_spread",
+        lambda coarse, ghost, c: jax.numpy.zeros(
+            (c * coarse.shape[0] + 2 * ghost, c * coarse.shape[1] + 2 * ghost),
+            coarse.dtype))
+    with pytest.raises(AssertionError, match="not numpy's"):
+        chip_smoke.solver_observed_check([(24, 48)], cpu)
+
+
 def test_solver_output_restart_check_tiny():
     cpu = jax.devices("cpu")
     cfg = sw.SWConfig(ny=24, nx=48, ghost=2)

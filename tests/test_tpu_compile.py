@@ -1128,27 +1128,37 @@ def test_a_steps_derivative_is_a_kernel_call(v5e, mesh_shape):
     assert calls == 2  # the sweep's scan's and the first step's
 
 
-def test_the_cells_gradient_fits_a_chip_with_room(v5e):
+def _the_cells_gradient(v5e):
     """``sw-adjoint-1chip``'s two programs at the cell's own size and
-    window: the backward sweep's peak by the compiler's buffer
-    assignment under 14e9 bytes (PR 54's line for taking a call
-    off the window), and over a quarter of a chip.  Pinned: 12.64e9,
-    of which 6.64e9 are the ``cost`` scope's broadcast of a coarse
-    cotangent over its 2 x 2 cells (``f32[1800,2,3600,2]``, two columns
-    to a tile of 128 lanes), not a checkpoint: the second level is ten
-    states of three fields since the adjoint kernel reads no more
-    (PERF.md, PR 55; ``ROADMAP.md`` S27)."""
+    window: ``(forward, backward, calls)``."""
     import json
 
     with open("perfbench/workloads/sw-adjoint-1chip.json") as f:
         grid = json.load(f)["grid"]
     with open("perfbench/configs/shallow-water-adjoint.json") as f:
         calls = json.load(f)["window"]["calls"]
-    forward, backward = _compiled_gradient(
-        v5e, (1, 1), grid["ny"], grid["nx"], calls, 10)
+    return (*_compiled_gradient(v5e, (1, 1), grid["ny"], grid["nx"], calls, 10),
+            calls)
+
+
+def test_the_cells_gradient_fits_a_chip_with_room(v5e):
+    """``sw-adjoint-1chip``'s two programs at the cell's own size and
+    window: the backward sweep's peak by the compiler's buffer
+    assignment under 14e9 bytes (PR 54's line for taking a call
+    off the window), and over a quarter of a chip.  Pinned: 9.45e9
+    (12.64e9 until PR 58, 6.64e9 of them the ``cost`` scope's broadcast
+    of a coarse cotangent over its 2 x 2 cells, which a matrix product
+    spreads since).  What it is made of stands inside a call's scan: arguments
+    3.08e9 (the four call starts' 24 arrays, the observations, the last
+    ``h``, the three fields), results 0.32e9, temporaries 6.27e9: the
+    second level, three stacks of ten kept fields (3.12e9; fields, no
+    tendencies, since the adjoint kernel reads no more), and some
+    thirty blocks of cotangents, carries and copies round the loop
+    (PERF.md, PR 58; ``ROADMAP.md`` S29)."""
+    forward, backward, _calls = _the_cells_gradient(v5e)
     peak = backward.memory_analysis().peak_memory_in_bytes
     assert 0.25 * 16e9 < peak < 14e9, peak
-    assert peak == pytest.approx(12.64e9, rel=0.01)
+    assert peak == pytest.approx(9.45e9, rel=0.01)
     kept = re.findall(r"f32\[10,3604,7204\]", backward.as_text().split("ENTRY")[1])
     assert kept  # a call's ten states, stacked: fields, no tendencies
     whiles = [line for line in backward.as_text().splitlines()
@@ -1157,3 +1167,42 @@ def test_the_cells_gradient_fits_a_chip_with_room(v5e):
         len(re.findall(r"f32\[10,3604,7204\]", line.split(" while(")[0])) == 3
         for line in whiles)
     assert forward.memory_analysis().peak_memory_in_bytes < peak
+
+
+def test_the_cells_sweep_spreads_a_coarse_cotangent_by_a_matrix_product(v5e):
+    """The transpose of the observation operator in the backward sweep
+    at the cell's size (``shallow_water._spread``): for each observed
+    state one matrix product at the highest precision, a register of
+    coarse columns times the 0/1 matrix, every one under
+    ``sw/adjoint/cost`` (``adjoint_device_share.sw`` books it there, and
+    ``adjoint_hbm_roofline_share``'s sweep is handed nothing new), as
+    is every instruction that holds one of the spread's arrays (the
+    coarse rows in registers, the rows spread, the rows copied).  Under
+    that scope the text holds no array whose last dimension is 2 (the
+    broadcast's ``f32[1800,2,3600,2]`` lay two columns to a tile of 128
+    lanes: 6.64e9 bytes, 18 ms a transpose; PERF.md, PR 58) and no
+    ``reduce-window`` with a base dilation (jax's own rule for a
+    window's sum, wrong at this size on a v5e: PERF.md, PR 54)."""
+    _forward, backward, calls = _the_cells_gradient(v5e)
+    text = backward.as_text()
+    products, under_cost = 0, 0
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not name or " = " not in line:
+            continue
+        body = line.split(" = ", 1)[1]
+        shapes = re.findall(r"[a-z]+\d*\[([\d,]+)\]", body.split(", metadata=")[0])
+        if {"1800,29,128", "1800,29,256", "1800,2,7200"} & set(shapes):
+            assert "sw/adjoint/cost" in name[1], line
+        if "sw/adjoint/cost" not in name[1]:
+            assert " convolution(" not in line, line
+            continue
+        under_cost += 1
+        assert not [s for s in shapes if s.split(",")[-1] == "2"], line
+        if " reduce-window(" in line:
+            assert "lhs_dilate" not in line, line
+        if " convolution(" in line:
+            assert "operand_precision={highest,highest}" in line, line
+            assert body.startswith("f32[1800,29,256]"), line
+            products += 1
+    assert under_cost > products == calls + 1
