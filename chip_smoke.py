@@ -474,6 +474,26 @@ def solver_invariance_check(cfg, devices, *, steps_per_call=25,
     return out
 
 
+def _window_inputs(cfg, comm, mesh_shape, calls, observe, seed):
+    """``(fields, obs, rng)`` of a differentiated window: the jet's own
+    interior fields, observations a seeded way off the observed ``h``
+    they start with, and the generator, for what else a check draws."""
+    import numpy as np
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    state = sw.make_init(cfg, comm)()
+    fields = tuple(
+        _interior(getattr(state, k), cfg.ghost, mesh_shape)
+        for k in ("h", "u", "v"))
+    coarse = fields[0].reshape(
+        cfg.ny // observe, observe, cfg.nx // observe, observe
+    ).mean(axis=(1, 3))
+    rng = np.random.default_rng(seed)
+    noise = rng.normal(size=(calls + 1, *coarse.shape))
+    return fields, (coarse + 0.05 * noise).astype(np.float32), rng
+
+
 def solver_adjoint_check(cfg, devices, *, calls=2, steps_per_call=5, observe=2,
                          tol=1e-4):
     """The gradient of a small window on 2x2 against the gradient of the
@@ -493,16 +513,8 @@ def solver_adjoint_check(cfg, devices, *, calls=2, steps_per_call=5, observe=2,
         )
         comm = m.MeshComm.from_mesh(mesh)
         if fields is None:
-            state = sw.make_init(cfg, comm)()
-            fields = tuple(
-                _interior(getattr(state, k), cfg.ghost, mesh_shape)
-                for k in ("h", "u", "v"))
-            coarse = fields[0].reshape(
-                cfg.ny // observe, observe, cfg.nx // observe, observe
-            ).mean(axis=(1, 3))
-            noise = np.random.default_rng(54).normal(
-                size=(calls + 1, *coarse.shape))
-            obs = (coarse + 0.05 * noise).astype(np.float32)
+            fields, obs, _ = _window_inputs(
+                cfg, comm, mesh_shape, calls, observe, seed=54)
         out = sw.make_gradient(
             cfg, comm, calls=calls, num_steps=steps_per_call, observe=observe
         )(*fields, obs)
@@ -524,6 +536,80 @@ def solver_adjoint_check(cfg, devices, *, calls=2, steps_per_call=5, observe=2,
     }
     if not all(np.isfinite(a).all() for a in four) or max(rel.values()) > tol:
         raise AssertionError(f"decomposition changes the gradient: {out}")
+    return out
+
+
+def solver_tangent_check(cfg, devices, ref_devices, *, calls=2, steps_per_call=5,
+                         observe=2, weight=0.11, tol=2e-4):
+    """The linearised window on ``devices`` (2x2 where there are four,
+    else 1x1) against the same on ``ref_devices``' first as 1x1
+    (``models/shallow_water.py make_product``): ``H M p`` by the
+    tangent-linear sweep and ``A p`` by the adjoint sweep of it, and the
+    adjoint test ``<M p, w> = <p, M^T w>`` on ``devices``' own two
+    programs.  On one chip against the CPU backend the kernel's walk and
+    its written-out tangent stand against the array code and jax's own
+    rules; on four chips against one the exchange's tangent crosses
+    chips on the one side and wraps onto the same block on the other.
+    The jet's own fields, observations a seeded way off what the window
+    makes of them, a seeded direction."""
+    import jax
+    import numpy as np
+
+    import mpi4jax_tpu as m
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    how = dict(calls=calls, num_steps=steps_per_call, observe=observe)
+
+    def product(mesh_shape, some, made=None):
+        mesh = jax.make_mesh(
+            mesh_shape, ("y", "x"), axis_types=_auto(2), devices=some
+        )
+        comm = m.MeshComm.from_mesh(mesh)
+        if made is None:
+            fields, obs, rng = _window_inputs(
+                cfg, comm, mesh_shape, calls, observe, seed=59)
+            made = (fields, obs,
+                    tuple(rng.normal(size=a.shape).astype(np.float32)
+                          for a in fields),
+                    rng.normal(size=obs.shape).astype(np.float32))
+        fields, obs, p, w = made
+        on_mesh = jax.sharding.NamedSharding(mesh, jax.P("y", "x"))
+        stacked = jax.sharding.NamedSharding(mesh, jax.P(None, "y", "x"))
+        fields = tuple(jax.device_put(a, on_mesh) for a in fields)
+        p = tuple(jax.device_put(a, on_mesh) for a in p)
+        _, starts, _ = sw.make_gradient(cfg, comm, **how).forward(
+            *fields, jax.device_put(obs, stacked))
+        run = sw.make_product(cfg, comm, weight=weight, **how)
+        seen = run.tangent(*fields, starts, *p)
+        back = run.adjoint(*fields, starts, jax.device_put(w, stacked))
+        there = float(np.vdot(np.asarray(seen), w))
+        home = sum(float(np.vdot(np.asarray(a), np.asarray(b)))
+                   for a, b in zip(p, back))
+        q = run(*fields, starts, *p)
+        return made, np.asarray(seen), [np.asarray(a) for a in q], (there, home)
+
+    mesh_shape = (2, 2) if len(devices) >= 4 else (1, 1)
+    made, seen, q, (there, home) = product(
+        mesh_shape, devices[:mesh_shape[0] * mesh_shape[1]])
+    _, want_seen, want_q, _ = product((1, 1), ref_devices[:1], made)
+
+    def rel(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    out = {
+        "compared": f"{cfg.ny}x{cfg.nx} ghost {cfg.ghost}: H M p and A p of a "
+        f"window of {1 + calls * steps_per_call} steps, h observed over "
+        f"{observe}x{observe} cells, weight {weight}: "
+        f"{mesh_shape[0]}x{mesh_shape[1]} on {devices[0].platform} with 1x1 on "
+        f"{ref_devices[0].platform}, relative L2 (tol {tol}); the adjoint "
+        "test on the former's two sweeps",
+        "rel_l2": {"tangent": rel(seen, want_seen),
+                   **{k: rel(a, b) for k, a, b in zip("huv", q, want_q)}},
+        "adjoint_test": [there, home, abs(there - home) / abs(there)],
+    }
+    finite = all(np.isfinite(a).all() for a in (seen, *q))
+    if not finite or max(out["rel_l2"].values()) > tol or out["adjoint_test"][2] > tol:
+        raise AssertionError(f"the linearised window is not its reference's: {out}")
     return out
 
 
@@ -1395,6 +1481,20 @@ def _all():
     return jax.devices()
 
 
+def _cpu():
+    import jax
+
+    return jax.devices("cpu")
+
+
+def _small_cfg():
+    """A block of 256x512 of the benchmark's cells, the kernel's
+    schedule."""
+    from dataclasses import replace
+
+    return replace(_bench_cfg(), ny=256, nx=512)
+
+
 def _bench_cfg():
     from mpi4jax_tpu.models import shallow_water as sw
 
@@ -1455,6 +1555,13 @@ GROUPS = {
             "solver.observed": lambda: solver_observed_check(
                 [(3600, 7200), (512, 1024)], _one()),
         }),
+        # a group of its own too: `chip_smoke.child("solver.tangent")` is a
+        # one-chip call.  The kernel's walk and its written-out tangent and
+        # transpose at a small block against the CPU backend's array code
+        "solver.tangent": (300, {
+            "solver.tangent": lambda: solver_tangent_check(
+                _small_cfg(), _one(), _cpu()),
+        }),
         "ops": (300, {
             "ops": lambda: ops_check(_one()),
             "ops.grad": lambda: grad_check(_one()),
@@ -1501,6 +1608,13 @@ GROUPS = {
         "solver4.adjoint": (420, {
             "solver4.adjoint": lambda: solver_adjoint_check(
                 _refined(_bench_cfg(), 2), _all()
+            ),
+        }),
+        # the linearised window likewise, at the same block (S28): the
+        # exchange's tangent over the wire, 2x2 against 1x1
+        "solver4.tangent": (420, {
+            "solver4.tangent": lambda: solver_tangent_check(
+                _refined(_bench_cfg(), 2), _all(), _all()
             ),
         }),
         "ops4": (300, {"ops4": lambda: ops_check(_all()[:4])}),

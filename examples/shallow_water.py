@@ -27,6 +27,11 @@ Usage:
     # observations of a truth run by 5 steps of steepest descent
     python examples/shallow_water.py --assimilate 5
 
+    # the run that is linearised: the same fit by incremental 4D-Var, 5
+    # iterations of its inner loop (a tangent-linear sweep and an
+    # adjoint sweep a conjugate-gradient iteration)
+    python examples/shallow_water.py --incremental 5
+
 Every mode but --benchmark builds `SWConfig()` with its default
 `ghost=1`: upstream's layout, (ny+2, nx+2) arrays a device, and
 upstream's step as written, array code with one halo exchange after
@@ -48,9 +53,9 @@ import numpy as np
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 
-def assimilate(cfg, comm, iterations, num_steps, calls=4, observe=2):
-    """The twin experiment of ``--assimilate``: returns the costs."""
-    import jax
+def twin_experiment(cfg, comm, num_steps, calls, observe):
+    """``(guess, obs)``: observations of ``h`` of a truth run, and the
+    first guess, the balanced jet without the demo's perturbation."""
     import jax.numpy as jnp
 
     from mpi4jax_tpu.models import shallow_water as sw
@@ -72,8 +77,16 @@ def assimilate(cfg, comm, iterations, num_steps, calls=4, observe=2):
     x = (jnp.arange(cfg.nx, dtype=jnp.float32) * cfg.dx)[None, :]
     bump = 0.2 * jnp.sin(x / cfg.length_x * 10 * jnp.pi) * jnp.cos(
         y / cfg.length_y * 8 * jnp.pi)
-    guess = (truth[0] - bump.astype(truth[0].dtype), truth[1], truth[2])
+    return (truth[0] - bump.astype(truth[0].dtype), truth[1], truth[2]), obs
 
+
+def assimilate(cfg, comm, iterations, num_steps, calls=4, observe=2):
+    """The twin experiment of ``--assimilate``: returns the costs."""
+    import jax
+
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    guess, obs = twin_experiment(cfg, comm, num_steps, calls, observe)
     fit = sw.Descent(cfg, comm, calls=calls, num_steps=num_steps, observe=observe)
     # one step length for the run, under what the roughest direction
     # of the cost allows
@@ -93,6 +106,34 @@ def assimilate(cfg, comm, iterations, num_steps, calls=4, observe=2):
     final = float(fit.gradient(*fit.fields, obs)[0][0, 0])
     print(f"after {iterations} steps: cost {final:.6g}")
     jax.block_until_ready(fit.fields)
+    return costs + [final]
+
+
+def incremental(cfg, comm, iterations, num_steps, calls=4, observe=2,
+                weight=0.11):
+    """The twin experiment of ``--incremental``: the quadratic cost
+    before the loop and after each iteration, then the nonlinear misfit
+    at the first guess plus the increment."""
+    from mpi4jax_tpu.models import shallow_water as sw
+
+    guess, obs = twin_experiment(cfg, comm, num_steps, calls, observe)
+    fit = sw.InnerLoop(cfg, comm, calls=calls, num_steps=num_steps,
+                       observe=observe, weight=weight, iterations=iterations)
+    print(
+        f"incremental: a window of {1 + calls * num_steps} steps, h observed "
+        f"over {observe}x{observe} cells {calls + 1} times; background "
+        f"weight {weight:g}",
+        file=sys.stderr,
+    )
+    fit.linearise(*guess, obs)  # the outer loop
+    fit.iterate(iterations)  # the inner loop: a tangent and an adjoint sweep each
+    fit.wait()
+    costs = fit.costs()
+    for i, c in enumerate(costs):
+        print(f"iteration {i}: quadratic cost {c:.6g}")
+    final = float(fit.gradient.forward(
+        *(a + d for a, d in zip(guess, fit.increment())), obs)[0][0, 0])
+    print(f"the misfit at the first guess plus the increment: {final:.6g}")
     return costs + [final]
 
 
@@ -176,6 +217,18 @@ def main(argv=None):
         "through every step and every halo exchange of the window; "
         "prints the cost before each step",
     )
+    p.add_argument(
+        "--incremental",
+        type=int,
+        default=0,
+        metavar="N",
+        help="the same twin experiment by incremental 4D-Var (Courtier, "
+        "Thepaut and Hollingsworth 1994): the nonlinear window once from "
+        "the first guess, then N iterations of conjugate gradients on the "
+        "quadratic cost in the increment, each one tangent-linear sweep "
+        "(forward mode through every step and every halo exchange) and "
+        "one adjoint sweep; prints the quadratic cost after each",
+    )
     args = p.parse_args(argv)
 
     import jax
@@ -212,6 +265,8 @@ def main(argv=None):
 
     if args.assimilate:
         return assimilate(cfg, comm, args.assimilate, args.multistep)
+    if args.incremental:
+        return incremental(cfg, comm, args.incremental, args.multistep)
 
     gather = None
     if args.plot or args.animate:

@@ -48,7 +48,7 @@ from jax import lax
 
 from mpi4jax_tpu.models import sw_kernels
 from mpi4jax_tpu.ops import reductions
-from mpi4jax_tpu.ops._core import SCOPE_PREFIX, as_token
+from mpi4jax_tpu.ops._core import SCOPE_PREFIX, as_token, both_modes
 from mpi4jax_tpu.ops.allreduce import allreduce
 from mpi4jax_tpu.ops.collectives import allgather, scan
 from mpi4jax_tpu.parallel.halo import (
@@ -68,6 +68,11 @@ __all__ = [
     "make_gradient",
     "make_descent_step",
     "Descent",
+    "make_tangent",
+    "make_adjoint",
+    "make_product",
+    "make_inner_step",
+    "InnerLoop",
     "Snapshot",
     "Checkpoint",
     "Monitor",
@@ -555,8 +560,24 @@ def _wall_v_wide(v, is_north):
     return jnp.where(is_north, v.at[-3, :].set(0.0), v)
 
 
+def _scatter_inside(a, inner, *, G=2):
+    return a.at[G:-G, G:-G].add(inner)
+
+
+def _add_inside(a, inner, *, G=2):
+    """:func:`_scatter_inside` as a slice, a sum and a write in place,
+    which is what XLA makes of that scatter, for the array code that is
+    pushed forwards (:func:`_walk_as_arrays`): the scatter's own ``add``
+    is an instruction of a nested computation, and inside a loop's body
+    jax 0.9 names such an instruction without the scopes around it
+    (``jvp()/add`` in a tangent sweep: ``tests/test_sw_tangent.py``), so
+    a trace could not say whose it is.  The step's own array code keeps
+    the scatter: its programs are what they were."""
+    return lax.dynamic_update_slice(a, a[G:-G, G:-G] + inner, (G, G))
+
+
 def _tendency_round(h, u, v, dh, du, dv, cfg, comm, is_south, is_north,
-                    first_step):
+                    first_step, add=_scatter_inside):
     """Round 1 of :func:`_step_wide` as array code: the tendencies of
     ``h``, ``u`` and ``v`` (ghosts fresh) from fluxes, potential
     vorticity and kinetic energy recomputed one ring into the ghost
@@ -615,19 +636,19 @@ def _tendency_round(h, u, v, dh, du, dv, cfg, comm, is_south, is_north,
 
     # --- AB2 update (interior) ---
     if first_step:
-        h = h.at[G:-G, G:-G].add(dt * dh_new)
-        u = u.at[G:-G, G:-G].add(dt * du_new)
-        v = v.at[G:-G, G:-G].add(dt * dv_new)
+        h = add(h, dt * dh_new)
+        u = add(u, dt * du_new)
+        v = add(v, dt * dv_new)
     else:
         a, b = cfg.ab_a, cfg.ab_b
-        h = h.at[G:-G, G:-G].add(dt * (a * dh_new + b * dh))
-        u = u.at[G:-G, G:-G].add(dt * (a * du_new + b * du))
-        v = v.at[G:-G, G:-G].add(dt * (a * dv_new + b * dv))
+        h = add(h, dt * (a * dh_new + b * dh))
+        u = add(u, dt * (a * du_new + b * du))
+        v = add(v, dt * (a * dv_new + b * dv))
     v = _wall_v_wide(v, is_north)
     return h, u, v, dh_new, du_new, dv_new
 
 
-def _viscosity_round(u, v, cfg, is_south, is_north):
+def _viscosity_round(u, v, cfg, is_south, is_north, add=_scatter_inside):
     """Round 2 of :func:`_step_wide` as array code: lateral friction of
     ``u`` and ``v`` (ghosts fresh) on the interior, then ``v = 0`` on the
     northern wall row.  The definition: what every backend but the TPU
@@ -643,9 +664,8 @@ def _viscosity_round(u, v, cfg, is_south, is_north):
         gy = nu * (V(a, 1, 1, 0) - V(a, 1)) / dy
         gx = _zero_wall_rows(gx, is_south, is_north)
         gy = _zero_wall_rows(gy, is_south, is_north)
-        return a.at[G:-G, G:-G].add(
-            dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy)
-        )
+        return add(
+            a, dt * ((_i(gx) - _w(gx)) / dx + (_i(gy) - _s(gy)) / dy))
 
     return friction(u), _wall_v_wide(friction(v), is_north)
 
@@ -823,10 +843,12 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
         how = dict(cfg=cfg, comm=comm, first_step=first_step, steps=steps)
         if _derives_as_kernels(cfg, comm):
             # the derivative of a walk is the adjoint kernel's, a step at
-            # a time, at the fields the step started from
+            # a time, at the fields the step started from; its tangent
+            # the array code's there
             state, token = _with_derivative(
                 forward,
                 partial(_kept_for_the_kernel, first_step=first_step, steps=steps),
+                partial(_walk_forwards, **how),
                 partial(_walk_backwards, read_whole=read_whole, **how),
                 scope)(*operands)
         else:
@@ -840,10 +862,13 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
     return _step_wide_arrays(state, cfg, comm, first_step, token)
 
 
-def _step_wide_arrays(state, cfg, comm, first_step, token):
+def _step_wide_arrays(state, cfg, comm, first_step, token,
+                      add=_scatter_inside):
     """:func:`_step_wide` as array code: five exchanges and both rounds
     (plain jax, which differentiates it by its own rules).  Returns
-    ``((state, ()), token)``."""
+    ``((state, ()), token)``.  ``add`` adds a round's update into a
+    block's interior (:func:`_add_inside` where the code is pushed
+    forwards)."""
     G = 2
     per = (False, True)
     is_north, is_south = _wall_masks(comm)
@@ -855,13 +880,13 @@ def _step_wide_arrays(state, cfg, comm, first_step, token):
     v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
 
     h, u, v, dh, du, dv = _tendency_round(
-        h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step)
+        h, u, v, dh, du, dv, cfg, comm, is_south, is_north, first_step, add)
 
     # --- round 2: refresh u/v ghosts for the viscosity stencils ---
     if cfg.lateral_viscosity > 0:
         u, token = halo_exchange_2d(u, comm, periodic=per, token=token, width=G)
         v, token = halo_exchange_2d(v, comm, periodic=per, token=token, width=G)
-        u, v = _viscosity_round(u, v, cfg, is_south, is_north)
+        u, v = _viscosity_round(u, v, cfg, is_south, is_north, add)
 
     return (SWState(h, u, v, dh, du, dv), ()), token
 
@@ -921,50 +946,54 @@ def _kernel_walk(state, token, sums, summing, *, cfg, comm, first_step, steps,
 # (`forward`), a call's steps run again with every state kept
 # (`recompute`), one step's derivative (`step_vjp`: the array code of
 # the step run at a kept state and then backwards, its exchanges the
-# adjoint exchange of parallel/halo.py), the misfit (`cost`) and the
-# descent step (`update`).  In a backward sweep jax wraps what it
-# transposes in ``transpose(jvp(...))``: the innermost
+# adjoint exchange of parallel/halo.py), the misfit (`cost`), the
+# descent step or a conjugate-gradient iteration's vector updates
+# (`update`) and the tangent-linear sweep (`tangent`: forward mode
+# through the window's steps and every exchange in them).  In a backward
+# sweep jax wraps what it transposes in ``transpose(jvp(...))``, in a
+# tangent sweep what it pushes forwards in ``jvp(...)``: the innermost
 # ``sw/adjoint/<phase>`` of an ``op_name`` says whose an instruction is.
 ADJOINT_SCOPE = f"{STEP_SCOPE}/adjoint"
-FORWARD, RECOMPUTE, STEP_VJP, COST, UPDATE = (
-    "forward", "recompute", "step_vjp", "cost", "update")
+FORWARD, RECOMPUTE, STEP_VJP, COST, UPDATE, TANGENT = (
+    "forward", "recompute", "step_vjp", "cost", "update", "tangent")
 
 
 def _adjoint_scope(phase):
     return jax.named_scope(f"{ADJOINT_SCOPE}/{phase}")
 
 
-def _with_derivative(forward, keep, backward, scope):
+def _with_derivative(forward, keep, tangent, backward, scope):
     """``forward`` (operands -> results) as a function whose derivative
-    is ``backward(keep(*operands), cotangents)``, run under ``scope``:
-    what ``keep`` returns is all that is held for the backward pass."""
-    kept = jax.custom_vjp(forward)
-
+    is written out in both modes (``ops/_core.py both_modes``):
+    backwards ``backward(keep(*operands), cotangents)``, run under
+    ``scope``; forwards ``tangent(keep(*operands), *tangents)``.  What
+    ``keep`` returns is all that is held for either."""
     def under_scope(held, cotangents):
         with jax.named_scope(scope):
             return backward(held, cotangents)
 
-    kept.defvjp(lambda *operands: (forward(*operands), keep(*operands)),
-                under_scope)
-    return kept
+    return both_modes(forward, keep, tangent, under_scope)
 
 
 def _kept_at_its_start(forward, twin, scope):
     """``forward`` (operands -> results) as a function whose derivative
-    is ``twin``'s, taken where the call started: the one thing kept for
-    the backward pass is the call's operands, and the backward pass runs
-    ``twin`` there forwards and backwards, under ``scope``.  With
-    ``twin`` the function itself this is ``jax.checkpoint`` with a name
-    on its backward half; with another program of the same function (a
-    kernel's array code; a call's steps one by one where ``forward``
-    walks two at a time) it is how that function gets a derivative or a
-    cheaper one to keep."""
+    is ``twin``'s, taken where the call started: the one thing kept is
+    the call's operands, the backward pass runs ``twin`` there forwards
+    and backwards, under ``scope``, and a tangent is ``jax.jvp`` of
+    ``twin`` there.  With ``twin`` the function itself this is
+    ``jax.checkpoint`` with a name on its backward half; with another
+    program of the same function (a kernel's array code; a call's steps
+    one by one where ``forward`` walks two at a time) it is how that
+    function gets a derivative or a cheaper one to keep."""
+    def tangent(operands, *tangents):
+        return jax.jvp(twin, operands, tangents)[1]
+
     def backward(operands, cotangents):
         _, vjp = jax.vjp(twin, *operands)
         return vjp(cotangents)
 
     return _with_derivative(
-        forward, lambda *operands: operands, backward, scope)
+        forward, lambda *operands: operands, tangent, backward, scope)
 
 
 def _derives_as_kernels(cfg, comm):
@@ -986,6 +1015,23 @@ def _kept_for_the_kernel(state, token, *, first_step, steps):
     if steps == 2 and not first_step:
         return state, token
     return SWState(*state[:3], None, None, None), token
+
+
+def _walk_forwards(kept, state, token, *, cfg, comm, first_step, steps):
+    """The tangent of a walk of the step's kernel where its transpose is
+    the adjoint kernel's (:func:`_walk_backwards`): ``jax.jvp`` of the
+    walk's array code (:func:`_walk_as_arrays`) at what was kept for
+    that kernel, the tangents ``state`` and ``token`` pushed through
+    every term of the step and every exchange of it.  A single step was
+    kept without its tendencies, which enter it linearly: zeros stand in
+    for them and change no tangent."""
+    at, stamp = kept
+    if not first_step and at[3] is None:
+        at = SWState(*at[:3], *(jnp.zeros_like(a) for a in at[:3]))
+    return jax.jvp(
+        partial(_walk_as_arrays, cfg=cfg, comm=comm, first_step=first_step,
+                steps=steps),
+        (at, stamp), (state, token))[1]
 
 
 def _walk_backwards(kept, cotangents, *, cfg, comm, first_step, steps,
@@ -1122,7 +1168,7 @@ def _walk_as_arrays(state, token, *, cfg, comm, first_step, steps):
         for a in state[3:]))
     for _ in range(1 if first_step else steps):
         (state, _), token = _step_wide_arrays(
-            state, cfg, comm, first_step, token)
+            state, cfg, comm, first_step, token, _add_inside)
     return SWState(*state[:3], *(jnp.pad(a, G) for a in state[3:])), token
 
 
@@ -1553,13 +1599,13 @@ def _observed(block, ghost, coarsen):
     1.12 from numpy's there, equal to it at the small size)."""
     c = coarsen
 
-    @jax.custom_vjp
     def observe(block):
         return _block_mean(block, ghost, c)
 
-    observe.defvjp(lambda block: (observe(block), None),
-                   lambda _, coarse: (_spread(coarse, ghost, c),))
-    return observe(block)
+    # linear: its tangent is itself, and nothing is kept
+    return both_modes(
+        observe, lambda block: (), lambda _, block: observe(block),
+        lambda _, coarse: (_spread(coarse, ghost, c),))(block)
 
 
 def _behind(x, earlier, comm):
@@ -1576,6 +1622,45 @@ def _behind(x, earlier, comm):
     mark = earlier[0, 0]
     corner = jnp.where(jnp.isnan(mark), mark.astype(x.dtype), x[0, 0])
     return lax.dynamic_update_slice(x, corner.reshape(1, 1), (0, 0))
+
+
+def _checked_window(cfg, comm, calls, observe):
+    """The shape of a window's observations on one device, ``(calls + 1,
+    rows, columns)``, where ``observe`` divides the device's block."""
+    ny_l, nx_l = cfg.local_interior(comm)
+    if observe < 1 or ny_l % observe or nx_l % observe:
+        raise ValueError(
+            f"observe {observe} does not divide a device's block of "
+            f"{ny_l}x{nx_l} cells")
+    return calls + 1, ny_l // observe, nx_l // observe
+
+
+def _sweep_backwards(window, comm, fields, starts, seed):
+    """A window's backward sweep, one device's part: the cotangents of
+    the window's initial fields from those of the observed states.
+    ``seed(k)`` is the cotangent of the ``h`` that observation ``k`` read
+    (a padded block): the misfit's own derivative there
+    (:func:`make_gradient`) or an observation-space vector spread over
+    its cells (:func:`make_adjoint`).  The calls last to first, each run
+    again from the state it started from (``starts``) with every state
+    kept and then backwards, the first step last."""
+    calls = len(starts)
+    ct = SWState(seed(calls), *(jnp.zeros_like(a) for a in starts[-1][1:]))
+    for k in reversed(range(calls)):
+        # one call at a time: its second run reads nothing of the
+        # call after it, so only this keeps it (its exchanges, and
+        # its stack of kept states) behind that call's way back
+        start = SWState(_behind(starts[k][0], ct.h, comm), *starts[k][1:])
+        # the call's steps again, every state kept, then backwards
+        with _adjoint_scope(RECOMPUTE):
+            _, vjp = jax.vjp(window.step_by_step, start)
+            ct, = vjp(ct)
+        ct = SWState(ct.h + seed(k), *ct[1:])
+    h0, u0, v0 = fields
+    h0 = _behind(h0, ct.h, comm)
+    with _adjoint_scope(RECOMPUTE):
+        _, vjp = jax.vjp(window.first, h0, u0, v0)
+        return vjp(ct)
 
 
 def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
@@ -1607,7 +1692,10 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
     (``sw_kernels.wide_step_vjp`` at the fields the step started from,
     :func:`_step_backwards`); on a block whose adjoint walk has no room
     in VMEM (:func:`_derives_as_kernels`) it is that of the step's
-    array code at the step's input state.
+    array code at the step's input state.  The same window forwards, a
+    perturbation of the initial fields pushed through every step and
+    every exchange, is :func:`make_tangent`'s, and this function's
+    backward sweep with a vector for the residuals :func:`make_adjoint`'s.
 
     Checkpointed at two levels, as two jitted programs a gradient (the
     function's ``forward`` and ``backward``).  The forward sweep runs
@@ -1637,14 +1725,8 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
     mesh in programs that do not donate their state (``ROADMAP.md``
     S28), and this function's two are such.
     """
-    ny_l, nx_l = cfg.local_interior(comm)
-    if observe < 1 or ny_l % observe or nx_l % observe:
-        raise ValueError(
-            f"observe {observe} does not divide a device's block of "
-            f"{ny_l}x{nx_l} cells")
+    observed = _checked_window(cfg, comm, calls, observe)
     window = _window(cfg, comm, num_steps, observe)
-
-    observed = (calls + 1, ny_l // observe, nx_l // observe)
 
     def checked(obs):
         if obs.shape != observed:
@@ -1671,27 +1753,10 @@ def make_gradient(cfg, comm, *, calls, num_steps, observe=1):
         return summed(mine), tuple(starts), state.h
 
     def backward(h0, u0, v0, obs, starts, last_h):
-        def of_misfit(h, y, like):
-            """A term of J's cotangent, as a state's."""
-            return SWState(jax.grad(window.misfit)(h, y),
-                           *(jnp.zeros_like(a) for a in like[1:]))
-
-        ct = of_misfit(last_h, obs[calls], starts[-1])
-        for k in reversed(range(calls)):
-            # one call at a time: its second run reads nothing of the
-            # call after it, so only this keeps it (its exchanges, and
-            # its stack of kept states) behind that call's way back
-            start = SWState(_behind(starts[k][0], ct.h, comm), *starts[k][1:])
-            # the call's steps again, every state kept, then backwards
-            with _adjoint_scope(RECOMPUTE):
-                _, vjp = jax.vjp(window.step_by_step, start)
-                ct, = vjp(ct)
-            mine = of_misfit(starts[k].h, obs[k], starts[k])
-            ct = SWState(ct.h + mine.h, *ct[1:])
-        h0 = _behind(h0, ct.h, comm)
-        with _adjoint_scope(RECOMPUTE):
-            _, vjp = jax.vjp(window.first, h0, u0, v0)
-            return vjp(ct)
+        seen = [start.h for start in starts] + [last_h]
+        return _sweep_backwards(
+            window, comm, (h0, u0, v0), starts,
+            lambda k: jax.grad(window.misfit)(seen[k], obs[k]))
 
     _kernels_ahead(cfg, comm)
     spec = jax.P(*comm.axes)
@@ -1823,16 +1888,330 @@ class Descent:
         cost before each step.  (What a
         gradient runs again is not counted here: a device trace shows
         it, under ``sw/adjoint/recompute``.)"""
-        state = jax.eval_shape(make_init(self.cfg, self.comm))
-        state_bytes = sum(a.size * a.dtype.itemsize for a in state)
-        kept = state[:3] if _derives_as_kernels(self.cfg, self.comm) else state
-        kept_bytes = sum(a.size * a.dtype.itemsize for a in kept)
         return {
             "gradients": len(self._costs),
             "window_steps": 1 + self.calls * self.num_steps,
-            "trajectory_bytes": (
-                self.calls * state_bytes + self.num_steps * kept_bytes),
+            "trajectory_bytes": _trajectory_bytes(
+                self.cfg, self.comm, self.calls, self.num_steps),
             "costs": self.costs(),
+        }
+
+
+def _trajectory_bytes(cfg, comm, calls, num_steps):
+    """What a window's two checkpoint levels keep on the mesh at their
+    fullest, from shapes: the state at each call's start and what is
+    kept of the states of one call's steps (the fields alone, where a
+    step's derivative is the kernel that reads no more)."""
+    state = jax.eval_shape(make_init(cfg, comm))
+    state_bytes = sum(a.size * a.dtype.itemsize for a in state)
+    kept = state[:3] if _derives_as_kernels(cfg, comm) else state
+    kept_bytes = sum(a.size * a.dtype.itemsize for a in kept)
+    return calls * state_bytes + num_steps * kept_bytes
+
+
+def make_tangent(cfg, comm, *, calls, num_steps, observe=1):
+    """Jitted global function ``(h0, u0, v0, starts, ph, pu, pv) -> z``:
+    the tangent-linear sweep of :func:`make_gradient`'s window, forward
+    mode through every step and every halo exchange of it.  ``ph``,
+    ``pu``, ``pv`` is a perturbation of the window's initial fields,
+    interior-shaped as they are; ``z`` is ``(calls + 1, cfg.ny //
+    observe, cfg.nx // observe)``, sharded as the observations: ``H M_k
+    p``, what the perturbation does to the observed means of ``h`` after
+    the first step and after every call, to first order.  Linearised
+    about the trajectory that ``gradient.forward(h0, u0, v0, obs)``
+    keeps: ``starts``, the state each call starts from (its second
+    result), which the sweep reads and does not run again.
+
+    A step's tangent is ``jax.jvp`` of the step: of its array code
+    where it is array code, exchange by exchange (the exchange's tangent
+    is the exchange of the tangents, ``parallel/halo.py``); where the
+    step is the kernel the walk runs as the kernel and its tangent is
+    that of the walk's array code at the same state
+    (:func:`_walk_forwards`), two steps a walk where the call walks two.
+    Nothing is approximated and nothing is kept between calls but the
+    tangent state, six arrays.  Under ``sw/adjoint/tangent``."""
+    window = _window(cfg, comm, num_steps, observe)
+    _checked_window(cfg, comm, calls, observe)
+
+    def tangent(h0, u0, v0, starts, ph, pu, pv):
+        with _adjoint_scope(TANGENT):
+            _, t = jax.jvp(window.first, (h0, u0, v0), (ph, pu, pv))
+            seen = [_block_mean(t.h, cfg.ghost, observe)]
+            for k in range(calls):
+                _, t = jax.jvp(window.call, (starts[k],), (SWState(*t),))
+                seen.append(_block_mean(t.h, cfg.ghost, observe))
+            return jnp.stack(seen)
+
+    _kernels_ahead(cfg, comm)
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        tangent, mesh=comm.mesh,
+        in_specs=(spec,) * 3 + ((_mesh_specs(comm),) * calls,) + (spec,) * 3,
+        out_specs=jax.P(None, *comm.axes)))
+
+
+def make_adjoint(cfg, comm, *, calls, num_steps, observe=1):
+    """Jitted global function ``(h0, u0, v0, starts, w) -> (gh, gu,
+    gv)``: :func:`make_gradient`'s backward sweep with an
+    observation-space vector in place of the misfit's residuals.  ``w``
+    is shaped and sharded as the observations, ``(calls + 1, cfg.ny //
+    observe, cfg.nx // observe)``; the result is ``sum_k M_k^T H^T
+    w_k``, interior-shaped: the transpose of :func:`make_tangent`, to
+    rounding (``<M p, w> = <p, M^T w>``), at the same trajectory
+    (``starts``).  The same sweep as the gradient's (the calls' steps
+    run again and kept, the adjoint kernel or the array code's transpose
+    at each, every exchange the adjoint exchange): with ``w`` the
+    residuals ``H(h_k) - obs_k`` it returns the gradient."""
+    window = _window(cfg, comm, num_steps, observe)
+    observed = _checked_window(cfg, comm, calls, observe)
+
+    def adjoint(h0, u0, v0, starts, w):
+        if w.shape != observed:
+            raise ValueError(
+                f"an observation-space vector of {w.shape} a device where "
+                f"the window's observations are {observed}")
+
+        def seed(k):
+            with _adjoint_scope(COST):
+                return _spread(w[k], cfg.ghost, observe)
+
+        return _sweep_backwards(window, comm, (h0, u0, v0), starts, seed)
+
+    _kernels_ahead(cfg, comm)
+    spec = jax.P(*comm.axes)
+    return jax.jit(jax.shard_map(
+        adjoint, mesh=comm.mesh,
+        in_specs=(spec,) * 3 + ((_mesh_specs(comm),) * calls,
+                                jax.P(None, *comm.axes)),
+        out_specs=(spec,) * 3))
+
+
+def make_product(cfg, comm, *, calls, num_steps, observe=1, weight=0.0):
+    """Global function ``(h0, u0, v0, starts, ph, pu, pv) -> (qh, qu,
+    qv)``: the Gauss-Newton product of the window's cost, ``A p = weight
+    p + sum_k M_k^T H^T H M_k p``, by one tangent-linear sweep
+    (:func:`make_tangent`) and one adjoint sweep (:func:`make_adjoint`)
+    of it, the function's ``tangent`` and ``adjoint``, and one program
+    that adds ``weight p``.  The Hessian of incremental 4D-Var's
+    quadratic cost (Courtier, Thepaut and Hollingsworth 1994) with a
+    background term ``weight / 2 |p|^2``; of a Gauss-Newton or
+    Newton-CG fit through the solver."""
+    how = dict(calls=calls, num_steps=num_steps, observe=observe)
+    tangent = make_tangent(cfg, comm, **how)
+    adjoint = make_adjoint(cfg, comm, **how)
+    spec = jax.P(*comm.axes)
+
+    def weighted(gh, gu, gv, ph, pu, pv):
+        with _adjoint_scope(UPDATE):
+            return tuple(g + jnp.asarray(weight, g.dtype) * p
+                         for g, p in zip((gh, gu, gv), (ph, pu, pv)))
+
+    add = jax.jit(jax.shard_map(
+        weighted, mesh=comm.mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 3),
+        donate_argnums=(0, 1, 2))
+
+    def product(h0, u0, v0, starts, ph, pu, pv):
+        seen = tangent(h0, u0, v0, starts, ph, pu, pv)
+        return add(*adjoint(h0, u0, v0, starts, seen), ph, pu, pv)
+
+    product.tangent, product.adjoint = tangent, adjoint
+    return product
+
+
+def make_inner_step(cfg, comm, weight):
+    """Jitted global functions ``(begin, step)`` of conjugate gradients
+    on ``A x = b``, ``A`` :func:`make_product`'s: the vectors are
+    triples of interior-shaped fields, the scalars every device's copy,
+    one element a device, and nothing comes to the host.
+
+    ``begin(bh, bu, bv) -> (x, r, p, rr)``: ``x = 0``, ``r = p = b``
+    (``b`` donated), ``rr = r . r``.  ``step(x, r, p, g, rr, cost) ->
+    (x, r, p, rr, cost, pq)`` with ``g`` the data term's product ``sum_k
+    M_k^T H^T H M_k p``: ``q = g + weight p``, ``alpha = rr / p . q``,
+    ``x += alpha p``, ``r -= alpha q``, ``p = r + (r' . r' / rr) p``;
+    ``cost`` the quadratic cost, which falls by ``alpha rr / 2`` (a
+    quadratic falls by ``alpha p . r - alpha^2 p . q / 2`` along ``p``,
+    and conjugate gradients' ``p . r`` is ``rr``), and ``pq`` the
+    curvature ``p . A p``.  Two dot products an iteration, ``p . q``
+    and ``r' . r'``, each summed over the mesh by ``allreduce``.  ``x``,
+    ``r`` and ``p`` are donated.  A curvature that is not positive takes
+    no step (``alpha`` 0)."""
+    def dot(a, b):
+        mine = sum(jnp.sum(x * y) for x, y in zip(a, b))
+        total, _tok = allreduce(mine, reductions.SUM, comm=comm)
+        return total.reshape(1, 1)
+
+    def begin(bh, bu, bv):
+        with _adjoint_scope(UPDATE):
+            b = (bh, bu, bv)
+            return tuple(jnp.zeros_like(a) for a in b), b, b, dot(b, b)
+
+    def step(x, r, p, g, rr, cost):
+        with _adjoint_scope(UPDATE):
+            q = tuple(a + jnp.asarray(weight, a.dtype) * b for a, b in zip(g, p))
+            pq = dot(p, q)
+            alpha = jnp.where(pq > 0, rr / jnp.where(pq > 0, pq, 1), 0)[0, 0]
+            x = tuple(a + alpha * b for a, b in zip(x, p))
+            r = tuple(a - alpha * b for a, b in zip(r, q))
+            new = dot(r, r)
+            beta = jnp.where(rr > 0, new / jnp.where(rr > 0, rr, 1), 0)[0, 0]
+            p = tuple(a + beta * b for a, b in zip(r, p))
+            cost = cost - 0.5 * alpha * rr
+            return x, r, p, new, cost, pq
+
+    spec = jax.P(*comm.axes)
+    triple = (spec,) * 3
+    return (
+        jax.jit(jax.shard_map(
+            begin, mesh=comm.mesh, in_specs=triple,
+            out_specs=(triple, triple, triple, spec)), donate_argnums=(0, 1, 2)),
+        jax.jit(jax.shard_map(
+            step, mesh=comm.mesh, in_specs=(triple,) * 4 + (spec, spec),
+            out_specs=(triple, triple, triple, spec, spec, spec)),
+            donate_argnums=(0, 1, 2)))
+
+
+def _on_the_host(scalars):
+    """Every device's copy of each of ``scalars`` (one element a device)
+    as a float, fetched together: no program runs for it."""
+    return [float(c[0, 0]) for c in jax.device_get(list(scalars))]
+
+
+class InnerLoop:
+    """The inner loop of incremental 4D-Var (Courtier, Thepaut and
+    Hollingsworth 1994) as a host drives it: the quadratic cost ``J(dx)
+    = weight / 2 |dx|^2 + 1/2 sum_k |H M_k dx - d_k|^2`` in the
+    increment ``dx`` of a window's initial fields, minimised by
+    conjugate gradients, every iteration one tangent-linear sweep
+    (:func:`make_tangent`), one adjoint sweep (:func:`make_adjoint`) and
+    the vector updates (:func:`make_inner_step`), three programs
+    enqueued without a wait between; the scalars stay on the device
+    until :meth:`costs` asks.
+
+        fit = InnerLoop(cfg, comm, calls=4, num_steps=10, observe=2,
+                        weight=0.11)
+        fit.linearise(h0, u0, v0, obs)   # the outer loop: once
+        fit.iterate(5)
+        fit.wait()
+        fit.costs()        # J after each of the five iterations
+        fit.increment()    # dx: add it to h0, u0, v0
+
+    :meth:`linearise` is the outer loop: the nonlinear window from the
+    first guess (``make_gradient``'s forward sweep, which keeps the
+    state each call starts from: the trajectory the inner loop is
+    linearised about, held for all of it), the innovations ``d_k = y_k -
+    H(h_k)`` and ``b = sum_k M_k^T H^T d_k``, minus the gradient that
+    ``make_gradient`` gives.  ``weight`` is the background term's: ``B``
+    a multiple of the identity.  ``iterations`` caps the loop:
+    :meth:`iterate` past it raises, and :meth:`begin` starts the loop
+    again from ``dx = 0`` at the same linearisation.
+
+    ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`) keeps the
+    host's spans, ``mpi4jax_tpu.incremental/linearise``,
+    ``incremental/enqueue`` an iteration and ``incremental/wait`` a
+    wait.  :meth:`stats` counts what was run and what is held.
+    """
+
+    def __init__(self, cfg, comm, *, calls, num_steps, weight, observe=1,
+                 iterations=50):
+        self.cfg, self.comm = cfg, comm
+        self.calls, self.num_steps = calls, num_steps
+        self.weight, self.iterations = weight, iterations
+        how = dict(calls=calls, num_steps=num_steps, observe=observe)
+        self.gradient = make_gradient(cfg, comm, **how)
+        self.product = make_product(cfg, comm, weight=weight, **how)
+        self.tangent, self.adjoint = self.product.tangent, self.product.adjoint
+        self._begin, self.update = make_inner_step(cfg, comm, weight)
+        self.trace = spans.Recorder(SCOPE_PREFIX)
+        self.fields = self.obs = self.starts = self.last_h = None
+        self.vectors = None  # x, r, p: the increment, the residual, the direction
+        # r . r, the quadratic cost and the cost at dx = 0: every device's
+        # copy, one element a device, on the device
+        self.rr = self.cost = self.cost0 = None
+        self._costs, self._curvatures = [], []
+
+    def linearise(self, h0, u0, v0, obs):
+        """The outer loop: the trajectory from the first guess, kept, and
+        the loop begun at ``dx = 0``."""
+        with self.trace.span("incremental/linearise"):
+            self.fields, self.obs = (h0, u0, v0), obs
+            self.cost0, self.starts, self.last_h = self.gradient.forward(
+                h0, u0, v0, obs)
+            self.begin()
+
+    def begin(self):
+        """The loop from ``dx = 0`` at the trajectory kept: ``r = p = b``,
+        by one backward sweep."""
+        grads = self.gradient.backward(
+            *self.fields, self.obs, self.starts, self.last_h)
+        *self.vectors, self.rr = self._begin(*(-g for g in grads))
+        self.cost = self.cost0
+        self._costs, self._curvatures = [], []
+
+    @property
+    def enqueued(self):
+        """Iterations enqueued since the loop began (nothing is read)."""
+        return len(self._costs)
+
+    def iterate(self, n=1):
+        """Enqueue ``n`` iterations; nothing is waited for."""
+        for _ in range(n):
+            if self.enqueued >= self.iterations:
+                raise ValueError(
+                    f"the inner loop is capped at {self.iterations} "
+                    "iterations: linearise again, or begin()")
+            with self.trace.span("incremental/enqueue", key=self.enqueued):
+                x, r, p = self.vectors
+                seen = self.tangent(*self.fields, self.starts, *p)
+                g = self.adjoint(*self.fields, self.starts, seen)
+                *self.vectors, self.rr, self.cost, pq = self.update(
+                    x, r, p, g, self.rr, self.cost)
+                self._costs.append(self.cost)
+                self._curvatures.append(pq)
+
+    def wait(self):
+        with self.trace.span("incremental/wait", key=self.enqueued):
+            jax.block_until_ready(self.vectors)
+
+    def costs(self):
+        """The quadratic cost at ``dx = 0`` and after each iteration so
+        far, as floats (waits)."""
+        return _on_the_host((self.cost0, *self._costs))
+
+    def curvatures(self):
+        """``p . A p`` of each iteration so far, as floats (waits)."""
+        return _on_the_host(self._curvatures)
+
+    def increment(self):
+        """``(dh0, du0, dv0)`` as the loop has it."""
+        return self.vectors[0]
+
+    def held(self):
+        """The arrays held on the mesh between an iteration's programs:
+        the first guess, the trajectory's first level (the state each
+        call starts from, and the last ``h``), the observations and the
+        loop's vectors."""
+        return self.fields, self.starts, self.last_h, self.obs, self.vectors
+
+    def stats(self):
+        """``iterations``: enqueued since the loop began.
+        ``window_steps``: the model steps of a window, each linearised
+        once and transposed once an iteration.  ``trajectory_bytes``:
+        what the two checkpoint levels keep on the mesh at their
+        fullest, as :meth:`Descent.stats` counts them; the first level
+        is held across the loop.  ``vector_bytes``: the loop's vectors,
+        ``x``, ``r``, ``p`` and a product, of three interior fields
+        each.  ``costs``, ``curvatures``: :meth:`costs`,
+        :meth:`curvatures`."""
+        field = self.cfg.ny * self.cfg.nx * jnp.dtype(self.cfg.dtype).itemsize
+        return {
+            "iterations": self.enqueued,
+            "window_steps": 1 + self.calls * self.num_steps,
+            "trajectory_bytes": _trajectory_bytes(
+                self.cfg, self.comm, self.calls, self.num_steps),
+            "vector_bytes": 4 * 3 * field,
+            "costs": self.costs(),
+            "curvatures": self.curvatures(),
         }
 
 

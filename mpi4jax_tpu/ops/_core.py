@@ -27,6 +27,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.custom_derivatives import SymbolicZero
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
 from jax.tree_util import register_pytree_node
 
 __all__ = [
@@ -604,6 +607,166 @@ def promote_vma(x, axes):
         else:
             x = lax.pvary(x, missing)
     return x
+
+
+def both_modes(forward, keep, tangent, transpose):
+    """``forward`` (operands -> results) as a function with a derivative
+    in both modes that is written out: ``jax.jvp``, ``jax.linearize``
+    and ``jax.jacfwd`` through it run ``tangent(kept, *tangents)``, and
+    ``jax.vjp`` and ``jax.grad`` run ``transpose(kept, cotangents)``,
+    which returns the cotangents of the operands, in their structure;
+    ``kept = keep(*operands)`` is all that is held for either.  A call
+    that is not differentiated runs ``forward`` and nothing else.
+
+    ``forward`` runs on the operands with their derivatives stopped, as
+    the caller's own lines (nothing wraps it: an instruction of it keeps
+    its scopes and its source line in a compiled text), and its results
+    go through a ``jax.custom_jvp`` that hands them on untouched and
+    takes the operands beside them for its rule.  (``forward`` inside
+    the ``custom_jvp`` is the same program with every instruction named
+    for the line that calls it: jax 0.9 lowers a ``custom_jvp_call``
+    once, out of line, and inlines it at the call's location.)  The rule
+    binds the tangents to :data:`linear_p`, a linear map that carries
+    its transpose: reverse mode, which linearises the rule and
+    transposes what it finds, finds that primitive and runs
+    ``transpose``, not the transposes of ``tangent``'s parts (which is
+    all a ``jax.custom_vjp`` is for, and a ``custom_vjp`` has no forward
+    mode).  Neither function is traced before a program that runs it is
+    lowered: a reverse-mode program never traces ``tangent``
+    (``jax.custom_derivatives.linear_call``, the same idea, traces its
+    linear function where it is called: 1.5 s more of every set-up of
+    the gradient's cell, ``PERF.md`` PR 59).  So neither may close over
+    a traced value: what they read of the operands comes through
+    ``kept``.  Operands whose tangents are symbolic zeros (a token made
+    inside the differentiated function) are no operands of the linear
+    map, which in a transposition takes unknowns alone: ``tangent`` is
+    handed zeros there and their cotangents are dropped.  What stays
+    open is forward over reverse: the map's own JVP asks for tangents of
+    ``kept``, which ``transpose`` (an adjoint kernel, say) does not
+    give."""
+    @jax.custom_jvp
+    def derived(out, *operands):
+        del operands
+        return out
+
+    def rule(primals, tangents):
+        out, *primals = primals
+        leaves, tree = jax.tree.flatten(
+            tuple(tangents[1:]), is_leaf=lambda t: isinstance(t, SymbolicZero))
+        live = [not isinstance(t, SymbolicZero) for t in leaves]
+        like = [(x.shape, x.dtype, vma_of(x) or ())
+                for x in jax.tree.leaves(primals)]
+        kept, kept_tree = jax.tree.flatten(keep(*primals))
+        held = len(kept)
+        out_tree = jax.tree.structure(out)
+
+        def pushed(*flat):
+            some = iter(flat[held:])
+            whole = tree.unflatten([
+                next(some) if is_live
+                else promote_vma(jnp.zeros(shape, dtype), vma)
+                for is_live, (shape, dtype, vma) in zip(live, like)])
+            return jax.tree.leaves(
+                tangent(kept_tree.unflatten(flat[:held]), *whole))
+
+        def pulled(*flat):
+            back = jax.tree.leaves(transpose(
+                kept_tree.unflatten(flat[:held]), out_tree.unflatten(flat[held:])))
+            if len(back) != len(live):
+                raise TypeError(
+                    f"a transpose that returns {len(back)} cotangents for "
+                    f"{len(live)} operands")
+            return [ct for ct, is_live in zip(back, live) if is_live]
+
+        pushed_out = linear_p.bind(
+            *kept, *(t for t, is_live in zip(leaves, live) if is_live),
+            fun=pushed, transpose=pulled, held=held,
+            out_avals=tuple(jax.typeof(x) for x in jax.tree.leaves(out)))
+        return out, out_tree.unflatten(pushed_out)
+
+    derived.defjvp(rule, symbolic_zeros=True)
+
+    def both(*operands):
+        return derived(
+            forward(*jax.tree.map(lax.stop_gradient, operands)), *operands)
+
+    return both
+
+
+# A linear map with its transpose beside it: ``bind(*kept, *linear,
+# fun=, transpose=, held=, out_avals=)`` is ``fun(*kept, *linear)``,
+# linear in ``linear`` (the operands after the first ``held``), and its
+# transposition is the same primitive with the two functions' places
+# changed, on the cotangents.  Both are flat functions of arrays, traced
+# where a program that holds the primitive is lowered and not before.
+linear_p = Primitive("mpi4jax_tpu_linear")
+linear_p.multiple_results = True
+
+
+def _linear_impl(*args, fun, transpose, held, out_avals):
+    del transpose, held, out_avals
+    return fun(*args)
+
+
+def _linear_jvp(primals, tangents, **how):
+    held = how["held"]
+    if any(type(t) is not ad.Zero for t in tangents[:held]):
+        raise NotImplementedError(
+            "forward over reverse through a derivative that is written out "
+            "(ops/_core.py both_modes): what it keeps has a tangent, and its "
+            "transpose gives none")
+    pushed = [ad.instantiate_zeros(t) for t in tangents[held:]]
+    return (linear_p.bind(*primals, **how),
+            linear_p.bind(*primals[:held], *pushed, **how))
+
+
+def _linear_transpose(cts, *args, fun, transpose, held, out_avals):
+    kept, linear = args[:held], args[held:]
+    if any(ad.is_undefined_primal(x) for x in kept) or not all(
+            ad.is_undefined_primal(x) for x in linear):
+        raise NotImplementedError(
+            "a linear map (ops/_core.py linear_p) is transposed in all of "
+            "its linear operands, and in none it keeps")
+    back = linear_p.bind(
+        *kept, *(ad.instantiate_zeros(ct) for ct in cts), fun=transpose,
+        transpose=fun, held=held, out_avals=tuple(x.aval for x in linear))
+    return [None] * held + list(back)
+
+
+def _linear_batch(args, dims, *, fun, transpose, held, out_avals):
+    """``jax.vmap`` (``jax.jacfwd``, ``jax.jacrev``): the map and its
+    transpose each under ``jax.vmap``, every linear operand batched
+    along its first axis."""
+    size = next(x.shape[d] for x, d in zip(args, dims) if d is not None)
+    args = [x if d is None else jnp.moveaxis(x, d, 0)
+            for x, d in zip(args, dims)]
+    linear = [jnp.broadcast_to(x, (size, *x.shape)) if d is None else x
+              for x, d in zip(args[held:], dims[held:])]
+    axes = [None if d is None else 0 for d in dims[:held]]
+
+    def over(one):
+        return lambda *flat: jax.vmap(
+            lambda kept, rest: one(*kept, *rest), in_axes=(axes, 0))(
+                list(flat[:held]), list(flat[held:]))
+
+    out = linear_p.bind(
+        *args[:held], *linear, fun=over(fun), transpose=over(transpose),
+        held=held,
+        out_avals=tuple(a.update(shape=(size, *a.shape)) for a in out_avals))
+    return out, [0] * len(out)
+
+
+linear_p.def_impl(_linear_impl)
+linear_p.def_abstract_eval(lambda *args, out_avals, **_: list(out_avals))
+ad.primitive_jvps[linear_p] = _linear_jvp
+ad.primitive_transposes[linear_p] = _linear_transpose
+batching.primitive_batchers[linear_p] = _linear_batch
+# (not cached: the functions are a trace's own, so no second equation
+# would hit, and a cached lowering is emitted at the equation's location,
+# every line of the function's body named for the call's)
+mlir.register_lowering(
+    linear_p, mlir.lower_fun(_linear_impl, multiple_results=True),
+    cacheable=False)
 
 
 def comm_key(comm):

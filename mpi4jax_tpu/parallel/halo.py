@@ -35,7 +35,7 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from mpi4jax_tpu.ops._core import (
-    as_token, promote_vma, publishes_token, vma_of)
+    as_token, both_modes, promote_vma, publishes_token, vma_of)
 from mpi4jax_tpu.ops.p2p import sendrecv, sendrecv_multi
 
 __all__ = ["halo_exchange_2d", "halo_exchange_2d_batch", "halo_slabs_2d"]
@@ -472,12 +472,14 @@ def _transposable(forward, backward, arrs, token):
     and the lane-tile strip of :func:`_place` likewise, where the
     adjoint exchange touches the slabs it moves and nothing else.  An
     exchange is linear in its blocks and passes its token's stamp
-    through: nothing is kept for the backward pass, the adjoint
-    exchange threads the token's cotangent through its four shifts as
-    their token, and a stamp's own cotangent is zero.  A ``jax.custom_vjp``:
-    reverse mode only (``jax.jvp`` through a mesh-tier exchange raises;
-    ``jax.custom_derivatives.linear_call`` would give both modes and
-    binds symbolic zeros as arrays in jax 0.9).  A call that is not
+    through: nothing is kept for either mode.  Forwards (``jax.jvp``,
+    ``jax.linearize``, ``jax.jacfwd``) its tangent is the exchange
+    itself on the tangents, under the same ``pack``, ``wire`` and
+    ``unpack`` scopes (a tangent sweep's events carry them inside
+    ``jvp(...)``), bit for bit what the exchange gives those blocks.
+    Backwards the adjoint exchange threads the token's cotangent through
+    its four shifts as their token, and a stamp's own cotangent is zero.
+    Both by ``ops/_core.py both_modes``.  A call that is not
     differentiated lowers to what ``forward`` lowers to."""
     token = as_token(token)
     # a stamp's cotangent says nothing; it has to be of the stamp's type
@@ -489,9 +491,9 @@ def _transposable(forward, backward, arrs, token):
                    for shape, dtype, vma in stamps]
         return blocks, jax.tree.unflatten(jax.tree.structure(token), nothing)
 
-    exchange = jax.custom_vjp(forward)
-    exchange.defvjp(
-        lambda arrs, token: (forward(arrs, token), None), transposed)
+    exchange = both_modes(
+        forward, lambda arrs, token: (),
+        lambda _, arrs, token: forward(arrs, token), transposed)
     return exchange(list(arrs), token)
 
 

@@ -103,6 +103,27 @@ def test_solver_adjoint_check_tiny():
             cfg, cpu, calls=2, steps_per_call=3, tol=0.0)
 
 
+def test_solver_tangent_check_tiny():
+    cpu = jax.devices("cpu")
+    cfg = sw.SWConfig(ny=24, nx=48, ghost=2)
+    out = chip_smoke.solver_tangent_check(cfg, cpu, cpu, calls=2, steps_per_call=3)
+    assert set(out["rel_l2"]) == {"tangent", "h", "u", "v"}
+    assert 0 < max(out["rel_l2"].values()) <= 2e-4
+    assert out["adjoint_test"][2] < 1e-5
+    assert "window of 7 steps" in out["compared"] and "2x2 on cpu" in out["compared"]
+    one = chip_smoke.solver_tangent_check(
+        cfg, cpu[:1], cpu, calls=1, steps_per_call=2)
+    assert "1x1 on cpu" in one["compared"] and max(one["rel_l2"].values()) == 0
+    assert "solver.tangent" in chip_smoke.GROUPS[1]["solver.tangent"][1]
+    assert "solver4.tangent" in chip_smoke.GROUPS[4]["solver4.tangent"][1]
+    small = chip_smoke._small_cfg()
+    assert (small.ny, small.nx, small.ghost) == (256, 512, 2)
+    # a product that is not the reference's is refused
+    with pytest.raises(AssertionError, match="not its reference's"):
+        chip_smoke.solver_tangent_check(
+            cfg, cpu, cpu, calls=2, steps_per_call=3, tol=0.0)
+
+
 def test_solver_observed_check_tiny(monkeypatch):
     cpu = jax.devices("cpu")
     out = chip_smoke.solver_observed_check([(24, 48), (16, 20)], cpu)

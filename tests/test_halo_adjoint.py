@@ -1,4 +1,6 @@
-"""The halo exchange transposes to the adjoint exchange: ``jax.vjp``
+"""The halo exchange differentiates both ways on the mesh tier: forwards
+its tangent is the exchange of the tangents; backwards it transposes to
+the adjoint exchange: ``jax.vjp``
 through ``halo_exchange_2d``, ``halo_exchange_2d_batch`` and
 ``halo_slabs_2d`` on the mesh tier gives, for every ghost cell, its
 cotangent added to the cell it was copied from (corners through both
@@ -250,16 +252,112 @@ def test_the_token_is_threaded_through_the_backward_sweep():
     assert "jvp(mpi4jax_tpu.halo_exchange_2d)/pack" in text
 
 
-def test_forward_mode_is_refused_with_jaxs_own_words():
-    comm = _comm((1, 1))
+FORMS = ["single", "batch", "slabs"]
+
+
+def _form(form, comm, w, per):
+    """``blocks -> arrays`` of one form of the exchange on ``comm``, as
+    a function of three blocks and of their results stacked (a slab
+    that is ``None`` left out): the single exchange on the first block,
+    the batch on all three, the slabs of the first."""
+    how = dict(periodic=per, width=w)
+
+    def run(a, b, c):
+        if form == "single":
+            return [halo_exchange_2d(a, comm, **how)[0]]
+        if form == "batch":
+            return halo_exchange_2d_batch([a, b, c], comm, **how)[0]
+        slabs, _ = halo_slabs_2d(a, comm, **how)
+        return [s for s in slabs if s is not None]
+
+    return run
+
+
+@pytest.mark.parametrize("per", ["walled_y", "periodic"])
+@pytest.mark.parametrize("w", [1, 2])
+@pytest.mark.parametrize("mesh_shape", [(2, 2), (2, 4)])
+@pytest.mark.parametrize("form", FORMS)
+def test_forward_mode_is_the_exchange_of_the_tangent_and_the_adjoints_transpose(
+        form, mesh_shape, w, per):
+    """``jax.jvp`` through each form of the exchange gives the exchange
+    of the tangents, bit for bit, and satisfies ``<J x, y> == <x, J^T
+    y>`` with the adjoint exchange that ``jax.vjp`` runs: whole numbers,
+    so both sides are exact; ``jax.linearize`` gives the same map."""
+    comm = _comm(mesh_shape)
+    py, px = mesh_shape
+    shape = (py * (N + 2 * w), px * (N + 2 * w))
+    run = _form(form, comm, w, PERIODIC[per])
+
+    def local(a, b, c, ta, tb, tc, *cts):
+        at, push = (a, b, c), (ta, tb, tc)
+        out, pushed = jax.jvp(run, at, push)
+        _, linear = jax.linearize(run, *at)
+        exchanged = run(*push)
+        pulled = jax.vjp(run, *at)[1](list(cts))
+        there = sum(jnp.sum(t * ct) for t, ct in zip(pushed, cts))
+        home = sum(jnp.sum(t * ct) for t, ct in zip(push, pulled))
+        same = jnp.stack(
+            [jnp.all(x == y) for x, y in zip(pushed, exchanged)]
+            + [jnp.all(x == y) for x, y in zip(pushed, linear(*push))])
+        return (there.reshape(1, 1), home.reshape(1, 1),
+                jnp.all(same).reshape(1, 1))
+
+    # cotangents shaped as the results are, a device: made inside
+    def program(a, b, c, ta, tb, tc):
+        key = jax.random.key(7)
+        shapes = [x.shape for x in run(a, b, c)]
+        me = jax.lax.axis_index("y") * px + jax.lax.axis_index("x")
+        cts = [jnp.round(4 * jax.random.normal(
+            jax.random.fold_in(jax.random.fold_in(key, i), me), shape))
+            for i, shape in enumerate(shapes)]
+        return local(a, b, c, ta, tb, tc, *cts)
+
+    fields = [jnp.asarray(_numbers(shape, seed)) for seed in range(6)]
+    there, home, same = _sharded(program, comm, 6, 3)(*fields)
+    assert bool(jnp.all(same))
+    assert float(there.sum()) == float(home.sum()) != 0.0
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_jacfwd_through_the_exchange_is_jacrevs_matrix(form):
+    """``jax.jacfwd`` (``jax.vmap`` of the tangent) through each form on
+    a 2x2 mesh builds the matrix that ``jax.jacrev`` builds from the
+    adjoint exchange, of a function that is not linear in the blocks
+    (whole numbers: the same, exactly)."""
+    comm = _comm((2, 2))
+    w, n = 1, 2
+    run = _form(form, comm, w, (False, True))
+
+    # results of one shape: a slab's ends patched to a block's
+    def of_first(a):
+        return sum(jnp.sum(x, keepdims=True) * a for x in run(a, 2 * a, 3 * a))
+
+    spec = jax.P("y", "x")
+    program = jax.jit(jax.shard_map(
+        of_first, mesh=comm.mesh, in_specs=spec, out_specs=spec))
+    x = jnp.asarray(_numbers((2 * (n + 2 * w),) * 2, 3))
+    forwards, backwards = jax.jacfwd(program)(x), jax.jacrev(program)(x)
+    np.testing.assert_array_equal(np.asarray(forwards), np.asarray(backwards))
+    assert float(jnp.abs(forwards).sum()) > 0
+
+
+def test_the_tangent_keeps_the_exchanges_scopes():
+    """A tangent's instructions lie under the op's scope inside jax's
+    ``jvp(...)``, with the three phases and without the ``transpose``
+    marker."""
+    comm = _comm((2, 2))
 
     def local(a):
         return jax.jvp(
-            lambda a: halo_exchange_2d(a, comm, periodic=(False, True), width=1)[0],
+            lambda a: halo_exchange_2d(a, comm, periodic=(False, True), width=2)[0],
             (a,), (a,))[1:]
 
-    with pytest.raises(TypeError, match="custom_vjp"):
-        _sharded(local, comm, 1, 1)(jnp.zeros((N + 2, N + 2), jnp.float32))
+    program = _sharded(local, comm, 1, 1)
+    text = program.lower(
+        jnp.zeros((2 * (N + 4),) * 2, jnp.float32)).as_text(debug_info=True)
+    for phase in (halo.PACK, halo.WIRE, halo.UNPACK):
+        assert f"jvp(mpi4jax_tpu.halo_exchange_2d)/{phase}" in text, phase
+    assert f"/{halo.TRANSPOSE}/" not in text
 
 
 def test_slabs_deeper_than_the_ring_differentiate_by_their_parts():

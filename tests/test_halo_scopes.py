@@ -20,8 +20,8 @@ from mpi4jax_tpu.parallel.halo import halo_exchange_2d, halo_exchange_2d_batch
 def _eqns(jaxpr, outer=""):
     """``(eqn, its name stack)`` over a jaxpr and its sub-jaxprs, in
     program order; a sub-jaxpr's stacks are relative to the eqn that
-    holds it (the exchange's body is a ``custom_vjp_call``'s since
-    PR 54), and the stack given is the whole one, as lowering joins it."""
+    holds it (the exchange's body is a ``custom_jvp_call``'s since
+    PR 59, a ``custom_jvp_call``'s from PR 54), and the stack given is the whole one, as lowering joins it."""
     for eqn in jaxpr.eqns:
         stack = "/".join(filter(None, [outer, str(eqn.source_info.name_stack)]))
         yield eqn, stack
@@ -88,11 +88,13 @@ def test_an_exchange_lowers_with_its_three_phases(comm2d, exchange, op, packed_b
     # the block is held row-major where the slabs are sliced from it
     assert all(s.endswith(f"{outer}/pack") for s in stacks["layout_constraint"])
     # nothing of the op lies outside its three phases
-    # (the call that carries the exchange's transpose holds the three and
-    # lowers to nothing of its own)
-    mine = {s for name, group in stacks.items() if name != "custom_vjp_call"
+    # (the call that carries the exchange's derivative takes the three's
+    # results and hands them on, its operands beside them with their
+    # derivatives stopped for the three: both lower to nothing)
+    mine = {s for name, group in stacks.items()
+            if name not in ("custom_jvp_call", "stop_gradient")
             for s in group if outer in s}
-    assert stacks["custom_vjp_call"] == {outer}
+    assert stacks["custom_jvp_call"] == stacks["stop_gradient"] == {outer}
     assert all(s.split(outer + "/")[-1].split("/")[0] in ("pack", "wire", "unpack")
                for s in mine)
 
@@ -151,11 +153,12 @@ def test_the_solvers_program_is_eqn_for_eqn_what_it_was(mesh_shape, ghost):
     state = jax.eval_shape(sw.make_init(cfg, comm))
     jaxpr = jax.make_jaxpr(sw.make_multistep(cfg, comm, 10))(state).jaxpr
     # but for the call round each mesh-tier exchange that carries its
-    # transpose (PR 54: five a step, one at ghost 4), whose body is the
+    # derivative (PR 54, PR 59: five a step, one at ghost 4), whose body is the
     # exchange's eqns as they were
     names = [eqn.primitive.name for eqn, _ in _eqns(jaxpr)]
-    calls = names.count("custom_vjp_call")
+    calls = names.count("custom_jvp_call")
     assert calls == (1 if ghost == 4 else 5)
-    names = [name for name in names if name != "custom_vjp_call"]
+    names = [name for name in names
+             if name not in ("custom_jvp_call", "stop_gradient")]
     digest = hashlib.sha1(" ".join(names).encode()).hexdigest()[:12]
     assert (len(names), digest) == PINNED[mesh_shape, ghost]

@@ -122,9 +122,10 @@ def test_the_slabs_lower_under_pack_and_wire_and_nothing_is_unpacked():
     scopes = {
         line.split(op + "/")[1].split("/")[0].split('"')[0]
         for line in text.splitlines() if op + "/" in line}
-    # (the call that carries the slabs' transpose, PR 54, holds both: a
-    # location of the lowered text that no compiled instruction keeps)
-    assert scopes - {"custom_vjp_call"} == {"pack", "wire"}
+    # (the call that carries the slabs' derivative, PR 54, both modes
+    # since PR 59, holds both: a location of the lowered text that no
+    # compiled instruction keeps)
+    assert scopes - {"custom_jvp_call"} == {"pack", "wire"}
     assert f"{op}/wire/{SCOPE_PREFIX}sendrecv" in text
     assert "/unpack" not in text
     # the compiled program's op_names, which the benchmark's readers

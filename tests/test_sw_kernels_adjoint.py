@@ -237,32 +237,37 @@ def test_widths_and_tiles(nx, tile, monkeypatch):
     _assert_the_same(got, _transposed(comm, arrays, state, cotangents))
 
 
+@pytest.mark.parametrize("through", ["arrays", "walk"])
+@pytest.mark.parametrize("walk", sorted(WALKS))
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
-def test_the_dot_product_identity(mesh_shape, monkeypatch):
-    """``<J x, y> == <x, J^T y>``: ``J x`` by ``jax.jvp`` of the array
-    code (its exchanges as plain jax: a mesh's exchange carries a
-    transpose and no tangent rule), ``J^T y`` by the adjoint kernel."""
+def test_the_dot_product_identity(mesh_shape, walk, through, monkeypatch):
+    """``<J x, y> == <x, J^T y>``: ``J^T y`` by the adjoint kernel, ``J
+    x`` by ``jax.jvp`` of the array code (its exchanges' tangents the
+    exchanges of the tangents) and by ``jax.jvp`` of the kernel's walk
+    itself, whose tangent is written out as the array code's at what the
+    adjoint kernel keeps (``_walk_forwards``)."""
     comm = _comm(mesh_shape)
     cfg = _Viscous(ny=10 * mesh_shape[0], nx=20 * mesh_shape[1], nu=0.2, **UNIT)
     calls = _interpreted(monkeypatch)
     state, y = _random(comm, cfg, seed=59)
     _, x = _random(comm, cfg, seed=60)
-    kernels, arrays = _steps(cfg, comm)
+    kernels, arrays = _steps(cfg, comm, **WALKS[walk])
     back = _transposed(comm, kernels, state, y)
     assert calls
-    monkeypatch.setattr(
-        halo, "_transposable",
-        lambda forward, backward, arrs, token: forward(list(arrs), token))
     spec = sw._mesh_specs(comm)
     pushed = jax.jit(jax.shard_map(
-        lambda state, x: jax.jvp(arrays, (state,), (x,))[1],
+        lambda state, x: jax.jvp(
+            kernels if through == "walk" else arrays, (state,), (x,))[1],
         mesh=comm.mesh, in_specs=(spec, spec), out_specs=spec))(state, x)
 
     def dot(a, b, of=lambda p: p):
         return sum(float(jnp.vdot(of(p), of(q))) for p, q in zip(a, b))
 
+    if walk == "euler":  # forward Euler reads no tendency
+        x = x[:3]
     # float32's rounding of a sum of thousands of products of either
     # sign, against the sum of their sizes
+    # (the twelve cases read 2e-9 to 2e-8 of it)
     assert dot(pushed, y) == pytest.approx(
         dot(x, back), abs=1e-6 * dot(x, back, of=jnp.abs))
 

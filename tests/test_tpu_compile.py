@@ -1017,7 +1017,7 @@ def _compiled_gradient(v5e, mesh_shape, ny, nx, calls, steps):
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
 def test_a_call_that_is_not_differentiated_compiles_to_what_it_did(
         v5e, mesh_shape, monkeypatch):
-    """The step's and the exchange's ``jax.custom_vjp`` leave nothing in
+    """The step's and the exchange's written-out derivatives leave nothing in
     a program nobody differentiates: ``make_multistep``'s compiled text
     is, instruction for instruction, the text of the same program with
     both wrappers taken off (``_with_derivative`` handing back the
@@ -1030,7 +1030,8 @@ def test_a_call_that_is_not_differentiated_compiles_to_what_it_did(
 
     text = _compiled_multistep(v5e, mesh_shape, 2, 1800, 3600, 10).as_text()
     monkeypatch.setattr(
-        sw, "_with_derivative", lambda forward, keep, backward, scope: forward)
+        sw, "_with_derivative",
+        lambda forward, keep, tangent, backward, scope: forward)
     monkeypatch.setattr(
         halo, "_transposable",
         lambda forward, backward, arrs, token: forward(list(arrs), token))
@@ -1206,3 +1207,109 @@ def test_the_cells_sweep_spreads_a_coarse_cotangent_by_a_matrix_product(v5e):
             assert body.startswith("f32[1800,29,256]"), line
             products += 1
     assert under_cost > products == calls + 1
+
+
+# -- the linearised run (PR 59) ---------------------------------------------
+
+
+@functools.cache
+def _compiled_inner_loop(v5e, mesh_shape, ny, nx, calls, steps):
+    """An inner-loop iteration's three programs at ``ny`` x ``nx`` cells
+    a chip, observed over 2 x 2 cells, compiled for the described
+    chips: ``(tangent, adjoint, update)``."""
+    py, px = mesh_shape
+    mesh = jax.make_mesh(
+        mesh_shape, ("y", "x"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=v5e.devices[:py * px],
+    )
+    comm = m.MeshComm.from_mesh(mesh)
+    cfg = sw.SWConfig(ny=ny * py, nx=nx * px, dx=2500.0, dy=2500.0, ghost=2)
+    sharding = NamedSharding(mesh, jax.P("y", "x"))
+    field = jax.ShapeDtypeStruct((cfg.ny, cfg.nx), jnp.float32, sharding=sharding)
+    obs = jax.ShapeDtypeStruct(
+        (calls + 1, cfg.ny // 2, cfg.nx // 2), jnp.float32,
+        sharding=NamedSharding(mesh, jax.P(None, "y", "x")))
+    how = dict(calls=calls, num_steps=steps, observe=2)
+    gradient = sw.make_gradient(cfg, comm, **how)
+    _cost, starts, _last = jax.eval_shape(gradient.forward, field, field, field, obs)
+    starts = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), starts)
+    product = sw.make_product(cfg, comm, weight=0.11, **how)
+    _begin, step = sw.make_inner_step(cfg, comm, 0.11)
+    triple = (field,) * 3
+    one = jax.ShapeDtypeStruct(mesh_shape, jnp.float32, sharding=sharding)
+    return (product.tangent.lower(*triple, starts, *triple).compile(),
+            product.adjoint.lower(*triple, starts, obs).compile(),
+            step.lower(triple, triple, triple, triple, one, one).compile())
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
+def test_the_tangent_sweep_runs_the_kernel_and_pushes_its_array_code(v5e, mesh_shape):
+    """``make_tangent`` where the step is the kernel: the window's walks
+    run as the kernel, each walk's tangent is its
+    array code's, every instruction of the sweep under
+    ``sw/adjoint/tangent``, the exchanges' tangents under the
+    exchange's own scopes inside ``jvp(...)`` and none under the
+    ``transpose`` marker; beside neighbours both the state's and the
+    tangent's slabs go over the wire.  The adjoint sweep beside it is
+    the gradient's: the adjoint kernel and the adjoint exchange."""
+    ny, nx = 1800, 3600
+    tangent, adjoint, update = _compiled_inner_loop(v5e, mesh_shape, ny, nx, 1, 4)
+    text = tangent.as_text()
+    assert _kernel_calls(text)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    scoped = [name for name in names if "sw/adjoint/" in name]
+    assert scoped and all("sw/adjoint/tangent" in name for name in scoped)
+    # every instruction that is named at all: no ``jvp()/add`` of a
+    # scatter inside the loop's body (``shallow_water._add_inside``), so a
+    # trace places each event of the sweep by its ``op_name``
+    elsewhere = {name for line in text.splitlines() if " parameter(" not in line
+                 for name in re.findall(r'op_name="([^"]*)"', line)
+                 if "sw/adjoint/" not in name}
+    assert all(re.fullmatch(r"jit\(tangent\)/shard_map(/\w+\.\d+)?", name)
+               for name in elsewhere), elsewhere
+    exchanged = [name for name in names if "mpi4jax_tpu.halo_" in name]
+    assert any("jvp(mpi4jax_tpu.halo_exchange_2d)/unpack" in name for name in exchanged)
+    assert not any("/transpose/" in name for name in exchanged)
+    assert "wide_step_vjp" not in text
+    assert ("collective-permute" in text) == (mesh_shape != (1, 1))
+    if mesh_shape != (1, 1):
+        wired = [line for line in text.splitlines()
+                 if " collective-permute-start(" in line]
+        assert any("jvp(mpi4jax_tpu.halo_exchange_2d)/wire" in line for line in wired)
+    backward = adjoint.as_text()
+    assert "wide_step_vjp" in backward and "/transpose/unpack" in backward
+    assert " all-reduce(" not in text and " all-reduce(" not in backward
+    # the loop's two dot products are the mesh's
+    reduces = [line for line in update.as_text().splitlines()
+               if " all-reduce(" in line]
+    assert (0 < len(reduces) <= 2) if mesh_shape != (1, 1) else not reduces
+
+
+def test_the_cells_inner_loop_fits_a_chip_with_room(v5e):
+    """``sw-incremental-1chip``'s three programs at the cell's own size
+    and window: each sweep's peak by the compiler's buffer assignment,
+    with the loop's vectors held beside it (``vector_bytes``, 1.24e9; a
+    sweep's arguments are the first guess and the trajectory's first
+    level), under 14e9 bytes (PR 54's line for taking a call off the
+    window) and over a quarter of a chip.  The adjoint sweep is the
+    gradient's backward sweep with a vector for the residuals, and
+    peaks where that does."""
+    import json
+
+    with open("perfbench/workloads/sw-incremental-1chip.json") as f:
+        grid = json.load(f)["grid"]
+    with open("perfbench/configs/shallow-water-incremental.json") as f:
+        calls = json.load(f)["window"]["calls"]
+    tangent, adjoint, update = _compiled_inner_loop(
+        v5e, (1, 1), grid["ny"], grid["nx"], calls, 10)
+    vectors = 4 * 3 * grid["ny"] * grid["nx"] * 4
+    peaks = {key: program.memory_analysis().peak_memory_in_bytes
+             for key, program in (("tangent", tangent), ("adjoint", adjoint))}
+    for key, peak in peaks.items():
+        assert 0.25 * 16e9 < peak + vectors < 14e9, (key, peak)
+    _forward, backward, _calls = _the_cells_gradient(v5e)
+    assert peaks["adjoint"] == pytest.approx(
+        backward.memory_analysis().peak_memory_in_bytes, rel=0.02)
+    assert update.memory_analysis().temp_size_in_bytes < 1 << 22
