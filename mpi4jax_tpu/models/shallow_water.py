@@ -842,19 +842,18 @@ def _step_wide(state, cfg, comm, *, first_step=False, token=None, steps=1,
         scope = f"{ADJOINT_SCOPE}/{STEP_VJP}"
         how = dict(cfg=cfg, comm=comm, first_step=first_step, steps=steps)
         if _derives_as_kernels(cfg, comm):
-            # the derivative of a walk is the adjoint kernel's, a step at
-            # a time, at the fields the step started from; its tangent
-            # the array code's there
+            # the derivative of a walk is a kernel's in either mode, a
+            # step at a time, at the fields the step started from
+            how = dict(how, read_whole=read_whole)
             state, token = _with_derivative(
                 forward,
                 partial(_kept_for_the_kernel, first_step=first_step, steps=steps),
-                partial(_walk_forwards, **how),
-                partial(_walk_backwards, read_whole=read_whole, **how),
+                partial(_walk_forwards, **how), partial(_walk_backwards, **how),
                 scope)(*operands)
         else:
-            # no room for the adjoint kernel's blocks: the derivative is
-            # that of the array code of the walk's steps, at the state
-            # the walk started from
+            # no room for those kernels' blocks: the derivative is that
+            # of the array code of the walk's steps, at the state the
+            # walk started from
             state, token = _kept_at_its_start(
                 forward, partial(_walk_as_arrays, **how), scope)(*operands)
         return (state, ()), token
@@ -892,11 +891,12 @@ def _step_wide_arrays(state, cfg, comm, first_step, token,
 
 
 def _kernel_walk(state, token, sums, summing, *, cfg, comm, first_step, steps,
-                 coarsen):
+                 coarsen, in_place=True):
     """:func:`_step_wide` where the step is the kernel: the exchange
     without its ghost writes, then the kernel, which reads and writes
     every tile that holds a ghost cell anyway and places the received
-    slabs itself.  Returns ``((state, sums), token)``."""
+    slabs itself.  Returns ``((state, sums), token)``.  ``in_place``:
+    ``sw_kernels.wide_step``'s, not set where ``state`` is read again."""
     G = 2
     per = (False, True)
     ny_l, _nx_l = cfg.local_interior(comm)
@@ -937,7 +937,7 @@ def _kernel_walk(state, token, sums, summing, *, cfg, comm, first_step, steps,
         nu=cfg.lateral_viscosity, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
         gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
         coriolis_beta=cfg.coriolis_beta, steps=2 if lone else steps,
-        coarsen=coarsen)
+        coarsen=coarsen, in_place=in_place)
     return (SWState(*state[:6]), tuple(state[6:])), token
 
 
@@ -997,10 +997,11 @@ def _kept_at_its_start(forward, twin, scope):
 
 
 def _derives_as_kernels(cfg, comm):
-    """Whether the derivative of a step that runs as the kernel is the
-    adjoint kernel's (``sw_kernels.wide_step_vjp``): where its blocks,
-    nine arrays in and six out, fit VMEM.  From the block's shape and
-    dtype, as :func:`_runs_as_kernels` decides; a block without that
+    """Whether the derivative of a step that runs as the kernel is a
+    kernel's too, in both modes (``sw_kernels.wide_step_vjp`` backwards,
+    ``sw_kernels.wide_step_jvp`` forwards): where their blocks, nine
+    arrays in and six out either way, fit VMEM.  From the block's shape
+    and dtype, as :func:`_runs_as_kernels` decides; a block without that
     room is differentiated as its array code (:func:`_walk_as_arrays`)."""
     ny_l, nx_l = cfg.local_interior(comm)
     return _runs_as_kernels(cfg, comm) and sw_kernels.adjoint_tile_rows(
@@ -1008,30 +1009,40 @@ def _derives_as_kernels(cfg, comm):
 
 
 def _kept_for_the_kernel(state, token, *, first_step, steps):
-    """What a walk keeps for :func:`_walk_backwards`: the fields it
-    started from, which is all the adjoint kernel reads of a state (the
-    old tendencies enter a step linearly); a walk of two steps keeps its
-    tendencies too, for the state between the two."""
+    """What a walk keeps for :func:`_walk_backwards` and
+    :func:`_walk_forwards`: the fields it started from, which is all
+    either kernel reads of a state (the old tendencies enter a step
+    linearly); a walk of two steps keeps its tendencies too, for the
+    state between the two."""
     if steps == 2 and not first_step:
         return state, token
     return SWState(*state[:3], None, None, None), token
 
 
-def _walk_forwards(kept, state, token, *, cfg, comm, first_step, steps):
-    """The tangent of a walk of the step's kernel where its transpose is
-    the adjoint kernel's (:func:`_walk_backwards`): ``jax.jvp`` of the
-    walk's array code (:func:`_walk_as_arrays`) at what was kept for
-    that kernel, the tangents ``state`` and ``token`` pushed through
-    every term of the step and every exchange of it.  A single step was
-    kept without its tendencies, which enter it linearly: zeros stand in
-    for them and change no tangent."""
+def _walk_forwards(kept, state, token, *, cfg, comm, first_step, steps,
+                   read_whole):
+    """The tangent of :func:`_walk_as_arrays` where the step's
+    derivative is a kernel (:func:`_derives_as_kernels`), the mirror of
+    :func:`_walk_backwards`: for each of the walk's steps, first to
+    last, :func:`_step_forwards` at the fields that step started from,
+    on the tangents ``state`` and ``token`` of what the walk read.
+    Those of a walk's second step are not kept: the forward kernel makes
+    them again, one walk of one step.  ``read_whole``:
+    :func:`_step_wide`'s, of the walk's last step; the state between two
+    steps of a walk nobody reads."""
     at, stamp = kept
-    if not first_step and at[3] is None:
-        at = SWState(*at[:3], *(jnp.zeros_like(a) for a in at[:3]))
-    return jax.jvp(
-        partial(_walk_as_arrays, cfg=cfg, comm=comm, first_step=first_step,
-                steps=steps),
-        (at, stamp), (state, token))[1]
+    push = partial(_step_forwards, cfg=cfg, comm=comm)
+    if first_step:
+        return push(at[:3], stamp, state[:3], token, first_step=True,
+                    read_whole=read_whole)
+    if steps == 2:
+        state, token = push(at[:3], stamp, state, token, read_whole=False)
+        # (not in place: the walk of two, whose tangent this is, reads
+        # the same state)
+        (at, _), stamp = _kernel_walk(
+            at, stamp, (), True, cfg=cfg, comm=comm, first_step=False,
+            steps=1, coarsen=0, in_place=False)
+    return push(at[:3], stamp, state, token, read_whole=read_whole)
 
 
 def _walk_backwards(kept, cotangents, *, cfg, comm, first_step, steps,
@@ -1058,15 +1069,20 @@ def _walk_backwards(kept, cotangents, *, cfg, comm, first_step, steps,
     return SWState(*back(state[:3], token, ct, read_whole=read_whole)), _no_stamp(token)
 
 
-def _outermost_ring(x, ct, scale, is_south, is_north, v_is_zero=False):
+def _outermost_ring(x, ct, scale, is_south, is_north, v_is_zero=False,
+                    added=False):
     """``x`` with its ghost frame set for the exchange's transpose to
     carry ring 2 of ``ct`` home, ``scale`` times: ring 2 of ``x`` is
     that, ring 1 zero, a wall's ghost rows zero (``v_is_zero``: and the
     northern wall's own row, where ``v`` is set to zero and its
     cotangent says nothing).  Four slabs written, the columns' before
-    the rows', as an exchange writes them."""
+    the rows', as an exchange writes them.  ``added``: the same cells
+    of ``ct``, ``scale`` times, added to ``x``'s and its ghost frame
+    otherwise as it is, which is this map's transpose (``ct`` then a
+    block an exchange has just filled: :func:`_step_forwards`)."""
     G = 2
     rows, width = ct.shape
+    as_it_came = x
     for region, at in ((np.s_[:, :G], (0, 0)), (np.s_[:, -G:], (0, width - G)),
                        (np.s_[:G, :], (0, 0)), (np.s_[-G:, :], (rows - G, 0))):
         slab = ct[region]
@@ -1077,6 +1093,8 @@ def _outermost_ring(x, ct, scale, is_south, is_north, v_is_zero=False):
             is_north & (r >= rows - G - int(v_is_zero)))
         slab = jnp.where(outermost & ~walled, slab * jnp.asarray(scale, slab.dtype),
                          jnp.zeros((), slab.dtype))
+        if added:
+            slab = as_it_came[region] + slab
         x = lax.dynamic_update_slice(x, slab, at)
     return x
 
@@ -1148,6 +1166,70 @@ def _step_backwards(fields, token, cotangents, *, ct_token, cfg, comm,
         coriolis_beta=cfg.coriolis_beta)
     ch, cu, cv = (send(ct) for send, ct in zip(home, (ch, cu, cv)))
     return ch, cu, cv, cdh, to_u(cdu), to_v(cdv)
+
+
+def _step_forwards(fields, token, tangents, t_token, *, cfg, comm,
+                   read_whole, first_step=False):
+    """One step of the kernel's walk pushed forwards, the mirror of
+    :func:`_step_backwards`: the six tangents of the state a step read
+    (a first step's: the three fields') to ``(the six of its results,
+    the token's)``, ``fields`` being the ``h``, ``u``, ``v`` it started
+    from.
+
+    The exchange's tangent is the exchange of the tangents
+    (``jax.linearize`` of ``halo_exchange_2d``, which is linear:
+    ``parallel/halo.py _transposable``): the kept fields get fresh
+    ghosts and the three field tangents theirs, one exchange each, and
+    so do the old ``du``, ``dv``'s, whose ring 1 the kernel's round 1
+    reads (the neighbours'; the old ``dh``'s is the interior's alone).
+    Five exchanges of tangents, as :func:`_step_backwards` transposes
+    five, then ``sw_kernels.wide_step_jvp``.  Where the state is read
+    whole (:func:`_step_wide`) and there is friction, the results ``u``,
+    ``v`` on ring 2 are the neighbours' round 1, of which ``a dt`` of
+    the new tendency's tangent is missing after the kernel: one exchange
+    more of the new ``du``, ``dv``'s tangents each, whose ring 2 is
+    added there (:func:`_outermost_ring`)."""
+    G = 2
+    ny_l, _nx_l = cfg.local_interior(comm)
+    is_north, is_south = _wall_masks(comm)
+    iy, _ix = _device_coords(comm)
+
+    def exchange(x, token):
+        return halo_exchange_2d(
+            x, comm, periodic=(False, True), token=token, width=G)
+
+    fresh, there = [], []
+    for x in fields:
+        (x, token), pushed = jax.linearize(exchange, x, token)
+        fresh.append(x)
+        there.append(pushed)
+
+    def exchanged(pushes, arrays, t_token):
+        out = []
+        for pushed, x in zip(pushes, arrays):
+            x, t_token = pushed(x, t_token)
+            out.append(x)
+        return out, t_token
+
+    (th, tu, tv), t_token = exchanged(there, tangents[:3], t_token)
+    if first_step:
+        a, b = 1.0, 0.0
+        tdh = tdu = tdv = jnp.zeros_like(th)
+    else:
+        a, b = cfg.ab_a, cfg.ab_b
+        tdh = tangents[3]
+        (tdu, tdv), t_token = exchanged(there[1:], tangents[4:], t_token)
+    th, tu, tv, tdh, tdu, tdv = sw_kernels.wide_step_jvp(
+        *fresh, (th, tu, tv, tdh, tdu, tdv), is_south, is_north, iy * ny_l, a, b,
+        nu=cfg.lateral_viscosity, dx=cfg.dx, dy=cfg.dy, dt=cfg.dt,
+        gravity=cfg.gravity, coriolis_f=cfg.coriolis_f,
+        coriolis_beta=cfg.coriolis_beta)
+    if cfg.lateral_viscosity > 0 and read_whole:
+        rest = (a * cfg.dt, is_south, is_north)
+        (theirs_u, theirs_v), t_token = exchanged(there[1:], (tdu, tdv), t_token)
+        tu = _outermost_ring(tu, theirs_u, *rest, added=True)
+        tv = _outermost_ring(tv, theirs_v, *rest, v_is_zero=True, added=True)
+    return SWState(th, tu, tv, tdh, tdu, tdv), t_token
 
 
 def _walk_as_arrays(state, token, *, cfg, comm, first_step, steps):
@@ -1336,7 +1418,8 @@ def _mesh_specs(comm):
     return SWState(*([spec] * 6))
 
 
-def _call_of_steps(state, sums=(), *, cfg, comm, num_steps, coarsen=0):
+def _call_of_steps(state, sums=(), *, cfg, comm, num_steps, coarsen=0,
+                   read_whole=True):
     """One device's part of :func:`make_multistep`: ``num_steps`` steps
     in one loop, two a walk where the kernel can
     (:func:`_walks_two_steps`), an odd count's last a single step's
@@ -1344,7 +1427,9 @@ def _call_of_steps(state, sums=(), *, cfg, comm, num_steps, coarsen=0):
     writes the snapshot's sums) the call's last walk stands apart and
     writes them into ``sums``, and ``(state, sums)`` comes back; without
     it the state does.  :func:`make_gradient`'s forward sweep runs its
-    calls through this too: what is differentiated is what is timed."""
+    calls through this too: what is differentiated is what is timed.
+    ``read_whole``: :func:`_step_wide`'s, of the walks of two in the
+    loop (a derivative's business alone: the steps are the same)."""
     # steps a walk of the kernel (``_step_wide``)
     stride = 2 if _walks_two_steps(cfg, comm) else 1
     # the walks of a call, the last one apart where it writes the sums
@@ -1361,7 +1446,8 @@ def _call_of_steps(state, sums=(), *, cfg, comm, num_steps, coarsen=0):
         elif stride == 1:
             s, _tok = shallow_water_step(s, cfg, comm)
         else:
-            (s, _), _tok = _step_wide(s, cfg, comm, steps=stride)
+            (s, _), _tok = _step_wide(
+                s, cfg, comm, steps=stride, read_whole=read_whole)
         return s, sums
 
     if looped:
@@ -1539,8 +1625,10 @@ def _window(cfg, comm, num_steps, observe):
             lambda s, _: (one_step(s), None), state, None, length=num_steps)[0])
 
     def call(state):
+        # a tangent sweep's states are read as the backward sweep's are:
+        # by the next step, and by the observation's mean
         return SWState(*_call_of_steps(
-            state, cfg=cfg, comm=comm, num_steps=num_steps))
+            state, cfg=cfg, comm=comm, num_steps=num_steps, read_whole=False))
 
     def misfit(h, y):
         with _adjoint_scope(COST):
@@ -1925,11 +2013,13 @@ def make_tangent(cfg, comm, *, calls, num_steps, observe=1):
     A step's tangent is ``jax.jvp`` of the step: of its array code
     where it is array code, exchange by exchange (the exchange's tangent
     is the exchange of the tangents, ``parallel/halo.py``); where the
-    step is the kernel the walk runs as the kernel and its tangent is
-    that of the walk's array code at the same state
-    (:func:`_walk_forwards`), two steps a walk where the call walks two.
-    Nothing is approximated and nothing is kept between calls but the
-    tangent state, six arrays.  Under ``sw/adjoint/tangent``."""
+    step is the kernel the walk runs as the kernel and each of its
+    steps' tangents is a kernel's too (``sw_kernels.wide_step_jvp``, at
+    the fields the step started from: :func:`_walk_forwards`; on a block
+    without room for it, ``jax.jvp`` of the walk's array code), two
+    steps a walk where the call walks two.  Nothing is approximated and
+    nothing is kept between calls but the tangent state, six arrays.
+    Under ``sw/adjoint/tangent``."""
     window = _window(cfg, comm, num_steps, observe)
     _checked_window(cfg, comm, calls, observe)
 
@@ -2109,7 +2199,8 @@ class InnerLoop:
     ``trace`` (a :class:`mpi4jax_tpu.utils.spans.Recorder`) keeps the
     host's spans, ``mpi4jax_tpu.incremental/linearise``,
     ``incremental/enqueue`` an iteration and ``incremental/wait`` a
-    wait.  :meth:`stats` counts what was run and what is held.
+    wait.  :meth:`stats` counts what was run and what is held;
+    :attr:`tangent_walks` says what the tangent sweep's steps are.
     """
 
     def __init__(self, cfg, comm, *, calls, num_steps, weight, observe=1,
@@ -2185,6 +2276,17 @@ class InnerLoop:
     def increment(self):
         """``(dh0, du0, dv0)`` as the loop has it."""
         return self.vectors[0]
+
+    @property
+    def tangent_walks(self):
+        """What pushes a walk's tangent forwards in the tangent-linear
+        sweep: ``"kernel"`` (``sw_kernels.wide_step_jvp``) or
+        ``"arrays"`` (``jax.jvp`` of the step's array code: the step is
+        array code, or its block has no room for the kernel).
+        :func:`_derives_as_kernels`' say, on the host: no program runs.
+        (Not a key of :meth:`stats`, whose keys a test of the benchmark
+        holds to a fixed set.)"""
+        return "kernel" if _derives_as_kernels(self.cfg, self.comm) else "arrays"
 
     def held(self):
         """The arrays held on the mesh between an iteration's programs:

@@ -10,9 +10,10 @@ written once, in place: 12 passes over a field a walk, the least a walk
 can move.  A walk advances **two time steps**, on one device and beside
 neighbours ("Two steps a walk", below): 12 passes where two walks move
 24, which its vector work keeps up with to within a few per cent of
-HBM's pace.  A step's **derivative** is a kernel as well
-(:func:`wide_step_vjp`, "The adjoint walk" below): 15 passes, the three
-kept fields and six cotangents in, six cotangents out.
+HBM's pace.  A step's **derivative** is a kernel as well, in either
+mode (:func:`wide_step_vjp`, "The adjoint walk" below; :func:`wide_step_jvp`,
+"The tangent walk"): 15 passes, the three kept fields and six
+cotangents or tangents in, six out.
 
 Schedule: three exchanges, not five
 -----------------------------------
@@ -352,6 +353,50 @@ lanes past its width) is whatever VMEM held: the kept fields are set to
 rest there (``h`` one, ``u``, ``v`` zero), so that a zero cotangent
 times what is made of them is zero and not a NaN.
 
+The tangent walk
+----------------
+Under ``jax.jvp`` (``jax.linearize``, ``jax.jacfwd``) a step that ran as
+the kernel is pushed forwards by a kernel too (:func:`wide_step_jvp`,
+which ``shallow_water._step_wide``'s tangent calls once a step): the
+array code's tangent was XLA's fusions, 113 passes over a field a step
+by the count that sees less than they read, 30 ms a step where this
+moves 15 passes (``PERF.md``, PRs 59, 60).  The adjoint walk's mirror,
+on the adjoint walk's blocks (:func:`_derivative_walk` is both): it
+**reads** the ``h``, ``u``, ``v`` the step started from, ghosts fresh,
+and the six tangents of what the step read, the fields' after the same
+exchange and the old tendencies' with ring 1 of ``du``, ``dv`` the
+neighbours'; it **writes** the six tangents of the step's results, each
+where its own operand lay, a tile behind.
+
+The stage (:func:`_tangent_stage`) is the step's two rounds on the
+tangents, in the single walk's schedule: ``fe``, ``fn``, ``q`` and the
+depth made again from the kept fields, as the adjoint stage makes them,
+each product's tangent taken beside it (the step is linear in the old
+tendencies, nonlinear in ``q (fn + fn_e)``, ``q (fe + fe_n)``, ``hx
+u``, ``(h + h_n) v``, the squares and the division); round 1 on the
+interior and, for ``u``, ``v``, on ring 1 too, ``v1 = 0`` on the
+northern wall's row, friction after it with no exchange between.  Its
+masks are the adjoint stage's, so that the two kernels are each other's
+transpose cell for cell, ghost cells included
+(``tests/test_sw_kernels_tangent.py``): what one reads of a ghost cell
+the other writes there.  The results' ghost cells are the array code's
+as far as one chip can say: ring 2 of ``u``, ``v`` lacks what only the
+neighbour's round 1 knows, which the caller adds where anybody reads it
+(``shallow_water._step_forwards``).  A stage takes rows with a strip of
+halo rows either side as one value and throws the halo away, as the
+adjoint stage does and for its reason: the kernel is bound by HBM, not
+by its vector work (2.39 ms a step at 7204 columns, 79.5 % of the
+table's bandwidth for its 15 passes, the adjoint kernel's 2.43 beside
+it: ``PERF.md``, PR 60).  Nothing beyond the block reaches a cell that is
+written (a cell reads the ring round it and selections drop the rest),
+so nothing is set to rest here.
+
+A walk of two steps is two calls with the state between them made again
+by the forward kernel, one walk of one step, **not in place**
+(``wide_step(in_place=False)``): the walk of two whose tangent this is
+reads the same state, and a call in place would cost a copy of each of
+its six arrays first.
+
 Building a kernel is set-up a user waits for, so it is kept short:
 ``jax.experimental.pallas`` is imported by :func:`pallas` where a step is
 built for TPU devices (the array code, which every other backend runs,
@@ -496,7 +541,7 @@ def holds_further(rows, width, dtype, further, arrays=6):
 
 def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
           steps=1, summed=(), coarsen=0, summing=True, sums=(),
-          point_slabs=(), *, interpret):
+          point_slabs=(), *, in_place=True, interpret):
     """One call on the tiling above: ``fields`` (one device's padded
     blocks, all of one shape and dtype) are updated in place behind
     their windows, and ``pointwise`` arrays of the same shape are read
@@ -564,6 +609,12 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
     is not set the walk passes the sums over, writes none of their
     blocks but the first, with whatever VMEM held, and hands ``sums``
     back otherwise as they came.
+
+    ``in_place`` not set: the new ``fields`` and ``pointwise`` are
+    arrays of their own and the call consumes none, for a caller that
+    still reads what the walk started from (every block of a result is
+    written whole, so nothing of it was ever the operand's: where the
+    operands live on, a call in place costs a copy of each before it).
     """
     pl, pltpu = pallas()
     rows, width = fields[0].shape
@@ -1010,10 +1061,12 @@ def _walk(body, scalars, fields, slabs, pointwise, n_second, n_carried,
         kernel,
         **specs,
         out_shape=[struct] * (n_fields + n_point) + [sums_struct] * n_summed,
+        # the sums' room always; the fields and the pointwise arrays
+        # where the walk is in place
         input_output_aliases={
-            **{first_field + k: k for k in range(n_fields)},
+            **({first_field + k: k for k in range(n_fields)} if in_place else {}),
             **{first_field + n_fields + len(arrived) + k: n_fields + k
-               for k in range(n_point + n_summed)}},
+               for k in range(0 if in_place else n_point, n_point + n_summed)}},
         compiler_params=pltpu.CompilerParams(
             # in order: a step reads the window the step before left
             dimension_semantics=("arbitrary",),
@@ -1325,11 +1378,12 @@ def _stages(roll, rows, width, dtype, nu, dx, dy, dt, gravity, coriolis_f,
 @functools.partial(
     jax.jit,
     static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
-                     "coriolis_beta", "steps", "coarsen", "interpret"))
+                     "coriolis_beta", "steps", "coarsen", "in_place",
+                     "interpret"))
 def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
               a, b, lone=False, summing=True, sums=(), *, nu, dx, dy, dt,
               gravity, coriolis_f, coriolis_beta, steps=1, coarsen=0,
-              interpret=False):
+              in_place=True, interpret=False):
     """A step of :func:`shallow_water._step_wide` after the wire of its
     first halo exchange, with no second one: the ghost writes of the
     first, the tendencies of ``h``, ``u`` and
@@ -1374,7 +1428,9 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
     returns what a call of one step returns from zero tendencies, which
     is what a run's first step is: a process then builds one kernel for
     its first step and the rest (the walk takes a double walk's time,
-    once a run).
+    once a run).  ``in_place``: :func:`_walk`'s; not set by who reads
+    the state again after the call (a tangent walk's state between its
+    two steps, ``shallow_water._walk_forwards``).
     """
     rows, width = h.shape
     dtype = h.dtype
@@ -1443,7 +1499,7 @@ def wide_step(h, u, v, dh, du, dv, slabs, is_south, is_north, first_row,
                  n_carried=4, steps=steps,
                  summed=(0, 1, 2) if coarsen else (), coarsen=coarsen,
                  summing=summing, sums=sums, point_slabs=slabs[3:],
-                 interpret=interpret)
+                 in_place=in_place, interpret=interpret)
 
 
 # what the adjoint walk's blocks take of VMEM, in a forward walk's
@@ -1611,10 +1667,228 @@ def _adjoint_stage(roll, rows, width, dtype, nu, dx, dy, dt, gravity,
     return jax.jit(back)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
-                     "coriolis_beta", "interpret"))
+@functools.lru_cache
+def _tangent_stage(roll, rows, width, dtype, nu, dx, dy, dt, gravity,
+                   coriolis_f, coriolis_beta):
+    """The tangent of :func:`_stages`' ``first`` and ``second``, in that
+    order, on rows taller than a strip, as :func:`_adjoint_stage` is
+    their transpose: ``push(scalars, g, kept, tangents)`` is handed the
+    rows' numbers, the kept ``h``, ``u``, ``v`` with fresh ghosts and
+    the six tangents of what the step read (the fields' after their
+    exchange, the old tendencies' with their ghost cells the
+    neighbours'), and returns the six tangents of the step's results.
+    A row's neighbours are rotations of the rows handed in, as there:
+    two rows spoilt at either end, of the strip the caller brings.
+    Jitted and kept like the stages.
+
+    ``fe``, ``fn``, ``q`` and the depth are made again from the kept
+    fields, as the adjoint stage makes them, and each product's tangent
+    is taken beside it; the update, the northern wall's row and the
+    friction are the step's own on the tangents, under the step's
+    masks.  What lies beyond the block is whatever VMEM held, and
+    reaches no cell that is updated: a row or a column reads the ring
+    round it, a selection drops the rest."""
+    lanes = _whole_registers(width)
+    inv_dx, inv_dy = 1.0 / dx, 1.0 / dy
+    kx, ky = nu * dt / (dx * dx), nu * dt / (dy * dy)
+    across = functools.partial(_across, lanes)
+    box = functools.partial(_box, width)
+
+    def push(scalars, g, kept, tangents):
+        (a, b, first_row, south_ghost_row, south_wall_row, north_wall_row,
+         north_ghost_row, inner_from, inner_to, reach_from, reach_to,
+         open_from, open_to) = scalars
+        h, u, v = kept
+        th, tu, tv, tdh, tdu, tdv = tangents
+        tall = h.shape[0]
+        zero = lax.full(h.shape, 0, dtype)
+
+        def east(x):
+            return roll(x, lanes - 1, 1)
+
+        def west(x):
+            return roll(x, 1, 1)
+
+        def north(x):
+            return roll(x, tall - 1, 0)
+
+        def south(x):
+            return roll(x, 1, 0)
+
+        def only(where, x):
+            return select(where, x, zero)
+
+        def unless(where, x):
+            return select(where, zero, x)
+
+        # the adjoint stage's cells: the interior; round 1's of u, v
+        # (ring 1 too where round 2 reads it); every cell of the rows no
+        # wall's ghost rows are among, where a neighbour's old tendency
+        # steps what the neighbour computes
+        interior = box(g, inner_from, inner_to, 0)
+        reach = box(g, reach_from, reach_to, 1) if nu > 0 else interior
+        open_rows = box(g, open_from, open_to, G) if nu > 0 else interior
+        sg, sw = across(eq(g, south_ghost_row)), across(eq(g, south_wall_row))
+        nw, ng = across(eq(g, north_wall_row)), across(eq(g, north_ghost_row))
+        wall = lax.bitwise_or(nw, sg)
+
+        # the step's products again, from the kept fields
+        hx = add(h, east(h))
+        hy = add(h, north(h))
+        fe = unless(ng, mul(mul(hx, 0.5), u))
+        fn = unless(wall, mul(mul(hy, 0.5), v))
+        depth = div(lax.full(h.shape, 1, dtype),
+                    add(hx, select(nw, hx, north(hx))))
+        y = mul(add(lax.convert_element_type(sub(g, G), dtype), first_row), dy)
+        vorticity = add(
+            across(add(mul(y, coriolis_beta), coriolis_f)),
+            sub(mul(sub(east(v), v), inv_dx), mul(sub(north(u), u), inv_dy)))
+        q = unless(sg, mul(vorticity, depth))
+
+        # their tangents: of the fluxes, of the vorticity's quarter (a
+        # quotient's: the depth's sum takes q of it), of the products
+        # that du and dv take of those, of the energy
+        thx = add(th, east(th))
+        t_fe = unless(ng, mul(add(mul(thx, u), mul(hx, tu)), 0.5))
+        t_fn = unless(wall, mul(
+            add(mul(add(th, north(th)), v), mul(hy, tv)), 0.5))
+        t_vorticity = sub(
+            mul(sub(east(tv), tv), inv_dx), mul(sub(north(tu), tu), inv_dy))
+        t_q = unless(sg, mul(
+            sub(t_vorticity, mul(q, add(thx, select(nw, thx, north(thx))))),
+            depth))
+        t_qf = add(mul(t_q, add(fn, east(fn))), mul(q, add(t_fn, east(t_fn))))
+        t_qe = add(mul(t_q, add(fe, north(fe))), mul(q, add(t_fe, north(t_fe))))
+        ut, vt = mul(u, tu), mul(v, tv)
+        t_ke = unless(ng, mul(add(add(ut, west(ut)), add(vt, south(vt))), 0.5))
+
+        # the tendencies', as `first` makes the tendencies
+        t_dh = sub(mul(sub(west(t_fe), t_fe), inv_dx),
+                   mul(sub(t_fn, south(t_fn)), inv_dy))
+        t_du = sub(
+            add(mul(sub(east(th), th), -gravity * inv_dx), add(t_qf, south(t_qf))),
+            mul(sub(east(t_ke), t_ke), inv_dx))
+        t_dv = sub(
+            sub(mul(sub(north(th), th), -gravity * inv_dy), add(t_qe, west(t_qe))),
+            mul(sub(north(t_ke), t_ke), inv_dy))
+
+        # x1 = x + dt (a T + b old): the new tendency on the cells this
+        # walk computes, the old one wherever a neighbour's walk does
+        a_dt, b_dt = mul(a, dt), mul(b, dt)
+        th = add(th, only(interior, add(mul(t_dh, a_dt), mul(tdh, b_dt))))
+        tu = add(tu, add(only(reach, mul(t_du, a_dt)),
+                         only(open_rows, mul(tdu, b_dt))))
+        tv = unless(nw, add(tv, add(only(reach, mul(t_dv, a_dt)),
+                                    only(open_rows, mul(tdv, b_dt)))))
+        if nu > 0:
+            def friction(c):
+                # the five points', no gradient across the southern
+                # wall's face
+                return add(c, only(interior, add(
+                    mul(add(sub(east(c), add(c, c)), west(c)), kx),
+                    mul(sub(sub(north(c), c), unless(sw, sub(c, south(c)))), ky))))
+
+            tu, tv = friction(tu), unless(nw, friction(tv))
+        return (th, tu, tv, only(interior, t_dh), only(interior, t_du),
+                only(interior, t_dv))
+
+    return jax.jit(push)
+
+
+def _derivative_walk(stage, h, u, v, six, is_south, is_north, first_row, a, b,
+                     constants, interpret):
+    """The walk of a step's derivative, either mode's: the kept ``h``,
+    ``u``, ``v`` and ``six`` arrays in, six out where the six lay, a
+    tile behind ("The adjoint walk", "Tiling").  ``stage``: the builder
+    of what maps a group of strips of the nine to the six
+    (:func:`_adjoint_stage`, :func:`_tangent_stage`), handed
+    ``constants`` after the block's shape and dtype."""
+    pl, pltpu = pallas()
+    rows, width = h.shape
+    lanes = _whole_registers(width)
+    dtype = h.dtype
+    arrays = [h, u, v, *six]
+    n_in, n_out = len(arrays), len(six)
+    tile = adjoint_tile_rows(rows, width, dtype)
+    tiles = -(-rows // tile)
+    strips = tile // STRIP
+    group = max(n for n in range(1, _ADJOINT_STRIPS + 1) if strips % n == 0)
+    tall = (group + 2) * STRIP
+    axes = vma_of(h) or ()
+    flags = promote_vma(jnp.stack([is_south, is_north]).astype(jnp.int32), axes)
+    floats = promote_vma(
+        jnp.stack([jnp.asarray(x, dtype) for x in (a, b, first_row)]), axes)
+
+    def kernel(flag_ref, float_ref, *refs):
+        taken, out, windows = refs[:n_in], refs[n_in:n_in + n_out], refs[n_in + n_out:]
+        i = pl.program_id(0)
+        through = stage(pltpu.roll, rows, width, dtype, *constants)
+        at_south, at_north = eq(flag_ref[0], 1), eq(flag_ref[1], 1)
+
+        def row(x, where):
+            return select(where, jnp.int32(x), jnp.int32(_NO_ROW))
+
+        scalars = (
+            float_ref[0], float_ref[1], float_ref[2],
+            row(G - 1, at_south), row(G, at_south),
+            row(rows - G - 1, at_north), row(rows - G, at_north),
+            jnp.int32(G), jnp.int32(rows - G),
+            select(at_south, jnp.int32(G), jnp.int32(G - 1)),
+            select(at_north, jnp.int32(rows - G), jnp.int32(rows - G + 1)),
+            select(at_south, jnp.int32(G), jnp.int32(0)),
+            select(at_north, jnp.int32(rows - G), jnp.int32(rows)))
+        r = lax.broadcasted_iota(jnp.int32, (tall, LANES), 0)
+
+        # a window's rows: the last strip of tile i - 2, tile i - 1, and
+        # the first strip of the tile just handed in (_walk's window)
+        for ref, win in zip(taken, windows):
+            win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
+
+        @pl.when(lax.gt(i, 0))
+        def _():
+            def run(j, carry):
+                first = pl.multiple_of(mul(j, group * STRIP), STRIP)
+                # the rows' numbers in the block, from the halo strip on
+                g = add(r, add(mul(sub(i, 1), tile), sub(first, STRIP)))
+                values = [win[pl.ds(first, tall), :] for win in windows]
+                new = through(scalars, g, values[:3], values[3:])
+                for ref, x in zip(out, new):
+                    ref[pl.ds(first, group * STRIP), :] = lax.slice_in_dim(
+                        x, STRIP, tall - STRIP)
+                return carry
+
+            lax.fori_loop(0, strips // group, run, 0)
+
+        for ref, win in zip(taken, windows):
+            win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
+            win[pl.ds(STRIP, tile), :] = ref[...]
+
+    struct = union_vma_struct(h.shape, dtype, *arrays, flags, floats)
+    in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        kernel,
+        grid=(tiles + 1,),
+        in_specs=[in_smem] * 2 + [pl.BlockSpec(
+            (tile, lanes), lambda i: (lax.min(i, tiles - 1), 0))] * n_in,
+        out_specs=[pl.BlockSpec(
+            (tile, lanes), lambda i: (lax.max(i - 1, 0), 0))] * n_out,
+        scratch_shapes=[pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_in,
+        out_shape=[struct] * n_out,
+        # each of the six is written where it was read, a tile behind
+        input_output_aliases={2 + 3 + k: k for k in range(n_out)},
+        compiler_params=pltpu.CompilerParams(
+            # in order: a step reads the window the step before left
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT * 3 // 2),
+        interpret=interpret,
+    )(flags, floats, *(promote_vma(x, axes) for x in arrays))
+
+
+_DERIVATIVE_STATICS = ("nu", "dx", "dy", "dt", "gravity", "coriolis_f",
+                       "coriolis_beta", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_DERIVATIVE_STATICS)
 def wide_step_vjp(h, u, v, cotangents, is_south, is_north, first_row, a, b,
                   *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
                   interpret=False):
@@ -1649,84 +1923,41 @@ def wide_step_vjp(h, u, v, cotangents, is_south, is_north, first_row, a, b,
     :func:`wide_step`'s; the caller has checked
     :func:`adjoint_tile_rows`.
     """
-    pl, pltpu = pallas()
-    rows, width = h.shape
-    lanes = _whole_registers(width)
-    dtype = h.dtype
-    arrays = [h, u, v, *cotangents]
-    n_in, n_out = len(arrays), len(cotangents)
-    tile = adjoint_tile_rows(rows, width, dtype)
-    tiles = -(-rows // tile)
-    strips = tile // STRIP
-    group = max(n for n in range(1, _ADJOINT_STRIPS + 1) if strips % n == 0)
-    tall = (group + 2) * STRIP
-    axes = vma_of(h) or ()
-    flags = promote_vma(jnp.stack([is_south, is_north]).astype(jnp.int32), axes)
-    floats = promote_vma(
-        jnp.stack([jnp.asarray(x, dtype) for x in (a, b, first_row)]), axes)
+    return _derivative_walk(
+        _adjoint_stage, h, u, v, cotangents, is_south, is_north, first_row, a, b,
+        (nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta), interpret)
 
-    def kernel(flag_ref, float_ref, *refs):
-        taken, out, windows = refs[:n_in], refs[n_in:n_in + n_out], refs[n_in + n_out:]
-        i = pl.program_id(0)
-        back = _adjoint_stage(
-            pltpu.roll, rows, width, dtype, nu, dx, dy, dt, gravity,
-            coriolis_f, coriolis_beta)
-        at_south, at_north = eq(flag_ref[0], 1), eq(flag_ref[1], 1)
 
-        def row(x, where):
-            return select(where, jnp.int32(x), jnp.int32(_NO_ROW))
+@functools.partial(jax.jit, static_argnames=_DERIVATIVE_STATICS)
+def wide_step_jvp(h, u, v, tangents, is_south, is_north, first_row, a, b,
+                  *, nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta,
+                  interpret=False):
+    """The tangent of one step of :func:`wide_step` at the state it
+    started from, as one kernel ("The tangent walk" in the module's
+    docstring): :func:`wide_step_vjp`'s transpose, cell for cell.
 
-        scalars = (
-            float_ref[0], float_ref[1], float_ref[2],
-            row(G - 1, at_south), row(G, at_south),
-            row(rows - G - 1, at_north), row(rows - G, at_north),
-            jnp.int32(G), jnp.int32(rows - G),
-            select(at_south, jnp.int32(G), jnp.int32(G - 1)),
-            select(at_north, jnp.int32(rows - G), jnp.int32(rows - G + 1)),
-            select(at_south, jnp.int32(G), jnp.int32(0)),
-            select(at_north, jnp.int32(rows - G), jnp.int32(rows)))
-        r = lax.broadcasted_iota(jnp.int32, (tall, LANES), 0)
+    ``h``, ``u``, ``v``: the kept fields **with fresh ghosts**, as the
+    step's first exchange left them.  ``tangents``: of the six arrays
+    the step read, padded like them: the fields' after the same
+    exchange, the old tendencies' with ring 1 of ``du``, ``dv`` the
+    neighbours' (an exchange's, or what a step before computed there:
+    ``sw_kernels``, "Schedule"); ``dh``'s ghost cells are not read.
 
-        # a window's rows: the last strip of tile i - 2, tile i - 1, and
-        # the first strip of the tile just handed in (_walk's window)
-        for ref, win in zip(taken, windows):
-            win[pl.ds(tile + STRIP, STRIP), :] = ref[pl.ds(0, STRIP), :]
-
-        @pl.when(lax.gt(i, 0))
-        def _():
-            def run(j, carry):
-                first = pl.multiple_of(mul(j, group * STRIP), STRIP)
-                # the rows' numbers in the block, from the halo strip on
-                g = add(r, add(mul(sub(i, 1), tile), sub(first, STRIP)))
-                values = [win[pl.ds(first, tall), :] for win in windows]
-                new = back(scalars, g, values[:3], values[3:])
-                for ref, x in zip(out, new):
-                    ref[pl.ds(first, group * STRIP), :] = lax.slice_in_dim(
-                        x, STRIP, tall - STRIP)
-                return carry
-
-            lax.fori_loop(0, strips // group, run, 0)
-
-        for ref, win in zip(taken, windows):
-            win[pl.ds(0, STRIP), :] = win[pl.ds(tile, STRIP), :]
-            win[pl.ds(STRIP, tile), :] = ref[...]
-
-    struct = union_vma_struct(h.shape, dtype, *arrays, flags, floats)
-    in_smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(tiles + 1,),
-        in_specs=[in_smem] * 2 + [pl.BlockSpec(
-            (tile, lanes), lambda i: (lax.min(i, tiles - 1), 0))] * n_in,
-        out_specs=[pl.BlockSpec(
-            (tile, lanes), lambda i: (lax.max(i - 1, 0), 0))] * n_out,
-        scratch_shapes=[pltpu.VMEM((tile + 2 * STRIP, lanes), dtype)] * n_in,
-        out_shape=[struct] * n_out,
-        # a cotangent is written where it was read, a tile behind
-        input_output_aliases={2 + 3 + k: k for k in range(n_out)},
-        compiler_params=pltpu.CompilerParams(
-            # in order: a step reads the window the step before left
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT * 3 // 2),
-        interpret=interpret,
-    )(flags, floats, *(promote_vma(x, axes) for x in arrays))
+    Returns the six tangents of the step's results.  On the interior
+    both rounds', and the new tendencies'.  On the ghost cells what the
+    array code's results hold there, as far as one chip can say: all of
+    ``h``'s, a wall's ghost rows of ``u``, ``v`` and without friction
+    all of theirs pass through; with friction ring 1 of ``u``, ``v`` is
+    round 1's tangent, computed here as the neighbour computes it, and
+    ring 2, which only the neighbour computes, the field's tangent and
+    ``b dt`` of the old tendency's, to which **the caller adds** ``a
+    dt`` **of the neighbour's new tendency's** after the call (an
+    exchange of the results ``du``, ``dv``:
+    ``shallow_water._step_forwards``, where the state is read whole).
+    The ghost cells of the tendencies' tangents come back zero.  The
+    scalars are :func:`wide_step`'s; the caller has checked
+    :func:`adjoint_tile_rows`.
+    """
+    return _derivative_walk(
+        _tangent_stage, h, u, v, tangents, is_south, is_north, first_row, a, b,
+        (nu, dx, dy, dt, gravity, coriolis_f, coriolis_beta), interpret)

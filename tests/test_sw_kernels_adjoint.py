@@ -41,7 +41,8 @@ def _interpreted(monkeypatch):
     list that the adjoint kernel's calls are noted in."""
     calls = []
     wide_step, wide_step_vjp = sw_kernels.wide_step, sw_kernels.wide_step_vjp
-    for kernel in (wide_step, wide_step_vjp):
+    wide_step_jvp = sw_kernels.wide_step_jvp
+    for kernel in (wide_step, wide_step_vjp, wide_step_jvp):
         kernel.clear_cache()
 
     def adjoint(*args, **kwargs):
@@ -52,6 +53,9 @@ def _interpreted(monkeypatch):
         sw_kernels, "wide_step",
         lambda *args, **kwargs: wide_step(*args, **dict(kwargs, interpret=True)))
     monkeypatch.setattr(sw_kernels, "wide_step_vjp", adjoint)
+    monkeypatch.setattr(
+        sw_kernels, "wide_step_jvp",
+        lambda *args, **kwargs: wide_step_jvp(*args, **dict(kwargs, interpret=True)))
     monkeypatch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: True)
     # Pallas's interpreter slices blocks at indices that vary over no
     # mesh axis, which shard_map's checker refuses
@@ -244,8 +248,8 @@ def test_the_dot_product_identity(mesh_shape, walk, through, monkeypatch):
     """``<J x, y> == <x, J^T y>``: ``J^T y`` by the adjoint kernel, ``J
     x`` by ``jax.jvp`` of the array code (its exchanges' tangents the
     exchanges of the tangents) and by ``jax.jvp`` of the kernel's walk
-    itself, whose tangent is written out as the array code's at what the
-    adjoint kernel keeps (``_walk_forwards``)."""
+    itself, whose tangent is the tangent kernel's at what the adjoint
+    kernel keeps (``_walk_forwards``)."""
     comm = _comm(mesh_shape)
     cfg = _Viscous(ny=10 * mesh_shape[0], nx=20 * mesh_shape[1], nu=0.2, **UNIT)
     calls = _interpreted(monkeypatch)

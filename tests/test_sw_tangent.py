@@ -80,7 +80,8 @@ def _interpreted(monkeypatch):
     """The step forced through its kernels, interpreted
     (``tests/test_sw_adjoint.py`` does the same)."""
     wide_step, wide_step_vjp = sw_kernels.wide_step, sw_kernels.wide_step_vjp
-    for kernel in (wide_step, wide_step_vjp):
+    wide_step_jvp = sw_kernels.wide_step_jvp
+    for kernel in (wide_step, wide_step_vjp, wide_step_jvp):
         kernel.clear_cache()
     walks = []
 
@@ -92,6 +93,9 @@ def _interpreted(monkeypatch):
     monkeypatch.setattr(
         sw_kernels, "wide_step_vjp",
         lambda *args, **kwargs: wide_step_vjp(*args, **dict(kwargs, interpret=True)))
+    monkeypatch.setattr(
+        sw_kernels, "wide_step_jvp",
+        lambda *args, **kwargs: wide_step_jvp(*args, **dict(kwargs, interpret=True)))
     monkeypatch.setattr(sw, "_runs_as_kernels", lambda cfg, comm: True)
     # Pallas's interpreter slices blocks at indices that vary over no
     # mesh axis, which shard_map's checker refuses
@@ -146,8 +150,8 @@ def test_jvp_through_the_programs_is_the_plain_solvers(
     """``jax.jvp``, ``jax.linearize`` through a step and a call against
     ``jax.jvp`` of the plain ``jax.numpy`` solver: as array code, and
     where the step is the kernel (interpreted: the first step a walk of
-    two with one passed over, the call walks of two, each one's tangent
-    its array code's at the same state)."""
+    two with one passed over, the call walks of two, each step's tangent
+    the tangent kernel's at the fields the step started from)."""
     comm = _comm(mesh_shape)
     cfg = sw.SWConfig(ghost=2, **CFG)
     at, d, _ = _seeded(cfg.ny, cfg.nx, jnp.float32)
@@ -155,7 +159,10 @@ def test_jvp_through_the_programs_is_the_plain_solvers(
     run = _solver(cfg, comm, steps)
     out, pushed = jax.jvp(run, at, d)
     if path == "kernel":
-        assert walks and set(walks) == {2}
+        # the call's walks of two, and for each the state between its
+        # two steps made again by a walk of one, which the tangent
+        # kernel's second step is taken at
+        assert walks and set(walks) == ({2} if steps == 1 else {1, 2})
     ref = _reference()
     want_out, want = jax.jvp(
         lambda *fields: ref.run(*fields, _parameters(cfg), steps), at, d)

@@ -1209,6 +1209,43 @@ def test_the_cells_sweep_spreads_a_coarse_cotangent_by_a_matrix_product(v5e):
     assert under_cost > products == calls + 1
 
 
+def _instructions_digest(text):
+    """A digest of a compiled program's instructions as a multiset:
+    every instruction's line less its metadata, the numbers that tell
+    two instructions of a kind apart, and a kernel's own text (which
+    names the lines of its source), sorted."""
+    import hashlib
+
+    lines = []
+    for line in text.splitlines():
+        if " = " not in line:
+            continue
+        line = re.sub(r",? metadata=\{[^}]*\}", "", line)
+        if "tpu_custom_call" in line:
+            line = line.split("custom_call_target")[0] + re.sub(
+                r".*(operand_layout_constraints=\{[^}]*\}\}?).*", r"\1", line)
+        line = re.sub(r", frontend_attributes=\{kernel_metadata=\{\}\}", "", line)
+        line = re.sub(r"(%[A-Za-z_\-]+(?:\.[A-Za-z_\-]+)*)[\.\d]*", r"\1", line)
+        line = re.sub(r"constantsunk[\.\w]*", "constantsunk", line)
+        lines.append(line)
+    return len(lines), hashlib.sha256("\n".join(sorted(lines)).encode()).hexdigest()
+
+
+def test_the_cells_gradient_is_the_programs_it_was_before_the_tangent_kernel(v5e):
+    """``sw-adjoint-1chip``'s two programs run no line of forward mode:
+    at the cell's size they compile to the instructions they compiled to
+    before the tangent kernel was written (PR 60: the same multisets at
+    commit 12c86a8 and after, by ``_work/digest60.py`` in either tree),
+    the adjoint kernel's call among them.  Pinned, so that a change to
+    reverse mode or to the forward sweep says so here: whoever means one
+    computes the new digests and writes them down."""
+    forward, backward, _calls = _the_cells_gradient(v5e)
+    assert _instructions_digest(forward.as_text()) == (
+        656, "b4d527d9d7f9d6133615d85453c453489b6c246f445888c0f9962c1bc3e9d35d")
+    assert _instructions_digest(backward.as_text()) == (
+        3077, "a87b3008fa57bb0d4ea7f67744f1aead3fbb1bb014e4fdf4c942494b130224e7")
+
+
 # -- the linearised run (PR 59) ---------------------------------------------
 
 
@@ -1244,26 +1281,57 @@ def _compiled_inner_loop(v5e, mesh_shape, ny, nx, calls, steps):
             step.lower(triple, triple, triple, triple, one, one).compile())
 
 
+def _tangent_kernel_calls(text):
+    """The lines of a compiled program's text that call the step's
+    tangent kernel."""
+    return [line for line in text.splitlines() if "tpu_custom_call" in line
+            and re.match(r"\s*(?:ROOT )?%wide_step_jvp(?:\.\d+)? =", line)]
+
+
+def _loop_bodies(text, holding):
+    """The computations of a compiled program's text that a ``while``
+    runs as its body and that hold ``holding``."""
+    bodies = set(re.findall(r"\bwhile\(.*?body=%([\w.\-]+)", text))
+    return [body for name, body in re.findall(
+        r"^%([\w.\-]+) \(.*?\) -> .*? \{$(.*?)^\}", text, re.M | re.S)
+        if name in bodies and holding in body]
+
+
 @pytest.mark.parametrize("mesh_shape", [(1, 1), (2, 2)])
-def test_the_tangent_sweep_runs_the_kernel_and_pushes_its_array_code(v5e, mesh_shape):
+def test_the_tangent_sweep_runs_the_forward_and_the_tangent_kernels(v5e, mesh_shape):
     """``make_tangent`` where the step is the kernel: the window's walks
-    run as the kernel, each walk's tangent is its
-    array code's, every instruction of the sweep under
-    ``sw/adjoint/tangent``, the exchanges' tangents under the
-    exchange's own scopes inside ``jvp(...)`` and none under the
-    ``transpose`` marker; beside neighbours both the state's and the
-    tangent's slabs go over the wire.  The adjoint sweep beside it is
-    the gradient's: the adjoint kernel and the adjoint exchange."""
+    run as the forward kernel and each step's tangent as the tangent
+    kernel (``sw_kernels.wide_step_jvp``: a walk of two is the forward
+    kernel's walk of two, the tangent kernel at the state it started
+    from, the forward kernel's walk of one for the state between, and
+    the tangent kernel there), every instruction of the sweep under
+    ``sw/adjoint/tangent``, the exchanges' tangents under the exchange's
+    own scopes inside ``jvp(...)`` and none under the ``transpose``
+    marker; beside neighbours both the state's and the tangents' slabs
+    go over the wire.  A walk copies three fields and no more: the kept
+    ``h``, ``u``, ``v`` get fresh ghosts for the tangent kernel while
+    the walk of two still reads them as they are.  The adjoint sweep
+    beside it is the gradient's: the adjoint kernel and the adjoint
+    exchange."""
     ny, nx = 1800, 3600
     tangent, adjoint, update = _compiled_inner_loop(v5e, mesh_shape, ny, nx, 1, 4)
     text = tangent.as_text()
-    assert _kernel_calls(text)
+    assert _kernel_calls(text) and _tangent_kernel_calls(text)
+    assert "wide_step_vjp" not in text
+    body, = _loop_bodies(text, "wide_step_jvp")
+    assert len(_tangent_kernel_calls(body)) == 2
+    # the walk of two in place; the walk of one, whose state the walk
+    # of two reads as well, not
+    assert sorted(_aliased_in_place(line, places)
+                  for line, _fields, _text, places in _kernel_calls(body)) == [False, True]
+    field = rf"f32\[{ny + 4},{nx + 4}\]"
+    assert len(re.findall(rf"= {field}\S* copy\(", body)) <= 3
+    assert not re.findall(rf"= \({field}\S*, {field}\S*, u32\[\]\S*\) copy-start\(", body)
     names = re.findall(r'op_name="([^"]*)"', text)
     scoped = [name for name in names if "sw/adjoint/" in name]
     assert scoped and all("sw/adjoint/tangent" in name for name in scoped)
-    # every instruction that is named at all: no ``jvp()/add`` of a
-    # scatter inside the loop's body (``shallow_water._add_inside``), so a
-    # trace places each event of the sweep by its ``op_name``
+    # every instruction that is named at all: a trace places each event
+    # of the sweep by its ``op_name``
     elsewhere = {name for line in text.splitlines() if " parameter(" not in line
                  for name in re.findall(r'op_name="([^"]*)"', line)
                  if "sw/adjoint/" not in name}
@@ -1272,14 +1340,17 @@ def test_the_tangent_sweep_runs_the_kernel_and_pushes_its_array_code(v5e, mesh_s
     exchanged = [name for name in names if "mpi4jax_tpu.halo_" in name]
     assert any("jvp(mpi4jax_tpu.halo_exchange_2d)/unpack" in name for name in exchanged)
     assert not any("/transpose/" in name for name in exchanged)
-    assert "wide_step_vjp" not in text
     assert ("collective-permute" in text) == (mesh_shape != (1, 1))
     if mesh_shape != (1, 1):
         wired = [line for line in text.splitlines()
                  if " collective-permute-start(" in line]
+        # the tangents' (and the kept fields') exchanges, and the slabs
+        # of the state that the forward kernel walks from
         assert any("jvp(mpi4jax_tpu.halo_exchange_2d)/wire" in line for line in wired)
+        assert any("/mpi4jax_tpu.halo_slabs_2d/wire" in line for line in wired)
     backward = adjoint.as_text()
     assert "wide_step_vjp" in backward and "/transpose/unpack" in backward
+    assert "wide_step_jvp" not in backward
     assert " all-reduce(" not in text and " all-reduce(" not in backward
     # the loop's two dot products are the mesh's
     reduces = [line for line in update.as_text().splitlines()
@@ -1295,7 +1366,15 @@ def test_the_cells_inner_loop_fits_a_chip_with_room(v5e):
     level), under 14e9 bytes (PR 54's line for taking a call off the
     window) and over a quarter of a chip.  The adjoint sweep is the
     gradient's backward sweep with a vector for the residuals, and
-    peaks where that does."""
+    peaks where that does: 9.35e9, the cell's fullest program.  The
+    tangent sweep's peak is pinned: 7.40e9 since its steps are the
+    tangent kernel's (3.16e9 of it arguments, the first guess and the
+    four call starts; a walk holds two states and a half besides its
+    own: the tangents, the state between its two steps and the three
+    kept fields with fresh ghosts), 8.04e9 while they were the array
+    code's fusions (PERF.md, PRs 59, 60): with the loop's vectors
+    8.6e9, over a quarter of a chip and under the adjoint sweep's, which
+    holds the cell's memory as before (``incremental_memory_share``)."""
     import json
 
     with open("perfbench/workloads/sw-incremental-1chip.json") as f:
@@ -1309,6 +1388,8 @@ def test_the_cells_inner_loop_fits_a_chip_with_room(v5e):
              for key, program in (("tangent", tangent), ("adjoint", adjoint))}
     for key, peak in peaks.items():
         assert 0.25 * 16e9 < peak + vectors < 14e9, (key, peak)
+    assert peaks["tangent"] == pytest.approx(7.40e9, rel=0.02), peaks
+    assert peaks["tangent"] < peaks["adjoint"]
     _forward, backward, _calls = _the_cells_gradient(v5e)
     assert peaks["adjoint"] == pytest.approx(
         backward.memory_analysis().peak_memory_in_bytes, rel=0.02)
